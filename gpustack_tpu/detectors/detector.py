@@ -1,12 +1,22 @@
 """TPU + system detection.
 
 Replaces the reference's detector stack (fastfetch binary wrapper +
-gpustack-runtime NVML probing, reference detectors/detector_factory.py):
-on a TPU-VM the source of truth is environment metadata
-(``TPU_ACCELERATOR_TYPE`` like "v5litepod-8", ``TPU_TOPOLOGY`` like
-"2x4", ``TPU_WORKER_ID``) plus ``/dev/accel*`` device nodes; system info
-comes straight from /proc (the C++ ``sysinfo`` tool in native/ provides
-the same JSON contract for non-Python consumers).
+gpustack-runtime NVML probing, reference detectors/detector_factory.py).
+Three sources, none of which opens a chip or a JAX backend:
+
+- the chips this host can open are its device nodes: ``/dev/accel*``,
+  else the numbered vfio groups ``/dev/vfio/<n>`` (``/dev/vfio/vfio`` is
+  the control node). Seen on a one-chip v5e machine (PR 23): no
+  ``/dev/accel*``, ``/dev/vfio/0`` + ``/dev/vfio/vfio``, four TPU
+  functions on the PCI bus and ``TPU_ACCELERATOR_TYPE=v5litepod-4`` —
+  only the device nodes say "one chip";
+- the generation comes from ``TPU_ACCELERATOR_TYPE`` ("v5litepod-8"),
+  else from the PCI device id of Google's functions in sysfs;
+- slice topology from ``TPU_TOPOLOGY`` / ``TPU_WORKER_ID`` /
+  ``TPU_WORKER_HOSTNAMES``.
+
+System info comes straight from /proc (the C++ ``sysinfo`` tool in
+native/ provides the same JSON contract for non-Python consumers).
 """
 
 from __future__ import annotations
@@ -16,7 +26,7 @@ import json
 import logging
 import os
 import platform
-from typing import Dict, Optional
+from typing import Dict, List, Optional
 
 from gpustack_tpu.schemas.workers import SliceTopology, TPUChip, WorkerStatus
 
@@ -37,6 +47,48 @@ _ACCEL_ALIASES = {
     "v6e": "v6e",
     "v4": "v4",
 }
+
+
+# A TPU chip is one PCI function of Google's vendor id; its device id
+# names the generation (the table the `tpu-info` tool reads chips by).
+GOOGLE_PCI_VENDOR = "0x1ae0"
+PCI_DEVICES_GLOB = "/sys/bus/pci/devices/*"
+_PCI_DEVICE_GENERATION = {
+    "0x005e": "v4",
+    "0x0063": "v5e",
+    "0x0062": "v5p",
+    "0x006f": "v6e",
+}
+
+
+def _read(path: str) -> str:
+    try:
+        with open(path) as f:
+            return f.read().strip()
+    except OSError:
+        return ""
+
+
+def pci_tpu_generations() -> List[str]:
+    """One entry per TPU chip on the PCI bus: its generation, or the raw
+    device id where the table above does not know it. Reads sysfs only —
+    the worker process never opens the chips or a JAX backend."""
+    out = []
+    for dev in sorted(glob.glob(PCI_DEVICES_GLOB)):
+        if _read(os.path.join(dev, "vendor")).lower() != GOOGLE_PCI_VENDOR:
+            continue
+        device_id = _read(os.path.join(dev, "device")).lower()
+        out.append(_PCI_DEVICE_GENERATION.get(device_id, device_id))
+    return out
+
+
+def chip_device_nodes() -> List[str]:
+    """The chips' device nodes: ``/dev/accel*``, else the numbered vfio
+    groups — ``/dev/vfio/vfio`` is the control node, not a chip."""
+    return sorted(glob.glob("/dev/accel*")) or sorted(
+        p for p in glob.glob("/dev/vfio/*")
+        if os.path.basename(p).isdigit()
+    )
 
 
 def parse_accelerator_type(accel: str):
@@ -83,15 +135,32 @@ class TPUDetector:
     def _fill_tpu(self, status: WorkerStatus) -> None:
         accel = os.environ.get("TPU_ACCELERATOR_TYPE", "")
         parsed = parse_accelerator_type(accel)
-        devices = sorted(glob.glob("/dev/accel*")) or sorted(
-            glob.glob("/dev/vfio/*")
+        pci_gens = pci_tpu_generations()
+        devices = chip_device_nodes()
+        looked_at = (
+            f"TPU_ACCELERATOR_TYPE={accel!r}, "
+            f"{len(pci_gens)} PCI function(s) of vendor {GOOGLE_PCI_VENDOR} "
+            f"under {PCI_DEVICES_GLOB} (device ids {sorted(set(pci_gens))}), "
+            f"device nodes {devices}"
         )
-        if parsed is None and not devices:
+        if parsed is None and not devices and not pci_gens:
+            logger.info("no TPU chips on this host: %s", looked_at)
             return
         if parsed:
             gen, total_chips = parsed
         else:
-            gen, total_chips = "v5e", len(devices)
+            known = {g for g in pci_gens if g in CHIP_HBM_GIB}
+            if len(known) != 1:
+                raise RuntimeError(
+                    "TPU chips found but their generation could not be "
+                    f"established: {looked_at}"
+                )
+            gen, total_chips = known.pop(), len(devices) or len(pci_gens)
+        if gen not in CHIP_HBM_GIB:
+            raise RuntimeError(
+                f"no HBM size known for TPU generation {gen!r} "
+                f"(known: {sorted(CHIP_HBM_GIB)}): {looked_at}"
+            )
         topology = os.environ.get("TPU_TOPOLOGY", "")
         num_hosts = max(
             1, int(os.environ.get("TPU_WORKER_COUNT", "0") or 0)
@@ -100,10 +169,13 @@ class TPUDetector:
         hostnames = os.environ.get("TPU_WORKER_HOSTNAMES", "")
         if num_hosts == 1 and hostnames:
             num_hosts = max(1, len(hostnames.split(",")))
+        # the device nodes are what an engine process can open: a host
+        # may show more TPU functions on its PCI bus (and name a bigger
+        # slice in TPU_ACCELERATOR_TYPE) than it was given chips
         chips_here = (
-            len(devices) if devices else total_chips // num_hosts or 1
+            len(devices) or len(pci_gens) or total_chips // num_hosts or 1
         )
-        hbm = CHIP_HBM_GIB.get(gen, 16) * 2**30
+        hbm = CHIP_HBM_GIB[gen] * 2**30
         status.chips = [
             TPUChip(index=i, chip_type=gen, hbm_bytes=hbm)
             for i in range(chips_here)
@@ -118,6 +190,8 @@ class TPUDetector:
         )
 
     def _fill_versions(self, status: WorkerStatus) -> None:
+        # version strings only: importing jax opens no backend, and the
+        # worker must never hold the chips its engines need
         try:
             import jax
 

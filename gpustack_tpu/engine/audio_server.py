@@ -313,6 +313,9 @@ def build_audio_engine_from_args(args) -> AudioEngine:
 
     if forced:
         jax.config.update("jax_platforms", forced)
+    from gpustack_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from gpustack_tpu.models.tts import TTS_PRESETS, init_tts_params
     from gpustack_tpu.models.whisper import (

@@ -768,11 +768,36 @@ class LLMEngine:
 
         return _np.asarray(vecs).tolist()
 
+    def device_info(self) -> Dict[str, Any]:
+        """What this replica runs on, from the devices of the runner's
+        mesh — so a replica that landed on the CPU says so. ``memory``
+        is per device, where the backend reports it (the CPU does not)."""
+        devices = list(self.runner.mesh.devices.flat)
+        memory = []
+        for d in devices:
+            local = d.process_index == jax.process_index()
+            stats = d.memory_stats() if local else None
+            if stats:
+                memory.append({
+                    "id": d.id,
+                    "bytes_in_use": stats.get("bytes_in_use"),
+                    "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+                    "bytes_limit": stats.get("bytes_limit"),
+                })
+        return {
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "count": len(devices),
+            "ids": [d.id for d in devices],
+            "memory": memory,
+        }
+
     def health(self) -> Dict[str, Any]:
         return {
             "status": "error" if self._fatal else "ok",
             "error": self._fatal,
             "model": self.cfg.name,
+            "device": self.device_info(),
             "slots_total": self.max_slots,
             # racy-tolerated gauge: HTTP thread reads the scheduler's
             # slot list length; worst case one admit stale

@@ -235,6 +235,9 @@ def build_image_engine_from_args(args) -> ImageEngine:
 
     if forced:
         jax.config.update("jax_platforms", forced)
+    from gpustack_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from gpustack_tpu.models.diffusion import (
         DIFFUSION_PRESETS,

@@ -22,6 +22,7 @@ Execution model (JetStream-style, TPU-first):
 from __future__ import annotations
 
 import dataclasses
+import logging
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
@@ -40,6 +41,8 @@ from gpustack_tpu.models.quant import QuantW, quant_pspecs
 from gpustack_tpu.models.transformer import KVCache, forward
 from gpustack_tpu.parallel.mesh import MeshPlan, make_mesh
 from gpustack_tpu.parallel.sharding import SpecLayout, param_pspecs
+
+logger = logging.getLogger(__name__)
 
 
 def bias_arrays(logit_bias):
@@ -170,6 +173,7 @@ class ModelRunner:
 
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
         self._prefills: Dict[int, Any] = {}
+        self._logged_attn_buckets: set = set()
         self._prefill_embeds: Dict[int, Any] = {}
         self._sample_first: Optional[Any] = None
         self._inserts: Dict[int, Any] = {}
@@ -213,25 +217,29 @@ class ModelRunner:
         ``0`` forces the XLA einsum path, unset = auto — flash on TPU for
         buckets >= 1024 (where the XLA path's [B, H, T, S] fp32 score
         tensor starts to dominate prefill HBM traffic; at 32k it simply
-        does not fit). On CPU the compiled kernel is unavailable, so auto
-        always picks XLA there (interpret mode is test-only — ~100x
-        slower).
+        does not fit). The compiled kernel exists only for the TPU, so
+        auto picks XLA on any other platform; no serving path reaches
+        the pallas interpreter.
         """
         import os
 
         if self.sp_mode:
-            return "ring"
-        knob = os.environ.get("GPUSTACK_TPU_FLASH", "")
-        if knob == "1":
-            return "flash"
-        if knob == "interpret":
-            # test hook: exercise the pallas kernel hermetically on CPU
-            return "flash_interpret"
-        if knob == "0":
-            return "xla"
-        from gpustack_tpu.utils.platform import is_tpu_backend
-
-        return "flash" if (is_tpu_backend() and bucket >= 1024) else "xla"
+            impl = "ring"
+        else:
+            knob = os.environ.get("GPUSTACK_TPU_FLASH", "")
+            if knob in ("0", "1"):
+                impl = "flash" if knob == "1" else "xla"
+            else:
+                on_tpu = self.mesh.devices.flat[0].platform == "tpu"
+                impl = "flash" if (on_tpu and bucket >= 1024) else "xla"
+        if bucket not in self._logged_attn_buckets:
+            # once per bucket, at compile time: the engine's log says
+            # which kernel serves which prompt widths
+            self._logged_attn_buckets.add(bucket)
+            logger.info(
+                "prefill bucket %d: attention impl %s", bucket, impl
+            )
+        return impl
 
     def _prefill_impl(self, params, tokens, true_len, *, attn_impl="xla"):
         """tokens [1, Tb]; returns (last_logits [V], k, v [L, Tb, H, hd])."""
@@ -241,7 +249,7 @@ class ModelRunner:
         logits, cache = forward(
             params, self.cfg, tokens, positions, cache,
             attn_impl=attn_impl,
-            mesh=self.mesh if attn_impl == "ring" else None,
+            mesh=self.mesh if attn_impl != "xla" else None,
         )
         last = jnp.take(logits[0], true_len - 1, axis=0)
         return last, cache.k[:, 0], cache.v[:, 0]
@@ -271,7 +279,7 @@ class ModelRunner:
         logits, cache = forward(
             params, self.cfg, tokens, positions, cache,
             attn_impl=attn_impl,
-            mesh=self.mesh if attn_impl == "ring" else None,
+            mesh=self.mesh if attn_impl != "xla" else None,
             embeds_override=(embeds, mask),
         )
         last = jnp.take(logits[0], true_len - 1, axis=0)
@@ -326,7 +334,7 @@ class ModelRunner:
         logits, cache = forward(
             params, self.cfg, tokens, positions, cache,
             attn_impl=attn_impl,
-            mesh=self.mesh if attn_impl == "ring" else None,
+            mesh=self.mesh if attn_impl != "xla" else None,
         )
         last = jnp.take(logits[0], true_len - 1, axis=0)
         return last, cache.k[:, 0], cache.v[:, 0]

@@ -98,26 +98,44 @@ def _taker(tensors: Dict[str, Any]):
     return take
 
 
-def load_hf_checkpoint(cfg: ModelConfig, model_dir: str) -> Dict[str, Any]:
+def load_hf_checkpoint(
+    cfg: ModelConfig, model_dir: str, quantization: str = ""
+) -> Dict[str, Any]:
     """Load *.safetensors from a local HF model dir into our param tree."""
     tensors = _read_safetensors(model_dir)
-    return build_lm_params(cfg, tensors)
+    return build_lm_params(cfg, tensors, quantization)
 
 
-def load_gguf_checkpoint(cfg: ModelConfig, gguf_path: str) -> Dict[str, Any]:
+def load_gguf_checkpoint(
+    cfg: ModelConfig, gguf_path: str, quantization: str = ""
+) -> Dict[str, Any]:
     """Load a GGUF checkpoint: dequantize to the HF tensor names
     (engine/gguf.py), then reuse the exact same mapping as safetensors —
     one param-tree builder, two on-disk formats."""
     from gpustack_tpu.engine.gguf import load_gguf_tensors
 
     tensors = load_gguf_tensors(gguf_path)
-    return build_lm_params(cfg, tensors)
+    return build_lm_params(cfg, tensors, quantization)
+
+
+class _QuantizeOnSet(dict):
+    """A layer dict that quantizes each stacked weight as it is stored, so
+    an int8 load never holds more than the int8 tree plus one bf16 leaf."""
+
+    def __setitem__(self, name: str, w) -> None:
+        from gpustack_tpu.models.quant import _CONTRACT_AXES, _quantize_leaf
+
+        if name in _CONTRACT_AXES:
+            w = _quantize_leaf(name, w)
+        super().__setitem__(name, w)
 
 
 def build_lm_params(
-    cfg: ModelConfig, tensors: Dict[str, Any]
+    cfg: ModelConfig, tensors: Dict[str, Any], quantization: str = ""
 ) -> Dict[str, Any]:
-    """HF-named tensors → the stacked functional param tree.
+    """HF-named tensors → the stacked functional param tree; with
+    ``quantization="int8"`` the tree ``quantize_params`` would give, each
+    leaf quantized as soon as it is stacked.
 
     DeepSeek checkpoints split into a dense prefix stack
     (``first_k_dense`` layers) + a MoE remainder — forward scans them
@@ -125,6 +143,7 @@ def build_lm_params(
     L = cfg.num_layers
     take = _taker(tensors)
     kd = cfg.first_k_dense if cfg.is_moe else 0
+    int8 = quantization == "int8"
 
     def build_range(rng, moe: bool) -> Dict[str, Any]:
         def stack(fmt: str, transpose: bool = False) -> jax.Array:
@@ -132,9 +151,8 @@ def build_lm_params(
                 [take(fmt.format(i), transpose) for i in rng]
             )
 
-        layers: Dict[str, Any] = {
-            "attn_norm": stack("model.layers.{}.input_layernorm.weight"),
-        }
+        layers: Dict[str, Any] = _QuantizeOnSet() if int8 else {}
+        layers["attn_norm"] = stack("model.layers.{}.input_layernorm.weight")
         if cfg.is_mla:
             # DeepSeek MLA projections (decompressed serving)
             if cfg.q_lora_rank:
@@ -359,7 +377,7 @@ def build_lm_params(
             layers["w_down"] = stack(
                 "model.layers.{}.mlp.down_proj.weight", True
             )
-        return layers
+        return dict(layers)
 
     params: Dict[str, Any] = {
         "embed": take("model.embed_tokens.weight"),
@@ -376,6 +394,12 @@ def build_lm_params(
             params["lm_head"] = params["embed"].T
     if tensors:
         logger.warning("unused checkpoint tensors: %s", sorted(tensors)[:8])
+    if int8:
+        from gpustack_tpu.models.quant import quantize_params
+
+        # the layer stacks are int8 already; this takes embed / lm_head
+        # (which one depends on the tie) and passes the rest through
+        params = quantize_params(params)
     return params
 
 
@@ -602,19 +626,29 @@ def checkpoint_source(model_dir: Optional[str]):
 
 
 def load_or_init_params(
-    cfg: ModelConfig, model_dir: Optional[str], seed: int = 0
+    cfg: ModelConfig,
+    model_dir: Optional[str],
+    seed: int = 0,
+    quantization: str = "",
 ) -> Dict[str, Any]:
+    """The model's param tree from its checkpoint, or seeded random
+    weights for a preset. With ``quantization="int8"`` the int8 tree is
+    built leaf by leaf — the whole bf16 tree is never on the device."""
     kind, path = checkpoint_source(model_dir)
     if kind == "safetensors":
         logger.info("loading checkpoint from %s", path)
-        return load_hf_checkpoint(cfg, path)
+        return load_hf_checkpoint(cfg, path, quantization)
     if kind == "gguf":
         logger.info("loading GGUF checkpoint from %s", path)
-        return load_gguf_checkpoint(cfg, path)
+        return load_gguf_checkpoint(cfg, path, quantization)
     logger.warning(
         "no checkpoint at %r — initializing random weights for %s",
         model_dir, cfg.name,
     )
+    if quantization == "int8":
+        from gpustack_tpu.models.quant import init_params_int8
+
+        return init_params_int8(cfg, jax.random.key(seed))
     return init_params(cfg, jax.random.key(seed))
 
 

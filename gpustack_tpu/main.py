@@ -309,6 +309,9 @@ def _preflight(args) -> int:
               path if present else "not built (make -C native)")
 
     if not getattr(args, "skip_jax", False):
+        # the one place outside an engine that opens a JAX backend:
+        # preflight is a command of its own and never runs inside the
+        # server or worker process, which must not hold the chips
         try:
             import jax
 

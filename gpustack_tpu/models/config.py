@@ -21,8 +21,8 @@ class ModelConfig:
 
     Attention type is derived, not stored: MHA when num_kv_heads ==
     num_heads, GQA when 1 < num_kv_heads < num_heads, MQA when
-    num_kv_heads == 1 (mirrors the attention-type taxonomy the reference
-    scheduler uses for KV-cache sizing,
+    num_kv_heads == 1 (mirrors the attention-type classification the
+    reference scheduler uses for KV-cache sizing,
     base_candidate_selector.py:148-165).
     """
 
@@ -373,7 +373,7 @@ def load_hf_config(path: str, name: str = "") -> ModelConfig:
 
 
 # ---------------------------------------------------------------------------
-# Presets. Flagship = llama3-8b (BASELINE.json north-star model). Tiny configs
+# Presets. Flagship = llama3-8b (BASELINE.md north-star model). Tiny configs
 # are for hermetic CPU tests (mirrors the reference's fixture doctrine,
 # SURVEY.md §4).
 # ---------------------------------------------------------------------------

@@ -67,7 +67,9 @@ _STACKED = {
 }
 
 
-def _quantize_leaf(name: str, w: jax.Array) -> QuantW:
+def _quantize_leaf(name: str, w) -> QuantW:
+    if isinstance(w, QuantW):
+        return w  # quantized as it was loaded (engine/weights.py)
     axes = _CONTRACT_AXES[name]
     if name in _STACKED:
         axes = tuple(a + 1 for a in axes)
@@ -98,6 +100,40 @@ def quantize_params(params: Dict[str, Any]) -> Dict[str, Any]:
         else:
             out[k] = v
     return out
+
+
+def init_params_int8(cfg, key: jax.Array) -> Dict[str, Any]:
+    """``quantize_params(init_params(cfg, key))`` without ever holding the
+    bf16 tree: one jitted program per quantized leaf, from which XLA drops
+    every other leaf's PRNG work, so the peak on the device is the int8
+    tree so far plus one bf16 leaf (an 8B model's bf16 tree does not fit
+    a 16 GB chip; its int8 tree does). The one definition of a preset's
+    seeded int8 weights: the engine start and the tests both call it."""
+    from gpustack_tpu.models.transformer import init_params
+
+    def build(k):
+        return quantize_params(init_params(cfg, k))
+
+    is_q = lambda x: isinstance(x, QuantW)  # noqa: E731
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        jax.eval_shape(build, key), is_leaf=is_q
+    )
+
+    def pick(paths):
+        def f(k):
+            by_path = dict(
+                jax.tree_util.tree_flatten_with_path(build(k), is_leaf=is_q)[0]
+            )
+            return [by_path[p] for p in paths]
+
+        return jax.jit(f)
+
+    small = [p for p, leaf in flat if not is_q(leaf)]
+    leaves = dict(zip(small, pick(small)(key)))
+    for p, leaf in flat:
+        if is_q(leaf):
+            leaves[p] = pick([p])(key)[0]
+    return jax.tree_util.tree_unflatten(treedef, [leaves[p] for p, _ in flat])
 
 
 def quant_pspecs(specs: Dict[str, Any], params: Dict[str, Any]):
@@ -214,92 +250,6 @@ def init_quantized_params(cfg, seed: int = 0):
         params["embed"] = qw((cfg.vocab_size, d), 2500, "embed")  # ~0.02
         params["lm_head"] = qw((d, cfg.vocab_size), d, "lm_head")
     return params
-
-
-def init_quantized_params_on_device(cfg, seed: int = 0):
-    """Same tree as :func:`init_quantized_params`, generated on-accelerator.
-
-    Under a remote / tunneled TPU (or any bandwidth-constrained
-    host↔device link) materializing ~8 GB of int8 weights host-side and
-    shipping them through the link dominates bench startup by minutes;
-    one jitted PRNG program generates them in HBM directly. The tree and
-    statistics match the host variant (absmax-quantized normal init).
-    """
-    import math
-
-    d, f, L = cfg.hidden_size, cfg.intermediate_size, cfg.num_layers
-
-    def qw(key, shape, fan_in, name):
-        # int32 draw then narrow: jax.random.randint's int8 path is not
-        # supported on all backends; XLA fuses the convert.
-        q = jax.random.randint(key, shape, -127, 128, dtype=jnp.int32).astype(
-            jnp.int8
-        )
-        axes = _CONTRACT_AXES[name]
-        if name in _STACKED:
-            axes = tuple(a + 1 for a in axes)
-        s_shape = tuple(n for i, n in enumerate(shape) if i not in axes)
-        s = jnp.full(
-            s_shape, 3.0 / math.sqrt(fan_in) / 127.0, jnp.bfloat16
-        )
-        return QuantW(q=q, s=s)
-
-    def build(key):
-        ones = lambda *shape: jnp.ones(shape, jnp.bfloat16)  # noqa: E731
-        zeros = lambda *shape: jnp.zeros(shape, jnp.bfloat16)  # noqa: E731
-        keys = iter(jax.random.split(key, 16))
-        gain = zeros if cfg.norm_delta_gain else ones
-        layers = {
-            "attn_norm": gain(L, d),
-            "mlp_norm": gain(L, d),
-            "wq": qw(next(keys), (L, d, cfg.q_dim), d, "wq"),
-            "wk": qw(next(keys), (L, d, cfg.kv_dim), d, "wk"),
-            "wv": qw(next(keys), (L, d, cfg.kv_dim), d, "wv"),
-            "wo": qw(next(keys), (L, cfg.q_dim, d), cfg.q_dim, "wo"),
-        }
-        if cfg.qkv_bias:
-            layers["bq"] = zeros(L, cfg.q_dim)
-            layers["bk"] = zeros(L, cfg.kv_dim)
-            layers["bv"] = zeros(L, cfg.kv_dim)
-        if cfg.qk_norm:
-            norm_init = zeros if cfg.norm_delta_gain else ones
-            layers["q_norm"] = norm_init(L, cfg.head_dim)
-            layers["k_norm"] = norm_init(L, cfg.head_dim)
-        if cfg.post_norms:
-            norm_init = zeros if cfg.norm_delta_gain else ones
-            layers["post_attn_norm"] = norm_init(L, d)
-            layers["post_mlp_norm"] = norm_init(L, d)
-        if cfg.is_moe:
-            fm, E = cfg.moe_intermediate_size, cfg.num_experts
-            layers["router"] = (
-                jax.random.normal(next(keys), (L, d, E), jnp.float32)
-                / math.sqrt(d)
-            ).astype(jnp.bfloat16)
-            layers["we_gate"] = qw(next(keys), (L, E, d, fm), d, "we_gate")
-            layers["we_up"] = qw(next(keys), (L, E, d, fm), d, "we_up")
-            layers["we_down"] = qw(next(keys), (L, E, fm, d), fm, "we_down")
-        else:
-            layers["w_gate"] = qw(next(keys), (L, d, f), d, "w_gate")
-            layers["w_up"] = qw(next(keys), (L, d, f), d, "w_up")
-            layers["w_down"] = qw(next(keys), (L, f, d), f, "w_down")
-        params = {"layers": layers, "final_norm": gain(d)}
-        if cfg.tie_word_embeddings:
-            params["embed"] = (
-                jax.random.normal(
-                    next(keys), (cfg.vocab_size, d), jnp.float32
-                )
-                * 0.02
-            ).astype(jnp.bfloat16)
-        else:
-            params["embed"] = qw(
-                next(keys), (cfg.vocab_size, d), 2500, "embed"
-            )
-            params["lm_head"] = qw(
-                next(keys), (d, cfg.vocab_size), d, "lm_head"
-            )
-        return params
-
-    return jax.jit(build)(jax.random.key(seed))
 
 
 def dequantize(name: str, w, stacked: Optional[bool] = None) -> jax.Array:

@@ -809,16 +809,26 @@ def forward(
                 # position are causally invisible)
                 from gpustack_tpu.ops.flash_attention import (
                     flash_attention_prefill,
+                    sharded_flash_attention_prefill,
                 )
 
-                attn = flash_attention_prefill(
+                flash_args = (
                     q.reshape(B, T, cfg.num_heads, cfg.head_dim),
                     new_k,
                     new_v,
                     scale,
+                )
+                flash_kw = dict(
                     interpret=attn_impl == "flash_interpret",
                     q_offset=positions[0, 0],
                 )
+                if mesh is not None:
+                    # under tp the kernel runs per shard of heads
+                    attn = sharded_flash_attention_prefill(
+                        mesh, *flash_args, **flash_kw
+                    )
+                else:
+                    attn = flash_attention_prefill(*flash_args, **flash_kw)
             else:
                 attn = _attend(
                     q, new_k, new_v, mask_l, scale,

@@ -13,9 +13,11 @@ Fully-masked k-blocks above the causal diagonal are skipped with
 ``pl.when`` — the sweep does ~half the work of a dense scan.
 
 Engine wiring: ``models/transformer.forward(attn_impl="flash")`` uses this
-for prefill steps; the engine enables it per prefill bucket via
-``GPUSTACK_TPU_FLASH`` (see engine/runner.py). Verified bit-close against
-the XLA reference in interpret mode (tests/ops/test_flash_attention.py).
+for prefill steps; the engine enables it per prefill bucket
+(engine/runner.py attn_impl_for). Verified bit-close against the XLA
+reference in interpret mode (tests/ops/test_flash_attention.py) and
+compiled for a described v5e at Qwen3-8B widths
+(tests/ops/test_chip_compile.py).
 """
 
 from __future__ import annotations
@@ -24,9 +26,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh
+from jax.sharding import PartitionSpec as P
 
 BLOCK_Q = 128
 BLOCK_K = 128
@@ -170,3 +174,39 @@ def flash_attention_prefill(
     )(off, qt, kt, vt)
     out = jnp.transpose(out[:, :, :T, :], (0, 2, 1, 3))  # [B, T, Hq, d]
     return out.reshape(B, T, Hq * d)
+
+
+def sharded_flash_attention_prefill(
+    mesh: Mesh,
+    q: jax.Array,       # [B, T, Hq, d]
+    k: jax.Array,       # [B, S, Hkv, d]
+    v: jax.Array,
+    scale: float,
+    interpret: bool = False,
+    q_offset=0,
+) -> jax.Array:
+    """:func:`flash_attention_prefill` on a mesh. The compiler cannot
+    partition a Mosaic kernel by itself, so under tensor parallelism the
+    kernel runs per shard of heads: q, k and v arrive head-sharded over
+    ``tp`` (contiguous shards keep every GQA group on one chip), rows
+    are replicated (the engine's prefill paths are B=1)."""
+    tp = int(mesh.shape["tp"])
+    if tp == 1:
+        return flash_attention_prefill(
+            q, k, v, scale, interpret=interpret, q_offset=q_offset
+        )
+    if k.shape[2] % tp:
+        raise ValueError(
+            f"flash prefill needs kv heads ({k.shape[2]}) divisible by "
+            f"tp={tp}"
+        )
+    heads = P(None, None, "tp", None)
+    return shard_map(
+        lambda q_, k_, v_, off: flash_attention_prefill(
+            q_, k_, v_, scale, interpret=interpret, q_offset=off
+        ),
+        mesh=mesh,
+        in_specs=(heads, heads, heads, P()),
+        out_specs=P(None, None, "tp"),
+        check_vma=False,
+    )(q, k, v, jnp.asarray(q_offset, jnp.int32))

@@ -29,13 +29,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-# jax moved shard_map to the top level in 0.6; older runtimes (this
-# container ships 0.4.x) only have the experimental path — resolve once
-# so every wrapper below works on both
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - exercised only on old-jax containers
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 _NEG = -1e30
 
@@ -110,17 +104,14 @@ def ring_attention(
 
     # the locally-created accumulators start device-invariant; mark them
     # varying over every mesh axis the loop body's outputs vary over, so
-    # the scan carry types match (k/v/k_positions are already varying).
-    # jax.typeof/lax.pvary are the 0.6+ varying-manual-axes machinery;
-    # pre-vma runtimes (0.4.x) need no marking — carry types match as-is
-    if hasattr(jax, "typeof") and hasattr(lax, "pvary"):
-        vma = jax.typeof(k).vma
-        m, l, acc = (
-            lax.pvary(
-                x, tuple(ax for ax in vma if ax not in jax.typeof(x).vma)
-            )
-            for x in (m, l, acc)
+    # the scan carry types match (k/v/k_positions are already varying)
+    vma = jax.typeof(k).vma
+    m, l, acc = (
+        lax.pvary(
+            x, tuple(ax for ax in vma if ax not in jax.typeof(x).vma)
         )
+        for x in (m, l, acc)
+    )
     m, l, acc, _, _, _ = lax.fori_loop(
         0, sp, body, (m, l, acc, k, v, k_positions)
     )

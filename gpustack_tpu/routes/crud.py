@@ -54,6 +54,10 @@ def default_worker_owns(principal, obj, new_fields) -> bool:
     return True
 
 
+# app key: the tasks of the open watch streams
+WATCH_STREAMS = "watch_streams"
+
+
 def add_crud_routes(
     app: web.Application,
     cls: Type[Record],
@@ -94,6 +98,8 @@ def add_crud_routes(
         for field in redact:
             data.pop(field, None)
         return data
+
+    app.setdefault(WATCH_STREAMS, set())
 
     def check_read(request: web.Request) -> Optional[web.Response]:
         if admin_read and (err := require_admin(request)):
@@ -177,6 +183,11 @@ def add_crud_routes(
         )
         await resp.prepare(request)
         agen = cls.subscribe(send_initial=True, heartbeat=15.0)
+        # a watch never ends by itself: shutdown cancels these instead
+        # of waiting out the runner's grace period (server.py _shutdown)
+        streams = request.app[WATCH_STREAMS]
+        task = asyncio.current_task()
+        streams.add(task)
         try:
             async for event in agen:
                 if (
@@ -208,6 +219,7 @@ def add_crud_routes(
         except (ConnectionResetError, asyncio.CancelledError):
             pass
         finally:
+            streams.discard(task)
             await agen.aclose()
         return resp
 

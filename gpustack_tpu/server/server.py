@@ -278,8 +278,18 @@ class Server:
             self._tasks.append(worker_task)
 
     async def run_forever(self) -> None:
+        """Serve until SIGTERM/SIGINT (graceful: the embedded worker's
+        engines are stopped first) or until a fatal path shut us down."""
+        from gpustack_tpu.utils.process import (
+            signalled_before,
+            stop_signal_event,
+        )
+
+        signalled = stop_signal_event()
         await self.start()
-        await self._stop.wait()
+        if await signalled_before(signalled, self._stop.wait()):
+            logger.info("stop signal received: shutting down")
+            await self.stop()
 
     async def stop(self) -> None:
         await self._shutdown(release_lease=True)
@@ -338,6 +348,12 @@ class Server:
         for t in self._tasks:
             t.cancel()
         if self._runner:
+            # open watch streams would each sit out the runner's whole
+            # grace period; requests that can finish still get it
+            from gpustack_tpu.routes.crud import WATCH_STREAMS
+
+            for t in list(self._runner.app.get(WATCH_STREAMS, ())):
+                t.cancel()
             await self._runner.cleanup()
         if self.db:
             self.db.close()

@@ -17,13 +17,14 @@ import logging
 import os
 import sys
 import tempfile
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 from gpustack_tpu.schemas import Model, ModelInstance
 from gpustack_tpu.schemas.inference_backends import (
     BackendVersionConfig,
     InferenceBackend,
 )
+from gpustack_tpu.utils.compile_cache import ENV_VAR as COMPILE_CACHE_ENV
 
 logger = logging.getLogger(__name__)
 
@@ -241,16 +242,21 @@ def _tpu_native_command(
     argv += model.backend_parameters
 
     env: Dict[str, str] = dict(model.env)
+    # one compile cache for the worker and every engine it starts
+    # (utils/compile_cache.py): the engine inherits the worker's
+    # setting, a model cannot point its replicas elsewhere
+    env.pop(COMPILE_CACHE_ENV, None)
     my_chips = (
         chip_indexes if chip_indexes is not None else instance.chip_indexes
     )
-    if my_chips:
-        # restrict the engine process to its assigned chips
-        env.setdefault(
-            "TPU_VISIBLE_CHIPS", ",".join(str(i) for i in my_chips)
-        )
-        env.setdefault("TPU_CHIPS_PER_PROCESS_BOUNDS", "")
-    if force_platform:
+    if not force_platform:
+        # a chip that cannot be opened is an error at start — an
+        # instance in `error` with the cause in its log — never a CPU
+        # run. --force-platform cpu is the one way to the CPU.
+        env["JAX_PLATFORMS"] = "tpu"
+        if my_chips and not instance.coordinator_address:
+            env.update(chip_env(my_chips))
+    else:
         env["GPUSTACK_TPU_PLATFORM"] = force_platform
         if force_platform == "cpu":
             # hermetic runs: the CPU backend must expose as many virtual
@@ -298,6 +304,34 @@ def _tpu_native_command(
         )
         env.setdefault("GPUSTACK_TPU_PROCESS_ID", str(process_index))
     return argv, env
+
+
+# libtpu's chip grid for a process that owns n chips of one host
+# (x,y,z): what jax's own multi-process TPU tests pass for these counts.
+_CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}
+
+
+def chip_env(chip_indexes: Sequence[int]) -> Dict[str, str]:
+    """libtpu environment that restricts one process to its chips of
+    this host and makes those chips a slice of their own, so several
+    engine processes can share a host, each on disjoint chips.
+
+    Established on a four-chip v5e host with libtpu 0.0.34 (PR 23): with
+    these three variables four one-chip processes, a two-chip and a
+    four-chip process all open exactly their chips, at once, with no
+    per-process ports or addresses and without lifting libtpu's
+    one-process lock. Each process numbers its own devices from 0."""
+    bounds = _CHIP_BOUNDS.get(len(chip_indexes))
+    if bounds is None:
+        raise ValueError(
+            f"no libtpu chip grid for {len(chip_indexes)} chips "
+            f"(supported: {sorted(_CHIP_BOUNDS)})"
+        )
+    return {
+        "TPU_VISIBLE_CHIPS": ",".join(str(i) for i in chip_indexes),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": bounds,
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
 
 
 def _render(

@@ -117,17 +117,9 @@ class DevManager:
                     fname, pid,
                 )
             os.unlink(path)
-        deadline = _time.monotonic() + 10.0
-        for pid in reaped:
-            while _time.monotonic() < deadline and os.path.exists(
-                f"/proc/{pid}"
-            ):
-                _time.sleep(0.2)
-            if os.path.exists(f"/proc/{pid}"):
-                try:
-                    os.kill(pid, signal.SIGKILL)
-                except OSError:
-                    pass
+        from gpustack_tpu.utils.process import wait_exit_or_kill
+
+        wait_exit_or_kill(reaped)
         return len(reaped)
 
     # -- event plumbing (mirrors ServeManager.handle_event) --------------
@@ -180,10 +172,9 @@ class DevManager:
         env = dict(os.environ)
         env.update(dev.env)
         if dev.chip_indexes:
-            env["TPU_VISIBLE_CHIPS"] = ",".join(
-                str(i) for i in dev.chip_indexes
-            )
-            env.setdefault("TPU_CHIPS_PER_PROCESS_BOUNDS", "")
+            from gpustack_tpu.worker.backends import chip_env
+
+            env.update(chip_env(dev.chip_indexes))
         env["GPUSTACK_TPU_DEV_INSTANCE"] = str(dev.id)
         return env
 

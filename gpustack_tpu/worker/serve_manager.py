@@ -44,6 +44,9 @@ class RunningInstance:
         self.restarts = 0
         self.stopping = False
         self.draining = False
+        # the engine answered its health check (set by the monitor):
+        # only then may anyone report this instance RUNNING
+        self.ready = False
         self.is_leader = True
         # external engines declare their own readiness endpoint (vLLM
         # uses /health) via BackendVersionConfig.health_path
@@ -290,6 +293,13 @@ class ServeManager:
                     # owns this id — its RUNNING report un-parks the
                     # row when it lands; respawning here would
                     # double-spawn the engine and leak the loser
+                    pass
+                elif not run.ready and run.process.returncode is None:
+                    # still starting (an 8B engine compiles for minutes;
+                    # seen on the chip, PR 23): the monitor's health wait
+                    # owns this id and its RUNNING report un-parks the
+                    # row — reporting RUNNING here would route requests
+                    # to an engine that does not listen yet
                     pass
                 elif run.process.returncode is None:
                     # we are reachable again AND the engine survived
@@ -568,7 +578,6 @@ class ServeManager:
         restarts. Blocks briefly until reaped pids exit so respawned
         engines don't race the old ones for the TPU device lock."""
         import json as _json
-        import time as _time
 
         reaped_pids = []
         for fname in os.listdir(self.log_dir):
@@ -610,19 +619,9 @@ class ServeManager:
                     fname, pid,
                 )
                 os.unlink(path)
-        # wait for exits (engines must release TPU devices before any
-        # respawn); escalate to SIGKILL at the deadline
-        deadline = _time.monotonic() + 10.0
-        for pid in reaped_pids:
-            while _time.monotonic() < deadline and os.path.exists(
-                f"/proc/{pid}"
-            ):
-                _time.sleep(0.2)
-            if os.path.exists(f"/proc/{pid}"):
-                try:
-                    os.kill(pid, 9)
-                except OSError:
-                    pass
+        from gpustack_tpu.utils.process import wait_exit_or_kill
+
+        wait_exit_or_kill(reaped_pids)
         return len(reaped_pids)
 
     async def stop_instance(
@@ -807,6 +806,7 @@ class ServeManager:
             if run.stopping:
                 return
             if healthy:
+                run.ready = True
                 await self._set_state(
                     run.instance_id, ModelInstanceState.RUNNING, ""
                 )

@@ -160,8 +160,18 @@ class WorkerAgent:
         )
 
     async def run_forever(self) -> None:
+        """Run until SIGTERM/SIGINT, then stop gracefully: engines are
+        terminated before the agent exits, so none is left on a chip."""
+        from gpustack_tpu.utils.process import (
+            signalled_before,
+            stop_signal_event,
+        )
+
+        signalled = stop_signal_event()
         await self.start()
-        await asyncio.gather(*self._tasks)
+        if await signalled_before(signalled, asyncio.gather(*self._tasks)):
+            logger.info("stop signal received: shutting down")
+            await self.stop()
 
     async def stop(self) -> None:
         self._stopping = True

@@ -151,7 +151,11 @@ def test_sigkill_recovery(tmp_path):
                     pass
                 time.sleep(1)
             assert new_engine_pid != old_engine_pid, "no new engine spawned"
-            assert not os.path.exists(f"/proc/{old_engine_pid}")
+            # exited (a zombie waiting for init to collect it holds no
+            # chip and no port — utils/process.py)
+            from gpustack_tpu.utils.process import pid_running
+
+            assert not pid_running(old_engine_pid)
             asyncio.run(_wait_running(base, token, 240))
 
             async def chat():

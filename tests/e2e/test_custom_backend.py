@@ -90,7 +90,8 @@ def test_custom_backend_full_lifecycle(tmp_path):
                     ) as r:
                         rows = (await r.json())["items"]
                     names = {b["name"] for b in rows}
-                    if "stub-openai" in names:
+                    # rows land one by one: wait for all that are checked
+                    if {"stub-openai", "vllm-tpu", "jetstream"} <= names:
                         break
                     await asyncio.sleep(0.5)
                 else:

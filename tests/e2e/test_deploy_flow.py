@@ -131,23 +131,29 @@ def test_deploy_and_infer(tmp_path):
                 assert inst["chip_indexes"] == [0]
                 assert inst["computed_resource_claim"]["mesh_plan"]
 
-                # chat through the server's OpenAI proxy
-                async with http.post(
-                    f"{base}/v1/chat/completions",
-                    headers=hdrs,
-                    json={
-                        "model": "tiny-chat",
-                        "messages": [
-                            {"role": "user", "content": "hello"}
-                        ],
-                        "max_tokens": 4,
-                        "temperature": 0,
-                    },
-                ) as r:
-                    assert r.status == 200, await r.text()
-                    data = await r.json()
-                assert data["object"] == "chat.completion"
-                assert data["usage"]["completion_tokens"] >= 1
+                # chat through the server's OpenAI proxy: the same walk
+                # chip_smoke.py makes on the chip (greedy twice and
+                # identical, streamed, a long prompt, four at once) —
+                # one piece of code, rehearsed here on the CPU
+                import chip_smoke
+
+                walked = await asyncio.to_thread(
+                    chip_smoke.exercise_chat, base, hdrs, "tiny-chat",
+                    long_prompt_chars=300, min_long_prompt_tokens=300,
+                    max_tokens=4, timeout=120.0,
+                )
+                assert walked["first"]["usage"]["completion_tokens"] >= 1
+                assert walked["long"]["usage"]["prompt_tokens"] >= 300
+
+                # the engine says what it runs on, through its worker
+                # (what chip_smoke.py's last line is read from)
+                workers = chip_smoke.worker_endpoints(str(tmp_path))
+                health = await asyncio.to_thread(
+                    chip_smoke.engine_health, workers, inst
+                )
+                assert health["device"]["platform"] == "cpu"
+                assert health["device"]["count"] == 1
+                assert health["tokens_generated"] > 0
 
                 # /v1/models lists the route
                 async with http.get(

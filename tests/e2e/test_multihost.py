@@ -70,7 +70,7 @@ def _kill_engines_under(data_dir) -> int:
     """SIGKILL engine processes recorded in a worker's pidfiles (engines
     outlive a killed agent — they run in their own session)."""
     killed = 0
-    log_dir = os.path.join(data_dir, "logs")
+    log_dir = os.path.join(data_dir, "instance-logs")
     if not os.path.isdir(log_dir):
         return 0
     for fname in os.listdir(log_dir):

@@ -3,13 +3,13 @@ interleaving (vLLM enable-chunked-prefill role, TPU-native formulation:
 chunks ride the prefix-continuation jit path)."""
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from gpustack_tpu.engine.engine import GenRequest, LLMEngine
-from gpustack_tpu.models import forward, init_params
+from gpustack_tpu.models import init_params
 from gpustack_tpu.models.config import get_config
+from gpustack_tpu.testing.oracle import greedy_reference as _greedy_reference
 
 
 @pytest.fixture(scope="module")
@@ -22,19 +22,6 @@ def setup():
 def _prompt(cfg, n, seed=3):
     rng = np.random.default_rng(seed)
     return rng.integers(1, cfg.vocab_size, n).tolist()
-
-
-def _greedy_reference(cfg, params, prompt_ids, n):
-    ids = list(prompt_ids)
-    out = []
-    for _ in range(n):
-        toks = jnp.asarray(ids, jnp.int32)[None, :]
-        pos = jnp.arange(len(ids), dtype=jnp.int32)[None, :]
-        logits, _ = forward(params, cfg, toks, pos)
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        ids.append(nxt)
-    return out
 
 
 def test_chunked_prefill_token_parity(setup):
@@ -156,8 +143,14 @@ def test_chunked_prefill_flash_continuation_parity(setup, monkeypatch):
     cfg = dataclasses.replace(cfg, dtype="float32")
     prompt = _prompt(cfg, 90, seed=7)
 
-    def run(flash_knob):
-        monkeypatch.setenv("GPUSTACK_TPU_FLASH", flash_knob)
+    from gpustack_tpu.engine.runner import ModelRunner
+
+    def run(attn_impl):
+        # steer the kernel choice from the test: the serving path has
+        # no way into the pallas interpreter
+        monkeypatch.setattr(
+            ModelRunner, "attn_impl_for", lambda self, bucket: attn_impl
+        )
         eng = LLMEngine(
             cfg, params, max_slots=1, max_seq_len=192, prefill_chunk=32
         )
@@ -173,7 +166,7 @@ def test_chunked_prefill_flash_continuation_parity(setup, monkeypatch):
         finally:
             eng.stop()
 
-    assert run("interpret") == run("0") == _greedy_reference(
+    assert run("flash_interpret") == run("xla") == _greedy_reference(
         cfg, params, prompt, 5
     )
 

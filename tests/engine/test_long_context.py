@@ -66,7 +66,14 @@ def test_long_context_composition(setup, monkeypatch):
     prompt = _prompt(cfg, PROMPT_LEN)
     expect = _reference(cfg, params, prompt, OUT)
 
-    monkeypatch.setenv("GPUSTACK_TPU_FLASH", "interpret")
+    from gpustack_tpu.engine.runner import ModelRunner
+
+    # steer from the test: any non-ring bucket would take the pallas
+    # kernel in interpret mode (the sp mesh keeps its ring path)
+    monkeypatch.setattr(
+        ModelRunner, "attn_impl_for",
+        lambda self, bucket: "ring" if self.sp_mode else "flash_interpret",
+    )
     eng = LLMEngine(
         cfg, params,
         max_slots=2, max_seq_len=SEQ,

@@ -145,3 +145,28 @@ def test_qwen3_int8_init_matches_tree():
     int8 = init_quantized_params(cfg, seed=0)
     assert set(bf16["layers"]) == set(int8["layers"])
     assert int8["layers"]["q_norm"].shape == (cfg.num_layers, cfg.head_dim)
+
+
+def test_qwen3_int8_checkpoint_load_quantizes_leaf_by_leaf(hf_checkpoint):
+    """An int8 load gives the tree quantize_params would give on the
+    bf16 load — same QuantW layout, same values — without the loader
+    ever returning a bf16 copy of a quantized weight."""
+    from gpustack_tpu.engine.weights import load_hf_checkpoint
+    from gpustack_tpu.models.config import load_hf_config
+    from gpustack_tpu.models.quant import QuantW, quantize_params
+
+    _, model_dir = hf_checkpoint
+    cfg = load_hf_config(model_dir)
+    ref = quantize_params(load_hf_checkpoint(cfg, model_dir))
+    got = load_hf_checkpoint(cfg, model_dir, quantization="int8")
+    is_q = lambda x: isinstance(x, QuantW)  # noqa: E731
+    assert jax.tree.structure(got, is_leaf=is_q) == jax.tree.structure(
+        ref, is_leaf=is_q
+    )
+    assert type(got["layers"]) is dict   # a plain pytree node again
+    for name in ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"):
+        assert isinstance(got["layers"][name], QuantW), name
+    assert isinstance(got["lm_head"], QuantW)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -98,3 +98,45 @@ def test_flash_q_offset_is_traced_not_specialized():
     )
     # offset widens the visible key range → outputs must differ
     assert not np.allclose(np.asarray(o1), np.asarray(o2))
+
+
+def test_flash_under_tensor_parallelism_runs_per_shard_of_heads():
+    """The chip's compiler cannot partition a Mosaic kernel by itself
+    ("wrap the call in a shard_map" — seen compiling the tp4 prefill for
+    a described v5e, PR 23): on a tp mesh the kernel runs per shard of
+    heads, with the same answer as on one device, for a continuation
+    (q_offset > 0, S > T) as well."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from gpustack_tpu.ops.flash_attention import (
+        sharded_flash_attention_prefill,
+    )
+    from gpustack_tpu.parallel.mesh import MeshPlan, make_mesh
+
+    B, T, S, off, Hq, Hkv, d = 1, 128, 256, 128, 8, 4, 64
+    ks = jax.random.split(jax.random.key(3), 3)
+    q = jax.random.normal(ks[0], (B, T, Hq, d), jnp.float32)
+    k = jax.random.normal(ks[1], (B, S, Hkv, d), jnp.float32)
+    v = jax.random.normal(ks[2], (B, S, Hkv, d), jnp.float32)
+    scale = d ** -0.5
+    ref = flash_attention_prefill(
+        q, k, v, scale, interpret=True, q_offset=off
+    )
+
+    mesh = make_mesh(MeshPlan(tp=4), jax.devices()[:4])
+    heads = NamedSharding(mesh, P(None, None, "tp", None))
+    out = jax.jit(
+        lambda q, k, v, off: sharded_flash_attention_prefill(
+            mesh, q, k, v, scale, interpret=True, q_offset=off
+        )
+    )(*(jax.device_put(x, heads) for x in (q, k, v)), jnp.int32(off))
+    assert out.sharding.spec == P(None, None, "tp")
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5
+    )
+    # kv heads that do not divide over tp are refused by name
+    with pytest.raises(ValueError, match="divisible by tp=4"):
+        sharded_flash_attention_prefill(
+            mesh, q, k[:, :, :2], v[:, :, :2], scale, interpret=True
+        )
