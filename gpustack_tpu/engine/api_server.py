@@ -182,8 +182,6 @@ class OpenAIServer:
             f"gpustack_engine_slots_total {h['slots_total']}",
             "# TYPE gpustack_engine_waiting gauge",
             f"gpustack_engine_waiting {h['waiting']}",
-            "# TYPE gpustack_engine_decode_steps_total counter",
-            f"gpustack_engine_decode_steps_total {h['steps']}",
             "# TYPE gpustack_engine_tokens_generated_total counter",
             f"gpustack_engine_tokens_generated_total {h['tokens_generated']}",
         ]
@@ -1185,6 +1183,7 @@ class OpenAIServer:
             retry = self._gen_request(
                 body, retry_ids, chat=True, json_mode=True
             )
+            retry.trace_id = gen.trace_id
             self.engine.submit(retry)
             await loop.run_in_executor(
                 None, retry.done.wait, remaining_s
@@ -1209,6 +1208,12 @@ class OpenAIServer:
             )
         except (TypeError, ValueError) as e:
             return _error(400, f"bad sampling params: {e}")
+        trace = request.get("trace")
+        if trace is not None:
+            # the engine's per-request flight entries carry the id the
+            # server's and the worker's hops of this request carry
+            for gen in gens:
+                gen.trace_id = trace.ctx.trace_id
         # disaggregated handoff: the proxy names the peer replica that
         # already holds this conversation's radix prefix (or the
         # prefill-role replica that should compute it) — pull its

@@ -42,6 +42,7 @@ import queue
 import tempfile
 import threading
 import time
+import weakref
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -67,10 +68,10 @@ _FETCH_LAG = 2
 # designated fetch/drain helpers (_process_fetch, _drain_pending,
 # _draft_propose, _upload_prefix, _resolve_staged_prefix) or off-thread.
 DISPATCH_SYNC_FREE = (
-    "_loop", "step", "_admit", "_start_request", "_finalize_start",
+    "_loop", "step", "_step", "_admit", "_start_request", "_finalize_start",
     "_new_slot_info", "_plan_chunk_job", "_advance_chunk",
-    "_decode_once", "_note_spec_dispatch", "_spec_safe", "_deliver",
-    "_emit_text", "_push", "_finish", "_flight_record",
+    "_decode_once", "_dispatch_decode", "_note_spec_dispatch", "_spec_safe",
+    "_deliver", "_emit_text", "_push", "_finish", "_flight_record",
     "_submit_kv_copy", "_store_finished_sequence", "_build_proposals",
     "_entry_ready", "_drain_ready", "_advance_one_shot",
     "_flush_detok",
@@ -83,8 +84,9 @@ DISPATCH_SYNC_FREE = (
 # pure overhead on the dispatch path. Cross-thread observational reads
 # (health gauges) carry explicit `# analysis: ignore[guarded-by]`.
 _SCHEDULER_METHODS = (
-    "step", "_loop", "_admit", "_advance_chunk", "_advance_one_shot",
-    "_build_proposals", "_decode_once", "_draft_propose",
+    "step", "_step", "_loop", "_admit", "_advance_chunk",
+    "_advance_one_shot", "_build_proposals", "_decode_once",
+    "_dispatch_decode", "_draft_propose",
     "_fail_all_requests", "_finalize_start", "_finalize_start_sync",
     "_finish", "_flight_record", "_process_fetch", "_drain_pending",
     "_drain_ready", "_start_request", "_deliver", "_flush_detok",
@@ -97,6 +99,7 @@ _SCHEDULER_METHODS = (
 GUARDED_BY = {
     "_overlap_s": "_overlap_mu",
     "_profile": "_profile_mu",
+    "_capturing": "_profile_mu",
     "_KVStager._inflight": "_mu",
     "_slots": _SCHEDULER_METHODS,
     "_free": _SCHEDULER_METHODS,
@@ -107,6 +110,40 @@ GUARDED_BY = {
     "_state": _SCHEDULER_METHODS,
     "_key": _SCHEDULER_METHODS,
 }
+
+# a busy step longer than this logs one WARNING line with its mode and
+# its phases: a stall of seconds (PERF.md, the token-loss mode) then
+# names where its time went in the engine's own log
+_SLOW_STEP_S = 1.0
+
+# jax.monitoring's listeners are process-wide and an engine has no sure
+# end of life (tests build many and stop few), so the process registers
+# one pair of listeners, with its first engine, and they feed the flight
+# recorder of every engine still alive.
+_compile_sinks: "weakref.WeakSet[_flight.FlightRecorder]" = weakref.WeakSet()
+_compile_sinks_mu = threading.Lock()
+_compile_listeners_on = False
+
+
+def _on_compile_event(event: str, seconds: float = 0.0, **_kw) -> None:
+    with _compile_sinks_mu:
+        sinks = list(_compile_sinks)
+    for recorder in sinks:
+        recorder.note_compile_event(event, seconds)
+
+
+def _watch_compiles(recorder: _flight.FlightRecorder) -> None:
+    """Count this process's lowerings and compiles into ``recorder``."""
+    global _compile_listeners_on
+    with _compile_sinks_mu:
+        _compile_sinks.add(recorder)
+        if not _compile_listeners_on:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_compile_event
+            )
+            jax.monitoring.register_event_listener(_on_compile_event)
+            _compile_listeners_on = True
+
 
 # thread-boundary contract (analysis/rules/thread_boundary.py): the
 # scheduler's working state must never be reached from `async def`
@@ -235,6 +272,11 @@ class GenRequest:
     embeds_override: Optional[Tuple[Any, Any]] = None
     stream: Optional[queue.Queue] = None   # receives (token_id, text_piece)
     request_id: str = ""
+    # the id of the hop trace this request arrived under (the API server
+    # sets it from the trace its middleware opened; "" for a request made
+    # in-process): the flight record's per-request entries carry it, so
+    # they join the server's and the worker's hops of the same request
+    trace_id: str = ""
 
     # filled by the engine
     output_ids: List[int] = dataclasses.field(default_factory=list)
@@ -540,8 +582,12 @@ class LLMEngine:
         # (observability/flight.py — the self-measured overhead ratio
         # is exported and tier-1 asserts it stays <1% of step time).
         self.flight = _flight.FlightRecorder(max_slots)
+        _watch_compiles(self.flight)
         # per-step accumulators reset at the top of step(); written only
         # by the scheduler thread
+        self._phases = _flight.StepPhases()
+        self._step_admitted: List[Tuple[str, float]] = []
+        self._step_first: List[Tuple[str, float]] = []
         self._step_mode = ""
         self._step_real = 0          # tokens genuinely dispatched
         self._step_padded = 0        # tokens the padded dispatch computed
@@ -549,10 +595,12 @@ class LLMEngine:
         self._step_prompt = 0        # prompt tokens entering prefill
         self._step_spec_proposed = 0
         self._step_spec_accepted = 0
-        # on-demand profiler capture (capture_profile): the scheduler
-        # thread starts/stops the jax.profiler trace around N busy steps
+        # on-demand profiler capture (capture_profile): the capturing
+        # thread starts and stops the jax.profiler trace; the scheduler
+        # only counts the armed capture's steps down
         self._profile_mu = threading.Lock()
         self._profile: Optional[Dict[str, Any]] = None
+        self._capturing = False
         self.ttft_hist = LatencyHistogram(TTFT_BUCKETS_S)
         self.tpot_hist = LatencyHistogram(TPOT_BUCKETS_S)
         self.e2e_hist = LatencyHistogram(E2E_BUCKETS_S)
@@ -819,6 +867,13 @@ class LLMEngine:
                 self.flight.rollback_tokens_total
             ),
             "idle_wait_s": round(self.flight.idle_wait_s_total, 3),
+            # lowerings and compiles in this process (jax.monitoring):
+            # a warm engine under traffic it has seen adds none
+            "programs_traced_total": self.flight.programs_traced_total,
+            "programs_compiled_total": self.flight.programs_compiled_total,
+            "compile_seconds_total": round(
+                self.flight.compile_seconds_total, 3
+            ),
             # the replica's multi-chip layout as one inspectable object
             # (parallel/sharding.SpecLayout)
             "layout": self.runner.layout.describe(),
@@ -960,11 +1015,29 @@ class LLMEngine:
 
     def step(self) -> bool:
         """One scheduling iteration. Returns False when fully idle."""
+        # unlocked probe: None is the steady state
+        if self._profile is None:  # analysis: ignore[guarded-by]
+            self._phases.annotate = None
+            return self._step()
+        # a capture is open: the step and its phases also go into the
+        # profiler's trace as host spans, on the clock of the device's
+        # operations
+        self._phases.annotate = jax.profiler.TraceAnnotation
+        with jax.profiler.StepTraceAnnotation(
+            "sched.step", step_num=self._step_count
+        ):
+            return self._step()
+
+    def _step(self) -> bool:
+        phases = self._phases
+        phases.reset()
         t0 = time.perf_counter()
         self._step_mode = ""
         self._step_real = self._step_padded = 0
         self._step_out = self._step_prompt = 0
         self._step_spec_proposed = self._step_spec_accepted = 0
+        self._step_admitted = []
+        self._step_first = []
         # Eager-ready drain BEFORE admission: fetch whatever the device
         # already finished (non-blocking readiness probe), so a slot
         # whose request ended re-tenants THIS step instead of
@@ -972,11 +1045,14 @@ class LLMEngine:
         # work (the only place the host may block), never a mandatory
         # delay — on a fast link results drain one step after dispatch,
         # on a slow link up to `depth` dispatches proceed unfetched.
-        self._drain_ready()
-        admitted = self._admit()
+        with phases.drain:
+            self._drain_ready()
+        with phases.admit:
+            admitted = self._admit()
         # at most one prefill chunk per step: decode cadence for running
         # slots is bounded by one chunk's latency, not a whole prompt's
-        progressed = self._advance_chunk()
+        with phases.chunk:
+            progressed = self._advance_chunk()
         if self._slots:
             self._decode_once()
             self._flight_record(t0)
@@ -986,8 +1062,9 @@ class LLMEngine:
             return True
         # Nothing active: drain any lagging fetches so finished requests
         # complete deterministically.
-        self._drain_pending()
-        if self._step_out or self._step_spec_accepted:
+        with phases.drain:
+            self._drain_pending()
+        if self._step_out or self._step_spec_accepted or self._step_first:
             # tokens delivered by the drain would otherwise vanish when
             # the next step resets the accumulators — record them so
             # flight tokens_out/spec_accepted match tokens_generated
@@ -1011,10 +1088,15 @@ class LLMEngine:
             overlap_total = self._overlap_s
         overlap_delta = overlap_total - self._overlap_seen
         self._overlap_seen = overlap_total
+        mode = self._step_mode or "decode"
+        phases_s = self._phases.seconds
         self.flight.record(
             dur_s=dur_s,
             host_overlap_s=max(0.0, overlap_delta),
-            mode=self._step_mode or "decode",
+            phases_s=phases_s,
+            admitted=self._step_admitted,
+            first_tokens=self._step_first,
+            mode=mode,
             slots_used=self.max_slots - len(self._free),
             waiting=self._waiting.qsize(),
             oldest_wait_s=max(0.0, oldest),
@@ -1029,6 +1111,14 @@ class LLMEngine:
                 kv.prefix_tokens_reused if kv is not None else 0
             ),
         )
+        if dur_s > _SLOW_STEP_S:
+            logger.warning(
+                "slow scheduler step: %.0f ms, mode %s, %s",
+                dur_s * 1e3, mode, ", ".join(
+                    f"{name} {sec * 1e3:.0f} ms"
+                    for name, sec in zip(_flight.PHASES, phases_s)
+                ),
+            )
         # unlocked fast-path probe: None is the steady state, and a
         # stale non-None just pays one _profile_step() lock round-trip
         if self._profile is not None:  # analysis: ignore[guarded-by]
@@ -1040,10 +1130,15 @@ class LLMEngine:
         self, steps: int, out_dir: str = "", timeout_s: float = 30.0
     ) -> Dict[str, Any]:
         """Wrap the next ``steps`` busy scheduler steps in a
-        ``jax.profiler`` trace (hasattr-guarded: jax builds in this
-        container drift across 0.4.x — when the profiler API is
-        missing, or ``out_dir`` is empty, the capture degrades to
-        flight-records-only) and return the captured step summary.
+        ``jax.profiler`` trace written under ``out_dir`` (an empty
+        ``out_dir`` captures the steps' flight records only) and return
+        the captured step summary.
+
+        The calling thread starts the trace, arms the countdown, waits,
+        and stops the trace: the scheduler only counts steps and never
+        holds ``_profile_mu`` across a profiler call, so collecting the
+        trace does not stand in its way. Steps that run while the trace
+        is being stopped are in the trace too, past the ones asked for.
 
         Blocks up to ``timeout_s`` for the steps to elapse; an idle
         engine returns whatever was captured by the deadline. One
@@ -1053,34 +1148,44 @@ class LLMEngine:
             "remaining": max(1, min(int(steps), 10_000)),
             "requested": max(1, min(int(steps), 10_000)),
             "records": [],
-            "out_dir": out_dir,
-            "profiler": "flight-only",
-            "started": False,
-            "error": "",
             "done": threading.Event(),
         }
         with self._profile_mu:
-            if self._profile is not None:
+            if self._capturing:
                 raise ValueError(
                     "a profile capture is already in progress"
                 )
-            self._profile = cap
-        cap["done"].wait(timeout_s)
-        with self._profile_mu:
-            if self._profile is cap:
+            self._capturing = True
+        profiler, error = "flight-only", ""
+        try:
+            if out_dir:
+                try:
+                    jax.profiler.start_trace(out_dir)
+                    profiler = "jax"
+                except Exception as e:  # reported; the steps still count
+                    error = f"start_trace failed: {e}"
+            with self._profile_mu:
+                self._profile = cap
+            cap["done"].wait(timeout_s)
+            with self._profile_mu:
+                # the idle-timeout path: the countdown never reached zero
                 self._profile = None
-            if cap["started"]:
-                # idle-timeout path: the scheduler never reached zero
-                # remaining, so the trace is still open — close it here
-                # (stop mid-step only truncates collection)
-                self._profiler_stop(cap)
-        records = list(cap["records"])
+                records = list(cap["records"])
+            if profiler == "jax":
+                try:
+                    jax.profiler.stop_trace()
+                except Exception as e:
+                    error = f"stop_trace failed: {e}"
+                    profiler = "flight-only"
+        finally:
+            with self._profile_mu:
+                self._capturing = False
         return {
             "requested": cap["requested"],
             "steps_captured": len(records),
-            "profiler": cap["profiler"],
-            "artifact": out_dir if cap["profiler"] == "jax" else "",
-            "error": cap["error"],
+            "profiler": profiler,
+            "artifact": out_dir if profiler == "jax" else "",
+            "error": error,
             "records": records,
             "aggregate": _flight.aggregate_records(
                 records, self.max_slots,
@@ -1089,50 +1194,20 @@ class LLMEngine:
         }
 
     def _profile_step(self) -> None:
-        """Advance the active capture by one recorded step (scheduler
-        thread; the lock only guards handoff with the capture thread's
-        timeout finalizer, never device work)."""
+        """Advance the armed capture by one recorded step (scheduler
+        thread; the lock only guards the handoff with the capturing
+        thread, never a profiler call or device work)."""
         with self._profile_mu:
             cap = self._profile
-            if cap is None or cap["remaining"] <= 0:
+            if cap is None:
                 return
-            if not cap["started"]:
-                cap["started"] = True
-                if cap["out_dir"] and self._profiler_start(cap):
-                    cap["profiler"] = "jax"
             snap = self.flight.snapshot(limit=1)
             if snap:
                 cap["records"].append(snap[-1])
             cap["remaining"] -= 1
             if cap["remaining"] <= 0:
-                self._profiler_stop(cap)
                 self._profile = None
                 cap["done"].set()
-
-    @staticmethod
-    def _profiler_start(cap: Dict[str, Any]) -> bool:
-        prof = getattr(jax, "profiler", None)
-        start = getattr(prof, "start_trace", None)
-        if start is None or not hasattr(prof, "stop_trace"):
-            cap["error"] = "jax.profiler.start_trace unavailable"
-            return False
-        try:
-            start(cap["out_dir"])
-            return True
-        except Exception as e:  # profiler must never kill the loop
-            cap["error"] = f"start_trace failed: {e}"
-            return False
-
-    @staticmethod
-    def _profiler_stop(cap: Dict[str, Any]) -> None:
-        if cap.get("profiler") != "jax" or cap.get("_stopped"):
-            return
-        cap["_stopped"] = True
-        try:
-            jax.profiler.stop_trace()
-        except Exception as e:
-            cap["error"] = f"stop_trace failed: {e}"
-            cap["profiler"] = "flight-only"
 
     def _plan_chunk_job(
         self, req: GenRequest, ids, matched: int = 0
@@ -1236,7 +1311,8 @@ class LLMEngine:
         failed or evicted stage cold-starts the job."""
         fut, job.pending_kv = job.pending_kv, None
         try:
-            got = fut.result()
+            with self._phases.wait:
+                got = fut.result()
         except Exception as e:
             logger.warning(
                 "prefix staging failed; cold chunked prefill: %s", e
@@ -1392,6 +1468,9 @@ class LLMEngine:
                 # client gone while queued: never spend a prefill on it
                 self._finish_aborted(req)
                 continue
+            self._step_admitted.append(
+                (req.trace_id, time.time() - req.submitted_at)
+            )
             slot = self._free.pop(0)
             self._start_request(slot, req)
             admitted = True
@@ -1491,7 +1570,8 @@ class LLMEngine:
             self._step_prompt += len(suffix)
             self._step_padded += sb
             t0 = time.time()
-            pk_dev, pv_dev = self._upload_prefix(pk, pv, use_len)
+            with self._phases.wait:
+                pk_dev, pv_dev = self._upload_prefix(pk, pv, use_len)
             req.kv_upload_s = time.time() - t0
             suffix_padded = list(suffix) + [0] * (sb - len(suffix))
             last_logits, k, v = self.runner.prefill_with_prefix(
@@ -1692,7 +1772,8 @@ class LLMEngine:
         proposers, logprobs, multi-host broadcast runners): reads the
         sampled token to the host before insert — a designated sync."""
         ids = req.prompt_ids
-        first = int(toks[0])
+        with self._phases.wait:
+            first = int(toks[0])
         first_lps = None
         if req.logprobs:
             first_lps = [(
@@ -1730,6 +1811,7 @@ class LLMEngine:
             self._slots[slot].pending_draft.clear()
 
     def _decode_once(self) -> None:
+        phases = self._phases
         if self.draft_runner is not None and self._spec_safe():
             # Drain the fetch pipeline first: a draft chain must continue
             # the target's ACTUAL last token — proposing from a lagged
@@ -1737,7 +1819,17 @@ class LLMEngine:
             # (the ngram proposer tolerates lag; a sequential draft does
             # not). One host sync per spec step, amortized over up to
             # spec_tokens generated tokens.
-            self._drain_pending()
+            with phases.drain:
+                self._drain_pending()
+        with phases.dispatch:
+            dispatched = self._dispatch_decode()
+        if dispatched and len(self._pending) > self.pipeline_depth:
+            with phases.drain:
+                self._process_fetch(*self._pending.pop(0))
+
+    def _dispatch_decode(self) -> bool:
+        """Hand the device its next decode (or verify) step; False when
+        no slot is live."""
         # Snapshot slot ownership at dispatch time: by the time this step's
         # tokens are fetched (lagged), a slot may have been retired and
         # re-used — the request_id check drops such stale tokens.
@@ -1745,7 +1837,7 @@ class LLMEngine:
             s: info.request.request_id for s, info in self._slots.items()
         }
         if not owners:
-            return
+            return False
         if self.speculative == "ngram" and self._spec_safe():
             proposals = self._build_proposals()
             self._state, tokens, produced = self.runner.verify_step(
@@ -1776,8 +1868,7 @@ class LLMEngine:
             self._step_real += len(owners)
             self._step_padded += self.max_slots
         self._step_count += 1
-        if len(self._pending) > self.pipeline_depth:
-            self._process_fetch(*self._pending.pop(0))
+        return True
 
     def _note_spec_dispatch(self, active: int) -> None:
         """Flight accounting for one verify step: every slot computes
@@ -1857,7 +1948,8 @@ class LLMEngine:
             self._draft_state, out = self.draft_runner.decode_step(
                 self._draft_state, key
             )
-            proposals[:, j] = np.asarray(out[0])
+            with self._phases.wait:
+                proposals[:, j] = np.asarray(out[0])
         self._draft_state = self.draft_runner.restore_sequence(
             self._draft_state, snap
         )
@@ -1897,18 +1989,23 @@ class LLMEngine:
                 # the speculative feed rolls back
                 self.flight.note_rollback(1)
                 return
-            self._deliver(slot, info, [int(np.asarray(payload)[0])])
+            with self._phases.wait:
+                first = int(np.asarray(payload)[0])
+            self._deliver(slot, info, [first])
             self._flush_detok()
             return
-        if kind == "spec":
-            tok_arr, produced = (np.asarray(x) for x in payload)
-        else:
-            tokens, tok_lp, top_ids, top_lps = payload
-            tok_arr = np.asarray(tokens)[:, None]   # sync point (lagged)
-            produced = None
-            lp_arr = np.asarray(tok_lp)
-            top_ids_arr = np.asarray(top_ids)
-            top_lps_arr = np.asarray(top_lps)
+        # the sync point (lagged): the one place a decode step's result
+        # makes the scheduler's thread wait for the device
+        with self._phases.wait:
+            if kind == "spec":
+                tok_arr, produced = [np.asarray(x) for x in payload]
+            else:
+                tokens, tok_lp, top_ids, top_lps = payload
+                tok_arr = np.asarray(tokens)[:, None]
+                produced = None
+                lp_arr = np.asarray(tok_lp)
+                top_ids_arr = np.asarray(top_ids)
+                top_lps_arr = np.asarray(top_lps)
         for slot, owner_id in owners.items():
             n = (
                 int(produced[slot]) if produced is not None
@@ -1959,6 +2056,9 @@ class LLMEngine:
             return
         if not req.first_token_at:
             req.first_token_at = time.time()
+            self._step_first.append(
+                (req.trace_id, req.first_token_at - req.submitted_at)
+            )
         offload: List[int] = []
         for j, tok in enumerate(toks):
             is_eos = tok in self.tokenizer.eos_ids or tok in req.stop_ids

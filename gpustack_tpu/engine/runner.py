@@ -241,6 +241,15 @@ class ModelRunner:
             )
         return impl
 
+    @staticmethod
+    def _named_jit(fn, name: str):
+        """``jax.jit`` of a ``functools.partial`` under a name of its
+        own: a partial has none, and the program would be
+        ``jit__unknown`` in every log and profiler trace, whatever its
+        bucket."""
+        fn.__name__ = name
+        return jax.jit(fn)
+
     def _prefill_impl(self, params, tokens, true_len, *, attn_impl="xla"):
         """tokens [1, Tb]; returns (last_logits [V], k, v [L, Tb, H, hd])."""
         Tb = tokens.shape[1]
@@ -261,8 +270,9 @@ class ModelRunner:
         assert Tb in self.prefill_buckets, (Tb, self.prefill_buckets)
         fn = self._prefills.get(Tb)
         if fn is None:
-            fn = jax.jit(
-                partial(self._prefill_impl, attn_impl=self.attn_impl_for(Tb))
+            fn = self._named_jit(
+                partial(self._prefill_impl, attn_impl=self.attn_impl_for(Tb)),
+                f"prefill_{Tb}",
             )
             self._prefills[Tb] = fn
         tokens = jnp.asarray(token_ids, jnp.int32)[None, :]
@@ -294,11 +304,12 @@ class ModelRunner:
         assert Tb in self.prefill_buckets, (Tb, self.prefill_buckets)
         fn = self._prefill_embeds.get(Tb)
         if fn is None:
-            fn = jax.jit(
+            fn = self._named_jit(
                 partial(
                     self._prefill_embeds_impl,
                     attn_impl=self.attn_impl_for(Tb),
-                )
+                ),
+                f"prefill_embeds_{Tb}",
             )
             self._prefill_embeds[Tb] = fn
         tokens = jnp.asarray(token_ids, jnp.int32)[None, :]
@@ -353,12 +364,13 @@ class ModelRunner:
             # a 512-token chunk against a 32k cache is exactly the
             # [T, S] blow-up flash exists to avoid (q_offset shifts the
             # kernel's causal diagonal)
-            fn = jax.jit(
+            fn = self._named_jit(
                 partial(
                     self._prefix_prefill_impl,
                     total_bucket=total_bucket,
                     attn_impl=self.attn_impl_for(total_bucket),
-                )
+                ),
+                f"prefix_prefill_{Pb}_{Tsb}_{total_bucket}",
             )
             self._prefix_prefills[key] = fn
         tokens = jnp.asarray(suffix_ids, jnp.int32)[None, :]

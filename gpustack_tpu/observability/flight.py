@@ -34,6 +34,21 @@ Record vocabulary (per step):
   on the scheduler: the overlapped engine's win, phase-attributed.
   ``host_overlap_ratio`` (aggregate) is overlapped host ms / step wall
   ms and can exceed 1.0 when several workers overlap one step.
+- ``drain_ms``/``admit_ms``/``chunk_ms``/``dispatch_ms``/``wait_ms`` —
+  the self time of the step's phases (``StepPhases``): delivering
+  fetched tokens, admitting queued requests (their prefill dispatch
+  included), one prefill chunk, the decode dispatch, and every place
+  the scheduler's thread blocked on the device. ``dur_ms - wait_ms`` is
+  the host's own work in the step (``host_ms_p50`` per mode in the
+  aggregate).
+- ``admitted``/``first_tokens`` — per request, in the step that caused
+  them: ``[trace_id, wait_ms]`` for each request taken from the queue
+  (now - submitted), ``[trace_id, ms]`` for each request whose first
+  token was handed on (first token - submitted). Empty in most steps.
+  ``trace_id`` is the hop trace's (``GET /v2/debug/traces?trace_id=``),
+  empty for a request made in-process.
+- ``traced``/``compiled`` — programs lowered and programs compiled (a
+  persistent-cache miss) in this process since the last record.
 
 Cumulative (not per-record): ``idle_wait_s_total`` — seconds the
 scheduler parked on its wakeup condition instead of busy-polling (the
@@ -49,12 +64,25 @@ stub engine and bench can share the exact contract.
 from __future__ import annotations
 
 import bisect
+import itertools
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 MODES = ("prefill", "prefill_chunk", "decode", "spec_verify")
+
+# the phases of a scheduler step, in the order the step runs them;
+# ``wait`` is innermost (inside ``drain``, ``chunk`` or ``admit``)
+PHASES = ("drain", "admit", "chunk", "dispatch", "wait")
+
+# what jax.monitoring calls the events the compile counters count
+# (observed on jax 0.9.0): one lowering a program, one backend compile
+# *or* persistent-cache load a program, and a cache hit announced just
+# before the latter
+LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 # step-time buckets: µs-scale stub steps through multi-second chunked
 # prefills on real hardware
@@ -64,6 +92,66 @@ STEP_BUCKETS_S = (
 )
 
 DEFAULT_CAPACITY = 2048
+
+
+class _Phase:
+    """One phase of ``StepPhases``, entered with ``with``. Not
+    re-entrant: no phase of a step nests inside itself."""
+
+    __slots__ = ("_owner", "_index", "_span", "_t0", "_inner", "_outer", "_ann")
+
+    def __init__(self, owner: "StepPhases", name: str):
+        self._owner = owner
+        self._index = PHASES.index(name)
+        self._span = "sched." + name
+        self._t0 = self._inner = 0.0
+        self._outer: Optional["_Phase"] = None
+        self._ann = None
+
+    def __enter__(self) -> None:
+        owner = self._owner
+        self._outer, owner._open = owner._open, self
+        self._inner = 0.0
+        if owner.annotate is not None:
+            self._ann = owner.annotate(self._span)
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> None:
+        took = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+        owner = self._owner
+        owner.seconds[self._index] += took - self._inner
+        outer, self._outer = self._outer, None
+        if outer is not None:
+            outer._inner += took
+        owner._open = outer
+
+
+class StepPhases:
+    """Self time of the phases of one scheduler step, for one thread.
+
+    ``with phases.drain: ...`` adds the seconds spent inside to drain's
+    entry of ``seconds`` (one a phase, in the order of ``PHASES``), less
+    the seconds of any phase entered inside it (``wait``): a phase's time is its self time, so the phases of a
+    step add up to no more than the step. While ``annotate`` is set (the
+    engine sets it to ``jax.profiler.TraceAnnotation`` for the steps of
+    an open profiler capture, and to None otherwise) every phase is also
+    entered as ``annotate("sched.<phase>")``, which puts the same span
+    on the profiler's clock beside the device's operations."""
+
+    def __init__(self) -> None:
+        self.seconds: List[float] = [0.0] * len(PHASES)
+        self.annotate: Optional[Callable[[str], Any]] = None
+        self._open: Optional[_Phase] = None
+        for name in PHASES:
+            setattr(self, name, _Phase(self, name))
+
+    def reset(self) -> None:
+        """A new step: a new list, so a record may keep the last one."""
+        self.seconds = [0.0] * len(PHASES)
 
 
 def _pctl(sorted_vals: List[float], q: float) -> float:
@@ -89,6 +177,7 @@ def aggregate_records(
         out["modes"] = {}
         return out
     by_mode: Dict[str, List[float]] = {}
+    host_by_mode: Dict[str, List[float]] = {}
     occ: List[float] = []
     waits: List[float] = []
     real = padded = tokens_out = proposed = accepted = 0
@@ -96,6 +185,9 @@ def aggregate_records(
     overlap_ms = dur_ms = 0.0
     for e in entries:
         by_mode.setdefault(e["mode"], []).append(e["dur_ms"])
+        host_by_mode.setdefault(e["mode"], []).append(
+            e["dur_ms"] - e.get("wait_ms", 0.0)
+        )
         occ.append(e["slots_used"] / max(1, slots_total))
         waits.append(e["oldest_wait_ms"])
         real += e["tokens_real"]
@@ -117,6 +209,10 @@ def aggregate_records(
             "steps": len(durs),
             "step_ms_p50": round(_pctl(sorted(durs), 0.5), 3),
             "step_ms_p95": round(_pctl(sorted(durs), 0.95), 3),
+            # the step less the time its thread blocked on the device
+            "host_ms_p50": round(
+                _pctl(sorted(host_by_mode[mode]), 0.5), 3
+            ),
         }
         for mode, durs in sorted(by_mode.items())
     }
@@ -172,6 +268,12 @@ GUARDED_BY = {
     "host_overlap_s_total": "_mu",
     "idle_wait_s_total": "_mu",
     "rollback_tokens_total": "_mu",
+    "programs_traced_total": "_mu",
+    "programs_compiled_total": "_mu",
+    "compile_seconds_total": "_mu",
+    "_cache_hits_pending": "_mu",
+    "_traced_since": "_mu",
+    "_compiled_since": "_mu",
     "_record_s": "_mu",
     "_step_s": "_mu",
 }
@@ -218,6 +320,15 @@ class FlightRecorder:
         self.host_overlap_s_total = 0.0
         self.idle_wait_s_total = 0.0
         self.rollback_tokens_total = 0
+        # programs lowered / compiled in this process (note_compile_event,
+        # fed by the engine's jax.monitoring listeners), and how many of
+        # each since the last step record
+        self.programs_traced_total = 0
+        self.programs_compiled_total = 0
+        self.compile_seconds_total = 0.0
+        self._cache_hits_pending = 0
+        self._traced_since = 0
+        self._compiled_since = 0
         # self-measurement
         self._record_s = 0.0
         self._step_s = 0.0
@@ -241,14 +352,20 @@ class FlightRecorder:
         kv_blocks: int = 0,
         kv_reused_total: int = 0,
         host_overlap_s: float = 0.0,
+        phases_s: Sequence[float] = (0.0,) * len(PHASES),
+        admitted: Sequence = (),
+        first_tokens: Sequence = (),
     ) -> None:
         t0 = time.perf_counter()
         with self._mu:
+            traced, self._traced_since = self._traced_since, 0
+            compiled, self._compiled_since = self._compiled_since, 0
             self._ring.append((
                 time.time(), dur_s, mode, slots_used, waiting,
                 oldest_wait_s, tokens_real, tokens_padded, tokens_out,
                 prompt_tokens, spec_proposed, spec_accepted, kv_blocks,
-                kv_reused_total, host_overlap_s,
+                kv_reused_total, host_overlap_s, phases_s, admitted,
+                first_tokens, traced, compiled,
             ))
             h = self._hist.get(mode)
             if h is None:
@@ -284,12 +401,33 @@ class FlightRecorder:
         with self._mu:
             self.rollback_tokens_total += tokens
 
+    def note_compile_event(self, event: str, seconds: float = 0.0) -> None:
+        """One ``jax.monitoring`` event (any thread of the process). A
+        lowering counts a program traced. A backend compile counts a
+        program compiled unless a persistent-cache hit was announced just
+        before it: JAX times the cache load under the same event."""
+        with self._mu:
+            if event == LOWERING_EVENT:
+                self.programs_traced_total += 1
+                self._traced_since += 1
+                self.compile_seconds_total += seconds
+            elif event == CACHE_HIT_EVENT:
+                self._cache_hits_pending += 1
+            elif event == BACKEND_COMPILE_EVENT:
+                self.compile_seconds_total += seconds
+                if self._cache_hits_pending:
+                    self._cache_hits_pending -= 1
+                else:
+                    self.programs_compiled_total += 1
+                    self._compiled_since += 1
+
     @staticmethod
     def _to_entry(row) -> Dict[str, Any]:
         (ts, dur_s, mode, slots_used, waiting, oldest_wait_s,
          tokens_real, tokens_padded, tokens_out, prompt_tokens,
          spec_proposed, spec_accepted, kv_blocks, kv_reused_total,
-         host_overlap_s) = row
+         host_overlap_s, phases_s, admitted, first_tokens, traced,
+         compiled) = row
         return {
             "ts": ts,
             "dur_ms": round(dur_s * 1e3, 4),
@@ -306,6 +444,18 @@ class FlightRecorder:
             "kv_blocks": kv_blocks,
             "kv_reused_total": kv_reused_total,
             "host_overlap_ms": round(host_overlap_s * 1e3, 4),
+            **{
+                f"{name}_ms": round(sec * 1e3, 4)
+                for name, sec in zip(PHASES, phases_s)
+            },
+            "admitted": [
+                [tid, round(s * 1e3, 3)] for tid, s in admitted
+            ],
+            "first_tokens": [
+                [tid, round(s * 1e3, 3)] for tid, s in first_tokens
+            ],
+            "traced": traced,
+            "compiled": compiled,
         }
 
     # ---- read side -----------------------------------------------------
@@ -329,10 +479,10 @@ class FlightRecorder:
     def snapshot(self, limit: int = 200) -> List[Dict[str, Any]]:
         """Newest-last copy of the most recent ``limit`` records."""
         with self._mu:
-            rows = list(self._ring)
-        return [
-            self._to_entry(r) for r in rows[-max(1, int(limit)):]
-        ]
+            rows = list(itertools.islice(
+                reversed(self._ring), max(1, int(limit))
+            ))
+        return [self._to_entry(r) for r in reversed(rows)]
 
     def aggregate(
         self, window_s: Optional[float] = None
@@ -380,6 +530,9 @@ class FlightRecorder:
             }
             idle_wait_s = self.idle_wait_s_total
             rollback_tokens = self.rollback_tokens_total
+            traced = self.programs_traced_total
+            compiled = self.programs_compiled_total
+            compile_s = self.compile_seconds_total
         lines = [decl("gpustack_engine_step_seconds")]
         for mode in sorted(hist):
             counts, total, count = hist[mode]
@@ -437,5 +590,11 @@ class FlightRecorder:
             decl("gpustack_engine_rollback_tokens_total"),
             f"gpustack_engine_rollback_tokens_total "
             f"{rollback_tokens}",
+            decl("gpustack_engine_programs_traced_total"),
+            f"gpustack_engine_programs_traced_total {traced}",
+            decl("gpustack_engine_programs_compiled_total"),
+            f"gpustack_engine_programs_compiled_total {compiled}",
+            decl("gpustack_engine_compile_seconds_total"),
+            f"gpustack_engine_compile_seconds_total {compile_s:.6f}",
         ]
         return lines

@@ -82,6 +82,14 @@ METRIC_FAMILIES = {
     "gpustack_engine_host_overlap_ratio": "gauge",
     "gpustack_engine_idle_wait_seconds_total": "counter",
     "gpustack_engine_rollback_tokens_total": "counter",
+    # programs lowered / compiled (persistent-cache misses) in the engine
+    # process and the seconds its threads stood in lowering, compiling
+    # or loading from the cache (jax.monitoring, ISSUE 26): a window
+    # that meets a shape for the first time shows here even when the
+    # persistent cache has the program
+    "gpustack_engine_programs_traced_total": "counter",
+    "gpustack_engine_programs_compiled_total": "counter",
+    "gpustack_engine_compile_seconds_total": "counter",
     # proxy-side usage metering (routes/openai_proxy.py _record_usage):
     # per-model token throughput on /metrics instead of DB-only, plus a
     # loss counter so silently-swallowed usage writes become visible

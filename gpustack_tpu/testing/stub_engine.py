@@ -225,8 +225,8 @@ def build_app(
         })
 
     async def debug_profile(request: web.Request):
-        # the stub has no jax: permanently the flight-only degradation
-        # path of the real engine's /debug/profile contract
+        # the stub has no jax: what the real engine answers to a capture
+        # with no out_dir, the steps' flight records alone
         try:
             steps = int(request.query.get("steps", 20))
         except ValueError:
@@ -241,7 +241,7 @@ def build_app(
             "steps_captured": len(records),
             "profiler": "flight-only",
             "artifact": "",
-            "error": "jax.profiler.start_trace unavailable",
+            "error": "",
             "records": records,
             "aggregate": aggregate_records(
                 records, flight.slots_total

@@ -19,7 +19,6 @@ METRIC_MAP: Dict[str, str] = {
     "gpustack_engine_slots_used": "gpustack_tpu:requests_running",
     "gpustack_engine_slots_total": "gpustack_tpu:slots_total",
     "gpustack_engine_waiting": "gpustack_tpu:requests_waiting",
-    "gpustack_engine_decode_steps_total": "gpustack_tpu:decode_steps_total",
     "gpustack_engine_tokens_generated_total":
         "gpustack_tpu:generation_tokens_total",
     "gpustack_engine_ttft_seconds": "gpustack_tpu:ttft_seconds",
@@ -116,7 +115,6 @@ NORMALIZED_FAMILIES: Dict[str, str] = {
     "gpustack_tpu:requests_running": "gauge",
     "gpustack_tpu:slots_total": "gauge",
     "gpustack_tpu:requests_waiting": "gauge",
-    "gpustack_tpu:decode_steps_total": "counter",
     "gpustack_tpu:generation_tokens_total": "counter",
     "gpustack_tpu:prompt_tokens_total": "counter",
     "gpustack_tpu:ttft_seconds": "histogram",

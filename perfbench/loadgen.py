@@ -342,6 +342,7 @@ async def drive(
     planned: List[Planned],
     seconds: float,
     during=None,
+    tail=None,
 ) -> Window:
     """Offer the mix for ``seconds``. Open loop: each request goes out
     when it is due, whatever the server is doing. Closed loop:
@@ -349,7 +350,16 @@ async def drive(
     last has finished, taking requests in order from ``planned`` (round
     again when it runs out). ``during(window)`` is an optional coroutine
     run beside the traffic (the traced run's profile call). When the
-    window closes, requests still running are cut."""
+    window closes, requests still running are cut.
+
+    ``tail(window)`` is an optional coroutine started when the window
+    closes (``--trace 2``: the profile call). Until it returns the same
+    traffic goes on, uncut: the closed loop's clients simply continue,
+    the open loop starts its plan again at the window's end (a longer
+    plan would be another multiset of lengths and gaps). Up to the
+    window's close a run with a tail does what a run without one does;
+    what is sent after it is due after it, and ``reduce_window`` scores
+    nothing that was."""
     import aiohttp
 
     url = f"{base}/v1/chat/completions"
@@ -373,12 +383,17 @@ async def drive(
         senders: List[asyncio.Task] = []
 
         async def open_loop() -> None:
-            for p in planned:
-                due = t0 + p.due_s
-                delay = due - time.perf_counter()
-                if delay > 0:
-                    await asyncio.sleep(delay)
-                senders.append(asyncio.ensure_future(one(p, due)))
+            start = t0
+            while True:
+                for p in planned:
+                    due = start + p.due_s
+                    delay = due - time.perf_counter()
+                    if delay > 0:
+                        await asyncio.sleep(delay)
+                    senders.append(asyncio.ensure_future(one(p, due)))
+                if tail is None:
+                    return
+                start += seconds
 
         async def client(queue: List[Planned]) -> None:
             while True:
@@ -396,6 +411,8 @@ async def drive(
             )
         side = asyncio.ensure_future(during(window)) if during else None
         await asyncio.sleep(max(0.0, t_end - time.perf_counter()))
+        if tail is not None:
+            await tail(window)
         for t in list(senders):
             t.cancel()
         await asyncio.gather(*senders, return_exceptions=True)

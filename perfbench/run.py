@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Run one cell of BENCHMARK.json once, through the user's entry points.
 
-    python perfbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+    python perfbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1|2>
     python perfbench/run.py --workload <cell> --rehearse        # CPU, counts only
 
 Starts ``python -m gpustack_tpu start`` (server + embedded worker, the
@@ -17,6 +17,13 @@ prints one JSON object as its last line.
 ``--trace 1`` runs the same window with a profiler capture inside it and
 reports the per-layer metrics (each by a reader of its own in
 ``perfbench/layer_metrics/``) and the device's busy time.
+``--trace 2`` is a ``--trace 0`` run followed by a short traced tail, in
+one process: up to the close of the measured window it does what
+``--trace 0`` does, and takes every end-to-end number from that window.
+Then the same traffic goes on, the profiler is started and stopped once
+for nothing, a capture of the mix's ``trace_steps`` is made, and the
+last line holds the end-to-end *and* the per-layer metrics. Its readers
+get the window's counters and spans and the tail's device trace.
 
 This process never imports JAX: the engine child needs the chip. The
 device in the last line is what the engine's ``/healthz`` names, and a
@@ -51,6 +58,8 @@ from perfbench import checks, cluster as cl, loadgen  # noqa: E402
 from perfbench.cluster import BenchFailure  # noqa: E402
 
 RUN_DEADLINE_S = 1150.0     # a first, compiling run may take 1200 s
+TAIL_LEAD_S = 1.5           # --trace 2: untraced traffic before the captures
+TAIL_BUDGET_S = 30.0        # and how long one capture may wait for its steps
 
 
 def log(record: Dict[str, Any]) -> None:
@@ -152,7 +161,7 @@ class Setup:
         }
         self.seconds = float(args.seconds)
         self.seed = int(args.seed)
-        self.trace = bool(args.trace)
+        self.trace = int(args.trace)     # 0, 1 (in the window) or 2 (after it)
 
     def plan(self) -> List[loadgen.Planned]:
         if self.mix["loop"] == "open":
@@ -247,16 +256,13 @@ def serving(setup: Setup, run_dir: str, planned):
 
 
 async def capture_profiles(
-    base: str, hdrs: Dict[str, str], insts, steps: int, at_s: float,
-    window: loadgen.Window,
+    base: str, hdrs: Dict[str, str], insts, steps: int, budget: float,
 ) -> List[Dict[str, Any]]:
-    """``POST /v2/model-instances/{id}/profile`` on every replica at once,
-    ``at_s`` into the window; each wraps the next ``steps`` busy scheduler
-    steps in ``jax.profiler`` and answers when they have run."""
+    """``POST /v2/model-instances/{id}/profile`` on every replica at once;
+    each wraps the next ``steps`` busy scheduler steps in ``jax.profiler``
+    and answers when they have run and the trace is written, or after
+    ``budget`` seconds without them."""
     import aiohttp
-
-    await asyncio.sleep(max(0.0, window.t0 + at_s - time.perf_counter()))
-    budget = max(5.0, window.seconds - at_s - 2.0)
 
     async def one(session, inst):
         url = (
@@ -346,6 +352,22 @@ def client_numbers(red: Dict[str, Any]) -> Dict[str, float]:
     return values
 
 
+def engine_stalls(flights: List[List[Dict[str, Any]]]) -> Dict[str, float]:
+    """In each engine's step records the longest scheduler step and the
+    longest time from one step's record to the next."""
+    if not any(flights):
+        return {}
+    return {
+        "engine_step_ms_max": round(
+            max(r["dur_ms"] for engine in flights for r in engine), 3),
+        "engine_step_to_step_ms_max": round(max(
+            ((b["ts"] - a["ts"]) * 1e3
+             for engine in flights for a, b in zip(engine, engine[1:])),
+            default=0.0,
+        ), 3),
+    }
+
+
 def stalls(
     window: loadgen.Window, flights: List[List[Dict[str, Any]]],
     seconds: float,
@@ -360,18 +382,12 @@ def stalls(
     times = sorted(
         t for r in window.results for t in r.chunk_times if t <= t_end
     )
-    out = {"client_silence_ms_max": max(
+    silence = max(
         ((b - a) * 1e3 for a, b in zip(times, times[1:])), default=0.0
-    )}
-    records = [r for engine in flights for r in engine]
-    if records:
-        out["engine_step_ms_max"] = max(r["dur_ms"] for r in records)
-        out["engine_step_to_step_ms_max"] = max(
-            ((b["ts"] - a["ts"]) * 1e3
-             for engine in flights for a, b in zip(engine, engine[1:])),
-            default=0.0,
-        )
-    return {k: round(v, 3) for k, v in out.items()}
+    )
+    return {
+        "client_silence_ms_max": round(silence, 3), **engine_stalls(flights)
+    }
 
 
 def end_to_end(setup: Setup, red: Dict[str, Any], setup_s: float) -> Dict[str, Any]:
@@ -392,7 +408,7 @@ def run(args: argparse.Namespace, cluster_box: List[cl.Cluster]) -> Dict[str, An
     deadline = T_PROCESS_START + RUN_DEADLINE_S
     run_dir = os.path.join(
         ROOT, "chiprun_out", "perfbench", "runs",
-        f"{setup.cell['name']}-s{setup.seed}-t{int(setup.trace)}"
+        f"{setup.cell['name']}-s{setup.seed}-t{setup.trace}"
         + ("-rehearse" if setup.rehearse else ""),
     )
     shutil.rmtree(run_dir, ignore_errors=True)
@@ -417,37 +433,88 @@ def run(args: argparse.Namespace, cluster_box: List[cl.Cluster]) -> Dict[str, An
     engines = [cl.engine_url(workers, i) for i in insts]
     buckets = warm_up(setup, cluster.base, hdrs, engines, planned)
 
+    def flights_between(t_lo: float, t_hi: float):
+        """Per engine, its step records sealed in ``(t_lo, t_hi]``."""
+        return [
+            [r for r in cl.engine_get(
+                workers, inst, "/debug/flight?limit=2048"
+            )["records"] if t_lo < r["ts"] <= t_hi]
+            for inst in insts
+        ]
+
+    def window_records(window: loadgen.Window):
+        """The engines' step records and the server's hop traces of the
+        measured window: every run reads the former for its log, a traced
+        run's readers take their numbers from both."""
+        t_lo, t_hi = window.t0_wall, window.t0_wall + window.seconds
+        flights = flights_between(t_lo, t_hi)
+        if not setup.trace:
+            return flights, []
+        hops = cl.expect(
+            cl.http(
+                "GET",
+                f"{cluster.base}/v2/debug/traces?component=server"
+                f"&model={setup.spec['name']}&phase=connect&limit=200",
+                headers=hdrs,
+            ), 200, "hop traces",
+        )["items"]
+        return flights, [
+            h for h in hops if t_lo <= h.get("started_at", 0) <= t_hi
+        ]
+
     cache_before = cache_entries()
-    during = None
+    during = tail = None
     profiles: List[Dict[str, Any]] = []
-    # the capture comes late in the window: when it stops, the profiler
-    # collects its events inside the scheduler's thread and the engine
-    # stands still for seconds (PERF.md), so what the traced run reads
-    # from counters and spans is what came before the capture began
+    steps = int(setup.mix.get("trace_steps", 16))
+    # --trace 1 captures late in the window and reads its counters and
+    # client-side numbers from the part of the window before the capture
+    # (PR 25: stopping the profiler held the engine still for seconds)
     trace_at_s = float(setup.mix.get("trace_at", 0.8)) * setup.seconds
-    if setup.trace:
+    if setup.trace == 1:
         async def during(window):
+            await asyncio.sleep(
+                max(0.0, window.t0 + trace_at_s - time.perf_counter())
+            )
             profiles.extend(await capture_profiles(
-                cluster.base, hdrs, insts,
-                int(setup.mix.get("trace_steps", 16)), trace_at_s, window,
+                cluster.base, hdrs, insts, steps,
+                max(5.0, window.seconds - trace_at_s - 2.0),
+            ))
+    read_at_close = None
+    if setup.trace == 2:
+        # --trace 2: the window is measured untraced and closed; the
+        # traffic goes on and only then is anything traced. The window's
+        # records are read first: the engines keep stepping through the
+        # tail and their rings hold 2048 steps.
+        async def tail(window):
+            nonlocal read_at_close
+            read_at_close = await asyncio.get_running_loop().run_in_executor(
+                None, window_records, window
+            )
+            await asyncio.sleep(TAIL_LEAD_S)
+            # the profiler's first start in a process costs more than a
+            # later one: start and stop it once and throw that trace away
+            for p in await capture_profiles(
+                cluster.base, hdrs, insts, 1, TAIL_BUDGET_S
+            ):
+                log({"phase": "profiler_first_start", **profile_summary(p)})
+                shutil.rmtree(p.get("artifact") or "", ignore_errors=True)
+            profiles.extend(await capture_profiles(
+                cluster.base, hdrs, insts, steps, TAIL_BUDGET_S
             ))
     setup_s = time.time() - T_PROCESS_START
     window = asyncio.run(loadgen.drive(
         cluster.base, hdrs, setup.spec["name"], setup.mix, planned,
-        setup.seconds, during,
+        setup.seconds, during, tail,
     ))
     cache_after = cache_entries()
     red = loadgen.reduce_window(
-        window, setup.mix, trace_at_s if setup.trace else None
+        window, setup.mix, trace_at_s if setup.trace == 1 else None
     )
-    # the engines' step records of the window, before anything else
-    # pushes them out of the ring: every run reads them for its log, the
-    # traced run's readers take their numbers from them
-    t_lo, t_hi = window.t0_wall, window.t0_wall + red["seconds"]
-    flights = []
-    for inst in insts:
-        got = cl.engine_get(workers, inst, "/debug/flight?limit=2048")
-        flights.append([r for r in got["records"] if t_lo <= r["ts"] <= t_hi])
+    # before anything else pushes the window's steps out of the rings
+    flights, hops = read_at_close or window_records(window)
+    t_cut = window.t0_wall + red["seconds"]
+    flights = [[r for r in e if r["ts"] <= t_cut] for e in flights]
+    hops = [h for h in hops if h.get("started_at", 0) <= t_cut]
     log({"phase": "window", "setup_s": round(setup_s, 3),
          "compiled_in_window": cache_after - cache_before,
          "cache_entries": cache_after, "buckets": buckets,
@@ -458,20 +525,22 @@ def run(args: argparse.Namespace, cluster_box: List[cl.Cluster]) -> Dict[str, An
              "client": client_numbers(red),
              "stalls": stalls(window, flights, red["seconds"]),
          })})
+    if setup.trace == 2 and not setup.rehearse:
+        # what the captures cost the engine: its steps from the window's
+        # close to now (a stop that held the scheduler shows as the gap
+        # to the first record after it, which is sealed after the answer)
+        t_lo, t_hi = window.t0_wall + window.seconds, time.time()
+        after = flights_between(t_lo, t_hi)
+        log({"phase": "tail", "seconds": round(t_hi - t_lo, 3),
+             "steps": sum(map(len, after)),
+             "capture_s": [
+                 round(p["_t1_wall"] - p["_t0_wall"], 3) for p in profiles
+             ],
+             "stalls": engine_stalls(after)})
 
     ctx: Dict[str, Any] = {}
     if setup.trace:
-        hops = cl.expect(
-            cl.http(
-                "GET",
-                f"{cluster.base}/v2/debug/traces?component=server"
-                f"&model={setup.spec['name']}&phase=connect&limit=200",
-                headers=hdrs,
-            ), 200, "hop traces",
-        )["items"]
-        ctx.update(flights=flights, hops=[
-            h for h in hops if t_lo <= h.get("started_at", 0) <= t_hi
-        ])
+        ctx.update(flights=flights, hops=hops)
 
     cl.poll(
         "the engines to drain", time.time() + 60,
@@ -487,6 +556,12 @@ def run(args: argparse.Namespace, cluster_box: List[cl.Cluster]) -> Dict[str, An
     if not sum(h.get("tokens_generated", 0) for h in healths):
         raise BenchFailure("the engines generated no tokens")
     peak = max(cl.peak_memory_bytes(h) for h in healths)
+    log({"phase": "engines", "health": [
+        {k: h.get(k) for k in (
+            "steps", "flight_overhead_ratio", "programs_traced_total",
+            "programs_compiled_total", "compile_seconds_total",
+        )} for h in healths
+    ]})
     cluster.stop()
     log({"phase": "stopped", "at_s": round(time.time() - T_PROCESS_START, 3)})
 
@@ -519,18 +594,15 @@ def run(args: argparse.Namespace, cluster_box: List[cl.Cluster]) -> Dict[str, An
                 p.get("steps_captured") for p in profiles
             ]
         return result
-    if not setup.trace:
+    if setup.trace != 1:
+        # from the untraced window (--trace 2: closed before any tracing)
         result["metrics"] = end_to_end(setup, red, setup_s)
+    if not setup.trace:
         return result
 
     reduced = []
     for p in profiles:
-        log({"phase": "profile", **{
-            k: p.get(k) for k in (
-                "_instance", "_status", "steps_captured", "profiler",
-                "artifact", "error",
-            )
-        }})
+        log({"phase": "profile", **profile_summary(p)})
         if p.get("profiler") == "jax" and p.get("artifact"):
             out = os.path.join(run_dir, f"trace-{p['_instance']}.json")
             got = reduce_trace(p["artifact"], out)
@@ -557,8 +629,17 @@ def run(args: argparse.Namespace, cluster_box: List[cl.Cluster]) -> Dict[str, An
         max_seq_len=int(setup.spec["max_seq_len"]),
         max_slots=int(setup.spec["max_slots"]),
     )
-    result["metrics"] = read_layer_metrics(setup, ctx)
+    result["metrics"].update(read_layer_metrics(setup, ctx))
     return result
+
+
+def profile_summary(p: Dict[str, Any]) -> Dict[str, Any]:
+    return {
+        k: p.get(k) for k in (
+            "_instance", "_status", "steps_captured", "profiler",
+            "artifact", "error",
+        )
+    }
 
 
 def peaks_for(kind: str) -> Dict[str, float]:
@@ -575,7 +656,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--seconds", type=float, default=None)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     ap.add_argument(
         "--rehearse", action="store_true",
         help="the same path on the CPU with a tiny model: counts only",

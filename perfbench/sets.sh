@@ -1,8 +1,8 @@
 #!/bin/bash
 # Sets of runs of cells, from a copy of the committed files (README.md,
 # "Measuring a cell for its bounds"):  sets.sh <runs> <sets> <cell>...
-# Sets are numbered from FIRST_SET (1). After the sets, one traced run of
-# each cell, unless TRACED=0. A run that fails, or is not correct, ends
+# Sets are numbered from FIRST_SET (1). After the sets, one run of each cell
+# that measures and then traces (--trace 2), unless TRACED=0. A run that fails, or is not correct, ends
 # everything: a set with such a run is no set, and chip time is dear.
 # RUN_ARGS is passed on to run.py (RUN_ARGS=--rehearse tries this script on the CPU).
 # Run i of every set has the seed SEED0 + i (SEED0: 3000000000).
@@ -31,7 +31,7 @@ for s in $(seq $first $((first + sets - 1))); do
 done
 if [ "${TRACED:-1}" != 0 ]; then
   for cell in "$@"; do
-    one $cell.T --workload $cell --seed $((seed0 + 1)) --trace 1
+    one $cell.T --workload $cell --seed $((seed0 + 1)) --trace 2
     tail -n 1 $out/$cell.T.out | cut -c1-3500
   done
 fi
