@@ -4,9 +4,11 @@ call's shapes (``perfbench/roofline.py``: the lower triangle's operations
 at the bf16 peak, or q, k, v, o moved once at the HBM peak, whichever is
 longer) over the device time the calls took. The shapes are read from the
 operation's own text in the trace, ``%flash_attention_prefill.N =
-bf16[batch, heads, tokens, head_dim] custom-call(...)``. Cells whose
-prompts stay under the 1024 bucket never run the kernel, and the reader
-then returns nothing."""
+bf16[batch, heads, tokens, head_dim] custom-call(...)``. A stretch without
+a call gives nothing to read, and the harness then captures again; a cell
+whose prompts stay under the 1024 bucket never runs the kernel
+(``engine/runner.py``: a smaller bucket takes the XLA path) and must not
+declare the metric."""
 
 import re
 

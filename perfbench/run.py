@@ -23,7 +23,13 @@ one process: up to the close of the measured window it does what
 Then the same traffic goes on, the profiler is started and stopped once
 for nothing, a capture of the mix's ``trace_steps`` is made, and the
 last line holds the end-to-end *and* the per-layer metrics. Its readers
-get the window's counters and spans and the tail's device trace.
+get the window's counters and spans and the tail's device trace. That
+trace is reduced while the traffic goes on, and if a ``device_trace``
+reader of the cell reads nothing from it (a closed loop's 16 steps now
+and then hold no whole prefill), it is deleted and the capture made
+again, at most ``MAX_CAPTURES`` times; a run that still has nothing to
+read fails and says for which metric, rather than print a line without
+a metric its cell declares.
 
 This process never imports JAX: the engine child needs the chip. The
 device in the last line is what the engine's ``/healthz`` names, and a
@@ -54,12 +60,16 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, ROOT)
 
-from perfbench import checks, cluster as cl, loadgen  # noqa: E402
+from perfbench import checks, cluster as cl, loadgen, stretch  # noqa: E402
 from perfbench.cluster import BenchFailure  # noqa: E402
 
 RUN_DEADLINE_S = 1150.0     # a first, compiling run may take 1200 s
 TAIL_LEAD_S = 1.5           # --trace 2: untraced traffic before the captures
 TAIL_BUDGET_S = 30.0        # and how long one capture may wait for its steps
+# --trace 2 captures until the cell's device_trace readers are served. One
+# capture in four is not (24 captures, PERF.md section 3): eight all fail
+# once in 65,000 runs, and cost 13 s each of the 360 s a run may take.
+MAX_CAPTURES = 8
 
 
 def log(record: Dict[str, Any]) -> None:
@@ -289,9 +299,9 @@ async def capture_profiles(
 
 
 def reduce_trace(artifact: str, out_path: str) -> Optional[Dict[str, Any]]:
-    """The xplane reduction, in a child that may import JAX (on the CPU):
-    by now the cluster is down and the chip is free, but this process
-    still never touches JAX."""
+    """The xplane reduction, in a child that may import JAX (on the CPU,
+    so that it can run while the engine holds the chip): this process
+    never touches JAX."""
     env = dict(os.environ, JAX_PLATFORMS="cpu", TPU_SKIP_MDS_QUERY="1")
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
     proc = subprocess.run(
@@ -305,6 +315,63 @@ def reduce_trace(artifact: str, out_path: str) -> Optional[Dict[str, Any]]:
     return load_json(out_path)
 
 
+def reduce_profiles(
+    profiles: List[Dict[str, Any]], run_dir: str, keep: bool
+) -> List[Dict[str, Any]]:
+    """Each capture's trace reduced (``trace-<instance>.json`` in the run
+    directory) and, unless it is to be kept, deleted."""
+    reduced = []
+    for p in profiles:
+        log({"phase": "profile", **profile_summary(p)})
+        if p.get("profiler") == "jax" and p.get("artifact"):
+            got = reduce_trace(
+                p["artifact"],
+                os.path.join(run_dir, f"trace-{p['_instance']}.json"),
+            )
+            if got is not None:
+                reduced.append(got)
+            if not keep:
+                shutil.rmtree(p["artifact"], ignore_errors=True)
+    return reduced
+
+
+async def capture_served(
+    base: str, hdrs: Dict[str, str], insts, steps: int, budget: float,
+    readers, ctx: Dict[str, Any], run_dir: str, keep: bool = False,
+) -> List[Dict[str, Any]]:
+    """``capture_profiles`` until the trace serves ``readers`` (the
+    cell's ``device_trace`` metrics, each with its reader's module): the
+    capture is reduced into ``ctx["traces"]`` while the traffic goes on
+    and each reader asked for its number. The first capture that gives
+    every one is the run's, as when one capture was all a run made. One
+    that does not is logged with what its steps were, and after
+    ``MAX_CAPTURES`` of them the run fails in its own words."""
+    loop = asyncio.get_running_loop()
+    for taken in range(1, MAX_CAPTURES + 1):
+        profiles = await capture_profiles(base, hdrs, insts, steps, budget)
+        ctx["traces"] = await loop.run_in_executor(
+            None, reduce_profiles, profiles, run_dir, keep
+        )
+        unread = [name for name, mod in readers if mod.read(ctx) is None]
+        if not unread:
+            for p in profiles:
+                p["_capture"] = taken
+            return profiles
+        seen = {
+            "modes": [stretch.modes(p.get("records") or []) for p in profiles],
+            "errors": [p["error"] for p in profiles if p.get("error")],
+        }
+        if taken < MAX_CAPTURES:
+            log({"phase": "capture_retaken", "capture": taken,
+                 "unread": unread, **seen})
+    raise BenchFailure(
+        f"{MAX_CAPTURES} captures of {steps} steps held nothing for "
+        f"{', '.join(unread)} to read; the last one: {json.dumps(seen)} "
+        f"(a letter a step: p prefill, c prefill chunk, d decode, s "
+        f"speculative verify)"
+    )
+
+
 def reader_path(metric: str) -> Optional[str]:
     """``layer_metrics/<metric>.py``; a quantity split over cells that
     report different end-to-end metrics (``<metric>.<part>``, one entry
@@ -316,25 +383,32 @@ def reader_path(metric: str) -> Optional[str]:
     return None
 
 
+def load_reader(metric: str):
+    path = reader_path(metric)
+    if path is None:
+        raise BenchFailure(f"per-layer metric {metric} has no reader")
+    spec = importlib.util.spec_from_file_location(
+        "perfbench_reader_" + metric.replace(".", "_").replace("-", "_"), path
+    )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def read_layer_metrics(
     setup: Setup, ctx: Dict[str, Any]
 ) -> Dict[str, Dict[str, Any]]:
     """Each per-layer metric of this cell through its own reader,
     ``perfbench/layer_metrics/<name>.py:read(ctx)``. A reader that finds
-    nothing to read returns None and the metric is left out."""
+    nothing to read returns None; its metric is left out of the line (a
+    program from before a counter existed still gets its line), and the
+    log says so."""
     out: Dict[str, Dict[str, Any]] = {}
     for m in metrics_of(setup.bench, "per_layer", setup.cell["name"]):
-        path = reader_path(m["name"])
-        if path is None:
-            raise BenchFailure(f"per-layer metric {m['name']} has no reader")
-        spec = importlib.util.spec_from_file_location(
-            "perfbench_reader_" + m["name"].replace(".", "_").replace("-", "_"),
-            path,
-        )
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        value = mod.read(ctx)
-        if value is not None:
+        value = load_reader(m["name"]).read(ctx)
+        if value is None:
+            log({"phase": "metric_left_out", "name": m["name"]})
+        else:
             out[m["name"]] = {"value": float(value), "unit": m["unit"]}
     return out
 
@@ -432,6 +506,17 @@ def run(args: argparse.Namespace, cluster_box: List[cl.Cluster]) -> Dict[str, An
          "instances": [i["id"] for i in insts], "device": devs[0]})
     engines = [cl.engine_url(workers, i) for i in insts]
     buckets = warm_up(setup, cluster.base, hdrs, engines, planned)
+    # what the readers get: filled as the run goes on
+    ctx: Dict[str, Any] = {
+        "model_config": setup.model_config, "spec": setup.spec,
+        "buckets": buckets,
+        "max_seq_len": int(setup.spec["max_seq_len"]),
+        "max_slots": int(setup.spec["max_slots"]),
+    }
+    if setup.trace:
+        ctx["peaks"] = (
+            {} if setup.rehearse else peaks_for(devs[0]["device_kind"])
+        )
 
     def flights_between(t_lo: float, t_hi: float):
         """Per engine, its step records sealed in ``(t_lo, t_hi]``."""
@@ -481,6 +566,14 @@ def run(args: argparse.Namespace, cluster_box: List[cl.Cluster]) -> Dict[str, An
             ))
     read_at_close = None
     if setup.trace == 2:
+        # the readers of the device trace decide whether a capture serves;
+        # a CPU run's trace has no chip's plane, and its one capture stands
+        traced = [] if setup.rehearse else [
+            (m["name"], load_reader(m["name"]))
+            for m in metrics_of(setup.bench, "per_layer", setup.cell["name"])
+            if m["source"] == "device_trace"
+        ]
+
         # --trace 2: the window is measured untraced and closed; the
         # traffic goes on and only then is anything traced. The window's
         # records are read first: the engines keep stepping through the
@@ -490,6 +583,7 @@ def run(args: argparse.Namespace, cluster_box: List[cl.Cluster]) -> Dict[str, An
             read_at_close = await asyncio.get_running_loop().run_in_executor(
                 None, window_records, window
             )
+            ctx.update(flights=read_at_close[0], hops=read_at_close[1])
             await asyncio.sleep(TAIL_LEAD_S)
             # the profiler's first start in a process costs more than a
             # later one: start and stop it once and throw that trace away
@@ -498,8 +592,9 @@ def run(args: argparse.Namespace, cluster_box: List[cl.Cluster]) -> Dict[str, An
             ):
                 log({"phase": "profiler_first_start", **profile_summary(p)})
                 shutil.rmtree(p.get("artifact") or "", ignore_errors=True)
-            profiles.extend(await capture_profiles(
-                cluster.base, hdrs, insts, steps, TAIL_BUDGET_S
+            profiles.extend(await capture_served(
+                cluster.base, hdrs, insts, steps, TAIL_BUDGET_S,
+                traced, ctx, run_dir, args.keep_trace,
             ))
     setup_s = time.time() - T_PROCESS_START
     window = asyncio.run(loadgen.drive(
@@ -533,12 +628,12 @@ def run(args: argparse.Namespace, cluster_box: List[cl.Cluster]) -> Dict[str, An
         after = flights_between(t_lo, t_hi)
         log({"phase": "tail", "seconds": round(t_hi - t_lo, 3),
              "steps": sum(map(len, after)),
+             "captures": max(p["_capture"] for p in profiles),
              "capture_s": [
                  round(p["_t1_wall"] - p["_t0_wall"], 3) for p in profiles
              ],
              "stalls": engine_stalls(after)})
 
-    ctx: Dict[str, Any] = {}
     if setup.trace:
         ctx.update(flights=flights, hops=hops)
 
@@ -593,43 +688,34 @@ def run(args: argparse.Namespace, cluster_box: List[cl.Cluster]) -> Dict[str, An
             result["counts"]["profiles"] = [
                 p.get("steps_captured") for p in profiles
             ]
-        return result
-    if setup.trace != 1:
+    elif setup.trace != 1:
         # from the untraced window (--trace 2: closed before any tracing)
         result["metrics"] = end_to_end(setup, red, setup_s)
     if not setup.trace:
         return result
 
-    reduced = []
-    for p in profiles:
-        log({"phase": "profile", **profile_summary(p)})
-        if p.get("profiler") == "jax" and p.get("artifact"):
-            out = os.path.join(run_dir, f"trace-{p['_instance']}.json")
-            got = reduce_trace(p["artifact"], out)
-            if got is not None:
-                reduced.append(got)
-            if not args.keep_trace:
-                shutil.rmtree(p["artifact"], ignore_errors=True)
-    devices = [d for r in reduced for d in r["devices"]]
-    if not devices:
+    if setup.trace == 1:
+        ctx["traces"] = reduce_profiles(profiles, run_dir, args.keep_trace)
+    devices = [d for r in ctx["traces"] for d in r["devices"]]
+    if devices:
+        from perfbench import trace_reduce
+
+        device["busy_s"] = sum(d["busy_s"] for d in devices) / len(devices)
+        device["window_s"] = sum(d["window_s"] for d in devices) / len(devices)
+        result["breakdown"] = trace_reduce.breakdown({"devices": devices})
+    elif not setup.rehearse:
         raise BenchFailure(
             "the traced run has no device plane with operations: "
             + json.dumps([p.get("error") for p in profiles])
         )
-    from perfbench import trace_reduce
-
-    device["busy_s"] = sum(d["busy_s"] for d in devices) / len(devices)
-    device["window_s"] = sum(d["window_s"] for d in devices) / len(devices)
-    merged = {"devices": devices}
-    result["breakdown"] = trace_reduce.breakdown(merged)
-    ctx.update(
-        loadgen=red, traces=reduced, healths=healths,
-        model_config=setup.model_config, spec=setup.spec,
-        peaks=peaks_for(device["kind"]), peak_memory_bytes=peak,
-        max_seq_len=int(setup.spec["max_seq_len"]),
-        max_slots=int(setup.spec["max_slots"]),
-    )
-    result["metrics"].update(read_layer_metrics(setup, ctx))
+    ctx.update(loadgen=red, healths=healths, peak_memory_bytes=peak)
+    layer = read_layer_metrics(setup, ctx)
+    if setup.rehearse:
+        # the readers ran over a CPU run's records and its trace, which
+        # has no chip's plane: which of them found something, no number
+        log({"phase": "metrics_read", "names": sorted(layer)})
+    else:
+        result["metrics"].update(layer)
     return result
 
 

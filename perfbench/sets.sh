@@ -17,6 +17,7 @@ one() {  # tag, then run.py's arguments
   rc=$?
   echo "$tag rc=$rc $(tail -n 1 $out/$tag.out | cut -c1-360)"
   grep '"phase": "window"' $out/$tag.out | sed 's/.*"client": /  /' | cut -c1-400
+  grep -E '"phase": "(capture_retaken|tail|metric_left_out)"' $out/$tag.out | cut -c1-400
   if [ $rc != 0 ] || ! tail -n 1 $out/$tag.out | grep -q '"correct": true, .*"failed": 0,'; then
     grep '"phase": "checks"' $out/$tag.out | cut -c1-1500; tail -c 3000 $out/$tag.err
     rm -rf chiprun_out; exit 1

@@ -29,7 +29,12 @@ def spread(values: List[float]) -> float:
     if len(values) < 2:
         return float("nan")
     q1, _, q3 = statistics.quantiles(values, n=4)
-    return (q3 - q1) / statistics.median(values)
+    median = statistics.median(values)
+    if median == 0:
+        # a count that is 0 in every run (runner.programs_traced) spreads
+        # by 0; one that is 0 in most has no share of its median
+        return 0.0 if q3 == q1 else float("inf")
+    return (q3 - q1) / median
 
 
 def trimmed(values: List[float]) -> List[float]:

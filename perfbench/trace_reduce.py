@@ -7,11 +7,12 @@ programs (XLA modules) by name, and the longest idle gaps.
 (``tests/perfbench/cut_fixture.py`` prints a trace for the eye and cuts
 the tests' fixture from one.)
 
-Run as a child of the benchmark with ``JAX_PLATFORMS=cpu``, after the
-cluster is down: reading a trace needs ``jax.profiler.ProfileData`` and
-the benchmark process itself never imports JAX. Everything but
-``read_xplane`` is plain Python over ``(name, start_ns, duration_ns)``
-tuples, and is what the tests check by hand-worked intervals.
+Run as a child of the benchmark with ``JAX_PLATFORMS=cpu`` (``--trace 2``
+reduces a capture while the engine still holds the chip): reading a trace
+needs ``jax.profiler.ProfileData`` and the benchmark process itself never
+imports JAX. Everything but ``read_xplane`` is plain Python over ``(name,
+start_ns, duration_ns)`` tuples, and is what the tests check by hand-worked
+intervals.
 
 What a v5e trace looks like (looked at by hand, PR 25; PERF.md section
 3): one plane per chip named ``/device:TPU:<n>``; on it the line ``XLA

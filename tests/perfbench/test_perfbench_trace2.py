@@ -13,6 +13,7 @@ import sys
 import pytest
 
 from perfbench import loadgen
+from perfbench.cluster import BenchFailure
 
 PB = os.path.dirname(os.path.abspath(loadgen.__file__))
 ROOT = os.path.dirname(PB)
@@ -64,17 +65,40 @@ def test_programs_traced_is_a_count_that_may_be_zero():
 def test_prefill_device_time_is_of_the_largest_bucket_traced():
     read = reader("runner.prefill_device_ms_p50")
     events = [
-        ["jit__decode_impl", 0.0, 41.9e6], ["jit_prefill_1024", 5.0, 108e6],
+        ["jit__decode_impl", 1.0, 41.9e6], ["jit_prefill_1024", 5.0, 108e6],
         ["jit_prefill_2048", 9.0, 240e6], ["jit_prefill_2048", 11.0, 230e6],
         ["jit_prefill_2048", 12.0, 236e6], ["jit_prefill_embeds_4096", 13.0, 9e9],
         ["jit__insert_impl", 14.0, 1e6],
     ]
-    ctx = {"traces": [{"devices": [{"module_events": events}]}]}
+    ctx = {"traces": [{"devices": [{"module_events": events, "window_s": 20.0}]}]}
     assert read(ctx) == pytest.approx(236.0)
+    # the largest bucket the plan's requests reach, if the run says which
+    assert read({**ctx, "buckets": [1024, 2048, 4096]}) is None
+    # the engine ran a bucket the plan does not reach: no capture of this
+    # run would ever serve, and the run says so at the first
+    with pytest.raises(BenchFailure, match=r"\[1024, 2048\].*reach \[512, 1024\]"):
+        read({**ctx, "buckets": [512, 1024]})
     # the parent's trace names every prefill program jit__unknown
-    old = [["jit__unknown", 0.0, 236e6], ["jit__decode_impl", 1.0, 41.9e6]]
-    assert read({"traces": [{"devices": [{"module_events": old}]}]}) is None
+    old = [["jit__unknown", 1.0, 236e6], ["jit__decode_impl", 2.0, 41.9e6]]
+    assert read({"traces": [{"devices": [{"module_events": old, "window_s": 1.0}]}]}) is None
     assert read({"traces": []}) is None and read({}) is None
+
+
+def test_prefill_device_time_leaves_out_a_program_the_trace_cuts():
+    """A trace begins and ends inside a program (the chip is never idle
+    under load): PR 27's runs read 208.18, 83.35 and 0.01 ms where the
+    whole program takes 268.1."""
+    read = reader("runner.prefill_device_ms_p50")
+    events = [
+        ["jit_prefill_2048", -0.0, 264.2e6], ["jit__decode_impl", 264.3e6, 24.1e6],
+        ["jit_prefill_2048", 288.4e6, 268.1e6], ["jit__decode_impl", 556.5e6, 24.1e6],
+        ["jit_prefill_2048", 580.6e6, 100.0e6],
+    ]
+    device = {"module_events": events, "window_s": 0.6806}
+    assert read({"traces": [{"devices": [device]}]}) == pytest.approx(268.1)
+    # nothing whole: nothing to read
+    cut = {"module_events": [events[0][:2] + [680.6e6]], "window_s": 0.6806}
+    assert read({"traces": [{"devices": [cut]}]}) is None
 
 
 def test_every_new_metric_has_its_reader_and_its_cells():
@@ -229,6 +253,22 @@ def test_rehearsal_of_trace_2_prints_the_contracts_last_line(which, tmp_path):
     assert counts["profiles"] == [16]
     phases = [l.get("phase") for l in log]
     assert phases.index("profiler_first_start") < phases.index("window")
+    # every per-layer metric the cell declares went through its reader:
+    # it either found something in this run's records (a CPU run prints
+    # no number, the log names the metric) or the log says it was left out
+    declared = {
+        m["name"] for m in bench["per_layer"]
+        if "workloads" not in m or cell["name"] in m["workloads"]
+    }
+    read = set(next(l for l in log if l.get("phase") == "metrics_read")["names"])
+    left_out = {l["name"] for l in log if l.get("phase") == "metric_left_out"}
+    assert read | left_out == declared and not read & left_out
+    # what needs the chip's plane of the trace, and nothing else
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    assert {n for n in declared if by_name[n]["source"] == "device_trace"} <= left_out
+    assert all(by_name[n]["source"] == "device_trace" or n.startswith("device.") for n in left_out)
+    # a CPU run's one capture stands (its trace has no chip's plane to judge)
+    assert "capture_retaken" not in phases
     window = next(l for l in log if l.get("phase") == "window")
     assert window["seconds"] == 3.0           # whole, not cut at the capture
     assert last["attempted"] == window["attempted"] >= last["completed"] >= 1

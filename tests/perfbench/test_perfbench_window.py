@@ -99,6 +99,9 @@ def test_spread_is_the_quartile_distance_over_the_median():
     assert sp.spread(values) == pytest.approx((115.5 - 100.75) / 102.5)
     assert sp.trimmed(values) == [100.0, 101.0, 102.0, 103.0, 104.0]
     assert sp.spread(sp.trimmed(values)) == pytest.approx(3.0 / 102.0)
+    # a count that is 0 in every run, and one that is 0 in most
+    assert sp.spread([0.0] * 6) == 0.0
+    assert sp.spread([0.0, 0.0, 0.0, 0.0, 3.0, 4.0]) == float("inf")
 
 
 def test_spread_reads_the_sets_a_run_left(tmp_path, capsys):
