@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -69,11 +70,19 @@ class KVCache:
     absolute token indices, so writing at ``positions`` and masking with
     ``cache_index <= query_position`` is all the bookkeeping attention needs.
 
-    Bounds contract: writes use ``dynamic_update_slice``, which CLAMPS
-    out-of-range starts instead of failing (static-shape jit semantics) —
-    writing at ``position >= max_len`` silently corrupts the tail of the
-    cache. Callers (the engine slot allocator) must enforce
-    ``position + T <= max_len`` before dispatching a step.
+    A step writes only its own rows: ``forward`` carries ``k`` and ``v``
+    whole through its scan over the layers and each layer scatters the
+    step's ``[B, T, H_kv, head_dim]`` rows to ``(layer, row,
+    start..start+T-1)``, ``start = positions[row, 0]`` (positions are
+    contiguous per row; ``_write_rows``). Donated to the jitted step, the
+    cache is updated in place; nothing else of it moves.
+
+    Bounds contract: a start with ``start + T > max_len`` is CLAMPED to
+    ``max_len - T`` instead of failing (static-shape jit semantics, what
+    ``dynamic_update_slice`` did before the scatter) — the block lands on
+    the tail of that row's own slot and silently corrupts it. Callers (the
+    engine slot allocator) must enforce ``position + T <= max_len`` before
+    dispatching a step.
     """
 
     k: jax.Array
@@ -89,12 +98,61 @@ class KVCache:
     ) -> "KVCache":
         if dtype is None:
             # follow the model's compute dtype: K/V written by forward
-            # must match the buffer (dynamic_update_slice is dtype-strict)
+            # must match the buffer (the row write is dtype-strict)
             dtype = (
                 jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
             )
         shape = (cfg.num_layers, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
         return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+
+
+_ROW_WRITE = lax.ScatterDimensionNumbers(
+    update_window_dims=(1, 2, 3),         # [T, H_kv, head_dim] a row
+    inserted_window_dims=(0,),            # one layer
+    scatter_dims_to_operand_dims=(0, 2),  # index = (layer, start)
+    operand_batching_dims=(1,),           # row b of the cache takes
+    scatter_indices_batching_dims=(0,),   # row b of the step
+)
+
+
+def _write_rows(
+    buf: jax.Array,      # [L, B, S_max, H_kv, head_dim], one of a KVCache
+    rows: jax.Array,     # [B, T, H_kv, head_dim], this step's K or V
+    layer: jax.Array,    # int32 scalar
+    start: jax.Array,    # [B] int32, each row's first position
+    by_position: bool = False,
+) -> jax.Array:
+    """``buf`` with ``rows[b]`` at ``[layer, b, start[b]:start[b]+T]``
+    (the bounds contract is ``KVCache``'s).
+
+    A scatter of ``B`` blocks: only the step's rows move. ``by_position``
+    gives the same result for a cache sharded over its positions (``sp``),
+    where GSPMD cannot place a block of ``T > 1`` at an offset it does not
+    know without gathering all of ``buf``, whatever sharding the carry
+    and the rows are pinned to (``with_sharding_constraint`` on either
+    compiles to the same all-gathers; PERF.md, PR 29): every position of
+    the layer takes its row of the step or keeps its own, one elementwise
+    pass over the layer that each shard makes over its own positions.
+    ``tests/ops/test_chip_compile.py`` holds both to a described 2x2.
+    """
+    T = rows.shape[1]
+    if by_position:
+        at = jnp.arange(buf.shape[2], dtype=jnp.int32)[None, :] - jnp.clip(
+            start, 0, buf.shape[2] - T
+        )[:, None]                                    # [B, S_max] into rows
+        new = jnp.take_along_axis(
+            rows, jnp.clip(at, 0, T - 1)[:, :, None, None], axis=1
+        )
+        old = lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
+        hit = ((at >= 0) & (at < T))[:, :, None, None]
+        return lax.dynamic_update_index_in_dim(
+            buf, jnp.where(hit, new, old), layer, 0
+        )
+    index = jnp.stack([jnp.broadcast_to(layer, start.shape), start], axis=1)
+    return lax.scatter(
+        buf, index, rows, _ROW_WRITE, unique_indices=True,
+        mode=lax.GatherScatterMode.CLIP,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -543,9 +601,11 @@ def forward(
     """Run the model.
 
     Without ``cache``: plain causal forward (training / scoring path).
-    With ``cache``: writes K/V at ``positions`` into the cache and attends
-    over the whole cache with an absolute-position causal mask. ``T > 1`` is
-    a prefill step, ``T == 1`` a decode step — same code path, different jit
+    With ``cache``: the cache rides the scan over the layers as its carry;
+    each layer writes the step's K/V rows into it at ``positions`` (only
+    those rows: ``KVCache``) and attends over its own layer of the whole
+    cache with an absolute-position causal mask. ``T > 1`` is a prefill
+    step, ``T == 1`` a decode step — same code path, different jit
     specialization.
 
     ``attn_impl`` selects the prefill attention kernel: ``"xla"`` (einsum
@@ -680,8 +740,9 @@ def forward(
         else lambda z: jax.nn.gelu(z, approximate=True)
     )
 
-    def block(x_in: jax.Array, scanned, moe_layer: bool):
-        lp, k_cache_l, v_cache_l, slide_flag = scanned
+    def block(carry, scanned, moe_layer: bool):
+        x_in, carried, layer = carry
+        lp, slide_flag = scanned
         if hetero:
             mask_l = jnp.where(slide_flag, mask_slide, mask_full)
             sin_b = jnp.where(slide_flag, sin_loc, sin)
@@ -772,15 +833,20 @@ def forward(
                 q, k, v, mask_l, scale, cfg.attn_logit_softcap,
                 sinks=sinks_l,
             )
-            new_k, new_v = k_cache_l, v_cache_l
         else:
-            # Write this step's K/V into the cache at each row's start
-            # position (positions are contiguous per row).
-            def write(buf, val, start):
-                return lax.dynamic_update_slice(buf, val, (start, 0, 0))
-
-            new_k = jax.vmap(write)(k_cache_l, k, positions[:, 0])
-            new_v = jax.vmap(write)(v_cache_l, v, positions[:, 0])
+            # Write this step's rows into the carried cache, in place,
+            # and attend over this layer of it.
+            write = partial(
+                _write_rows, layer=layer, start=positions[:, 0],
+                by_position=use_ring and T > 1,
+            )
+            carried = KVCache(
+                k=write(carried.k, k), v=write(carried.v, v)
+            )
+            new_k, new_v = (
+                lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
+                for buf in (carried.k, carried.v)
+            )
             if use_ring:
                 from gpustack_tpu.ops.ring_attention import (
                     sharded_prefill_attention,
@@ -883,55 +949,27 @@ def forward(
                 mlp, lp["post_mlp_norm"], cfg.rms_norm_eps,
                 cfg.norm_delta_gain,
             )
-        return x_mid + mlp, (new_k, new_v)
+        return (x_mid + mlp, carried, layer + 1), None
 
     # DeepSeek ships heterogeneous stacks: the first first_k_dense
     # layers use a dense MLP, the rest MoE — structurally different
-    # params can't share one lax.scan, so the stacks run back-to-back
-    # over split slices of the same cache.
+    # params can't share one lax.scan, so the stacks run back-to-back,
+    # the second going on from the first's carry: the hidden state, the
+    # one cache (None without one) and the layer index.
     kd = (
         len(next(iter(params["dense_layers"].values())))
         if "dense_layers" in params else 0
     )
-
-    def run_stack(x, stack, k_c, v_c, flags, moe_layer):
-        from functools import partial as _partial
-
-        return lax.scan(
-            _partial(block, moe_layer=moe_layer),
-            x, (stack, k_c, v_c, flags),
+    carry = (x, cache, jnp.int32(0))
+    if kd:
+        carry, _ = lax.scan(
+            partial(block, moe_layer=False),
+            carry, (params["dense_layers"], slide_flags[:kd]),
         )
-
-    if cache is None:
-        L = cfg.num_layers
-        def dummy(n):
-            return jnp.zeros(
-                (n, B, 0, cfg.num_kv_heads, cfg.head_dim), dtype
-            )
-        if kd:
-            x, _ = run_stack(
-                x, params["dense_layers"], dummy(kd), dummy(kd),
-                slide_flags[:kd], False,
-            )
-        x, _ = run_stack(
-            x, params["layers"], dummy(L - kd), dummy(L - kd),
-            slide_flags[kd:], cfg.is_moe,
-        )
-        new_cache = None
-    else:
-        if kd:
-            x, (k_d, v_d) = run_stack(
-                x, params["dense_layers"], cache.k[:kd], cache.v[:kd],
-                slide_flags[:kd], False,
-            )
-        x, (k_new, v_new) = run_stack(
-            x, params["layers"], cache.k[kd:], cache.v[kd:],
-            slide_flags[kd:], cfg.is_moe,
-        )
-        if kd:
-            k_new = jnp.concatenate([k_d, k_new], axis=0)
-            v_new = jnp.concatenate([v_d, v_new], axis=0)
-        new_cache = KVCache(k=k_new, v=v_new)
+    (x, new_cache, _), _ = lax.scan(
+        partial(block, moe_layer=cfg.is_moe),
+        carry, (params["layers"], slide_flags[kd:]),
+    )
 
     x = rms_norm(
         x, params["final_norm"], cfg.rms_norm_eps, cfg.norm_delta_gain

@@ -241,3 +241,162 @@ def test_prefill_flash_matches_xla(tiny):
     np.testing.assert_array_equal(
         np.asarray(cache_fl.k[0]), np.asarray(cache_xla.k[0])
     )
+
+
+# ---------------------------------------------------------------------------
+# The cache rides the layer scan as its carry; a step writes only its rows
+# ---------------------------------------------------------------------------
+
+_TINY, _TINY_MOE = get_config("tiny"), get_config("tiny-moe")
+_CARRY_PRESETS = {
+    "dense": _TINY,
+    "moe": _TINY_MOE,
+    # leading dense layers: two scans over one carried cache
+    "two-stack": dataclasses.replace(
+        _TINY_MOE, num_layers=3, first_k_dense=1
+    ),
+    "sliding-per-layer": dataclasses.replace(
+        _TINY, num_layers=4, sliding_window=3,
+        layer_sliding=(True, False, True, False),
+    ),
+}
+
+
+def _loop_forward(params, cfg, toks):
+    """The whole causal forward of ``toks`` [B, n] as a plain Python loop
+    over the layers (no scan, no cache): ``(logits [B, n, V], k, v
+    [L, B, n, H_kv, hd])``, from the module's per-layer pieces."""
+    from gpustack_tpu.models.transformer import (
+        _attend, _moe_mlp, apply_rope, rms_norm, rope_params, rope_sin_cos,
+    )
+
+    B, n = toks.shape
+    pos = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), (B, n))
+    sin, cos = rope_sin_cos(pos, rope_params(cfg)[0])
+    causal = pos[:, :, None] >= pos[:, None, :]
+    near = (pos[:, :, None] - pos[:, None, :]) < (cfg.sliding_window or n)
+    x = params["embed"][toks]
+    stacks = [(params["layers"], cfg.is_moe)]
+    if "dense_layers" in params:
+        stacks.insert(0, (params["dense_layers"], False))
+    ks, vs = [], []
+    for stack, moe in stacks:
+        for i in range(len(stack["attn_norm"])):
+            lp = jax.tree.map(lambda a: a[i], stack)
+            slides = (
+                cfg.layer_sliding[len(ks)] if cfg.layer_sliding
+                else bool(cfg.sliding_window)
+            )
+            h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+            q = (h @ lp["wq"]).reshape(B, n, cfg.num_heads, cfg.head_dim)
+            k = (h @ lp["wk"]).reshape(B, n, cfg.num_kv_heads, cfg.head_dim)
+            v = (h @ lp["wv"]).reshape(B, n, cfg.num_kv_heads, cfg.head_dim)
+            q = apply_rope(q, sin, cos).reshape(
+                B, n, cfg.num_kv_heads, cfg.group_size, cfg.head_dim
+            )
+            k = apply_rope(k, sin, cos)
+            attn = _attend(
+                q, k, v, causal & near if slides else causal,
+                cfg.head_dim ** -0.5,
+            )
+            x = x + attn @ lp["wo"]
+            h2 = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+            if moe:
+                x = x + _moe_mlp(
+                    h2, lp["router"], lp["we_gate"], lp["we_up"],
+                    lp["we_down"], cfg,
+                )
+            else:
+                x = x + (
+                    jax.nn.silu(h2 @ lp["w_gate"]) * (h2 @ lp["w_up"])
+                ) @ lp["w_down"]
+            ks.append(k)
+            vs.append(v)
+    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    return x @ params["lm_head"], jnp.stack(ks), jnp.stack(vs)
+
+
+@pytest.mark.parametrize("T", [1, 3], ids=["decode", "block"])
+@pytest.mark.parametrize("preset", list(_CARRY_PRESETS))
+def test_a_step_writes_only_its_rows_into_the_carried_cache(preset, T):
+    cfg = dataclasses.replace(_CARRY_PRESETS[preset], dtype="float32")
+    params = init_params(cfg, jax.random.key(0), dtype=jnp.float32)
+    B, S, n = 3, 16, 13
+    lens = np.array([5, 9, 2])                   # ragged contexts
+    toks = _tokens(cfg, B, n)
+    ref_logits, ref_k, ref_v = _loop_forward(params, cfg, toks)
+
+    # a cache of junk, each row's context in place below its position
+    shape = (cfg.num_layers, B, S, cfg.num_kv_heads, cfg.head_dim)
+    old_k = np.array(jax.random.normal(jax.random.key(7), shape))
+    old_v = np.array(jax.random.normal(jax.random.key(8), shape))
+    written = np.zeros(shape, bool)
+    for b, m in enumerate(lens):
+        old_k[:, b, :m] = ref_k[:, b, :m]
+        old_v[:, b, :m] = ref_v[:, b, :m]
+        written[:, b, m:m + T] = True
+    step = np.stack([np.arange(m, m + T) for m in lens]).astype(np.int32)
+    step_toks = jnp.take_along_axis(toks, jnp.asarray(step), axis=1)
+
+    def run(k, v):
+        return forward(
+            params, cfg, step_toks, jnp.asarray(step), KVCache(k=k, v=v)
+        )
+
+    logits, new = jax.jit(run)(jnp.asarray(old_k), jnp.asarray(old_v))
+    for got, old, ref in (
+        (np.asarray(new.k), old_k, ref_k), (np.asarray(new.v), old_v, ref_v)
+    ):
+        # every other row of the cache is bit-identical to before
+        np.testing.assert_array_equal(got[~written], old[~written])
+        for b, m in enumerate(lens):
+            np.testing.assert_allclose(
+                got[:, b, m:m + T], ref[:, b, m:m + T], rtol=1e-5, atol=1e-5
+            )
+    for b, m in enumerate(lens):
+        np.testing.assert_allclose(
+            logits[b], ref_logits[b, m:m + T], rtol=1e-4, atol=1e-4
+        )
+
+    # the cache passes through every scan as carry alone: nothing of its
+    # shape (or of a stack's share of it) among consts, xs or ys
+    def cache_like(var):
+        s = var.aval.shape
+        return len(s) == 5 and s[1:] == shape[1:]
+
+    scans = [
+        e for e in jax.make_jaxpr(run)(old_k, old_v).eqns
+        if e.primitive.name == "scan"
+    ]
+    assert len(scans) == (2 if preset == "two-stack" else 1)
+    for e in scans:
+        nc, ncar = e.params["num_consts"], e.params["num_carry"]
+        carry_in = e.invars[nc:nc + ncar]
+        assert [v.aval.shape for v in carry_in if cache_like(v)] == [shape] * 2
+        outside = (
+            e.invars[:nc] + e.invars[nc + ncar:] + e.outvars[ncar:]
+        )
+        assert not [v.aval.shape for v in outside if cache_like(v)]
+
+
+@pytest.mark.parametrize("T", [1, 3, 16], ids=["row", "block", "whole"])
+def test_row_write_by_position_equals_the_scatter(T):
+    """The write a position-sharded cache gets (``by_position``) is the
+    scatter's, bit for bit, a start past ``max_len - T`` clamped alike."""
+    from gpustack_tpu.models.transformer import _write_rows
+
+    L, B, S, H, D = 3, 4, 16, 2, 8
+    buf = jax.random.normal(jax.random.key(0), (L, B, S, H, D), jnp.bfloat16)
+    rows = jax.random.normal(jax.random.key(1), (B, T, H, D), jnp.bfloat16)
+    start = jnp.asarray([0, 5, S - T, S - 1], jnp.int32)   # last: clamped
+    got = [
+        np.asarray(_write_rows(
+            buf, rows, jnp.int32(1), start, by_position=by_position
+        ).astype(jnp.float32))
+        for by_position in (False, True)
+    ]
+    np.testing.assert_array_equal(got[0], got[1])
+    want = np.array(buf.astype(jnp.float32))
+    for b, s0 in enumerate(np.minimum(np.asarray(start), S - T)):
+        want[1, b, s0:s0 + T] = np.asarray(rows[b].astype(jnp.float32))
+    np.testing.assert_array_equal(got[0], want)

@@ -17,6 +17,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -92,15 +93,15 @@ def test_flash_prefill_compiles_for_v5e(one_chip, T, S):
     assert mem.temp_size_in_bytes < QWEN3_8B.num_heads * T * S * 4 / 4
 
 
-def test_int8_decode_layer_compiles_for_v5e(one_chip):
-    """One int8 decode step over a single Qwen3-8B-wide layer, at the
-    smoke deployment's batch (8 slots x 2048)."""
+MAX_LEN = 2048
+
+
+def _int8_step_compiled(one_chip, cfg, slots: int, T: int):
+    """``forward`` over int8 weights and a donated ``slots`` x ``MAX_LEN``
+    cache, ``T`` tokens a slot, compiled from shapes alone."""
     from gpustack_tpu.models import init_params
     from gpustack_tpu.models.quant import quantize_params
     from gpustack_tpu.models.transformer import KVCache, forward
-
-    cfg = dataclasses.replace(QWEN3_8B, num_layers=1)
-    slots, max_len = 8, 2048
 
     def on_chip(tree):
         return jax.tree_util.tree_map(
@@ -114,17 +115,137 @@ def test_int8_decode_layer_compiles_for_v5e(one_chip):
         lambda: quantize_params(init_params(cfg, jax.random.key(0)))
     ))
     cache = on_chip(jax.eval_shape(
-        lambda: KVCache.create(cfg, slots, max_len)
+        lambda: KVCache.create(cfg, slots, MAX_LEN)
     ))
-    tokens = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    tokens = jax.ShapeDtypeStruct((slots, T), jnp.int32, sharding=one_chip)
 
     def step(params, tokens, positions, cache):
         return forward(params, cfg, tokens, positions, cache)
 
+    return jax.jit(step, donate_argnums=(3,)).lower(
+        params, tokens, tokens, cache
+    ).compile()
+
+
+@pytest.mark.parametrize(
+    "preset,layers,slots,T",
+    [
+        ("qwen3-8b", 4, 12, 1),        # the 8B deployment's decode step
+        ("qwen3-30b-a3b", 2, 32, 1),   # the MoE deployment's
+        ("qwen3-8b", 4, 12, 4),        # the verify shape, B x T
+    ],
+)
+def test_a_step_moves_only_its_rows_of_the_donated_cache(
+    one_chip, preset, layers, slots, T
+):
+    """The cache is the layer scan's carry: with it donated, a step over
+    int8 weights at published widths updates it in place. As ``xs`` in
+    and ``ys`` out it cost a second cache of temporaries, two copies of
+    the whole cache and each layer's slab written back whole (PERF.md,
+    PR 29); this keeps them from coming back with a JAX upgrade."""
+    import re
+
+    cfg = dataclasses.replace(get_config(preset), num_layers=layers)
+    compiled = _int8_step_compiled(one_chip, cfg, slots, T)
+    slab = slots * MAX_LEN * cfg.num_kv_heads * cfg.head_dim
+    cache_bytes = 2 * layers * slab * 2          # k and v, bf16
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.05 * cache_bytes
+    assert mem.alias_size_in_bytes >= cache_bytes
+
+    # every instruction's element count, by name; then no copy of the
+    # whole cache, and no dynamic-update-slice that writes a layer's
+    # whole slab or more. (The slab a layer attends over is still
+    # sliced out of the carry and relaid out inside the attention
+    # fusion, as before: that copy is not the cache's.)
+    text = compiled.as_text()
+    size = {
+        name: int(np.prod([int(d) for d in dims.split(",") if d]))
+        for name, dims in re.findall(
+            r"%([\w.-]+) = \w+\[([\d,]*)\]", text
+        )
+    }
+    whole = f"bf16[{layers},{slots},{MAX_LEN},"
+    assert not re.findall(rf"= {re.escape(whole)}[^ ]* copy\(", text)
+    updates = re.findall(
+        r"dynamic-update-slice\(%[\w.-]+, %([\w.-]+),", text
+    )
+    assert updates and max(size[u] for u in updates) < slab, updates
+
+
+@pytest.mark.parametrize("T", [1, 4])
+def test_a_position_sharded_cache_takes_its_rows_without_a_gather(topo, T):
+    """``sp`` = 4 over the described 2x2: the cache is sharded over its
+    positions and a step's rows land at offsets the partitioner does not
+    know. One row a slot (``T`` = 1) it places shard by shard. A block of
+    ``T`` = 4 it can only place in a gathered copy of the whole carry,
+    every layer, whatever the carry is constrained to, so ``forward``
+    writes it by position there (``_write_rows``): this fails with an
+    all-gather of ``bf16[4,8,2048,8,128]`` when that goes."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from gpustack_tpu.models import init_params
+    from gpustack_tpu.models.quant import quant_pspecs, quantize_params
+    from gpustack_tpu.models.transformer import KVCache, forward
+    from gpustack_tpu.parallel.mesh import MeshPlan, make_mesh
+    from gpustack_tpu.parallel.sharding import SpecLayout, param_pspecs
+
+    cfg = dataclasses.replace(QWEN3_8B, num_layers=4)
+    slots = 8
+    mesh = make_mesh(MeshPlan(sp=4), topo.devices)
+
+    def placed(tree, specs):
+        return jax.tree_util.tree_map(
+            lambda x, spec: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(mesh, spec)
+            ),
+            tree, specs,
+        )
+
+    shapes = jax.eval_shape(
+        lambda: quantize_params(init_params(cfg, jax.random.key(0)))
+    )
+    params = placed(
+        shapes, quant_pspecs(param_pspecs(shapes, train=False), shapes)
+    )
+    cache = jax.eval_shape(lambda: KVCache.create(cfg, slots, MAX_LEN))
+    on_positions = SpecLayout(long_context=True).cache()
+    cache = placed(cache, KVCache(on_positions, on_positions))
+    tokens = jax.ShapeDtypeStruct(
+        (slots, T), jnp.int32,
+        sharding=NamedSharding(mesh, PartitionSpec()),
+    )
+
+    def step(params, tokens, positions, cache):
+        return forward(
+            params, cfg, tokens, positions, cache,
+            attn_impl="ring", mesh=mesh,
+        )
+
     compiled = jax.jit(step, donate_argnums=(3,)).lower(
         params, tokens, tokens, cache
     ).compile()
-    mem = compiled.memory_analysis()
+    slab = slots * MAX_LEN * cfg.num_kv_heads * cfg.head_dim
+    gathered = [
+        int(np.prod([int(d) for d in dims.split(",")]))
+        for dims in re.findall(
+            r"= \w+\[([\d,]+)\][^ ]* all-gather(?:-start)?\(",
+            compiled.as_text(),
+        )
+    ]
+    assert all(n < slab for n in gathered), gathered
+    assert compiled.memory_analysis().alias_size_in_bytes >= (
+        2 * cfg.num_layers * slab * 2 // 4      # a shard of k and of v
+    )
+
+
+def test_int8_decode_layer_compiles_for_v5e(one_chip):
+    """One int8 decode step over a single Qwen3-8B-wide layer, at the
+    smoke deployment's batch (8 slots x 2048)."""
+    cfg = dataclasses.replace(QWEN3_8B, num_layers=1)
+    mem = _int8_step_compiled(one_chip, cfg, 8, 1).memory_analysis()
     # one layer's int8 weights + embed/lm_head + this cache: well under
     # a gigabyte and a half of arguments, and no bf16 copy of a weight
     # among the temporaries
