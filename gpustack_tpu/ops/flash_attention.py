@@ -1,28 +1,75 @@
 """Pallas TPU flash-attention kernel for causal prefill.
 
-Blocked online-softmax attention: the grid walks (batch, q-head, q-block,
-k-block) with the k-block axis innermost; running max/sum/accumulator live
-in VMEM scratch that persists across the k sweep, so the [T, S] score
-matrix never exists in HBM and VMEM use is O(BLOCK_Q x BLOCK_K) regardless
-of sequence length — a 32k prefill fits as easily as a 1k one (the XLA
-path materializes a [B, H, T, S] fp32 score tensor: 128 GiB at 32k for an
-8B model; reference long-context profile:
+Blocked online-softmax attention. The grid walks (batch, kv-head,
+q-block, k-block) with the k-block axis innermost. One grid point holds
+the ``block_q`` query rows of **all** ``G`` query heads of one GQA group
+(``G * block_q`` rows) against one ``block_k``-row block of that group's
+K and V, so a K/V block is fetched once for the whole group and the grid
+has tens of points a layer, not thousands (at 2,048 tokens, 32 / 8
+heads: 64-128 against the 8,192 of the one-head, 128 x 128 grid this
+replaced; a grid point costs about 0.3 us whatever it does).
+
+Inside a point the fetched block is walked in 128-row sub-blocks, in
+ascending order, and the q rows in sub-blocks of ``sub_q``: the running
+max / sum / accumulator (VMEM scratch that persists across the k sweep)
+are updated once per 128 keys, exactly as on the old grid, so every
+float32 sum keeps its order and the result is the old kernel's element
+for element (tests/ops/test_flash_attention.py holds the old kernel as
+the oracle; hack/flash_bench.py counts the same on the chip). The
+mathematics is as it was: q * scale, QK^T, the softmax and PV written in
+float32 at the default precision (at which the chip's MXU takes a
+float32 operand in one bf16 pass: explicit bf16 feeds differ nowhere,
+PERF.md, PR 32).
+
+Two things about the body decide its time on the chip, both measured
+(PERF.md, PR 32: 2.53 ms a layer at 2,048 tokens before, 0.43 after):
+
+- the running max and sum live broadcast over 128 lanes, in scratch and
+  in every operation on them. As [rows, 1] columns they cost a vector
+  register for every eight rows, as much as the scores themselves, plus
+  a relayout each way: the kernel with column statistics took 1.12 ms,
+  the same kernel with them broadcast 0.43, which is the two products'
+  own time;
+- ``unroll`` sub-blocks of an interior stretch sit in one basic block,
+  so the scheduler can put one sub-block's products beside another's
+  softmax (a loop with run-time bounds gives it one sub-block at a
+  time), and one matmul holds about 1,024 rows (``G * sub_q``).
+
+Causal work is bounded three ways:
+
+- a (q sub-block, k sub-block) pair wholly above the diagonal is never
+  computed: the sweeps over a block's sub-blocks are loops whose bounds
+  come from the run-time offset;
+- a k-block wholly above a q-block's diagonal is never fetched: the
+  offset is a scalar-prefetch operand, the K/V index maps clamp the
+  block index to the last block the q-block needs, and a point that
+  names the block already resident issues no copy;
+- the iotas, compares and selects of the mask run only on sub-blocks
+  that straddle the diagonal or hold the ``seq_k`` tail; on an interior
+  sub-block the mask is all true and ``where(mask, s, _NEG)`` the
+  identity.
+
+The [T, S] score matrix never exists in HBM and VMEM use is
+O(G * block_q x 128) regardless of sequence length, so a 32k prefill
+fits as easily as a 1k one (the XLA path materializes a [B, H, T, S]
+fp32 score tensor: 128 GiB at 32k for an 8B model; reference
+long-context profile:
 gpustack/assets/profiles_config/profiles_config.yaml:29-38).
 
-Fully-masked k-blocks above the causal diagonal are skipped with
-``pl.when`` — the sweep does ~half the work of a dense scan.
+The block sizes follow the shapes (:func:`choose_tiles`): no knob.
 
 Engine wiring: ``models/transformer.forward(attn_impl="flash")`` uses this
 for prefill steps; the engine enables it per prefill bucket
 (engine/runner.py attn_impl_for). Verified bit-close against the XLA
-reference in interpret mode (tests/ops/test_flash_attention.py) and
-compiled for a described v5e at Qwen3-8B widths
-(tests/ops/test_chip_compile.py).
+reference and bit-equal to the old kernel in interpret mode
+(tests/ops/test_flash_attention.py), and compiled for a described v5e at
+Qwen3-8B and Qwen3-30B-A3B widths (tests/ops/test_chip_compile.py).
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -32,25 +79,107 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-BLOCK_Q = 128
-BLOCK_K = 128
-# scratch lane width: TPU vector registers are (8, 128); the running
-# max/sum are stored broadcast across one 128-lane tile
+# keys per online-softmax update: fixed, it is the order of summation
+SUB_K = 128
+# lane width: TPU vector registers are (8, 128); the running max/sum are
+# kept broadcast across one 128-lane tile
 _LANES = 128
 _NEG = -1e30
+# what a grid point may hold of the 16 MiB of VMEM a kernel is given by
+# default on a v5e (blocks double-buffered, scratch, float32 temporaries)
+_VMEM_BUDGET = 10 * 1024 * 1024
+# rows of one matmul inside a grid point (all G heads of ``sub_q`` rows)
+# and sub-blocks of an interior stretch in one basic block: the chip's
+# sweep (PERF.md, PR 32) has 512 rows 18 % and no unrolling 16 % slower
+_MATMUL_ROWS = 1024
+_UNROLL = 4
+
+
+class Tiles(NamedTuple):
+    block_q: int    # query rows (per head) of a grid point: chosen
+    sub_q: int      # query rows (per head) of one matmul inside it: follows
+    block_k: int    # K/V rows fetched for a grid point: chosen
+    unroll: int     # 128-key sub-blocks of it in one basic block: follows
+
+
+def tiles_of(block_q: int, block_k: int, G: int) -> Tiles:
+    """The tile of a pair of block sizes: the one place a tile is made.
+    The block sizes are the choice; the inner shape follows from them, the
+    group's size and the two constants above. ``sub_q`` is the largest of
+    512 / 256 / 128 that holds at most ``_MATMUL_ROWS`` rows over the
+    ``G`` heads and divides ``block_q``: the kernel walks ``block_q //
+    sub_q`` sub-blocks, so one that does not divide would leave a
+    q-block's last rows unwritten (any ``G``: 3, 5, 6, 7 too)."""
+    if block_q % SUB_K or block_k % SUB_K:
+        raise ValueError(
+            f"block sizes ({block_q}, {block_k}) must be multiples of "
+            f"{SUB_K}"
+        )
+    most = max(SUB_K, _MATMUL_ROWS // G)
+    sub_q = next(
+        s for s in (512, 256, 128) if s <= most and block_q % s == 0
+    )
+    return Tiles(block_q, sub_q, block_k, min(_UNROLL, block_k // SUB_K))
+
+
+def _vmem_bytes(tiles: Tiles, G: int, d: int, itemsize: int) -> int:
+    """What a grid point holds, roughly: the chip's compiler has the last
+    word (tests/ops/test_chip_compile.py at the cells' shapes)."""
+    rows, sub_rows = G * tiles.block_q, G * tiles.sub_q
+    blocks = 2 * (2 * rows * d + 2 * tiles.block_k * d) * itemsize
+    scratch = rows * (2 * _LANES + d) * 4
+    # q, two score-sized tiles, the product, and a sub-block of k and v
+    temps = sub_rows * (2 * d + 2 * SUB_K) * 4 + 2 * SUB_K * d * 4
+    return blocks + scratch + temps
+
+
+def candidate_tiles(T_pad: int, S_pad: int, G: int) -> list[Tiles]:
+    """The tiles that divide the padded lengths, largest first; the last
+    is always 128 x 128."""
+    return [
+        tiles_of(block_q, block_k, G)
+        for block_q in (512, 256, 128) if T_pad % block_q == 0
+        for block_k in (512, 256, 128) if S_pad % block_k == 0
+    ]
+
+
+def choose_tiles(
+    T_pad: int, S_pad: int, G: int, d: int, itemsize: int
+) -> Tiles:
+    """The largest tiles of a short list that divide the padded lengths
+    and fit VMEM; 128 x 128 where nothing larger does."""
+    candidates = candidate_tiles(T_pad, S_pad, G)
+    return next(
+        (
+            tiles for tiles in candidates
+            if _vmem_bytes(tiles, G, d, itemsize) <= _VMEM_BUDGET
+        ),
+        candidates[-1],
+    )
+
+
+def _across(x, d: int):
+    """``x`` [rows, 128], every lane of a row alike, as [rows, d]."""
+    return jnp.tile(x, (1, -(-d // _LANES)))[:, :d]
 
 
 def _flash_kernel(
     off_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-    *, scale: float, seq_k: int, n_kb: int,
+    *, scale: float, seq_k: int, tiles: Tiles,
 ):
-    """Grid point = one (batch, q-head, q-block, k-block) tile.
+    """Grid point = one (batch, kv-head, q-block, k-block) tile: the
+    ``block_q`` rows of the group's ``G`` query heads against ``block_k``
+    keys.
 
-    ``off_ref`` (SMEM scalar) is the absolute position of q row 0 —
+    ``off_ref`` (scalar prefetch) is the absolute position of q row 0 —
     zero for prefill-from-scratch; the prefix length for chunked-prefill
     continuation steps, whose queries sit at positions offset..offset+T-1
     against a cache of offset+T keys.
     """
+    block_q, sub_q, block_k, unroll = tiles
+    G, d = q_ref.shape[1], q_ref.shape[3]
+    rows = G * sub_q
+    n_sub_k = block_k // SUB_K
     qb = pl.program_id(2)
     kb = pl.program_id(3)
 
@@ -61,49 +190,152 @@ def _flash_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     off = off_ref[0]
-    q_start = qb * BLOCK_Q
-    k_start = kb * BLOCK_K
+    first_sub_k = kb * n_sub_k      # this block's first sub-block, of all
 
-    # causal: skip k-blocks entirely above the (offset) diagonal
-    @pl.when(k_start <= off + q_start + BLOCK_Q - 1)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale   # [BQ, d]
-        k = k_ref[0, 0].astype(jnp.float32)           # [BK, d]
-        v = v_ref[0, 0].astype(jnp.float32)
+    def update(qs, q_first, q, j, masked):
+        """One online-softmax update of q sub-block ``qs`` (row 0 at
+        position ``q_first``) against keys 128 j .. 128 j + 127 of the
+        fetched block. The running max and sum stay broadcast over 128
+        lanes all through (module docstring)."""
+        k_rows = pl.ds(pl.multiple_of(j * SUB_K, SUB_K), SUB_K)
+        k = k_ref[0, 0, k_rows, :].astype(jnp.float32)    # [128, d]
+        v = v_ref[0, 0, k_rows, :].astype(jnp.float32)
         s = lax.dot_general(
             q, k,
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                             # [BQ, BK]
-        q_idx = off + q_start + lax.broadcasted_iota(
-            jnp.int32, s.shape, 0
-        )
-        k_idx = k_start + lax.broadcasted_iota(
-            jnp.int32, s.shape, 1
-        )
-        mask = (k_idx <= q_idx) & (k_idx < seq_k)
-        s = jnp.where(mask, s, _NEG)
+        )                                                 # [rows, 128]
+        if masked:
+            q_idx = q_first + lax.rem(
+                lax.broadcasted_iota(jnp.int32, s.shape, 0), sub_q
+            )
+            k_idx = (first_sub_k + j) * SUB_K + lax.broadcasted_iota(
+                jnp.int32, s.shape, 1
+            )
+            s = jnp.where((k_idx <= q_idx) & (k_idx < seq_k), s, _NEG)
 
-        m_prev = m_ref[...][:, :1]                    # [BQ, 1]
-        l_prev = l_ref[...][:, :1]
+        m_prev, l_prev = m_ref[qs], l_ref[qs]             # [rows, 128]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.where(s <= _NEG / 2, 0.0, jnp.exp(s - m_new))
+        p = jnp.exp(s - m_new)
+        if masked:
+            p = jnp.where(s <= _NEG / 2, 0.0, p)
         corr = jnp.where(m_prev <= _NEG / 2, 0.0, jnp.exp(m_prev - m_new))
-        l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + lax.dot_general(
+        l_ref[qs] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[qs] = m_new
+        acc_ref[qs] = acc_ref[qs] * _across(corr, d) + lax.dot_general(
             p, v,
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(kb == n_kb - 1)
+    def sweep(qs, q_first, lo, hi, masked, unroll):
+        """Sub-blocks lo .. hi - 1 in ascending order, ``unroll`` of them
+        to a basic block and then the rest one by one."""
+        def some(first, n):
+            q = q_ref[0, :, pl.ds(qs * sub_q, sub_q), :].reshape(rows, d)
+            q = q.astype(jnp.float32) * scale             # [G*sub_q, d]
+            for t in range(n):
+                update(qs, q_first, q, first + t, masked)
+
+        def group(i, carry):
+            some(lo + i * unroll, unroll)
+            return carry
+
+        def single(j, carry):
+            some(j, 1)
+            return carry
+
+        if unroll > 1:
+            groups = (hi - lo) // unroll
+            lax.fori_loop(0, groups, group, 0)
+            lo = lo + groups * unroll
+        lax.fori_loop(lo, hi, single, 0)
+
+    for qs in range(block_q // sub_q):
+        q_first = off + qb * block_q + qs * sub_q    # position of row 0
+        # sub-blocks j (keys 128 j .. 128 j + 127), counted from key 0:
+        # those below n_seen hold a key some row sees; those below
+        # n_clear are seen whole by every row and lie inside seq_k, and
+        # need no mask
+        n_seen = (q_first + sub_q - 1) // SUB_K + 1
+        n_clear = jnp.minimum((q_first + 1) // SUB_K, seq_k // SUB_K)
+        clear = jnp.clip(n_clear - first_sub_k, 0, n_sub_k)
+        seen = jnp.clip(n_seen - first_sub_k, 0, n_sub_k)
+        sweep(qs, q_first, 0, clear, masked=False, unroll=unroll)
+        sweep(qs, q_first, clear, seen, masked=True, unroll=1)
+
+    @pl.when(kb == pl.num_programs(3) - 1)
     def _finish():
-        l = l_ref[...][:, :1]
-        o_ref[0, 0] = (
-            acc_ref[...] / jnp.maximum(l, 1e-30)
-        ).astype(o_ref.dtype)
+        for qs in range(block_q // sub_q):
+            o = acc_ref[qs] / _across(jnp.maximum(l_ref[qs], 1e-30), d)
+            o_ref[0, :, pl.ds(qs * sub_q, sub_q), :] = o.reshape(
+                G, sub_q, d
+            ).astype(o_ref.dtype)
+
+
+def flash_call(
+    qt: jax.Array,      # [B, Hq, T_pad, d], head-major, rows padded to 128
+    kt: jax.Array,      # [B, Hkv, S_pad, d]
+    vt: jax.Array,
+    off: jax.Array,     # int32[1]: the position of q row 0
+    *, scale: float, seq_k: int, interpret: bool = False,
+    _blocks: tuple[int, int] | None = None,
+) -> jax.Array:
+    """The ``pallas_call`` alone, on head-major operands whose rows are
+    already padded to multiples of 128: what :func:`flash_attention_prefill`
+    runs between its transposes, and what ``hack/flash_bench.py`` times.
+    ``_blocks`` (``block_q``, ``block_k``) is for that timer and the
+    tests, which go through every tile; nothing that serves passes it,
+    and the shapes choose (:func:`choose_tiles`). Returns
+    [B, Hq, T_pad, d] in q's dtype."""
+    B, Hq, T_pad, d = qt.shape
+    Hkv, S_pad = kt.shape[1], kt.shape[2]
+    G = Hq // Hkv
+    if _blocks is None:
+        tiles = choose_tiles(T_pad, S_pad, G, d, qt.dtype.itemsize)
+    else:
+        tiles = tiles_of(*_blocks, G)
+    block_q, sub_q, block_k, _ = tiles
+    if T_pad % block_q or S_pad % block_k or block_q % sub_q:
+        raise ValueError(f"{tiles} does not divide {T_pad} x {S_pad}")
+    n_qs, n_kb = block_q // sub_q, S_pad // block_k
+
+    def q_block(b, h, qb, kb, off_ref):
+        return (b, h, qb, 0)     # heads h*G .. h*G+G-1: one GQA group
+
+    def kv_block(b, h, qb, kb, off_ref):
+        # the last block that holds a key the q-block's last row sees: a
+        # point past it names that block again and nothing is copied
+        last = (off_ref[0] + (qb + 1) * block_q - 1) // block_k
+        return (b, h, jnp.minimum(kb, jnp.minimum(last, n_kb - 1)), 0)
+
+    return pl.pallas_call(
+        functools.partial(
+            _flash_kernel, scale=scale, seq_k=seq_k, tiles=tiles
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, T_pad, d), qt.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Hkv, T_pad // block_q, n_kb),
+            in_specs=[
+                pl.BlockSpec((1, G, block_q, d), q_block),
+                pl.BlockSpec((1, 1, block_k, d), kv_block),
+                pl.BlockSpec((1, 1, block_k, d), kv_block),
+            ],
+            out_specs=pl.BlockSpec((1, G, block_q, d), q_block),
+            scratch_shapes=[
+                pltpu.VMEM((n_qs, G * sub_q, _LANES), jnp.float32),  # max
+                pltpu.VMEM((n_qs, G * sub_q, _LANES), jnp.float32),  # sum
+                pltpu.VMEM((n_qs, G * sub_q, d), jnp.float32),       # acc
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(
+                "parallel", "parallel", "parallel", "arbitrary"
+            ),
+        ),
+        interpret=interpret,
+    )(off, qt, kt, vt)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -119,59 +351,30 @@ def flash_attention_prefill(
     against k positions 0..S-1, with keys at index >= S masked via
     ``seq_k``). ``q_offset`` (traced scalar) supports chunked-prefill
     continuation: every batch row shares the one offset. Returns
-    [B, T, Hq*d]. T and S are padded to block multiples internally; any
-    sequence length fits (VMEM use is O(block))."""
+    [B, T, Hq*d]. T and S are padded to multiples of 128 internally; any
+    sequence length fits (VMEM use is O(block)); the shapes choose the
+    tiles (:func:`choose_tiles`)."""
     B, T, Hq, d = q.shape
     S, Hkv = k.shape[1], k.shape[2]
     if Hq % Hkv != 0:
         raise ValueError(
             f"q heads ({Hq}) must be a multiple of kv heads ({Hkv})"
         )
-    G = Hq // Hkv
 
     # head-major layout for blocking; pad seq dims to block multiples
     qt = jnp.transpose(q, (0, 2, 1, 3))          # [B, Hq, T, d]
     kt = jnp.transpose(k, (0, 2, 1, 3))          # [B, Hkv, S, d]
     vt = jnp.transpose(v, (0, 2, 1, 3))
-    T_pad = -(-T // BLOCK_Q) * BLOCK_Q
-    S_pad = -(-S // BLOCK_K) * BLOCK_K
+    T_pad = -(-T // SUB_K) * SUB_K
+    S_pad = -(-S // SUB_K) * SUB_K
     qt = jnp.pad(qt, ((0, 0), (0, 0), (0, T_pad - T), (0, 0)))
     kt = jnp.pad(kt, ((0, 0), (0, 0), (0, S_pad - S), (0, 0)))
     vt = jnp.pad(vt, ((0, 0), (0, 0), (0, S_pad - S), (0, 0)))
 
-    n_kb = S_pad // BLOCK_K
-    grid = (B, Hq, T_pad // BLOCK_Q, n_kb)
-    off = jnp.asarray(q_offset, jnp.int32).reshape(1)
-    out = pl.pallas_call(
-        functools.partial(
-            _flash_kernel, scale=scale, seq_k=S, n_kb=n_kb
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, T_pad, d), q.dtype),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(
-                (1, 1, BLOCK_Q, d), lambda b, h, qb, kb: (b, h, qb, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, BLOCK_K, d),
-                lambda b, h, qb, kb, G=G: (b, h // G, kb, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, BLOCK_K, d),
-                lambda b, h, qb, kb, G=G: (b, h // G, kb, 0),
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, BLOCK_Q, d), lambda b, h, qb, kb: (b, h, qb, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((BLOCK_Q, _LANES), jnp.float32),   # running max
-            pltpu.VMEM((BLOCK_Q, _LANES), jnp.float32),   # running sum
-            pltpu.VMEM((BLOCK_Q, d), jnp.float32),        # accumulator
-        ],
-        interpret=interpret,
-    )(off, qt, kt, vt)
+    out = flash_call(
+        qt, kt, vt, jnp.asarray(q_offset, jnp.int32).reshape(1),
+        scale=scale, seq_k=S, interpret=interpret,
+    )
     out = jnp.transpose(out[:, :, :T, :], (0, 2, 1, 3))  # [B, T, Hq, d]
     return out.reshape(B, T, Hq * d)
 

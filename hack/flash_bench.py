@@ -1,0 +1,192 @@
+"""The flash prefill kernel alone, on the chip: the old tiling (one query
+head and 128 x 128 keys a grid point, kept in
+``tests/ops/test_flash_attention.py`` as the oracle) beside the new, at
+the shapes the benchmark's cells run and at a continuation.
+
+    python hack/flash_bench.py [--sweep] [--reps N]
+
+Each line: ms a call (the ``pallas_call`` alone on head-major operands,
+``reps`` calls chained inside one program so the host is out of it), the
+call's share of ``perfbench/roofline.py``'s floor, and the count of output
+elements that differ from the old kernel's (expected 0). ``--sweep`` times
+every pair of block sizes and every inner shape (rows of a matmul,
+sub-blocks unrolled: two constants of the kernel's module, which the sweep
+sets while it traces), not only what the shapes choose. One JSON line a
+measurement on stdout and in ``chiprun_out/flash_bench.jsonl``.
+Nothing a cell runs imports this file.
+"""
+
+from __future__ import annotations
+
+import argparse
+import importlib.util
+import json
+import pathlib
+import sys
+import time
+from unittest import mock
+
+ROOT = pathlib.Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(ROOT))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax import lax  # noqa: E402
+
+from gpustack_tpu.ops import flash_attention as fa  # noqa: E402
+from perfbench import roofline  # noqa: E402
+
+# (name, T, S, offset, q heads, kv heads, head width)
+SHAPES = [
+    ("8b-2048", 2048, 2048, 0, 32, 8, 128),
+    ("8b-1024", 1024, 1024, 0, 32, 8, 128),
+    ("moe-2048", 2048, 2048, 0, 32, 4, 128),
+    ("moe-1024", 1024, 1024, 0, 32, 4, 128),
+    ("8b-chunk", 512, 2048, 1536, 32, 8, 128),
+    ("moe-chunk", 512, 2048, 1536, 32, 4, 128),
+    ("g7-2048", 2048, 2048, 0, 28, 4, 128),     # Qwen2.5-7B: seven a group
+]
+SWEEP_Q = (128, 256, 512)
+SWEEP_K = (128, 256, 512, 1024)
+SWEEP_ROWS = (512, 1024, 2048)
+SWEEP_UNROLL = (1, 2, 4)
+
+
+def old_flash_call():
+    spec = importlib.util.spec_from_file_location(
+        "flash_oracle", ROOT / "tests/ops/test_flash_attention.py"
+    )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.old_flash_call
+
+
+def floor_seconds(T, S, off, Hq, Hkv, d, peaks) -> float:
+    """``flash_prefill_call``'s floor; for a continuation the rows of the
+    triangle below the offset are taken off its operations, and q and o
+    count T rows against k's and v's S."""
+    whole = roofline.flash_prefill_call(off + T, Hq, Hkv, d)
+    below = roofline.flash_prefill_call(off, Hq, Hkv, d)
+    bytes_ = 2.0 * d * (2 * T * Hq + 2 * S * Hkv)
+    return roofline.least_seconds(
+        whole["flops"] - below["flops"], bytes_, peaks
+    )["seconds"]
+
+
+def timed(call, q, k, v, off, reps: int):
+    """Seconds a call, ``reps`` calls chained in one program (each takes
+    the last one's output as its q: same shape, and the time does not
+    depend on the values), best of three; and one call's output."""
+    chain = jax.jit(lambda q, k, v, off: lax.fori_loop(
+        0, reps, lambda _, x: call(x, k, v, off), q
+    ))
+    out = jax.jit(call)(q, k, v, off)
+    jax.block_until_ready(chain(q, k, v, off))
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        jax.block_until_ready(chain(q, k, v, off))
+        best = min(best, (time.perf_counter() - t0) / reps)
+    return best, out
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--sweep", action="store_true")
+    ap.add_argument("--reps", type=int, default=50)
+    ap.add_argument("--shapes", default="")
+    ap.add_argument(
+        "--rehearse", action="store_true",
+        help="no chip: interpret mode, v5e's peaks; the counts mean "
+        "something, the times nothing",
+    )
+    args = ap.parse_args()
+
+    dev = jax.devices()[0]
+    peaks = json.loads((ROOT / "perfbench/peaks.json").read_text()).get(
+        "TPU v5 lite" if args.rehearse else dev.device_kind
+    )
+    if peaks is None:
+        print(json.dumps({"ok": False, "device": dev.device_kind,
+                          "why": "no peaks for this device: not a chip"}))
+        return 3
+    old_call = old_flash_call()
+    out_path = ROOT / "chiprun_out/flash_bench.jsonl"
+    out_path.parent.mkdir(exist_ok=True)
+    want = set(filter(None, args.shapes.split(",")))
+
+    def say(rec):
+        line = json.dumps(rec)
+        print(line, flush=True)
+        with out_path.open("a") as f:
+            f.write(line + "\n")
+
+    for name, T, S, off, Hq, Hkv, d in SHAPES:
+        if want and name not in want:
+            continue
+        G = Hq // Hkv
+        ks = jax.random.split(jax.random.key(T + S + Hkv), 3)
+        q = jax.random.normal(ks[0], (1, Hq, T, d), jnp.float32)
+        k = jax.random.normal(ks[1], (1, Hkv, S, d), jnp.float32)
+        v = jax.random.normal(ks[2], (1, Hkv, S, d), jnp.float32)
+        q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+        off_arr = jnp.full((1,), off, jnp.int32)
+        floor = floor_seconds(T, S, off, Hq, Hkv, d, peaks)
+        kw = dict(scale=d ** -0.5, seq_k=S, interpret=args.rehearse)
+
+        def report(which, tiles, call, old_out=None):
+            rec = {"shape": name, "kernel": which, "tiles": tiles}
+            try:
+                sec, out = timed(call, q, k, v, off_arr, args.reps)
+            except Exception as e:   # a tile the chip's VMEM refuses
+                rec["refused"] = str(e).splitlines()[0][:160]
+                say(rec)
+                return None
+            rec["ms"] = sec * 1e3
+            rec["roofline_pct"] = 100.0 * floor / sec
+            if old_out is not None:
+                rec["differing"] = int(jnp.sum(
+                    out.astype(jnp.float32) != old_out.astype(jnp.float32)
+                ))
+            say(rec)
+            return out
+
+        old_out = report(
+            "old", [128, 128, 128, 1],
+            lambda q, k, v, o: old_call(q, k, v, o, **kw),
+        )
+        chosen = fa.choose_tiles(T, S, G, d, q.dtype.itemsize)
+        inner = (fa._MATMUL_ROWS, fa._UNROLL)
+        # (block_q, block_k, rows of a matmul, sub-blocks unrolled)
+        todo = [(chosen.block_q, chosen.block_k, *inner)]
+        if args.sweep:
+            # block sizes with the module's inner shape, then the inner
+            # shape at the chosen blocks
+            todo += [
+                (bq, bk, *inner)
+                for bq in SWEEP_Q if T % bq == 0
+                for bk in SWEEP_K if S % bk == 0
+            ] + [
+                (chosen.block_q, chosen.block_k, rows, unroll)
+                for rows in SWEEP_ROWS for unroll in SWEEP_UNROLL
+            ]
+            todo = list(dict.fromkeys(todo))
+        for bq, bk, rows, unroll in todo:
+            def call(q, k, v, o):
+                with mock.patch.multiple(
+                    fa, _MATMUL_ROWS=rows, _UNROLL=unroll
+                ):
+                    return fa.flash_call(q, k, v, o, _blocks=(bq, bk), **kw)
+
+            with mock.patch.multiple(fa, _MATMUL_ROWS=rows, _UNROLL=unroll):
+                tiles = fa.tiles_of(bq, bk, G)
+            report(
+                "chosen" if tiles == chosen else "new", list(tiles), call,
+                old_out,
+            )
+    print(json.dumps({"ok": True, "device": dev.device_kind}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

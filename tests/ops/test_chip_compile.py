@@ -14,6 +14,7 @@ the one that loads it. Keep these compiles in this one file.
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -54,10 +55,9 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _flash_compiled(one_chip, T: int, S: int):
+def _flash_compiled(one_chip, cfg, T: int, S: int):
     from gpustack_tpu.ops.flash_attention import flash_attention_prefill
 
-    cfg = QWEN3_8B
     q = jax.ShapeDtypeStruct(
         (1, T, cfg.num_heads, cfg.head_dim), jnp.bfloat16, sharding=one_chip
     )
@@ -78,6 +78,14 @@ def _flash_compiled(one_chip, T: int, S: int):
 
 
 @pytest.mark.parametrize(
+    "preset,heads,kv_heads,block_q,sub_q",
+    [
+        ("qwen3-8b", 32, 8, 512, 256),        # four query heads a group
+        ("qwen3-30b-a3b", 32, 4, 256, 128),   # eight
+        ("qwen2.5-7b", 28, 4, 256, 128),      # seven: no power of two
+    ],
+)
+@pytest.mark.parametrize(
     "T,S",
     [
         (1024, 1024),   # the 1024 bucket, from scratch
@@ -85,12 +93,34 @@ def _flash_compiled(one_chip, T: int, S: int):
         (512, 2048),    # chunked continuation: q_offset > 0, S > T
     ],
 )
-def test_flash_prefill_compiles_for_v5e(one_chip, T, S):
-    compiled = _flash_compiled(one_chip, T, S)
+def test_flash_prefill_compiles_for_v5e(
+    one_chip, T, S, preset, heads, kv_heads, block_q, sub_q
+):
+    """At the shapes the benchmark's cells run the kernel takes its large
+    tiles (the G * sub_q = 1,024 rows a matmul and the four sub-blocks a
+    basic block that the chip's sweep chose, PERF.md PR 32) and the
+    chip's compiler takes them: a refusal for VMEM, or a chooser that
+    fell back to 128 x 128 without saying, fails here."""
+    from gpustack_tpu.ops.flash_attention import choose_tiles
+
+    cfg = get_config(preset)
+    assert (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim) == (
+        heads, kv_heads, 128
+    )
+    tiles = choose_tiles(T, S, heads // kv_heads, cfg.head_dim, 2)
+    assert tiles == (block_q, sub_q, 512, 4)
+    compiled = _flash_compiled(one_chip, cfg, T, S)
+    # the benchmark's reader finds the call by this name and shape
+    # (perfbench/layer_metrics/kernel.flash_prefill_roofline.py)
+    assert re.search(
+        rf"%flash_attention_prefill[\w.\-]* = bf16\[1,{heads},{T},128\]"
+        r".* custom-call\(",
+        compiled.as_text(),
+    )
     mem = compiled.memory_analysis()
     # the [T, S] score matrix never exists in HBM: temporaries stay near
     # the transposed/padded operands, far below Hq*T*S*4 bytes
-    assert mem.temp_size_in_bytes < QWEN3_8B.num_heads * T * S * 4 / 4
+    assert mem.temp_size_in_bytes < heads * T * S * 4 / 4
 
 
 MAX_LEN = 2048
@@ -143,8 +173,6 @@ def test_a_step_moves_only_its_rows_of_the_donated_cache(
     and ``ys`` out it cost a second cache of temporaries, two copies of
     the whole cache and each layer's slab written back whole (PERF.md,
     PR 29); this keeps them from coming back with a JAX upgrade."""
-    import re
-
     cfg = dataclasses.replace(get_config(preset), num_layers=layers)
     compiled = _int8_step_compiled(one_chip, cfg, slots, T)
     slab = slots * MAX_LEN * cfg.num_kv_heads * cfg.head_dim
@@ -182,8 +210,6 @@ def test_a_position_sharded_cache_takes_its_rows_without_a_gather(topo, T):
     every layer, whatever the carry is constrained to, so ``forward``
     writes it by position there (``_write_rows``): this fails with an
     all-gather of ``bf16[4,8,2048,8,128]`` when that goes."""
-    import re
-
     from jax.sharding import NamedSharding, PartitionSpec
 
     from gpustack_tpu.models import init_params
