@@ -1,4 +1,4 @@
-.PHONY: test test-fast test-engine test-e2e native bench smoke clean verify analyze chaos scale lockdep
+.PHONY: test test-fast test-engine test-e2e native clean verify analyze chaos scale lockdep
 
 test:
 	python -m pytest tests/ -q
@@ -71,12 +71,6 @@ test-e2e:
 
 native:
 	$(MAKE) -C native
-
-bench:
-	python bench.py
-
-smoke:
-	BENCH_SMOKE=1 python bench.py
 
 clean:
 	$(MAKE) -C native clean

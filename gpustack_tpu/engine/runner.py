@@ -38,7 +38,11 @@ from gpustack_tpu.engine.sampling import (
 )
 from gpustack_tpu.models.config import ModelConfig
 from gpustack_tpu.models.quant import QuantW, quant_pspecs
-from gpustack_tpu.models.transformer import KVCache, forward
+from gpustack_tpu.models.transformer import (
+    KVCache,
+    forward,
+    needs_xla_attention,
+)
 from gpustack_tpu.parallel.mesh import MeshPlan, make_mesh
 from gpustack_tpu.parallel.sharding import SpecLayout, param_pspecs
 
@@ -55,6 +59,28 @@ def bias_arrays(logit_bias):
             ids[j] = int(tid)
             vals[j] = float(bias)
     return jnp.asarray(ids, jnp.int32), jnp.asarray(vals, jnp.float32)
+
+
+def prefill_attention(
+    platform: str, bucket: int, sp_mode: bool, cfg: ModelConfig
+) -> str:
+    """Which attention serves a prefill of ``bucket`` tokens: the one
+    place that decides, from what the runner can observe.
+
+    ``"ring"`` under ``sp`` (the cache is sharded over positions; no
+    other path reads it). ``"flash"`` on a TPU for buckets >= 1024,
+    where the XLA path's [B, H, T, S] fp32 score tensor starts to
+    dominate prefill HBM traffic (at 32k it simply does not fit), for a
+    model the kernel takes. ``"xla"`` otherwise: the compiled kernel
+    exists only for the TPU (no serving path reaches the pallas
+    interpreter), and a sliding window, a softcap or sinks need the
+    einsum path's mask and scores.
+    """
+    if sp_mode:
+        return "ring"
+    if platform == "tpu" and bucket >= 1024 and not needs_xla_attention(cfg):
+        return "flash"
+    return "xla"
 
 
 @jax.tree_util.register_dataclass
@@ -211,27 +237,12 @@ class ModelRunner:
         )
 
     def attn_impl_for(self, bucket: int) -> str:
-        """Prefill attention kernel per bucket.
-
-        ``GPUSTACK_TPU_FLASH``: ``1`` forces the pallas flash kernel,
-        ``0`` forces the XLA einsum path, unset = auto — flash on TPU for
-        buckets >= 1024 (where the XLA path's [B, H, T, S] fp32 score
-        tensor starts to dominate prefill HBM traffic; at 32k it simply
-        does not fit). The compiled kernel exists only for the TPU, so
-        auto picks XLA on any other platform; no serving path reaches
-        the pallas interpreter.
-        """
-        import os
-
-        if self.sp_mode:
-            impl = "ring"
-        else:
-            knob = os.environ.get("GPUSTACK_TPU_FLASH", "")
-            if knob in ("0", "1"):
-                impl = "flash" if knob == "1" else "xla"
-            else:
-                on_tpu = self.mesh.devices.flat[0].platform == "tpu"
-                impl = "flash" if (on_tpu and bucket >= 1024) else "xla"
+        """:func:`prefill_attention` for this runner's mesh and model,
+        logged once per bucket."""
+        impl = prefill_attention(
+            self.mesh.devices.flat[0].platform, bucket, self.sp_mode,
+            self.cfg,
+        )
         if bucket not in self._logged_attn_buckets:
             # once per bucket, at compile time: the engine's log says
             # which kernel serves which prompt widths

@@ -587,6 +587,16 @@ def _moe_mlp(
 # ---------------------------------------------------------------------------
 
 
+def needs_xla_attention(cfg: ModelConfig) -> bool:
+    """True for a model whose scores only the XLA einsum path computes:
+    the blocked kernels (pallas flash, ring) know a causal mask and
+    nothing else, so a sliding window, a logit softcap or attention
+    sinks rule them out."""
+    return bool(
+        cfg.sliding_window or cfg.attn_logit_softcap or cfg.attn_sinks
+    )
+
+
 def forward(
     params: Params,
     cfg: ModelConfig,
@@ -614,7 +624,10 @@ def forward(
     prefill), or ``"flash_interpret"`` (same kernel in interpret mode, for
     hermetic CPU tests). Flash applies to the prefill-from-zero cache path
     (T > 1, cache sized to the bucket); decode and the cacheless paths
-    always use XLA attention.
+    always use XLA attention. A model the kernel refuses
+    (:func:`needs_xla_attention`) raises: the caller chooses
+    (``engine/runner.py prefill_attention``), this function never falls
+    back in silence.
 
     ``attn_impl="ring"`` (requires ``mesh`` with an ``sp`` axis) is the
     sequence-parallel serving path: prefill attention runs as ring
@@ -696,19 +709,15 @@ def forward(
         and cache is not None
         and T > 1
         and cache.max_len >= T
-        and not cfg.sliding_window
-        and not cfg.attn_logit_softcap
-        and not cfg.attn_sinks
     )
     use_ring = attn_impl == "ring" and cache is not None
-    if use_ring and (
-        mesh is None or cfg.sliding_window or cfg.attn_logit_softcap
-        or cfg.attn_sinks
-    ):
+    if (use_flash or use_ring) and needs_xla_attention(cfg):
         raise ValueError(
-            "attn_impl='ring' needs a mesh, no sliding window, no "
+            f"attn_impl={attn_impl!r} needs no sliding window, no "
             "attention softcapping and no attention sinks"
         )
+    if use_ring and mesh is None:
+        raise ValueError("attn_impl='ring' needs a mesh")
 
     # mask[b, t, s] — query t attends key s
     if cache is None:

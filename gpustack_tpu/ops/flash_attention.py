@@ -60,7 +60,7 @@ The block sizes follow the shapes (:func:`choose_tiles`): no knob.
 
 Engine wiring: ``models/transformer.forward(attn_impl="flash")`` uses this
 for prefill steps; the engine enables it per prefill bucket
-(engine/runner.py attn_impl_for). Verified bit-close against the XLA
+(engine/runner.py prefill_attention). Verified bit-close against the XLA
 reference and bit-equal to the old kernel in interpret mode
 (tests/ops/test_flash_attention.py), and compiled for a described v5e at
 Qwen3-8B and Qwen3-30B-A3B widths (tests/ops/test_chip_compile.py).

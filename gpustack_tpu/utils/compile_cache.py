@@ -1,8 +1,8 @@
 """One persistent XLA compile cache for every process that compiles.
 
 Call :func:`enable_compile_cache` once, before the first jit, in every
-process that compiles: the engine servers, ``bench.py``,
-``__graft_entry__.py`` and the test session. The directory is part of
+process that compiles: the engine servers, ``__graft_entry__.py`` and
+the test session. The directory is part of
 what a later process has to find again, so it never moves:
 
 - where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no

@@ -19,6 +19,8 @@ processes call the same helper and arrive at the same directory.
 import os
 import sys
 
+import pytest
+
 # XLA reads this at backend init; conftest runs before any test imports jax.
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
@@ -47,6 +49,29 @@ from gpustack_tpu.utils.compile_cache import (  # noqa: E402
 
 enable_compile_cache()
 
+# Clusters of different xdist workers must not probe one band of engine
+# ports (gpustack_tpu/testing/ports.py): every Config an in-process
+# server or a child process loads reads this. Assigned, not defaulted:
+# an xdist worker inherits what the controller, which loads this file
+# first, put there.
+from gpustack_tpu.testing.ports import (  # noqa: E402
+    coordinator_port_base,
+    engine_port_base,
+)
+
+os.environ["GPUSTACK_TPU_ENGINE_PORT_BASE"] = str(engine_port_base())
+
+
+@pytest.fixture
+def own_coordinator_band(monkeypatch):
+    """The scheduler of an in-process server hands multi-host replicas
+    coordinator ports no other test process's scheduler hands out."""
+    from gpustack_tpu.scheduler import scheduler
+
+    monkeypatch.setattr(
+        scheduler, "COORDINATOR_PORT_BASE", coordinator_port_base()
+    )
+
 
 # ---------------------------------------------------------------------------
 # Test tiers (reference keeps pytest markers, pytest.ini:1-3; our split):
@@ -64,8 +89,6 @@ _TIER_BY_DIR = {
 
 
 def pytest_collection_modifyitems(config, items):
-    import pytest
-
     tests_root = os.path.dirname(os.path.abspath(__file__))
     for item in items:
         try:

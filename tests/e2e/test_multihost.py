@@ -27,6 +27,8 @@ import time
 import aiohttp
 import pytest
 
+from gpustack_tpu.testing.ports import engine_port_base
+
 REPO = os.path.dirname(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
@@ -39,7 +41,7 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
-def _spawn_worker(server_port, data_dir, fixture, name, port_base=40000):
+def _spawn_worker(server_port, data_dir, fixture, name, host):
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     env["GPUSTACK_TPU_HEARTBEAT_INTERVAL"] = "1.0"
@@ -47,8 +49,9 @@ def _spawn_worker(server_port, data_dir, fixture, name, port_base=40000):
     # DISJOINT engine-port bands per worker: on real deployments each
     # worker is its own host, but both e2e workers share localhost —
     # identical bands race the probe-then-bind window and an engine can
-    # die at bind (recoverable via restart, but it flakes the test)
-    env["GPUSTACK_TPU_ENGINE_PORT_BASE"] = str(port_base)
+    # die at bind (recoverable via restart, but it flakes the test).
+    # And disjoint from every other test process's clusters.
+    env["GPUSTACK_TPU_ENGINE_PORT_BASE"] = str(engine_port_base(host))
     return subprocess.Popen(
         [
             sys.executable, "-m", "gpustack_tpu", "start",
@@ -86,7 +89,7 @@ def _kill_engines_under(data_dir) -> int:
     return killed
 
 
-def test_multihost_serve_and_follower_loss(tmp_path):
+def test_multihost_serve_and_follower_loss(tmp_path, own_coordinator_band):
     from gpustack_tpu.config import Config
     from gpustack_tpu.server.server import Server
 
@@ -115,11 +118,11 @@ def test_multihost_serve_and_follower_loss(tmp_path):
         try:
             workers.append(_spawn_worker(
                 server_port, dirs[0], "v4_8_host0.json", "host0",
-                port_base=40000,
+                host=0,
             ))
             workers.append(_spawn_worker(
                 server_port, dirs[1], "v4_8_host1.json", "host1",
-                port_base=46000,
+                host=1,
             ))
             async with aiohttp.ClientSession() as http:
                 async with http.post(

@@ -22,6 +22,8 @@ import time
 
 import aiohttp
 
+from gpustack_tpu.testing.ports import engine_port_base
+
 REPO = os.path.dirname(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
@@ -34,12 +36,14 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
-def _spawn_worker(server_port, data_dir, fixture, name, port_base):
+def _spawn_worker(server_port, data_dir, fixture, name, host):
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     env["GPUSTACK_TPU_HEARTBEAT_INTERVAL"] = "1.0"
     env["GPUSTACK_TPU_STATUS_INTERVAL"] = "2.0"
-    env["GPUSTACK_TPU_ENGINE_PORT_BASE"] = str(port_base)
+    # a band of engine ports for each host, shared with no other test
+    # process's clusters
+    env["GPUSTACK_TPU_ENGINE_PORT_BASE"] = str(engine_port_base(host))
     return subprocess.Popen(
         [
             sys.executable, "-m", "gpustack_tpu", "start",
@@ -57,7 +61,9 @@ def _spawn_worker(server_port, data_dir, fixture, name, port_base):
     )
 
 
-def test_four_process_replica_with_chunked_prefill(tmp_path):
+def test_four_process_replica_with_chunked_prefill(
+    tmp_path, own_coordinator_band
+):
     from gpustack_tpu.config import Config
     from gpustack_tpu.server.server import Server
 
@@ -87,7 +93,7 @@ def test_four_process_replica_with_chunked_prefill(tmp_path):
             for i in range(4):
                 workers.append(_spawn_worker(
                     server_port, dirs[i], f"v4_8_quarter{i}.json",
-                    f"host{i}", port_base=40000 + 3000 * i,
+                    f"host{i}", host=i,
                 ))
             async with aiohttp.ClientSession() as http:
                 async with http.post(

@@ -243,6 +243,30 @@ def test_prefill_flash_matches_xla(tiny):
     )
 
 
+@pytest.mark.parametrize(
+    "feature",
+    [
+        {"sliding_window": 16},
+        {"attn_logit_softcap": 50.0},
+        {"attn_sinks": True},
+    ],
+    ids=lambda f: next(iter(f)),
+)
+def test_flash_on_a_model_the_kernel_refuses_raises(tiny, feature):
+    """The caller chooses the kernel (engine/runner.py
+    prefill_attention); asked for flash on a windowed, softcapped or
+    sink model, ``forward`` says so instead of running XLA in silence."""
+    cfg, params = tiny
+    cfg = dataclasses.replace(cfg, **feature)
+    B, T = 1, 32
+    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    with pytest.raises(ValueError, match="flash_interpret"):
+        forward(
+            params, cfg, _tokens(cfg, B, T), positions,
+            KVCache.create(cfg, B, T), attn_impl="flash_interpret",
+        )
+
+
 # ---------------------------------------------------------------------------
 # The cache rides the layer scan as its carry; a step writes only its rows
 # ---------------------------------------------------------------------------
