@@ -198,9 +198,21 @@ class OpenAIServer:
                 h["kv_cache_prefix_tokens_reused"],
             ),
             ("gpustack_kv_cache_bytes", h["kv_cache_host_bytes"]),
+            ("gpustack_engine_kv_cache_bytes", h["kv_cache_bytes"]),
+            (
+                "gpustack_engine_kv_cache_bytes_per_token",
+                h["kv_cache_bytes_per_token"],
+            ),
         ):
             lines.append(f"# TYPE {family} {METRIC_FAMILIES[family]}")
             lines.append(f"{family} {value}")
+        if h.get("moe_pairs") is not None:   # a share of the experts
+            family = "gpustack_engine_moe_pairs_total"
+            lines.append(f"# TYPE {family} {METRIC_FAMILIES[family]}")
+            for held, key in (("yes", "held"), ("no", "absent")):
+                lines.append(
+                    f'{family}{{held="{held}"}} {h["moe_pairs"][key]}'
+                )
         # disaggregated KV handoff (engine/kv_transfer.py): wire
         # bytes/blocks per direction + pull failures; the latency
         # histogram rides the request-histogram loop below

@@ -543,6 +543,11 @@ class LLMEngine:
         self.max_slots = max_slots
         self.max_seq_len = max_seq_len
         self._state: DecodeState = self.runner.new_state()
+        cache = self._state.cache
+        self._kv_cache_bytes = int(cache.k.nbytes + cache.v.nbytes)
+        self._kv_cache_bytes_per_token = cfg.kv_cache_bytes_per_token(
+            8 * cache.k.dtype.itemsize
+        ) // cfg.num_layers
         self._slots: Dict[int, _SlotInfo] = {}
         self._free = list(range(max_slots))
         self._waiting: "queue.Queue[GenRequest]" = queue.Queue()
@@ -595,6 +600,8 @@ class LLMEngine:
         self._step_prompt = 0        # prompt tokens entering prefill
         # prompt tokens entering prefill, by expert dispatch (MoE only)
         self._step_moe_dispatch: Dict[str, int] = {}
+        # the form of attention the step's prefill ran (MLA only)
+        self._step_attn: Optional[str] = None
         self._step_spec_proposed = 0
         self._step_spec_accepted = 0
         # on-demand profiler capture (capture_profile): the capturing
@@ -856,6 +863,13 @@ class LLMEngine:
             "steps": self._step_count,
             "tokens_generated": self._tokens_generated,
             "prompt_tokens": self.flight.prompt_tokens_total,
+            # the device's KV cache: all of it, and what one position of
+            # one layer takes (ModelConfig.kv_row_shapes)
+            "kv_cache_bytes": self._kv_cache_bytes,
+            "kv_cache_bytes_per_token": self._kv_cache_bytes_per_token,
+            # under a share of the experts: the prefill programs' router
+            # pairs by whether their expert is held here (else None)
+            "moe_pairs": self.runner.moe_pairs(),
             "flight_overhead_ratio": round(
                 self.flight.overhead_ratio(), 6
             ),
@@ -1038,6 +1052,7 @@ class LLMEngine:
         self._step_real = self._step_padded = 0
         self._step_out = self._step_prompt = 0
         self._step_moe_dispatch = {}
+        self._step_attn = None
         self._step_spec_proposed = self._step_spec_accepted = 0
         self._step_admitted = []
         self._step_first = []
@@ -1080,6 +1095,7 @@ class LLMEngine:
         self._step_real += tokens
         self._step_prompt += tokens
         self._step_padded += bucket
+        self._step_attn = self.runner.attn_label(bucket)
         dispatch = self.runner.moe_dispatch_for(bucket)
         if dispatch is not None:
             by_dispatch = self._step_moe_dispatch
@@ -1125,6 +1141,8 @@ class LLMEngine:
                 kv.prefix_tokens_reused if kv is not None else 0
             ),
             moe_dispatch=self._step_moe_dispatch,
+            # a step without a prefill went over the cache
+            attn=self._step_attn or self.runner.attn_label(),
         )
         if dur_s > _SLOW_STEP_S:
             logger.warning(

@@ -135,6 +135,12 @@ class ModelRunner:
         # attention, decode/verify the pmax/psum merge (ops/ring_attention).
         self.sp_mode = self.plan.sp > 1
         if self.sp_mode:
+            if cfg.is_mla:
+                raise ValueError(
+                    "sp>1 serving shards the cache over its positions, "
+                    "which cannot carry a latent (MLA) cache yet; serve "
+                    f"{cfg.name} with sp=1"
+                )
             if self.plan.dp != 1:
                 raise ValueError(
                     "sp>1 serving requires dp=1 (one sequence-sharded "
@@ -189,7 +195,7 @@ class ModelRunner:
         # serves layout.describe() on its health surface.
         self.layout = SpecLayout(long_context=self.sp_mode)
         self._cache_sharding = NamedSharding(
-            self.mesh, self.layout.cache()
+            self.mesh, self.layout.cache(latent=cfg.is_mla)
         )
         self._slot_sharding = NamedSharding(
             self.mesh, self.layout.slot_state()
@@ -199,7 +205,9 @@ class ModelRunner:
         )
 
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
+        self._decode_routing = None
         self._prefills: Dict[int, Any] = {}
+        self._prefills_routing: Dict[int, Any] = {}
         self._logged_attn_buckets: set = set()
         self._prefill_embeds: Dict[int, Any] = {}
         self._sample_first: Optional[Any] = None
@@ -208,6 +216,15 @@ class ModelRunner:
         self._verifies: Dict[int, Any] = {}
         self._ingests: Dict[int, Any] = {}
         self._prefix_prefills: Dict[Tuple[int, int, int], Any] = {}
+        # under a share of the experts (cfg.experts_held): the router's
+        # (token, expert) pairs the prefill programs have made, on the
+        # device the ones on held experts (added up without a sync), and
+        # the last pair of counts that was read
+        self._pairs_routed = 0
+        self._pairs_held = (
+            jnp.zeros((), jnp.int32) if cfg.experts_held else None
+        )
+        self._pairs_read = {"held": 0, "absent": 0}
 
     # -- state ------------------------------------------------------------
 
@@ -253,6 +270,44 @@ class ModelRunner:
             )
         return impl
 
+    def attn_label(self, bucket: Optional[int] = None) -> Optional[str]:
+        """For a latent-attention model, the form of attention a step
+        runs, for the step's flight record: ``"mla_flash"`` /
+        ``"mla_xla"`` for a prefill of ``bucket`` tokens (decompressed,
+        by :func:`prefill_attention`'s kernel), ``"mla_absorbed"`` for a
+        step over the latent cache (``bucket`` None). None for any other
+        model."""
+        if not self.cfg.is_mla:
+            return None
+        if bucket is None:
+            return "mla_absorbed"
+        return "mla_" + self.attn_impl_for(bucket)
+
+    def moe_pairs(self) -> Optional[Dict[str, int]]:
+        """Under a share of the experts, the router's (token, expert)
+        pairs of every prefill program so far, bucket padding included,
+        by whether the pair's expert is held here; None for a model
+        whose experts are all held. Never waits for the device (a
+        health probe must not stand behind a prefill): while the count
+        of the last prefill dispatched is not there yet, the counts as
+        they were last read."""
+        if self._pairs_held is None:
+            return None
+        held, routed = self._pairs_held, self._pairs_routed
+        if held.is_ready():
+            self._pairs_read = {
+                "held": int(held), "absent": routed - int(held)
+            }
+        return self._pairs_read
+
+    def _note_pairs(self, held, rows: int) -> None:
+        cfg = self.cfg
+        self._pairs_held = self._pairs_held + held
+        self._pairs_routed += (
+            rows * cfg.num_experts_per_tok
+            * (cfg.num_layers - cfg.first_k_dense)
+        )
+
     def moe_dispatch_for(self, rows: int) -> Optional[str]:
         """The dispatch ``forward`` traces a program of ``rows`` tokens
         with on this runner's mesh (:func:`moe_dispatch`, given what
@@ -272,33 +327,51 @@ class ModelRunner:
         fn.__name__ = name
         return jax.jit(fn)
 
-    def _prefill_impl(self, params, tokens, true_len, *, attn_impl="xla"):
-        """tokens [1, Tb]; returns (last_logits [V], k, v [L, Tb, H, hd])."""
+    def _prefill_impl(
+        self, params, tokens, true_len, *, attn_impl="xla", routing=False
+    ):
+        """tokens [1, Tb]; returns (last_logits [V], k, v [L, Tb, heads,
+        width]), and under a share of the experts the count of the
+        router's pairs on held experts; with ``routing`` last of all what
+        ``forward(routing_out=True)`` adds."""
         Tb = tokens.shape[1]
         cache = KVCache.create(self.cfg, 1, Tb)
         positions = jnp.arange(Tb, dtype=jnp.int32)[None, :]
-        logits, cache = forward(
+        logits, cache, *extras = forward(
             params, self.cfg, tokens, positions, cache,
             attn_impl=attn_impl,
             mesh=self.mesh,
+            count_held_pairs=bool(self.cfg.experts_held),
+            routing_out=routing,
         )
         last = jnp.take(logits[0], true_len - 1, axis=0)
-        return last, cache.k[:, 0], cache.v[:, 0]
+        return (last, cache.k[:, 0], cache.v[:, 0], *extras)
 
-    def prefill(self, token_ids, true_len: int):
+    def prefill(self, token_ids, true_len: int, routing: bool = False):
         """Run prefill at the bucket for ``true_len``. ``token_ids`` must be
-        padded to the bucket length already (any pad id)."""
+        padded to the bucket length already (any pad id).
+
+        ``routing``: the same program with one more output, each layer's
+        chosen experts and router logits (``forward``), returned fourth.
+        For a comparison with a reference; the engine never asks."""
         Tb = len(token_ids)
         assert Tb in self.prefill_buckets, (Tb, self.prefill_buckets)
-        fn = self._prefills.get(Tb)
+        fns = self._prefills_routing if routing else self._prefills
+        fn = fns.get(Tb)
         if fn is None:
             fn = self._named_jit(
-                partial(self._prefill_impl, attn_impl=self.attn_impl_for(Tb)),
-                f"prefill_{Tb}",
+                partial(
+                    self._prefill_impl, attn_impl=self.attn_impl_for(Tb),
+                    **({"routing": True} if routing else {}),
+                ),
+                f"prefill_{Tb}" + ("_routing" if routing else ""),
             )
-            self._prefills[Tb] = fn
+            fns[Tb] = fn
         tokens = jnp.asarray(token_ids, jnp.int32)[None, :]
-        return fn(self.params, tokens, jnp.int32(true_len))
+        last, k, v, *extras = fn(self.params, tokens, jnp.int32(true_len))
+        if self.cfg.experts_held:
+            self._note_pairs(extras[0], Tb)
+        return (last, k, v, extras[-1]) if routing else (last, k, v)
 
     def _prefill_embeds_impl(
         self, params, tokens, true_len, embeds, mask, *, attn_impl="xla"
@@ -364,13 +437,14 @@ class ModelRunner:
         positions = (
             prefix_len + jnp.arange(Tsb, dtype=jnp.int32)
         )[None, :]
-        logits, cache = forward(
+        logits, cache, *held = forward(
             params, self.cfg, tokens, positions, cache,
             attn_impl=attn_impl,
             mesh=self.mesh,
+            count_held_pairs=bool(self.cfg.experts_held),
         )
         last = jnp.take(logits[0], true_len - 1, axis=0)
-        return last, cache.k[:, 0], cache.v[:, 0]
+        return (last, cache.k[:, 0], cache.v[:, 0], *held)
 
     def prefill_with_prefix(
         self, prefix_k, prefix_v, prefix_len: int,
@@ -396,7 +470,7 @@ class ModelRunner:
             )
             self._prefix_prefills[key] = fn
         tokens = jnp.asarray(suffix_ids, jnp.int32)[None, :]
-        return fn(
+        last, k, v, *held = fn(
             self.params,
             jnp.asarray(prefix_k),
             jnp.asarray(prefix_v),
@@ -405,6 +479,9 @@ class ModelRunner:
             # logits cover the suffix only
             jnp.int32(suffix_true_len),
         )
+        if held:
+            self._note_pairs(held[0], Tsb)
+        return last, k, v
 
     # -- embeddings -------------------------------------------------------
 
@@ -511,13 +588,14 @@ class ModelRunner:
 
     # -- decode -----------------------------------------------------------
 
-    def _decode_impl(self, params, state, key):
+    def _decode_impl(self, params, state, key, routing=False):
         tokens = state.last_tokens[:, None]
         positions = state.positions[:, None]
-        logits, cache = forward(
+        logits, cache, *extras = forward(
             params, self.cfg, tokens, positions, state.cache,
             attn_impl="ring" if self.sp_mode else "xla",
             mesh=self.mesh,
+            routing_out=routing,
         )
         sampled, tok_lp, top_ids, top_lps = sample(
             logits[:, 0], state.sampling, key, state.positions
@@ -548,14 +626,21 @@ class ModelRunner:
                 active=state.active & ~at_capacity,
                 sampling=state.sampling,
             ),
-            (sampled, tok_lp, top_ids, top_lps),
+            (sampled, tok_lp, top_ids, top_lps, *extras),
         )
 
-    def decode_step(self, state: DecodeState, key):
+    def decode_step(self, state: DecodeState, key, routing: bool = False):
         """One decode step. Returns ``(state', (tokens [B], token_logprob
         [B], top_ids [B, TOPLP], top_logprobs [B, TOPLP]))`` — the
-        logprob extras ride the same device round-trip as the tokens."""
-        return self._decode(self.params, state, key)
+        logprob extras ride the same device round-trip as the tokens.
+        ``routing``: as :meth:`prefill`'s, a fifth in the tuple."""
+        if not routing:
+            return self._decode(self.params, state, key)
+        if self._decode_routing is None:
+            fn = partial(self._decode_impl, routing=True)
+            fn.__name__ = "_decode_routing"
+            self._decode_routing = jax.jit(fn, donate_argnums=(1,))
+        return self._decode_routing(self.params, state, key)
 
     def _sample_first_impl(
         self, last_logits, temperature, top_k, top_p, seed, seeded,

@@ -154,7 +154,9 @@ def build_lm_params(
         layers: Dict[str, Any] = _QuantizeOnSet() if int8 else {}
         layers["attn_norm"] = stack("model.layers.{}.input_layernorm.weight")
         if cfg.is_mla:
-            # DeepSeek MLA projections (decompressed serving)
+            # DeepSeek MLA projections; kv_b_proj is split once, here,
+            # into its key and value columns (models/transformer.py
+            # mla_attention absorbs them apart)
             if cfg.q_lora_rank:
                 layers["wq_a"] = stack(
                     "model.layers.{}.self_attn.q_a_proj.weight", True
@@ -176,8 +178,17 @@ def build_lm_params(
             layers["kv_a_norm"] = stack(
                 "model.layers.{}.self_attn.kv_a_layernorm.weight"
             )
-            layers["wkv_b"] = stack(
+            kv_b = stack(
                 "model.layers.{}.self_attn.kv_b_proj.weight", True
+            ).reshape(
+                len(rng), cfg.kv_lora_rank, cfg.num_heads,
+                cfg.qk_nope_head_dim + cfg.v_head_dim,
+            )
+            layers["wk_b"] = kv_b[..., : cfg.qk_nope_head_dim].reshape(
+                len(rng), cfg.kv_lora_rank, -1
+            )
+            layers["wv_b"] = kv_b[..., cfg.qk_nope_head_dim:].reshape(
+                len(rng), cfg.kv_lora_rank, -1
             )
             layers["wo"] = stack(
                 "model.layers.{}.self_attn.o_proj.weight", True
@@ -321,7 +332,11 @@ def build_lm_params(
                     )
                     for i in rng
                 ])
-            E = cfg.num_experts
+            # under a share only the held experts' weights are read
+            held = range(
+                cfg.first_held_expert,
+                cfg.first_held_expert + cfg.num_held_experts,
+            )
 
             def stack_experts(w: str, transpose: bool) -> jax.Array:
                 return jnp.stack([
@@ -335,7 +350,7 @@ def build_lm_params(
                                 f".experts.{e}.{w}.weight"
                             )
                         )
-                        for e in range(E)
+                        for e in held
                     ])
                     for i in rng
                 ])

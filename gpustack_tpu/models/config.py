@@ -62,13 +62,18 @@ class ModelConfig:
     moe_intermediate_size: int = 0
     norm_topk_prob: bool = True
     # ---- DeepSeek-family knobs ----
-    # MLA (multi-head latent attention, DeepSeek-V2/V3): kv_lora_rank>0
-    # switches the attention block to compressed-latent projections. The
-    # engine serves the DECOMPRESSED form: per-head K/V are materialized
-    # (head_dim = qk_nope + qk_rope, num_kv_heads = num_heads) so the
-    # existing cache/flash/ring machinery applies unchanged; v (width
-    # v_head_dim) is zero-padded to head_dim in the cache and sliced
-    # before o_proj. Trades cache bytes for zero structural divergence.
+    # MLA (multi-head latent attention, DeepSeek-V2/V3 family):
+    # kv_lora_rank>0 switches the attention block to compressed-latent
+    # projections, and the cache to the latent: a position holds the
+    # normed c_kv (kv_lora_rank wide, in the cache's k) and the rotated
+    # shared rope key (qk_rope_head_dim wide, in its v), one "head" for
+    # all query heads (kv_row_shapes). A prefill decompresses K and V
+    # inside its program (head_dim = qk_nope + qk_rope for q and k,
+    # v_head_dim for v); a step over cached rows absorbs the
+    # up-projections into the query and the output
+    # (models/transformer.py). num_kv_heads stays num_heads: it counts
+    # the heads the weights and the decompressed K/V divide into, not
+    # the cache's.
     q_lora_rank: int = 0            # 0 = direct q projection
     kv_lora_rank: int = 0           # >0 = MLA
     qk_nope_head_dim: int = 0
@@ -87,6 +92,19 @@ class ModelConfig:
     # "softmax" (v2) | "sigmoid" (v3: score + e_score_correction_bias)
     # | "softmax_topk" (GPT-OSS: softmax over the selected top-k logits)
     moe_scoring: str = "softmax"
+    # group-limited selection (DeepSeek-V3 family): the experts form
+    # n_group groups of equal size, a group scores the sum of its two
+    # best experts, the topk_group best groups stay and the token's
+    # experts are chosen within them. n_group 1 is plain top-k.
+    n_group: int = 1
+    topk_group: int = 1
+    # One chip's share of an expert-parallel layer: the router keeps all
+    # num_experts outputs, the weights hold only the experts_held
+    # experts from first_held_expert on, and the layer computes their
+    # part of the result for the tokens routed to them (absent experts
+    # add nothing). 0 = every expert is held.
+    experts_held: int = 0
+    first_held_expert: int = 0
     # ---- GPT-OSS knobs ----
     # learned per-head attention-sink logits (join the softmax
     # denominator only — modeling_gpt_oss eager_attention_forward)
@@ -121,6 +139,25 @@ class ModelConfig:
         return self.kv_lora_rank > 0
 
     @property
+    def num_held_experts(self) -> int:
+        """Experts whose weights this replica holds (``experts_held``)."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def kv_row_shapes(self) -> Tuple[Tuple[int, int], Tuple[int, int]]:
+        """``((heads, width) of k, (heads, width) of v)``: what one
+        position of one layer holds in the cache. The one place that
+        says so: ``KVCache.create`` and the byte counts follow it. GQA
+        holds each kv head's key and value; MLA the shared latent in
+        ``k`` and the shared rope key in ``v``, one head each."""
+        if self.is_mla:
+            return (1, self.kv_lora_rank), (1, self.qk_rope_head_dim)
+        return (
+            (self.num_kv_heads, self.head_dim),
+            (self.num_kv_heads, self.head_dim),
+        )
+
+    @property
     def attention_type(self) -> str:
         if self.num_kv_heads == 1:
             return "MQA"
@@ -135,6 +172,15 @@ class ModelConfig:
         if self.is_moe:
             assert self.num_experts_per_tok > 0
             assert self.moe_intermediate_size > 0
+            assert self.num_experts % self.n_group == 0, (
+                "n_group must divide the experts"
+            )
+            assert 1 <= self.topk_group <= self.n_group
+            assert (
+                0 <= self.first_held_expert
+                and self.first_held_expert + self.num_held_experts
+                <= self.num_experts
+            ), "the held experts must lie among the router's"
         if self.layer_sliding is not None:
             assert len(self.layer_sliding) == self.num_layers
             assert self.sliding_window > 0
@@ -170,7 +216,7 @@ class ModelConfig:
             if self.qk_norm:
                 attn += 2 * self.head_dim
         if self.is_moe:
-            mlp = d * self.num_experts + self.num_experts * (
+            mlp = d * self.num_experts + self.num_held_experts * (
                 3 * d * self.moe_intermediate_size
             )
             if self.shared_expert_intermediate_size:
@@ -195,8 +241,9 @@ class ModelConfig:
         return self.param_count() * bits // 8
 
     def kv_cache_bytes_per_token(self, bits: int = 16) -> int:
-        """Bytes of K+V cache per token position (all layers)."""
-        return 2 * self.num_layers * self.kv_dim * bits // 8
+        """Bytes of cache per token position (all layers)."""
+        per_layer = sum(h * w for h, w in self.kv_row_shapes)
+        return self.num_layers * per_layer * bits // 8
 
 
 def config_from_hf(cfg: Dict[str, Any], name: str = "custom") -> ModelConfig:
@@ -217,21 +264,23 @@ def config_from_hf(cfg: Dict[str, Any], name: str = "custom") -> ModelConfig:
         or cfg.get("n_routed_experts")    # DeepSeek-V2/V3
         or 0
     )
+    # the DeepSeek-V2/V3 family; a model that ships its layers unchanged
+    # under another name (A.X-K1) is served from a file that names the
+    # family (perfbench/configs/ax-k1-int8-ep16-l12/deployment.json)
     deepseek = "Deepseek" in arch
     mla = deepseek and int(cfg.get("kv_lora_rank") or 0) > 0
     if mla:
         qk_nope = int(cfg.get("qk_nope_head_dim") or 0)
         qk_rope = int(cfg.get("qk_rope_head_dim") or 0)
-        # decompressed MLA: the cache is per-head over the full qk dim
+        # the width of a decompressed query or key head
         head_dim = qk_nope + qk_rope
-    if deepseek and int(cfg.get("n_group") or 1) > 1:
-        # group-limited expert routing selects a DIFFERENT expert set
-        # than plain top-k — serving it ungrouped would be silently
-        # wrong logits, for any topk_method
-        raise ValueError(
-            "DeepSeek group-limited routing (n_group>1) is not "
-            "supported yet; serve a checkpoint with n_group=1"
-        )
+    # one chip's share of the experts: the file's expert count is what
+    # is held here, and ``experts_held`` beside it states the router's
+    # published width and the first held id
+    share = cfg.get("experts_held") or {}
+    experts_held = num_experts if share else 0
+    if share:
+        num_experts = int(share["published"])
     # Gemma2/Gemma3 text: (1+w) norms, scaled embeddings, sandwich
     # norms, gelu-tanh MLP, softcapping (gemma2), alternating
     # sliding/full layers, dual rope thetas (gemma3).  Gemma1
@@ -279,7 +328,7 @@ def config_from_hf(cfg: Dict[str, Any], name: str = "custom") -> ModelConfig:
         intermediate_size=cfg.get("intermediate_size", 4 * hidden),
         num_layers=cfg["num_hidden_layers"],
         num_heads=heads,
-        # decompressed MLA materializes per-head K/V: MHA cache shape
+        # MLA decompresses to one K/V head a query head
         num_kv_heads=(
             heads if mla
             else cfg.get("num_key_value_heads", heads)
@@ -362,6 +411,12 @@ def config_from_hf(cfg: Dict[str, Any], name: str = "custom") -> ModelConfig:
             if deepseek and cfg.get("scoring_func") == "sigmoid"
             else ("softmax_topk" if gptoss else "softmax")
         ),
+        # any topk_method: the family's public port selects within the
+        # kept groups whatever the key says
+        n_group=int(cfg.get("n_group") or 1) if deepseek else 1,
+        topk_group=int(cfg.get("topk_group") or 1) if deepseek else 1,
+        experts_held=experts_held,
+        first_held_expert=int(share.get("first", 0)),
     ).validate()
 
 
@@ -551,7 +606,8 @@ PRESETS: Dict[str, ModelConfig] = {
         max_position_embeddings=32768,
     ),
     # DeepSeek-V2-Lite (deepseek-ai/DeepSeek-V2-Lite): MLA + DeepSeek
-    # MoE, served decompressed (see the MLA notes on ModelConfig)
+    # MoE, served over the latent cache (see the MLA notes on
+    # ModelConfig)
     "deepseek-v2-lite": ModelConfig(
         name="deepseek-v2-lite",
         vocab_size=102400,

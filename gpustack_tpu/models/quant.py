@@ -55,14 +55,14 @@ _CONTRACT_AXES: Dict[str, tuple] = {
     # DeepSeek MLA projections + shared experts (the tiny rank-sized
     # norms and router bias stay unquantized like other small leaves)
     "wq_a": (0,), "wq_b": (0,),
-    "wkv_a": (0,), "wkv_b": (0,),
+    "wkv_a": (0,), "wk_b": (0,), "wv_b": (0,),
     "ws_gate": (0,), "ws_up": (0,), "ws_down": (0,),
 }
 # Layer-stacked leaves carry a leading [L] axis not present at use time.
 _STACKED = {
     "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
     "we_gate", "we_up", "we_down",
-    "wq_a", "wq_b", "wkv_a", "wkv_b",
+    "wq_a", "wq_b", "wkv_a", "wk_b", "wv_b",
     "ws_gate", "ws_up", "ws_down",
 }
 
@@ -153,9 +153,9 @@ def quant_pspecs(specs: Dict[str, Any], params: Dict[str, Any]):
 
     out: Dict[str, Any] = {}
     for k, v in params.items():
-        if k == "layers":
+        if k in ("layers", "dense_layers"):
             out[k] = {
-                lk: adapt(lk, specs["layers"][lk], lv) for lk, lv in v.items()
+                lk: adapt(lk, specs[k][lk], lv) for lk, lv in v.items()
             }
         else:
             out[k] = adapt(k, specs[k], v)
@@ -220,15 +220,16 @@ def init_quantized_params(cfg, seed: int = 0):
         layers["post_mlp_norm"] = norm_init(L, d)
     if cfg.is_moe:
         fm, E = cfg.moe_intermediate_size, cfg.num_experts
+        Eh = cfg.num_held_experts    # weights for the held experts only
         layers["router"] = (
             jnp.asarray(
                 rng.standard_normal((L, d, E), dtype=np.float32)
                 / math.sqrt(d)
             ).astype(jnp.bfloat16)
         )
-        layers["we_gate"] = qw((L, E, d, fm), d, "we_gate")
-        layers["we_up"] = qw((L, E, d, fm), d, "we_up")
-        layers["we_down"] = qw((L, E, fm, d), fm, "we_down")
+        layers["we_gate"] = qw((L, Eh, d, fm), d, "we_gate")
+        layers["we_up"] = qw((L, Eh, d, fm), d, "we_up")
+        layers["we_down"] = qw((L, Eh, fm, d), fm, "we_down")
     else:
         layers["w_gate"] = qw((L, d, f), d, "w_gate")
         layers["w_up"] = qw((L, d, f), d, "w_up")

@@ -64,7 +64,14 @@ def _embed_lookup(embed, tokens: jax.Array, dtype) -> jax.Array:
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class KVCache:
-    """Slot-based KV cache: ``k, v`` are ``[L, B, S_max, H_kv, head_dim]``.
+    """Slot-based KV cache: ``k, v`` are ``[L, B, S_max, heads, width]``,
+    what a position holds being the configuration's to say
+    (``ModelConfig.kv_row_shapes``): each kv head's key and value for
+    GQA; for MLA the shared latent ``c_kv`` (after its norm) in ``k`` and
+    the shared rope key (after its rotation) in ``v``, one head each and
+    of different widths. Whatever stores, moves or copies a slot's rows
+    treats ``k`` and ``v`` as two opaque blocks of ``[L, T, heads,
+    width]``; only ``forward`` knows what is in them.
 
     Rows (batch slots) are owned by the engine's slot allocator; positions are
     absolute token indices, so writing at ``positions`` and masking with
@@ -102,12 +109,15 @@ class KVCache:
             dtype = (
                 jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
             )
-        shape = (cfg.num_layers, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
-        return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+        k_row, v_row = cfg.kv_row_shapes
+        lead = (cfg.num_layers, batch, max_len)
+        return KVCache(
+            k=jnp.zeros(lead + k_row, dtype), v=jnp.zeros(lead + v_row, dtype)
+        )
 
 
 _ROW_WRITE = lax.ScatterDimensionNumbers(
-    update_window_dims=(1, 2, 3),         # [T, H_kv, head_dim] a row
+    update_window_dims=(1, 2, 3),         # [T, heads, width] a row
     inserted_window_dims=(0,),            # one layer
     scatter_dims_to_operand_dims=(0, 2),  # index = (layer, start)
     operand_batching_dims=(1,),           # row b of the cache takes
@@ -115,9 +125,18 @@ _ROW_WRITE = lax.ScatterDimensionNumbers(
 )
 
 
+_ROW_WRITE_1HEAD = lax.ScatterDimensionNumbers(
+    update_window_dims=(1, 2),            # [T, width] a row
+    inserted_window_dims=(0,),
+    scatter_dims_to_operand_dims=(0, 2),
+    operand_batching_dims=(1,),
+    scatter_indices_batching_dims=(0,),
+)
+
+
 def _write_rows(
-    buf: jax.Array,      # [L, B, S_max, H_kv, head_dim], one of a KVCache
-    rows: jax.Array,     # [B, T, H_kv, head_dim], this step's K or V
+    buf: jax.Array,      # [L, B, S_max, heads, width], one of a KVCache
+    rows: jax.Array,     # [B, T, heads, width], this step's rows for it
     layer: jax.Array,    # int32 scalar
     start: jax.Array,    # [B] int32, each row's first position
     by_position: bool = False,
@@ -134,24 +153,43 @@ def _write_rows(
     the layer takes its row of the step or keeps its own, one elementwise
     pass over the layer that each shard makes over its own positions.
     ``tests/ops/test_chip_compile.py`` holds both to a described 2x2.
+
+    A cache of one head (the MLA latent) comes **without its head**,
+    ``[L, B, S_max, width]`` and ``[B, T, width]``: that is how the TPU
+    stores it, and a scatter of ``[T, 1, width]`` windows asks for
+    another layout, to which the whole cache is copied and back, every
+    step (compiled for a described v5e: 4 GB of temporaries at 16 slots
+    of 8,192). Where its width is no whole number of lane tiles (the
+    rope keys, 64) the TPU stores it with the **positions on the
+    lanes**, which no scatter writes in place: the pass over the layer's
+    positions does, whatever the layout (17 MB a layer at 16 slots of
+    8,192; the program still copies that array whole once in and once
+    out a step, 0.2 GB and 0.37 ms each: ROADMAP M1).
     """
     T = rows.shape[1]
-    if by_position:
+    one_head = buf.ndim == 4
+    # a row's trailing axes, for what is indexed by [B, S_max] or [B, T]
+    each = (slice(None), slice(None)) + (None,) * (rows.ndim - 2)
+    if by_position or (one_head and buf.shape[3] % 128):
         at = jnp.arange(buf.shape[2], dtype=jnp.int32)[None, :] - jnp.clip(
             start, 0, buf.shape[2] - T
         )[:, None]                                    # [B, S_max] into rows
-        new = jnp.take_along_axis(
-            rows, jnp.clip(at, 0, T - 1)[:, :, None, None], axis=1
+        # one row a slot (a decode step) goes to its position as it is:
+        # a select against the broadcast row runs at the memory's rate,
+        # where the gather below took 0.57 ms a layer of [16, 8192, 64]
+        # (my chip run, PR 35: 6.8 of a decode step's 26 ms)
+        new = rows if T == 1 else jnp.take_along_axis(
+            rows, jnp.clip(at, 0, T - 1)[each], axis=1
         )
         old = lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
-        hit = ((at >= 0) & (at < T))[:, :, None, None]
+        hit = ((at >= 0) & (at < T))[each]
         return lax.dynamic_update_index_in_dim(
             buf, jnp.where(hit, new, old), layer, 0
         )
     index = jnp.stack([jnp.broadcast_to(layer, start.shape), start], axis=1)
     return lax.scatter(
-        buf, index, rows, _ROW_WRITE, unique_indices=True,
-        mode=lax.GatherScatterMode.CLIP,
+        buf, index, rows, _ROW_WRITE_1HEAD if one_head else _ROW_WRITE,
+        unique_indices=True, mode=lax.GatherScatterMode.CLIP,
     )
 
 
@@ -164,6 +202,29 @@ def init_params(
     cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16
 ) -> Params:
     """Random init with layer weights stacked on a leading [L] axis."""
+    if cfg.is_moe and cfg.first_k_dense:
+        # DeepSeek's heterogeneous stack is two homogeneous ones, each
+        # drawn at its own depth: a dense prefix (own MLP shapes) and the
+        # MoE remainder (forward scans them back to back). Drawn as one
+        # stack of L and cut in two, a stacked matrix is materialised in
+        # float32 before its slices (three times 7.9 GB for an expert
+        # matrix of A.X-K1's share, on a v5e, PR 35), where a leaf drawn
+        # whole fuses into one pass.
+        kd = cfg.first_k_dense
+        k_dense, k_rest = jax.random.split(key)
+        params = init_params(
+            dataclasses.replace(
+                cfg, num_layers=cfg.num_layers - kd, first_k_dense=0
+            ),
+            k_rest, dtype,
+        )
+        params["dense_layers"] = init_params(
+            dataclasses.replace(
+                cfg, num_layers=kd, first_k_dense=0, num_experts=0
+            ),
+            k_dense, dtype,
+        )["layers"]
+        return params
     d, f, L = cfg.hidden_size, cfg.intermediate_size, cfg.num_layers
     keys = iter(jax.random.split(key, 32))
 
@@ -179,9 +240,16 @@ def init_params(
                 next(keys), L, d, cfg.kv_lora_rank + cfg.qk_rope_head_dim
             ),
             "kv_a_norm": jnp.ones((L, cfg.kv_lora_rank), dtype),
-            "wkv_b": w(
+            # the checkpoint's kv_b_proj, its key and value columns
+            # apart (W_uk, W_uv): a step over the latent cache absorbs
+            # the one into the query and the other into the output
+            "wk_b": w(
                 next(keys), L, cfg.kv_lora_rank,
-                cfg.num_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim),
+                cfg.num_heads * cfg.qk_nope_head_dim,
+            ),
+            "wv_b": w(
+                next(keys), L, cfg.kv_lora_rank,
+                cfg.num_heads * cfg.v_head_dim,
             ),
             "wo": w(next(keys), L, cfg.num_heads * cfg.v_head_dim, d),
             "mlp_norm": jnp.ones((L, d), dtype),
@@ -225,10 +293,12 @@ def init_params(
         layers["post_mlp_norm"] = init((L, d), dtype)
     if cfg.is_moe:
         fm, E = cfg.moe_intermediate_size, cfg.num_experts
+        # the router scores every expert; weights exist for those held
+        Eh = cfg.num_held_experts
         layers["router"] = w(next(keys), L, d, E)
-        layers["we_gate"] = w(next(keys), L, E, d, fm)
-        layers["we_up"] = w(next(keys), L, E, d, fm)
-        layers["we_down"] = w(next(keys), L, E, fm, d, scale=1.0 / math.sqrt(fm))
+        layers["we_gate"] = w(next(keys), L, Eh, d, fm)
+        layers["we_up"] = w(next(keys), L, Eh, d, fm)
+        layers["we_down"] = w(next(keys), L, Eh, fm, d, scale=1.0 / math.sqrt(fm))
         if cfg.shared_expert_intermediate_size:
             fs = cfg.shared_expert_intermediate_size
             layers["ws_gate"] = w(next(keys), L, d, fs)
@@ -240,9 +310,9 @@ def init_params(
             # DeepSeek-V3 correction bias / GPT-OSS affine router
             layers["router_bias"] = jnp.zeros((L, E), jnp.float32)
         if cfg.moe_bias:
-            layers["we_gate_b"] = jnp.zeros((L, E, fm), dtype)
-            layers["we_up_b"] = jnp.zeros((L, E, fm), dtype)
-            layers["we_down_b"] = jnp.zeros((L, E, d), dtype)
+            layers["we_gate_b"] = jnp.zeros((L, Eh, fm), dtype)
+            layers["we_up_b"] = jnp.zeros((L, Eh, fm), dtype)
+            layers["we_down_b"] = jnp.zeros((L, Eh, d), dtype)
     else:
         layers["w_gate"] = w(next(keys), L, d, f)
         layers["w_up"] = w(next(keys), L, d, f)
@@ -255,23 +325,6 @@ def init_params(
             jnp.zeros if cfg.norm_delta_gain else jnp.ones
         )((d,), dtype),
     }
-    if cfg.is_moe and cfg.first_k_dense:
-        # split the stacked tree: a dense prefix stack (own MLP shapes)
-        # + the MoE remainder (forward scans them back-to-back)
-        kd = cfg.first_k_dense
-        moe_keys = (
-            "router", "we_gate", "we_up", "we_down",
-            "ws_gate", "ws_up", "ws_down", "shared_gate",
-            "router_bias", "we_gate_b", "we_up_b", "we_down_b",
-        )
-        dense: Dict[str, jax.Array] = {
-            k: v[:kd] for k, v in layers.items() if k not in moe_keys
-        }
-        dense["w_gate"] = w(next(keys), kd, d, f)
-        dense["w_up"] = w(next(keys), kd, d, f)
-        dense["w_down"] = w(next(keys), kd, f, d)
-        params["dense_layers"] = dense
-        params["layers"] = {k: v[kd:] for k, v in layers.items()}
     if not cfg.tie_word_embeddings:
         params["lm_head"] = w(next(keys), d, cfg.vocab_size)
     return params
@@ -487,6 +540,27 @@ def _attend(
     return out.reshape(b, t, -1)
 
 
+def _kept_groups(sel: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """``sel`` with the experts outside the token's ``topk_group`` best
+    groups set to 0, as the family's public ports do before the top-k
+    (``masked_fill(~score_mask, 0.0)``). A group scores the sum of its
+    two best experts under sigmoid scoring (DeepSeek-V3 ``noaux_tc``)
+    and its best expert under softmax scoring (DeepSeek-V2
+    ``group_limited_greedy``); ``lax.top_k`` breaks ties towards the
+    lower index, among groups as among experts."""
+    lead, E = sel.shape[:-1], sel.shape[-1]
+    grouped = sel.reshape(*lead, cfg.n_group, E // cfg.n_group)
+    if cfg.moe_scoring == "sigmoid":
+        group_score = jnp.sum(lax.top_k(grouped, 2)[0], axis=-1)
+    else:
+        group_score = jnp.max(grouped, axis=-1)
+    _, kept = lax.top_k(group_score, cfg.topk_group)
+    keep = jnp.any(
+        jax.nn.one_hot(kept, cfg.n_group, dtype=jnp.bool_), axis=-2
+    )
+    return jnp.where(keep[..., None], grouped, 0.0).reshape(sel.shape)
+
+
 def _route(
     x: jax.Array,           # [B, T, D]
     router_w: jax.Array,    # [D, E]
@@ -494,7 +568,9 @@ def _route(
     router_bias=None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Each token's ``num_experts_per_tok`` experts and their combine
-    weights: ``(top_idx int32 [B, T, k], top_w float32 [B, T, k])``."""
+    weights: ``(top_idx int32 [B, T, k], top_w float32 [B, T, k])``,
+    over all of the router's ``E`` experts, whichever of them this
+    replica holds."""
     # Router math in fp32: top-k selection must not flip on bf16 rounding
     # (which differs between sharded and unsharded contraction orders).
     logits = jnp.einsum(
@@ -507,6 +583,8 @@ def _route(
         # correction bias, the combine WEIGHTS use the raw scores
         scores = jax.nn.sigmoid(logits)
         sel = scores + (router_bias if router_bias is not None else 0.0)
+        if cfg.n_group > 1:
+            sel = _kept_groups(sel, cfg)
         _, top_idx = lax.top_k(sel, cfg.num_experts_per_tok)
         top_w = jnp.take_along_axis(scores, top_idx, axis=-1)
     elif cfg.moe_scoring == "softmax_topk":
@@ -517,6 +595,12 @@ def _route(
             logits = logits + router_bias.astype(jnp.float32)
         top_v, top_idx = lax.top_k(logits, cfg.num_experts_per_tok)
         top_w = jax.nn.softmax(top_v, axis=-1)
+    elif cfg.n_group > 1:
+        gates = jax.nn.softmax(logits, axis=-1)
+        _, top_idx = lax.top_k(
+            _kept_groups(gates, cfg), cfg.num_experts_per_tok
+        )
+        top_w = jnp.take_along_axis(gates, top_idx, axis=-1)
     else:
         gates = jax.nn.softmax(logits, axis=-1)
         top_w, top_idx = lax.top_k(gates, cfg.num_experts_per_tok)
@@ -542,9 +626,15 @@ def _experts_dense(x, top_idx, top_w, we_gate, we_up, we_down, cfg, biases):
     for, and no token moves: what GSPMD partitions over ``ep`` (each
     device its own experts for all tokens, the combine a psum) and what
     a decode step wants, whose few rows touch nearly every expert, so
-    that reading all the weights once is the whole cost either way."""
+    that reading all the weights once is the whole cost either way.
+    Under a share (``cfg.experts_held``) the one-hot is over the held
+    ids only: a pair on an absent expert matches none of them and adds
+    nothing."""
+    held_idx = (
+        top_idx - cfg.first_held_expert if cfg.first_held_expert else top_idx
+    )
     combine = jnp.sum(
-        jax.nn.one_hot(top_idx, cfg.num_experts, dtype=jnp.float32)
+        jax.nn.one_hot(held_idx, cfg.num_held_experts, dtype=jnp.float32)
         * top_w[..., None],
         axis=-2,
     ).astype(x.dtype)
@@ -561,32 +651,11 @@ def _experts_dense(x, top_idx, top_w, we_gate, we_up, we_down, cfg, biases):
     return jnp.einsum("bted,bte->btd", y, combine)
 
 
-def _experts_grouped(
-    x, top_idx, top_w, we_gate, we_up, we_down, cfg, biases, interpret,
-    layer=None,
-):
-    """Only the (token, expert) pairs the router chose, sorted by expert
-    (``ops/grouped_matmul.py``): the same three products as the dense
-    formulation, bf16 operands and float32 accumulation, each pair's row
-    against its own expert. A token's ``k`` results are weighed in
-    float32 and summed in the router's order, so the same input gives
-    the same bits. Every pair is computed, whatever the skew. With
-    ``layer`` the three weights are all the layers', as stored, and the
-    kernel reads that layer's blocks (``grouped_matmul``)."""
-    from gpustack_tpu.ops.grouped_matmul import (
-        BLOCK_M,
-        group_rows,
-        grouped_matmul,
-    )
-
-    B, T, D = x.shape
-    k = cfg.num_experts_per_tok
-    rows = group_rows(
-        top_idx.reshape(-1).astype(jnp.int32), cfg.num_experts
-    )
-    # a padding row computes the last token against its tile's expert;
-    # nothing reads the result
-    xs = jnp.take(x.reshape(B * T, D), rows.src // k, axis=0, mode="clip")
+def _grouped_products(xs, rows, we_gate, we_up, we_down, cfg, biases,
+                      interpret, layer):
+    """The three expert products over rows laid out by ``group_rows``:
+    ``[M, D] -> [M, D]``, each row against its tile's expert."""
+    from gpustack_tpu.ops.grouped_matmul import BLOCK_M, grouped_matmul
 
     def mm(a, w, bias):
         q, s = (w.q, w.s) if isinstance(w, QuantW) else (w, None)
@@ -603,11 +672,125 @@ def _experts_grouped(
 
     bg, bu, bd = biases if biases is not None else (None, None, None)
     h = _expert_act(mm(xs, we_gate, bg), mm(xs, we_up, bu), cfg)
-    y = jnp.take(mm(h, we_down, bd), rows.dest, axis=0, mode="clip")
+    return mm(h, we_down, bd)
+
+
+def _experts_grouped(
+    x, top_idx, top_w, we_gate, we_up, we_down, cfg, biases, interpret,
+    layer=None,
+):
+    """Only the (token, expert) pairs the router chose, sorted by expert
+    (``ops/grouped_matmul.py``): the same three products as the dense
+    formulation, bf16 operands and float32 accumulation, each pair's row
+    against its own expert. A token's ``k`` results are weighed in
+    float32 and summed in the router's order, so the same input gives
+    the same bits. Every pair is computed, whatever the skew. With
+    ``layer`` the three weights are all the layers', as stored, and the
+    kernel reads that layer's blocks (``grouped_matmul``)."""
+    from gpustack_tpu.ops.grouped_matmul import group_rows
+
+    B, T, D = x.shape
+    k = cfg.num_experts_per_tok
+    rows = group_rows(
+        top_idx.reshape(-1).astype(jnp.int32), cfg.num_experts
+    )
+    # a padding row computes the last token against its tile's expert;
+    # nothing reads the result
+    xs = jnp.take(x.reshape(B * T, D), rows.src // k, axis=0, mode="clip")
+    y = jnp.take(
+        _grouped_products(
+            xs, rows, we_gate, we_up, we_down, cfg, biases, interpret, layer
+        ),
+        rows.dest, axis=0, mode="clip",
+    )
     out = jnp.sum(
         y.reshape(B * T, k, D).astype(jnp.float32)
         * top_w.reshape(B * T, k, 1),
         axis=1,
+    )
+    return out.astype(x.dtype).reshape(B, T, D)
+
+
+def held_capacity(pairs: int, cfg: ModelConfig) -> int:
+    """Pairs one round of :func:`_experts_grouped_held` takes: twice what
+    an even router sends the held experts, in whole tiles. The work is
+    sized for what a share is expected to receive, not for the worst
+    routing (every pair on a held expert), whose rows would be sixteen
+    times the expected at 12 of 192."""
+    from gpustack_tpu.ops.grouped_matmul import BLOCK_M
+
+    even = pairs * cfg.num_held_experts / cfg.num_experts
+    return min(pairs, max(1, -(-int(2 * even) // BLOCK_M)) * BLOCK_M)
+
+
+def _experts_grouped_held(
+    x, top_idx, top_w, we_gate, we_up, we_down, cfg, biases, interpret,
+    layer=None,
+):
+    """:func:`_experts_grouped` under a share (``cfg.experts_held``): the
+    pairs on absent experts are dropped before the sort, and the held
+    ones, in the router's order, are taken ``held_capacity`` at a time,
+    as many rounds as the routing needs (one where it is at most twice
+    as heavy on the held experts as an even one): every held pair is
+    computed, whatever the skew, and nothing is sized for the worst. A
+    round lays its pairs out by expert, runs the three products, and
+    adds each token's results, weighed in float32, in the router's
+    order."""
+    from gpustack_tpu.ops.grouped_matmul import group_rows
+
+    B, T, D = x.shape
+    k, Eh = cfg.num_experts_per_tok, cfg.num_held_experts
+    R, P = B * T, B * T * k
+    C = held_capacity(P, cfg)
+    x2 = x.reshape(R, D)
+    expert = top_idx.reshape(P).astype(jnp.int32) - cfg.first_held_expert
+    held = (expert >= 0) & (expert < Eh)
+    # a held pair's place among the held pairs, in the router's order
+    place = jnp.cumsum(held, dtype=jnp.int32) - 1
+    n_held = place[-1] + 1
+    # the pairs with the held ones first, each kind in its own order
+    _, order = lax.sort_key_val(
+        jnp.logical_not(held).astype(jnp.int32),
+        jnp.arange(P, dtype=jnp.int32),
+    )
+    order = jnp.concatenate([order, jnp.full((C,), P - 1, jnp.int32)])
+    place = jnp.where(held, place, -1).reshape(R, k)
+    w = top_w.reshape(R, k).astype(jnp.float32)
+
+    def one_round(state):
+        r, out = state
+        first = r * C
+        pair = lax.dynamic_slice(order, (first,), (C,))
+        live = first + jnp.arange(C, dtype=jnp.int32) < n_held
+        # a slot past the last held pair joins no expert's group: it
+        # sorts behind them all and no tile of it is computed
+        rows = group_rows(
+            jnp.where(live, jnp.take(expert, pair), Eh), Eh
+        )
+        token = jnp.concatenate([pair // k, jnp.full((1,), R - 1, jnp.int32)])
+        xs = jnp.take(x2, jnp.take(token, rows.src), axis=0, mode="clip")
+        y = jnp.take(
+            _grouped_products(
+                xs, rows, we_gate, we_up, we_down, cfg, biases, interpret,
+                layer,
+            ),
+            rows.dest, axis=0, mode="clip",
+        )
+        # rows of tiles that were not computed hold whatever was there
+        y = jnp.where(live[:, None], y, jnp.zeros_like(y))
+        for j in range(k):
+            at = place[:, j] - first
+            mine = (at >= 0) & (at < C)
+            got = jnp.take(y, jnp.clip(at, 0, C - 1), axis=0)
+            out = out + jnp.where(
+                mine[:, None], got.astype(jnp.float32) * w[:, j:j + 1], 0.0
+            )
+        return r + 1, out
+
+    _, out = lax.while_loop(
+        lambda state: state[0] * C < n_held,
+        one_round,
+        (jnp.int32(0), jnp.zeros((R, D), jnp.float32)),
     )
     return out.astype(x.dtype).reshape(B, T, D)
 
@@ -625,7 +808,9 @@ def _moe_mlp(
     biases=None,            # (bg [E,Fm], bu [E,Fm], bd [E,D]) GPT-OSS
     dispatch: str = "dense",
     layer=None,             # grouped: we_* are [L, E, ...], this layer's
-) -> jax.Array:
+    count_held: bool = False,
+    routing_out: bool = False,
+):
     """Top-k mixture of experts: one router, one set of products, two ways
     to enumerate them (:func:`moe_dispatch` chooses).
 
@@ -645,7 +830,10 @@ def _moe_mlp(
             x, top_idx, top_w, we_gate, we_up, we_down, cfg, biases
         )
     else:
-        out = _experts_grouped(
+        grouped = (
+            _experts_grouped_held if cfg.experts_held else _experts_grouped
+        )
+        out = grouped(
             x, top_idx, top_w, we_gate, we_up, we_down, cfg, biases,
             interpret=dispatch == "grouped_interpret", layer=layer,
         )
@@ -666,7 +854,20 @@ def _moe_mlp(
                 _mm("btd,dg->btg", x, gate_w)
             )
         out = out + shared_out
-    return out
+    extras = ()
+    if count_held:
+        at = top_idx - cfg.first_held_expert
+        extras += (jnp.sum(
+            (at >= 0) & (at < cfg.num_held_experts), dtype=jnp.int32
+        ),)
+    if routing_out:
+        # the router's logits as _route computes them (the compiler
+        # merges the two), for a comparison with a reference
+        extras += ((top_idx, jnp.einsum(
+            "btd,de->bte", x.astype(jnp.float32),
+            router_w.astype(jnp.float32),
+        )),)
+    return (out, *extras) if extras else out
 
 
 # ---------------------------------------------------------------------------
@@ -707,10 +908,33 @@ def moe_dispatch(rows: int, cfg: ModelConfig, platform: str, mesh) -> str:
     for the TPU, as the flash kernel's).
     """
     one_chip = platform == "tpu" and (mesh is None or mesh.size == 1)
+    # pairs an expert, held or not, on the average: under a share the
+    # held experts get their part of the pairs, not all of them
     fills = (
         rows * cfg.num_experts_per_tok >= GROUPED_MIN_FILL * cfg.num_experts
     )
     return "grouped" if one_chip and fills else "dense"
+
+
+def mla_decode_attention_impl(
+    rows: int, max_len: int, platform: str, mesh
+) -> str:
+    """How a step of ``rows`` tokens a slot attends over the latent
+    cache in its absorbed form (``forward``'s MLA branch): the one place
+    that decides, from what ``forward`` can observe.
+
+    ``"kernel"`` (``ops/mla_attention.py``) for a decode step on one TPU
+    chip whose cache divides into the kernel's blocks: one token a slot,
+    each block of cached positions read once for all heads and only as
+    far as the slot's position. ``"xla"`` otherwise: a verify step or a
+    continuation (several rows a slot), a mesh of more than one device
+    (the kernel is not wrapped in a ``shard_map``), any other platform.
+    """
+    from gpustack_tpu.ops.mla_attention import block_positions
+
+    one_chip = platform == "tpu" and (mesh is None or mesh.size == 1)
+    fits = rows == 1 and block_positions(max_len) is not None
+    return "kernel" if one_chip and fits else "xla"
 
 
 def forward(
@@ -724,6 +948,9 @@ def forward(
     mesh=None,
     embeds_override: Optional[Tuple[jax.Array, jax.Array]] = None,
     moe_dispatch_impl: Optional[str] = None,
+    mla_decode_impl: Optional[str] = None,
+    count_held_pairs: bool = False,
+    routing_out: bool = False,
 ) -> Tuple[jax.Array, Optional[KVCache]]:
     """Run the model.
 
@@ -763,15 +990,35 @@ def forward(
     (``"grouped_interpret"``, as ``"flash_interpret"``) and a caller that
     differentiates (``"dense"``: the grouped kernel has no VJP).
 
-    Returns ``(logits [B, T, vocab] fp32, updated cache or None)``.
+    A latent-attention model (MLA) keeps the latent in the cache and
+    attends in one of two forms (``mla_attention`` below): decompressed
+    where the step's rows are all the keys (``attn_impl`` then chooses
+    the kernel as for any model), absorbed over cached rows, by
+    :func:`mla_decode_attention_impl` or ``mla_decode_impl``
+    (``"kernel_interpret"`` for the tests). A cache sharded over its
+    positions (``"ring"``) cannot yet carry a latent and is refused.
+
+    Returns ``(logits [B, T, vocab] fp32, updated cache or None)``, and
+    with ``count_held_pairs`` (a model served as one share of its
+    experts, ``cfg.experts_held``) a third: how many of the router's
+    ``B * T * k`` pairs a layer, over the layers with experts, fell on
+    experts held here (int32 scalar; the engine's
+    ``gpustack_engine_moe_pairs_total``). With ``routing_out`` a last
+    one more: ``(chosen int32 [L_moe, B, T, k], router logits float32
+    [L_moe, B, T, E])`` of the layers with experts, for a comparison
+    with a reference that must follow the program's choices
+    (``perfbench/reference_check.py``); no served program asks for it.
     """
     B, T = tokens.shape
+    platform = (
+        mesh.devices.flat[0].platform if mesh is not None
+        else jax.default_backend()
+    )
     if cfg.is_moe and moe_dispatch_impl is None:
-        moe_dispatch_impl = moe_dispatch(
-            B * T, cfg,
-            mesh.devices.flat[0].platform if mesh is not None
-            else jax.default_backend(),
-            mesh,
+        moe_dispatch_impl = moe_dispatch(B * T, cfg, platform, mesh)
+    if cfg.is_mla and cache is not None and mla_decode_impl is None:
+        mla_decode_impl = mla_decode_attention_impl(
+            T, cache.max_len, platform, mesh
         )
     dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
     x = _embed_lookup(params["embed"], tokens, dtype)
@@ -849,6 +1096,11 @@ def forward(
         )
     if use_ring and mesh is None:
         raise ValueError("attn_impl='ring' needs a mesh")
+    if use_ring and cfg.is_mla:
+        raise ValueError(
+            "attn_impl='ring': a cache sharded over its positions cannot "
+            "carry a latent (MLA) yet; serve this model with sp=1"
+        )
 
     # mask[b, t, s] — query t attends key s
     if cache is None:
@@ -889,8 +1141,128 @@ def forward(
         stacked = {k: layers[k] for k in ("we_gate", "we_up", "we_down")}
         layers = {k: v for k, v in layers.items() if k not in stacked}
 
+    def mla_attention(h, lp, carried, layer, mask_l):
+        """One layer of latent attention (DeepSeek-V2/V3 family) over the
+        latent cache: ``(attn [B, T, H * v_head_dim], carried)``.
+
+        The step's latent rows (``c_kv`` after its norm, the shared rope
+        key after its rotation) are written to the cache; nothing wider
+        is ever stored. Then one of two forms of the same attention:
+
+        - **decompressed**, where the step's own rows are every key
+          there is (no cache, or a prefill from position 0 into a cache
+          of the step's length): ``k_nope`` and ``v`` are made per head
+          from ``c_kv`` inside the program, compute-bound, and the flash
+          kernel takes the 192-wide keys and the 128-wide values as
+          they are;
+        - **absorbed**, over cached rows (decode, verify, a
+          continuation): ``W_uk`` goes into the query (``q' = q_nope
+          W_uk^T``, 128 -> 512 a head) and ``W_uv`` into the output, and
+          all heads attend over the latent as one shared key/value head
+          of width 576 / 512, so a cached position is read once for all
+          64 heads and never decompressed.
+        """
+        H = cfg.num_heads
+        nope, rope_d = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        rank, vd = cfg.kv_lora_rank, cfg.v_head_dim
+        if cfg.q_lora_rank:
+            q_c = rms_norm(
+                _mm("btd,dr->btr", h, lp["wq_a"]),
+                lp["q_a_norm"], cfg.rms_norm_eps, False,
+            )
+            q = _mm("btr,rq->btq", q_c, lp["wq_b"])
+        else:
+            q = _mm("btd,dq->btq", h, lp["wq"])
+        q = q.reshape(B, T, H, cfg.head_dim)
+        q_nope = q[..., :nope]
+        q_pe = apply_rope_interleaved(q[..., nope:], mla_sin, mla_cos)
+        kv_a = _mm("btd,dr->btr", h, lp["wkv_a"])
+        c_kv = rms_norm(
+            kv_a[..., :rank], lp["kv_a_norm"], cfg.rms_norm_eps, False
+        )
+        k_pe = apply_rope_interleaved(
+            kv_a[..., rank:][:, :, None, :], mla_sin, mla_cos
+        )                                               # [B, T, 1, rope]
+        if carried is not None:
+            # the cache rides the scan without its one head (below)
+            write = partial(_write_rows, layer=layer, start=positions[:, 0])
+            carried = KVCache(
+                k=write(carried.k, c_kv), v=write(carried.v, k_pe[:, :, 0])
+            )
+        if cache is None or (T > 1 and cache.max_len == T):
+            k_nope = _mm("btr,rq->btq", c_kv, lp["wk_b"])
+            v = _mm("btr,rq->btq", c_kv, lp["wv_b"]).reshape(B, T, H, vd)
+            k = jnp.concatenate(
+                [
+                    k_nope.reshape(B, T, H, nope),
+                    jnp.broadcast_to(k_pe, (B, T, H, rope_d)),
+                ],
+                axis=-1,
+            )
+            q = jnp.concatenate([q_nope, q_pe], axis=-1)
+            if use_flash:
+                from gpustack_tpu.ops.flash_attention import (
+                    flash_attention_prefill,
+                    sharded_flash_attention_prefill,
+                )
+
+                flash_kw = dict(
+                    interpret=attn_impl == "flash_interpret",
+                    q_offset=positions[0, 0],
+                )
+                if mesh is not None:
+                    attn = sharded_flash_attention_prefill(
+                        mesh, q, k, v, scale, **flash_kw
+                    )
+                else:
+                    attn = flash_attention_prefill(
+                        q, k, v, scale, **flash_kw
+                    )
+            else:
+                attn = _attend(q[:, :, :, None, :], k, v, mask_l, scale)
+            return attn, carried
+
+        # absorbed, over this layer of the cache. An int8 weight's
+        # scales are per output channel of kv_b_proj, (head, nope) or
+        # (head, v): absorbing W_uk contracts over nope, so its scales
+        # go onto the query first; W_uv's multiply the output.
+        wk, wv = lp["wk_b"], lp["wv_b"]
+        if isinstance(wk, QuantW):
+            q_nope = q_nope * wk.s.reshape(H, nope).astype(q_nope.dtype)
+            wk = wk.q.astype(q_nope.dtype)
+        q_lat = jnp.einsum(
+            "bthn,rhn->bthr", q_nope, wk.reshape(rank, H, nope)
+        )
+        if mla_decode_impl == "xla":
+            c_all, r_all = (
+                lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
+                for buf in (carried.k, carried.v)
+            )                                   # [B, S, rank], [B, S, rope]
+            scores = (
+                jnp.einsum("bthr,bsr->bhts", q_lat, c_all)
+                + jnp.einsum("bthe,bse->bhts", q_pe, r_all)
+            ).astype(jnp.float32) * scale
+            scores = jnp.where(mask_l[:, None, :, :], scores, -1e30)
+            weights = jax.nn.softmax(scores, axis=-1).astype(q_lat.dtype)
+            u = jnp.einsum("bhts,bsr->bthr", weights, c_all)
+        else:
+            from gpustack_tpu.ops.mla_attention import mla_decode_attention
+
+            u = mla_decode_attention(
+                q_lat[:, 0], q_pe[:, 0], carried.k, carried.v, layer,
+                positions[:, 0], scale,
+                interpret=mla_decode_impl == "kernel_interpret",
+            )[:, None]
+        if isinstance(wv, QuantW):
+            attn = jnp.einsum(
+                "bthr,rhv->bthv", u, wv.q.astype(u.dtype).reshape(rank, H, vd)
+            ) * wv.s.reshape(H, vd).astype(u.dtype)
+        else:
+            attn = jnp.einsum("bthr,rhv->bthv", u, wv.reshape(rank, H, vd))
+        return attn.reshape(B, T, H * vd), carried
+
     def block(carry, scanned, moe_layer: bool):
-        x_in, carried, layer = carry
+        x_in, carried, layer, *held_pairs = carry
         lp, slide_flag = scanned
         lp = {**lp, **stacked}
         if hetero:
@@ -903,53 +1275,7 @@ def forward(
             x_in, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_delta_gain
         )
         if cfg.is_mla:
-            # DeepSeek MLA, served decompressed: latent down-projections
-            # + per-head up-projections materialize full K/V (head_dim =
-            # qk_nope + qk_rope); v (v_head_dim wide) zero-pads to
-            # head_dim so one cache layout serves every family.
-            nope, rope_d = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-            if cfg.q_lora_rank:
-                q_c = rms_norm(
-                    _mm("btd,dr->btr", h, lp["wq_a"]),
-                    lp["q_a_norm"], cfg.rms_norm_eps, False,
-                )
-                q = _mm("btr,rq->btq", q_c, lp["wq_b"])
-            else:
-                q = _mm("btd,dq->btq", h, lp["wq"])
-            q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)
-            q_nope, q_pe = q[..., :nope], q[..., nope:]
-            kv_a = _mm("btd,dr->btr", h, lp["wkv_a"])
-            c_kv = kv_a[..., : cfg.kv_lora_rank]
-            k_pe = kv_a[..., cfg.kv_lora_rank:]
-            c_kv = rms_norm(
-                c_kv, lp["kv_a_norm"], cfg.rms_norm_eps, False
-            )
-            kv = _mm("btr,rq->btq", c_kv, lp["wkv_b"]).reshape(
-                B, T, cfg.num_heads, nope + cfg.v_head_dim
-            )
-            k_nope, v_small = kv[..., :nope], kv[..., nope:]
-            q_pe = apply_rope_interleaved(q_pe, mla_sin, mla_cos)
-            k_pe = apply_rope_interleaved(
-                k_pe[:, :, None, :], mla_sin, mla_cos
-            )
-            k_pe = jnp.broadcast_to(
-                k_pe, (B, T, cfg.num_heads, rope_d)
-            )
-            k = jnp.concatenate([k_nope, k_pe], axis=-1)
-            v = jnp.concatenate(
-                [
-                    v_small,
-                    jnp.zeros(
-                        (B, T, cfg.num_heads,
-                         cfg.head_dim - cfg.v_head_dim),
-                        v_small.dtype,
-                    ),
-                ],
-                axis=-1,
-            )
-            q = jnp.concatenate([q_nope, q_pe], axis=-1).reshape(
-                B, T, cfg.num_kv_heads, cfg.group_size, cfg.head_dim
-            )
+            attn, carried = mla_attention(h, lp, carried, layer, mask_l)
         else:
             q = _mm("btd,dq->btq", h, lp["wq"])
             k = _mm("btd,dk->btk", h, lp["wk"])
@@ -974,92 +1300,84 @@ def forward(
             )
             k = apply_rope(k, sin_b, cos_b)
 
-        sinks_l = (
-            lp["sinks"].reshape(cfg.num_kv_heads, cfg.group_size)
-            if cfg.attn_sinks else None
-        )
-        if cache is None:
-            attn = _attend(
-                q, k, v, mask_l, scale, cfg.attn_logit_softcap,
-                sinks=sinks_l,
+            sinks_l = (
+                lp["sinks"].reshape(cfg.num_kv_heads, cfg.group_size)
+                if cfg.attn_sinks else None
             )
-        else:
-            # Write this step's rows into the carried cache, in place,
-            # and attend over this layer of it.
-            write = partial(
-                _write_rows, layer=layer, start=positions[:, 0],
-                by_position=use_ring and T > 1,
-            )
-            carried = KVCache(
-                k=write(carried.k, k), v=write(carried.v, v)
-            )
-            new_k, new_v = (
-                lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
-                for buf in (carried.k, carried.v)
-            )
-            if use_ring:
-                from gpustack_tpu.ops.ring_attention import (
-                    sharded_prefill_attention,
-                    sp_cache_attention,
-                )
-
-                if T > 1 and cache.max_len == T:
-                    # prefill-from-zero: ring attention over the
-                    # sp-sharded step K/V (== the whole written cache)
-                    attn = sharded_prefill_attention(
-                        mesh, q, k, v, positions, scale
-                    )
-                else:
-                    # decode / verify: exact attention over the
-                    # sp-sharded resident cache
-                    attn = sp_cache_attention(
-                        mesh, q, new_k, new_v, positions, scale
-                    )
-            elif use_flash:
-                # prefill (from zero or from a chunk/prefix offset):
-                # q rows sit at positions offset..offset+T-1 against the
-                # freshly written cache; the kernel's q_offset shifts the
-                # causal diagonal (all batch rows share one offset — the
-                # engine's prefill paths are B=1; pad keys masked via
-                # seq_k, pad/garbage cache rows above the last query
-                # position are causally invisible)
-                from gpustack_tpu.ops.flash_attention import (
-                    flash_attention_prefill,
-                    sharded_flash_attention_prefill,
-                )
-
-                flash_args = (
-                    q.reshape(B, T, cfg.num_heads, cfg.head_dim),
-                    new_k,
-                    new_v,
-                    scale,
-                )
-                flash_kw = dict(
-                    interpret=attn_impl == "flash_interpret",
-                    q_offset=positions[0, 0],
-                )
-                if mesh is not None:
-                    # under tp the kernel runs per shard of heads
-                    attn = sharded_flash_attention_prefill(
-                        mesh, *flash_args, **flash_kw
-                    )
-                else:
-                    attn = flash_attention_prefill(*flash_args, **flash_kw)
-            else:
+            if cache is None:
                 attn = _attend(
-                    q, new_k, new_v, mask_l, scale,
-                    cfg.attn_logit_softcap,
+                    q, k, v, mask_l, scale, cfg.attn_logit_softcap,
                     sinks=sinks_l,
                 )
+            else:
+                # Write this step's rows into the carried cache, in place,
+                # and attend over this layer of it.
+                write = partial(
+                    _write_rows, layer=layer, start=positions[:, 0],
+                    by_position=use_ring and T > 1,
+                )
+                carried = KVCache(
+                    k=write(carried.k, k), v=write(carried.v, v)
+                )
+                new_k, new_v = (
+                    lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
+                    for buf in (carried.k, carried.v)
+                )
+                if use_ring:
+                    from gpustack_tpu.ops.ring_attention import (
+                        sharded_prefill_attention,
+                        sp_cache_attention,
+                    )
 
-        if cfg.is_mla:
-            # drop the zero-padded v tail before o_proj (which expects
-            # num_heads * v_head_dim inputs)
-            attn = attn.reshape(
-                B, T, cfg.num_heads, cfg.head_dim
-            )[..., : cfg.v_head_dim].reshape(
-                B, T, cfg.num_heads * cfg.v_head_dim
-            )
+                    if T > 1 and cache.max_len == T:
+                        # prefill-from-zero: ring attention over the
+                        # sp-sharded step K/V (== the whole written cache)
+                        attn = sharded_prefill_attention(
+                            mesh, q, k, v, positions, scale
+                        )
+                    else:
+                        # decode / verify: exact attention over the
+                        # sp-sharded resident cache
+                        attn = sp_cache_attention(
+                            mesh, q, new_k, new_v, positions, scale
+                        )
+                elif use_flash:
+                    # prefill (from zero or from a chunk/prefix offset):
+                    # q rows sit at positions offset..offset+T-1 against the
+                    # freshly written cache; the kernel's q_offset shifts the
+                    # causal diagonal (all batch rows share one offset — the
+                    # engine's prefill paths are B=1; pad keys masked via
+                    # seq_k, pad/garbage cache rows above the last query
+                    # position are causally invisible)
+                    from gpustack_tpu.ops.flash_attention import (
+                        flash_attention_prefill,
+                        sharded_flash_attention_prefill,
+                    )
+
+                    flash_args = (
+                        q.reshape(B, T, cfg.num_heads, cfg.head_dim),
+                        new_k,
+                        new_v,
+                        scale,
+                    )
+                    flash_kw = dict(
+                        interpret=attn_impl == "flash_interpret",
+                        q_offset=positions[0, 0],
+                    )
+                    if mesh is not None:
+                        # under tp the kernel runs per shard of heads
+                        attn = sharded_flash_attention_prefill(
+                            mesh, *flash_args, **flash_kw
+                        )
+                    else:
+                        attn = flash_attention_prefill(*flash_args, **flash_kw)
+                else:
+                    attn = _attend(
+                        q, new_k, new_v, mask_l, scale,
+                        cfg.attn_logit_softcap,
+                        sinks=sinks_l,
+                    )
+
         attn_out = _mm("btq,qd->btd", attn, lp["wo"])
         if cfg.o_bias:
             attn_out = attn_out + lp["bo"]
@@ -1073,6 +1391,7 @@ def forward(
         h2 = rms_norm(
             x_mid, lp["mlp_norm"], cfg.rms_norm_eps, cfg.norm_delta_gain
         )
+        routing = None
         if moe_layer:
             mlp = _moe_mlp(
                 h2, lp["router"], lp["we_gate"], lp["we_up"], lp["we_down"],
@@ -1091,7 +1410,15 @@ def forward(
                 ),
                 dispatch=moe_dispatch_impl,
                 layer=layer - kd if stacked else None,
+                count_held=count_held_pairs,
+                routing_out=routing_out,
             )
+            if count_held_pairs or routing_out:
+                mlp, *extras = mlp
+                if count_held_pairs:
+                    held_pairs = [held_pairs[0] + extras.pop(0)]
+                if routing_out:
+                    (routing,) = extras
         else:
             g = _mm("btd,df->btf", h2, lp["w_gate"])
             u = _mm("btd,df->btf", h2, lp["w_up"])
@@ -1101,7 +1428,7 @@ def forward(
                 mlp, lp["post_mlp_norm"], cfg.rms_norm_eps,
                 cfg.norm_delta_gain,
             )
-        return (x_mid + mlp, carried, layer + 1), None
+        return (x_mid + mlp, carried, layer + 1, *held_pairs), routing
 
     # DeepSeek ships heterogeneous stacks: the first first_k_dense
     # layers use a dense MLP, the rest MoE — structurally different
@@ -1112,23 +1439,35 @@ def forward(
         len(next(iter(params["dense_layers"].values())))
         if "dense_layers" in params else 0
     )
+    if cfg.is_mla and cache is not None:
+        # an MLA cache has one head: inside the scan it is the same
+        # arrays without it, as the TPU stores them (``_write_rows``)
+        cache = KVCache(k=cache.k[:, :, :, 0, :], v=cache.v[:, :, :, 0, :])
     carry = (x, cache, jnp.int32(0))
+    if count_held_pairs:
+        carry += (jnp.int32(0),)
     if kd:
         carry, _ = lax.scan(
             partial(block, moe_layer=False),
             carry, (params["dense_layers"], slide_flags[:kd]),
         )
-    (x, new_cache, _), _ = lax.scan(
+    (x, new_cache, _, *held_pairs), routing = lax.scan(
         partial(block, moe_layer=cfg.is_moe),
         carry, (layers, slide_flags[kd:]),
     )
+    if routing_out:
+        held_pairs = [*held_pairs, routing]
 
+    if cfg.is_mla and new_cache is not None:
+        new_cache = KVCache(
+            k=new_cache.k[:, :, :, None, :], v=new_cache.v[:, :, :, None, :]
+        )
     x = rms_norm(
         x, params["final_norm"], cfg.rms_norm_eps, cfg.norm_delta_gain
     )
     if return_hidden:
         # embeddings path: final normalized hidden states, no LM head
-        return x.astype(jnp.float32), new_cache
+        return (x.astype(jnp.float32), new_cache, *held_pairs)
     if cfg.tie_word_embeddings:
         logits = jnp.einsum("btd,vd->btv", x, params["embed"])
     else:
@@ -1137,4 +1476,4 @@ def forward(
     if cfg.final_logit_softcap:
         cap = cfg.final_logit_softcap
         logits = cap * jnp.tanh(logits / cap)
-    return logits, new_cache
+    return (logits, new_cache, *held_pairs)

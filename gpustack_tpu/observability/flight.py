@@ -53,6 +53,11 @@ Record vocabulary (per step):
   with experts: prompt tokens by the dispatch the program was traced
   with (``{"grouped": n}`` / ``{"dense": n}``,
   ``models/transformer.py moe_dispatch``). Absent otherwise.
+- ``attn`` — for a latent-attention (MLA) model, the form of attention
+  the step ran: ``mla_flash`` / ``mla_xla`` in a step that ran a
+  prefill program (decompressed, by the bucket's kernel),
+  ``mla_absorbed`` in a decode or verify step (over the latent cache).
+  Absent for any other model.
 
 Cumulative (not per-record): ``idle_wait_s_total`` — seconds the
 scheduler parked on its wakeup condition instead of busy-polling (the
@@ -363,6 +368,7 @@ class FlightRecorder:
         admitted: Sequence = (),
         first_tokens: Sequence = (),
         moe_dispatch: Optional[Dict[str, int]] = None,   # empty as None
+        attn: Optional[str] = None,
     ) -> None:
         t0 = time.perf_counter()
         with self._mu:
@@ -373,7 +379,7 @@ class FlightRecorder:
                 oldest_wait_s, tokens_real, tokens_padded, tokens_out,
                 prompt_tokens, spec_proposed, spec_accepted, kv_blocks,
                 kv_reused_total, host_overlap_s, phases_s, admitted,
-                first_tokens, traced, compiled, moe_dispatch,
+                first_tokens, traced, compiled, moe_dispatch, attn,
             ))
             h = self._hist.get(mode)
             if h is None:
@@ -439,7 +445,7 @@ class FlightRecorder:
          tokens_real, tokens_padded, tokens_out, prompt_tokens,
          spec_proposed, spec_accepted, kv_blocks, kv_reused_total,
          host_overlap_s, phases_s, admitted, first_tokens, traced,
-         compiled, moe_dispatch) = row
+         compiled, moe_dispatch, attn) = row
         entry = {
             "ts": ts,
             "dur_ms": round(dur_s * 1e3, 4),
@@ -471,6 +477,8 @@ class FlightRecorder:
         }
         if moe_dispatch:
             entry["moe_dispatch"] = dict(moe_dispatch)
+        if attn:
+            entry["attn"] = attn
         return entry
 
     # ---- read side -----------------------------------------------------

@@ -72,6 +72,15 @@ METRIC_FAMILIES = {
     # prompt tokens by the expert dispatch their prefill program was
     # traced with (label dispatch=grouped|dense); absent for a dense model
     "gpustack_engine_moe_prompt_tokens_total": "counter",
+    # a replica that holds a share of its model's experts
+    # (ModelConfig.experts_held): the router's (token, expert) pairs of
+    # its prefill programs, bucket padding included, by whether the
+    # pair's expert is held here (label held=yes|no); absent otherwise
+    "gpustack_engine_moe_pairs_total": "counter",
+    # the device's KV cache: its bytes, and the bytes one position of
+    # one layer takes (1,152 for an MLA latent of 512 + 64 in bf16)
+    "gpustack_engine_kv_cache_bytes": "gauge",
+    "gpustack_engine_kv_cache_bytes_per_token": "gauge",
     "gpustack_engine_occupancy_ratio": "gauge",
     "gpustack_engine_queue_oldest_wait_seconds": "gauge",
     "gpustack_engine_queue_depth": "gauge",

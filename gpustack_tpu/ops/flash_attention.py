@@ -124,7 +124,8 @@ def tiles_of(block_q: int, block_k: int, G: int) -> Tiles:
 
 def _vmem_bytes(tiles: Tiles, G: int, d: int, itemsize: int) -> int:
     """What a grid point holds, roughly: the chip's compiler has the last
-    word (tests/ops/test_chip_compile.py at the cells' shapes)."""
+    word (tests/ops/test_chip_compile.py at the cells' shapes). ``d`` is
+    the wider of the two head widths where keys and values differ."""
     rows, sub_rows = G * tiles.block_q, G * tiles.sub_q
     blocks = 2 * (2 * rows * d + 2 * tiles.block_k * d) * itemsize
     scratch = rows * (2 * _LANES + d) * 4
@@ -177,7 +178,9 @@ def _flash_kernel(
     against a cache of offset+T keys.
     """
     block_q, sub_q, block_k, unroll = tiles
-    G, d = q_ref.shape[1], q_ref.shape[3]
+    # d: the width of a query or key head; dv: of a value head, and of
+    # the result (latent attention decompresses to 192 / 128)
+    G, d, dv = q_ref.shape[1], q_ref.shape[3], v_ref.shape[3]
     rows = G * sub_q
     n_sub_k = block_k // SUB_K
     qb = pl.program_id(2)
@@ -222,7 +225,7 @@ def _flash_kernel(
         corr = jnp.where(m_prev <= _NEG / 2, 0.0, jnp.exp(m_prev - m_new))
         l_ref[qs] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
         m_ref[qs] = m_new
-        acc_ref[qs] = acc_ref[qs] * _across(corr, d) + lax.dot_general(
+        acc_ref[qs] = acc_ref[qs] * _across(corr, dv) + lax.dot_general(
             p, v,
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -267,16 +270,16 @@ def _flash_kernel(
     @pl.when(kb == pl.num_programs(3) - 1)
     def _finish():
         for qs in range(block_q // sub_q):
-            o = acc_ref[qs] / _across(jnp.maximum(l_ref[qs], 1e-30), d)
+            o = acc_ref[qs] / _across(jnp.maximum(l_ref[qs], 1e-30), dv)
             o_ref[0, :, pl.ds(qs * sub_q, sub_q), :] = o.reshape(
-                G, sub_q, d
+                G, sub_q, dv
             ).astype(o_ref.dtype)
 
 
 def flash_call(
     qt: jax.Array,      # [B, Hq, T_pad, d], head-major, rows padded to 128
     kt: jax.Array,      # [B, Hkv, S_pad, d]
-    vt: jax.Array,
+    vt: jax.Array,      # [B, Hkv, S_pad, dv]
     off: jax.Array,     # int32[1]: the position of q row 0
     *, scale: float, seq_k: int, interpret: bool = False,
     _blocks: tuple[int, int] | None = None,
@@ -287,12 +290,14 @@ def flash_call(
     ``_blocks`` (``block_q``, ``block_k``) is for that timer and the
     tests, which go through every tile; nothing that serves passes it,
     and the shapes choose (:func:`choose_tiles`). Returns
-    [B, Hq, T_pad, d] in q's dtype."""
+    [B, Hq, T_pad, dv] in q's dtype."""
     B, Hq, T_pad, d = qt.shape
-    Hkv, S_pad = kt.shape[1], kt.shape[2]
+    Hkv, S_pad, dv = kt.shape[1], kt.shape[2], vt.shape[3]
     G = Hq // Hkv
     if _blocks is None:
-        tiles = choose_tiles(T_pad, S_pad, G, d, qt.dtype.itemsize)
+        tiles = choose_tiles(
+            T_pad, S_pad, G, max(d, dv), qt.dtype.itemsize
+        )
     else:
         tiles = tiles_of(*_blocks, G)
     block_q, sub_q, block_k, _ = tiles
@@ -313,20 +318,20 @@ def flash_call(
         functools.partial(
             _flash_kernel, scale=scale, seq_k=seq_k, tiles=tiles
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, T_pad, d), qt.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, T_pad, dv), qt.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, Hkv, T_pad // block_q, n_kb),
             in_specs=[
                 pl.BlockSpec((1, G, block_q, d), q_block),
                 pl.BlockSpec((1, 1, block_k, d), kv_block),
-                pl.BlockSpec((1, 1, block_k, d), kv_block),
+                pl.BlockSpec((1, 1, block_k, dv), kv_block),
             ],
-            out_specs=pl.BlockSpec((1, G, block_q, d), q_block),
+            out_specs=pl.BlockSpec((1, G, block_q, dv), q_block),
             scratch_shapes=[
                 pltpu.VMEM((n_qs, G * sub_q, _LANES), jnp.float32),  # max
                 pltpu.VMEM((n_qs, G * sub_q, _LANES), jnp.float32),  # sum
-                pltpu.VMEM((n_qs, G * sub_q, d), jnp.float32),       # acc
+                pltpu.VMEM((n_qs, G * sub_q, dv), jnp.float32),      # acc
             ],
         ),
         compiler_params=pltpu.CompilerParams(
@@ -342,7 +347,7 @@ def flash_call(
 def flash_attention_prefill(
     q: jax.Array,       # [B, T, Hq, d]
     k: jax.Array,       # [B, S, Hkv, d]
-    v: jax.Array,       # [B, S, Hkv, d]
+    v: jax.Array,       # [B, S, Hkv, dv]
     scale: float,
     interpret: bool = False,
     q_offset=0,
@@ -351,9 +356,11 @@ def flash_attention_prefill(
     against k positions 0..S-1, with keys at index >= S masked via
     ``seq_k``). ``q_offset`` (traced scalar) supports chunked-prefill
     continuation: every batch row shares the one offset. Returns
-    [B, T, Hq*d]. T and S are padded to multiples of 128 internally; any
-    sequence length fits (VMEM use is O(block)); the shapes choose the
-    tiles (:func:`choose_tiles`)."""
+    [B, T, Hq*dv]: the values may be narrower than the keys (latent
+    attention decompresses to keys of 192 and values of 128), nothing is
+    padded to make them alike. T and S are padded to multiples of 128
+    internally; any sequence length fits (VMEM use is O(block)); the
+    shapes choose the tiles (:func:`choose_tiles`)."""
     B, T, Hq, d = q.shape
     S, Hkv = k.shape[1], k.shape[2]
     if Hq % Hkv != 0:
@@ -375,8 +382,8 @@ def flash_attention_prefill(
         qt, kt, vt, jnp.asarray(q_offset, jnp.int32).reshape(1),
         scale=scale, seq_k=S, interpret=interpret,
     )
-    out = jnp.transpose(out[:, :, :T, :], (0, 2, 1, 3))  # [B, T, Hq, d]
-    return out.reshape(B, T, Hq * d)
+    out = jnp.transpose(out[:, :, :T, :], (0, 2, 1, 3))  # [B, T, Hq, dv]
+    return out.reshape(B, T, Hq * v.shape[3])
 
 
 def sharded_flash_attention_prefill(

@@ -1,0 +1,193 @@
+"""Pallas TPU kernel for a decode step's attention over the latent cache.
+
+Latent attention (MLA, DeepSeek-V2/V3 family) caches, for each position
+of a layer, the shared latent ``c_kv`` (``kv_lora_rank`` wide) and the
+shared rope key (``qk_rope_head_dim`` wide). In the absorbed form every
+query head attends over those as over one key/value head: the score of a
+head is ``q_lat . c_kv + q_pe . k_r`` (``q_lat`` the head's ``nope`` part
+through ``W_uk``) and its result the softmax-weighted sum of the
+``c_kv``, which ``W_uv`` then takes to the head's value width
+(``models/transformer.py``, ``mla_attention``).
+
+One grid point holds all ``H`` query heads of one slot against one block
+of that slot's cached positions: the block is fetched once for all heads
+(at 64 heads about 128 operations a byte, near a v5e's ridge), the
+running max / sum / accumulator persist in VMEM across the sweep over
+the blocks (online softmax, float32), and the ``[H, S]`` score matrix
+never exists in HBM. A slot's live length bounds its work two ways, both
+from its position (a scalar-prefetch operand): a block wholly above it
+is never fetched (the index map names the last needed block again, and a
+point that names the block already resident copies nothing) and never
+computed.
+
+Both caches are passed with all their layers, as they are stored, and the
+layer's index prefetched: a custom call cannot read the scan's slice in
+place as a fusion can, and a sliced operand would be copied whole before
+every call (134 MB a layer at 16 slots of 8,192: PERF.md section 6,
+PR 34 met the same with the experts' weights). "As they are stored" is
+the TPU's choice, not the logical shape's: the latent, 512 wide, lies
+with the positions next to the width; the rope keys, 64 wide, lie with
+the **positions on the lanes**, so the view ``[L, B, rope, S]`` taken
+here is free and the score product
+over them a plain matmul (``tests/ops/test_chip_compile.py`` holds the
+decode program to having no copy of either cache).
+
+``tests/ops/test_mla_attention.py`` holds it to the XLA formulation in
+interpret mode; ``tests/ops/test_chip_compile.py`` compiles it for a
+described v5e at A.X-K1's widths.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_LANES = 128
+_NEG = -1e30
+# cached positions a grid point: 1,024 x 576 values are 1.2 MB in bf16,
+# twice for the two buffers and 2.4 MB more as float32 operands, well
+# inside the 16 MiB a kernel is given; a grid point costs about 0.3 us
+# whatever it does, so the largest block that divides the cache
+_BLOCKS = (1024, 512, 256, 128)
+
+
+def block_positions(max_len: int) -> Optional[int]:
+    """The block of cached positions for a cache of ``max_len``: the
+    largest of ``_BLOCKS`` that divides it; a cache shorter than the
+    smallest is one block (the tests'); None where nothing divides, and
+    the caller takes the XLA formulation."""
+    for b in _BLOCKS:
+        if max_len % b == 0:
+            return b
+    if max_len < _BLOCKS[-1] and max_len % 8 == 0:
+        return max_len
+    return None
+
+
+def _across(x, n: int):
+    """``x`` [rows, 128], every lane of a row alike, as [rows, n]."""
+    return jnp.tile(x, (1, -(-n // _LANES)))[:, :n]
+
+
+def _kernel(
+    pos_ref, layer_ref, ql_ref, qp_ref, c_ref, r_ref, o_ref,
+    m_ref, l_ref, acc_ref, *, scale: float, block_s: int,
+):
+    """Grid point = (slot, block of cached positions): the slot's ``H``
+    heads against ``block_s`` positions of its latent rows."""
+    del layer_ref
+    b, j = pl.program_id(0), pl.program_id(1)
+    rank = ql_ref.shape[1]
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    pos = pos_ref[b]
+
+    @pl.when(j * block_s <= pos)
+    def _block():
+        c = c_ref[...].astype(jnp.float32)                # [block_s, rank]
+        s = lax.dot_general(
+            ql_ref[...].astype(jnp.float32), c,
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) + jnp.dot(
+            qp_ref[...].astype(jnp.float32),
+            r_ref[...].astype(jnp.float32),               # [rope, block_s]
+            preferred_element_type=jnp.float32,
+        )                                                 # [H, block_s]
+        k_idx = j * block_s + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(k_idx <= pos, s * scale, _NEG)
+        # the running max and sum stay broadcast over 128 lanes (as in
+        # ops/flash_attention.py: a [rows, 1] column costs a register
+        # for every eight rows)
+        m_prev, l_prev = m_ref[...], l_ref[...]           # [H, 128]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _across(m_new, block_s))
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = m_new
+        acc_ref[...] = acc_ref[...] * _across(corr, rank) + jnp.dot(
+            p, c, preferred_element_type=jnp.float32
+        )
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finish():
+        o_ref[...] = (
+            acc_ref[...] / _across(jnp.maximum(l_ref[...], 1e-30), rank)
+        ).astype(o_ref.dtype)
+
+
+def mla_decode_attention(
+    q_lat: jax.Array,     # [B, H, rank]: q_nope through W_uk
+    q_pe: jax.Array,      # [B, H, rope], rotated
+    c_cache: jax.Array,   # [L, B, S, rank]: KVCache.k (MLA), without
+    r_cache: jax.Array,   # [L, B, S, rope]: KVCache.v      its one head
+    layer: jax.Array,     # int32 scalar: which of the L
+    positions: jax.Array,  # int32 [B]: each slot's query position
+    scale: float,
+    *,
+    interpret: bool = False,
+) -> jax.Array:
+    """``softmax((q_lat . c + q_pe . k_r) * scale) @ c`` over positions
+    ``0 .. positions[b]`` of slot ``b``'s rows in layer ``layer``:
+    ``[B, H, rank]`` in ``q_lat``'s dtype, to go through ``W_uv``."""
+    B, H, rank = q_lat.shape
+    L, _, S, rope = r_cache.shape
+    block_s = block_positions(S)
+    if block_s is None:
+        raise ValueError(f"no block of {_BLOCKS} divides a cache of {S}")
+    n_blocks = S // block_s
+
+    def last(b, j, pos_ref):
+        # the last block that holds a position the slot attends: a point
+        # past it names that block again and nothing is copied
+        return jnp.minimum(j, pos_ref[b] // block_s)
+
+    def q_block(b, j, pos_ref, layer_ref):
+        return (b, 0, 0)
+
+    def c_block(b, j, pos_ref, layer_ref):
+        return (layer_ref[0], b, last(b, j, pos_ref), 0)
+
+    def r_block(b, j, pos_ref, layer_ref):
+        return (layer_ref[0], b, 0, last(b, j, pos_ref))
+
+    return pl.pallas_call(
+        functools.partial(_kernel, scale=scale, block_s=block_s),
+        out_shape=jax.ShapeDtypeStruct((B, H, rank), q_lat.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, n_blocks),
+            in_specs=[
+                pl.BlockSpec((None, H, rank), q_block),
+                pl.BlockSpec((None, H, rope), q_block),
+                pl.BlockSpec((None, None, block_s, rank), c_block),
+                pl.BlockSpec((None, None, rope, block_s), r_block),
+            ],
+            out_specs=pl.BlockSpec((None, H, rank), q_block),
+            scratch_shapes=[
+                pltpu.VMEM((H, _LANES), jnp.float32),     # running max
+                pltpu.VMEM((H, _LANES), jnp.float32),     # running sum
+                pltpu.VMEM((H, rank), jnp.float32),       # accumulator
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        ),
+        name="mla_decode_attention",
+        interpret=interpret,
+    )(
+        jnp.clip(positions, 0, S - 1).astype(jnp.int32),
+        jnp.reshape(layer, (1,)).astype(jnp.int32),
+        q_lat, q_pe, c_cache, jnp.transpose(r_cache, (0, 1, 3, 2)),
+    )

@@ -98,7 +98,8 @@ class SpecLayout:
             "wq_b": P(None, None, tp),
             "wkv_a": P(None, fsdp, None),
             "kv_a_norm": P(None, None),
-            "wkv_b": P(None, None, tp),
+            "wk_b": P(None, None, tp),
+            "wv_b": P(None, None, tp),
             # DeepSeek shared experts: dense-MLP-shaped, same sharding
             "ws_gate": P(None, fsdp, tp),
             "ws_up": P(None, fsdp, tp),
@@ -145,15 +146,16 @@ class SpecLayout:
         over sp."""
         return P(self.dp_axis, self.sp_axis if seq_sharded else None)
 
-    def cache(self) -> P:
+    def cache(self, latent: bool = False) -> P:
         """KV cache [L, B, S, H_kv, hd]: rows over dp, heads over tp;
         the sequence dim shards over sp in long-context mode (context
         parallelism as a first-class placement dimension — SURVEY.md
-        §5)."""
+        §5). A ``latent`` cache (MLA) has one head for all query heads:
+        nothing to divide over tp, every tp shard holds it whole."""
         return P(
             None, self.dp_axis,
             self.sp_axis if self.long_context else None,
-            self.tp_axis, None,
+            None if latent else self.tp_axis, None,
         )
 
     def slot_state(self) -> P:

@@ -1,11 +1,12 @@
 """DeepSeek-V2/V3 family: MLA attention + DeepSeek-MoE HF parity.
 
 The reference's Performance Lab headliners are DeepSeek models; this
-engine serves them with DECOMPRESSED MLA (per-head K/V materialized so
-the existing cache/flash/ring machinery applies — models/transformer.py)
+engine serves them with MLA over a latent cache (decompressed inside a
+prefill's program, absorbed over cached rows — models/transformer.py)
 and DeepSeek MoE (shared experts, routed scaling, sigmoid scoring,
-first-k-dense prefix stack). Bit-parity against transformers on tiny
-random checkpoints, same doctrine as the gemma/qwen tests.
+group-limited selection, first-k-dense prefix stack). Bit-parity against
+transformers on tiny random checkpoints, same doctrine as the gemma/qwen
+tests.
 """
 
 import dataclasses
@@ -271,25 +272,77 @@ def test_yarn_mscale_softmax_correction_value():
     assert yarn_get_mscale(0.5, 0.707) == 1.0
 
 
-def test_group_routing_rejected():
-    from gpustack_tpu.models.config import config_from_hf
+@pytest.fixture(scope="module")
+def grouped_checkpoint(tmp_path_factory):
+    """V3 with group-limited selection as the large checkpoints ship it:
+    8 experts in 4 groups, 2 groups kept, 3 experts a token, a correction
+    bias that matters."""
+    torch = pytest.importorskip("torch")
+    tfm = pytest.importorskip("transformers")
 
-    with pytest.raises(ValueError, match="n_group"):
-        config_from_hf({
-            "architectures": ["DeepseekV2ForCausalLM"],
-            "hidden_size": 32, "num_attention_heads": 4,
-            "vocab_size": 64, "num_hidden_layers": 2,
-            "kv_lora_rank": 16, "qk_nope_head_dim": 8,
-            "qk_rope_head_dim": 8, "v_head_dim": 8,
-            "n_routed_experts": 8, "num_experts_per_tok": 2,
-            "moe_intermediate_size": 16,
-            "n_group": 8, "topk_group": 3,
-            "topk_method": "group_limited_greedy",
-        })
+    torch.manual_seed(3)
+    hf_cfg = tfm.DeepseekV3Config(
+        vocab_size=128, hidden_size=32, intermediate_size=64,
+        moe_intermediate_size=16, num_hidden_layers=2,
+        num_attention_heads=4, num_key_value_heads=4, n_shared_experts=1,
+        n_routed_experts=8, routed_scaling_factor=2.5, kv_lora_rank=16,
+        q_lora_rank=24, qk_rope_head_dim=8, qk_nope_head_dim=8,
+        v_head_dim=8, num_experts_per_tok=3, n_group=4, topk_group=2,
+        first_k_dense_replace=1, norm_topk_prob=True,
+        scoring_func="sigmoid", topk_method="noaux_tc",
+        max_position_embeddings=128, rope_theta=10000.0,
+        tie_word_embeddings=False, attention_dropout=0.0,
+        attention_bias=False,
+    )
+    model = tfm.DeepseekV3ForCausalLM(hf_cfg).eval()
+    with torch.no_grad():
+        for layer in model.model.layers:
+            if hasattr(layer.mlp, "gate"):
+                layer.mlp.gate.e_score_correction_bias.uniform_(-0.3, 0.3)
+    d = tmp_path_factory.mktemp("dsgroups")
+    model.save_pretrained(d, safe_serialization=True)
+    return model, str(d)
+
+
+def test_group_limited_routing_matches_transformers(grouped_checkpoint):
+    """n_group > 1 was refused until PR 35; now the selection is the
+    public port's (the sum of a group's two best, the kept groups, the
+    top-k within them, the bias on the selection only)."""
+    torch = pytest.importorskip("torch")
+    model, model_dir = grouped_checkpoint
+    cfg, ours = _logits_ours(model_dir, TOKENS)
+    assert (cfg.n_group, cfg.topk_group) == (4, 2)
+    assert cfg.moe_scoring == "sigmoid" and cfg.q_lora_rank == 24
+    with torch.no_grad():
+        ref = model(torch.tensor(TOKENS, dtype=torch.long)).logits.numpy()
+    np.testing.assert_allclose(ours, ref, atol=5e-3, rtol=2e-2)
+
+
+def test_a_position_sharded_cache_refuses_a_latent():
+    """The one consumer that cannot carry a latent cache yet says so."""
+    from gpustack_tpu.models.config import config_from_hf
+    from gpustack_tpu.models.transformer import KVCache, init_params
+    from gpustack_tpu.parallel.mesh import MeshPlan, make_mesh
+
+    cfg = config_from_hf({
+        "architectures": ["DeepseekV2ForCausalLM"],
+        "hidden_size": 32, "num_attention_heads": 4,
+        "vocab_size": 64, "num_hidden_layers": 1,
+        "kv_lora_rank": 16, "qk_nope_head_dim": 8,
+        "qk_rope_head_dim": 8, "v_head_dim": 8,
+    })
+    params = init_params(cfg, jax.random.key(0))
+    toks = jnp.zeros((1, 8), jnp.int32)
+    pos = jnp.arange(8, dtype=jnp.int32)[None]
+    with pytest.raises(ValueError, match="latent"):
+        forward(
+            params, cfg, toks, pos, KVCache.create(cfg, 1, 8),
+            attn_impl="ring", mesh=make_mesh(MeshPlan(sp=2)),
+        )
 
 
 def test_deepseek_engine_greedy_serving(v2_checkpoint):
-    """The full serving path (prefill→insert→decode over the padded-v
+    """The full serving path (prefill→insert→decode over the latent
     cache) produces the oracle's greedy tokens."""
     _, model_dir = v2_checkpoint
 

@@ -340,3 +340,130 @@ def test_the_moe_prefill_takes_grouped_experts_and_fits_beside_decode(
     # the padded rows (32,640 x 2,048 bf16, four of them at most) stay
     # under the vocabulary head's 0.62 GB, as the dense path's did
     assert pre.temp_size_in_bytes < 0.7e9, pre.temp_size_in_bytes
+
+
+# ---- A.X-K1 (perfbench/configs/ax-k1-int8-ep16-l12): latent attention ----
+
+AXK1_DIR = "perfbench/configs/ax-k1-int8-ep16-l12"
+
+
+def _axk1(layers: int):
+    import os
+
+    from gpustack_tpu.models.config import load_hf_config
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)
+    )))
+    return dataclasses.replace(
+        load_hf_config(os.path.join(root, AXK1_DIR)), num_layers=layers
+    )
+
+
+def _axk1_shapes(one_chip, cfg):
+    from gpustack_tpu.models import init_params
+    from gpustack_tpu.models.quant import quantize_params
+
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(
+            lambda: quantize_params(init_params(cfg, jax.random.key(0)))
+        ),
+    )
+
+
+def test_flash_prefill_takes_keys_of_192_and_values_of_128(one_chip):
+    """The MLA prefill's call: 64 heads, keys 192 and values 128 wide,
+    nothing padded; the result is as wide as a value, which is where the
+    benchmark's reader takes the value width from
+    (perfbench/layer_metrics/kernel.mla_prefill_roofline.py)."""
+    from gpustack_tpu.ops.flash_attention import flash_attention_prefill
+
+    T = 8192
+    q = jax.ShapeDtypeStruct((1, T, 64, 192), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, T, 64, 128), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(
+        lambda q, k, v: flash_attention_prefill(q, k, v, scale=0.1)
+    ).lower(q, q, v).compile()
+    assert re.search(
+        rf"%flash_attention_prefill[\w.\-]* = bf16\[1,64,{T},128\]"
+        r".* custom-call\(",
+        compiled.as_text(),
+    )
+    # the head-major copies of q, k, v and o, no [T, T] scores
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
+def test_the_latent_decode_step_moves_no_cache_but_the_rope_keys(one_chip):
+    """A.X-K1's decode program at the cell's 16 slots of 8,192 (two
+    layers: the scan's body is what is looked at): the absorbed attention
+    is the kernel the benchmark's reader finds by name, and no operation
+    copies, transposes or slices out the latent cache, the 7/8 of it
+    that is 512 wide. The rope keys (64 wide, stored with the positions
+    on the lanes) are written by a pass over the layer and relaid out
+    whole once in and once out a step: ROADMAP A1."""
+    from gpustack_tpu.models.transformer import KVCache, forward
+
+    cfg = _axk1(2)
+    slots, S = 16, 8192
+    shapes = _axk1_shapes(one_chip, cfg)
+    cache = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: KVCache.create(cfg, slots, S)),
+    )
+    ids = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+
+    def step(params, cache, tokens, positions):
+        return forward(
+            params, cfg, tokens, positions, cache,
+            mla_decode_impl="kernel", moe_dispatch_impl="dense",
+        )
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        shapes, cache, ids, ids
+    ).compile()
+    text = compiled.as_text()
+    assert re.search(
+        r"%mla_decode_attention[\w.\-]* = bf16\[16,64,512\].* custom-call\(",
+        text,
+    )
+    moved = [
+        line.strip()[:120] for line in text.splitlines()
+        if re.search(r"= bf16\[(2,)?16,8192,(1,)?512\]", line)
+        and re.search(r" (copy|transpose|dynamic-slice)\(", line)
+    ]
+    assert moved == []
+    # the cache is the 1,152 bytes a position a layer, and is aliased
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * slots * S * 1152
+    assert mem.temp_size_in_bytes < 0.3e9
+
+
+def test_the_latent_prefill_s_temporaries_leave_the_resident_model_room(one_chip):
+    """The cell's 8,192 prefill program, grouped dispatch under the share
+    of 12 experts (two layers: the scan's body is what takes the
+    temporaries, whatever the depth): it compiles (the flash call at 192 /
+    128, the grouped kernel in rounds) and its temporaries, 1.9 GB, leave
+    the 10.05 GB that stay resident at 12 layers (tree and cache) room on
+    a 16 GB chip."""
+    from gpustack_tpu.models.transformer import KVCache, forward
+
+    bucket = 8192
+    cfg = _axk1(2)
+    shapes = _axk1_shapes(one_chip, cfg)
+
+    def prefill(params, tokens):
+        cache = KVCache.create(cfg, 1, bucket)
+        positions = jnp.arange(bucket, dtype=jnp.int32)[None]
+        logits, cache, held = forward(
+            params, cfg, tokens, positions, cache, attn_impl="flash",
+            moe_dispatch_impl="grouped", count_held_pairs=True,
+        )
+        return logits[0, -1], cache.k[:, 0], cache.v[:, 0], held
+
+    compiled = jax.jit(prefill).lower(
+        shapes, jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=one_chip)
+    ).compile()
+    text = compiled.as_text()
+    assert "moe_grouped_matmul" in text and "flash_attention_prefill" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
