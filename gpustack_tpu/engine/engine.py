@@ -870,6 +870,10 @@ class LLMEngine:
             # under a share of the experts: the prefill programs' router
             # pairs by whether their expert is held here (else None)
             "moe_pairs": self.runner.moe_pairs(),
+            # how every sample() finds its candidates (sampling.
+            # top_candidates): "chunked: 64 of 1187 chunks of 128" or
+            # "whole row of <vocabulary>"
+            "sample_candidates": self.runner.sample_candidates,
             "flight_overhead_ratio": round(
                 self.flight.overhead_ratio(), 6
             ),

@@ -34,6 +34,7 @@ from jax.sharding import PartitionSpec as P
 from gpustack_tpu.engine.sampling import (
     MAX_BIAS,
     SamplingState,
+    candidates_form,
     sample,
 )
 from gpustack_tpu.models.config import ModelConfig
@@ -204,6 +205,10 @@ class ModelRunner:
             self.mesh, self.layout.replicated()
         )
 
+        # how the decode and first-token programs find a row's top
+        # candidates: static, from the vocabulary's width alone
+        self.sample_candidates = candidates_form(cfg.vocab_size)
+        logger.info("sampling candidates: %s", self.sample_candidates)
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
         self._decode_routing = None
         self._prefills: Dict[int, Any] = {}

@@ -10,8 +10,16 @@ logprob and the top-``TOPLP`` (id, logprob) candidates — the data the
 OpenAI ``logprobs``/``top_logprobs`` response fields need (reference
 proxies vLLM's logprobs surface, gpustack/routes/openai.py). They come
 almost free: the sampler already ranks the top-``CAND`` logits, so the
-only extra work is one logsumexp for normalization — no second
-full-vocab sort.
+only extra work is one logsumexp for normalization.
+
+Nothing here sorts a whole vocabulary. A ``jax.lax.top_k`` over the row
+did: XLA writes it as a sort of the row and a slice, and makes a ``TopK``
+call of the pair only where nothing else reads the sort, which the
+top-``TOPLP`` sliced from the top-``CAND`` below prevents (on the v5e
+3.0-5.9 ms a decode step at 151,936 columns and 1.6 ms a first token:
+PERF.md section 6, PR 36). So :func:`top_candidates` first narrows a wide
+row to the ``CAND`` lane-wide chunks that can hold its ``CAND`` largest
+logits and ranks only those — exactly, ties included.
 """
 
 from __future__ import annotations
@@ -74,12 +82,11 @@ class SamplingState:
         )
 
 
-# Sampling never looks past the top CAND candidates: a full-vocab sort
-# (128k wide, every decode step) is the single most expensive non-matmul op
-# on TPU, while the probability mass beyond the top-64 logits is
-# negligible. Exact for greedy and for top_k <= CAND; pure temperature
-# sampling is truncated to the top-64 tail (the standard serving-engine
-# tradeoff).
+# Sampling never looks past the top CAND candidates: the probability mass
+# beyond the top-64 logits is negligible, and ranking more of the row
+# costs a sort of it. Exact for greedy and for top_k <= CAND; pure
+# temperature sampling is truncated to the top-64 tail (the standard
+# serving-engine tradeoff). How the CAND are found: top_candidates.
 CAND = 64
 # Top-logprob candidates returned per step (OpenAI caps top_logprobs at 20).
 TOPLP = 20
@@ -87,6 +94,76 @@ TOPLP = 20
 # top-k rank (exact semantics — a +bias can promote a token from outside
 # the candidate window, a -100 ban always lands).
 MAX_BIAS = 64
+
+
+# One chunk of a row in top_candidates: the TPU's lane width, so a row of
+# chunks is the row's own tiling and a chunk's maximum is one lane reduce.
+LANES = 128
+# Rows of fewer chunks than this are ranked whole: the selection ranks
+# CAND * LANES = 8,192 columns whatever the row's width, so it cannot pay
+# at CAND chunks and does from about one and a half times that. ``sample``
+# on the v5e, whole row against chunks, microseconds (hack/sample_bench.py;
+# PERF.md section 6, PR 36): at 16 slots 64 chunks 88 / 104, 96 chunks
+# 145 / 106, 128 chunks 178 / 107, 160 chunks (A.X-K1's slice of 20,480
+# columns) 272 / 108; at 32 slots 1,187 chunks (Qwen3's 151,936) 6,200 /
+# 444. Twice CAND, so that the tests' vocabularies of a few thousand
+# columns compile what they always did.
+CHUNKED_MIN_CHUNKS = 2 * CAND
+
+
+def candidate_chunks(vocab: int, n: int = CAND) -> int:
+    """How many chunks :func:`top_candidates` cuts a row of ``vocab``
+    columns into for its top ``n``; 0 where it ranks the row whole. A
+    static choice from the shape alone."""
+    chunks = -(-vocab // LANES)
+    return chunks if chunks >= max(CHUNKED_MIN_CHUNKS, n) else 0
+
+
+def candidates_form(vocab: int) -> str:
+    """Which form of :func:`top_candidates` every ``sample`` over a
+    vocabulary of ``vocab`` columns is built with, in words: the
+    engine's start-up log and ``sample_candidates`` in ``/healthz``."""
+    chunks = candidate_chunks(vocab)
+    if chunks:
+        return f"chunked: {CAND} of {chunks} chunks of {LANES}"
+    return f"whole row of {vocab}"
+
+
+def top_candidates(logits: jax.Array, n: int):
+    """``jax.lax.top_k(logits, n)`` — same values, same ids, same order
+    — without a sort of the row's whole width.
+
+    A wide row is viewed as chunks of ``LANES`` columns. The ``n`` chunks
+    with the largest maxima are gathered in the vocabulary's own order
+    and only those ``n * LANES`` columns are ranked. Exact: with ``t`` the
+    ``n``-th largest chunk maximum, the chosen chunks hold at least ``n``
+    values >= ``t`` and every value of a chunk left out is <= its
+    maximum <= ``t``, so the ``n`` largest candidates are the row's.
+    Ties: both ``top_k`` calls put the lower index first among equals and
+    the candidates keep the vocabulary's order, so a chunk left out only
+    ever ties with chosen chunks of lower index, each of which holds an
+    equal value of lower index: the ids are ``lax.top_k``'s as well.
+    """
+    B, V = logits.shape
+    C = candidate_chunks(V, n)
+    if not C:
+        return jax.lax.top_k(logits, n)
+    with jax.named_scope("sample_candidates"):
+        if C * LANES != V:   # padding ranks after every real column
+            logits = jnp.pad(
+                logits, ((0, 0), (0, C * LANES - V)),
+                constant_values=-jnp.inf,
+            )
+        chunks = logits.reshape(B, C, LANES)
+        _, chunk_ids = jax.lax.top_k(jnp.max(chunks, axis=-1), n)
+        chunk_ids = jnp.sort(chunk_ids, axis=-1)
+        cands = jnp.take_along_axis(chunks, chunk_ids[:, :, None], axis=1)
+        vals, local = jax.lax.top_k(cands.reshape(B, n * LANES), n)
+        ids = (
+            jnp.take_along_axis(chunk_ids, local // LANES, axis=1) * LANES
+            + local % LANES
+        )
+        return vals, ids
 
 
 def _row_keys(state: SamplingState, positions: jax.Array, key: jax.Array):
@@ -132,7 +209,7 @@ def sample(
         jnp.arange(B)[:, None], bias_cols
     ].add(bias_vals)
     n = min(CAND, V)
-    top_logits, top_idx = jax.lax.top_k(logits, n)   # [B, n] descending
+    top_logits, top_idx = top_candidates(logits, n)   # [B, n] descending
 
     temp = jnp.maximum(state.temperature, 1e-6)[:, None]
     scaled = top_logits / temp

@@ -55,6 +55,14 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _shapes_on(one_chip, make):
+    """The shapes of what ``make()`` would build, placed on the chip."""
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(make),
+    )
+
+
 def _flash_compiled(one_chip, cfg, T: int, S: int):
     from gpustack_tpu.ops.flash_attention import flash_attention_prefill
 
@@ -133,20 +141,10 @@ def _int8_step_compiled(one_chip, cfg, slots: int, T: int):
     from gpustack_tpu.models.quant import quantize_params
     from gpustack_tpu.models.transformer import KVCache, forward
 
-    def on_chip(tree):
-        return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(
-                x.shape, x.dtype, sharding=one_chip
-            ),
-            tree,
-        )
-
-    params = on_chip(jax.eval_shape(
-        lambda: quantize_params(init_params(cfg, jax.random.key(0)))
-    ))
-    cache = on_chip(jax.eval_shape(
-        lambda: KVCache.create(cfg, slots, MAX_LEN)
-    ))
+    params = _shapes_on(
+        one_chip, lambda: quantize_params(init_params(cfg, jax.random.key(0)))
+    )
+    cache = _shapes_on(one_chip, lambda: KVCache.create(cfg, slots, MAX_LEN))
     tokens = jax.ShapeDtypeStruct((slots, T), jnp.int32, sharding=one_chip)
 
     def step(params, tokens, positions, cache):
@@ -279,6 +277,51 @@ def test_int8_decode_layer_compiles_for_v5e(one_chip):
     assert mem.temp_size_in_bytes < 0.6e9
 
 
+def test_the_decode_program_sorts_no_row_of_the_vocabulary(one_chip):
+    """A decode step's sampler at Qwen3-8B's widths (12 slots x 151,936
+    columns): ``top_candidates`` ranks 64 chunks of 128, so the
+    program's sorts are of 1,187 and of 8,192 columns. A ``lax.top_k``
+    over the row compiles here to a sort of the whole row, because the
+    top log-probs are sliced from the candidates (3.0 ms a step on the
+    chip: PERF.md, PR 36); this keeps one from coming back unseen."""
+    from gpustack_tpu.engine.sampling import (
+        CAND, LANES, SamplingState, sample,
+    )
+    from gpustack_tpu.models import init_params
+    from gpustack_tpu.models.quant import quantize_params
+    from gpustack_tpu.models.transformer import KVCache, forward
+
+    cfg = dataclasses.replace(QWEN3_8B, num_layers=1)
+    slots = 12
+
+    params = _shapes_on(
+        one_chip, lambda: quantize_params(init_params(cfg, jax.random.key(0)))
+    )
+    cache = _shapes_on(one_chip, lambda: KVCache.create(cfg, slots, MAX_LEN))
+    state = _shapes_on(one_chip, lambda: SamplingState.create(slots))
+    key = _shapes_on(one_chip, lambda: jax.random.key(0))
+    tokens = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+
+    def step(params, tokens, positions, cache, state, key):
+        logits, cache = forward(
+            params, cfg, tokens[:, None], positions[:, None], cache
+        )
+        return sample(logits[:, 0], state, key, positions), cache
+
+    text = jax.jit(step, donate_argnums=(3,)).lower(
+        params, tokens, tokens, cache, state, key
+    ).compile().as_text()
+    # what the program ranks: the first operand of every sort, and of
+    # every TopK call (what a top_k that nothing else reads becomes)
+    dims = dict(re.findall(r"%([\w.-]+) = \(?\w+\[([\d,]*)\]", text))
+    ranked = re.findall(r" sort\(%([\w.-]+)", text) + re.findall(
+        r' custom-call\(%([\w.-]+)[^\n]*custom_call_target="TopK"', text
+    )
+    widths = sorted({int(dims[name].split(",")[-1]) for name in ranked})
+    assert CAND * LANES in widths, widths        # the selection is there
+    assert max(widths) < cfg.vocab_size // 8, widths
+
+
 def test_the_moe_prefill_takes_grouped_experts_and_fits_beside_decode(
     one_chip,
 ):
@@ -301,17 +344,9 @@ def test_the_moe_prefill_takes_grouped_experts_and_fits_beside_decode(
     assert moe_dispatch(2048, cfg, "tpu", None) == "grouped"
     assert moe_dispatch(32, cfg, "tpu", None) == "dense"
 
-    def on_chip(tree):
-        return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(
-                x.shape, x.dtype, sharding=one_chip
-            ),
-            tree,
-        )
-
-    params = on_chip(jax.eval_shape(
-        lambda: quantize_params(init_params(cfg, jax.random.key(0)))
-    ))
+    params = _shapes_on(
+        one_chip, lambda: quantize_params(init_params(cfg, jax.random.key(0)))
+    )
     tokens = jax.ShapeDtypeStruct((1, 2048), jnp.int32, sharding=one_chip)
 
     def prefill(params, tokens):   # ModelRunner._prefill_impl on a TPU
@@ -364,11 +399,8 @@ def _axk1_shapes(one_chip, cfg):
     from gpustack_tpu.models import init_params
     from gpustack_tpu.models.quant import quantize_params
 
-    return jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
-        jax.eval_shape(
-            lambda: quantize_params(init_params(cfg, jax.random.key(0)))
-        ),
+    return _shapes_on(
+        one_chip, lambda: quantize_params(init_params(cfg, jax.random.key(0)))
     )
 
 
