@@ -602,6 +602,9 @@ class LLMEngine:
         self._step_moe_dispatch: Dict[str, int] = {}
         # the form of attention the step's prefill ran (MLA only)
         self._step_attn: Optional[str] = None
+        # cached positions the step's decode attends / the cache allocates
+        self._step_kv_live = 0
+        self._step_kv_allocated = 0
         self._step_spec_proposed = 0
         self._step_spec_accepted = 0
         # on-demand profiler capture (capture_profile): the capturing
@@ -874,6 +877,10 @@ class LLMEngine:
             # top_candidates): "chunked: 64 of 1187 chunks of 128" or
             # "whole row of <vocabulary>"
             "sample_candidates": self.runner.sample_candidates,
+            # how a decode step attends over the cache (transformer.
+            # decode_attention_impl): "kernel" reads the live slots'
+            # rows where they lie, "xla" every slot's slab
+            "decode_attention": self.runner.decode_attention,
             "flight_overhead_ratio": round(
                 self.flight.overhead_ratio(), 6
             ),
@@ -1057,6 +1064,7 @@ class LLMEngine:
         self._step_out = self._step_prompt = 0
         self._step_moe_dispatch = {}
         self._step_attn = None
+        self._step_kv_live = self._step_kv_allocated = 0
         self._step_spec_proposed = self._step_spec_accepted = 0
         self._step_admitted = []
         self._step_first = []
@@ -1147,6 +1155,8 @@ class LLMEngine:
             moe_dispatch=self._step_moe_dispatch,
             # a step without a prefill went over the cache
             attn=self._step_attn or self.runner.attn_label(),
+            kv_live=self._step_kv_live,
+            kv_allocated=self._step_kv_allocated,
         )
         if dur_s > _SLOW_STEP_S:
             logger.warning(
@@ -1892,6 +1902,14 @@ class LLMEngine:
             self._step_mode = self._step_mode or "decode"
             self._step_real += len(owners)
             self._step_padded += self.max_slots
+            # what the step's live slots attend of what the cache
+            # allocates, from the scheduler's own counts (flight
+            # ``kv_live_pct``)
+            self._step_kv_live += sum(
+                len(info.request.prompt_ids) + len(info.request.output_ids)
+                for info in self._slots.values()
+            )
+            self._step_kv_allocated += self.max_slots * self.max_seq_len
         self._step_count += 1
         return True
 

@@ -41,6 +41,7 @@ from gpustack_tpu.models.config import ModelConfig
 from gpustack_tpu.models.quant import QuantW, quant_pspecs
 from gpustack_tpu.models.transformer import (
     KVCache,
+    decode_attention_impl,
     forward,
     moe_dispatch,
     needs_xla_attention,
@@ -209,6 +210,14 @@ class ModelRunner:
         # candidates: static, from the vocabulary's width alone
         self.sample_candidates = candidates_form(cfg.vocab_size)
         logger.info("sampling candidates: %s", self.sample_candidates)
+        # how a decode step attends over the cache, as ``forward`` will
+        # choose when the decode program is traced: ``kernel`` reads the
+        # live slots' rows where they lie, ``xla`` every slot's slab
+        self.decode_attention = decode_attention_impl(
+            cfg, 1, max_seq_len, self.mesh.devices.flat[0].platform,
+            self.mesh,
+        )
+        logger.info("decode attention: %s", self.decode_attention)
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
         self._decode_routing = None
         self._prefills: Dict[int, Any] = {}
@@ -600,6 +609,7 @@ class ModelRunner:
             params, self.cfg, tokens, positions, state.cache,
             attn_impl="ring" if self.sp_mode else "xla",
             mesh=self.mesh,
+            live=state.active,
             routing_out=routing,
         )
         sampled, tok_lp, top_ids, top_lps = sample(
@@ -616,7 +626,9 @@ class ModelRunner:
         )
         # Inactive slots keep feeding their last token at a frozen position;
         # their cache writes are confined to their own rows and invisible
-        # through the causal mask of any future tenant.
+        # through the causal mask of any future tenant. Under the decode
+        # kernel they attend nothing (``live``), and what they sample is
+        # dropped here either way.
         next_tokens = jnp.where(state.active, sampled, state.last_tokens)
         at_capacity = state.positions + 1 >= self.max_seq_len
         new_positions = jnp.where(

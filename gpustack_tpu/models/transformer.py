@@ -916,25 +916,41 @@ def moe_dispatch(rows: int, cfg: ModelConfig, platform: str, mesh) -> str:
     return "grouped" if one_chip and fills else "dense"
 
 
-def mla_decode_attention_impl(
-    rows: int, max_len: int, platform: str, mesh
+def decode_attention_impl(
+    cfg: ModelConfig, rows: int, max_len: int, platform: str, mesh
 ) -> str:
-    """How a step of ``rows`` tokens a slot attends over the latent
-    cache in its absorbed form (``forward``'s MLA branch): the one place
-    that decides, from what ``forward`` can observe.
+    """How a step of ``rows`` tokens a slot attends over cached rows,
+    whichever cache the model keeps (a GQA cache, or MLA's latent in its
+    absorbed form): the one place that decides, from what ``forward``
+    can observe.
 
-    ``"kernel"`` (``ops/mla_attention.py``) for a decode step on one TPU
-    chip whose cache divides into the kernel's blocks: one token a slot,
-    each block of cached positions read once for all heads and only as
-    far as the slot's position. ``"xla"`` otherwise: a verify step or a
+    ``"kernel"`` (``ops/decode_attention.py``, ``ops/mla_attention.py``)
+    for a decode step on one TPU chip whose cache divides into the
+    kernel's blocks: one token a slot, each block of cached positions
+    read where it lies, once for all heads and only as far as the
+    slot's length. ``"xla"`` otherwise: a verify step, an ingest or a
     continuation (several rows a slot), a mesh of more than one device
-    (the kernel is not wrapped in a ``shard_map``), any other platform.
+    (the kernels are not wrapped in a ``shard_map``), any other
+    platform, and for a GQA cache a model whose scores only the einsum
+    computes (:func:`needs_xla_attention`) or whose heads are no whole
+    number of lane tiles wide.
     """
-    from gpustack_tpu.ops.mla_attention import block_positions
+    from gpustack_tpu.ops.decode_attention import (
+        block_positions,
+        gqa_block_positions,
+    )
 
+    if cfg.is_mla:
+        block = block_positions(max_len)
+    elif needs_xla_attention(cfg):
+        block = None
+    else:
+        block = gqa_block_positions(
+            max_len, cfg.num_kv_heads, cfg.head_dim,
+            2 if cfg.dtype == "bfloat16" else 4,
+        )
     one_chip = platform == "tpu" and (mesh is None or mesh.size == 1)
-    fits = rows == 1 and block_positions(max_len) is not None
-    return "kernel" if one_chip and fits else "xla"
+    return "kernel" if one_chip and rows == 1 and block is not None else "xla"
 
 
 def forward(
@@ -948,7 +964,8 @@ def forward(
     mesh=None,
     embeds_override: Optional[Tuple[jax.Array, jax.Array]] = None,
     moe_dispatch_impl: Optional[str] = None,
-    mla_decode_impl: Optional[str] = None,
+    decode_attn_impl: Optional[str] = None,
+    live: Optional[jax.Array] = None,
     count_held_pairs: bool = False,
     routing_out: bool = False,
 ) -> Tuple[jax.Array, Optional[KVCache]]:
@@ -993,10 +1010,18 @@ def forward(
     A latent-attention model (MLA) keeps the latent in the cache and
     attends in one of two forms (``mla_attention`` below): decompressed
     where the step's rows are all the keys (``attn_impl`` then chooses
-    the kernel as for any model), absorbed over cached rows, by
-    :func:`mla_decode_attention_impl` or ``mla_decode_impl``
-    (``"kernel_interpret"`` for the tests). A cache sharded over its
-    positions (``"ring"``) cannot yet carry a latent and is refused.
+    the kernel as for any model), absorbed over cached rows. A cache
+    sharded over its positions (``"ring"``) cannot yet carry a latent
+    and is refused.
+
+    Over cached rows a step attends as :func:`decode_attention_impl`
+    says, or ``decode_attn_impl`` (``"kernel_interpret"`` for the
+    tests): a decode step's kernel reads the cache where it lies and
+    each slot only as far as its length, ``positions[:, 0] + 1``, or 0
+    where ``live`` (bool ``[B]``; None: every slot) says nobody holds
+    the slot. Such a slot's logits mean nothing (zeros attended under
+    the kernel, its last tenant's rows under ``"xla"``, which takes no
+    notice of ``live``); its row of the cache is still written.
 
     Returns ``(logits [B, T, vocab] fp32, updated cache or None)``, and
     with ``count_held_pairs`` (a model served as one share of its
@@ -1016,10 +1041,24 @@ def forward(
     )
     if cfg.is_moe and moe_dispatch_impl is None:
         moe_dispatch_impl = moe_dispatch(B * T, cfg, platform, mesh)
-    if cfg.is_mla and cache is not None and mla_decode_impl is None:
-        mla_decode_impl = mla_decode_attention_impl(
-            T, cache.max_len, platform, mesh
+    if cache is not None and decode_attn_impl is None:
+        decode_attn_impl = decode_attention_impl(
+            cfg, T, cache.max_len, platform, mesh
         )
+    if cache is not None and decode_attn_impl != "xla":
+        lengths = positions[:, 0] + 1
+        if live is not None:
+            lengths = jnp.where(live, lengths, 0)
+        # the kernel's walk over the slots, once a step: inside the scan
+        # XLA makes it again every layer
+        if cfg.is_mla:
+            from gpustack_tpu.ops.mla_attention import mla_walk
+
+            walk = mla_walk(lengths, cache.max_len)
+        else:
+            from gpustack_tpu.ops.decode_attention import gqa_walk
+
+            walk = gqa_walk(lengths, cache.k)
     dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
     x = _embed_lookup(params["embed"], tokens, dtype)
     if embeds_override is not None:
@@ -1233,7 +1272,7 @@ def forward(
         q_lat = jnp.einsum(
             "bthn,rhn->bthr", q_nope, wk.reshape(rank, H, nope)
         )
-        if mla_decode_impl == "xla":
+        if decode_attn_impl == "xla":
             c_all, r_all = (
                 lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
                 for buf in (carried.k, carried.v)
@@ -1250,8 +1289,8 @@ def forward(
 
             u = mla_decode_attention(
                 q_lat[:, 0], q_pe[:, 0], carried.k, carried.v, layer,
-                positions[:, 0], scale,
-                interpret=mla_decode_impl == "kernel_interpret",
+                walk, scale,
+                interpret=decode_attn_impl == "kernel_interpret",
             )[:, None]
         if isinstance(wv, QuantW):
             attn = jnp.einsum(
@@ -1319,64 +1358,78 @@ def forward(
                 carried = KVCache(
                     k=write(carried.k, k), v=write(carried.v, v)
                 )
-                new_k, new_v = (
-                    lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
-                    for buf in (carried.k, carried.v)
-                )
-                if use_ring:
-                    from gpustack_tpu.ops.ring_attention import (
-                        sharded_prefill_attention,
-                        sp_cache_attention,
+                if decode_attn_impl != "xla":
+                    # a decode step on one chip: the kernel reads the
+                    # layer's rows where they lie and no slab is taken
+                    # out of the carry
+                    from gpustack_tpu.ops.decode_attention import (
+                        gqa_decode_attention,
                     )
 
-                    if T > 1 and cache.max_len == T:
-                        # prefill-from-zero: ring attention over the
-                        # sp-sharded step K/V (== the whole written cache)
-                        attn = sharded_prefill_attention(
-                            mesh, q, k, v, positions, scale
-                        )
-                    else:
-                        # decode / verify: exact attention over the
-                        # sp-sharded resident cache
-                        attn = sp_cache_attention(
-                            mesh, q, new_k, new_v, positions, scale
-                        )
-                elif use_flash:
-                    # prefill (from zero or from a chunk/prefix offset):
-                    # q rows sit at positions offset..offset+T-1 against the
-                    # freshly written cache; the kernel's q_offset shifts the
-                    # causal diagonal (all batch rows share one offset — the
-                    # engine's prefill paths are B=1; pad keys masked via
-                    # seq_k, pad/garbage cache rows above the last query
-                    # position are causally invisible)
-                    from gpustack_tpu.ops.flash_attention import (
-                        flash_attention_prefill,
-                        sharded_flash_attention_prefill,
-                    )
-
-                    flash_args = (
-                        q.reshape(B, T, cfg.num_heads, cfg.head_dim),
-                        new_k,
-                        new_v,
-                        scale,
-                    )
-                    flash_kw = dict(
-                        interpret=attn_impl == "flash_interpret",
-                        q_offset=positions[0, 0],
-                    )
-                    if mesh is not None:
-                        # under tp the kernel runs per shard of heads
-                        attn = sharded_flash_attention_prefill(
-                            mesh, *flash_args, **flash_kw
-                        )
-                    else:
-                        attn = flash_attention_prefill(*flash_args, **flash_kw)
+                    attn = gqa_decode_attention(
+                        q.reshape(B, cfg.num_heads, cfg.head_dim),
+                        carried.k, carried.v, layer, walk, scale,
+                        interpret=decode_attn_impl == "kernel_interpret",
+                    )[:, None]
                 else:
-                    attn = _attend(
-                        q, new_k, new_v, mask_l, scale,
-                        cfg.attn_logit_softcap,
-                        sinks=sinks_l,
+                    new_k, new_v = (
+                        lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
+                        for buf in (carried.k, carried.v)
                     )
+                    if use_ring:
+                        from gpustack_tpu.ops.ring_attention import (
+                            sharded_prefill_attention,
+                            sp_cache_attention,
+                        )
+
+                        if T > 1 and cache.max_len == T:
+                            # prefill-from-zero: ring attention over the
+                            # sp-sharded step K/V (== the whole written cache)
+                            attn = sharded_prefill_attention(
+                                mesh, q, k, v, positions, scale
+                            )
+                        else:
+                            # decode / verify: exact attention over the
+                            # sp-sharded resident cache
+                            attn = sp_cache_attention(
+                                mesh, q, new_k, new_v, positions, scale
+                            )
+                    elif use_flash:
+                        # prefill (from zero or from a chunk/prefix offset):
+                        # q rows sit at positions offset..offset+T-1 against the
+                        # freshly written cache; the kernel's q_offset shifts the
+                        # causal diagonal (all batch rows share one offset — the
+                        # engine's prefill paths are B=1; pad keys masked via
+                        # seq_k, pad/garbage cache rows above the last query
+                        # position are causally invisible)
+                        from gpustack_tpu.ops.flash_attention import (
+                            flash_attention_prefill,
+                            sharded_flash_attention_prefill,
+                        )
+
+                        flash_args = (
+                            q.reshape(B, T, cfg.num_heads, cfg.head_dim),
+                            new_k,
+                            new_v,
+                            scale,
+                        )
+                        flash_kw = dict(
+                            interpret=attn_impl == "flash_interpret",
+                            q_offset=positions[0, 0],
+                        )
+                        if mesh is not None:
+                            # under tp the kernel runs per shard of heads
+                            attn = sharded_flash_attention_prefill(
+                                mesh, *flash_args, **flash_kw
+                            )
+                        else:
+                            attn = flash_attention_prefill(*flash_args, **flash_kw)
+                    else:
+                        attn = _attend(
+                            q, new_k, new_v, mask_l, scale,
+                            cfg.attn_logit_softcap,
+                            sinks=sinks_l,
+                        )
 
         attn_out = _mm("btq,qd->btd", attn, lp["wo"])
         if cfg.o_bias:

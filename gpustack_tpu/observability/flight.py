@@ -58,6 +58,14 @@ Record vocabulary (per step):
   prefill program (decompressed, by the bucket's kernel),
   ``mla_absorbed`` in a decode or verify step (over the latent cache).
   Absent for any other model.
+- ``kv_live_pct`` — in a step that dispatched a decode step: the cached
+  positions its live slots attend (each one's prompt and output so far,
+  as the scheduler counts them: no device read, and behind the device
+  by the fetch pipeline's lag) over the ``slots x max_len`` the cache
+  allocates. It is the share of the cache a decode step has to read:
+  all the decode kernel reads (``decode_attention: kernel`` in
+  ``/healthz``), where the XLA form reads every position. Absent
+  otherwise.
 
 Cumulative (not per-record): ``idle_wait_s_total`` — seconds the
 scheduler parked on its wakeup condition instead of busy-polling (the
@@ -269,6 +277,8 @@ GUARDED_BY = {
     "tokens_out_total": "_mu",
     "prompt_tokens_total": "_mu",
     "moe_prompt_tokens_total": "_mu",
+    "decode_kv_live_total": "_mu",
+    "decode_kv_allocated_total": "_mu",
     "spec_proposed_total": "_mu",
     "spec_accepted_total": "_mu",
     "_last_slots_used": "_mu",
@@ -319,6 +329,10 @@ class FlightRecorder:
         self.prompt_tokens_total = 0
         # prompt tokens by expert dispatch; empty for a dense model
         self.moe_prompt_tokens_total: Dict[str, int] = {}
+        # cached positions over the decode steps: what their live slots
+        # attended, and what the cache allocates (slots x max_len a step)
+        self.decode_kv_live_total = 0
+        self.decode_kv_allocated_total = 0
         self.spec_proposed_total = 0
         self.spec_accepted_total = 0
         self._last_slots_used = 0
@@ -369,6 +383,8 @@ class FlightRecorder:
         first_tokens: Sequence = (),
         moe_dispatch: Optional[Dict[str, int]] = None,   # empty as None
         attn: Optional[str] = None,
+        kv_live: int = 0,
+        kv_allocated: int = 0,     # 0: the step dispatched no decode step
     ) -> None:
         t0 = time.perf_counter()
         with self._mu:
@@ -380,6 +396,7 @@ class FlightRecorder:
                 prompt_tokens, spec_proposed, spec_accepted, kv_blocks,
                 kv_reused_total, host_overlap_s, phases_s, admitted,
                 first_tokens, traced, compiled, moe_dispatch, attn,
+                kv_live, kv_allocated,
             ))
             h = self._hist.get(mode)
             if h is None:
@@ -397,6 +414,8 @@ class FlightRecorder:
                 totals = self.moe_prompt_tokens_total
                 for name, n in moe_dispatch.items():
                     totals[name] = totals.get(name, 0) + n
+            self.decode_kv_live_total += kv_live
+            self.decode_kv_allocated_total += kv_allocated
             self.spec_proposed_total += spec_proposed
             self.spec_accepted_total += spec_accepted
             self._last_kv_blocks = kv_blocks
@@ -445,7 +464,7 @@ class FlightRecorder:
          tokens_real, tokens_padded, tokens_out, prompt_tokens,
          spec_proposed, spec_accepted, kv_blocks, kv_reused_total,
          host_overlap_s, phases_s, admitted, first_tokens, traced,
-         compiled, moe_dispatch, attn) = row
+         compiled, moe_dispatch, attn, kv_live, kv_allocated) = row
         entry = {
             "ts": ts,
             "dur_ms": round(dur_s * 1e3, 4),
@@ -479,6 +498,8 @@ class FlightRecorder:
             entry["moe_dispatch"] = dict(moe_dispatch)
         if attn:
             entry["attn"] = attn
+        if kv_allocated:
+            entry["kv_live_pct"] = round(100.0 * kv_live / kv_allocated, 2)
         return entry
 
     # ---- read side -----------------------------------------------------
@@ -546,6 +567,8 @@ class FlightRecorder:
             padded = self.tokens_padded_total
             prompt = self.prompt_tokens_total
             moe_prompt = dict(self.moe_prompt_tokens_total)
+            kv_live = self.decode_kv_live_total
+            kv_allocated = self.decode_kv_allocated_total
             proposed = self.spec_proposed_total
             accepted = self.spec_accepted_total
             hist = {
@@ -588,6 +611,11 @@ class FlightRecorder:
             f"{padded}",
             decl("gpustack_engine_prompt_tokens_total"),
             f"gpustack_engine_prompt_tokens_total {prompt}",
+            decl("gpustack_engine_decode_kv_positions_total"),
+            f'gpustack_engine_decode_kv_positions_total{{kind="live"}} '
+            f"{kv_live}",
+            f"gpustack_engine_decode_kv_positions_total"
+            f'{{kind="allocated"}} {kv_allocated}',
             decl("gpustack_engine_occupancy_ratio"),
             f"gpustack_engine_occupancy_ratio "
             f"{slots_used / max(1, self.slots_total):.4f}",

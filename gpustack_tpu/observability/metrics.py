@@ -77,6 +77,11 @@ METRIC_FAMILIES = {
     # its prefill programs, bucket padding included, by whether the
     # pair's expert is held here (label held=yes|no); absent otherwise
     "gpustack_engine_moe_pairs_total": "counter",
+    # cached positions over the decode steps (label kind=live|allocated):
+    # what the steps' live slots attended, by the scheduler's count, and
+    # the slots x max_len a step that the cache allocates; live over
+    # allocated is the share of the cache a decode step has to read
+    "gpustack_engine_decode_kv_positions_total": "counter",
     # the device's KV cache: its bytes, and the bytes one position of
     # one layer takes (1,152 for an MLA latent of 512 + 64 in bf16)
     "gpustack_engine_kv_cache_bytes": "gauge",

@@ -14,11 +14,12 @@ of that slot's cached positions: the block is fetched once for all heads
 (at 64 heads about 128 operations a byte, near a v5e's ridge), the
 running max / sum / accumulator persist in VMEM across the sweep over
 the blocks (online softmax, float32), and the ``[H, S]`` score matrix
-never exists in HBM. A slot's live length bounds its work two ways, both
-from its position (a scalar-prefetch operand): a block wholly above it
-is never fetched (the index map names the last needed block again, and a
-point that names the block already resident copies nothing) and never
-computed.
+never exists in HBM. A slot's length (how many positions it attends, a
+scalar-prefetch operand; 0 for a slot nobody holds) bounds its work two
+ways: a block at or above it is never fetched (the index map names the
+block already resident instead, and such a point copies nothing:
+``ops/decode_attention.py slot_walk``) and never computed. A slot
+of length 0 reads nothing and gives zeros.
 
 Both caches are passed with all their layers, as they are stored, and the
 layer's index prefetched: a custom call cannot read the scan's slice in
@@ -40,7 +41,6 @@ described v5e at A.X-K1's widths.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -48,40 +48,24 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_LANES = 128
-_NEG = -1e30
-# cached positions a grid point: 1,024 x 576 values are 1.2 MB in bf16,
-# twice for the two buffers and 2.4 MB more as float32 operands, well
-# inside the 16 MiB a kernel is given; a grid point costs about 0.3 us
-# whatever it does, so the largest block that divides the cache
-_BLOCKS = (1024, 512, 256, 128)
-
-
-def block_positions(max_len: int) -> Optional[int]:
-    """The block of cached positions for a cache of ``max_len``: the
-    largest of ``_BLOCKS`` that divides it; a cache shorter than the
-    smallest is one block (the tests'); None where nothing divides, and
-    the caller takes the XLA formulation."""
-    for b in _BLOCKS:
-        if max_len % b == 0:
-            return b
-    if max_len < _BLOCKS[-1] and max_len % 8 == 0:
-        return max_len
-    return None
-
-
-def _across(x, n: int):
-    """``x`` [rows, 128], every lane of a row alike, as [rows, n]."""
-    return jnp.tile(x, (1, -(-n // _LANES)))[:, :n]
+from gpustack_tpu.ops.decode_attention import (
+    _LANES,
+    _NEG,
+    _across,
+    Walk,
+    block_positions,
+    cached_block,
+    slot_walk,
+)
 
 
 def _kernel(
-    pos_ref, layer_ref, ql_ref, qp_ref, c_ref, r_ref, o_ref,
-    m_ref, l_ref, acc_ref, *, scale: float, block_s: int,
+    len_ref, slot_ref, block_ref, layer_ref, ql_ref, qp_ref, c_ref, r_ref,
+    o_ref, m_ref, l_ref, acc_ref, *, scale: float, block_s: int,
 ):
     """Grid point = (slot, block of cached positions): the slot's ``H``
     heads against ``block_s`` positions of its latent rows."""
-    del layer_ref
+    del slot_ref, block_ref, layer_ref
     b, j = pl.program_id(0), pl.program_id(1)
     rank = ql_ref.shape[1]
 
@@ -91,9 +75,9 @@ def _kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    pos = pos_ref[b]
+    length = len_ref[b]
 
-    @pl.when(j * block_s <= pos)
+    @pl.when(j * block_s < length)
     def _block():
         c = c_ref[...].astype(jnp.float32)                # [block_s, rank]
         s = lax.dot_general(
@@ -106,7 +90,7 @@ def _kernel(
             preferred_element_type=jnp.float32,
         )                                                 # [H, block_s]
         k_idx = j * block_s + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(k_idx <= pos, s * scale, _NEG)
+        s = jnp.where(k_idx < length, s * scale, _NEG)
         # the running max and sum stay broadcast over 128 lanes (as in
         # ops/flash_attention.py: a [rows, 1] column costs a register
         # for every eight rows)
@@ -127,46 +111,55 @@ def _kernel(
         ).astype(o_ref.dtype)
 
 
+def mla_walk(lengths: jax.Array, max_len: int) -> Walk:
+    """:func:`slot_walk` over a latent cache of ``max_len`` positions, in
+    the largest block that divides it: 1,024 x 576 values are 1.2 MB in
+    bf16, twice for the two buffers and 2.4 MB more as float32 operands,
+    well inside the 16 MiB a kernel is given."""
+    block_s = block_positions(max_len)
+    if block_s is None:
+        raise ValueError(f"no block divides a cache of {max_len}")
+    return slot_walk(lengths, max_len, block_s)
+
+
 def mla_decode_attention(
     q_lat: jax.Array,     # [B, H, rank]: q_nope through W_uk
     q_pe: jax.Array,      # [B, H, rope], rotated
     c_cache: jax.Array,   # [L, B, S, rank]: KVCache.k (MLA), without
     r_cache: jax.Array,   # [L, B, S, rope]: KVCache.v      its one head
     layer: jax.Array,     # int32 scalar: which of the L
-    positions: jax.Array,  # int32 [B]: each slot's query position
+    lengths,              # int32 [B]: positions each slot attends, 0..S,
+                          # or their Walk (mla_walk), made once a step
     scale: float,
     *,
     interpret: bool = False,
 ) -> jax.Array:
     """``softmax((q_lat . c + q_pe . k_r) * scale) @ c`` over positions
-    ``0 .. positions[b]`` of slot ``b``'s rows in layer ``layer``:
-    ``[B, H, rank]`` in ``q_lat``'s dtype, to go through ``W_uv``."""
+    ``0 .. lengths[b] - 1`` of slot ``b``'s rows in layer ``layer``:
+    ``[B, H, rank]`` in ``q_lat``'s dtype, to go through ``W_uv``; zeros
+    for a slot of length 0, whose rows are not read."""
     B, H, rank = q_lat.shape
     L, _, S, rope = r_cache.shape
-    block_s = block_positions(S)
-    if block_s is None:
-        raise ValueError(f"no block of {_BLOCKS} divides a cache of {S}")
+    walk = lengths if isinstance(lengths, Walk) else mla_walk(lengths, S)
+    block_s = walk.block_s
     n_blocks = S // block_s
 
-    def last(b, j, pos_ref):
-        # the last block that holds a position the slot attends: a point
-        # past it names that block again and nothing is copied
-        return jnp.minimum(j, pos_ref[b] // block_s)
-
-    def q_block(b, j, pos_ref, layer_ref):
+    def q_block(b, j, *_):
         return (b, 0, 0)
 
-    def c_block(b, j, pos_ref, layer_ref):
-        return (layer_ref[0], b, last(b, j, pos_ref), 0)
+    def c_block(b, j, *prefetched):
+        layer, slot, block = cached_block(b, j, *prefetched)
+        return (layer, slot, block, 0)
 
-    def r_block(b, j, pos_ref, layer_ref):
-        return (layer_ref[0], b, 0, last(b, j, pos_ref))
+    def r_block(b, j, *prefetched):
+        layer, slot, block = cached_block(b, j, *prefetched)
+        return (layer, slot, 0, block)
 
     return pl.pallas_call(
         functools.partial(_kernel, scale=scale, block_s=block_s),
         out_shape=jax.ShapeDtypeStruct((B, H, rank), q_lat.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=4,
             grid=(B, n_blocks),
             in_specs=[
                 pl.BlockSpec((None, H, rank), q_block),
@@ -187,7 +180,7 @@ def mla_decode_attention(
         name="mla_decode_attention",
         interpret=interpret,
     )(
-        jnp.clip(positions, 0, S - 1).astype(jnp.int32),
+        walk.lengths, walk.slot, walk.block,
         jnp.reshape(layer, (1,)).astype(jnp.int32),
         q_lat, q_pe, c_cache, jnp.transpose(r_cache, (0, 1, 3, 2)),
     )

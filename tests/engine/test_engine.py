@@ -163,6 +163,22 @@ def test_engine_health(engine):
     assert h["status"] == "ok" and h["slots_total"] == 4
 
 
+def test_the_engine_says_how_its_decode_step_attends(caplog):
+    """The form is static (the platform, the mesh, the cache's shape),
+    so the engine says it once: the runner's start-up line and
+    ``decode_attention`` in ``health()``. On the CPU it is the XLA form."""
+    import logging
+
+    cfg = get_config("tiny")
+    with caplog.at_level(logging.INFO, logger="gpustack_tpu.engine.runner"):
+        eng = LLMEngine(
+            cfg, init_params(cfg, jax.random.key(0)),
+            max_slots=2, max_seq_len=64,
+        )
+    assert eng.health()["decode_attention"] == "xla"
+    assert "decode attention: xla" in caplog.text
+
+
 def test_sampling_greedy_and_filters():
     logits = jnp.asarray(
         [[1.0, 2.0, 3.0, 0.5], [10.0, 0.0, 0.0, 0.0]], jnp.float32
@@ -309,6 +325,58 @@ def test_flight_says_which_expert_dispatch_a_prefill_ran(preset):
         )
     else:
         assert seen == [] and "moe_prompt_tokens" not in text
+
+
+def test_a_finished_slot_s_neighbours_decode_as_before():
+    """Under the decode kernel (interpret mode here) a slot whose request
+    has ended attends nothing from then on (``live`` = ``state.active``):
+    the requests beside it go on to the tokens the cacheless oracle
+    gives each alone, and the steps' records say what share of the cache
+    was live."""
+    import dataclasses
+    from unittest import mock
+
+    from gpustack_tpu.models import transformer
+
+    cfg = dataclasses.replace(
+        get_config("tiny-qwen3"), head_dim=128, dtype="float32"
+    )
+    params = init_params(cfg, jax.random.key(0), dtype=jnp.float32)
+    chosen = []
+
+    def interpreted(cfg, rows, max_len, platform, mesh):
+        chosen.append(rows)
+        return "kernel_interpret" if rows == 1 else "xla"
+
+    prompts = [[3, 1, 4, 1, 5], [15, 9, 2, 6], [5, 3, 5, 8, 9, 7]]
+    lengths = [12, 2, 9]          # the middle slot's tenant ends first
+    with mock.patch.object(transformer, "decode_attention_impl", interpreted):
+        eng = LLMEngine(cfg, params, max_slots=3, max_seq_len=64)
+        eng.start()
+        try:
+            reqs = [
+                eng.submit(GenRequest(
+                    prompt_ids=p, max_tokens=n, temperature=0.0,
+                ))
+                for p, n in zip(prompts, lengths)
+            ]
+            for r in reqs:
+                assert r.done.wait(180), r.request_id
+        finally:
+            eng.stop()
+    assert 1 in chosen                  # the decode program took the kernel
+    for r, p, n in zip(reqs, prompts, lengths):
+        assert r.output_ids == _greedy_reference(cfg, params, p, n)
+    live = [
+        e["kv_live_pct"] for e in eng.flight.snapshot() if "kv_live_pct" in e
+    ]
+    # three slots of 64: at most (5 + 12) + (6 + 9) + (4 + 2) positions
+    assert live and 0 < min(live) and max(live) <= 100 * 38 / 192
+    text = "\n".join(eng.flight.metrics_lines())
+    assert (
+        'gpustack_engine_decode_kv_positions_total{kind="allocated"} '
+        f"{192 * len(live)}"
+    ) in text
 
 
 def test_abort_frees_slot_mid_generation(engine):

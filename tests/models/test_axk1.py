@@ -118,7 +118,7 @@ def test_prefill_then_decode_through_the_latent_cache_against_the_reference(
     for i in range(T, T + N):
         logits, cache = forward(
             params, cfg, toks[:, i:i + 1], pos[:, i:i + 1], cache,
-            mla_decode_impl=decode,
+            decode_attn_impl=decode,
         )
         for b in range(2):
             got[b].append(np.asarray(logits[b, 0]))

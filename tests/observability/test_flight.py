@@ -269,6 +269,25 @@ class TestMetricsLines:
         }
         assert by_dispatch == {"grouped": 1500, "dense": 30}
 
+    def test_live_share_of_the_cache_a_decode_step(self):
+        """A step that dispatched a decode step says what share of the
+        cache's positions its live slots attend; any other step says
+        nothing, and the counters add both sides up."""
+        fr = FlightRecorder(slots_total=4)
+        _rec(fr, mode="prefill")
+        _rec(fr, mode="decode", kv_live=1500, kv_allocated=8192)
+        _rec(fr, mode="prefill", kv_live=2500, kv_allocated=8192)
+        got = [e.get("kv_live_pct") for e in fr.snapshot(limit=3)]
+        assert got == [None, 18.31, 30.52]
+        samples, _types = promtext.assert_well_formed(
+            "\n".join(fr.metrics_lines()) + "\n"
+        )
+        by_kind = {
+            s.labels["kind"]: s.value for s in samples
+            if s.name == "gpustack_engine_decode_kv_positions_total"
+        }
+        assert by_kind == {"live": 4000, "allocated": 16384}
+
     def test_families_all_declared(self):
         from gpustack_tpu.observability.metrics import METRIC_FAMILIES
 

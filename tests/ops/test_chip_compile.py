@@ -136,10 +136,18 @@ MAX_LEN = 2048
 
 def _int8_step_compiled(one_chip, cfg, slots: int, T: int):
     """``forward`` over int8 weights and a donated ``slots`` x ``MAX_LEN``
-    cache, ``T`` tokens a slot, compiled from shapes alone."""
+    cache, ``T`` tokens a slot, compiled from shapes alone. Over the
+    cache it attends as the chooser says for one TPU chip (``forward``
+    itself sees this process's CPU and would say ``"xla"``)."""
     from gpustack_tpu.models import init_params
     from gpustack_tpu.models.quant import quantize_params
-    from gpustack_tpu.models.transformer import KVCache, forward
+    from gpustack_tpu.models.transformer import (
+        KVCache,
+        decode_attention_impl,
+        forward,
+    )
+
+    attends = decode_attention_impl(cfg, T, MAX_LEN, "tpu", None)
 
     params = _shapes_on(
         one_chip, lambda: quantize_params(init_params(cfg, jax.random.key(0)))
@@ -148,7 +156,9 @@ def _int8_step_compiled(one_chip, cfg, slots: int, T: int):
     tokens = jax.ShapeDtypeStruct((slots, T), jnp.int32, sharding=one_chip)
 
     def step(params, tokens, positions, cache):
-        return forward(params, cfg, tokens, positions, cache)
+        return forward(
+            params, cfg, tokens, positions, cache, decode_attn_impl=attends
+        )
 
     return jax.jit(step, donate_argnums=(3,)).lower(
         params, tokens, tokens, cache
@@ -170,7 +180,14 @@ def test_a_step_moves_only_its_rows_of_the_donated_cache(
     int8 weights at published widths updates it in place. As ``xs`` in
     and ``ys`` out it cost a second cache of temporaries, two copies of
     the whole cache and each layer's slab written back whole (PERF.md,
-    PR 29); this keeps them from coming back with a JAX upgrade."""
+    PR 29); this keeps them from coming back with a JAX upgrade.
+
+    A decode step (``T`` = 1) attends through the kernel of
+    ``ops/decode_attention.py``, which reads the cache where it lies: no
+    value of a layer's slab's shape exists in the program at all, sliced,
+    copied or transposed (the two slab slices and the attention over
+    them were 7.75 of the 8B deployment's 24.1 ms step: PERF.md, PR 41).
+    The verify shape keeps the XLA form and its slab."""
     cfg = dataclasses.replace(get_config(preset), num_layers=layers)
     compiled = _int8_step_compiled(one_chip, cfg, slots, T)
     slab = slots * MAX_LEN * cfg.num_kv_heads * cfg.head_dim
@@ -181,9 +198,7 @@ def test_a_step_moves_only_its_rows_of_the_donated_cache(
 
     # every instruction's element count, by name; then no copy of the
     # whole cache, and no dynamic-update-slice that writes a layer's
-    # whole slab or more. (The slab a layer attends over is still
-    # sliced out of the carry and relaid out inside the attention
-    # fusion, as before: that copy is not the cache's.)
+    # whole slab or more
     text = compiled.as_text()
     size = {
         name: int(np.prod([int(d) for d in dims.split(",") if d]))
@@ -197,6 +212,65 @@ def test_a_step_moves_only_its_rows_of_the_donated_cache(
         r"dynamic-update-slice\(%[\w.-]+, %([\w.-]+),", text
     )
     assert updates and max(size[u] for u in updates) < slab, updates
+
+    # the slab, as stored or with positions and heads merged
+    kv = cfg.num_kv_heads
+    slabs = re.findall(
+        rf"= bf16\[(?:1,)?{slots},(?:{MAX_LEN},{kv}|{MAX_LEN * kv}),128\]"
+        r"[^ ]* ([\w-]+)\(", text,
+    )
+    kernel = re.search(
+        rf"%gqa_decode_attention[\w.\-]* = bf16\[{slots},{cfg.num_heads},128\]"
+        r".* custom-call\(", text,
+    )
+    if T == 1:
+        assert kernel and slabs == [], slabs
+        assert not re.findall(
+            rf"= {re.escape(whole)}[^ ]* (?:transpose|dynamic-slice)\(", text
+        )
+    else:
+        assert not kernel and slabs
+
+
+@pytest.mark.parametrize(
+    "preset,change,rows,max_len,platform,devices,want",
+    [
+        ("qwen3-8b", {}, 1, 2048, "tpu", 1, "kernel"),
+        ("qwen3-30b-a3b", {}, 1, 2048, "tpu", 1, "kernel"),
+        ("qwen2.5-7b", {}, 1, 32768, "tpu", 1, "kernel"),   # seven a group
+        ("llama3-70b", {}, 1, 8192, "tpu", None, "kernel"),  # no mesh at all
+        ("qwen3-8b", {}, 4, 2048, "tpu", 1, "xla"),         # verify, ingest
+        ("qwen3-8b", {}, 512, 2048, "tpu", 1, "xla"),       # a continuation
+        ("qwen3-8b", {}, 1, 2048, "tpu", 4, "xla"),         # tp, sp replicas
+        ("qwen3-8b", {}, 1, 2048, "cpu", 1, "xla"),
+        ("qwen3-8b", {}, 1, 2048, "gpu", 1, "xla"),
+        ("qwen3-8b", {}, 1, 1000, "tpu", 1, "xla"),         # no block divides
+        ("qwen3-8b", {"sliding_window": 1024}, 1, 2048, "tpu", 1, "xla"),
+        ("qwen3-8b", {"attn_logit_softcap": 50.0}, 1, 2048, "tpu", 1, "xla"),
+        ("qwen3-8b", {"attn_sinks": True}, 1, 2048, "tpu", 1, "xla"),
+        ("gemma2-9b", {}, 1, 2048, "tpu", 1, "xla"),
+        ("gpt-oss-20b", {}, 1, 2048, "tpu", 1, "xla"),
+        ("qwen3-8b", {"head_dim": 64}, 1, 2048, "tpu", 1, "xla"),
+        ("tiny", {}, 1, 2048, "tpu", 1, "xla"),             # heads of 16
+        ("deepseek-v2-lite", {}, 1, 2048, "tpu", 1, "kernel"),   # the latent
+        ("deepseek-v2-lite", {}, 1, 1000, "tpu", 1, "xla"),
+        ("deepseek-v2-lite", {}, 4, 2048, "tpu", 1, "xla"),
+    ],
+)
+def test_the_chooser_s_table(
+    preset, change, rows, max_len, platform, devices, want
+):
+    """``decode_attention_impl``: the kernel for a decode step on one TPU
+    chip whose cache divides into blocks and whose scores it can compute;
+    the XLA form for everything else. Shapes and the mesh, never a name."""
+    from gpustack_tpu.models.transformer import decode_attention_impl
+
+    class Mesh:
+        size = devices
+
+    mesh = None if devices is None else Mesh()
+    cfg = dataclasses.replace(get_config(preset), **change)
+    assert decode_attention_impl(cfg, rows, max_len, platform, mesh) == want
 
 
 @pytest.mark.parametrize("T", [1, 4])
@@ -448,7 +522,7 @@ def test_the_latent_decode_step_moves_no_cache_but_the_rope_keys(one_chip):
     def step(params, cache, tokens, positions):
         return forward(
             params, cfg, tokens, positions, cache,
-            mla_decode_impl="kernel", moe_dispatch_impl="dense",
+            decode_attn_impl="kernel", moe_dispatch_impl="dense",
         )
 
     compiled = jax.jit(step, donate_argnums=(1,)).lower(
