@@ -33,6 +33,8 @@ from gpustack_tpu.engine.openai_tools import (
     forced_function,
     parse_tool_calls,
 )
+from gpustack_tpu.observability.startup import EngineStart, process_programs
+from gpustack_tpu.observability.tracing import LOG_FORMAT
 
 logger = logging.getLogger(__name__)
 
@@ -105,11 +107,19 @@ def _completion_logprobs(req: GenRequest, tokenizer, k: int) -> Dict[str, Any]:
 class OpenAIServer:
     """aiohttp application serving one LLMEngine."""
 
-    def __init__(self, engine: LLMEngine, model_name: Optional[str] = None):
+    def __init__(
+        self,
+        engine: LLMEngine,
+        model_name: Optional[str] = None,
+        startup: Optional[EngineStart] = None,
+    ):
         from gpustack_tpu.observability.tracing import trace_middleware
 
         self.engine = engine
         self.model_name = model_name or engine.cfg.name
+        # the process's start span (``main`` opens it); a server built
+        # inside another process has none
+        self.startup = startup
         # the engine is the last hop of the trace: the middleware adopts
         # the worker proxy's traceparent and logs this hop's trace=… line.
         # Body cap matches the worker reverse proxy's (256 MiB): a KV
@@ -129,6 +139,7 @@ class OpenAIServer:
                 web.post("/v1/rerank", self.rerank),
                 web.get("/metrics", self.metrics),
                 web.get("/debug/flight", self.debug_flight),
+                web.get("/debug/startup", self.debug_startup),
                 web.post("/debug/profile", self.debug_profile),
                 # disaggregated prefill/decode (docs/KV_CACHE.md "KV
                 # handoff"): content-addressed block export/import
@@ -154,9 +165,12 @@ class OpenAIServer:
 
     async def healthz(self, request: web.Request) -> web.Response:
         health = self.engine.health()
-        return web.json_response(
-            health, status=200 if health["status"] == "ok" else 503
-        )
+        ok = health["status"] == "ok"
+        if self.startup is not None:
+            if ok:
+                self.startup.mark_ready()
+            health["startup"] = self.startup.summary()
+        return web.json_response(health, status=200 if ok else 503)
 
     async def models(self, request: web.Request) -> web.Response:
         return web.json_response(
@@ -310,7 +324,19 @@ class OpenAIServer:
                 lines.append(f'{name}_bucket{{le="{le}"}} {c}')
             lines.append(f"{name}_sum {total:.6f}")
             lines.append(f"{name}_count {count}")
+        if self.startup is not None:
+            lines.extend(self.startup.metrics_lines())
         return web.Response(text="\n".join(lines) + "\n")
+
+    async def debug_startup(self, request: web.Request) -> web.Response:
+        """The span of this process's start (observability/startup.py):
+        its phases from the process's creation to listening, the
+        ``ready`` and ``first_token`` events, and a record for every
+        program lowered or loaded in the process's life, by name. The
+        ``trace_id`` is the worker's ``instance_start`` span's."""
+        if self.startup is None:
+            return _error(404, "this server was not started by main()")
+        return web.json_response(self.startup.describe())
 
     async def debug_flight(self, request: web.Request) -> web.Response:
         """Raw flight-recorder view: the most recent per-step records
@@ -1553,7 +1579,13 @@ def _error(status: int, message: str) -> web.Response:
 # ---------------------------------------------------------------------------
 
 
-def build_engine_from_args(args) -> LLMEngine:
+def build_engine_from_args(
+    args, start: Optional[EngineStart] = None
+) -> LLMEngine:
+    """``start`` is the process's start span, in its ``backend`` phase:
+    the phases ``config``, ``weights`` and ``engine`` are entered here,
+    each where its work begins."""
+    enter = start.enter if start is not None else (lambda phase: None)
     # Hermetic-test hook: the serve manager sets GPUSTACK_TPU_PLATFORM=cpu
     # (from --force-platform) so engine subprocesses run on the CPU
     # backend; without it the worker sets JAX_PLATFORMS=tpu and a chip
@@ -1581,7 +1613,9 @@ def build_engine_from_args(args) -> LLMEngine:
             ),
             process_id=int(os.environ.get("GPUSTACK_TPU_PROCESS_ID", "0")),
         )
+    n_devices = len(jax.devices())      # the chip's runtime is up
 
+    enter("config")
     from gpustack_tpu.models import init_params
     from gpustack_tpu.models.config import get_config, load_hf_config
     from gpustack_tpu.models.quant import quantize_params
@@ -1612,11 +1646,12 @@ def build_engine_from_args(args) -> LLMEngine:
         plan = MeshPlan.parse(args.mesh_plan)
     else:
         plan = plan_mesh(
-            min(len(jax.devices()), args.num_devices or len(jax.devices())),
+            min(n_devices, args.num_devices or n_devices),
             cfg.num_kv_heads,
             cfg.num_experts,
         )
 
+    enter("weights")
     from gpustack_tpu.engine.weights import load_or_init_params
 
     lora = getattr(args, "lora", None)
@@ -1646,7 +1681,12 @@ def build_engine_from_args(args) -> LLMEngine:
         else:
             draft_cfg = get_config(source)
             draft_params = load_or_init_params(draft_cfg, None, seed=0)
+    if start is not None:
+        # the phase ends with the tree on the device, not with its last
+        # program dispatched (once, here; never in a serving path)
+        jax.block_until_ready((params, draft_params))
 
+    enter("engine")
     # the decode batch is dp-sharded, so the slot count must be a
     # multiple of the mesh's dp degree; round capacity UP rather than
     # crash in device_put when the auto-planner picks dp > max_slots
@@ -1732,6 +1772,10 @@ def build_engine_from_args(args) -> LLMEngine:
 
 
 def main(argv=None) -> None:
+    # the process's start span ends its ``import`` phase here, and the
+    # compile listeners are on before the first program (the weights')
+    entered = time.time()
+    programs = process_programs()
     p = argparse.ArgumentParser("gpustack-tpu engine API server")
     p.add_argument("--model-dir", default="")
     p.add_argument("--preset", default="llama3-8b")
@@ -1807,8 +1851,12 @@ def main(argv=None) -> None:
     )
     args = p.parse_args(argv)
 
-    logging.basicConfig(level=logging.INFO)
-    engine = build_engine_from_args(args)
+    logging.basicConfig(level=logging.INFO, format=LOG_FORMAT)
+    start = EngineStart(programs, model=args.served_name or args.preset)
+    start.enter("backend", at=entered)
+    engine = build_engine_from_args(args, start)
+    start.enter("listen")
+    engine.on_first_token = start.mark_first_token
     follower = getattr(engine, "follower_loop", None)
     if follower is not None:
         # follower host of a multi-host replica: no scheduling loop —
@@ -1818,7 +1866,14 @@ def main(argv=None) -> None:
         follower.start()
     else:
         engine.start()
-    server = OpenAIServer(engine, model_name=args.served_name or None)
+    server = OpenAIServer(
+        engine, model_name=args.served_name or None, startup=start
+    )
+
+    def listening(*message) -> None:
+        # run_app's one message, printed once its sites accept
+        start.listening()
+        print(*message, flush=True)
 
     async def on_startup(app):
         async def watchdog():
@@ -1837,7 +1892,9 @@ def main(argv=None) -> None:
         app["engine_watchdog"] = asyncio.create_task(watchdog())
 
     server.app.on_startup.append(on_startup)
-    web.run_app(server.app, host=args.host, port=args.port)
+    web.run_app(
+        server.app, host=args.host, port=args.port, print=listening
+    )
 
 
 if __name__ == "__main__":

@@ -21,6 +21,8 @@ import uuid
 
 from aiohttp import web
 
+from gpustack_tpu.observability.tracing import LOG_FORMAT
+
 logger = logging.getLogger(__name__)
 
 
@@ -368,7 +370,7 @@ def main(argv=None) -> None:
     p.add_argument("--mesh-plan", default="")
     args, _ = p.parse_known_args(argv)
 
-    logging.basicConfig(level=logging.INFO)
+    logging.basicConfig(level=logging.INFO, format=LOG_FORMAT)
     engine = build_audio_engine_from_args(args)
     server = AudioServer(engine, model_name=args.served_name or None)
     web.run_app(server.app, host=args.host, port=args.port)

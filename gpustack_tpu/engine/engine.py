@@ -42,8 +42,7 @@ import queue
 import tempfile
 import threading
 import time
-import weakref
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -52,6 +51,7 @@ from gpustack_tpu.engine.runner import DecodeState, ModelRunner
 from gpustack_tpu.engine.tokenizer import load_tokenizer
 from gpustack_tpu.models.config import ModelConfig
 from gpustack_tpu.observability import flight as _flight
+from gpustack_tpu.observability import startup as _startup
 
 logger = logging.getLogger(__name__)
 
@@ -115,35 +115,6 @@ GUARDED_BY = {
 # its phases: a stall of seconds (PERF.md, the token-loss mode) then
 # names where its time went in the engine's own log
 _SLOW_STEP_S = 1.0
-
-# jax.monitoring's listeners are process-wide and an engine has no sure
-# end of life (tests build many and stop few), so the process registers
-# one pair of listeners, with its first engine, and they feed the flight
-# recorder of every engine still alive.
-_compile_sinks: "weakref.WeakSet[_flight.FlightRecorder]" = weakref.WeakSet()
-_compile_sinks_mu = threading.Lock()
-_compile_listeners_on = False
-
-
-def _on_compile_event(event: str, seconds: float = 0.0, **_kw) -> None:
-    with _compile_sinks_mu:
-        sinks = list(_compile_sinks)
-    for recorder in sinks:
-        recorder.note_compile_event(event, seconds)
-
-
-def _watch_compiles(recorder: _flight.FlightRecorder) -> None:
-    """Count this process's lowerings and compiles into ``recorder``."""
-    global _compile_listeners_on
-    with _compile_sinks_mu:
-        _compile_sinks.add(recorder)
-        if not _compile_listeners_on:
-            jax.monitoring.register_event_duration_secs_listener(
-                _on_compile_event
-            )
-            jax.monitoring.register_event_listener(_on_compile_event)
-            _compile_listeners_on = True
-
 
 # thread-boundary contract (analysis/rules/thread_boundary.py): the
 # scheduler's working state must never be reached from `async def`
@@ -586,8 +557,15 @@ class LLMEngine:
         # Flight recorder: one record per scheduler step, always on
         # (observability/flight.py — the self-measured overhead ratio
         # is exported and tier-1 asserts it stays <1% of step time).
-        self.flight = _flight.FlightRecorder(max_slots)
-        _watch_compiles(self.flight)
+        # Its compile counters are the process's (jax.monitoring's
+        # listeners are process-wide): every engine of a process reads
+        # the one log, which an engine server opens before its weights.
+        self.flight = _flight.FlightRecorder(
+            max_slots, programs=_startup.process_programs()
+        )
+        # called once, with its time.time(), when this engine hands on
+        # the first token of its life (the engine server's start span)
+        self.on_first_token: Optional[Callable[[float], None]] = None
         # per-step accumulators reset at the top of step(); written only
         # by the scheduler thread
         self._phases = _flight.StepPhases()
@@ -1132,7 +1110,7 @@ class LLMEngine:
         self._overlap_seen = overlap_total
         mode = self._step_mode or "decode"
         phases_s = self._phases.seconds
-        self.flight.record(
+        programs = self.flight.record(
             dur_s=dur_s,
             host_overlap_s=max(0.0, overlap_delta),
             phases_s=phases_s,
@@ -1160,11 +1138,16 @@ class LLMEngine:
         )
         if dur_s > _SLOW_STEP_S:
             logger.warning(
-                "slow scheduler step: %.0f ms, mode %s, %s",
+                "slow scheduler step: %.0f ms, mode %s, %s%s",
                 dur_s * 1e3, mode, ", ".join(
                     f"{name} {sec * 1e3:.0f} ms"
                     for name, sec in zip(_flight.PHASES, phases_s)
                 ),
+                "; programs " + ", ".join(
+                    f"{name} (lower {lower_ms:.0f} ms, "
+                    f"{'load' if cached else 'compile'} {load_ms:.0f} ms)"
+                    for name, lower_ms, load_ms, cached in programs
+                ) if programs else "",
             )
         # unlocked fast-path probe: None is the steady state, and a
         # stale non-None just pays one _profile_step() lock round-trip
@@ -2102,6 +2085,9 @@ class LLMEngine:
             self._step_first.append(
                 (req.trace_id, req.first_token_at - req.submitted_at)
             )
+            if self.on_first_token is not None:
+                hook, self.on_first_token = self.on_first_token, None
+                hook(req.first_token_at)
         offload: List[int] = []
         for j, tok in enumerate(toks):
             is_eos = tok in self.tokenizer.eos_ids or tok in req.stop_ids

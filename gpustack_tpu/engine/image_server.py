@@ -24,6 +24,8 @@ from typing import Optional
 
 from aiohttp import web
 
+from gpustack_tpu.observability.tracing import LOG_FORMAT
+
 logger = logging.getLogger(__name__)
 
 SIZE_CHOICES = (256, 512, 768, 1024)
@@ -270,7 +272,7 @@ def main(argv=None) -> None:
     p.add_argument("--mesh-plan", default="")
     args, _ = p.parse_known_args(argv)
 
-    logging.basicConfig(level=logging.INFO)
+    logging.basicConfig(level=logging.INFO, format=LOG_FORMAT)
     engine = build_image_engine_from_args(args)
     server = ImageServer(engine, model_name=args.served_name or None)
     web.run_app(server.app, host=args.host, port=args.port)

@@ -12,6 +12,8 @@ import asyncio
 import logging
 import sys
 
+from gpustack_tpu.observability.tracing import LOG_FORMAT
+
 
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
@@ -115,7 +117,7 @@ def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     logging.basicConfig(
         level=logging.DEBUG if getattr(args, "debug", False) else logging.INFO,
-        format="%(asctime)s %(levelname)s %(name)s: %(message)s",
+        format=LOG_FORMAT,
     )
     if args.command == "version":
         from gpustack_tpu import __version__

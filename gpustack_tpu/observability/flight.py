@@ -49,6 +49,11 @@ Record vocabulary (per step):
   empty for a request made in-process.
 - ``traced``/``compiled`` — programs lowered and programs compiled (a
   persistent-cache miss) in this process since the last record.
+- ``programs`` — only in a step in which a program's record closed
+  (``observability/startup.py ProgramLog``): ``[name, lower_ms,
+  load_ms, cached]`` for each, Python tracing and lowering, then the
+  backend's compile or (``cached``) its load from the persistent cache.
+  A steady step's record does not have the key.
 - ``moe_dispatch`` — in a step that ran a prefill program of a model
   with experts: prompt tokens by the dispatch the program was traced
   with (``{"grouped": n}`` / ``{"dense": n}``,
@@ -85,21 +90,21 @@ import itertools
 import threading
 import time
 from collections import deque
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+
+from gpustack_tpu.observability.startup import (  # noqa: F401
+    BACKEND_COMPILE_EVENT,
+    CACHE_HIT_EVENT,
+    LOWERING_EVENT,
+    ProgramLog,
+    brief,
+)
 
 MODES = ("prefill", "prefill_chunk", "decode", "spec_verify")
 
 # the phases of a scheduler step, in the order the step runs them;
 # ``wait`` is innermost (inside ``drain``, ``chunk`` or ``admit``)
 PHASES = ("drain", "admit", "chunk", "dispatch", "wait")
-
-# what jax.monitoring calls the events the compile counters count
-# (observed on jax 0.9.0): one lowering a program, one backend compile
-# *or* persistent-cache load a program, and a cache hit announced just
-# before the latter
-LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
-BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 # step-time buckets: µs-scale stub steps through multi-second chunked
 # prefills on real hardware
@@ -268,8 +273,11 @@ def aggregate_records(
 # concurrency contract (checked by `python -m gpustack_tpu.analysis`,
 # rule guarded-by): one writer (the engine scheduler's record/note_*
 # calls), many readers (HTTP exporters, bench) — every touch of the
-# ring, histogram, counters, and self-measurement under `_mu`.
+# ring, histogram, counters, and self-measurement under `_mu`; how far
+# into the program log the last record read is the writer's alone.
 GUARDED_BY = {
+    "_programs_seen": ("record",),
+    "_programs_version": ("record",),
     "_ring": "_mu",
     "_hist": "_mu",
     "tokens_real_total": "_mu",
@@ -288,12 +296,6 @@ GUARDED_BY = {
     "host_overlap_s_total": "_mu",
     "idle_wait_s_total": "_mu",
     "rollback_tokens_total": "_mu",
-    "programs_traced_total": "_mu",
-    "programs_compiled_total": "_mu",
-    "compile_seconds_total": "_mu",
-    "_cache_hits_pending": "_mu",
-    "_traced_since": "_mu",
-    "_compiled_since": "_mu",
     "_record_s": "_mu",
     "_step_s": "_mu",
 }
@@ -311,8 +313,15 @@ class FlightRecorder:
     """
 
     def __init__(
-        self, slots_total: int, capacity: int = DEFAULT_CAPACITY
+        self,
+        slots_total: int,
+        capacity: int = DEFAULT_CAPACITY,
+        programs: Optional[ProgramLog] = None,
     ):
+        """``programs`` is the log of the process's programs
+        (``startup.process_programs()`` for an engine: it counts from
+        the process's start); without one the recorder has a log of its
+        own that nothing feeds."""
         self.slots_total = max(1, int(slots_total))
         self._mu = threading.Lock()
         # tuples, not dicts: the write path is on the scheduler's step
@@ -346,15 +355,11 @@ class FlightRecorder:
         self.host_overlap_s_total = 0.0
         self.idle_wait_s_total = 0.0
         self.rollback_tokens_total = 0
-        # programs lowered / compiled in this process (note_compile_event,
-        # fed by the engine's jax.monitoring listeners), and how many of
-        # each since the last step record
-        self.programs_traced_total = 0
-        self.programs_compiled_total = 0
-        self.compile_seconds_total = 0.0
-        self._cache_hits_pending = 0
-        self._traced_since = 0
-        self._compiled_since = 0
+        # the programs lowered / compiled in this process, and how far
+        # the last step record read: (lowered, compiled, records closed)
+        self.programs = programs if programs is not None else ProgramLog()
+        self._programs_version = self.programs.version
+        self._programs_seen: Tuple[int, int, int] = self.programs.counts()
         # self-measurement
         self._record_s = 0.0
         self._step_s = 0.0
@@ -385,18 +390,26 @@ class FlightRecorder:
         attn: Optional[str] = None,
         kv_live: int = 0,
         kv_allocated: int = 0,     # 0: the step dispatched no decode step
-    ) -> None:
+    ) -> Optional[Sequence[List[Any]]]:
+        """Returns the step's ``programs`` (None in a steady step)."""
         t0 = time.perf_counter()
+        traced = compiled = 0
+        programs: Optional[Sequence[List[Any]]] = None
+        if self.programs.version != self._programs_version:
+            self._programs_version = self.programs.version
+            was = self._programs_seen
+            seen = self._programs_seen = self.programs.counts()
+            traced, compiled = seen[0] - was[0], seen[1] - was[1]
+            if seen[2] != was[2]:
+                programs = [brief(r) for r in self.programs.since(was[2])]
         with self._mu:
-            traced, self._traced_since = self._traced_since, 0
-            compiled, self._compiled_since = self._compiled_since, 0
             self._ring.append((
                 time.time(), dur_s, mode, slots_used, waiting,
                 oldest_wait_s, tokens_real, tokens_padded, tokens_out,
                 prompt_tokens, spec_proposed, spec_accepted, kv_blocks,
                 kv_reused_total, host_overlap_s, phases_s, admitted,
                 first_tokens, traced, compiled, moe_dispatch, attn,
-                kv_live, kv_allocated,
+                kv_live, kv_allocated, programs,
             ))
             h = self._hist.get(mode)
             if h is None:
@@ -425,6 +438,7 @@ class FlightRecorder:
             self.host_overlap_s_total += host_overlap_s
             self._step_s += dur_s
             self._record_s += time.perf_counter() - t0
+        return programs
 
     def note_idle_wait(self, seconds: float) -> None:
         """Scheduler parked on its wakeup condition for ``seconds`` —
@@ -438,25 +452,21 @@ class FlightRecorder:
         with self._mu:
             self.rollback_tokens_total += tokens
 
-    def note_compile_event(self, event: str, seconds: float = 0.0) -> None:
-        """One ``jax.monitoring`` event (any thread of the process). A
-        lowering counts a program traced. A backend compile counts a
-        program compiled unless a persistent-cache hit was announced just
-        before it: JAX times the cache load under the same event."""
-        with self._mu:
-            if event == LOWERING_EVENT:
-                self.programs_traced_total += 1
-                self._traced_since += 1
-                self.compile_seconds_total += seconds
-            elif event == CACHE_HIT_EVENT:
-                self._cache_hits_pending += 1
-            elif event == BACKEND_COMPILE_EVENT:
-                self.compile_seconds_total += seconds
-                if self._cache_hits_pending:
-                    self._cache_hits_pending -= 1
-                else:
-                    self.programs_compiled_total += 1
-                    self._compiled_since += 1
+    # lowerings and compiles are the process's, not a recorder's: read
+    # from the one log (``compile_seconds_total`` is its two unions)
+
+    @property
+    def programs_traced_total(self) -> int:
+        return self.programs.counts()[0]
+
+    @property
+    def programs_compiled_total(self) -> int:
+        return self.programs.counts()[1]
+
+    @property
+    def compile_seconds_total(self) -> float:
+        totals = self.programs.totals()
+        return totals["lower_s"] + totals["load_s"]
 
     @staticmethod
     def _to_entry(row) -> Dict[str, Any]:
@@ -464,7 +474,8 @@ class FlightRecorder:
          tokens_real, tokens_padded, tokens_out, prompt_tokens,
          spec_proposed, spec_accepted, kv_blocks, kv_reused_total,
          host_overlap_s, phases_s, admitted, first_tokens, traced,
-         compiled, moe_dispatch, attn, kv_live, kv_allocated) = row
+         compiled, moe_dispatch, attn, kv_live, kv_allocated,
+         programs) = row
         entry = {
             "ts": ts,
             "dur_ms": round(dur_s * 1e3, 4),
@@ -500,6 +511,8 @@ class FlightRecorder:
             entry["attn"] = attn
         if kv_allocated:
             entry["kv_live_pct"] = round(100.0 * kv_live / kv_allocated, 2)
+        if programs:
+            entry["programs"] = programs
         return entry
 
     # ---- read side -----------------------------------------------------
@@ -577,9 +590,9 @@ class FlightRecorder:
             }
             idle_wait_s = self.idle_wait_s_total
             rollback_tokens = self.rollback_tokens_total
-            traced = self.programs_traced_total
-            compiled = self.programs_compiled_total
-            compile_s = self.compile_seconds_total
+        traced = self.programs_traced_total
+        compiled = self.programs_compiled_total
+        compile_s = self.compile_seconds_total
         lines = [decl("gpustack_engine_step_seconds")]
         for mode in sorted(hist):
             counts, total, count = hist[mode]

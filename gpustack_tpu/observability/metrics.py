@@ -100,13 +100,19 @@ METRIC_FAMILIES = {
     "gpustack_engine_idle_wait_seconds_total": "counter",
     "gpustack_engine_rollback_tokens_total": "counter",
     # programs lowered / compiled (persistent-cache misses) in the engine
-    # process and the seconds its threads stood in lowering, compiling
-    # or loading from the cache (jax.monitoring, ISSUE 26): a window
+    # process since its start, the weights' programs included, and the
+    # seconds its threads stood in tracing and lowering, compiling or
+    # loading from the cache (jax.monitoring, ISSUE 26, 42): a window
     # that meets a shape for the first time shows here even when the
     # persistent cache has the program
     "gpustack_engine_programs_traced_total": "counter",
     "gpustack_engine_programs_compiled_total": "counter",
     "gpustack_engine_compile_seconds_total": "counter",
+    # an engine process's start (observability/startup.py): seconds of
+    # each phase (import, backend, config, weights, engine, listen), and
+    # from the process's creation to the first /healthz 200
+    # (phase="ready") and to the first token (phase="first_token")
+    "gpustack_engine_start_seconds": "gauge",
     # proxy-side usage metering (routes/openai_proxy.py _record_usage):
     # per-model token throughput on /metrics instead of DB-only, plus a
     # loss counter so silently-swallowed usage writes become visible

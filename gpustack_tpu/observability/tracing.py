@@ -35,6 +35,13 @@ logger = logging.getLogger(__name__)
 
 TRACEPARENT_HEADER = "traceparent"
 REQUEST_ID_HEADER = "X-Request-ID"
+# how a trace crosses ``exec``: the W3C name for the header's value in a
+# child's environment (the worker sets it for the engine it spawns)
+TRACEPARENT_ENV = "TRACEPARENT"
+
+# the one log format of every process of the stack (server, worker,
+# engine servers): a line without a time cannot be laid beside a span
+LOG_FORMAT = "%(asctime)s %(levelname)s %(name)s: %(message)s"
 
 # probe/scrape chatter no hop should trace: a health poll every few
 # seconds would flood the hop log and evict real requests from the
@@ -43,7 +50,7 @@ REQUEST_ID_HEADER = "X-Request-ID"
 UNTRACED_PATHS = frozenset(
     {
         "/healthz", "/readyz", "/health", "/metrics", "/metrics/raw",
-        "/debug/flight",
+        "/debug/flight", "/debug/startup",
     }
 )
 
@@ -243,13 +250,18 @@ class RequestTrace:
         component: str,
         name: str,
         model: str = "",
+        started_at: float = 0.0,
     ):
+        """``started_at`` (``time.time()``) dates the span's zero in the
+        past: a process's start is traced from its creation, which is
+        before any of its code ran."""
         self.ctx = ctx
         self.component = component
         self.name = name
         self.model = model
-        self.started_at = time.time()
-        self._t0 = time.monotonic()
+        now = time.time()
+        self.started_at = started_at or now
+        self._t0 = time.monotonic() - (now - self.started_at)
         self._open: Dict[str, float] = {}
         self.phases: List[Dict[str, Any]] = []
         self.events: List[Dict[str, Any]] = []
@@ -316,10 +328,13 @@ class RequestTrace:
         status: int = 0,
         outcome: str = "",
         log: bool = True,
+        observe: bool = True,
         **attrs: Any,
     ) -> float:
         """Seal the trace; returns total duration in ms. Idempotent —
-        the first call wins (middleware and handler may both try)."""
+        the first call wins (middleware and handler may both try).
+        ``observe=False`` keeps a span that is no request (a replica's
+        start) out of the component's request-duration histogram."""
         if self._finished:
             return 0.0
         self._finished = True
@@ -351,7 +366,8 @@ class RequestTrace:
                 k: v for k, v in attrs.items() if v is not None
             }
         get_store(self.component).add(entry)
-        self._observe(duration_s, outcome)
+        if observe:
+            self._observe(duration_s, outcome)
         if log:
             logger.info("%s", self.log_line(entry))
         return entry["duration_ms"]

@@ -23,6 +23,12 @@ from gpustack_tpu.client.client import (
     ClientSet,
 )
 from gpustack_tpu.config import Config
+from gpustack_tpu.observability.tracing import (
+    TRACEPARENT_ENV,
+    RequestTrace,
+    TraceContext,
+    make_trace_id,
+)
 from gpustack_tpu.schemas import Model, ModelInstance, ModelInstanceState
 from gpustack_tpu.schemas.inference_backends import InferenceBackend
 from gpustack_tpu.server.bus import Event, EventType
@@ -54,6 +60,10 @@ class RunningInstance:
         # served model name: labels this instance's scraped engine
         # metrics on the worker exporter (worker/server.py)
         self.model_name = ""
+        # the span ``instance_start`` of the start in progress (phases
+        # ``spawn``, ``health_wait``); the engine's ``engine_start`` is
+        # its child. None once sealed into the worker's trace store.
+        self.start_trace: Optional[RequestTrace] = None
 
 
 class ServeManager:
@@ -432,6 +442,14 @@ class ServeManager:
                     instance_id, ModelInstanceState.ERROR, str(e)
                 )
             return
+        # one trace a replica start: this span from the command built to
+        # the engine's first 200, the engine process's own as its child
+        model_name = inst.model_name or model.name
+        trace = RequestTrace(
+            TraceContext(make_trace_id()), "worker", "instance_start",
+            model=model_name,
+        )
+        trace.begin("spawn")
 
         # multi-host leader: fence the jax.distributed coordinator port
         # pair (coordinator + command channel, engine/multihost.py)
@@ -503,11 +521,13 @@ class ServeManager:
         run.port = port
         run.is_leader = is_leader
         run.health_path = health_path_for(model, backend)
-        run.model_name = inst.model_name or model.name
+        run.model_name = model_name
+        run.start_trace = trace
         self.running[instance_id] = run
 
         env = dict(os.environ)
         env.update(extra_env)
+        env[TRACEPARENT_ENV] = trace.ctx.traceparent()
         # the engine subprocess must be able to import gpustack_tpu even
         # when the package isn't installed (repo checkout)
         import gpustack_tpu
@@ -546,6 +566,7 @@ class ServeManager:
             await asyncio.to_thread(_write_pidfile)
         except OSError as e:
             log_file.close()
+            self._seal_start(run, 500)
             if is_leader:
                 await self._set_state(
                     instance_id, ModelInstanceState.ERROR,
@@ -556,9 +577,11 @@ class ServeManager:
             if not log_file.closed:
                 log_file.close()
 
+        trace.end("spawn")
         # followers report nothing: the leader's health probe is the
         # instance's state (the engine blocks until all hosts rendezvous)
         if is_leader:
+            trace.begin("health_wait")
             await self._set_state(
                 instance_id, ModelInstanceState.STARTING, "",
                 port=port, pid=run.process.pid,
@@ -800,9 +823,24 @@ class ServeManager:
 
     # ---- monitoring -----------------------------------------------------
 
+    @staticmethod
+    def _seal_start(run: RunningInstance, status: int) -> None:
+        """Seal the start's span into the worker's trace store (``GET
+        /v2/debug/traces?component=worker``); no request, so not in the
+        request-duration histogram."""
+        trace, run.start_trace = run.start_trace, None
+        if trace is not None:
+            if status == 200:
+                trace.end("health_wait")     # _wait_healthy's 200
+            trace.finish(
+                status=status, observe=False,
+                instance_id=run.instance_id, model=run.model_name,
+            )
+
     async def _monitor(self, run: RunningInstance, model: Model) -> None:
         if run.is_leader:
             healthy = await self._wait_healthy(run)
+            self._seal_start(run, 200 if healthy else 503)
             if run.stopping:
                 return
             if healthy:
@@ -815,6 +853,7 @@ class ServeManager:
                     run.process.kill()
                 await self._crash(run, model, "engine failed health check")
                 return
+        self._seal_start(run, 200)      # a follower's: ``spawn`` alone
         # process exit watch
         assert run.process is not None
         code = await run.process.wait()

@@ -187,33 +187,78 @@ class TestStepRecordFields:
         assert aggregate_records(old, 4)["modes"]["decode"]["host_ms_p50"] == 42.0
 
 
+class _Events:
+    """What ``jax.monitoring`` would tell a recorder's program log, on a
+    clock of its own: each span begins where the last one ended."""
+
+    def __init__(self, fr, at=1000.0):
+        self.log, self.at = fr.programs, at
+
+    def span(self, event, seconds, name="jit(step)"):
+        self.log.on_time_span(event, self.at, self.at + seconds, fun_name=name)
+        self.at += seconds
+
+    def lowered(self, seconds=0.01, name="jit(step)"):
+        self.span(LOWERING_EVENT, seconds, name)
+
+    def loaded(self, seconds, name="jit(step)", cached=False):
+        if cached:
+            self.log.on_event(CACHE_HIT_EVENT)
+        self.span(BACKEND_COMPILE_EVENT, seconds, name)
+
+
 class TestCompileCounters:
     def test_a_cache_hit_is_traced_but_not_compiled(self):
         fr = FlightRecorder(slots_total=2)
-        fr.note_compile_event(LOWERING_EVENT, 0.01)
-        fr.note_compile_event(BACKEND_COMPILE_EVENT, 2.0)     # a miss
-        fr.note_compile_event(LOWERING_EVENT, 0.01)
-        fr.note_compile_event(CACHE_HIT_EVENT)
-        fr.note_compile_event(BACKEND_COMPILE_EVENT, 0.1)     # the load
-        fr.note_compile_event("/jax/core/compile/jaxpr_trace_duration", 9.0)
+        ev = _Events(fr)
+        ev.lowered(0.01)
+        ev.loaded(2.0)                         # a miss
+        ev.lowered(0.01)
+        ev.loaded(0.1, cached=True)            # the load
+        fr.programs.on_duration("/jax/some/other_duration", 9.0)
         assert fr.programs_traced_total == 2
         assert fr.programs_compiled_total == 1
         assert fr.compile_seconds_total == pytest.approx(2.12)
 
     def test_each_record_carries_what_came_since_the_last(self):
         fr = FlightRecorder(slots_total=2)
+        ev = _Events(fr)
         _rec(fr)
-        fr.note_compile_event(LOWERING_EVENT, 0.01)
-        fr.note_compile_event(BACKEND_COMPILE_EVENT, 0.5)
-        fr.note_compile_event(LOWERING_EVENT, 0.01)
+        ev.lowered(0.01, "jit(prefill_64)")
+        ev.loaded(0.5, "jit(prefill_64)")
+        ev.lowered(0.01, "jit(decode)")
         _rec(fr)
         _rec(fr)
-        got = [(e["traced"], e["compiled"]) for e in fr.snapshot(limit=3)]
+        first, second, third = fr.snapshot(limit=3)
+        got = [(e["traced"], e["compiled"]) for e in (first, second, third)]
         assert got == [(0, 0), (2, 1), (0, 0)]
+        # the program whose record closed in the step, by name; a steady
+        # step's record has no such key (and no other key more)
+        assert second["programs"] == [["jit(prefill_64)", 10.0, 500.0, False]]
+        assert "programs" not in first and "programs" not in third
+        assert set(second) - set(first) == {"programs"}
         text = "\n".join(fr.metrics_lines())
         assert "gpustack_engine_programs_traced_total 2" in text
         assert "gpustack_engine_programs_compiled_total 1" in text
         assert "gpustack_engine_compile_seconds_total 0.52" in text
+
+    def test_a_recorder_counts_from_its_logs_start_and_records_from_its_own(self):
+        from gpustack_tpu.observability.startup import ProgramLog
+
+        log = ProgramLog()
+        early = FlightRecorder(slots_total=2, programs=log)
+        ev = _Events(early)
+        ev.lowered(0.2, "jit(init)")
+        ev.loaded(1.0, "jit(init)")
+        late = FlightRecorder(slots_total=2, programs=log)
+        # the totals are the process's, whenever the recorder was made
+        assert late.programs_traced_total == early.programs_traced_total == 1
+        assert late.compile_seconds_total == pytest.approx(1.2)
+        # a step record carries what came since the recorder's last
+        _rec(late)
+        _rec(early)
+        assert late.snapshot(limit=1)[0]["traced"] == 0
+        assert early.snapshot(limit=1)[0]["programs"][0][0] == "jit(init)"
 
 
 class TestMetricsLines:
