@@ -5,10 +5,13 @@
 # that measures and then traces (--trace 2), unless TRACED=0. A run that fails, or is not correct, ends
 # everything: a set with such a run is no set, and chip time is dear.
 # RUN_ARGS is passed on to run.py (RUN_ARGS=--rehearse tries this script on the CPU).
-# Run i of every set has the seed SEED0 + i (SEED0: 3000000000).
+# Run i of every set has the seed SEED0 + i * SEED_STEP (SEED0: 3000000000;
+# SEED_STEP: 1; SEED_STEP=0 runs one seed over and over, which splits the
+# schedule's part of a spread from the machine's).
 runs=$1; sets=$2; shift 2
 first=${FIRST_SET:-1}
 seed0=${SEED0:-3000000000}
+step=${SEED_STEP:-1}
 cd .archive_check || exit 9
 out=../chiprun_out/perfbench/sets; mkdir -p $out
 one() {  # tag, then run.py's arguments
@@ -26,7 +29,7 @@ one() {  # tag, then run.py's arguments
 for s in $(seq $first $((first + sets - 1))); do
   for cell in "$@"; do
     for i in $(seq 1 $runs); do
-      one $cell.S$s.$i --workload $cell --seed $((seed0 + i)) --trace 0
+      one $cell.S$s.$i --workload $cell --seed $((seed0 + i * step)) --trace 0
     done
   done
 done
