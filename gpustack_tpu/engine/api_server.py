@@ -220,6 +220,12 @@ class OpenAIServer:
         ):
             lines.append(f"# TYPE {family} {METRIC_FAMILIES[family]}")
             lines.append(f"{family} {value}")
+        family = "gpustack_engine_cache_bytes"
+        lines.append(f"# TYPE {family} {METRIC_FAMILIES[family]}")
+        for kind in ("kv", "state"):
+            lines.append(
+                f'{family}{{kind="{kind}"}} {h["cache"][kind + "_bytes"]}'
+            )
         if h.get("moe_pairs") is not None:   # a share of the experts
             family = "gpustack_engine_moe_pairs_total"
             lines.append(f"# TYPE {family} {METRIC_FAMILIES[family]}")

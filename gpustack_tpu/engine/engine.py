@@ -477,6 +477,35 @@ class _KVStager:
             return fut
 
 
+def _refuse_for_a_state(
+    cfg, speculative, host_kv_cache_mb, kv_spill_mb, kv_role, prefill_chunk
+) -> None:
+    """A model with state-space layers keeps a recurrent state a slot
+    beside its rows (``KVCache.ssm``), which is no span of positions:
+    whatever cuts, stores, moves or rolls back a slot by positions would
+    serve tokens from a state that is not the sequence's. Each such
+    mechanism is refused here, at engine start, by name."""
+    asked = [
+        (speculative, f"speculative={speculative!r}: a verify step cannot "
+         "roll a recurrent state back past a rejected draft"),
+        (host_kv_cache_mb > 0, "host_kv_cache_mb: the prefix cache "
+         "(engine/kv_host_cache.py) keeps blocks of rows a token span; a "
+         "prefix's recurrent state is not among them"),
+        (kv_spill_mb > 0, "kv_spill_mb: the spill tier (engine/kv_spill.py) "
+         "stores blocks of rows and no recurrent state"),
+        (kv_role, f"kv_role={kv_role!r}: a KV handoff (engine/"
+         "kv_transfer.py) moves blocks of rows and no recurrent state"),
+        (prefill_chunk > 0, "prefill_chunk: a chunk goes on from cached "
+         "rows (prefill_with_prefix), which carry no recurrent state"),
+    ]
+    for on, why in asked:
+        if on:
+            raise ValueError(
+                f"{cfg.name} has state-space layers and cannot be served "
+                f"with {why}"
+            )
+
+
 class LLMEngine:
     """Single-replica continuous-batching LLM engine."""
 
@@ -506,6 +535,11 @@ class LLMEngine:
         kv_spill_dir: str = "",      # spill directory ("" = derived tmp)
     ):
         self.cfg = cfg
+        if cfg.layer_kinds is not None:
+            _refuse_for_a_state(
+                cfg, speculative, host_kv_cache_mb, kv_spill_mb, kv_role,
+                prefill_chunk,
+            )
         self.tokenizer = tokenizer or load_tokenizer(model_dir)
         self.runner = ModelRunner(
             cfg, params, plan=plan, mesh=mesh,
@@ -518,7 +552,16 @@ class LLMEngine:
         self._kv_cache_bytes = int(cache.k.nbytes + cache.v.nbytes)
         self._kv_cache_bytes_per_token = cfg.kv_cache_bytes_per_token(
             8 * cache.k.dtype.itemsize
-        ) // cfg.num_layers
+        ) // max(1, cfg.num_kv_layers)
+        # the second kind of per-slot memory (a hybrid's recurrent
+        # state: KVCache.ssm / .conv), 0 for any other model
+        self._state_bytes = (
+            int(cache.ssm.nbytes + cache.conv.nbytes)
+            if cache.ssm is not None else 0
+        )
+        self._state_dtype = (
+            str(cache.ssm.dtype) if cache.ssm is not None else None
+        )
         self._slots: Dict[int, _SlotInfo] = {}
         self._free = list(range(max_slots))
         self._waiting: "queue.Queue[GenRequest]" = queue.Queue()
@@ -587,9 +630,11 @@ class LLMEngine:
         # (flight ``moe_read_pct``)
         self._step_moe_read = 0
         self._step_moe_held = 0
-        self._moe_held_a_step = cfg.num_held_experts * (
-            cfg.num_layers - cfg.first_k_dense
-        )
+        self._moe_held_a_step = cfg.num_held_experts * cfg.num_moe_layers
+        # a hybrid: slots whose state the step's decode step moved, and
+        # prompt tokens the step sent through the chunked scan
+        self._step_state_slots = 0
+        self._step_ssm_tokens = 0
         self._step_spec_proposed = 0
         self._step_spec_accepted = 0
         # on-demand profiler capture (capture_profile): the capturing
@@ -855,6 +900,19 @@ class LLMEngine:
             # one layer takes (ModelConfig.kv_row_shapes)
             "kv_cache_bytes": self._kv_cache_bytes,
             "kv_cache_bytes_per_token": self._kv_cache_bytes_per_token,
+            # both kinds of per-slot memory: rows a position, and a
+            # hybrid's recurrent state (0 and None for any other model)
+            "cache": {
+                "kv_bytes": self._kv_cache_bytes,
+                "state_bytes": self._state_bytes,
+                "state_dtype": self._state_dtype,
+            },
+            # how a state-space layer runs its scan over a prompt and
+            # moves its state in a decode step (runner.ssm_update:
+            # "kernel" the stacked state in place, live slots only);
+            # None for a model without such layers
+            "ssm_scan": self.runner.ssm_scan,
+            "ssm_update": self.runner.ssm_update,
             # under a share of the experts: the prefill programs' router
             # pairs by whether their expert is held here (else None)
             "moe_pairs": self.runner.moe_pairs(),
@@ -1055,6 +1113,7 @@ class LLMEngine:
         self._step_moe_dispatch = {}
         self._step_attn = None
         self._step_kv_live = self._step_kv_allocated = 0
+        self._step_state_slots = self._step_ssm_tokens = 0
         self._step_moe_read = self._step_moe_held = 0
         self._step_spec_proposed = self._step_spec_accepted = 0
         self._step_admitted = []
@@ -1099,6 +1158,8 @@ class LLMEngine:
         self._step_prompt += tokens
         self._step_padded += bucket
         self._step_attn = self.runner.attn_label(bucket)
+        if self._state_bytes:
+            self._step_ssm_tokens += tokens
         dispatch = self.runner.moe_dispatch_for(bucket)
         if dispatch is not None:
             by_dispatch = self._step_moe_dispatch
@@ -1150,6 +1211,10 @@ class LLMEngine:
             kv_allocated=self._step_kv_allocated,
             moe_read=self._step_moe_read,
             moe_held=self._step_moe_held,
+            ssm=(
+                (self._step_state_slots, self._step_ssm_tokens)
+                if self._state_bytes else None
+            ),
         )
         if dur_s > _SLOW_STEP_S:
             logger.warning(
@@ -1613,13 +1678,15 @@ class LLMEngine:
                 pk_dev, pv_dev, use_len, suffix_padded, len(suffix),
                 total_bucket,
             )
+            mixer = ()
         else:
             self._step_mode = self._step_mode or "prefill"
             self._note_prefill(len(ids), bucket)
-            last_logits, k, v = self.runner.prefill(padded, len(ids))
+            # a hybrid's prefill hands its recurrent state back too
+            last_logits, k, v, *mixer = self.runner.prefill(padded, len(ids))
         if kv_cache is not None:
             self._submit_kv_copy(ids, k, v, len(ids))
-        self._finalize_start(slot, req, last_logits, k, v)
+        self._finalize_start(slot, req, last_logits, k, v, *mixer)
 
     @staticmethod
     def _padded_embeds(req: GenRequest, bucket: int, n_ids: int):
@@ -1747,7 +1814,7 @@ class LLMEngine:
         return info
 
     def _finalize_start(
-        self, slot: int, req: GenRequest, last_logits, k, v
+        self, slot: int, req: GenRequest, last_logits, k, v, mixer=None
     ) -> None:
         """Insert a finished prefill into the decode state and feed the
         first sampled token (shared by the one-shot, cached and chunked
@@ -1786,6 +1853,7 @@ class LLMEngine:
                 self._state, k, v, slot, len(ids), toks[0],
                 req.temperature, req.top_k, req.top_p,
                 seed, req.seed is not None, req.logit_bias,
+                **({} if mixer is None else {"mixer": mixer}),
             )
             self._slots[slot] = self._new_slot_info(req)
             # deferred first-token feed: fetched (and rolled back if the
@@ -1795,11 +1863,12 @@ class LLMEngine:
             )
             return
         self._finalize_start_sync(
-            slot, req, k, v, seed, toks, tok_lp, top_ids, top_lps
+            slot, req, k, v, seed, toks, tok_lp, top_ids, top_lps, mixer
         )
 
     def _finalize_start_sync(
-        self, slot, req, k, v, seed, toks, tok_lp, top_ids, top_lps
+        self, slot, req, k, v, seed, toks, tok_lp, top_ids, top_lps,
+        mixer=None,
     ) -> None:
         """Synchronous first-token path (serial mode, speculative
         proposers, logprobs, multi-host broadcast runners): reads the
@@ -1822,16 +1891,19 @@ class LLMEngine:
             self._state, k, v, slot, len(ids), first,
             req.temperature, req.top_k, req.top_p,
             seed, req.seed is not None, req.logit_bias,
+            **({} if mixer is None else {"mixer": mixer}),
         )
         info = self._new_slot_info(req)
         if self.draft_runner is not None:
             # mirror the slot on the draft: prefill + insert (greedy)
             dk_bucket = self.draft_runner.bucket_for(max(1, len(ids)))
             d_padded = list(ids) + [0] * (dk_bucket - len(ids))
-            _, dk, dv = self.draft_runner.prefill(d_padded, len(ids))
+            _, dk, dv, *d_mixer = self.draft_runner.prefill(
+                d_padded, len(ids)
+            )
             self._draft_state = self.draft_runner.insert(
                 self._draft_state, dk, dv, slot, len(ids), first,
-                0.0, 0, 1.0,
+                0.0, 0, 1.0, **({"mixer": d_mixer[0]} if d_mixer else {}),
             )
         self._slots[slot] = info
         self._deliver(slot, info, [first], first_lps)
@@ -1908,6 +1980,8 @@ class LLMEngine:
                 for info in self._slots.values()
             )
             self._step_kv_allocated += self.max_slots * self.max_seq_len
+            if self._state_bytes:
+                self._step_state_slots += len(owners)
         self._step_count += 1
         return True
 

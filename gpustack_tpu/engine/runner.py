@@ -136,6 +136,17 @@ class ModelRunner:
         # seq-sharded over sp for the whole generation; prefill runs ring
         # attention, decode/verify the pmax/psum merge (ops/ring_attention).
         self.sp_mode = self.plan.sp > 1
+        # a hybrid (cfg.layer_kinds) keeps a recurrent state a slot
+        # beside its rows (KVCache.ssm / .conv)
+        self.hybrid = cfg.layer_kinds is not None
+        if self.hybrid and self.mesh.size > 1:
+            raise ValueError(
+                f"{cfg.name}: a model with state-space layers is served "
+                "on one device: its recurrent state and its kernels are "
+                "not sharded yet (tp/ep/dp), and 'ring' attention (sp>1) "
+                "shards a cache over positions, which a recurrent state "
+                f"has none of; got plan {self.plan}"
+            )
         if self.sp_mode:
             if cfg.is_mla:
                 raise ValueError(
@@ -226,6 +237,21 @@ class ModelRunner:
         )
         if self.decode_moe_dispatch:
             logger.info("decode experts: %s", self.decode_moe_dispatch)
+        # how a state-space layer moves its state, the form strings of
+        # /healthz and the flight record: the chunked scan in a prefill,
+        # in a decode step ``kernel`` (ops/ssm.py, the stacked state in
+        # place) or ``xla``; None without such layers
+        self.ssm_scan = self.ssm_update = None
+        if self.hybrid:
+            from gpustack_tpu.models.hybrid import ssm_update_impl
+
+            platform = self.mesh.devices.flat[0].platform
+            self.ssm_scan = "chunked_einsum"
+            self.ssm_update = ssm_update_impl(1, platform, self.mesh)
+            logger.info(
+                "state-space layers: scan %s, update %s",
+                self.ssm_scan, self.ssm_update,
+            )
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
         self._decode_routing = None
         self._prefills: Dict[int, Any] = {}
@@ -255,7 +281,13 @@ class ModelRunner:
         return jax.device_put(
             state,
             DecodeState(
-                cache=KVCache(self._cache_sharding, self._cache_sharding),
+                cache=KVCache(
+                    self._cache_sharding, self._cache_sharding,
+                    **(
+                        dict(ssm=self._replicated, conv=self._replicated)
+                        if self.hybrid else {}
+                    ),
+                ),
                 last_tokens=self._slot_sharding,
                 positions=self._slot_sharding,
                 active=self._slot_sharding,
@@ -326,8 +358,7 @@ class ModelRunner:
         cfg = self.cfg
         self._pairs_held = self._pairs_held + held
         self._pairs_routed += (
-            rows * cfg.num_experts_per_tok
-            * (cfg.num_layers - cfg.first_k_dense)
+            rows * cfg.num_experts_per_tok * cfg.num_moe_layers
         )
 
     def moe_dispatch_for(
@@ -357,9 +388,11 @@ class ModelRunner:
         self, params, tokens, true_len, *, attn_impl="xla", routing=False
     ):
         """tokens [1, Tb]; returns (last_logits [V], k, v [L, Tb, heads,
-        width]), and under a share of the experts the count of the
-        router's pairs on held experts; with ``routing`` last of all what
-        ``forward(routing_out=True)`` adds."""
+        width]); for a hybrid then ``(ssm, conv)``, the slot's recurrent
+        state after ``true_len`` tokens (not after the bucket: the
+        padding moves no state); under a share of the experts the count
+        of the router's pairs on held experts; with ``routing`` last of
+        all what ``forward(routing_out=True)`` adds."""
         Tb = tokens.shape[1]
         cache = KVCache.create(self.cfg, 1, Tb)
         positions = jnp.arange(Tb, dtype=jnp.int32)[None, :]
@@ -369,17 +402,23 @@ class ModelRunner:
             mesh=self.mesh,
             count_held_pairs=bool(self.cfg.experts_held),
             routing_out=routing,
+            **({"true_len": true_len[None]} if self.hybrid else {}),
         )
         last = jnp.take(logits[0], true_len - 1, axis=0)
-        return (last, cache.k[:, 0], cache.v[:, 0], *extras)
+        mixer = ((cache.ssm[:, 0], cache.conv[:, 0]),) if self.hybrid else ()
+        return (last, cache.k[:, 0], cache.v[:, 0], *mixer, *extras)
 
     def prefill(self, token_ids, true_len: int, routing: bool = False):
         """Run prefill at the bucket for ``true_len``. ``token_ids`` must be
         padded to the bucket length already (any pad id).
 
         ``routing``: the same program with one more output, each layer's
-        chosen experts and router logits (``forward``), returned fourth.
-        For a comparison with a reference; the engine never asks."""
+        chosen experts and router logits (``forward``), returned last.
+        For a comparison with a reference; the engine never asks.
+
+        A hybrid returns one more after ``k, v``: ``(ssm, conv)``, the
+        recurrent state the prompt ends in, for :meth:`insert`'s
+        ``mixer``."""
         Tb = len(token_ids)
         assert Tb in self.prefill_buckets, (Tb, self.prefill_buckets)
         fns = self._prefills_routing if routing else self._prefills
@@ -395,9 +434,10 @@ class ModelRunner:
             fns[Tb] = fn
         tokens = jnp.asarray(token_ids, jnp.int32)[None, :]
         last, k, v, *extras = fn(self.params, tokens, jnp.int32(true_len))
+        mixer = (extras.pop(0),) if self.hybrid else ()
         if self.cfg.experts_held:
             self._note_pairs(extras[0], Tb)
-        return (last, k, v, extras[-1]) if routing else (last, k, v)
+        return (last, k, v, *mixer, *((extras[-1],) if routing else ()))
 
     def _prefill_embeds_impl(
         self, params, tokens, true_len, embeds, mask, *, attn_impl="xla"
@@ -477,6 +517,12 @@ class ModelRunner:
         suffix_ids, suffix_true_len: int, total_bucket: int,
     ):
         """suffix_ids must be pre-padded to a prefill bucket."""
+        if self.hybrid:
+            raise ValueError(
+                f"{self.cfg.name}: a prefill cannot go on from cached rows "
+                "(prefix reuse, chunked prefill): the rows carry no "
+                "recurrent state to go on from"
+            )
         Pb = prefix_k.shape[1]
         Tsb = len(suffix_ids)
         key = (Pb, Tsb, total_bucket)
@@ -561,13 +607,23 @@ class ModelRunner:
     def _insert_impl(
         self, state, k, v, slot, true_len, first_token,
         temperature, top_k, top_p, seed, seeded, bias_ids, bias_vals,
+        mixer=None,
     ):
         Tb = k.shape[1]
         cache = state.cache
         new_k = cache.k.at[:, slot, :Tb].set(k)
         new_v = cache.v.at[:, slot, :Tb].set(v)
+        held = {}
+        if self.hybrid:
+            # the slot's whole recurrent state is the prompt's, nothing
+            # of its last tenant's stays (zeros without one)
+            ssm, conv = mixer if mixer is not None else (0.0, 0.0)
+            held = dict(
+                ssm=cache.ssm.at[:, slot].set(ssm),
+                conv=cache.conv.at[:, slot].set(conv),
+            )
         return DecodeState(
-            cache=KVCache(k=new_k, v=new_v),
+            cache=KVCache(k=new_k, v=new_v, **held),
             last_tokens=state.last_tokens.at[slot].set(first_token),
             positions=state.positions.at[slot].set(true_len),
             active=state.active.at[slot].set(True),
@@ -581,7 +637,11 @@ class ModelRunner:
         self, state: DecodeState, k, v, slot: int, true_len: int,
         first_token: int, temperature: float, top_k: int, top_p: float,
         seed: int = 0, seeded: bool = False, logit_bias=None,
+        mixer=None,
     ) -> DecodeState:
+        """Place a prefill's rows, and for a hybrid its recurrent state
+        (``mixer``: what :meth:`prefill` returned after ``k, v``), in
+        ``slot`` and make the slot live."""
         Tb = k.shape[1]
         fn = self._inserts.get(Tb)
         if fn is None:
@@ -594,6 +654,7 @@ class ModelRunner:
             jnp.int32(top_k), jnp.float32(top_p),
             jnp.uint32(seed), jnp.bool_(seeded),
             bias_ids, bias_vals,
+            *((mixer,) if self.hybrid else ()),
         )
 
     def deactivate(self, state: DecodeState, slot: int) -> DecodeState:
@@ -736,6 +797,8 @@ class ModelRunner:
             params, self.cfg, fed, positions, state.cache,
             attn_impl="ring" if self.sp_mode else "xla",
             mesh=self.mesh,
+            # a hybrid's recurrent state takes the tokens that count
+            **({"true_len": counts} if self.hybrid else {}),
         )
         has_any = counts > 0
         last_idx = jnp.maximum(counts - 1, 0)
@@ -773,11 +836,19 @@ class ModelRunner:
         speculative proposal run to rewind the draft's sequence state
         (cache entries above the restored positions are masked out).
         COPIES: the decode steps in between donate the state, which would
-        invalidate aliased buffers."""
-        return jnp.array(state.positions), jnp.array(state.last_tokens)
+        invalidate aliased buffers. A hybrid's recurrent state cannot be
+        masked out: every slot's is copied too and put back whole."""
+        snap = jnp.array(state.positions), jnp.array(state.last_tokens)
+        if self.hybrid:
+            snap += (jnp.array(state.cache.ssm), jnp.array(state.cache.conv))
+        return snap
 
     def restore_sequence(self, state: DecodeState, snap) -> DecodeState:
-        positions, last_tokens = snap
+        positions, last_tokens, *mixer = snap
+        if mixer:
+            state = dataclasses.replace(state, cache=dataclasses.replace(
+                state.cache, ssm=mixer[0], conv=mixer[1]
+            ))
         return dataclasses.replace(
             state, positions=positions, last_tokens=last_tokens
         )
@@ -800,6 +871,11 @@ class ModelRunner:
         ``position + P < max_seq_len`` (the engine falls back to plain
         decode near capacity) — the block KV write is contiguous.
         """
+        if self.hybrid:
+            raise ValueError(
+                f"{self.cfg.name}: a verify step cannot roll a recurrent "
+                "state back past a rejected draft"
+            )
         B, P = proposals.shape
         tokens = jnp.concatenate(
             [state.last_tokens[:, None], proposals[:, :-1]], axis=1

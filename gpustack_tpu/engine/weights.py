@@ -140,6 +140,8 @@ def build_lm_params(
     DeepSeek checkpoints split into a dense prefix stack
     (``first_k_dense`` layers) + a MoE remainder — forward scans them
     back-to-back (models/transformer.py)."""
+    if cfg.layer_kinds is not None:
+        return build_hybrid_params(cfg, tensors, quantization)
     L = cfg.num_layers
     take = _taker(tensors)
     kd = cfg.first_k_dense if cfg.is_moe else 0
@@ -414,6 +416,117 @@ def build_lm_params(
 
         # the layer stacks are int8 already; this takes embed / lm_head
         # (which one depends on the tie) and passes the rest through
+        params = quantize_params(params)
+    return params
+
+
+def build_hybrid_params(
+    cfg: ModelConfig, tensors: Dict[str, Any], quantization: str = ""
+) -> Dict[str, Any]:
+    """The Nemotron-H checkpoint's names (``backbone.layers.N.norm`` and
+    ``backbone.layers.N.mixer.*``, the mixer's kind by the layer's place
+    in ``hybrid_override_pattern``) -> the three stacks of
+    ``models/hybrid.py``, a leaf at a time. Matrices transpose on load
+    and go to int8 under ``quantization="int8"``; ``A_log``, ``D``,
+    ``dt_bias`` and the convolution stay float32, norms bf16. Under a
+    share of the experts only the held ids are read; the experts' width
+    is filled up to whole lane tiles (``pad_expert_width``)."""
+    from gpustack_tpu.models.hybrid import pad_expert_width
+
+    take = _taker(tensors)
+    int8 = quantization == "int8"
+    by_kind: Dict[str, list] = {"M": [], "E": [], "*": []}
+    for i, kind in enumerate(cfg.layer_kinds):
+        by_kind[kind].append(i)
+
+    def take32(name: str) -> jax.Array:
+        return jnp.asarray(
+            tensors.pop(name).float().numpy(), jnp.float32
+        )
+
+    def stacks(kind: str):
+        layers_of = by_kind[kind]
+
+        def stack(fmt: str, transpose: bool = False, get=take):
+            if get is take:
+                return jnp.stack(
+                    [take(fmt.format(i), transpose) for i in layers_of]
+                )
+            return jnp.stack([get(fmt.format(i)) for i in layers_of])
+
+        out: Dict[str, Any] = _QuantizeOnSet() if int8 else {}
+        out["norm"] = stack("backbone.layers.{}.norm.weight")
+        return out, stack
+
+    params: Dict[str, Any] = {}
+    mixer = "backbone.layers.{}.mixer."
+    if by_kind["M"]:
+        out, stack = stacks("M")
+        out["w_in"] = stack(mixer + "in_proj.weight", True)
+        # torch's depthwise conv1d weight is [C, 1, K]: ours [K, C]
+        out["conv_w"] = jnp.swapaxes(
+            stack(mixer + "conv1d.weight", get=take32)[:, :, 0, :], 1, 2
+        )
+        out["conv_b"] = stack(mixer + "conv1d.bias", get=take32)
+        for ours, theirs in (
+            ("dt_bias", "dt_bias"), ("A_log", "A_log"), ("D", "D"),
+        ):
+            out[ours] = stack(mixer + theirs, get=take32)
+        out["gate_norm"] = stack(mixer + "norm.weight")
+        out["w_out"] = stack(mixer + "out_proj.weight", True)
+        params["ssm_layers"] = dict(out)
+    if by_kind["E"]:
+        out, stack = stacks("E")
+        held = range(
+            cfg.first_held_expert,
+            cfg.first_held_expert + cfg.num_held_experts,
+        )
+        out["router"] = stack(mixer + "gate.weight", True)
+        out["router_bias"] = stack(
+            mixer + "gate.e_score_correction_bias", get=take32
+        )
+
+        def experts(which: str, axis: int):
+            return pad_expert_width(jnp.stack([
+                jnp.stack([
+                    take(
+                        f"backbone.layers.{i}.mixer.experts.{e}."
+                        f"{which}.weight", True,
+                    ) for e in held
+                ]) for i in by_kind["E"]
+            ]), axis)
+
+        out["we_up"] = experts("up_proj", -1)
+        out["we_down"] = experts("down_proj", -2)
+        if cfg.shared_expert_intermediate_size:
+            out["ws_up"] = stack(
+                mixer + "shared_experts.up_proj.weight", True
+            )
+            out["ws_down"] = stack(
+                mixer + "shared_experts.down_proj.weight", True
+            )
+        if cfg.experts_held:
+            # the absent experts' tensors are some other chip's
+            for name in [n for n in tensors if ".mixer.experts." in n]:
+                del tensors[name]
+        params["moe_layers"] = dict(out)
+    if by_kind["*"]:
+        out, stack = stacks("*")
+        for ours, theirs in (
+            ("wq", "q_proj"), ("wk", "k_proj"), ("wv", "v_proj"),
+            ("wo", "o_proj"),
+        ):
+            out[ours] = stack(mixer + theirs + ".weight", True)
+        params["attn_layers"] = dict(out)
+    params["embed"] = take("backbone.embeddings.weight")
+    params["final_norm"] = take("backbone.norm_f.weight")
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = take("lm_head.weight", True)
+    if tensors:
+        logger.warning("unused checkpoint tensors: %s", sorted(tensors)[:8])
+    if int8:
+        from gpustack_tpu.models.quant import quantize_params
+
         params = quantize_params(params)
     return params
 

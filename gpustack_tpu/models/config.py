@@ -17,7 +17,12 @@ from typing import Any, Dict, Optional, Tuple
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
-    """Architecture description for a Llama/Qwen/Mistral/Mixtral-class LM.
+    """Architecture description of a decoder-only LM of one of the
+    families :data:`FAMILIES` lists: Llama / Qwen / Mistral / Gemma
+    dense stacks, Mixtral / Qwen-MoE / GPT-OSS / DeepSeek (MLA) expert
+    stacks, each layer attention + MLP; and the Nemotron-H hybrid
+    (``layer_kinds``), each layer ONE mixer: a Mamba-2 state-space
+    mixer, a mixture of two-matrix experts, or GQA.
 
     Attention type is derived, not stored: MHA when num_kv_heads ==
     num_heads, GQA when 1 < num_kv_heads < num_heads, MQA when
@@ -110,11 +115,26 @@ class ModelConfig:
     # denominator only — modeling_gpt_oss eager_attention_forward)
     attn_sinks: bool = False
     o_bias: bool = False            # bias on the attention out proj
-    # expert activation: "silu" (swiglu) | "gptoss" (clamped
-    # gate*sigmoid(1.702*gate), combined as (up+1)*glu) — experts carry
-    # biases on gate/up/down when moe_bias is set
+    # the experts' form: gated, three matrices an expert, "silu"
+    # (swiglu) | "gptoss" (clamped gate*sigmoid(1.702*gate), combined as
+    # (up+1)*glu); or plain, two matrices an expert and no gate, "relu2"
+    # (down(relu(up x)^2), Nemotron-H: the shared expert likewise) —
+    # experts carry biases on gate/up/down when moe_bias is set
     moe_act: str = "silu"
     moe_bias: bool = False
+    # ---- Nemotron-H (hybrid) knobs ----
+    # One mixer a layer, by kind: "M" a Mamba-2 state-space mixer, "E"
+    # experts alone, "*" GQA alone (``hybrid_override_pattern``), each
+    # behind one pre-norm and one residual add. None: every layer is
+    # attention + MLP, as every other family. num_layers == len of it.
+    layer_kinds: Optional[Tuple[str, ...]] = None
+    rope: bool = True               # False: attention without rotary
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    mamba_n_groups: int = 1
+    conv_kernel: int = 4
+    ssm_chunk_size: int = 128
     dtype: str = "bfloat16"
 
     # ---- derived ----
@@ -142,6 +162,36 @@ class ModelConfig:
     def num_held_experts(self) -> int:
         """Experts whose weights this replica holds (``experts_held``)."""
         return self.experts_held or self.num_experts
+
+    # ---- the hybrid's layers, by kind ----
+    def layers_of(self, kind: str) -> int:
+        """How many layers are of ``kind`` (``"M"``, ``"E"``, ``"*"``);
+        0 for a model without ``layer_kinds``."""
+        return sum(k == kind for k in self.layer_kinds or ())
+
+    @property
+    def num_kv_layers(self) -> int:
+        """Layers that keep rows a position in the cache: every layer,
+        or the hybrid's attention layers."""
+        if self.layer_kinds is None:
+            return self.num_layers
+        return self.layers_of("*")
+
+    @property
+    def num_moe_layers(self) -> int:
+        """Layers with routed experts."""
+        if self.layer_kinds is not None:
+            return self.layers_of("E")
+        return self.num_layers - self.first_k_dense if self.is_moe else 0
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Width of ``xBC``, what the causal convolution runs over."""
+        return self.mamba_inner + 2 * self.mamba_n_groups * self.ssm_state_size
 
     @property
     def kv_row_shapes(self) -> Tuple[Tuple[int, int], Tuple[int, int]]:
@@ -184,14 +234,46 @@ class ModelConfig:
         if self.layer_sliding is not None:
             assert len(self.layer_sliding) == self.num_layers
             assert self.sliding_window > 0
+        if self.layer_kinds is not None:
+            assert len(self.layer_kinds) == self.num_layers
+            assert set(self.layer_kinds) <= {"M", "E", "*"}, self.layer_kinds
+            if self.layers_of("M"):
+                assert self.mamba_num_heads % self.mamba_n_groups == 0
+                assert self.mamba_inner and self.ssm_state_size
         return self
 
     # ---- memory accounting (used by scheduler + engine sizing) ----
     def param_count(self) -> int:
-        """Exact parameter count for this architecture."""
+        """Exact parameter count of what this replica holds: embedding,
+        head, every layer's matrices, norms, biases and router, with the
+        experts it holds (``experts_held``) and not the absent ones. A
+        file cut to a share counts the share; the published file counts
+        the published model."""
         d, v = self.hidden_size, self.vocab_size
         embed = v * d
         lm_head = 0 if self.tie_word_embeddings else d * v
+        if self.layer_kinds is not None:
+            inner, conv = self.mamba_inner, self.mamba_conv_dim
+            heads = self.mamba_num_heads
+            mamba = (
+                d * (inner + conv + heads)      # in_proj: z | xBC | dt
+                + conv * self.conv_kernel + conv    # conv1d and its bias
+                + 3 * heads                     # A_log, D, dt_bias
+                + inner                         # the gated norm's gain
+                + inner * d                     # out_proj
+            )
+            experts = (
+                d * self.num_experts + self.num_experts     # router, bias
+                + self.num_held_experts * 2 * d * self.moe_intermediate_size
+                + 2 * d * self.shared_expert_intermediate_size
+            )
+            attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+            return (
+                embed + lm_head + d
+                + self.layers_of("M") * (mamba + d)
+                + self.layers_of("E") * (experts + d)
+                + self.layers_of("*") * (attn + d)
+            )
         if self.is_mla:
             qk_dim = self.qk_nope_head_dim + self.qk_rope_head_dim
             if self.q_lora_rank:
@@ -238,26 +320,145 @@ class ModelConfig:
         )
 
     def weight_bytes(self, bits: int = 16) -> int:
-        return self.param_count() * bits // 8
+        """Bytes of the weights as stored: the parameters, and for a
+        hybrid the zeros that fill its experts' width up to whole lane
+        tiles of 128 (``models/hybrid.py pad_expert_width``)."""
+        stored = self.param_count()
+        if self.layer_kinds is not None:
+            stored += (
+                self.layers_of("E") * self.num_held_experts
+                * 2 * self.hidden_size * (-self.moe_intermediate_size % 128)
+            )
+        return stored * bits // 8
 
     def kv_cache_bytes_per_token(self, bits: int = 16) -> int:
-        """Bytes of cache per token position (all layers)."""
+        """Bytes of cache per token position (all layers that keep rows
+        a position: ``num_kv_layers``)."""
         per_layer = sum(h * w for h, w in self.kv_row_shapes)
-        return self.num_layers * per_layer * bits // 8
+        return self.num_kv_layers * per_layer * bits // 8
+
+    def state_bytes_per_slot(self, bits: int = 16) -> int:
+        """Bytes a slot keeps whatever its length: a state-space layer's
+        recurrent state (float32, as the family's serving notes ask) and
+        the last ``conv_kernel - 1`` rows of ``xBC`` (``bits`` wide). 0
+        for a model without such layers."""
+        per_layer = (
+            self.mamba_inner * self.ssm_state_size * 4
+            + (self.conv_kernel - 1) * self.mamba_conv_dim * bits // 8
+        )
+        return self.layers_of("M") * per_layer
+
+
+# The families ``config_from_hf`` reads, by a substring of the file's
+# first ``architectures`` entry. A file that names an architecture of
+# none of them is refused by name: served as a Llama-class stack (what
+# any file with ``hidden_size`` and ``num_attention_heads`` used to get)
+# it would answer with the wrong model's tokens. A file that names no
+# architecture at all is read by its keys, as the presets' and the
+# tests' small files are.
+FAMILIES: Tuple[str, ...] = (
+    "Llama", "Mistral", "Mixtral", "Qwen2", "Qwen3", "Gemma", "GptOss",
+    "Deepseek", "NemotronH",
+    # multimodal wrappers whose text stack is one of the above
+    "Llava", "VLForConditionalGeneration",
+)
+
+
+def _nemotron_h_config(cfg: Dict[str, Any], name: str) -> ModelConfig:
+    """The Nemotron-H hybrid (``model_type: nemotron_h``): one mixer a
+    layer by ``hybrid_override_pattern`` (``M`` Mamba-2, ``E`` experts,
+    ``*`` attention; ``-``, a dense MLP layer of the family's older
+    members, is not read). The router's keys are the DeepSeek-V3
+    router's; attention takes no rotary embedding (no layer of the
+    family's public port reads ``rope_theta``).
+
+    One chip's share of the experts is ``n_routed_experts`` (how many
+    are held here) beside ``experts_held: {"of": <the router's published
+    width>, "first": <the first held id>}``. ``of``, not the
+    ``published`` of the DeepSeek family's files, on purpose: a reader
+    from before this family (it takes any file with ``hidden_size`` and
+    ``num_attention_heads`` for an attention + gated-expert stack, 52
+    layers of it here) then fails on the share's key and the instance
+    ends in ``error`` at once, where it would otherwise wait for a
+    placement no chip can give (PERF.md section 6, PR 46)."""
+    pattern = cfg["hybrid_override_pattern"]
+    if set(pattern) - {"M", "E", "*"}:
+        raise ValueError(
+            f"hybrid_override_pattern {pattern!r}: only M (Mamba-2), E "
+            "(experts) and * (attention) layers are served"
+        )
+    if len(pattern) != cfg["num_hidden_layers"]:
+        raise ValueError(
+            f"hybrid_override_pattern has {len(pattern)} layers, "
+            f"num_hidden_layers says {cfg['num_hidden_layers']}"
+        )
+    if cfg.get("mlp_hidden_act", "relu2") != "relu2":
+        raise ValueError(
+            f"mlp_hidden_act {cfg['mlp_hidden_act']!r}: the hybrid's "
+            "two-matrix experts are served with relu2 only"
+        )
+    hidden, heads = cfg["hidden_size"], cfg["num_attention_heads"]
+    share = cfg.get("experts_held") or {}
+    held = int(cfg.get("n_routed_experts") or 0)
+    m_heads = int(cfg["mamba_num_heads"])
+    m_dim = int(cfg["mamba_head_dim"])
+    return ModelConfig(
+        name=name,
+        vocab_size=cfg["vocab_size"],
+        hidden_size=hidden,
+        intermediate_size=cfg.get("intermediate_size", 0),
+        num_layers=len(pattern),
+        num_heads=heads,
+        num_kv_heads=cfg.get("num_key_value_heads", heads),
+        head_dim=cfg.get("head_dim") or hidden // heads,
+        rope=False,
+        rms_norm_eps=cfg.get("layer_norm_epsilon")
+        or cfg.get("norm_eps", 1e-5),
+        tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+        max_position_embeddings=cfg.get("max_position_embeddings", 8192),
+        num_experts=int(share["of"]) if share else held,
+        experts_held=held if share else 0,
+        first_held_expert=int(share.get("first", 0)),
+        num_experts_per_tok=cfg.get("num_experts_per_tok", 0),
+        moe_intermediate_size=int(cfg.get("moe_intermediate_size") or 0),
+        norm_topk_prob=cfg.get("norm_topk_prob", True),
+        n_shared_experts=int(cfg.get("n_shared_experts") or 0),
+        shared_expert_intermediate_size=int(
+            cfg.get("moe_shared_expert_intermediate_size") or 0
+        ),
+        routed_scaling_factor=float(cfg.get("routed_scaling_factor") or 1.0),
+        moe_scoring="sigmoid",
+        n_group=int(cfg.get("n_group") or 1),
+        topk_group=int(cfg.get("topk_group") or 1),
+        moe_act="relu2",
+        layer_kinds=tuple(pattern),
+        mamba_num_heads=m_heads,
+        mamba_head_dim=m_dim,
+        ssm_state_size=int(cfg["ssm_state_size"]),
+        mamba_n_groups=int(cfg.get("n_groups") or 1),
+        conv_kernel=int(cfg.get("conv_kernel") or 4),
+        ssm_chunk_size=int(cfg.get("chunk_size") or 128),
+    ).validate()
 
 
 def config_from_hf(cfg: Dict[str, Any], name: str = "custom") -> ModelConfig:
-    """Build a ModelConfig from an HF ``config.json`` dict.
-
-    Covers LlamaForCausalLM / Qwen2ForCausalLM / MistralForCausalLM /
-    MixtralForCausalLM / Qwen2MoeForCausalLM-style keys — the same families the
-    reference's selectors introspect (base_candidate_selector.py:56-165).
-    """
+    """Build a ModelConfig from an HF ``config.json`` dict of one of
+    :data:`FAMILIES` (the reference's selectors introspect the same
+    keys, base_candidate_selector.py:56-165). An architecture of no
+    family listed is refused by name."""
+    archs = cfg.get("architectures") or [""]
+    arch = archs[0] if archs else ""
+    if arch and not any(f in arch for f in FAMILIES):
+        raise ValueError(
+            f"architecture {arch!r} is of no family this engine reads "
+            f"({', '.join(FAMILIES)}); it is not served as a Llama-class "
+            "stack"
+        )
+    if "NemotronH" in arch or cfg.get("model_type") == "nemotron_h":
+        return _nemotron_h_config(cfg, name)
     hidden = cfg["hidden_size"]
     heads = cfg["num_attention_heads"]
     head_dim = cfg.get("head_dim") or hidden // heads
-    archs = cfg.get("architectures") or [""]
-    arch = archs[0] if archs else ""
     num_experts = (
         cfg.get("num_local_experts")      # Mixtral
         or cfg.get("num_experts")         # Qwen2-MoE

@@ -1,0 +1,487 @@
+"""The Nemotron-H hybrid: one mixer a layer, three kinds of layer.
+
+``cfg.layer_kinds`` names each layer's mixer: ``"M"`` a Mamba-2
+state-space mixer, ``"E"`` a mixture of two-matrix ``relu2`` experts
+with a shared expert, ``"*"`` grouped-query attention without rotary
+embedding. A layer is ``x <- x + mixer(rms_norm(x))``. The weights are
+three stacks, one a kind (``ssm_layers``, ``moe_layers``,
+``attn_layers``, each ``[L_kind, ...]`` and drawn a leaf at a time), and
+the layers are visited in the pattern's order, each reading its own
+stack at its index within its kind.
+
+What a slot keeps (``transformer.KVCache``): rows a position for the
+attention layers only (``k, v [L_*, B, S, Hkv, hd]``), and for every
+state-space layer a recurrent state ``ssm [L_M, B, H, P, N]`` (float32,
+as the family's serving notes ask) and the last ``conv_kernel - 1`` rows
+of ``xBC`` before its causal convolution, ``conv [L_M, B, (K-1) * C]``
+(the rows side by side on the lanes: as ``[.., K-1, C]`` the TPU pads
+the three rows to a tile of sixteen).
+
+``transformer.forward`` hands a model with ``layer_kinds`` to
+:func:`forward_hybrid`; no other model's program passes through here.
+"""
+
+from __future__ import annotations
+
+import math
+from functools import partial
+from typing import Any, Dict, Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from gpustack_tpu.models.config import ModelConfig
+from gpustack_tpu.models.quant import QuantW
+
+STACKS = {"M": "ssm_layers", "E": "moe_layers", "*": "attn_layers"}
+
+
+def init_hybrid_layers(cfg: ModelConfig, key: jax.Array, dtype) -> Dict[str, Any]:
+    """The three stacks, random, each leaf drawn whole at its own depth
+    (a stack drawn at the model's depth and cut is materialised in
+    float32 before its slices: ``transformer.init_params``)."""
+    d = cfg.hidden_size
+    keys = iter(jax.random.split(key, 16))
+
+    def w(*shape, scale=None):
+        scale = scale if scale is not None else 1.0 / math.sqrt(shape[-2])
+        return (
+            jax.random.normal(next(keys), shape, jnp.float32) * scale
+        ).astype(dtype)
+
+    Lm, Le, La = (cfg.layers_of(k) for k in "ME*")
+    H, inner, conv = cfg.mamba_num_heads, cfg.mamba_inner, cfg.mamba_conv_dim
+    f32 = jnp.float32
+    out: Dict[str, Any] = {}
+    if Lm:
+        out["ssm_layers"] = {
+            "norm": jnp.ones((Lm, d), dtype),
+            # [z | xBC | dt]
+            "w_in": w(Lm, d, inner + conv + H),
+            "conv_w": w(Lm, cfg.conv_kernel, conv, scale=0.5).astype(f32),
+            "conv_b": jnp.zeros((Lm, conv), f32),
+            # dt around softplus^-1 of 0.001..0.1, A in -(1..16), as the
+            # family initialises them: a state that neither dies in a
+            # step nor never forgets
+            "dt_bias": jnp.log(jnp.expm1(jnp.exp(
+                jax.random.uniform(
+                    next(keys), (Lm, H), f32,
+                    math.log(1e-3), math.log(1e-1),
+                )
+            ))),
+            "A_log": jnp.log(
+                jax.random.uniform(next(keys), (Lm, H), f32, 1.0, 16.0)
+            ),
+            "D": jnp.ones((Lm, H), f32),
+            "gate_norm": jnp.ones((Lm, inner), dtype),
+            "w_out": w(Lm, inner, d),
+        }
+    if Le:
+        fm, E, Eh = (
+            cfg.moe_intermediate_size, cfg.num_experts, cfg.num_held_experts
+        )
+        fs = cfg.shared_expert_intermediate_size
+        out["moe_layers"] = {
+            "norm": jnp.ones((Le, d), dtype),
+            "router": w(Le, d, E),
+            "router_bias": jnp.zeros((Le, E), f32),
+            "we_up": pad_expert_width(w(Le, Eh, d, fm), -1),
+            "we_down": pad_expert_width(w(Le, Eh, fm, d), -2),
+        }
+        if fs:
+            out["moe_layers"]["ws_up"] = w(Le, d, fs)
+            out["moe_layers"]["ws_down"] = w(Le, fs, d)
+    if La:
+        out["attn_layers"] = {
+            "norm": jnp.ones((La, d), dtype),
+            "wq": w(La, d, cfg.q_dim),
+            "wk": w(La, d, cfg.kv_dim),
+            "wv": w(La, d, cfg.kv_dim),
+            "wo": w(La, cfg.q_dim, d),
+        }
+    return out
+
+
+def pad_expert_width(w: jax.Array, axis: int) -> jax.Array:
+    """An expert matrix with its intermediate width (``axis``) filled up
+    with zeros to whole lane tiles of 128 (1,856 -> 1,920). The TPU
+    stores a last dimension in tiles of 128, so row-major the columns
+    take that room anyway; left at 1,856 it stores the array transposed
+    instead, which the experts' kernels cannot read in place: the decode
+    program then copied all 1.8 GB of ``we_up`` into the other layout
+    every step (compiled for a described v5e, PR 46). A zero column
+    gives ``relu(0)^2 = 0`` into a zero row of the down matrix: the
+    result is the published width's, bit for bit."""
+    pad = -w.shape[axis] % 128
+    if not pad:
+        return w
+    widths = [(0, 0)] * w.ndim
+    widths[axis] = (0, pad)
+    return jnp.pad(w, widths)
+
+
+def ssm_update_impl(rows: int, platform: str, mesh) -> str:
+    """How a step of ``rows`` tokens a slot moves the recurrent state:
+    ``"kernel"`` (``ops/ssm.py ssm_state_update``, the stacked state in
+    place, live slots only) for a decode step on one TPU chip; the
+    chunked scan for several rows a slot; ``"xla"`` for one row anywhere
+    else."""
+    one_chip = platform == "tpu" and (mesh is None or mesh.size == 1)
+    if rows > 1:
+        return "scan"
+    return "kernel" if one_chip else "xla"
+
+
+def grouped_rms_norm(y, gate, w, eps: float, groups: int):
+    """``rms_norm(y * silu(gate))`` over ``groups`` equal groups of the
+    last axis, each with its own mean of squares, then one gain over the
+    whole width (the family's ``MambaRMSNormGated``, ``norm_before_gate``
+    false)."""
+    f32 = jnp.float32
+    h = y.astype(f32) * jax.nn.silu(gate.astype(f32))
+    lead, width = h.shape[:-1], h.shape[-1]
+    hg = h.reshape(*lead, groups, width // groups)
+    hg = hg * lax.rsqrt(jnp.mean(hg * hg, axis=-1, keepdims=True) + eps)
+    return hg.reshape(*lead, width).astype(y.dtype) * w
+
+
+def forward_hybrid(
+    params,
+    cfg: ModelConfig,
+    tokens: jax.Array,
+    positions: jax.Array,
+    cache=None,
+    *,
+    return_hidden: bool = False,
+    attn_impl: str = "xla",
+    mesh=None,
+    moe_dispatch_impl: Optional[str] = None,
+    decode_attn_impl: Optional[str] = None,
+    ssm_impl: Optional[str] = None,
+    live: Optional[jax.Array] = None,
+    true_len: Optional[jax.Array] = None,
+    count_held_pairs: bool = False,
+    routing_out: bool = False,
+    count_experts_read: bool = False,
+):
+    """``transformer.forward`` for a model with ``layer_kinds``; the same
+    arguments and results, and two more arguments.
+
+    ``true_len`` (int32 ``[B]``; None: every position counts): how many
+    of each row's ``T`` positions are real. A state-space layer's state
+    and its kept ``xBC`` rows end after them and not after the padding
+    of a bucket: padded positions get ``dt = 0``, which moves no state.
+    Attention needs nothing of the kind (a padded row is above every
+    real query).
+
+    With a cache a state-space layer starts from the cache's state of
+    its slot (zeros in a fresh prefill cache) and leaves its new one
+    there: a decode step (``T == 1``) through ``ssm_impl`` (by
+    :func:`ssm_update_impl`: the kernel over the live slots, or XLA
+    operations), several rows a slot through the chunked scan. Without
+    a cache every layer starts from zeros and keeps nothing.
+    """
+    from gpustack_tpu.models import transformer as tf
+    from gpustack_tpu.ops.ssm import (
+        ssm_chunk_scan,
+        ssm_state_update,
+        ssm_step_xla,
+    )
+
+    B, T = tokens.shape
+    platform = (
+        mesh.devices.flat[0].platform if mesh is not None
+        else jax.default_backend()
+    )
+    decode = cache is not None and T == 1
+    if moe_dispatch_impl is None:
+        moe_dispatch_impl = tf.moe_dispatch(
+            B * T, cfg, platform, mesh, decode=decode
+        )
+    if cache is not None and decode_attn_impl is None:
+        decode_attn_impl = tf.decode_attention_impl(
+            cfg, T, cache.max_len, platform, mesh
+        )
+    if ssm_impl is None:
+        ssm_impl = ssm_update_impl(
+            T if cache is not None else 2, platform, mesh
+        )
+    if attn_impl == "ring":
+        raise ValueError(
+            "attn_impl='ring': a cache sharded over its positions cannot "
+            "carry a recurrent state; serve this model with sp=1"
+        )
+    use_flash = (
+        attn_impl in ("flash", "flash_interpret")
+        and cache is not None and T > 1 and cache.max_len >= T
+    )
+    walk = None
+    if cache is not None and decode_attn_impl != "xla":
+        from gpustack_tpu.ops.decode_attention import gqa_walk
+
+        lengths = positions[:, 0] + 1
+        if live is not None:
+            lengths = jnp.where(live, lengths, 0)
+        walk = gqa_walk(lengths, cache.k)
+    alive = live if live is not None else jnp.ones((B,), bool)
+
+    dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
+    f32 = jnp.float32
+    eps = cfg.rms_norm_eps
+    x = tf._embed_lookup(params["embed"], tokens, dtype)
+    scale = 1.0 / math.sqrt(cfg.head_dim)
+    if cache is None:
+        mask = positions[:, :, None] >= positions[:, None, :]
+    else:
+        mask = (
+            jnp.arange(cache.max_len, dtype=jnp.int32)[None, None, :]
+            <= positions[:, :, None]
+        )
+    # which of a row's positions count (state-space layers)
+    real = (
+        jnp.ones((B, T), bool) if true_len is None
+        else jnp.arange(T, dtype=jnp.int32)[None, :] < true_len[:, None]
+    )
+
+    H, P = cfg.mamba_num_heads, cfg.mamba_head_dim
+    G, N = cfg.mamba_n_groups, cfg.ssm_state_size
+    inner, conv_dim, K = cfg.mamba_inner, cfg.mamba_conv_dim, cfg.conv_kernel
+
+    # the experts' stacked matrices go to the kernels whole, with the
+    # layer's index; the scales of the touched kernel as its blocks
+    # take them (``transformer.forward``)
+    moe = params.get("moe_layers", {})
+    stacked = {}
+    if moe and moe_dispatch_impl != "dense":
+        stacked = {k: moe[k] for k in ("we_up", "we_down")}
+        if moe_dispatch_impl.startswith("touched"):
+            stacked = {
+                k: QuantW(q=w.q, s=w.s[:, :, None, :])
+                if isinstance(w, QuantW) else w
+                for k, w in stacked.items()
+            }
+
+    def at(stack, i):
+        """Layer ``i``'s leaves of a stack, without the matrices that go
+        to a kernel whole."""
+        return {
+            k: jax.tree.map(
+                lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+                w,
+            )
+            for k, w in stack.items() if k not in stacked
+        }
+
+    def mamba(h, lp, carried, i):
+        """One Mamba-2 mixer: ``(out [B, T, D], carried)``."""
+        with jax.named_scope("ssm_mixer"):
+            zxd = tf._mm("btd,df->btf", h, lp["w_in"])
+            z = zxd[..., :inner]
+            xbc = zxd[..., inner:inner + conv_dim]
+            dt = jax.nn.softplus(
+                zxd[..., inner + conv_dim:].astype(f32) + lp["dt_bias"]
+            )
+            dt = jnp.where(real[..., None], dt, 0.0)
+            A = -jnp.exp(lp["A_log"].astype(f32))
+            # the K - 1 rows before the step's first, oldest first
+            if carried is not None:
+                before = lax.dynamic_index_in_dim(
+                    carried.conv, i, 0, keepdims=False
+                ).reshape(B, K - 1, conv_dim)
+            else:
+                before = jnp.zeros((B, K - 1, conv_dim), xbc.dtype)
+            window = jnp.concatenate([before.astype(xbc.dtype), xbc], axis=1)
+            conv = lp["conv_b"].astype(f32) + sum(
+                window[:, j:j + T].astype(f32) * lp["conv_w"][j].astype(f32)
+                for j in range(K)
+            )
+            xbc_a = jax.nn.silu(conv).astype(dtype)
+            xs = xbc_a[..., :inner].reshape(B, T, H, P)
+            Bm = xbc_a[..., inner:inner + G * N].reshape(B, T, G, N)
+            Cm = xbc_a[..., inner + G * N:].reshape(B, T, G, N)
+            if carried is not None:
+                # the last K - 1 rows that count: rows n .. n + K - 2 of
+                # the window, n the row's real length
+                n = jnp.sum(real, axis=1, dtype=jnp.int32)
+                rows = n[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None]
+                kept = jnp.take_along_axis(
+                    window, rows[..., None], axis=1
+                ).reshape(B, (K - 1) * conv_dim)
+                new_conv = lax.dynamic_update_index_in_dim(
+                    carried.conv, kept.astype(carried.conv.dtype), i, 0
+                )
+            if carried is None:
+                y, _ = ssm_chunk_scan(
+                    xs, dt, A, Bm, Cm, jnp.zeros((B, H, P, N), f32),
+                    cfg.ssm_chunk_size,
+                )
+            elif ssm_impl == "scan":
+                h0 = lax.dynamic_index_in_dim(
+                    carried.ssm, i, 0, keepdims=False
+                )
+                y, last = ssm_chunk_scan(
+                    xs, dt, A, Bm, Cm, h0, cfg.ssm_chunk_size
+                )
+                new_ssm = lax.dynamic_update_index_in_dim(
+                    carried.ssm, last.astype(carried.ssm.dtype), i, 0
+                )
+            elif ssm_impl == "xla":
+                y, new_ssm = ssm_step_xla(
+                    carried.ssm, i, xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0]
+                )
+                y = y[:, None]
+            else:
+                y, new_ssm = ssm_state_update(
+                    carried.ssm, i, xs[:, 0], dt[:, 0], A, Bm[:, 0],
+                    Cm[:, 0], alive,
+                    interpret=ssm_impl == "kernel_interpret",
+                )
+                y = y[:, None]
+            if carried is not None:
+                carried = type(carried)(
+                    k=carried.k, v=carried.v, ssm=new_ssm, conv=new_conv
+                )
+            y = y + lp["D"].astype(f32)[:, None] * xs.astype(f32)
+            y = grouped_rms_norm(
+                y.astype(dtype).reshape(B, T, inner), z, lp["gate_norm"],
+                eps, G,
+            )
+            return tf._mm("btf,fd->btd", y, lp["w_out"]), carried
+
+    def experts(h, lp, i):
+        """One expert layer: ``(out, held pairs, experts read,
+        routing)``, the counts 0 and the routing None unless asked."""
+        shared = (
+            (None, lp["ws_up"], lp["ws_down"], None) if "ws_up" in lp
+            else None
+        )
+        out = tf._moe_mlp(
+            h, lp["router"], None,
+            stacked.get("we_up", lp.get("we_up")),
+            stacked.get("we_down", lp.get("we_down")), cfg,
+            router_bias=lp.get("router_bias"), shared=shared,
+            dispatch=moe_dispatch_impl,
+            layer=i if stacked else None,
+            count_held=count_held_pairs, routing_out=routing_out,
+            live=live, count_read=count_experts_read,
+        )
+        out, *extras = out if isinstance(out, tuple) else (out,)
+        routing = extras.pop() if routing_out else None
+        held = extras.pop(0) if count_held_pairs else jnp.int32(0)
+        read = extras.pop(0) if count_experts_read else jnp.int32(0)
+        return out, held, read, routing
+
+    def attention(h, lp, carried, i):
+        """One GQA layer, no rotary embedding: ``(out, carried)``."""
+        q = tf._mm("btd,dq->btq", h, lp["wq"]).reshape(
+            B, T, cfg.num_kv_heads, cfg.group_size, cfg.head_dim
+        )
+        k = tf._mm("btd,dk->btk", h, lp["wk"]).reshape(
+            B, T, cfg.num_kv_heads, cfg.head_dim
+        )
+        v = tf._mm("btd,dk->btk", h, lp["wv"]).reshape(
+            B, T, cfg.num_kv_heads, cfg.head_dim
+        )
+        if carried is None:
+            attn = tf._attend(q, k, v, mask, scale)
+        else:
+            start = positions[:, 0]
+            carried = type(carried)(
+                k=tf._write_rows(carried.k, k, i, start),
+                v=tf._write_rows(carried.v, v, i, start),
+                ssm=carried.ssm, conv=carried.conv,
+            )
+            if decode_attn_impl != "xla":
+                from gpustack_tpu.ops.decode_attention import (
+                    gqa_decode_attention,
+                )
+
+                attn = gqa_decode_attention(
+                    q.reshape(B, cfg.num_heads, cfg.head_dim),
+                    carried.k, carried.v, i, walk, scale,
+                    interpret=decode_attn_impl == "kernel_interpret",
+                )[:, None]
+            else:
+                all_k, all_v = (
+                    lax.dynamic_index_in_dim(buf, i, 0, keepdims=False)
+                    for buf in (carried.k, carried.v)
+                )
+                if use_flash:
+                    from gpustack_tpu.ops.flash_attention import (
+                        flash_attention_prefill,
+                        sharded_flash_attention_prefill,
+                    )
+
+                    flash = (
+                        flash_attention_prefill if mesh is None
+                        else partial(sharded_flash_attention_prefill, mesh)
+                    )
+                    attn = flash(
+                        q.reshape(B, T, cfg.num_heads, cfg.head_dim),
+                        all_k, all_v, scale,
+                        interpret=attn_impl == "flash_interpret",
+                        q_offset=positions[0, 0],
+                    )
+                else:
+                    attn = tf._attend(q, all_k, all_v, mask, scale)
+        return tf._mm("btq,qd->btd", attn.reshape(B, T, -1), lp["wo"]), carried
+
+    # One function a kind of layer, traced once: the layers are visited in
+    # a Python loop (every layer's operations are in the program, which
+    # XLA inlines), but each kind is a jitted function called with its
+    # stack and the layer's index in it, so the 23 mixers are one trace
+    # and one lowered function, not 23. A ``lax.scan`` over the layers
+    # with a ``lax.switch`` over the kind lowers faster still and is not
+    # taken: compiled for a described v5e, XLA copies the stacked state
+    # whole through the conditional every step (0.72 GB of temporaries
+    # against 0.09 GB, a ``copy`` of ``f32[23, 32, 64, 64, 128]``).
+    def m_layer(x, carried, stack, i):
+        lp = at(stack, i)
+        out, carried = mamba(tf.rms_norm(x, lp["norm"], eps), lp, carried, i)
+        return x + out, carried
+
+    def e_layer(x, stack, i):
+        lp = at(stack, i)
+        out, *more = experts(tf.rms_norm(x, lp["norm"], eps), lp, i)
+        return (x + out, *more)
+
+    def a_layer(x, carried, stack, i):
+        lp = at(stack, i)
+        out, carried = attention(
+            tf.rms_norm(x, lp["norm"], eps), lp, carried, i
+        )
+        return x + out, carried
+
+    m_layer, e_layer, a_layer = map(jax.jit, (m_layer, e_layer, a_layer))
+    held = read = jnp.int32(0)
+    routings = []
+    index = {"M": 0, "E": 0, "*": 0}
+    for kind in cfg.layer_kinds:
+        i = jnp.int32(index[kind])
+        index[kind] += 1
+        if kind == "M":
+            x, cache = m_layer(x, cache, params["ssm_layers"], i)
+        elif kind == "*":
+            x, cache = a_layer(x, cache, params["attn_layers"], i)
+        else:
+            x, n_held, n_read, routing = e_layer(x, moe, i)
+            held, read = held + n_held, read + n_read
+            if routing_out:
+                routings.append(routing)
+
+    extras = []
+    if count_held_pairs:
+        extras.append(held)
+    if count_experts_read:
+        extras.append(read)
+    if routing_out:
+        extras.append(tuple(jnp.stack(r) for r in zip(*routings)))
+    x = tf.rms_norm(x, params["final_norm"], eps)
+    if return_hidden:
+        return (x.astype(f32), cache, *extras)
+    if cfg.tie_word_embeddings:
+        logits = jnp.einsum("btd,vd->btv", x, params["embed"])
+    else:
+        logits = tf._mm("btd,dv->btv", x, params["lm_head"])
+    return (logits.astype(f32), cache, *extras)

@@ -57,13 +57,21 @@ _CONTRACT_AXES: Dict[str, tuple] = {
     "wq_a": (0,), "wq_b": (0,),
     "wkv_a": (0,), "wk_b": (0,), "wv_b": (0,),
     "ws_gate": (0,), "ws_up": (0,), "ws_down": (0,),
+    # the hybrid's state-space mixer: in_proj [d, z|xBC|dt], out_proj
+    # (its convolution, A_log, D, dt_bias and norms stay as they are)
+    "w_in": (0,), "w_out": (0,),
 }
+# The stacks of layers in a param tree: one for most models, a dense
+# prefix beside it for DeepSeek, three for the hybrid (models/hybrid.py)
+LAYER_STACKS = (
+    "layers", "dense_layers", "ssm_layers", "moe_layers", "attn_layers",
+)
 # Layer-stacked leaves carry a leading [L] axis not present at use time.
 _STACKED = {
     "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
     "we_gate", "we_up", "we_down",
     "wq_a", "wq_b", "wkv_a", "wk_b", "wv_b",
-    "ws_gate", "ws_up", "ws_down",
+    "ws_gate", "ws_up", "ws_down", "w_in", "w_out",
 }
 
 
@@ -90,7 +98,7 @@ def quantize_params(params: Dict[str, Any]) -> Dict[str, Any]:
     out: Dict[str, Any] = {}
     tie = "lm_head" not in params
     for k, v in params.items():
-        if k in ("layers", "dense_layers"):
+        if k in LAYER_STACKS:
             out[k] = {
                 lk: _quantize_leaf(lk, lv) if lk in _CONTRACT_AXES else lv
                 for lk, lv in v.items()
@@ -153,7 +161,7 @@ def quant_pspecs(specs: Dict[str, Any], params: Dict[str, Any]):
 
     out: Dict[str, Any] = {}
     for k, v in params.items():
-        if k in ("layers", "dense_layers"):
+        if k in LAYER_STACKS:
             out[k] = {
                 lk: adapt(lk, specs[k][lk], lv) for lk, lv in v.items()
             }

@@ -94,6 +94,18 @@ class KVCache:
 
     k: jax.Array
     v: jax.Array
+    # a hybrid's second kind of per-slot memory (models/hybrid.py), None
+    # for every other model: each state-space layer's recurrent state
+    # ``[L_M, B, H, P, N]`` (float32) and the last ``conv_kernel - 1``
+    # rows of its convolution's input, side by side, ``[L_M, B, (K-1) *
+    # C]``. Not rows a position: a slot has one of each whatever its
+    # length, a prefill ends in one and a decode step moves it on, so
+    # nothing that cuts, stores or reuses a span of positions (a prefix
+    # block, a spilled or transferred run, a rolled-back draft) can
+    # carry it, and the engine refuses those for such a model. ``k`` and
+    # ``v`` then have the attention layers only (``cfg.num_kv_layers``).
+    ssm: Optional[jax.Array] = None
+    conv: Optional[jax.Array] = None
 
     @property
     def max_len(self) -> int:
@@ -110,9 +122,23 @@ class KVCache:
                 jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
             )
         k_row, v_row = cfg.kv_row_shapes
-        lead = (cfg.num_layers, batch, max_len)
+        lead = (cfg.num_kv_layers, batch, max_len)
+        state = {}
+        if cfg.layers_of("M"):
+            Lm = cfg.layers_of("M")
+            state = dict(
+                ssm=jnp.zeros(
+                    (Lm, batch, cfg.mamba_num_heads, cfg.mamba_head_dim,
+                     cfg.ssm_state_size), jnp.float32,
+                ),
+                conv=jnp.zeros(
+                    (Lm, batch, (cfg.conv_kernel - 1) * cfg.mamba_conv_dim),
+                    dtype,
+                ),
+            )
         return KVCache(
-            k=jnp.zeros(lead + k_row, dtype), v=jnp.zeros(lead + v_row, dtype)
+            k=jnp.zeros(lead + k_row, dtype), v=jnp.zeros(lead + v_row, dtype),
+            **state,
         )
 
 
@@ -202,6 +228,23 @@ def init_params(
     cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16
 ) -> Params:
     """Random init with layer weights stacked on a leading [L] axis."""
+    if cfg.layer_kinds is not None:
+        # the hybrid: three stacks, one a kind of layer (models/hybrid.py)
+        from gpustack_tpu.models.hybrid import init_hybrid_layers
+
+        k_layers, k_embed, k_head = jax.random.split(key, 3)
+        d = cfg.hidden_size
+        params = init_hybrid_layers(cfg, k_layers, dtype)
+        params["embed"] = (
+            jax.random.normal(k_embed, (cfg.vocab_size, d), jnp.float32) * 0.02
+        ).astype(dtype)
+        params["final_norm"] = jnp.ones((d,), dtype)
+        if not cfg.tie_word_embeddings:
+            params["lm_head"] = (
+                jax.random.normal(k_head, (d, cfg.vocab_size), jnp.float32)
+                / math.sqrt(d)
+            ).astype(dtype)
+        return params
     if cfg.is_moe and cfg.first_k_dense:
         # DeepSeek's heterogeneous stack is two homogeneous ones, each
         # drawn at its own depth: a dense prefix (own MLP shapes) and the
@@ -609,7 +652,12 @@ def _route(
     return top_idx, top_w
 
 
-def _expert_act(g: jax.Array, u: jax.Array, cfg: ModelConfig) -> jax.Array:
+def _expert_act(g, u: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """What goes into an expert's down matrix. The gated forms take the
+    gate's product ``g`` and the up matrix's ``u``; the plain form
+    (``"relu2"``, two matrices an expert) has no gate: ``g`` is None."""
+    if cfg.moe_act == "relu2":
+        return jnp.square(jax.nn.relu(u))
     if cfg.moe_act == "gptoss":
         # GptOssExperts: clamped glu — gate capped above, up clamped
         # both ways, (up + 1) multiplies gate*sigmoid(1.702*gate)
@@ -639,7 +687,7 @@ def _experts_dense(x, top_idx, top_w, we_gate, we_up, we_down, cfg, biases):
         * top_w[..., None],
         axis=-2,
     ).astype(x.dtype)
-    g = _mm("btd,edf->btef", x, we_gate)
+    g = None if we_gate is None else _mm("btd,edf->btef", x, we_gate)
     u = _mm("btd,edf->btef", x, we_up)
     if biases is not None:
         bg, bu, _bd = biases
@@ -672,8 +720,8 @@ def _grouped_products(xs, rows, we_gate, we_up, we_down, cfg, biases,
         return out
 
     bg, bu, bd = biases if biases is not None else (None, None, None)
-    h = _expert_act(mm(xs, we_gate, bg), mm(xs, we_up, bu), cfg)
-    return mm(h, we_down, bd)
+    g = None if we_gate is None else mm(xs, we_gate, bg)
+    return mm(_expert_act(g, mm(xs, we_up, bu), cfg), we_down, bd)
 
 
 def _experts_grouped(
@@ -833,13 +881,14 @@ def _experts_touched(
     # the touched ids first, ascending; the rest name the last expert
     ids = lax.sort(jnp.where(touched, held, Eh - 1))
     ids = ids[: min(Eh, B * cfg.num_experts_per_tok)]
+    # the plain form (cfg.moe_act "relu2") has no gate: None all along
     weights, scales = zip(*(
         (w.q, w.s) if isinstance(w, QuantW) else (w, None)
         for w in (we_gate, we_up, we_down)
     ))
     out = touched_experts(
         x[:, 0], combine, ids, n_touched[None], *weights,
-        scales if scales[0] is not None else None, layer,
+        scales if scales[1] is not None else None, layer,
         interpret=interpret,
     )
     return out.astype(x.dtype)[:, None], n_touched
@@ -848,7 +897,7 @@ def _experts_touched(
 def _moe_mlp(
     x: jax.Array,           # [B, T, D]
     router_w: jax.Array,    # [D, E]
-    we_gate: jax.Array,     # [E, D, Fm]
+    we_gate,                # [E, D, Fm]; None for the plain form (relu2)
     we_up: jax.Array,       # [E, D, Fm]
     we_down: jax.Array,     # [E, Fm, D]
     cfg: ModelConfig,
@@ -892,10 +941,10 @@ def _moe_mlp(
             x, top_idx, top_w, we_gate, we_up, we_down, cfg, biases
         )
     elif dispatch.startswith("touched"):
-        if biases is not None or cfg.moe_act != "silu":
+        if biases is not None or cfg.moe_act not in ("silu", "relu2"):
             raise ValueError(
                 "dispatch 'touched' takes no expert biases and no "
-                f"activation but silu (moe_act={cfg.moe_act!r})"
+                f"activation but silu or relu2 (moe_act={cfg.moe_act!r})"
             )
         out, n_read = _experts_touched(
             x, top_idx, top_w, we_gate, we_up, we_down, cfg, live,
@@ -918,9 +967,14 @@ def _moe_mlp(
         # to the routed output — ungated (DeepSeek) or gated by
         # sigmoid(x @ g) (Qwen2-MoE)
         ws_gate, ws_up, ws_down, gate_w = shared
-        sg = _mm("btd,df->btf", x, ws_gate)
-        su = _mm("btd,df->btf", x, ws_up)
-        shared_out = _mm("btf,fd->btd", jax.nn.silu(sg) * su, ws_down)
+        if ws_gate is None:
+            # the plain form's shared expert has two matrices as well
+            sh = jnp.square(jax.nn.relu(_mm("btd,df->btf", x, ws_up)))
+        else:
+            sg = _mm("btd,df->btf", x, ws_gate)
+            su = _mm("btd,df->btf", x, ws_up)
+            sh = jax.nn.silu(sg) * su
+        shared_out = _mm("btf,fd->btd", sh, ws_down)
         if gate_w is not None:
             shared_out = shared_out * jax.nn.sigmoid(
                 _mm("btd,dg->btg", x, gate_w)
@@ -977,7 +1031,7 @@ def moe_dispatch(
     observe. ``decode``: the rows are one a slot over a cache.
 
     On one TPU chip: ``"touched"`` for a decode step of a model the
-    kernel takes (no expert biases, the silu activation): it reads the
+    kernel takes (no expert biases; silu, or the plain relu2): it reads the
     experts the live rows chose, at most what the dense form reads, so
     no row count chooses between them; ``"grouped"`` where the rows fill
     the groups. ``"dense"`` otherwise: a verify, ingest, chunked or
@@ -991,7 +1045,7 @@ def moe_dispatch(
     if not one_chip:
         return "dense"
     if decode:
-        taken = cfg.moe_act == "silu" and not cfg.moe_bias
+        taken = cfg.moe_act in ("silu", "relu2") and not cfg.moe_bias
         return "touched" if taken else "dense"
     # pairs an expert, held or not, on the average: under a share the
     # held experts get their part of the pairs, not all of them
@@ -1054,8 +1108,15 @@ def forward(
     count_held_pairs: bool = False,
     routing_out: bool = False,
     count_experts_read: bool = False,
+    true_len: Optional[jax.Array] = None,
+    ssm_impl: Optional[str] = None,
 ) -> Tuple[jax.Array, Optional[KVCache]]:
     """Run the model.
+
+    A model of one mixer a layer (``cfg.layer_kinds``, the Nemotron-H
+    hybrid) runs in ``models/hybrid.py forward_hybrid``, which takes
+    these arguments and says what ``true_len`` and ``ssm_impl`` are;
+    every other model takes no notice of those two.
 
     Without ``cache``: plain causal forward (training / scoring path).
     With ``cache``: the cache rides the scan over the layers as its carry;
@@ -1128,6 +1189,19 @@ def forward(
     ``"touched"``: the engine's
     ``gpustack_engine_moe_decode_experts_total``).
     """
+    if cfg.layer_kinds is not None:
+        from gpustack_tpu.models.hybrid import forward_hybrid
+
+        if embeds_override is not None:
+            raise ValueError("a hybrid model takes no embedding override")
+        return forward_hybrid(
+            params, cfg, tokens, positions, cache,
+            return_hidden=return_hidden, attn_impl=attn_impl, mesh=mesh,
+            moe_dispatch_impl=moe_dispatch_impl,
+            decode_attn_impl=decode_attn_impl, ssm_impl=ssm_impl, live=live,
+            true_len=true_len, count_held_pairs=count_held_pairs,
+            routing_out=routing_out, count_experts_read=count_experts_read,
+        )
     B, T = tokens.shape
     platform = (
         mesh.devices.flat[0].platform if mesh is not None

@@ -79,6 +79,11 @@ Record vocabulary (per step):
   ``decode_moe_dispatch: touched`` (``/healthz``) it is the share of the
   experts' weights the live rows' routing touched; under ``dense`` 100.
   Absent otherwise.
+- ``state_slots``, ``ssm_tokens`` — for a model with state-space layers
+  (a recurrent state a slot beside its rows): the slots whose state the
+  step's decode step moved on, one token each, and the prompt tokens
+  the step sent through the chunked scan (a prefill). Absent for any
+  other model.
 
 Cumulative (not per-record): ``idle_wait_s_total`` — seconds the
 scheduler parked on its wakeup condition instead of busy-polling (the
@@ -287,23 +292,25 @@ GUARDED_BY = {
     "_programs_seen": ("record",),
     "_programs_version": ("record",),
     "_ring": "_mu",
+    "_unfolded": "_mu",
     "_hist": "_mu",
-    "tokens_real_total": "_mu",
-    "tokens_padded_total": "_mu",
-    "tokens_out_total": "_mu",
-    "prompt_tokens_total": "_mu",
-    "moe_prompt_tokens_total": "_mu",
-    "decode_kv_live_total": "_mu",
-    "decode_kv_allocated_total": "_mu",
-    "moe_decode_read_total": "_mu",
-    "moe_decode_held_total": "_mu",
-    "spec_proposed_total": "_mu",
-    "spec_accepted_total": "_mu",
+    "_tokens_real_total": "_mu",
+    "_tokens_padded_total": "_mu",
+    "_tokens_out_total": "_mu",
+    "_prompt_tokens_total": "_mu",
+    "_moe_prompt_tokens_total": "_mu",
+    "_decode_kv_live_total": "_mu",
+    "_decode_kv_allocated_total": "_mu",
+    "_moe_decode_read_total": "_mu",
+    "_moe_decode_held_total": "_mu",
+    "_ssm_tokens_total": "_mu",
+    "_spec_proposed_total": "_mu",
+    "_spec_accepted_total": "_mu",
     "_last_slots_used": "_mu",
     "_last_waiting": "_mu",
     "_last_oldest_wait_s": "_mu",
     "_last_kv_blocks": "_mu",
-    "host_overlap_s_total": "_mu",
+    "_host_overlap_s_total": "_mu",
     "idle_wait_s_total": "_mu",
     "rollback_tokens_total": "_mu",
     "_record_s": "_mu",
@@ -339,25 +346,38 @@ class FlightRecorder:
         # a 14-key dict per step costs ~10x a tuple append. snapshot()
         # re-materializes dicts on the (cold) read side.
         self._ring: deque = deque(maxlen=max(16, int(capacity)))
+        # The step path only appends its row: the histogram and the
+        # cumulative counters are brought up to date from the ring when
+        # somebody reads them (``_fold_locked``), or every ``_fold_at`` rows at
+        # the latest, well before the ring could drop a row unread. A
+        # record then costs the scheduler about half of what it did (a
+        # record of 3 us in a loop is 9 us after a millisecond of other
+        # work, most of it these thirty read-modify-writes: PR 46). The
+        # counters are read through properties of their old names.
+        self._unfolded = 0
+        self._fold_at = min(256, self._ring.maxlen // 2)
         # per-mode step-time histogram: plain lists, single writer
         # (same torn-read tolerance as the engine's LatencyHistogram)
         self._hist: Dict[str, List] = {}
-        self.tokens_real_total = 0
-        self.tokens_padded_total = 0
-        self.tokens_out_total = 0
-        self.prompt_tokens_total = 0
+        self._tokens_real_total = 0
+        self._tokens_padded_total = 0
+        self._tokens_out_total = 0
+        self._prompt_tokens_total = 0
         # prompt tokens by expert dispatch; empty for a dense model
-        self.moe_prompt_tokens_total: Dict[str, int] = {}
+        self._moe_prompt_tokens_total: Dict[str, int] = {}
         # cached positions over the decode steps: what their live slots
         # attended, and what the cache allocates (slots x max_len a step)
-        self.decode_kv_live_total = 0
-        self.decode_kv_allocated_total = 0
+        self._decode_kv_live_total = 0
+        self._decode_kv_allocated_total = 0
         # expert weights over the decode steps fetched (a model with
         # experts): the held experts a step read, and held x layers
-        self.moe_decode_read_total = 0
-        self.moe_decode_held_total = 0
-        self.spec_proposed_total = 0
-        self.spec_accepted_total = 0
+        self._moe_decode_read_total = 0
+        self._moe_decode_held_total = 0
+        # tokens through the state-space layers (a hybrid), by the
+        # program that took them; None until such a step is recorded
+        self._ssm_tokens_total: Optional[Dict[str, int]] = None
+        self._spec_proposed_total = 0
+        self._spec_accepted_total = 0
         self._last_slots_used = 0
         self._last_waiting = 0
         self._last_oldest_wait_s = 0.0
@@ -366,7 +386,7 @@ class FlightRecorder:
         # overlapped with device compute, scheduler idle-park seconds
         # (the spin the condition-variable wakeup saves), and tokens the
         # dispatch-ahead pipeline rolled back after a lagged fetch
-        self.host_overlap_s_total = 0.0
+        self._host_overlap_s_total = 0.0
         self.idle_wait_s_total = 0.0
         self.rollback_tokens_total = 0
         # the programs lowered / compiled in this process, and how far
@@ -406,6 +426,7 @@ class FlightRecorder:
         kv_allocated: int = 0,     # 0: the step dispatched no decode step
         moe_read: int = 0,
         moe_held: int = 0,         # 0: the step fetched no experts' count
+        ssm: Optional[Sequence[int]] = None,   # (state_slots, ssm_tokens)
     ) -> Optional[Sequence[List[Any]]]:
         """Returns the step's ``programs`` (None in a steady step)."""
         t0 = time.perf_counter()
@@ -418,15 +439,39 @@ class FlightRecorder:
             traced, compiled = seen[0] - was[0], seen[1] - was[1]
             if seen[2] != was[2]:
                 programs = [brief(r) for r in self.programs.since(was[2])]
+        row = (
+            time.time(), dur_s, mode, slots_used, waiting,
+            oldest_wait_s, tokens_real, tokens_padded, tokens_out,
+            prompt_tokens, spec_proposed, spec_accepted, kv_blocks,
+            kv_reused_total, host_overlap_s, phases_s, admitted,
+            first_tokens, traced, compiled, moe_dispatch, attn,
+            kv_live, kv_allocated, moe_read, moe_held, programs, ssm,
+        )
         with self._mu:
-            self._ring.append((
-                time.time(), dur_s, mode, slots_used, waiting,
-                oldest_wait_s, tokens_real, tokens_padded, tokens_out,
-                prompt_tokens, spec_proposed, spec_accepted, kv_blocks,
-                kv_reused_total, host_overlap_s, phases_s, admitted,
-                first_tokens, traced, compiled, moe_dispatch, attn,
-                kv_live, kv_allocated, moe_read, moe_held, programs,
-            ))
+            if self._unfolded >= self._fold_at:
+                self._fold_locked()
+            self._ring.append(row)
+            self._unfolded += 1
+            self._step_s += dur_s
+            self._record_s += time.perf_counter() - t0
+        return programs
+
+    def _fold_locked(self) -> None:
+        """The rows recorded since the last reader, into the histogram
+        and the cumulative counters. Called with ``_mu`` held, by
+        whoever is about to read one of them."""
+        n = self._unfolded
+        if not n:
+            return
+        self._unfolded = 0
+        rows = list(itertools.islice(reversed(self._ring), n))
+        for row in reversed(rows):
+            (_ts, dur_s, mode, slots_used, waiting, oldest_wait_s,
+             tokens_real, tokens_padded, tokens_out, prompt_tokens,
+             spec_proposed, spec_accepted, kv_blocks, _kv_reused,
+             host_overlap_s, _phases, _admitted, _first, _traced,
+             _compiled, moe_dispatch, _attn, kv_live, kv_allocated,
+             moe_read, moe_held, _programs, ssm) = row
             h = self._hist.get(mode)
             if h is None:
                 h = self._hist[mode] = [
@@ -435,28 +480,33 @@ class FlightRecorder:
             h[0][bisect.bisect_left(STEP_BUCKETS_S, dur_s)] += 1
             h[1] += dur_s
             h[2] += 1
-            self.tokens_real_total += tokens_real
-            self.tokens_padded_total += tokens_padded
-            self.tokens_out_total += tokens_out
-            self.prompt_tokens_total += prompt_tokens
+            self._tokens_real_total += tokens_real
+            self._tokens_padded_total += tokens_padded
+            self._tokens_out_total += tokens_out
+            self._prompt_tokens_total += prompt_tokens
             if moe_dispatch:
-                totals = self.moe_prompt_tokens_total
-                for name, n in moe_dispatch.items():
-                    totals[name] = totals.get(name, 0) + n
-            self.decode_kv_live_total += kv_live
-            self.decode_kv_allocated_total += kv_allocated
-            self.moe_decode_read_total += moe_read
-            self.moe_decode_held_total += moe_held
-            self.spec_proposed_total += spec_proposed
-            self.spec_accepted_total += spec_accepted
-            self._last_kv_blocks = kv_blocks
-            self._last_waiting = waiting
-            self._last_oldest_wait_s = oldest_wait_s
-            self._last_slots_used = slots_used
-            self.host_overlap_s_total += host_overlap_s
-            self._step_s += dur_s
-            self._record_s += time.perf_counter() - t0
-        return programs
+                totals = self._moe_prompt_tokens_total
+                for name, count in moe_dispatch.items():
+                    totals[name] = totals.get(name, 0) + count
+            self._decode_kv_live_total += kv_live
+            self._decode_kv_allocated_total += kv_allocated
+            self._moe_decode_read_total += moe_read
+            self._moe_decode_held_total += moe_held
+            if ssm is not None:
+                totals = self._ssm_tokens_total
+                if totals is None:
+                    totals = self._ssm_tokens_total = {
+                        "decode": 0, "prefill": 0,
+                    }
+                totals["decode"] += ssm[0]
+                totals["prefill"] += ssm[1]
+            self._spec_proposed_total += spec_proposed
+            self._spec_accepted_total += spec_accepted
+            self._host_overlap_s_total += host_overlap_s
+        self._last_kv_blocks = kv_blocks
+        self._last_waiting = waiting
+        self._last_oldest_wait_s = oldest_wait_s
+        self._last_slots_used = slots_used
 
     def note_idle_wait(self, seconds: float) -> None:
         """Scheduler parked on its wakeup condition for ``seconds`` —
@@ -493,7 +543,7 @@ class FlightRecorder:
          spec_proposed, spec_accepted, kv_blocks, kv_reused_total,
          host_overlap_s, phases_s, admitted, first_tokens, traced,
          compiled, moe_dispatch, attn, kv_live, kv_allocated,
-         moe_read, moe_held, programs) = row
+         moe_read, moe_held, programs, ssm) = row
         entry = {
             "ts": ts,
             "dur_ms": round(dur_s * 1e3, 4),
@@ -533,6 +583,8 @@ class FlightRecorder:
             entry["moe_read_pct"] = round(100.0 * moe_read / moe_held, 2)
         if programs:
             entry["programs"] = programs
+        if ssm is not None:
+            entry["state_slots"], entry["ssm_tokens"] = ssm
         return entry
 
     # ---- read side -----------------------------------------------------
@@ -551,7 +603,8 @@ class FlightRecorder:
         with self._mu:
             if self._step_s <= 0.0:
                 return 0.0
-            return self.host_overlap_s_total / self._step_s
+            self._fold_locked()
+            return self._host_overlap_s_total / self._step_s
 
     def snapshot(self, limit: int = 200) -> List[Dict[str, Any]]:
         """Newest-last copy of the most recent ``limit`` records."""
@@ -592,20 +645,22 @@ class FlightRecorder:
             return f"# TYPE {family} {METRIC_FAMILIES[family]}"
 
         with self._mu:
+            self._fold_locked()
             slots_used = self._last_slots_used
             waiting = self._last_waiting
             oldest = self._last_oldest_wait_s
             kv_blocks = self._last_kv_blocks
-            real = self.tokens_real_total
-            padded = self.tokens_padded_total
-            prompt = self.prompt_tokens_total
-            moe_prompt = dict(self.moe_prompt_tokens_total)
-            kv_live = self.decode_kv_live_total
-            kv_allocated = self.decode_kv_allocated_total
-            moe_read = self.moe_decode_read_total
-            moe_held = self.moe_decode_held_total
-            proposed = self.spec_proposed_total
-            accepted = self.spec_accepted_total
+            real = self._tokens_real_total
+            padded = self._tokens_padded_total
+            prompt = self._prompt_tokens_total
+            moe_prompt = dict(self._moe_prompt_tokens_total)
+            kv_live = self._decode_kv_live_total
+            kv_allocated = self._decode_kv_allocated_total
+            moe_read = self._moe_decode_read_total
+            moe_held = self._moe_decode_held_total
+            ssm_tokens = dict(self._ssm_tokens_total or {})
+            proposed = self._spec_proposed_total
+            accepted = self._spec_accepted_total
             hist = {
                 mode: (list(h[0]), h[1], h[2])
                 for mode, h in self._hist.items()
@@ -692,6 +747,12 @@ class FlightRecorder:
                 f'gpustack_engine_moe_decode_experts_total{{kind="held"}} '
                 f"{moe_held}",
             ]
+        if ssm_tokens:   # a model with state-space layers
+            lines.append(decl("gpustack_engine_ssm_tokens_total"))
+            lines += [
+                f'gpustack_engine_ssm_tokens_total{{kind="{kind}"}} {n}'
+                for kind, n in sorted(ssm_tokens.items())
+            ]
         if moe_prompt:   # a model with experts, once it has prefilled
             lines.append(decl("gpustack_engine_moe_prompt_tokens_total"))
             lines += [
@@ -700,3 +761,24 @@ class FlightRecorder:
                 for name, n in sorted(moe_prompt.items())
             ]
         return lines
+
+
+def _folded(name: str) -> property:
+    """A cumulative counter under its public name: brought up to date
+    from the ring (``FlightRecorder._fold_locked``) and read, under the lock."""
+    def read(self):
+        with self._mu:
+            self._fold_locked()
+            return getattr(self, "_" + name)
+
+    return property(read)
+
+
+for _name in (
+    "tokens_real_total", "tokens_padded_total", "tokens_out_total",
+    "prompt_tokens_total", "moe_prompt_tokens_total",
+    "decode_kv_live_total", "decode_kv_allocated_total",
+    "moe_decode_read_total", "moe_decode_held_total", "ssm_tokens_total",
+    "spec_proposed_total", "spec_accepted_total", "host_overlap_s_total",
+):
+    setattr(FlightRecorder, _name, _folded(_name))

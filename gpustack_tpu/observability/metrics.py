@@ -93,6 +93,15 @@ METRIC_FAMILIES = {
     # one layer takes (1,152 for an MLA latent of 512 + 64 in bf16)
     "gpustack_engine_kv_cache_bytes": "gauge",
     "gpustack_engine_kv_cache_bytes_per_token": "gauge",
+    # what the slots keep on the device, by kind (label kind=kv|state):
+    # rows a position, and the recurrent state of a model with
+    # state-space layers (0 for any other)
+    "gpustack_engine_cache_bytes": "gauge",
+    # a model with state-space layers: tokens through them, by the
+    # program that took them (label kind=prefill|decode: the chunked
+    # scan over a prompt, the one-step update of a live slot); absent
+    # for any other model
+    "gpustack_engine_ssm_tokens_total": "counter",
     "gpustack_engine_occupancy_ratio": "gauge",
     "gpustack_engine_queue_oldest_wait_seconds": "gauge",
     "gpustack_engine_queue_depth": "gauge",

@@ -255,17 +255,19 @@ def grouped_matmul(
 
 
 def choose_block_f(
-    B: int, D: int, F: int, lhs_bytes: int, rhs_bytes: int
+    B: int, D: int, F: int, lhs_bytes: int, rhs_bytes: int,
+    matrices: int = 3,
 ) -> int:
     """Columns of the intermediate width a grid point of
     :func:`touched_experts` takes: all ``F`` where a point's VMEM holds
     an expert's three matrices whole (two buffers each, and their
     upcasts), else the largest divisor of ``F`` that is a multiple of
-    128 and fits (the smallest, where none does)."""
+    128 and fits (the smallest, where none does). ``matrices``: 3 an
+    expert, or 2 for the plain form without a gate."""
 
     def vmem(bf):
         return (
-            3 * D * bf * (2 * rhs_bytes + lhs_bytes)   # gate, up, down
+            matrices * D * bf * (2 * rhs_bytes + lhs_bytes)  # gate, up, down
             + 2 * B * D * lhs_bytes                    # rows, two buffers
             + 3 * B * D * 4                            # product, out twice
             + 3 * B * bf * 4                           # gate, up, their act
@@ -277,10 +279,13 @@ def choose_block_f(
     return next((bf for bf in chunks if vmem(bf) <= _VMEM_BUDGET), chunks[-1])
 
 
-def _touched_kernel(ids_ref, n_ref, layer_ref, x_ref, combine_ref, gate_ref,
-                    up_ref, down_ref, *rest, scaled: bool):
+def _touched_kernel(ids_ref, n_ref, layer_ref, x_ref, combine_ref, *rest,
+                    scaled: bool, gated: bool):
     del layer_ref
-    *scale_refs, out_ref = rest
+    # the matrices (gate, up, down; up, down for the plain form), their
+    # scales in the same order, the result
+    n_w = 3 if gated else 2
+    w_refs, scale_refs, out_ref = rest[:n_w], rest[n_w:-1], rest[-1]
     t = pl.program_id(0)
 
     @pl.when((t == 0) & (pl.program_id(1) == 0))
@@ -302,9 +307,14 @@ def _touched_kernel(ids_ref, n_ref, layer_ref, x_ref, combine_ref, gate_ref,
 
         # gate and up round to the activations' dtype as _mm's do; the
         # activation is taken in float32 and rounded once
-        g = mm(x, gate_ref, 0).astype(x.dtype).astype(jnp.float32)
-        u = mm(x, up_ref, 1).astype(x.dtype).astype(jnp.float32)
-        y = mm((jax.nn.silu(g) * u).astype(x.dtype), down_ref, 2)
+        if gated:
+            g = mm(x, w_refs[0], 0).astype(x.dtype).astype(jnp.float32)
+            u = mm(x, w_refs[1], 1).astype(x.dtype).astype(jnp.float32)
+            h = jax.nn.silu(g) * u
+        else:
+            u = mm(x, w_refs[0], 0).astype(x.dtype).astype(jnp.float32)
+            h = jnp.square(jnp.maximum(u, 0.0))
+        y = mm(h.astype(x.dtype), w_refs[-1], n_w - 1)
         # the rows' weights for this expert: its column of [B, E], taken
         # by a compare and a sum over the lanes (one term is not zero)
         lanes = lax.broadcasted_iota(jnp.int32, combine_ref.shape, 1)
@@ -320,7 +330,8 @@ def touched_experts(
     combine: jax.Array,    # float32 [B, E]: row b's weight for expert e
     ids: jax.Array,        # int32 [G]: the touched experts, ascending
     n_touched: jax.Array,  # int32 [1]: how many of ``ids`` count
-    gate: jax.Array,       # [(L,) E, D, F] int8 or a float type
+    gate,                  # [(L,) E, D, F] int8 or a float type; None:
+                           # the plain form, relu(x @ up)^2 @ down
     up: jax.Array,         # [(L,) E, D, F]
     down: jax.Array,       # [(L,) E, F, D]
     scales: Optional[tuple] = None,   # ([(L,) E, F], [.. F], [.. D])
@@ -331,7 +342,8 @@ def touched_experts(
 ) -> jax.Array:
     """``out[b] = sum over t < n_touched of combine[b, ids[t]] *
     expert_ids[t](x[b])`` in float32 ``[B, D]``, an expert being
-    ``(silu(x @ gate) * (x @ up)) @ down`` with :func:`grouped_matmul`'s
+    ``(silu(x @ gate) * (x @ up)) @ down`` (without a gate,
+    ``relu(x @ up) ** 2 @ down``) with :func:`grouped_matmul`'s
     mathematics: every expert in ``ids`` meets all ``B`` rows, read once,
     and a row that did not choose it weighs its result by zero.
 
@@ -343,14 +355,18 @@ def touched_experts(
     adding ``0 * y`` changes nothing: a row's bits depend on its own
     experts only. ``layer`` as in :func:`grouped_matmul`; ``_block_f``
     is for the tests."""
+    gated = gate is not None
+    mats = [gate, up, down] if gated else [up, down]
+    if scales is not None:
+        scales = [s for s in scales if s is not None]
     if layer is None:
-        gate, up, down = gate[None], up[None], down[None]
-        scales = None if scales is None else tuple(s[None] for s in scales)
+        mats = [m[None] for m in mats]
+        scales = None if scales is None else [s[None] for s in scales]
         layer = jnp.int32(0)
     B, D = x.shape
-    L, E, _, F = gate.shape
+    L, E, _, F = mats[-2].shape     # up's
     bf = _block_f or choose_block_f(
-        B, D, F, x.dtype.itemsize, gate.dtype.itemsize
+        B, D, F, x.dtype.itemsize, up.dtype.itemsize, len(mats)
     )
     if F % bf:
         raise ValueError(f"chunks of {bf} do not divide {F}")
@@ -377,23 +393,24 @@ def touched_experts(
         e, _ = point(t, f, ids_ref, n_ref)
         return (layer_ref[0], e, 0, 0)
 
+    n_cols = len(mats) - 1     # gate and up, or up alone
     in_specs = [
         pl.BlockSpec((B, D), whole),
         pl.BlockSpec((B, E), whole),
-        pl.BlockSpec((None, None, D, bf), columns),
-        pl.BlockSpec((None, None, D, bf), columns),
+        *[pl.BlockSpec((None, None, D, bf), columns)] * n_cols,
         pl.BlockSpec((None, None, bf, D), rows),
     ]
-    operands = [x, combine.astype(jnp.float32), gate, up, down]
+    operands = [x, combine.astype(jnp.float32), *mats]
     if scales is not None:
         in_specs += [
-            pl.BlockSpec((None, None, 1, bf), columns),
-            pl.BlockSpec((None, None, 1, bf), columns),
+            *[pl.BlockSpec((None, None, 1, bf), columns)] * n_cols,
             pl.BlockSpec((None, None, 1, D), down_scale),
         ]
         operands += [s.reshape(L, E, 1, -1) for s in scales]
     return pl.pallas_call(
-        functools.partial(_touched_kernel, scaled=scales is not None),
+        functools.partial(
+            _touched_kernel, scaled=scales is not None, gated=gated
+        ),
         out_shape=jax.ShapeDtypeStruct((B, D), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,

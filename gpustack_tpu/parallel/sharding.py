@@ -128,13 +128,19 @@ class SpecLayout:
         specs: Dict[str, Any] = {
             "embed": self.embed(),
             "final_norm": P(None),
-            "layers": {k: rules[k] for k in params["layers"]},
         }
+        if "layers" in params:
+            specs["layers"] = {k: rules[k] for k in params["layers"]}
         if "dense_layers" in params:
             # DeepSeek first_k_dense prefix stack (models/transformer.py)
             specs["dense_layers"] = {
                 k: rules[k] for k in params["dense_layers"]
             }
+        for stack in ("ssm_layers", "moe_layers", "attn_layers"):
+            # the hybrid's three stacks (models/hybrid.py), served on one
+            # device: every leaf whole on it
+            if stack in params:
+                specs[stack] = {k: P() for k in params[stack]}
         if "lm_head" in params:
             specs["lm_head"] = self.lm_head()
         return specs

@@ -260,6 +260,11 @@ def evaluate_model(model: Model, role: str = "") -> ModelEvaluation:
         * model.max_seq_len
         * kv_slots
     )
+    # a model with state-space layers keeps a recurrent state a slot
+    # beside the rows of its attention layers, whatever the context
+    state_bytes = getattr(cfg, "state_bytes_per_slot", None)
+    if state_bytes is not None:
+        kv_bytes += state_bytes(kv_bits) * kv_slots
     # activation + runtime overhead: prefill attention scratch dominates;
     # scale with seq len, floor at 256 MiB (audio configs use d_model)
     hidden = getattr(cfg, "hidden_size", 0) or cfg.d_model
@@ -359,6 +364,10 @@ def chips_for_claim(
 
     start = explicit_chips or 1
     chips = max(1, start)
+    if getattr(cfg, "layer_kinds", None) is not None:
+        # a model with state-space layers is served on one device
+        # (engine/runner.py): more chips than one hold nothing of it
+        max_chips = min(max_chips, 1)
     while chips <= max_chips:
         if (
             allowed_counts is not None

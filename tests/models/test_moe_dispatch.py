@@ -41,7 +41,24 @@ SCORINGS = {
     "gptoss": dataclasses.replace(
         BASE, moe_scoring="softmax_topk", moe_act="gptoss", moe_bias=True
     ),
+    # Nemotron-H: the DeepSeek-V3 router over two-matrix experts, no gate
+    "relu2": dataclasses.replace(
+        BASE, moe_scoring="sigmoid", routed_scaling_factor=2.5,
+        moe_act="relu2",
+    ),
 }
+
+
+def _plain(cfg, w, kw):
+    """A gated layer's tensors as the plain form takes them: no gate
+    matrix, in the routed experts or in the shared one."""
+    if cfg.moe_act != "relu2":
+        return w, kw
+    w = {**w, "we_gate": None}
+    if "shared" in kw:
+        kw = {**kw, "shared": (None,) + tuple(kw["shared"][1:])}
+    return w, kw
+
 # rows an expert: none a multiple of the 128-row tile; "one" and "none"
 # push an expert past one tile
 ROUTINGS = {"even": 77, "one_takes_all": 150, "several_take_none": 300}
@@ -85,6 +102,7 @@ def _layer(scoring: str, routing: str, shared: bool, int8: bool, rows: int):
             (jax.random.normal(ks[11], (F, D)) * 0.2).astype(dtype),
             None,
         )
+    w, kw = _plain(cfg, w, kw)
     return cfg, x, router.astype(dtype), w, kw
 
 
@@ -99,7 +117,9 @@ def _reference(cfg, x, top_idx, top_w, w, kw, first=0):
         return np.asarray(m, np.float64)
 
     xs = np.asarray(x.astype(jnp.float32), np.float64)[0]
-    wg, wu, wd = (deq(w[n]) for n in ("we_gate", "we_up", "we_down"))
+    wu, wd = (deq(w[n]) for n in ("we_up", "we_down"))
+    # the plain form (relu2) has no gate: its place is never read
+    wg = deq(w["we_gate"]) if w["we_gate"] is not None else wu
     held = len(wg)
     bg, bu, bd = (
         (np.asarray(b.astype(jnp.float32), np.float64) for b in kw["biases"])
@@ -117,17 +137,24 @@ def _reference(cfg, x, top_idx, top_w, w, kw, first=0):
             if cfg.moe_act == "gptoss":
                 g, u = np.minimum(g, 7.0), np.clip(u, -7.0, 7.0)
                 h = (u + 1.0) * g * sig(1.702 * g)
+            elif cfg.moe_act == "relu2":
+                h = np.maximum(u, 0.0) ** 2
             else:
                 h = g * sig(g) * u
             out[t] += wt * (h @ wd[e] + bd[e])
     out *= cfg.routed_scaling_factor
     if "shared" in kw:
-        sg, su, sd = (
+        su, sd = (
             np.asarray(m.astype(jnp.float32), np.float64)
-            for m in kw["shared"][:3]
+            for m in kw["shared"][1:3]
         )
-        a = xs @ sg
-        out += (a * sig(a) * (xs @ su)) @ sd
+        if kw["shared"][0] is None:
+            out += (np.maximum(xs @ su, 0.0) ** 2) @ sd
+        else:
+            a = xs @ np.asarray(
+                kw["shared"][0].astype(jnp.float32), np.float64
+            )
+            out += (a * sig(a) * (xs @ su)) @ sd
     return out
 
 
@@ -136,7 +163,7 @@ def _reference(cfg, x, top_idx, top_w, w, kw, first=0):
 @pytest.mark.parametrize(
     "scoring,shared",
     [("softmax", False), ("softmax", True), ("sigmoid_bias", True),
-     ("gptoss", False)],
+     ("gptoss", False), ("relu2", True)],
 )
 def test_grouped_equals_dense_pair_by_pair(scoring, shared, routing, int8):
     rows = ROUTINGS[routing]
@@ -344,6 +371,11 @@ DECODE_CONFIGS = {
     "share_of_2_from_4": dataclasses.replace(
         SCORINGS["sigmoid_bias"], experts_held=2, first_held_expert=4
     ),
+    # Nemotron-H's two-matrix experts, whole and as a share
+    "relu2": SCORINGS["relu2"],
+    "relu2_share_of_2_from_4": dataclasses.replace(
+        SCORINGS["relu2"], experts_held=2, first_held_expert=4
+    ),
 }
 
 
@@ -377,6 +409,7 @@ def _decode_layer(name: str, int8: bool, rows: int, seed: int = 0):
             (jax.random.normal(ks[8], (F, D)) * 0.2).astype(dtype),
             None,
         )
+    w, kw = _plain(cfg, w, kw)
     return cfg, x, router, w, kw
 
 

@@ -700,3 +700,138 @@ def test_the_touched_kernel_takes_other_models_widths(
         scales, on((), jnp.int32),
     ).compile()
     assert "moe_touched_experts" in compiled.as_text()
+
+
+# ---- Nemotron-3-Nano: a recurrent state beside K and V (PR 46) ----
+
+
+def _nemotron(pattern: str):
+    """The benchmark's configuration at its published widths and its
+    share, ``pattern`` deep (every kind of layer; the programs are
+    unrolled, so a layer's operations are what is looked at)."""
+    import json
+    import os
+
+    from gpustack_tpu.models.config import config_from_hf
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)
+    )))
+    with open(os.path.join(
+        root, "perfbench", "configs", "nemotron-3-nano-30b-a3b-int8-ep8",
+        "config.json",
+    )) as f:
+        hf = json.load(f)
+    hf.update(hybrid_override_pattern=pattern, num_hidden_layers=len(pattern))
+    return config_from_hf(hf, "nemotron-3-nano")
+
+
+def test_the_hybrid_decode_step_moves_its_state_in_place(one_chip):
+    """The decode program of the benchmark's hybrid as the runner traces
+    it on one TPU chip, 32 slots of 4,096: every state-space layer's
+    ``ssm_state_update`` reads and writes the stacked state where it
+    lies (donated and aliased; PR 29 found XLA copying a donated stacked
+    carry whole twice a step), the GQA kernel takes 2 kv heads, the
+    touched-experts kernel the two-matrix form, and the experts' stored
+    width keeps their matrices out of the temporaries (at 1,856 columns
+    the TPU stores ``we_up`` transposed and the program copied all of
+    it before every call: 1.8 GB of temporaries at full depth)."""
+    from gpustack_tpu.models import init_params
+    from gpustack_tpu.models.hybrid import ssm_update_impl
+    from gpustack_tpu.models.quant import quantize_params
+    from gpustack_tpu.models.transformer import (
+        KVCache,
+        decode_attention_impl,
+        forward,
+        moe_dispatch,
+    )
+
+    cfg = _nemotron("MEM*EME")
+    slots, S = 32, 4096
+    assert moe_dispatch(slots, cfg, "tpu", None, decode=True) == "touched"
+    assert decode_attention_impl(cfg, 1, S, "tpu", None) == "kernel"
+    assert ssm_update_impl(1, "tpu", None) == "kernel"
+    assert ssm_update_impl(512, "tpu", None) == "scan"
+    assert ssm_update_impl(1, "cpu", None) == "xla"
+    params = _shapes_on(
+        one_chip, lambda: quantize_params(init_params(cfg, jax.random.key(0)))
+    )
+    assert params["moe_layers"]["we_up"].q.shape == (3, 16, 2688, 1920)
+    cache = _shapes_on(one_chip, lambda: KVCache.create(cfg, slots, S))
+    ids = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)
+
+    def step(params, cache, tokens, positions, live):
+        return forward(
+            params, cfg, tokens, positions, cache, live=live,
+            decode_attn_impl="kernel", moe_dispatch_impl="touched",
+            ssm_impl="kernel", count_experts_read=True,
+        )
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, ids, ids, live
+    ).compile()
+    text = compiled.as_text()
+    state = f"f32[3,{slots},64,64,128]"
+    assert len(re.findall(
+        rf"%ssm_state_update[\w.\-]* = \({re.escape(state)}", text
+    )) == 3
+    assert not re.findall(
+        rf"= {re.escape(state)}[^ ]* (?:copy|dynamic-update-slice|"
+        r"dynamic-slice|transpose)\(", text,
+    )
+    assert re.search(
+        rf"%gqa_decode_attention[\w.\-]* = bf16\[{slots},32,128\]"
+        r".* custom-call\(", text,
+    )
+    assert len(re.findall(
+        rf"%moe_touched_experts[\w.\-]* = f32\[{slots},2688\].* custom-call\(",
+        text,
+    )) == 3
+    # the stacked experts go in as they are stored: no copy of the stack
+    # into another layout, no layer's slab cut out of it
+    assert not re.findall(
+        r"= s8\[(?:3,)?16,(?:2688,1920|1920,2688)\][^ ]* "
+        r"(?:copy|dynamic-slice|transpose)\(", text,
+    )
+    mem = compiled.memory_analysis()
+    state_bytes = 3 * slots * 64 * 64 * 128 * 4
+    assert mem.alias_size_in_bytes >= state_bytes
+    # no copy of the state (0.2 GB here, 1.57 GB at full depth)
+    assert mem.temp_size_in_bytes < 0.25 * state_bytes
+
+
+def test_the_touched_kernel_takes_two_matrix_experts_of_1920(one_chip):
+    from gpustack_tpu.ops import grouped_matmul as gm
+
+    B, E, D, F = 32, 16, 2688, 1920
+    assert gm.choose_block_f(B, D, F, 2, 1, matrices=2) == 640
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    scales = (
+        None, on((23, E, 1, F), jnp.bfloat16), on((23, E, 1, D), jnp.bfloat16)
+    )
+    compiled = jax.jit(
+        lambda x, c, ids, n, up, down, scales, layer: gm.touched_experts(
+            x, c, ids, n, None, up, down, scales, layer
+        )
+    ).lower(
+        on((B, D), jnp.bfloat16), on((B, E), jnp.float32),
+        on((E,), jnp.int32), on((1,), jnp.int32),
+        on((23, E, D, F), jnp.int8), on((23, E, F, D), jnp.int8),
+        scales, on((), jnp.int32),
+    ).compile()
+    assert "moe_touched_experts" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.05e9
+
+
+def test_flash_prefill_takes_two_kv_heads_of_sixteen_query_heads(one_chip):
+    cfg = _nemotron("*")
+    assert (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim) == (32, 2, 128)
+    compiled = _flash_compiled(one_chip, cfg, 1024, 1024)
+    assert re.search(
+        r"%flash_attention_prefill[\w.\-]* = bf16\[1,32,1024,128\]"
+        r".* custom-call\(", compiled.as_text(),
+    )

@@ -56,6 +56,9 @@ def operands(S, kv_heads, group, slots, dtype=jnp.float32, layers=3):
         (2048, 8, 4, [2048, 513, 512]),        # four blocks of 512
         (256, 4, 8, [0, 128, 0, 0, 129, 0]),   # nobody holds 0, 2, 3, 5
         (256, 8, 4, [40, 0]),                  # ... or the last
+        # Nemotron-3-Nano: 2 kv heads, sixteen query heads a group
+        (1024, 2, 16, [1024, 513, 0, 7]),
+        (64, 2, 16, [33, 64]),
         (64, 2, 2, [0, 0]),                    # ... or any
     ],
 )

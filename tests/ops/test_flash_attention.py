@@ -245,6 +245,7 @@ def test_the_rows_of_a_matmul_divide_the_block_for_any_group(G):
     (1, 256, 4, 2, 64),
     (2, 128, 2, 2, 64),     # MHA
     (1, 200, 4, 1, 64),     # MQA + non-block-multiple T
+    (1, 256, 32, 2, 128),   # Nemotron-3-Nano: 2 kv heads, groups of 16
 ])
 def test_flash_matches_reference(B, T, Hq, Hkv, d):
     ks = jax.random.split(jax.random.key(0), 3)

@@ -112,3 +112,44 @@ def test_resolve_errors():
         resolve_model_config(
             Model(name="x", huggingface_repo_id="meta/llama")
         )
+
+
+def _nemotron(**spec):
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)
+    )))
+    return Model(
+        name="m", quantization="int8", local_path=os.path.join(
+            root, "perfbench", "configs", "nemotron-3-nano-30b-a3b-int8-ep8"
+        ), **spec,
+    )
+
+
+def test_a_hybrid_claims_its_slots_states_beside_the_rows_of_six_layers():
+    """Nemotron-3-Nano's share as the benchmark deploys it: the weights,
+    and a slot a recurrent state of 23 layers (49.1 MB, whatever the
+    context) and rows of the 6 attention layers only (6 KB a position,
+    not 52 layers' 52 KB)."""
+    ev = evaluate_model(_nemotron(max_seq_len=4096, max_slots=32))
+    assert ev.config.layer_kinds is not None
+    # 5.26 B parameters at a byte, and the experts' stored width
+    assert 5.25e9 < ev.weight_bytes < 5.45e9
+    state = 23 * (64 * 64 * 128 * 4 + 3 * 6144 * 2)
+    rows = 6 * 2 * 2 * 128 * 2 * 4096
+    assert ev.kv_cache_bytes == 32 * (state + rows)
+    assert round(state / 1e6, 1) == 49.1 and round(rows / 1e6, 1) == 25.2
+    claim = chips_for_claim(ev, hbm_per_chip=16 * _GIB, max_chips=8)
+    assert claim is not None and claim.chips == 1
+    # the state does not grow with the context, the rows do
+    longer = evaluate_model(_nemotron(max_seq_len=8192, max_slots=32))
+    assert longer.kv_cache_bytes - ev.kv_cache_bytes == 32 * rows
+
+
+def test_a_hybrid_that_does_not_fit_one_chip_is_not_spread_over_more():
+    """The runner serves such a model on one device: a claim of two
+    chips would start an instance that refuses its mesh."""
+    ev = evaluate_model(_nemotron(max_seq_len=4096, max_slots=160))
+    assert ev.total_bytes > 16 * _GIB
+    assert chips_for_claim(ev, hbm_per_chip=16 * _GIB, max_chips=8) is None
