@@ -374,15 +374,12 @@ def forward_hybrid(
 
     def attention(h, lp, carried, i):
         """One GQA layer, no rotary embedding: ``(out, carried)``."""
-        q = tf._mm("btd,dq->btq", h, lp["wq"]).reshape(
-            B, T, cfg.num_kv_heads, cfg.group_size, cfg.head_dim
+        q, k, v = tf.qkv_projections(
+            h, lp, decode=carried is not None and T == 1
         )
-        k = tf._mm("btd,dk->btk", h, lp["wk"]).reshape(
-            B, T, cfg.num_kv_heads, cfg.head_dim
-        )
-        v = tf._mm("btd,dk->btk", h, lp["wv"]).reshape(
-            B, T, cfg.num_kv_heads, cfg.head_dim
-        )
+        q = q.reshape(B, T, cfg.num_kv_heads, cfg.group_size, cfg.head_dim)
+        k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+        v = v.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
         if carried is None:
             attn = tf._attend(q, k, v, mask, scale)
         else:

@@ -49,6 +49,40 @@ def _mm(eq: str, x: jax.Array, w) -> jax.Array:
     return jnp.einsum(eq, x, w)
 
 
+def finish_products(decode: bool, *products):
+    """The projections ``products`` (each ``[B, T, heads * width]``)
+    of a layer's attention, finished before anything reads them where
+    ``decode`` says the step is one row a slot over a cache (static).
+
+    Without the barrier the TPU's compiler folds the reshape to heads
+    that follows (and a q/k norm's sum of squares) into each product as
+    an output fusion that writes heads-major, and for that wants the
+    weight with the model dimension minor: it slices the layer's matrix
+    out of the stacked int8 array and copies it into the transposed
+    layout, every layer of every step (``wq``, ``wk`` and ``wv``: 3.7
+    of the 8B deployment's 20.4 ms step; PERF.md, PR 47). Behind the
+    barrier each is a plain ``[B, width]`` product that reads the stack
+    where it lies, as the MLP's and ``wo``'s do;
+    ``tests/ops/test_chip_compile.py::
+    test_a_decode_step_reads_its_attention_weights_in_place`` holds it.
+    With ``T > 1`` the copy is under a hundredth of the layer and the
+    barrier would cost a pass over the products; and the trainer
+    differentiates the cacheless path, which a barrier stays out of.
+    """
+    return lax.optimization_barrier(products) if decode else products
+
+
+def qkv_projections(h: jax.Array, lp, decode: bool):
+    """``(q, k, v)`` of one GQA layer as ``[B, T, heads * head_dim]``,
+    before bias, reshape, norm and rotation (:func:`finish_products`)."""
+    return finish_products(
+        decode,
+        _mm("btd,dq->btq", h, lp["wq"]),
+        _mm("btd,dk->btk", h, lp["wk"]),
+        _mm("btd,dk->btk", h, lp["wv"]),
+    )
+
+
 def _embed_lookup(embed, tokens: jax.Array, dtype) -> jax.Array:
     if isinstance(embed, QuantW):
         x = jnp.take(embed.q, tokens, axis=0).astype(dtype)
@@ -1390,6 +1424,8 @@ def forward(
             q = _mm("btr,rq->btq", q_c, lp["wq_b"])
         else:
             q = _mm("btd,dq->btq", h, lp["wq"])
+        # wq_b meets the same fold as a GQA layer's wq
+        (q,) = finish_products(carried is not None and T == 1, q)
         q = q.reshape(B, T, H, cfg.head_dim)
         q_nope = q[..., :nope]
         q_pe = apply_rope_interleaved(q[..., nope:], mla_sin, mla_cos)
@@ -1494,9 +1530,9 @@ def forward(
         if cfg.is_mla:
             attn, carried = mla_attention(h, lp, carried, layer, mask_l)
         else:
-            q = _mm("btd,dq->btq", h, lp["wq"])
-            k = _mm("btd,dk->btk", h, lp["wk"])
-            v = _mm("btd,dk->btk", h, lp["wv"])
+            q, k, v = qkv_projections(
+                h, lp, decode=cache is not None and T == 1
+            )
             if cfg.qkv_bias:
                 q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
             q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)

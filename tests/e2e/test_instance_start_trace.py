@@ -121,7 +121,12 @@ def started(tmp_path_factory):
 
 
 def _instance_start(started):
-    mine = [t for t in started["traces"] if t["name"] == "instance_start"]
+    # the worker's trace store is the process's: another test file on this
+    # xdist worker may have left an ``instance_start`` of its own model
+    mine = [
+        t for t in started["traces"]
+        if t["name"] == "instance_start" and t.get("model") == "tiny-start"
+    ]
     assert len(mine) == 1, started["traces"]
     return mine[0]
 

@@ -384,3 +384,37 @@ def test_float8_activations_read_further_from_the_engine_than_float32():
     low = np.asarray(ref.forward(params, HF, seq.tolist(), want, fault="fp8_activations"))
     bf16 = np.asarray(ref.forward(params, HF, seq.tolist(), want, fault="bf16_softmax"))
     assert np.max(np.abs(low - own)) > 10 * np.max(np.abs(bf16 - own)) > 0
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["float32", "int8"])
+def test_wq_b_s_finished_product_moves_nothing_past_rounding(
+    with_and_without_the_barrier, int8
+):
+    """``wq_b``'s product meets the fold a GQA layer's ``wq`` met, and a
+    decode step finishes it behind the same barrier
+    (``transformer.finish_products``): logits and the latent cache are
+    the program's without it to float32's rounding (a compiler is free
+    to associate the absorbed query's two products the other way when
+    nothing stands between them, and the CPU's does: 6e-6 at most
+    here, where the GQA layers' tests are bit for bit)."""
+    cfg, params = model(HF, int8)
+    toks = tokens(2).reshape(2, 1)
+    pos = jnp.asarray([[7], [3]], jnp.int32)
+    shapes = jax.eval_shape(lambda: KVCache.create(cfg, 2, 16))
+    k, v = (
+        jax.random.normal(jax.random.key(i), s.shape).astype(s.dtype)
+        for i, s in ((7, shapes.k), (8, shapes.v))
+    )
+
+    def program():
+        def step(k, v):
+            logits, cache = forward(params, cfg, toks, pos, KVCache(k=k, v=v))
+            return logits, cache.k, cache.v
+
+        return step
+
+    # one in each stack's scan: the leading dense layer's, the others'
+    got, want = with_and_without_the_barrier(program, k, v, barriers=2)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=0, atol=5e-5)
+    assert not np.array_equal(np.asarray(got[1]), np.asarray(k))

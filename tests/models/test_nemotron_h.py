@@ -400,3 +400,33 @@ def test_the_checkpoint_s_names_round_trip(tmp_path):
         np.asarray(params["moe_layers"]["we_up"][:, 4:6], np.float32),
         rtol=1e-2, atol=1e-3,
     )
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["float32", "int8"])
+def test_the_attention_layer_s_finished_products_change_no_number(
+    with_and_without_the_barrier, int8
+):
+    """The hybrid's GQA layers take ``wq``, ``wk`` and ``wv`` through the
+    helper every other model's layer does (``transformer.
+    qkv_projections``), with its barrier in a decode step: logits, rows
+    and both states are bit for bit the program's without it."""
+    cfg, params = model(int8=int8)
+    toks = tokens(2).reshape(2, 1)
+    pos = jnp.asarray([[7], [3]], jnp.int32)
+    shapes = jax.eval_shape(lambda: KVCache.create(cfg, 2, 16))
+    cache = jax.tree.map(
+        lambda s: 0.1 * jax.random.normal(
+            jax.random.key(s.size % 97), s.shape
+        ).astype(s.dtype),
+        shapes,
+    )
+
+    def program():
+        def step(cache):
+            return forward(params, cfg, toks, pos, cache)
+
+        return step
+
+    got, want = with_and_without_the_barrier(program, cache)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -424,3 +424,65 @@ def test_row_write_by_position_equals_the_scatter(T):
     for b, s0 in enumerate(np.minimum(np.asarray(start), S - T)):
         want[1, b, s0:s0 + T] = np.asarray(rows[b].astype(jnp.float32))
     np.testing.assert_array_equal(got[0], want)
+
+
+@pytest.mark.parametrize("T", [1, 3], ids=["decode", "ragged-prefill"])
+@pytest.mark.parametrize("weights", ["float32", "int8"])
+@pytest.mark.parametrize("preset", ["tiny", "tiny-qwen3"])   # qk-norm: no, yes
+def test_finished_products_change_no_number(
+    with_and_without_the_barrier, preset, weights, T
+):
+    """A decode step finishes ``wq``'s, ``wk``'s and ``wv``'s products
+    behind a barrier so that the TPU reads the weights where they lie
+    (``transformer.finish_products``). Same mathematics: the logits and
+    every row of the cache, written or not, are bit for bit what the
+    program without the barrier gives: a decode step and a block of
+    rows at ragged positions, float32 weights and int8 under bf16."""
+    from gpustack_tpu.models.quant import quantize_params
+
+    cfg = get_config(preset)
+    if weights == "float32":
+        cfg = dataclasses.replace(cfg, dtype="float32")
+        params = init_params(cfg, jax.random.key(0), dtype=jnp.float32)
+        dtype = jnp.float32
+    else:
+        params = quantize_params(init_params(cfg, jax.random.key(0)))
+        dtype = jnp.bfloat16
+    B, S = 3, 16
+    lens = np.array([5, 9, 2])                   # ragged contexts
+    shape = (cfg.num_layers, B, S, cfg.num_kv_heads, cfg.head_dim)
+    k = jax.random.normal(jax.random.key(7), shape).astype(dtype)
+    v = jax.random.normal(jax.random.key(8), shape).astype(dtype)
+    positions = jnp.asarray(
+        np.stack([np.arange(m, m + T) for m in lens]).astype(np.int32)
+    )
+    toks = _tokens(cfg, B, T)
+
+    def program():
+        def step(k, v):
+            logits, cache = forward(
+                params, cfg, toks, positions, KVCache(k=k, v=v)
+            )
+            return logits, cache.k, cache.v
+
+        return step
+
+    got, want = with_and_without_the_barrier(
+        program, k, v, barriers=1 if T == 1 else 0
+    )
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    assert not np.array_equal(np.asarray(got[1]), np.asarray(k))  # rows written
+
+
+def test_no_barrier_stands_in_what_the_trainer_differentiates(tiny):
+    """Without a cache (training, scoring) the products are handed on as
+    they are, whatever ``T``: the barrier is a decode step's alone."""
+    cfg, params = tiny
+    toks = _tokens(cfg, 2, 1)
+    pos = jnp.zeros((2, 1), jnp.int32)
+
+    def loss(params):
+        return forward(params, cfg, toks, pos)[0].sum()
+
+    assert "optimization_barrier" not in str(jax.make_jaxpr(jax.grad(loss))(params))

@@ -240,6 +240,43 @@ def test_a_step_moves_only_its_rows_of_the_donated_cache(
 
 
 @pytest.mark.parametrize(
+    "preset,layers,slots",
+    [
+        ("qwen3-8b", 4, 12),          # q and k norms under the rope
+        ("qwen3-30b-a3b", 2, 32),     # the MoE deployment's
+        ("qwen2.5-7b", 4, 12),        # no qk-norm, biases: the reshape alone
+    ],
+)
+def test_a_decode_step_reads_its_attention_weights_in_place(
+    one_chip, preset, layers, slots
+):
+    """``wq``, ``wk`` and ``wv`` are read out of the stacked int8 arrays
+    inside their products' fusions, as the MLP's and ``wo``'s are. With
+    the reshape to heads (and the q/k norm's reduce) folded into the
+    product, the compiler wanted each weight with the model dimension
+    minor: a stand-alone ``dynamic-slice`` fusion took the layer's matrix
+    out of the stack (``s8[1,4096,4096]``, ``s8[1,4096,1024]`` twice at
+    the 8B's widths) and a ``copy`` wrote it again transposed, every
+    layer of every step: 3.7 of the 8B deployment's 20.4 ms step
+    (PERF.md, PR 47). ``transformer.finish_products`` keeps the fold
+    from forming in a decode step; this keeps it from coming back."""
+    cfg = dataclasses.replace(get_config(preset), num_layers=layers)
+    text = _int8_step_compiled(one_chip, cfg, slots, 1).as_text()
+    d, q, kv = cfg.hidden_size, cfg.q_dim, cfg.kv_dim
+    taken_out = re.findall(
+        rf"%([\w.-]+) = s8\[1,{d},(?:{q}|{kv})\][^ ]* (?:copy|fusion)\(",
+        text,
+    )
+    assert taken_out == []
+    # and no int8 value of any shape is copied: the weights are the
+    # program's only int8 arrays
+    assert not re.findall(r"%([\w.-]+) = s8\[[\d,]*\][^ ]* copy\(", text)
+    # the three products are there, under their einsums' names
+    assert "btd,dq->btq/dot_general" in text
+    assert "btd,dk->btk/dot_general" in text
+
+
+@pytest.mark.parametrize(
     "preset,change,rows,max_len,platform,devices,want",
     [
         ("qwen3-8b", {}, 1, 2048, "tpu", 1, "kernel"),
@@ -562,6 +599,9 @@ def test_the_latent_decode_step_moves_no_cache_but_the_rope_keys(one_chip):
         and re.search(r" (copy|transpose|dynamic-slice)\(", line)
     ]
     assert moved == []
+    # wq_b is read out of the stack inside its product
+    # (transformer.finish_products: it met the fold a GQA layer's wq met)
+    assert not re.findall(r"= s8\[1,1536,12288\][^ ]* (?:copy|fusion)\(", text)
     # the cache is the 1,152 bytes a position a layer, and is aliased
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * slots * S * 1152
@@ -793,6 +833,13 @@ def test_the_hybrid_decode_step_moves_its_state_in_place(one_chip):
     assert not re.findall(
         r"= s8\[(?:3,)?16,(?:2688,1920|1920,2688)\][^ ]* "
         r"(?:copy|dynamic-slice|transpose)\(", text,
+    )
+    # the attention layer's wq, wk and wv are read where they lie too
+    # (transformer.finish_products; s8[4096,2688] and s8[1,2688,256]
+    # twice were copied transposed every step until PR 47)
+    assert not re.findall(
+        r"= s8\[(?:1,)?(?:2688,4096|4096,2688|2688,256|256,2688)\][^ ]* "
+        r"copy\(", text,
     )
     mem = compiled.memory_analysis()
     state_bytes = 3 * slots * 64 * 64 * 128 * 4
