@@ -222,7 +222,7 @@ class OpenAIServer:
             lines.append(f"{family} {value}")
         family = "gpustack_engine_cache_bytes"
         lines.append(f"# TYPE {family} {METRIC_FAMILIES[family]}")
-        for kind in ("kv", "state"):
+        for kind in ("kv", "state", "window"):
             lines.append(
                 f'{family}{{kind="{kind}"}} {h["cache"][kind + "_bytes"]}'
             )

@@ -477,6 +477,14 @@ class _KVStager:
             return fut
 
 
+def _refuse_what_was_asked(model_has: str, asked) -> None:
+    """The first of ``asked`` (``(was it asked for, why it cannot be)``)
+    that was asked for, refused by name."""
+    for on, why in asked:
+        if on:
+            raise ValueError(f"{model_has} and cannot be served with {why}")
+
+
 def _refuse_for_a_state(
     cfg, speculative, host_kv_cache_mb, kv_spill_mb, kv_role, prefill_chunk
 ) -> None:
@@ -498,12 +506,36 @@ def _refuse_for_a_state(
         (prefill_chunk > 0, "prefill_chunk: a chunk goes on from cached "
          "rows (prefill_with_prefix), which carry no recurrent state"),
     ]
-    for on, why in asked:
-        if on:
-            raise ValueError(
-                f"{cfg.name} has state-space layers and cannot be served "
-                f"with {why}"
-            )
+    _refuse_what_was_asked(f"{cfg.name} has state-space layers", asked)
+
+
+def _refuse_for_a_ring(
+    cfg, speculative, host_kv_cache_mb, kv_spill_mb, kv_role, prefill_chunk
+) -> None:
+    """A stack that keeps its sliding layers' rows at window size
+    (``KVCache.wk``: a ring, row = position mod window) holds of a slot
+    only the last ``window`` positions of those layers: whatever cuts,
+    stores, moves or rolls back a slot by positions would need rows the
+    ring has overwritten. Each such mechanism is refused here, at engine
+    start, by name (a mesh of several devices: ``ModelRunner``)."""
+    asked = [
+        (speculative, f"speculative={speculative!r}: a verify step cannot "
+         "roll back rows a ring has overwritten with a rejected draft's"),
+        (host_kv_cache_mb > 0, "host_kv_cache_mb: the prefix cache "
+         "(engine/kv_host_cache.py) keeps blocks of rows a token span; a "
+         "span's sliding rows are gone once the window has passed it"),
+        (kv_spill_mb > 0, "kv_spill_mb: the spill tier (engine/kv_spill.py) "
+         "stores blocks of rows a token span, which a ring does not keep"),
+        (kv_role, f"kv_role={kv_role!r}: a KV handoff (engine/"
+         "kv_transfer.py) moves blocks of rows a token span, which a ring "
+         "does not keep"),
+        (prefill_chunk > 0, "prefill_chunk: a chunk goes on from cached "
+         "rows (prefill_with_prefix), and a ring holds only the last "
+         "window of them"),
+    ]
+    _refuse_what_was_asked(
+        f"{cfg.name} keeps its sliding layers' rows at window size", asked
+    )
 
 
 class LLMEngine:
@@ -540,6 +572,11 @@ class LLMEngine:
                 cfg, speculative, host_kv_cache_mb, kv_spill_mb, kv_role,
                 prefill_chunk,
             )
+        if cfg.window_rows:
+            _refuse_for_a_ring(
+                cfg, speculative, host_kv_cache_mb, kv_spill_mb, kv_role,
+                prefill_chunk,
+            )
         self.tokenizer = tokenizer or load_tokenizer(model_dir)
         self.runner = ModelRunner(
             cfg, params, plan=plan, mesh=mesh,
@@ -562,6 +599,14 @@ class LLMEngine:
         self._state_dtype = (
             str(cache.ssm.dtype) if cache.ssm is not None else None
         )
+        # the third: the window store of a stack that keeps its sliding
+        # layers' rows at window size (KVCache.wk / .wv), 0 for any
+        # other model; kv_bytes is then the full layers' rows alone
+        self._window_bytes = (
+            int(cache.wk.nbytes + cache.wv.nbytes)
+            if cache.wk is not None else 0
+        )
+        self._window_rows = cache.wk.shape[2] if cache.wk is not None else 0
         self._slots: Dict[int, _SlotInfo] = {}
         self._free = list(range(max_slots))
         self._waiting: "queue.Queue[GenRequest]" = queue.Queue()
@@ -635,6 +680,10 @@ class LLMEngine:
         # prompt tokens the step sent through the chunked scan
         self._step_state_slots = 0
         self._step_ssm_tokens = 0
+        # a stack with a window store: rows the step's sliding layers
+        # and its full layers attended, over slots, positions and layers
+        self._step_window_rows = 0
+        self._step_full_rows = 0
         self._step_spec_proposed = 0
         self._step_spec_accepted = 0
         # on-demand profiler capture (capture_profile): the capturing
@@ -906,6 +955,10 @@ class LLMEngine:
                 "kv_bytes": self._kv_cache_bytes,
                 "state_bytes": self._state_bytes,
                 "state_dtype": self._state_dtype,
+                # the sliding layers' rows, kept at window size (0 for a
+                # model without such a store); kv_bytes is then the
+                # full layers' rows
+                "window_bytes": self._window_bytes,
             },
             # how a state-space layer runs its scan over a prompt and
             # moves its state in a decode step (runner.ssm_update:
@@ -1114,6 +1167,7 @@ class LLMEngine:
         self._step_attn = None
         self._step_kv_live = self._step_kv_allocated = 0
         self._step_state_slots = self._step_ssm_tokens = 0
+        self._step_window_rows = self._step_full_rows = 0
         self._step_moe_read = self._step_moe_held = 0
         self._step_spec_proposed = self._step_spec_accepted = 0
         self._step_admitted = []
@@ -1160,10 +1214,25 @@ class LLMEngine:
         self._step_attn = self.runner.attn_label(bucket)
         if self._state_bytes:
             self._step_ssm_tokens += tokens
+        if self._window_bytes:
+            # position i attends min(i + 1, window) keys in a sliding
+            # layer and i + 1 in a full one
+            w = min(tokens, self._window_rows)
+            self._note_attended(
+                w * (w + 1) // 2 + (tokens - w) * w,
+                tokens * (tokens + 1) // 2,
+            )
         dispatch = self.runner.moe_dispatch_for(bucket)
         if dispatch is not None:
             by_dispatch = self._step_moe_dispatch
             by_dispatch[dispatch] = by_dispatch.get(dispatch, 0) + tokens
+
+    def _note_attended(self, sliding: int, full: int) -> None:
+        """Rows a sliding layer and a full layer attended in this step,
+        over slots and positions: counted for every layer of the kind."""
+        cfg = self.cfg
+        self._step_window_rows += sliding * cfg.num_window_layers
+        self._step_full_rows += full * cfg.num_kv_layers
 
     def _flight_record(self, t0: float) -> None:
         """Seal this step's flight record (and advance an in-flight
@@ -1214,6 +1283,10 @@ class LLMEngine:
             ssm=(
                 (self._step_state_slots, self._step_ssm_tokens)
                 if self._state_bytes else None
+            ),
+            attn_rows=(
+                (self._step_window_rows, self._step_full_rows)
+                if self._window_bytes else None
             ),
         )
         if dur_s > _SLOW_STEP_S:
@@ -1975,13 +2048,19 @@ class LLMEngine:
             # what the step's live slots attend of what the cache
             # allocates, from the scheduler's own counts (flight
             # ``kv_live_pct``)
-            self._step_kv_live += sum(
+            lengths = [
                 len(info.request.prompt_ids) + len(info.request.output_ids)
                 for info in self._slots.values()
-            )
+            ]
+            self._step_kv_live += sum(lengths)
             self._step_kv_allocated += self.max_slots * self.max_seq_len
             if self._state_bytes:
                 self._step_state_slots += len(owners)
+            if self._window_bytes:
+                self._note_attended(
+                    sum(min(n, self._window_rows) for n in lengths),
+                    sum(lengths),
+                )
         self._step_count += 1
         return True
 

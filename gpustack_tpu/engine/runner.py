@@ -74,10 +74,14 @@ def prefill_attention(
     other path reads it). ``"flash"`` on a TPU for buckets >= 1024,
     where the XLA path's [B, H, T, S] fp32 score tensor starts to
     dominate prefill HBM traffic (at 32k it simply does not fit), for a
-    model the kernel takes. ``"xla"`` otherwise: the compiled kernel
-    exists only for the TPU (no serving path reaches the pallas
-    interpreter), and a sliding window, a softcap or sinks need the
-    einsum path's mask and scores.
+    model the kernel takes: a causal mask, and under it the band of a
+    stack that keeps its sliding layers' rows at window size
+    (``cfg.window_rows``: its sliding layers call the kernel with the
+    window, its full layers without). ``"xla"`` otherwise: the compiled
+    kernel exists only for the TPU (no serving path reaches the pallas
+    interpreter), and a softcap, sinks or a window masked over ``S_max``
+    rows need the einsum path's mask and scores
+    (``transformer.needs_xla_attention``).
     """
     if sp_mode:
         return "ring"
@@ -147,6 +151,19 @@ class ModelRunner:
                 "shards a cache over positions, which a recurrent state "
                 f"has none of; got plan {self.plan}"
             )
+        # a stack that keeps its sliding layers' rows at window size has a
+        # second store a slot beside k and v (KVCache.wk / .wv)
+        self.windowed = cfg.window_rows
+        if self.windowed and self.mesh.size > 1:
+            raise ValueError(
+                f"{cfg.name}: a model with a window store is served on "
+                "one device: the ring of a sliding layer and the band in "
+                "the kernels are not sharded yet (tp/ep/dp/sp); got plan "
+                f"{self.plan}"
+            )
+        # what a prefill hands on beside k and v, and insert takes as
+        # ``mixer``: a hybrid's recurrent state, a window store's rows
+        self.keeps_beside_rows = self.hybrid or self.windowed
         if self.sp_mode:
             if cfg.is_mla:
                 raise ValueError(
@@ -287,6 +304,10 @@ class ModelRunner:
                         dict(ssm=self._replicated, conv=self._replicated)
                         if self.hybrid else {}
                     ),
+                    **(
+                        dict(wk=self._replicated, wv=self._replicated)
+                        if self.windowed else {}
+                    ),
                 ),
                 last_tokens=self._slot_sharding,
                 positions=self._slot_sharding,
@@ -402,10 +423,17 @@ class ModelRunner:
             mesh=self.mesh,
             count_held_pairs=bool(self.cfg.experts_held),
             routing_out=routing,
-            **({"true_len": true_len[None]} if self.hybrid else {}),
+            **(
+                {"true_len": true_len[None]} if self.keeps_beside_rows
+                else {}
+            ),
         )
         last = jnp.take(logits[0], true_len - 1, axis=0)
-        mixer = ((cache.ssm[:, 0], cache.conv[:, 0]),) if self.hybrid else ()
+        mixer = ()
+        if self.hybrid:
+            mixer = ((cache.ssm[:, 0], cache.conv[:, 0]),)
+        elif self.windowed:
+            mixer = ((cache.wk[:, 0], cache.wv[:, 0]),)
         return (last, cache.k[:, 0], cache.v[:, 0], *mixer, *extras)
 
     def prefill(self, token_ids, true_len: int, routing: bool = False):
@@ -418,7 +446,8 @@ class ModelRunner:
 
         A hybrid returns one more after ``k, v``: ``(ssm, conv)``, the
         recurrent state the prompt ends in, for :meth:`insert`'s
-        ``mixer``."""
+        ``mixer``; a stack with a window store likewise ``(wk, wv)``,
+        its sliding layers' rows."""
         Tb = len(token_ids)
         assert Tb in self.prefill_buckets, (Tb, self.prefill_buckets)
         fns = self._prefills_routing if routing else self._prefills
@@ -434,7 +463,7 @@ class ModelRunner:
             fns[Tb] = fn
         tokens = jnp.asarray(token_ids, jnp.int32)[None, :]
         last, k, v, *extras = fn(self.params, tokens, jnp.int32(true_len))
-        mixer = (extras.pop(0),) if self.hybrid else ()
+        mixer = (extras.pop(0),) if self.keeps_beside_rows else ()
         if self.cfg.experts_held:
             self._note_pairs(extras[0], Tb)
         return (last, k, v, *mixer, *((extras[-1],) if routing else ()))
@@ -517,6 +546,12 @@ class ModelRunner:
         suffix_ids, suffix_true_len: int, total_bucket: int,
     ):
         """suffix_ids must be pre-padded to a prefill bucket."""
+        if self.windowed:
+            raise ValueError(
+                f"{self.cfg.name}: a prefill cannot go on from cached rows "
+                "(prefix reuse, chunked prefill): a span's sliding rows "
+                "are gone once the window has passed it"
+            )
         if self.hybrid:
             raise ValueError(
                 f"{self.cfg.name}: a prefill cannot go on from cached rows "
@@ -622,6 +657,16 @@ class ModelRunner:
                 ssm=cache.ssm.at[:, slot].set(ssm),
                 conv=cache.conv.at[:, slot].set(conv),
             )
+        if self.windowed:
+            # the prefill's ring rows lie where the slot's ring wants
+            # them (row = position mod W; a bucket under the window is
+            # its own first rows); what the slot's last tenant left
+            # above them is overwritten before a length reaches it
+            wk, wv = mixer
+            held = dict(
+                wk=cache.wk.at[:, slot, :wk.shape[1]].set(wk),
+                wv=cache.wv.at[:, slot, :wv.shape[1]].set(wv),
+            )
         return DecodeState(
             cache=KVCache(k=new_k, v=new_v, **held),
             last_tokens=state.last_tokens.at[slot].set(first_token),
@@ -640,6 +685,7 @@ class ModelRunner:
         mixer=None,
     ) -> DecodeState:
         """Place a prefill's rows, and for a hybrid its recurrent state
+        or for a stack with a window store its sliding layers' rows
         (``mixer``: what :meth:`prefill` returned after ``k, v``), in
         ``slot`` and make the slot live."""
         Tb = k.shape[1]
@@ -654,7 +700,7 @@ class ModelRunner:
             jnp.int32(top_k), jnp.float32(top_p),
             jnp.uint32(seed), jnp.bool_(seeded),
             bias_ids, bias_vals,
-            *((mixer,) if self.hybrid else ()),
+            *((mixer,) if self.keeps_beside_rows else ()),
         )
 
     def deactivate(self, state: DecodeState, slot: int) -> DecodeState:
@@ -875,6 +921,11 @@ class ModelRunner:
             raise ValueError(
                 f"{self.cfg.name}: a verify step cannot roll a recurrent "
                 "state back past a rejected draft"
+            )
+        if self.windowed:
+            raise ValueError(
+                f"{self.cfg.name}: a verify step cannot roll back rows a "
+                "ring has overwritten"
             )
         B, P = proposals.shape
         tokens = jnp.concatenate(

@@ -142,6 +142,13 @@ def build_lm_params(
     back-to-back (models/transformer.py)."""
     if cfg.layer_kinds is not None:
         return build_hybrid_params(cfg, tensors, quantization)
+    if cfg.parallel_block:
+        raise ValueError(
+            f"{cfg.name}: no checkpoint of a cohere2_moe stack is read "
+            "yet (its tensors' names are not mapped); it is served with "
+            "seeded weights from a directory that holds a config.json "
+            "alone"
+        )
     L = cfg.num_layers
     take = _taker(tensors)
     kd = cfg.first_k_dense if cfg.is_moe else 0

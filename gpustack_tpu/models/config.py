@@ -135,6 +135,29 @@ class ModelConfig:
     mamba_n_groups: int = 1
     conv_kernel: int = 4
     ssm_chunk_size: int = 128
+    # ---- Cohere2-MoE (Command A+) knobs ----
+    # one norm a layer, attention and MLP both from it and both added to
+    # the stream (``use_parallel_block``)
+    parallel_block: bool = False
+    # mean-centred LayerNorm without bias where every other family has
+    # an RMS norm; ``rms_norm_eps`` holds its epsilon
+    layer_norm: bool = False
+    # rotary embedding in interleaved pairs over the whole head
+    # (``rope_gptj``), and none at all on the full-attention layers of a
+    # stack with ``layer_sliding``
+    rope_interleaved: bool = False
+    nope_full_layers: bool = False
+    # the sliding layers' rows in a store of their own of
+    # ``sliding_window`` rows a slot (``KVCache.wk / .wv``), written at
+    # ``position mod window``; the full layers' rows in ``k, v``. False:
+    # a window is a mask over ``S_max`` rows (the Gemma / GPT-OSS files)
+    window_rows: bool = False
+    # the shared experts' outputs are averaged, not summed
+    # (``shared_expert_combination_strategy: "average"``)
+    shared_expert_average: bool = False
+    # False: a sigmoid router without the selection's correction bias
+    router_correction_bias: bool = True
+    logit_scale: float = 1.0
     dtype: str = "bfloat16"
 
     # ---- derived ----
@@ -171,11 +194,29 @@ class ModelConfig:
 
     @property
     def num_kv_layers(self) -> int:
-        """Layers that keep rows a position in the cache: every layer,
-        or the hybrid's attention layers."""
+        """Layers that keep a row for every position of a slot in the
+        cache's ``k, v``: every layer, the hybrid's attention layers, or
+        under ``window_rows`` the full-attention layers."""
         if self.layer_kinds is None:
-            return self.num_layers
+            return self.num_layers - self.num_window_layers
         return self.layers_of("*")
+
+    @property
+    def window_period(self) -> Tuple[bool, ...]:
+        """The shortest run of ``layer_sliding`` that the stack repeats
+        (three sliding layers and a full one); the whole stack where it
+        repeats nothing. ``forward`` scans over periods and writes a
+        period's layers out in the scan's body."""
+        flags = self.layer_sliding or ()
+        for n in range(1, len(flags) + 1):
+            if len(flags) % n == 0 and flags == flags[:n] * (len(flags) // n):
+                return flags[:n]
+        return ()
+
+    @property
+    def num_window_layers(self) -> int:
+        """Layers whose rows live in the window store (``window_rows``)."""
+        return sum(self.layer_sliding) if self.window_rows else 0
 
     @property
     def num_moe_layers(self) -> int:
@@ -234,6 +275,11 @@ class ModelConfig:
         if self.layer_sliding is not None:
             assert len(self.layer_sliding) == self.num_layers
             assert self.sliding_window > 0
+        if self.window_rows:
+            assert self.layer_sliding is not None and not self.is_mla
+            assert not (self.attn_logit_softcap or self.attn_sinks), (
+                "the blocked kernels take a window, no softcap or sinks"
+            )
         if self.layer_kinds is not None:
             assert len(self.layer_kinds) == self.num_layers
             assert set(self.layer_kinds) <= {"M", "E", "*"}, self.layer_kinds
@@ -303,11 +349,11 @@ class ModelConfig:
             )
             if self.shared_expert_intermediate_size:
                 mlp += 3 * d * self.shared_expert_intermediate_size
-            if self.moe_scoring == "sigmoid":
+            if self.moe_scoring == "sigmoid" and self.router_correction_bias:
                 mlp += self.num_experts     # e_score_correction_bias
         else:
             mlp = 3 * d * self.intermediate_size
-        norms = (4 if self.post_norms else 2) * d
+        norms = (4 if self.post_norms else 1 if self.parallel_block else 2) * d
         per_layer = attn + mlp + norms
         dense_delta = 0
         if self.is_moe and self.first_k_dense:
@@ -337,6 +383,14 @@ class ModelConfig:
         per_layer = sum(h * w for h, w in self.kv_row_shapes)
         return self.num_kv_layers * per_layer * bits // 8
 
+    def window_bytes_per_slot(self, max_len: int, bits: int = 16) -> int:
+        """Bytes a slot of ``max_len`` positions keeps in the window
+        store: ``min(sliding_window, max_len)`` rows of every sliding
+        layer, whatever its length. 0 without ``window_rows``."""
+        per_layer = sum(h * w for h, w in self.kv_row_shapes)
+        rows = min(self.sliding_window, max_len)
+        return self.num_window_layers * rows * per_layer * bits // 8
+
     def state_bytes_per_slot(self, bits: int = 16) -> int:
         """Bytes a slot keeps whatever its length: a state-space layer's
         recurrent state (float32, as the family's serving notes ask) and
@@ -358,7 +412,7 @@ class ModelConfig:
 # tests' small files are.
 FAMILIES: Tuple[str, ...] = (
     "Llama", "Mistral", "Mixtral", "Qwen2", "Qwen3", "Gemma", "GptOss",
-    "Deepseek", "NemotronH",
+    "Deepseek", "NemotronH", "Cohere2Moe",
     # multimodal wrappers whose text stack is one of the above
     "Llava", "VLForConditionalGeneration",
 )
@@ -441,6 +495,92 @@ def _nemotron_h_config(cfg: Dict[str, Any], name: str) -> ModelConfig:
     ).validate()
 
 
+def _cohere2_moe_config(cfg: Dict[str, Any], name: str) -> ModelConfig:
+    """Command A+ (``model_type: cohere2_moe``): a parallel block behind
+    one mean-centred LayerNorm; window and full attention layers by
+    ``layer_types``, the window layers rotated in interleaved pairs
+    (``rope_gptj``) and their rows kept at window size, the full layers
+    without positional embedding; sigmoid scores, the ``num_experts_per_
+    tok`` largest chosen without groups or bias and normalised;
+    ``num_shared_experts`` shared experts of the routed experts' width,
+    averaged; the embedding tied to the head under ``logit_scale``.
+
+    One chip's share of the experts is ``num_experts`` (how many are
+    held here) beside ``experts_held: {"of", "first"}``, as the
+    Nemotron-H files state it and for its reason (a reader from before
+    this family fails on the key at once)."""
+    def refuse(key, want):
+        if cfg.get(key, want) != want:
+            raise ValueError(
+                f"{key} {cfg[key]!r}: a cohere2_moe stack is served with "
+                f"{want!r} only"
+            )
+
+    refuse("position_embedding_type", "rope_gptj")
+    refuse("expert_selection_fn", "sigmoid")
+    refuse("shared_expert_combination_strategy", "average")
+    refuse("use_parallel_block", True)
+    refuse("use_gated_activation", True)
+    refuse("use_qk_norm", False)
+    refuse("attention_bias", False)
+    refuse("rotary_pct", 1)
+    refuse("hidden_act", "silu")
+    if int(cfg.get("first_k_dense_replace") or 0):
+        raise ValueError(
+            "first_k_dense_replace: a dense prefix of a cohere2_moe stack "
+            "(prefix_dense_*) is not read"
+        )
+    # a file cut in depth keeps the published list whole: the stack is
+    # its first num_hidden_layers entries
+    layer_types = (cfg.get("layer_types") or [])[:cfg["num_hidden_layers"]]
+    if len(layer_types) != cfg["num_hidden_layers"]:
+        raise ValueError(
+            f"layer_types has {len(layer_types)} layers, "
+            f"num_hidden_layers says {cfg['num_hidden_layers']}"
+        )
+    hidden, heads = cfg["hidden_size"], cfg["num_attention_heads"]
+    share = cfg.get("experts_held") or {}
+    held = int(cfg["num_experts"])
+    width = int(cfg["intermediate_size"])
+    shared = int(cfg.get("num_shared_experts") or 0)
+    return ModelConfig(
+        name=name,
+        vocab_size=cfg["vocab_size"],
+        hidden_size=hidden,
+        intermediate_size=width,
+        num_layers=cfg["num_hidden_layers"],
+        num_heads=heads,
+        num_kv_heads=cfg.get("num_key_value_heads", heads),
+        head_dim=cfg.get("head_dim") or hidden // heads,
+        rope_theta=float(
+            (cfg.get("rope_parameters") or {}).get("rope_theta")
+            or cfg.get("rope_theta", 10000.0)
+        ),
+        rms_norm_eps=cfg.get("layer_norm_eps", 1e-5),
+        tie_word_embeddings=cfg.get("tie_word_embeddings", True),
+        max_position_embeddings=cfg.get("max_position_embeddings", 8192),
+        sliding_window=int(cfg["sliding_window"]),
+        layer_sliding=tuple(t == "sliding_attention" for t in layer_types),
+        num_experts=int(share["of"]) if share else held,
+        experts_held=held if share else 0,
+        first_held_expert=int(share.get("first", 0)),
+        num_experts_per_tok=cfg["num_experts_per_tok"],
+        moe_intermediate_size=width,
+        norm_topk_prob=cfg.get("norm_topk_prob", True),
+        moe_scoring="sigmoid",
+        n_shared_experts=shared,
+        shared_expert_intermediate_size=shared * width,
+        shared_expert_average=shared > 1,
+        router_correction_bias=False,
+        parallel_block=True,
+        layer_norm=True,
+        rope_interleaved=True,
+        nope_full_layers=True,
+        window_rows=True,
+        logit_scale=float(cfg.get("logit_scale", 1.0)),
+    ).validate()
+
+
 def config_from_hf(cfg: Dict[str, Any], name: str = "custom") -> ModelConfig:
     """Build a ModelConfig from an HF ``config.json`` dict of one of
     :data:`FAMILIES` (the reference's selectors introspect the same
@@ -456,6 +596,8 @@ def config_from_hf(cfg: Dict[str, Any], name: str = "custom") -> ModelConfig:
         )
     if "NemotronH" in arch or cfg.get("model_type") == "nemotron_h":
         return _nemotron_h_config(cfg, name)
+    if "Cohere2Moe" in arch or cfg.get("model_type") == "cohere2_moe":
+        return _cohere2_moe_config(cfg, name)
     hidden = cfg["hidden_size"]
     heads = cfg["num_attention_heads"]
     head_dim = cfg.get("head_dim") or hidden // heads

@@ -98,8 +98,11 @@ def _embed_lookup(embed, tokens: jax.Array, dtype) -> jax.Array:
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class KVCache:
-    """Slot-based KV cache: ``k, v`` are ``[L, B, S_max, heads, width]``,
-    what a position holds being the configuration's to say
+    """Slot-based KV cache: ``k, v`` are ``[L, B, S_max, heads, width]``
+    (``L``: the layers that keep a row for every position,
+    ``cfg.num_kv_layers``; a stack with a window store keeps its sliding
+    layers' rows in ``wk, wv`` below), what a position holds being the
+    configuration's to say
     (``ModelConfig.kv_row_shapes``): each kv head's key and value for
     GQA; for MLA the shared latent ``c_kv`` (after its norm) in ``k`` and
     the shared rope key (after its rotation) in ``v``, one head each and
@@ -140,6 +143,21 @@ class KVCache:
     # ``v`` then have the attention layers only (``cfg.num_kv_layers``).
     ssm: Optional[jax.Array] = None
     conv: Optional[jax.Array] = None
+    # the window store of a stack that keeps its sliding layers' rows at
+    # window size (``cfg.window_rows``), None for every other model:
+    # ``wk, wv [L_sliding, B, W, heads, width]``, ``W =
+    # min(sliding_window, S_max)`` rows a slot, a ring: position ``p``
+    # lies in row ``p mod W``, so once a slot is longer than the window
+    # a row holds the newest position of its residue and the ring the
+    # last ``W`` positions, which is all a sliding layer attends. Keys
+    # are stored after their rotation, so the order of a ring's rows
+    # means nothing to the softmax. ``k, v`` then hold the full layers
+    # only (``cfg.num_kv_layers``). A span of positions cut out of a
+    # slot (a prefix block, a spilled or transferred run, a rolled-back
+    # draft, a chunk to go on from) has lost the sliding rows the ring
+    # has overwritten: the engine refuses those for such a model.
+    wk: Optional[jax.Array] = None
+    wv: Optional[jax.Array] = None
 
     @property
     def max_len(self) -> int:
@@ -169,6 +187,15 @@ class KVCache:
                     (Lm, batch, (cfg.conv_kernel - 1) * cfg.mamba_conv_dim),
                     dtype,
                 ),
+            )
+        if cfg.window_rows:
+            ring = (
+                cfg.num_window_layers, batch,
+                min(cfg.sliding_window, max_len),
+            )
+            state = dict(
+                wk=jnp.zeros(ring + k_row, dtype),
+                wv=jnp.zeros(ring + v_row, dtype),
             )
         return KVCache(
             k=jnp.zeros(lead + k_row, dtype), v=jnp.zeros(lead + v_row, dtype),
@@ -348,6 +375,9 @@ def init_params(
             "wo": w(next(keys), L, cfg.q_dim, d),
             "mlp_norm": jnp.ones((L, d), dtype),
         }
+    if cfg.parallel_block:
+        # one norm a layer: attention and MLP both read attn_norm's
+        del layers["mlp_norm"]
     if cfg.qkv_bias:
         layers["bq"] = jnp.zeros((L, cfg.q_dim), dtype)
         layers["bk"] = jnp.zeros((L, cfg.kv_dim), dtype)
@@ -383,7 +413,10 @@ def init_params(
             layers["ws_down"] = w(next(keys), L, fs, d)
             if cfg.shared_expert_gated:
                 layers["shared_gate"] = w(next(keys), L, d, 1)
-        if cfg.moe_scoring in ("sigmoid", "softmax_topk"):
+        if (
+            cfg.moe_scoring in ("sigmoid", "softmax_topk")
+            and cfg.router_correction_bias
+        ):
             # DeepSeek-V3 correction bias / GPT-OSS affine router
             layers["router_bias"] = jnp.zeros((L, E), jnp.float32)
         if cfg.moe_bias:
@@ -423,6 +456,15 @@ def rms_norm(
         # multiplied in fp32 before the downcast
         return (n * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
     return n.astype(x.dtype) * w
+
+
+def layer_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
+    """Mean-centred LayerNorm without bias (Cohere): ``(x - mean) /
+    sqrt(var + eps) * w``, float32 inside."""
+    xf = x.astype(jnp.float32)
+    xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
+    return (xc * lax.rsqrt(var + eps)).astype(x.dtype) * w
 
 
 def _inv_freq(theta: float, head_dim: int) -> jax.Array:
@@ -1013,6 +1055,12 @@ def _moe_mlp(
             shared_out = shared_out * jax.nn.sigmoid(
                 _mm("btd,dg->btg", x, gate_w)
             )
+        if cfg.shared_expert_average:
+            # the n shared experts are the one wide MLP's columns side
+            # by side, so its output is their sum: the mean is 1/n of it
+            shared_out = shared_out * jnp.asarray(
+                1.0 / cfg.n_shared_experts, shared_out.dtype
+            )
         out = out + shared_out
     extras = ()
     if count_held:
@@ -1038,12 +1086,19 @@ def _moe_mlp(
 
 
 def needs_xla_attention(cfg: ModelConfig) -> bool:
-    """True for a model whose scores only the XLA einsum path computes:
-    the blocked kernels (pallas flash, ring) know a causal mask and
-    nothing else, so a sliding window, a logit softcap or attention
-    sinks rule them out."""
+    """True for a model whose scores only the XLA einsum path computes.
+    The blocked kernels know a causal mask and a band under it (the
+    flash kernel's ``window``; a decode step over a ring of window rows
+    is the decode kernel over a short cache), so a plain window no
+    longer counts where its rows are kept at window size
+    (``cfg.window_rows``). A logit softcap or attention sinks still
+    rule the kernels out, and so does a window that is a mask over
+    ``S_max`` rows a slot (the Gemma / GPT-OSS / Mistral files): the
+    decode kernel walks a slot from its first row, and the ring kernel
+    (``sp``) knows no band."""
     return bool(
-        cfg.sliding_window or cfg.attn_logit_softcap or cfg.attn_sinks
+        (cfg.sliding_window and not cfg.window_rows)
+        or cfg.attn_logit_softcap or cfg.attn_sinks
     )
 
 
@@ -1118,10 +1173,16 @@ def decode_attention_impl(
     elif needs_xla_attention(cfg):
         block = None
     else:
+        itemsize = 2 if cfg.dtype == "bfloat16" else 4
         block = gqa_block_positions(
-            max_len, cfg.num_kv_heads, cfg.head_dim,
-            2 if cfg.dtype == "bfloat16" else 4,
+            max_len, cfg.num_kv_heads, cfg.head_dim, itemsize
         )
+        if cfg.window_rows and gqa_block_positions(
+            min(cfg.sliding_window, max_len), cfg.num_kv_heads,
+            cfg.head_dim, itemsize,
+        ) is None:
+            # the ring of a sliding layer is walked by the same kernel
+            block = None
     one_chip = platform == "tpu" and (mesh is None or mesh.size == 1)
     return "kernel" if one_chip and rows == 1 and block is not None else "xla"
 
@@ -1150,7 +1211,14 @@ def forward(
     A model of one mixer a layer (``cfg.layer_kinds``, the Nemotron-H
     hybrid) runs in ``models/hybrid.py forward_hybrid``, which takes
     these arguments and says what ``true_len`` and ``ssm_impl`` are;
-    every other model takes no notice of those two.
+    no other model takes notice of ``ssm_impl``, and of ``true_len``
+    only a stack that keeps its sliding layers' rows at window size
+    (``cfg.window_rows``; ``window_attention`` below): int32 ``[B]``,
+    how many of a prefill's ``T`` positions are real, so that the ring
+    it leaves holds the last real rows and nothing of the padding (None:
+    every position counts). Such a stack takes a cache in two forms
+    only, a prefill from position 0 into a cache of the step's length
+    and one row a slot, and neither a mesh nor ``"ring"``.
 
     Without ``cache``: plain causal forward (training / scoring path).
     With ``cache``: the cache rides the scan over the layers as its carry;
@@ -1163,10 +1231,12 @@ def forward(
     ``attn_impl`` selects the prefill attention kernel: ``"xla"`` (einsum
     scores, fine for short prompts), ``"flash"`` (pallas blocked
     online-softmax — no [T, S] score tensor; required for long-context
-    prefill), or ``"flash_interpret"`` (same kernel in interpret mode, for
+    prefill; with the band of a window whose rows are kept at window
+    size), or ``"flash_interpret"`` (same kernel in interpret mode, for
     hermetic CPU tests). Flash applies to the prefill-from-zero cache path
-    (T > 1, cache sized to the bucket); decode and the cacheless paths
-    always use XLA attention. A model the kernel refuses
+    (T > 1, cache sized to the bucket); a step over cached rows attends
+    as :func:`decode_attention_impl` says (below), the cacheless paths
+    through XLA. A model the kernel refuses
     (:func:`needs_xla_attention`) raises: the caller chooses
     (``engine/runner.py prefill_attention``), this function never falls
     back in silence.
@@ -1263,6 +1333,11 @@ def forward(
             from gpustack_tpu.ops.decode_attention import gqa_walk
 
             walk = gqa_walk(lengths, cache.k)
+            if cfg.window_rows:
+                # a sliding layer walks its ring's live rows
+                walk_w = gqa_walk(
+                    jnp.minimum(lengths, cache.wk.shape[2]), cache.wk
+                )
     dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
     x = _embed_lookup(params["embed"], tokens, dtype)
     if embeds_override is not None:
@@ -1334,9 +1409,24 @@ def forward(
     use_ring = attn_impl == "ring" and cache is not None
     if (use_flash or use_ring) and needs_xla_attention(cfg):
         raise ValueError(
-            f"attn_impl={attn_impl!r} needs no sliding window, no "
-            "attention softcapping and no attention sinks"
+            f"attn_impl={attn_impl!r} needs no attention softcapping, no "
+            "attention sinks and no sliding window but one whose rows "
+            "are kept at window size"
         )
+    if cfg.window_rows and cache is not None:
+        if use_ring or (mesh is not None and mesh.size > 1):
+            raise ValueError(
+                f"{cfg.name}: a window store is not sharded; serve it on "
+                "one device"
+            )
+        if T > 1 and cache.max_len != T:
+            raise ValueError(
+                f"{cfg.name}: {T} rows a slot over a cache of "
+                f"{cache.max_len}: a stack that keeps its sliding layers' "
+                "rows at window size takes a prefill from position 0 into "
+                "a cache of its own length, or one row a slot (a chunk, a "
+                "prefix or a draft would need rows the ring has dropped)"
+            )
     if use_ring and mesh is None:
         raise ValueError("attn_impl='ring' needs a mesh")
     if use_ring and cfg.is_mla:
@@ -1514,21 +1604,145 @@ def forward(
             attn = jnp.einsum("bthr,rhv->bthv", u, wv.reshape(rank, H, vd))
         return attn.reshape(B, T, H * vd), carried
 
-    def block(carry, scanned, moe_layer: bool):
+    period = cfg.window_period if cfg.window_rows else ()
+
+    def window_attention(h, lp, carried, layer, kind):
+        """One GQA layer of a stack that keeps its sliding layers' rows
+        at window size (``cfg.window_rows``): ``(attn [B, T, H * hd],
+        carried)``. ``kind`` (static) is ``(sliding, at)``: whether the
+        layer is a sliding one, and how many of its kind come before it
+        in its period, which with the period's number says where its
+        rows lie in its store.
+
+        A sliding layer sees keys ``0 <= i - j < sliding_window``; its
+        step's rows go to the ring at ``position mod W`` and a decode
+        step attends the ring's live rows, ``min(length, W)`` of them. A
+        full layer is a causal layer over ``k, v``. A prefill is from
+        position 0 and its own rows are every key, so it attends over
+        them as they come (a band in the flash kernel) and leaves each
+        sliding layer's last ``min(true_len, W)`` rows in the ring: a
+        row takes the newest real position of its residue, the padding
+        of a bucket writes nothing."""
+        sliding, at = kind
+        q, k, v = qkv_projections(h, lp, decode=cache is not None and T == 1)
+        q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)
+        k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+        v = v.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+        if sliding or not cfg.nope_full_layers:
+            rotate = (
+                apply_rope_interleaved if cfg.rope_interleaved else apply_rope
+            )
+            q, k = rotate(q, sin, cos), rotate(k, sin, cos)
+        grouped = q.reshape(
+            B, T, cfg.num_kv_heads, cfg.group_size, cfg.head_dim
+        )
+        mask_l = mask_slide if sliding else mask_full
+        if cache is None:
+            return _attend(grouped, k, v, mask_l, scale), carried
+        n_kind = sum(s == sliding for s in period)
+        store = (layer // len(period)) * n_kind + at
+        buf_k, buf_v = (
+            (carried.wk, carried.wv) if sliding else (carried.k, carried.v)
+        )
+        rows = buf_k.shape[2]
+        if T == 1:
+            start = positions[:, 0] % rows if sliding else positions[:, 0]
+            buf_k = _write_rows(buf_k, k, store, start)
+            buf_v = _write_rows(buf_v, v, store, start)
+            if decode_attn_impl != "xla":
+                from gpustack_tpu.ops.decode_attention import (
+                    gqa_decode_attention,
+                )
+
+                attn = gqa_decode_attention(
+                    q[:, 0], buf_k, buf_v, store,
+                    walk_w if sliding else walk, scale,
+                    interpret=decode_attn_impl == "kernel_interpret",
+                    **(
+                        {"name": "gqa_window_decode_attention"}
+                        if sliding else {}
+                    ),
+                )[:, None]
+            else:
+                all_k, all_v = (
+                    lax.dynamic_index_in_dim(buf, store, 0, keepdims=False)
+                    for buf in (buf_k, buf_v)
+                )
+                # a ring's live rows are its first min(length, W)
+                live_rows = mask_full if not sliding else (
+                    jnp.arange(rows, dtype=jnp.int32)[None, None, :]
+                    < jnp.minimum(positions + 1, rows)[:, :, None]
+                )
+                attn = _attend(grouped, all_k, all_v, live_rows, scale)
+        else:
+            if sliding:
+                # ring row r takes position r + W * ((n - 1 - r) // W),
+                # the newest real one of its residue, where r < n
+                n = (
+                    true_len if true_len is not None
+                    else jnp.full((B,), T, jnp.int32)
+                )[:, None]
+                r = jnp.arange(rows, dtype=jnp.int32)[None, :]
+                src = jnp.clip(r + rows * ((n - 1 - r) // rows), 0, T - 1)
+                kept = [
+                    jnp.where(
+                        (r < n)[:, :, None, None],
+                        jnp.take_along_axis(
+                            new, src[:, :, None, None], axis=1
+                        ),
+                        jnp.zeros((), new.dtype),
+                    ) for new in (k, v)
+                ]
+            else:
+                kept = [k, v]
+            buf_k, buf_v = (
+                lax.dynamic_update_index_in_dim(buf, new, store, 0)
+                for buf, new in zip((buf_k, buf_v), kept)
+            )
+            if use_flash:
+                from gpustack_tpu.ops.flash_attention import (
+                    flash_attention_prefill,
+                )
+
+                band = cfg.sliding_window if sliding else 0
+                attn = flash_attention_prefill(
+                    q, k, v, scale,
+                    interpret=attn_impl == "flash_interpret",
+                    q_offset=positions[0, 0],
+                    window=band if band < T else 0,
+                )
+            else:
+                attn = _attend(grouped, k, v, mask_l, scale)
+        carried = dataclasses.replace(
+            carried, **(
+                dict(wk=buf_k, wv=buf_v) if sliding
+                else dict(k=buf_k, v=buf_v)
+            )
+        )
+        return attn, carried
+
+    def norm(x_, w):
+        if cfg.layer_norm:
+            return layer_norm(x_, w, cfg.rms_norm_eps)
+        return rms_norm(x_, w, cfg.rms_norm_eps, cfg.norm_delta_gain)
+
+    def block(carry, scanned, moe_layer: bool, kind=None):
         x_in, carried, layer, *counts = carry
         lp, slide_flag = scanned
         lp = {**lp, **stacked}
-        if hetero:
+        if kind is not None:
+            mask_l = sin_b = cos_b = None   # window_attention's own
+        elif hetero:
             mask_l = jnp.where(slide_flag, mask_slide, mask_full)
             sin_b = jnp.where(slide_flag, sin_loc, sin)
             cos_b = jnp.where(slide_flag, cos_loc, cos)
         else:
             mask_l, sin_b, cos_b = mask, sin, cos
-        h = rms_norm(
-            x_in, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_delta_gain
-        )
+        h = norm(x_in, lp["attn_norm"])
         if cfg.is_mla:
             attn, carried = mla_attention(h, lp, carried, layer, mask_l)
+        elif kind is not None:
+            attn, carried = window_attention(h, lp, carried, layer, kind)
         else:
             q, k, v = qkv_projections(
                 h, lp, decode=cache is not None and T == 1
@@ -1653,11 +1867,13 @@ def forward(
                 attn_out, lp["post_attn_norm"], cfg.rms_norm_eps,
                 cfg.norm_delta_gain,
             )
-        x_mid = x_in + attn_out
-
-        h2 = rms_norm(
-            x_mid, lp["mlp_norm"], cfg.rms_norm_eps, cfg.norm_delta_gain
-        )
+        if cfg.parallel_block:
+            # attention and MLP both read the one norm's output, and
+            # both are added to the stream
+            x_mid, h2 = x_in, h
+        else:
+            x_mid = x_in + attn_out
+            h2 = norm(x_mid, lp["mlp_norm"])
         routing = None
         if moe_layer:
             mlp = _moe_mlp(
@@ -1696,7 +1912,8 @@ def forward(
                 mlp, lp["post_mlp_norm"], cfg.rms_norm_eps,
                 cfg.norm_delta_gain,
             )
-        return (x_mid + mlp, carried, layer + 1, *counts), routing
+        x_out = x_mid + attn_out + mlp if cfg.parallel_block else x_mid + mlp
+        return (x_out, carried, layer + 1, *counts), routing
 
     # DeepSeek ships heterogeneous stacks: the first first_k_dense
     # layers use a dense MLP, the rest MoE — structurally different
@@ -1720,10 +1937,52 @@ def forward(
             partial(block, moe_layer=False),
             carry, (params["dense_layers"], slide_flags[:kd]),
         )
-    (x, new_cache, _, *extras), routing = lax.scan(
-        partial(block, moe_layer=cfg.is_moe),
-        carry, (layers, slide_flags[kd:]),
-    )
+    if period:
+        # Window and full layers in one stack: a scan over the periods,
+        # a period's layers written out in its body, each reading its
+        # own store. (A ``lax.switch`` on the kind inside a scan over
+        # the layers copies the stacked cache whole through the
+        # conditional every step: models/hybrid.py.)
+        per = len(period)
+        # (sliding, how many of its kind come before it in the period)
+        kinds = [(s, period[:j].count(s)) for j, s in enumerate(period)]
+
+        def one_period(carry, _):
+            routings = []
+            for kind in kinds:
+                # the layer's leaves read where they lie in the stacks,
+                # by its index: as a scan's slices of [periods, layers a
+                # period, ...] a period's four matrices of every kind
+                # are copied out before the body reads them (1.3 GB of
+                # temporaries at this model's widths, compiled for a
+                # described v5e)
+                lp = jax.tree.map(
+                    lambda a: lax.dynamic_index_in_dim(
+                        a, carry[2], 0, keepdims=False
+                    ),
+                    layers,
+                )
+                carry, routing = block(
+                    carry, (lp, None), moe_layer=cfg.is_moe, kind=kind,
+                )
+                routings.append(routing)
+            if not routing_out:
+                return carry, None
+            return carry, tuple(jnp.stack(r) for r in zip(*routings))
+
+        (x, new_cache, _, *extras), routing = lax.scan(
+            one_period, carry, None, length=cfg.num_layers // per,
+        )
+        if routing_out:
+            # [periods, layers a period, ...] -> [L, ...]
+            routing = tuple(
+                r.reshape(-1, *r.shape[2:]) for r in routing
+            )
+    else:
+        (x, new_cache, _, *extras), routing = lax.scan(
+            partial(block, moe_layer=cfg.is_moe),
+            carry, (layers, slide_flags[kd:]),
+        )
     if routing_out:
         extras = [*extras, routing]
 
@@ -1731,9 +1990,7 @@ def forward(
         new_cache = KVCache(
             k=new_cache.k[:, :, :, None, :], v=new_cache.v[:, :, :, None, :]
         )
-    x = rms_norm(
-        x, params["final_norm"], cfg.rms_norm_eps, cfg.norm_delta_gain
-    )
+    x = norm(x, params["final_norm"])
     if return_hidden:
         # embeddings path: final normalized hidden states, no LM head
         return (x.astype(jnp.float32), new_cache, *extras)
@@ -1742,6 +1999,8 @@ def forward(
     else:
         logits = _mm("btd,dv->btv", x, params["lm_head"])
     logits = logits.astype(jnp.float32)
+    if cfg.logit_scale != 1.0:
+        logits = logits * cfg.logit_scale
     if cfg.final_logit_softcap:
         cap = cfg.final_logit_softcap
         logits = cap * jnp.tanh(logits / cap)

@@ -85,6 +85,13 @@ Record vocabulary (per step):
   the step sent through the chunked scan (a prefill). Absent for any
   other model.
 
+- ``window_rows``, ``full_rows`` — for a stack that keeps its sliding
+  layers' rows at window size (``/healthz`` ``cache.window_bytes``):
+  the cached rows the step's sliding layers and its full layers
+  attended, over slots, positions and layers of the kind (a decode step:
+  ``min(length, window)`` and ``length`` a slot a layer; a prefill the
+  band's and the triangle's). Absent for any other model.
+
 Cumulative (not per-record): ``idle_wait_s_total`` — seconds the
 scheduler parked on its wakeup condition instead of busy-polling (the
 old 2 ms sleep loop, measured as saved spin); ``rollback_tokens_total``
@@ -304,6 +311,7 @@ GUARDED_BY = {
     "_moe_decode_read_total": "_mu",
     "_moe_decode_held_total": "_mu",
     "_ssm_tokens_total": "_mu",
+    "_attn_rows_total": "_mu",
     "_spec_proposed_total": "_mu",
     "_spec_accepted_total": "_mu",
     "_last_slots_used": "_mu",
@@ -376,6 +384,9 @@ class FlightRecorder:
         # tokens through the state-space layers (a hybrid), by the
         # program that took them; None until such a step is recorded
         self._ssm_tokens_total: Optional[Dict[str, int]] = None
+        # cached rows attended by kind of layer (a stack with a window
+        # store); None until such a step is recorded
+        self._attn_rows_total: Optional[Dict[str, int]] = None
         self._spec_proposed_total = 0
         self._spec_accepted_total = 0
         self._last_slots_used = 0
@@ -427,6 +438,7 @@ class FlightRecorder:
         moe_read: int = 0,
         moe_held: int = 0,         # 0: the step fetched no experts' count
         ssm: Optional[Sequence[int]] = None,   # (state_slots, ssm_tokens)
+        attn_rows: Optional[Sequence[int]] = None,  # (window_rows, full_rows)
     ) -> Optional[Sequence[List[Any]]]:
         """Returns the step's ``programs`` (None in a steady step)."""
         t0 = time.perf_counter()
@@ -446,6 +458,7 @@ class FlightRecorder:
             kv_reused_total, host_overlap_s, phases_s, admitted,
             first_tokens, traced, compiled, moe_dispatch, attn,
             kv_live, kv_allocated, moe_read, moe_held, programs, ssm,
+            attn_rows,
         )
         with self._mu:
             if self._unfolded >= self._fold_at:
@@ -471,7 +484,7 @@ class FlightRecorder:
              spec_proposed, spec_accepted, kv_blocks, _kv_reused,
              host_overlap_s, _phases, _admitted, _first, _traced,
              _compiled, moe_dispatch, _attn, kv_live, kv_allocated,
-             moe_read, moe_held, _programs, ssm) = row
+             moe_read, moe_held, _programs, ssm, attn_rows) = row
             h = self._hist.get(mode)
             if h is None:
                 h = self._hist[mode] = [
@@ -500,6 +513,14 @@ class FlightRecorder:
                     }
                 totals["decode"] += ssm[0]
                 totals["prefill"] += ssm[1]
+            if attn_rows is not None:
+                totals = self._attn_rows_total
+                if totals is None:
+                    totals = self._attn_rows_total = {
+                        "sliding": 0, "full": 0,
+                    }
+                totals["sliding"] += attn_rows[0]
+                totals["full"] += attn_rows[1]
             self._spec_proposed_total += spec_proposed
             self._spec_accepted_total += spec_accepted
             self._host_overlap_s_total += host_overlap_s
@@ -543,7 +564,7 @@ class FlightRecorder:
          spec_proposed, spec_accepted, kv_blocks, kv_reused_total,
          host_overlap_s, phases_s, admitted, first_tokens, traced,
          compiled, moe_dispatch, attn, kv_live, kv_allocated,
-         moe_read, moe_held, programs, ssm) = row
+         moe_read, moe_held, programs, ssm, attn_rows) = row
         entry = {
             "ts": ts,
             "dur_ms": round(dur_s * 1e3, 4),
@@ -585,6 +606,8 @@ class FlightRecorder:
             entry["programs"] = programs
         if ssm is not None:
             entry["state_slots"], entry["ssm_tokens"] = ssm
+        if attn_rows is not None:
+            entry["window_rows"], entry["full_rows"] = attn_rows
         return entry
 
     # ---- read side -----------------------------------------------------
@@ -659,6 +682,7 @@ class FlightRecorder:
             moe_read = self._moe_decode_read_total
             moe_held = self._moe_decode_held_total
             ssm_tokens = dict(self._ssm_tokens_total or {})
+            attn_rows = dict(self._attn_rows_total or {})
             proposed = self._spec_proposed_total
             accepted = self._spec_accepted_total
             hist = {
@@ -753,6 +777,12 @@ class FlightRecorder:
                 f'gpustack_engine_ssm_tokens_total{{kind="{kind}"}} {n}'
                 for kind, n in sorted(ssm_tokens.items())
             ]
+        if attn_rows:   # a stack with a window store
+            lines.append(decl("gpustack_engine_attn_rows_total"))
+            lines += [
+                f'gpustack_engine_attn_rows_total{{layer="{kind}"}} {n}'
+                for kind, n in sorted(attn_rows.items())
+            ]
         if moe_prompt:   # a model with experts, once it has prefilled
             lines.append(decl("gpustack_engine_moe_prompt_tokens_total"))
             lines += [
@@ -779,6 +809,7 @@ for _name in (
     "prompt_tokens_total", "moe_prompt_tokens_total",
     "decode_kv_live_total", "decode_kv_allocated_total",
     "moe_decode_read_total", "moe_decode_held_total", "ssm_tokens_total",
+    "attn_rows_total",
     "spec_proposed_total", "spec_accepted_total", "host_overlap_s_total",
 ):
     setattr(FlightRecorder, _name, _folded(_name))

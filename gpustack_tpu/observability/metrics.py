@@ -93,10 +93,15 @@ METRIC_FAMILIES = {
     # one layer takes (1,152 for an MLA latent of 512 + 64 in bf16)
     "gpustack_engine_kv_cache_bytes": "gauge",
     "gpustack_engine_kv_cache_bytes_per_token": "gauge",
-    # what the slots keep on the device, by kind (label kind=kv|state):
-    # rows a position, and the recurrent state of a model with
-    # state-space layers (0 for any other)
+    # what the slots keep on the device, by kind (label
+    # kind=kv|state|window): rows a position, the recurrent state of a
+    # model with state-space layers, and the sliding layers' rows of a
+    # stack that keeps them at window size (each 0 for any other)
     "gpustack_engine_cache_bytes": "gauge",
+    # a stack with a window store: cached rows its layers attended, by
+    # kind of layer (label layer=sliding|full), over slots, positions
+    # and layers; absent for any other model
+    "gpustack_engine_attn_rows_total": "counter",
     # a model with state-space layers: tokens through them, by the
     # program that took them (label kind=prefill|decode: the chunked
     # scan over a prompt, the one-step update of a live slot); absent

@@ -144,7 +144,8 @@ def cached_block(b, j, len_ref, slot_ref, block_ref, layer_ref):
 
 
 def gqa_walk(lengths: jax.Array, k_cache: jax.Array) -> Walk:
-    """:func:`slot_walk` over a GQA cache ``[L, B, S, Hkv, hd]``."""
+    """:func:`slot_walk` over a GQA cache ``[L, B, S, Hkv, hd]`` (a ring
+    of window rows is such a cache, its lengths the live rows')."""
     S, Hkv, hd = k_cache.shape[2:]
     block_s = gqa_block_positions(S, Hkv, hd, k_cache.dtype.itemsize)
     if block_s is None:
@@ -216,11 +217,14 @@ def gqa_decode_attention(
     scale: float,
     *,
     interpret: bool = False,
+    name: str = "gqa_decode_attention",
 ) -> jax.Array:
     """``softmax(q . k * scale) @ v`` for every query head over positions
     ``0 .. lengths[b] - 1`` of slot ``b``'s rows of its kv head in layer
     ``layer``: ``[B, Hq * hd]`` in ``q``'s dtype. A slot of length 0
-    gives zeros and reads none of its rows."""
+    gives zeros and reads none of its rows. ``name``: the call's name in
+    the compiled program and the profiler's trace (a sliding layer's
+    walk over its ring of window rows goes under one of its own)."""
     B, Hq, hd = q.shape
     L, _, S, Hkv, _ = k_cache.shape
     walk = lengths if isinstance(lengths, Walk) else gqa_walk(lengths, k_cache)
@@ -268,7 +272,7 @@ def gqa_decode_attention(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-        name="gqa_decode_attention",
+        name=name,
         interpret=interpret,
     )(
         walk.lengths, walk.slot, walk.block,

@@ -49,6 +49,15 @@ Causal work is bounded three ways:
   sub-block the mask is all true and ``where(mask, s, _NEG)`` the
   identity.
 
+With a **band** (``window``, static; 0: none) query ``i`` sees keys
+``i - window < j <= i``, and the same three hold at the band's lower
+edge: a pair wholly below it is never computed, a k-block wholly below a
+q-block's band is never fetched (the index maps clamp from below as they
+do from above), and the mask runs on the sub-blocks that straddle either
+edge. A stack that mixes window and full layers calls the kernel with
+and without one (``models/transformer.py window_attention``); without
+one the program is the kernel's as it was.
+
 The [T, S] score matrix never exists in HBM and VMEM use is
 O(G * block_q x 128) regardless of sequence length, so a 32k prefill
 fits as easily as a 1k one (the XLA path materializes a [B, H, T, S]
@@ -166,7 +175,7 @@ def _across(x, d: int):
 
 def _flash_kernel(
     off_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-    *, scale: float, seq_k: int, tiles: Tiles,
+    *, scale: float, seq_k: int, tiles: Tiles, window: int = 0,
 ):
     """Grid point = one (batch, kv-head, q-block, k-block) tile: the
     ``block_q`` rows of the group's ``G`` query heads against ``block_k``
@@ -215,7 +224,10 @@ def _flash_kernel(
             k_idx = (first_sub_k + j) * SUB_K + lax.broadcasted_iota(
                 jnp.int32, s.shape, 1
             )
-            s = jnp.where((k_idx <= q_idx) & (k_idx < seq_k), s, _NEG)
+            seen = (k_idx <= q_idx) & (k_idx < seq_k)
+            if window:
+                seen = seen & (q_idx - k_idx < window)
+            s = jnp.where(seen, s, _NEG)
 
         m_prev, l_prev = m_ref[qs], l_ref[qs]             # [rows, 128]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -264,7 +276,20 @@ def _flash_kernel(
         n_clear = jnp.minimum((q_first + 1) // SUB_K, seq_k // SUB_K)
         clear = jnp.clip(n_clear - first_sub_k, 0, n_sub_k)
         seen = jnp.clip(n_seen - first_sub_k, 0, n_sub_k)
-        sweep(qs, q_first, 0, clear, masked=False, unroll=unroll)
+        if not window:
+            sweep(qs, q_first, 0, clear, masked=False, unroll=unroll)
+            sweep(qs, q_first, clear, seen, masked=True, unroll=1)
+            continue
+        # the band's lower edge: sub-blocks below lo_seen hold no key
+        # the first row sees (the other rows see less far back); from
+        # lo_clear on the last row sees a sub-block whole
+        lo_seen = jnp.maximum(q_first - window + 1, 0) // SUB_K
+        lo_clear = -(-jnp.maximum(q_first + sub_q - window, 0) // SUB_K)
+        first = jnp.clip(lo_seen - first_sub_k, 0, seen)
+        whole = jnp.clip(lo_clear - first_sub_k, first, seen)
+        clear = jnp.clip(clear, whole, seen)
+        sweep(qs, q_first, first, whole, masked=True, unroll=1)
+        sweep(qs, q_first, whole, clear, masked=False, unroll=unroll)
         sweep(qs, q_first, clear, seen, masked=True, unroll=1)
 
     @pl.when(kb == pl.num_programs(3) - 1)
@@ -282,7 +307,7 @@ def flash_call(
     vt: jax.Array,      # [B, Hkv, S_pad, dv]
     off: jax.Array,     # int32[1]: the position of q row 0
     *, scale: float, seq_k: int, interpret: bool = False,
-    _blocks: tuple[int, int] | None = None,
+    _blocks: tuple[int, int] | None = None, window: int = 0,
 ) -> jax.Array:
     """The ``pallas_call`` alone, on head-major operands whose rows are
     already padded to multiples of 128: what :func:`flash_attention_prefill`
@@ -312,11 +337,20 @@ def flash_call(
         # the last block that holds a key the q-block's last row sees: a
         # point past it names that block again and nothing is copied
         last = (off_ref[0] + (qb + 1) * block_q - 1) // block_k
-        return (b, h, jnp.minimum(kb, jnp.minimum(last, n_kb - 1)), 0)
+        at = jnp.minimum(kb, jnp.minimum(last, n_kb - 1))
+        if window:
+            # nor a block below the band of the q-block's first row: a
+            # point before it names the band's first block early
+            low = jnp.maximum(
+                off_ref[0] + qb * block_q - window + 1, 0
+            ) // block_k
+            at = jnp.maximum(at, jnp.minimum(low, n_kb - 1))
+        return (b, h, at, 0)
 
     return pl.pallas_call(
         functools.partial(
-            _flash_kernel, scale=scale, seq_k=seq_k, tiles=tiles
+            _flash_kernel, scale=scale, seq_k=seq_k, tiles=tiles,
+            **({"window": window} if window else {}),
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hq, T_pad, dv), qt.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -339,11 +373,17 @@ def flash_call(
                 "parallel", "parallel", "parallel", "arbitrary"
             ),
         ),
+        # a call with a band under a name of its own in the compiled
+        # program and the profiler's trace; without one the call is
+        # named after the jitted function, as it was
+        **({"name": "flash_attention_window"} if window else {}),
         interpret=interpret,
     )(off, qt, kt, vt)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("scale", "interpret", "window")
+)
 def flash_attention_prefill(
     q: jax.Array,       # [B, T, Hq, d]
     k: jax.Array,       # [B, S, Hkv, d]
@@ -351,6 +391,7 @@ def flash_attention_prefill(
     scale: float,
     interpret: bool = False,
     q_offset=0,
+    window: int = 0,
 ) -> jax.Array:
     """Causal GQA prefill attention (q positions q_offset..q_offset+T-1
     against k positions 0..S-1, with keys at index >= S masked via
@@ -358,8 +399,9 @@ def flash_attention_prefill(
     continuation: every batch row shares the one offset. Returns
     [B, T, Hq*dv]: the values may be narrower than the keys (latent
     attention decompresses to keys of 192 and values of 128), nothing is
-    padded to make them alike. T and S are padded to multiples of 128
-    internally; any sequence length fits (VMEM use is O(block)); the
+    padded to make them alike. ``window`` (static; 0: none) keeps a
+    query to the keys ``i - window < j <= i``. T and S are padded to
+    multiples of 128 internally; any sequence length fits (VMEM use is O(block)); the
     shapes choose the tiles (:func:`choose_tiles`)."""
     B, T, Hq, d = q.shape
     S, Hkv = k.shape[1], k.shape[2]
@@ -381,6 +423,7 @@ def flash_attention_prefill(
     out = flash_call(
         qt, kt, vt, jnp.asarray(q_offset, jnp.int32).reshape(1),
         scale=scale, seq_k=S, interpret=interpret,
+        **({"window": window} if window else {}),
     )
     out = jnp.transpose(out[:, :, :T, :], (0, 2, 1, 3))  # [B, T, Hq, dv]
     return out.reshape(B, T, Hq * v.shape[3])

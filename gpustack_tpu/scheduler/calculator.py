@@ -265,6 +265,12 @@ def evaluate_model(model: Model, role: str = "") -> ModelEvaluation:
     state_bytes = getattr(cfg, "state_bytes_per_slot", None)
     if state_bytes is not None:
         kv_bytes += state_bytes(kv_bits) * kv_slots
+    # a stack that keeps its sliding layers' rows at window size: those
+    # layers are not among kv_cache_bytes_per_token's, and a slot holds
+    # min(window, context) rows of each whatever its length
+    window_bytes = getattr(cfg, "window_bytes_per_slot", None)
+    if window_bytes is not None:
+        kv_bytes += window_bytes(model.max_seq_len, kv_bits) * kv_slots
     # activation + runtime overhead: prefill attention scratch dominates;
     # scale with seq len, floor at 256 MiB (audio configs use d_model)
     hidden = getattr(cfg, "hidden_size", 0) or cfg.d_model
@@ -364,9 +370,12 @@ def chips_for_claim(
 
     start = explicit_chips or 1
     chips = max(1, start)
-    if getattr(cfg, "layer_kinds", None) is not None:
-        # a model with state-space layers is served on one device
-        # (engine/runner.py): more chips than one hold nothing of it
+    if getattr(cfg, "layer_kinds", None) is not None or getattr(
+        cfg, "window_rows", False
+    ):
+        # a model with state-space layers, or with a window store, is
+        # served on one device (engine/runner.py): more chips than one
+        # hold nothing of it
         max_chips = min(max_chips, 1)
     while chips <= max_chips:
         if (

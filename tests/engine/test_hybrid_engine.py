@@ -58,7 +58,7 @@ def test_the_engine_serves_the_reference_s_tokens_and_reports_both_kinds(model):
     state = 3 * 3 * (4 * 8 * 16 * 4 + 3 * 96 * 4)    # float32 conv rows here
     assert health["cache"] == {
         "kv_bytes": 2 * 1 * 3 * 64 * 2 * 16 * 4, "state_bytes": state,
-        "state_dtype": "float32",
+        "state_dtype": "float32", "window_bytes": 0,
     }
     assert (health["ssm_scan"], health["ssm_update"]) == (
         "chunked_einsum", "xla"

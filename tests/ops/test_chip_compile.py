@@ -882,3 +882,181 @@ def test_flash_prefill_takes_two_kv_heads_of_sixteen_query_heads(one_chip):
         r"%flash_attention_prefill[\w.\-]* = bf16\[1,32,1024,128\]"
         r".* custom-call\(", compiled.as_text(),
     )
+
+
+# ---- Command A+: window and full layers in one stack ------------------------
+
+COMMAND_A_DIR = "perfbench/configs/command-a-plus-int8-ep8-l8"
+
+
+def _command_a(periods: int = 2):
+    """The benchmark's configuration at its published widths and its
+    share, ``periods`` periods of three sliding layers and a full one."""
+    import os
+
+    from gpustack_tpu.models.config import load_hf_config
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)
+    )))
+    cfg = load_hf_config(os.path.join(root, COMMAND_A_DIR))
+    return dataclasses.replace(
+        cfg, num_layers=4 * periods,
+        layer_sliding=cfg.layer_sliding[:4 * periods],
+    )
+
+
+def _no_score_tensor(text: str, T: int, S: int):
+    """No array of the program holds a query axis of ``T`` beside a key
+    axis of ``S``: the ``[B, H, T, S]`` scores of the einsum path."""
+    for dims in re.findall(r"(?:f32|bf16)\[([\d,]+)\]", text):
+        sizes = [int(d) for d in dims.split(",")]
+        both = sizes.count(T) >= 2 if T == S else T in sizes and S in sizes
+        assert not (both and len(sizes) >= 3 and T * S <= np.prod(sizes)), dims
+
+
+def test_a_window_stack_s_decode_step_walks_its_rings_in_place(one_chip):
+    """The decode program of the benchmark's Command A+ share as the
+    runner traces it on one TPU chip, 16 slots of 8,192: each sliding
+    layer's kernel walks its ring of 4,096 rows in the window store,
+    the full layer's its 8,192, both stores donated and aliased, every
+    layer's matrices read where they lie in the stacks (scanned as
+    ``[periods, 4, ...]`` slices they were copied out a period at a
+    time: 1.3 GB of temporaries), 128 query heads a block of 512
+    positions, and no score tensor over a slot's whole context."""
+    from gpustack_tpu.models import init_params
+    from gpustack_tpu.models.quant import quantize_params
+    from gpustack_tpu.models.transformer import (
+        KVCache,
+        decode_attention_impl,
+        forward,
+        moe_dispatch,
+        needs_xla_attention,
+    )
+    from gpustack_tpu.ops.decode_attention import gqa_block_positions
+
+    cfg = _command_a()
+    slots, S = 16, 8192
+    assert not needs_xla_attention(cfg)
+    assert decode_attention_impl(cfg, 1, S, "tpu", None) == "kernel"
+    assert decode_attention_impl(cfg, 4, S, "tpu", None) == "xla"
+    assert moe_dispatch(slots, cfg, "tpu", None, decode=True) == "touched"
+    assert gqa_block_positions(4096, 8, 128, 2) == 512
+    params = _shapes_on(
+        one_chip, lambda: quantize_params(init_params(cfg, jax.random.key(0)))
+    )
+    assert "mlp_norm" not in params["layers"]
+    cache = _shapes_on(one_chip, lambda: KVCache.create(cfg, slots, S))
+    assert cache.k.shape == (2, slots, S, 8, 128)
+    assert cache.wk.shape == (6, slots, 4096, 8, 128)
+    rows = cache.k.size + cache.wk.size
+    assert cache.wk.size / rows == 0.6
+    tokens = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)
+
+    def step(params, tokens, positions, cache, live):
+        return forward(
+            params, cfg, tokens, positions, cache, live=live,
+            decode_attn_impl="kernel", moe_dispatch_impl="touched",
+            count_experts_read=True,
+        )
+
+    compiled = jax.jit(step, donate_argnums=(3,)).lower(
+        params, tokens, tokens, cache, live
+    ).compile()
+    text = compiled.as_text()
+    # one period in the scan's body: three rings and a full layer
+    assert len(re.findall(
+        r"%gqa_window_decode_attention[\w.\-]* = bf16\[16,128,128\]", text
+    )) == 3
+    assert len(re.findall(
+        r"%gqa_decode_attention[\w.\-]* = bf16\[16,128,128\]", text
+    )) == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * rows * 2
+    assert mem.temp_size_in_bytes < 64 * 2**20
+    # the einsum path's scores, [B, Hkv, G, 1, S] float32, over a slab
+    assert not re.search(r"f32\[16,(8,16|128),(1,)?(4096|8192)\]", text)
+
+
+def test_a_window_stack_s_prefill_runs_the_band_and_no_score_tensor(one_chip):
+    """An 8,192 prefill of one period at the published widths: the three
+    sliding layers call the flash kernel with the band (under a name of
+    their own), the full layer without, 128 query heads on 8 in tiles of
+    128 query rows, the experts grouped; no ``[B, H, T, S]`` scores
+    anywhere (34 GB at these shapes), and temporaries that leave the
+    resident model room."""
+    from gpustack_tpu.models import init_params
+    from gpustack_tpu.models.quant import quantize_params
+    from gpustack_tpu.models.transformer import KVCache, forward, moe_dispatch
+    from gpustack_tpu.ops.flash_attention import choose_tiles
+
+    cfg = _command_a(periods=1)
+    T = 8192
+    assert moe_dispatch(T, cfg, "tpu", None) == "grouped"
+    assert choose_tiles(T, T, 16, 128, 2) == (128, 128, 512, 4)
+    params = _shapes_on(
+        one_chip, lambda: quantize_params(init_params(cfg, jax.random.key(0)))
+    )
+    tokens = jax.ShapeDtypeStruct((1, T), jnp.int32, sharding=one_chip)
+    true_len = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def prefill(params, tokens, true_len):
+        cache = KVCache.create(cfg, 1, T)
+        positions = jnp.arange(T, dtype=jnp.int32)[None]
+        logits, cache, held = forward(
+            params, cfg, tokens, positions, cache, attn_impl="flash",
+            moe_dispatch_impl="grouped", count_held_pairs=True,
+            true_len=true_len[None],
+        )
+        return (
+            jnp.take(logits[0], true_len - 1, axis=0), cache.k[:, 0],
+            cache.v[:, 0], cache.wk[:, 0], cache.wv[:, 0], held,
+        )
+
+    compiled = jax.jit(prefill).lower(params, tokens, true_len).compile()
+    text = compiled.as_text()
+    assert len(re.findall(
+        r"%flash_attention_window[\w.\-]* = bf16\[1,128,8192,128\]", text
+    )) == 3
+    assert len(re.findall(
+        r"%flash_attention_prefill[\w.\-]* = bf16\[1,128,8192,128\]", text
+    )) == 1
+    _no_score_tensor(text, T, T)
+    mem = compiled.memory_analysis()
+    # the ring's rows go out at window size: 3 layers x 4,096 rows
+    assert mem.output_size_in_bytes < 2 * (8192 + 3 * 4096) * 2048 * 2 * 1.1 + 2**20
+    assert mem.temp_size_in_bytes < 2.5e9
+
+
+@pytest.fixture(scope="module")
+def lowered_hashes(one_chip):
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    import lowered_programs
+
+    return lowered_programs.hashes(one_chip)
+
+
+def _lowered_names():
+    import json
+    import os
+
+    with open(os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), "lowered_programs.json"
+    )) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("program", sorted(_lowered_names()))
+def test_the_other_models_programs_lower_to_the_text_they_had(
+    lowered_hashes, program
+):
+    """The decode and prefill programs of the benchmark's four other
+    configurations, lowered for the chip at their cells' shapes, are to
+    the letter what they were before the window store, the band and the
+    parallel block went into ``forward`` (``lowered_programs.py`` says
+    what is hashed and how to take the hashes again on purpose)."""
+    assert lowered_hashes[program] == _lowered_names()[program]
