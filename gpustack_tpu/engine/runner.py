@@ -423,12 +423,15 @@ class ModelRunner:
             mesh=self.mesh,
             count_held_pairs=bool(self.cfg.experts_held),
             routing_out=routing,
+            # a prefill keeps one row of its bucket: the final norm and
+            # the vocabulary head run on that row alone
+            logits_at=(true_len - 1)[None],
             **(
                 {"true_len": true_len[None]} if self.keeps_beside_rows
                 else {}
             ),
         )
-        last = jnp.take(logits[0], true_len - 1, axis=0)
+        last = logits[0, 0]
         mixer = ()
         if self.hybrid:
             mixer = ((cache.ssm[:, 0], cache.conv[:, 0]),)
@@ -481,8 +484,9 @@ class ModelRunner:
             attn_impl=attn_impl,
             mesh=self.mesh,
             embeds_override=(embeds, mask),
+            logits_at=(true_len - 1)[None],   # as _prefill_impl
         )
-        last = jnp.take(logits[0], true_len - 1, axis=0)
+        last = logits[0, 0]
         return last, cache.k[:, 0], cache.v[:, 0]
 
     def prefill_with_embeds(
@@ -537,8 +541,9 @@ class ModelRunner:
             attn_impl=attn_impl,
             mesh=self.mesh,
             count_held_pairs=bool(self.cfg.experts_held),
+            logits_at=(true_len - 1)[None],   # as _prefill_impl
         )
-        last = jnp.take(logits[0], true_len - 1, axis=0)
+        last = logits[0, 0]
         return (last, cache.k[:, 0], cache.v[:, 0], *held)
 
     def prefill_with_prefix(
@@ -599,6 +604,7 @@ class ModelRunner:
         positions = jnp.broadcast_to(
             jnp.arange(Tb, dtype=jnp.int32)[None, :], tokens.shape
         )
+        # every row is pooled: no ``logits_at``
         hidden, _ = forward(
             params, self.cfg, tokens, positions, return_hidden=True,
             mesh=self.mesh,
@@ -724,6 +730,7 @@ class ModelRunner:
     def _decode_impl(self, params, state, key, routing=False):
         tokens = state.last_tokens[:, None]
         positions = state.positions[:, None]
+        # one row a slot already: nothing for ``logits_at`` to drop
         logits, cache, *extras = forward(
             params, self.cfg, tokens, positions, state.cache,
             attn_impl="ring" if self.sp_mode else "xla",
@@ -839,6 +846,8 @@ class ModelRunner:
             state.positions[:, None]
             + jnp.arange(P, dtype=jnp.int32)[None, :]
         )
+        # the logits are discarded, and the compiler drops the head
+        # with them: no ``logits_at``
         _, cache = forward(
             params, self.cfg, fed, positions, state.cache,
             attn_impl="ring" if self.sp_mode else "xla",
@@ -935,6 +944,8 @@ class ModelRunner:
             state.positions[:, None]
             + jnp.arange(P, dtype=jnp.int32)[None, :]
         )
+        # every row's argmax is compared with a proposal: no
+        # ``logits_at``
         logits, cache = forward(
             params, self.cfg, tokens, positions, state.cache,
             attn_impl="ring" if self.sp_mode else "xla",

@@ -164,6 +164,7 @@ def forward_hybrid(
     count_held_pairs: bool = False,
     routing_out: bool = False,
     count_experts_read: bool = False,
+    logits_at: Optional[jax.Array] = None,
 ):
     """``transformer.forward`` for a model with ``layer_kinds``; the same
     arguments and results, and two more arguments.
@@ -474,11 +475,5 @@ def forward_hybrid(
         extras.append(read)
     if routing_out:
         extras.append(tuple(jnp.stack(r) for r in zip(*routings)))
-    x = tf.rms_norm(x, params["final_norm"], eps)
-    if return_hidden:
-        return (x.astype(f32), cache, *extras)
-    if cfg.tie_word_embeddings:
-        logits = jnp.einsum("btd,vd->btv", x, params["embed"])
-    else:
-        logits = tf._mm("btd,dv->btv", x, params["lm_head"])
-    return (logits.astype(f32), cache, *extras)
+    out = tf.head(x, params, cfg, logits_at, return_hidden)
+    return (out, cache, *extras)

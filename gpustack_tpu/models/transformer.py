@@ -467,6 +467,54 @@ def layer_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     return (xc * lax.rsqrt(var + eps)).astype(x.dtype) * w
 
 
+def model_norm(x: jax.Array, w: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """The norm ``cfg`` names, round every layer and before the head."""
+    if cfg.layer_norm:
+        return layer_norm(x, w, cfg.rms_norm_eps)
+    return rms_norm(x, w, cfg.rms_norm_eps, cfg.norm_delta_gain)
+
+
+def head(
+    x: jax.Array,                     # [B, T, d] after the last layer
+    params: Params,
+    cfg: ModelConfig,
+    logits_at: Optional[jax.Array] = None,
+    return_hidden: bool = False,
+) -> jax.Array:
+    """The final norm and the vocabulary head, the one copy every model's
+    ``forward`` ends in: float32 logits ``[B, T, vocab]``, or with
+    ``return_hidden`` the normalised hidden states ``[B, T, d]``.
+
+    ``logits_at`` (int32 ``[B]``, an index along ``T``; None: every row)
+    names the one row a sequence whose result is wanted: the hidden
+    state is gathered to ``[B, 1, d]`` *before* the norm and the
+    product, in the dtype it has, so the product keeps its operands and
+    its accumulation and only its row count changes. The compiler does
+    not move a ``take`` of the logits through the product: a 2,048
+    prefill of 151,936 columns computed all 2,048 rows and kept one
+    (13-15 ms of the 8B's 193 ms prefill and 1.2 GB of float32: PERF.md,
+    PR 49)."""
+    if logits_at is not None:
+        x = jnp.take_along_axis(
+            x, logits_at[:, None, None], axis=1, mode="clip"
+        )
+    x = model_norm(x, params["final_norm"], cfg)
+    if return_hidden:
+        # embeddings path: final normalized hidden states, no LM head
+        return x.astype(jnp.float32)
+    if cfg.tie_word_embeddings:
+        logits = jnp.einsum("btd,vd->btv", x, params["embed"])
+    else:
+        logits = _mm("btd,dv->btv", x, params["lm_head"])
+    logits = logits.astype(jnp.float32)
+    if cfg.logit_scale != 1.0:
+        logits = logits * cfg.logit_scale
+    if cfg.final_logit_softcap:
+        cap = cfg.final_logit_softcap
+        logits = cap * jnp.tanh(logits / cap)
+    return logits
+
+
 def _inv_freq(theta: float, head_dim: int) -> jax.Array:
     half = head_dim // 2
     return 1.0 / (
@@ -1205,6 +1253,7 @@ def forward(
     count_experts_read: bool = False,
     true_len: Optional[jax.Array] = None,
     ssm_impl: Optional[str] = None,
+    logits_at: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[KVCache]]:
     """Run the model.
 
@@ -1277,7 +1326,11 @@ def forward(
     the kernel, its last tenant's rows under ``"xla"``, which takes no
     notice of ``live``); its row of the cache is still written.
 
-    Returns ``(logits [B, T, vocab] fp32, updated cache or None)``, and
+    Returns ``(logits [B, T, vocab] fp32, updated cache or None)``; with
+    ``logits_at`` (int32 ``[B]``, an index along ``T``) the logits are
+    ``[B, 1, vocab]``, those of the one row a sequence it names, and the
+    final norm and the vocabulary head run on that row alone
+    (:func:`head`; a prefill keeps one row of its bucket). And
     with ``count_held_pairs`` (a model served as one share of its
     experts, ``cfg.experts_held``) a third: how many of the router's
     ``B * T * k`` pairs a layer, over the layers with experts, fell on
@@ -1305,6 +1358,7 @@ def forward(
             decode_attn_impl=decode_attn_impl, ssm_impl=ssm_impl, live=live,
             true_len=true_len, count_held_pairs=count_held_pairs,
             routing_out=routing_out, count_experts_read=count_experts_read,
+            logits_at=logits_at,
         )
     B, T = tokens.shape
     platform = (
@@ -1721,11 +1775,6 @@ def forward(
         )
         return attn, carried
 
-    def norm(x_, w):
-        if cfg.layer_norm:
-            return layer_norm(x_, w, cfg.rms_norm_eps)
-        return rms_norm(x_, w, cfg.rms_norm_eps, cfg.norm_delta_gain)
-
     def block(carry, scanned, moe_layer: bool, kind=None):
         x_in, carried, layer, *counts = carry
         lp, slide_flag = scanned
@@ -1738,7 +1787,7 @@ def forward(
             cos_b = jnp.where(slide_flag, cos_loc, cos)
         else:
             mask_l, sin_b, cos_b = mask, sin, cos
-        h = norm(x_in, lp["attn_norm"])
+        h = model_norm(x_in, lp["attn_norm"], cfg)
         if cfg.is_mla:
             attn, carried = mla_attention(h, lp, carried, layer, mask_l)
         elif kind is not None:
@@ -1873,7 +1922,7 @@ def forward(
             x_mid, h2 = x_in, h
         else:
             x_mid = x_in + attn_out
-            h2 = norm(x_mid, lp["mlp_norm"])
+            h2 = model_norm(x_mid, lp["mlp_norm"], cfg)
         routing = None
         if moe_layer:
             mlp = _moe_mlp(
@@ -1990,18 +2039,5 @@ def forward(
         new_cache = KVCache(
             k=new_cache.k[:, :, :, None, :], v=new_cache.v[:, :, :, None, :]
         )
-    x = norm(x, params["final_norm"])
-    if return_hidden:
-        # embeddings path: final normalized hidden states, no LM head
-        return (x.astype(jnp.float32), new_cache, *extras)
-    if cfg.tie_word_embeddings:
-        logits = jnp.einsum("btd,vd->btv", x, params["embed"])
-    else:
-        logits = _mm("btd,dv->btv", x, params["lm_head"])
-    logits = logits.astype(jnp.float32)
-    if cfg.logit_scale != 1.0:
-        logits = logits * cfg.logit_scale
-    if cfg.final_logit_softcap:
-        cap = cfg.final_logit_softcap
-        logits = cap * jnp.tanh(logits / cap)
-    return (logits, new_cache, *extras)
+    out = head(x, params, cfg, logits_at, return_hidden)
+    return (out, new_cache, *extras)

@@ -102,6 +102,7 @@ def lowered(one_chip) -> dict:
                     bucket, cfg, "tpu", None
                 ) if experts else None,
                 count_held_pairs=bool(cfg.experts_held),
+                logits_at=(true_len - 1)[None],
                 **({"true_len": true_len[None], "ssm_impl": "scan"}
                    if hybrid else {}),
             )

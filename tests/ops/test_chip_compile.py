@@ -277,6 +277,79 @@ def test_a_decode_step_reads_its_attention_weights_in_place(
 
 
 @pytest.mark.parametrize(
+    "preset,program,rows",
+    [
+        ("qwen3-8b", "prefill", 2048),     # the 8B deployment's bucket
+        # the MoE deployment's other bucket: at 2,048, its hidden size,
+        # the head's own matrix is ``[2048, 151936]``
+        ("qwen3-30b-a3b", "prefill", 1024),
+        ("qwen3-8b", "verify", 4),         # needs every row's argmax
+    ],
+)
+def test_a_prefill_computes_the_head_for_the_row_it_keeps(
+    topo, one_chip, preset, program, rows
+):
+    """``ModelRunner``'s own prefill program (int8, a bucket of ``rows``,
+    the flash kernel) holds no value of the bucket's rows by the
+    vocabulary's columns, in any dtype, not even inside a fusion: the
+    final norm and the head run on the one row ``true_len - 1`` names
+    (``transformer.head``, ``logits_at``). With the ``take`` behind the
+    product the compiler computed every row (``fusion
+    bf16[2048,151936]``, 13-15 ms of the 8B deployment's 193 ms prefill
+    and 1.2 GB of float32: PERF.md, PR 49); this keeps the fold from
+    coming back. The verify program (12 slots x ``rows``) keeps all its
+    rows."""
+    import types
+    from functools import partial
+
+    from gpustack_tpu.engine.runner import DecodeState, ModelRunner
+    from gpustack_tpu.models import init_params
+    from gpustack_tpu.models.quant import quantize_params
+    from gpustack_tpu.parallel.mesh import MeshPlan, make_mesh
+
+    cfg = dataclasses.replace(get_config(preset), num_layers=2)
+    # what the two programs ask of their runner, on a mesh of the one
+    # described chip (so ``forward``'s choosers see a TPU)
+    runner = types.SimpleNamespace(
+        cfg=cfg, mesh=make_mesh(MeshPlan(), [topo.devices[0]]),
+        sp_mode=False, hybrid=False, windowed=False,
+        keeps_beside_rows=False, max_seq_len=MAX_LEN,
+    )
+    params = _shapes_on(
+        one_chip, lambda: quantize_params(init_params(cfg, jax.random.key(0)))
+    )
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    if program == "prefill":
+        lowered = jax.jit(
+            partial(ModelRunner._prefill_impl, runner, attn_impl="flash")
+        ).lower(params, ints(1, rows), ints())
+    else:
+        state = _shapes_on(
+            one_chip, lambda: DecodeState.create(cfg, 12, MAX_LEN)
+        )
+        lowered = jax.jit(
+            partial(ModelRunner._verify_impl, runner), donate_argnums=(1,)
+        ).lower(params, state, ints(12, rows))
+    text = lowered.compile().as_text()
+    V = cfg.vocab_size
+    # the head's product under its einsum's name, by its leading dims
+    heads = re.findall(
+        rf"= \w+\[([\d,]*){V}\]\S* (?:fusion|convolution|dot)\("
+        r"[^\n]*btd,dv->btv/dot_general", text,
+    )
+    assert heads, "the head's product is not under its einsum's name"
+    if program == "prefill":
+        assert "flash_attention_prefill" in text
+        assert not re.findall(rf"\w+\[(?:\d+,)*{rows},{V}\]", text)
+        assert all(dims in ("", "1,", "1,1,") for dims in heads), heads
+    else:
+        assert f"12,{rows}," in heads, heads
+
+
+@pytest.mark.parametrize(
     "preset,change,rows,max_len,platform,devices,want",
     [
         ("qwen3-8b", {}, 1, 2048, "tpu", 1, "kernel"),
