@@ -93,7 +93,8 @@ _SCHEDULER_METHODS = (
     "_store_finished_sequence", "_upload_prefix",
     "_resolve_staged_prefix", "_plan_chunk_job", "_new_slot_info",
     "_emit_text", "_push", "_note_spec_dispatch", "_spec_safe",
-    "_entry_ready", "_submit_kv_copy",
+    "_entry_ready", "_submit_kv_copy", "_draw", "_insert",
+    "_flush_freed",
 )
 
 GUARDED_BY = {
@@ -108,7 +109,8 @@ GUARDED_BY = {
     "_detok_batch": _SCHEDULER_METHODS,
     "_overlap_seen": _SCHEDULER_METHODS,
     "_state": _SCHEDULER_METHODS,
-    "_key": _SCHEDULER_METHODS,
+    "_draws": _SCHEDULER_METHODS,
+    "_freed": _SCHEDULER_METHODS,
 }
 
 # a busy step longer than this logs one WARNING line with its mode and
@@ -610,7 +612,17 @@ class LLMEngine:
         self._slots: Dict[int, _SlotInfo] = {}
         self._free = list(range(max_slots))
         self._waiting: "queue.Queue[GenRequest]" = queue.Queue()
-        self._key = jax.random.key(seed)
+        # Between two serving programs the scheduler's thread hands the
+        # device nothing (ROADMAP A11): what a program needs from the
+        # host goes in as NumPy arguments of the call itself. So a draw
+        # (an admission's first token, a decode step) gets the words
+        # (seed, count of draws) and its program wraps them as its key
+        # (runner.draw_words), where a split here was two programs; and
+        # a finished slot waits in ``_freed`` for the next decode
+        # program to switch it off, where a scatter here was one.
+        self._seed = int(seed) & 0xFFFFFFFF
+        self._draws = 0
+        self._freed: set = set()
         self._pending: List[Tuple[Any, Dict[int, int]]] = []
         # Dispatch-ahead pipeline (docs/ENGINE_PIPELINE.md): sampled
         # tokens are fetched this many steps behind dispatch, so the
@@ -1909,11 +1921,10 @@ class LLMEngine:
         # len(ids)-1 to keep every draw's stream unique — a collision
         # would replay identical gumbel noise on two consecutive,
         # similarly-distributed steps.
-        self._key, first_key = jax.random.split(self._key)
         seed = 0 if req.seed is None else int(req.seed) & 0xFFFFFFFF
         toks, tok_lp, top_ids, top_lps = self.runner.sample_first(
             last_logits, req.temperature, req.top_k, req.top_p,
-            seed, req.seed is not None, len(ids) - 1, first_key,
+            seed, req.seed is not None, len(ids) - 1, self._draw(),
             logit_bias=req.logit_bias,
         )
         if (
@@ -1922,12 +1933,7 @@ class LLMEngine:
             and not req.logprobs
             and getattr(self.runner, "supports_async_insert", False)
         ):
-            self._state = self.runner.insert(
-                self._state, k, v, slot, len(ids), toks[0],
-                req.temperature, req.top_k, req.top_p,
-                seed, req.seed is not None, req.logit_bias,
-                **({} if mixer is None else {"mixer": mixer}),
-            )
+            self._insert(slot, req, k, v, toks, seed, mixer)
             self._slots[slot] = self._new_slot_info(req)
             # deferred first-token feed: fetched (and rolled back if the
             # request was aborted meanwhile) with the decode pipeline
@@ -1947,25 +1953,22 @@ class LLMEngine:
         proposers, logprobs, multi-host broadcast runners): reads the
         sampled token to the host before insert — a designated sync."""
         ids = req.prompt_ids
+        # read whole and indexed here: ``toks[0]`` on the device's array
+        # is a program
         with self._phases.wait:
-            first = int(toks[0])
+            first = int(np.asarray(toks)[0])
         first_lps = None
         if req.logprobs:
             first_lps = [(
-                float(tok_lp[0]),
+                float(np.asarray(tok_lp)[0]),
                 [
                     (int(i), float(lp))
                     for i, lp in zip(
-                        np.asarray(top_ids[0]), np.asarray(top_lps[0])
+                        np.asarray(top_ids)[0], np.asarray(top_lps)[0]
                     )
                 ],
             )]
-        self._state = self.runner.insert(
-            self._state, k, v, slot, len(ids), first,
-            req.temperature, req.top_k, req.top_p,
-            seed, req.seed is not None, req.logit_bias,
-            **({} if mixer is None else {"mixer": mixer}),
-        )
+        self._insert(slot, req, k, v, first, seed, mixer)
         info = self._new_slot_info(req)
         if self.draft_runner is not None:
             # mirror the slot on the draft: prefill + insert (greedy)
@@ -1988,6 +1991,36 @@ class LLMEngine:
             # insert); queueing it again would double-feed it
             self._slots[slot].pending_draft.clear()
 
+    def _insert(self, slot, req, k, v, first, seed, mixer) -> None:
+        """The prefill's rows into ``slot``, live from here on: a slot
+        whose last request ended since the last step is no longer one
+        to switch off."""
+        self._freed.discard(slot)
+        self._state = self.runner.insert(
+            self._state, k, v, slot, len(req.prompt_ids), first,
+            req.temperature, req.top_k, req.top_p,
+            seed, req.seed is not None, req.logit_bias,
+            **({} if mixer is None else {"mixer": mixer}),
+        )
+
+    def _draw(self) -> np.ndarray:
+        """The next draw's key as the programs take it
+        (``runner.draw_words``): the engine's seed and how many draws
+        it has made, this one included."""
+        self._draws += 1
+        return np.array(
+            [self._seed, self._draws & 0xFFFFFFFF], np.uint32
+        )
+
+    def _flush_freed(self) -> None:
+        """Switch the finished slots off now, a program each
+        (``runner.deactivate``): for a step that takes no ``freed``, a
+        verify step or a follower's replay, neither of which a
+        benchmark cell runs."""
+        for slot in sorted(self._freed):
+            self._state = self.runner.deactivate(self._state, slot)
+        self._freed.clear()
+
     def _decode_once(self) -> None:
         phases = self._phases
         if self.draft_runner is not None and self._spec_safe():
@@ -2001,7 +2034,14 @@ class LLMEngine:
                 self._drain_pending()
         with phases.dispatch:
             dispatched = self._dispatch_decode()
-        if dispatched and len(self._pending) > self.pipeline_depth:
+        # The depth caps what is in flight, so as many fetches as it
+        # takes: an admission's first token is an entry of its own, and
+        # one fetch a step let the line grow by one with every admission
+        # for as long as the device, not the host, set the pace, each new
+        # prefill behind all of it. (Not inside ``_admit``: a host that
+        # waits there gathers arrivals into one step, prefill after
+        # prefill with no decode step between them.)
+        while dispatched and len(self._pending) > self.pipeline_depth:
             with phases.drain:
                 self._process_fetch(*self._pending.pop(0))
 
@@ -2017,6 +2057,7 @@ class LLMEngine:
         if not owners:
             return False
         if self.speculative == "ngram" and self._spec_safe():
+            self._flush_freed()
             proposals = self._build_proposals()
             self._state, tokens, produced = self.runner.verify_step(
                 self._state, proposals
@@ -2026,6 +2067,7 @@ class LLMEngine:
             self._pending.append((("spec", (tokens, produced)), owners))
             self._note_spec_dispatch(len(owners))
         elif self.draft_runner is not None and self._spec_safe():
+            self._flush_freed()
             proposals = self._draft_propose()
             self._state, tokens, produced = self.runner.verify_step(
                 self._state, proposals
@@ -2035,9 +2077,17 @@ class LLMEngine:
             self._pending.append((("spec", (tokens, produced)), owners))
             self._note_spec_dispatch(len(owners))
         else:
-            self._key, step_key = jax.random.split(self._key)
+            # a mask of its own a step (the call may read it after this
+            # thread has gone on), and none where no slot waits: a
+            # runner that replays its calls never has one and takes none
+            freed = {}
+            if self._freed:
+                mask = np.zeros((self.max_slots,), np.bool_)
+                mask[list(self._freed)] = True
+                self._freed.clear()
+                freed = {"freed": mask}
             self._state, out = self.runner.decode_step(
-                self._state, step_key
+                self._state, self._draw(), **freed
             )
             self._pending.append((("decode", out), owners))
             # decode runs every slot whether or not it is active: the
@@ -2137,7 +2187,7 @@ class LLMEngine:
             )
         snap = self.draft_runner.snapshot_sequence(self._draft_state)
         proposals = np.zeros((self.max_slots, P), np.int32)
-        key = jax.random.key(0)  # draft sampling is greedy; key unused
+        key = np.zeros((2,), np.uint32)  # the draft is greedy; key unused
         for j in range(P - 1):
             self._draft_state, out = self.draft_runner.decode_step(
                 self._draft_state, key
@@ -2372,7 +2422,11 @@ class LLMEngine:
                     (req.finished_at - req.first_token_at)
                     / (len(req.output_ids) - 1)
                 )
-        self._state = self.runner.deactivate(self._state, slot)
+        # the next decode program switches it off (``_dispatch_decode``)
+        self._freed.add(slot)
+        if getattr(self.runner, "replays", False):
+            # multi-host: an op on the wire at the finish, as ever
+            self._flush_freed()
         if self.draft_runner is not None:
             self._draft_state = self.draft_runner.deactivate(
                 self._draft_state, slot

@@ -12,9 +12,10 @@ the reference's multinode vLLM (reference worker/backends/vllm.py:
 stdlib sockets + ndjson because the op vocabulary is tiny.
 
 Determinism contract:
-- PRNG keys ride the wire as raw ``jax.random.key_data`` — followers
-  never derive keys themselves, so leader/follower sampling programs
-  see bit-identical key inputs.
+- A draw's key rides the wire as the two words the leader's programs
+  take (``runner.draw_words``: the engine's seed and its count of
+  draws) — followers never count draws themselves, so leader/follower
+  sampling programs see bit-identical key inputs.
 - Device arrays never ride the wire. A follower's ``prefill`` output is
   registered locally and consumed by its next ``insert`` — the engine's
   scheduling loop is single-threaded, so prefill→insert order is stable.
@@ -42,8 +43,9 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
-import jax
-import jax.numpy as jnp
+import numpy as np
+
+from gpustack_tpu.engine.runner import draw_words
 
 logger = logging.getLogger(__name__)
 
@@ -51,13 +53,11 @@ _CONNECT_TIMEOUT_S = 600.0   # follower hosts may still be downloading
 
 
 def _key_data_list(key) -> List[int]:
-    import numpy as np
-
-    return np.asarray(jax.random.key_data(key)).astype("uint32").tolist()
+    return np.asarray(draw_words(key)).tolist()
 
 
 def _key_from_list(data: List[int]):
-    return jax.random.wrap_key_data(jnp.asarray(data, jnp.uint32))
+    return np.asarray(data, np.uint32)
 
 
 def channel_token() -> str:
@@ -195,6 +195,11 @@ class BroadcastingRunner:
     # NOT hand it a device-scalar first token. Class attr (not
     # __getattr__-delegated) so the wrapped runner's True never leaks.
     supports_async_insert = False
+    # every device call is an op on the wire that the followers replay,
+    # one for one: the engine switches a finished slot off at its finish
+    # (:meth:`deactivate`), where for a local runner it hands the next
+    # decode step a mask
+    replays = True
 
     def __init__(self, runner, leader: CommandLeader):
         self._runner = runner

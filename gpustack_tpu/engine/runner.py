@@ -23,11 +23,14 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import threading
+from collections import deque
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -54,14 +57,29 @@ logger = logging.getLogger(__name__)
 
 def bias_arrays(logit_bias):
     """{token_id: bias} → fixed-width (ids i32[MAX_BIAS], vals
-    f32[MAX_BIAS]) arrays (-1 = unused slot)."""
+    f32[MAX_BIAS]) arrays (-1 = unused slot). NumPy, as every small
+    value a wrapper hands its program: the call uploads it, where a
+    ``jnp`` value is a program of its own, launched before the one it is
+    for."""
     ids = [-1] * MAX_BIAS
     vals = [0.0] * MAX_BIAS
     if logit_bias:
         for j, (tid, bias) in enumerate(list(logit_bias.items())[:MAX_BIAS]):
             ids[j] = int(tid)
             vals[j] = float(bias)
-    return jnp.asarray(ids, jnp.int32), jnp.asarray(vals, jnp.float32)
+    return np.asarray(ids, np.int32), np.asarray(vals, np.float32)
+
+
+def draw_words(key):
+    """A draw's key as the decode and first-token programs take it: the
+    two ``uint32`` words of its data, which the program wraps
+    (``jax.random.wrap_key_data``: nothing to lower, where a split or a
+    ``fold_in`` inside it was 0.2 s of every start). The engine makes
+    them on the host, ``(seed, count of draws)``, so no two draws use
+    one key and the host splits nothing (``LLMEngine._draw``); a typed
+    key, from a caller that is not the engine, gives its data (an eager
+    call)."""
+    return key if isinstance(key, np.ndarray) else jax.random.key_data(key)
 
 
 def prefill_attention(
@@ -271,6 +289,7 @@ class ModelRunner:
             )
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
         self._decode_routing = None
+        self._none_freed = np.zeros((max_slots,), np.bool_)
         self._prefills: Dict[int, Any] = {}
         self._prefills_routing: Dict[int, Any] = {}
         self._logged_attn_buckets: set = set()
@@ -282,13 +301,13 @@ class ModelRunner:
         self._ingests: Dict[int, Any] = {}
         self._prefix_prefills: Dict[Tuple[int, int, int], Any] = {}
         # under a share of the experts (cfg.experts_held): the router's
-        # (token, expert) pairs the prefill programs have made, on the
-        # device the ones on held experts (added up without a sync), and
-        # the last pair of counts that was read
-        self._pairs_routed = 0
-        self._pairs_held = (
-            jnp.zeros((), jnp.int32) if cfg.experts_held else None
-        )
+        # (token, expert) pairs the prefill programs have made. Each
+        # program's count of the ones on held experts stays on the
+        # device, beside the pairs it routed, until it is there to be
+        # read (no sync, and no program to add them up); ``_pairs_read``
+        # is the sum of those that were
+        self._pairs_unread: deque = deque()
+        self._pairs_mu = threading.Lock()
         self._pairs_read = {"held": 0, "absent": 0}
 
     # -- state ------------------------------------------------------------
@@ -366,21 +385,24 @@ class ModelRunner:
         health probe must not stand behind a prefill): while the count
         of the last prefill dispatched is not there yet, the counts as
         they were last read."""
-        if self._pairs_held is None:
+        if not self.cfg.experts_held:
             return None
-        held, routed = self._pairs_held, self._pairs_routed
-        if held.is_ready():
-            self._pairs_read = {
-                "held": int(held), "absent": routed - int(held)
-            }
-        return self._pairs_read
+        with self._pairs_mu:
+            unread, read = self._pairs_unread, dict(self._pairs_read)
+            while unread and unread[0][0].is_ready():
+                held, routed = unread.popleft()
+                read["held"] += int(held)
+                read["absent"] += routed - int(held)
+            self._pairs_read = read
+        return read
 
     def _note_pairs(self, held, rows: int) -> None:
         cfg = self.cfg
-        self._pairs_held = self._pairs_held + held
-        self._pairs_routed += (
-            rows * cfg.num_experts_per_tok * cfg.num_moe_layers
+        self._pairs_unread.append(
+            (held, rows * cfg.num_experts_per_tok * cfg.num_moe_layers)
         )
+        if len(self._pairs_unread) >= 64:
+            self.moe_pairs()   # nobody asks: keep the line short
 
     def moe_dispatch_for(
         self, rows: int, decode: bool = False
@@ -464,8 +486,8 @@ class ModelRunner:
                 f"prefill_{Tb}" + ("_routing" if routing else ""),
             )
             fns[Tb] = fn
-        tokens = jnp.asarray(token_ids, jnp.int32)[None, :]
-        last, k, v, *extras = fn(self.params, tokens, jnp.int32(true_len))
+        tokens = np.asarray(token_ids, np.int32)[None, :]
+        last, k, v, *extras = fn(self.params, tokens, np.int32(true_len))
         mixer = (extras.pop(0),) if self.keeps_beside_rows else ()
         if self.cfg.experts_held:
             self._note_pairs(extras[0], Tb)
@@ -506,10 +528,10 @@ class ModelRunner:
                 f"prefill_embeds_{Tb}",
             )
             self._prefill_embeds[Tb] = fn
-        tokens = jnp.asarray(token_ids, jnp.int32)[None, :]
+        tokens = np.asarray(token_ids, np.int32)[None, :]
         return fn(
-            self.params, tokens, jnp.int32(true_len),
-            jnp.asarray(embeds)[None, :], jnp.asarray(mask, bool)[None, :],
+            self.params, tokens, np.int32(true_len),
+            np.asarray(embeds)[None, :], np.asarray(mask, bool)[None, :],
         )
 
     def _prefix_prefill_impl(
@@ -581,15 +603,15 @@ class ModelRunner:
                 f"prefix_prefill_{Pb}_{Tsb}_{total_bucket}",
             )
             self._prefix_prefills[key] = fn
-        tokens = jnp.asarray(suffix_ids, jnp.int32)[None, :]
+        tokens = np.asarray(suffix_ids, np.int32)[None, :]
         last, k, v, *held = fn(
             self.params,
-            jnp.asarray(prefix_k),
-            jnp.asarray(prefix_v),
-            jnp.int32(prefix_len),
+            prefix_k,
+            prefix_v,
+            np.int32(prefix_len),
             tokens,
             # logits cover the suffix only
-            jnp.int32(suffix_true_len),
+            np.int32(suffix_true_len),
         )
         if held:
             self._note_pairs(held[0], Tsb)
@@ -675,7 +697,9 @@ class ModelRunner:
             )
         return DecodeState(
             cache=KVCache(k=new_k, v=new_v, **held),
-            last_tokens=state.last_tokens.at[slot].set(first_token),
+            # ``first_token`` is the [1] that the first-token program
+            # returned: indexed here, not by a program of its own
+            last_tokens=state.last_tokens.at[slot].set(first_token[0]),
             positions=state.positions.at[slot].set(true_len),
             active=state.active.at[slot].set(True),
             sampling=state.sampling.set_slot(
@@ -686,30 +710,38 @@ class ModelRunner:
 
     def insert(
         self, state: DecodeState, k, v, slot: int, true_len: int,
-        first_token: int, temperature: float, top_k: int, top_p: float,
+        first_token, temperature: float, top_k: int, top_p: float,
         seed: int = 0, seeded: bool = False, logit_bias=None,
         mixer=None,
     ) -> DecodeState:
         """Place a prefill's rows, and for a hybrid its recurrent state
         or for a stack with a window store its sliding layers' rows
         (``mixer``: what :meth:`prefill` returned after ``k, v``), in
-        ``slot`` and make the slot live."""
+        ``slot`` and make the slot live. ``first_token``: an int, or
+        the ``[1]`` array of tokens :meth:`sample_first` returned,
+        still on the device."""
         Tb = k.shape[1]
         fn = self._inserts.get(Tb)
         if fn is None:
             fn = jax.jit(self._insert_impl, donate_argnums=(0,))
             self._inserts[Tb] = fn
         bias_ids, bias_vals = bias_arrays(logit_bias)
+        if not isinstance(first_token, jax.Array):
+            first_token = np.asarray([first_token], np.int32)
         return fn(
-            state, k, v, jnp.int32(slot), jnp.int32(true_len),
-            jnp.int32(first_token), jnp.float32(temperature),
-            jnp.int32(top_k), jnp.float32(top_p),
-            jnp.uint32(seed), jnp.bool_(seeded),
+            state, k, v, np.int32(slot), np.int32(true_len),
+            first_token, np.float32(temperature),
+            np.int32(top_k), np.float32(top_p),
+            np.uint32(seed), np.bool_(seeded),
             bias_ids, bias_vals,
             *((mixer,) if self.keeps_beside_rows else ()),
         )
 
     def deactivate(self, state: DecodeState, slot: int) -> DecodeState:
+        """Switch ``slot`` off now, by a program of its own. The engine
+        hands the slots its requests freed to the next decode step
+        instead (:meth:`decode_step`'s ``freed``), and calls this only
+        before a step that takes no such argument."""
         return dataclasses.replace(
             state, active=state.active.at[slot].set(False)
         )
@@ -727,7 +759,10 @@ class ModelRunner:
 
     # -- decode -----------------------------------------------------------
 
-    def _decode_impl(self, params, state, key, routing=False):
+    def _decode_impl(self, params, state, key, freed, routing=False):
+        # the slots whose requests ended since the last step go off
+        # first: what follows reads ``active`` as the host knows it
+        active = state.active & ~freed
         tokens = state.last_tokens[:, None]
         positions = state.positions[:, None]
         # one row a slot already: nothing for ``logits_at`` to drop
@@ -735,7 +770,7 @@ class ModelRunner:
             params, self.cfg, tokens, positions, state.cache,
             attn_impl="ring" if self.sp_mode else "xla",
             mesh=self.mesh,
-            live=state.active,
+            live=active,
             routing_out=routing,
             count_experts_read=self.cfg.is_moe,
         )
@@ -743,7 +778,8 @@ class ModelRunner:
         # finds it where it was
         extras.reverse()
         sampled, tok_lp, top_ids, top_lps = sample(
-            logits[:, 0], state.sampling, key, state.positions
+            logits[:, 0], state.sampling, jax.random.wrap_key_data(key),
+            state.positions,
         )
         # the host reads these every step; on a multi-host mesh an
         # unconstrained output can land dp/tp-sharded and span
@@ -759,10 +795,10 @@ class ModelRunner:
         # through the causal mask of any future tenant. Under the decode
         # kernel they attend nothing (``live``), and what they sample is
         # dropped here either way.
-        next_tokens = jnp.where(state.active, sampled, state.last_tokens)
+        next_tokens = jnp.where(active, sampled, state.last_tokens)
         at_capacity = state.positions + 1 >= self.max_seq_len
         new_positions = jnp.where(
-            state.active, jnp.minimum(state.positions + 1, self.max_seq_len - 1),
+            active, jnp.minimum(state.positions + 1, self.max_seq_len - 1),
             state.positions,
         )
         return (
@@ -770,27 +806,33 @@ class ModelRunner:
                 cache=cache,
                 last_tokens=next_tokens,
                 positions=new_positions,
-                active=state.active & ~at_capacity,
+                active=active & ~at_capacity,
                 sampling=state.sampling,
             ),
             (sampled, tok_lp, top_ids, top_lps, *extras),
         )
 
-    def decode_step(self, state: DecodeState, key, routing: bool = False):
+    def decode_step(
+        self, state: DecodeState, key, routing: bool = False, freed=None
+    ):
         """One decode step. Returns ``(state', (tokens [B], token_logprob
         [B], top_ids [B, TOPLP], top_logprobs [B, TOPLP]))`` — the
         logprob extras ride the same device round-trip as the tokens.
+        ``key``: :func:`draw_words`. ``freed``: ``bool[B]`` (NumPy), the
+        slots to switch off before the step; None for none.
         ``routing``: as :meth:`prefill`'s, a fifth in the tuple. A model
         with experts adds one last: the held experts the step read,
         summed over its layers (int32 scalar: ``forward``'s
         ``count_experts_read``)."""
-        if not routing:
-            return self._decode(self.params, state, key)
-        if self._decode_routing is None:
-            fn = partial(self._decode_impl, routing=True)
-            fn.__name__ = "_decode_routing"
-            self._decode_routing = jax.jit(fn, donate_argnums=(1,))
-        return self._decode_routing(self.params, state, key)
+        if routing and self._decode_routing is None:
+            impl = partial(self._decode_impl, routing=True)
+            impl.__name__ = "_decode_routing"
+            self._decode_routing = jax.jit(impl, donate_argnums=(1,))
+        fn = self._decode_routing if routing else self._decode
+        return fn(
+            self.params, state, draw_words(key),
+            self._none_freed if freed is None else freed,
+        )
 
     def _sample_first_impl(
         self, last_logits, temperature, top_k, top_p, seed, seeded,
@@ -801,7 +843,10 @@ class ModelRunner:
             top_p=top_p[None], seed=seed[None], seeded=seeded[None],
             bias_ids=bias_ids[None], bias_vals=bias_vals[None],
         )
-        outs = sample(last_logits[None, :], st, key, position[None])
+        outs = sample(
+            last_logits[None, :], st, jax.random.wrap_key_data(key),
+            position[None],
+        )
         # host-read outputs must be replicated on multi-host meshes
         rep = self._replicated
         return tuple(
@@ -814,16 +859,17 @@ class ModelRunner:
     ):
         """Sample the first generated token from a prefill's last-position
         logits — one row through the same device sampler as decode, so
-        the whole sequence shares one sampling semantics. A runner method
+        the whole sequence shares one sampling semantics (``key``:
+        :func:`draw_words`). A runner method
         (not engine-inline) so multi-host followers can replay it
         (engine/multihost.py)."""
         if self._sample_first is None:
             self._sample_first = jax.jit(self._sample_first_impl)
         bias_ids, bias_vals = bias_arrays(logit_bias)
         return self._sample_first(
-            last_logits, jnp.float32(temperature), jnp.int32(top_k),
-            jnp.float32(top_p), jnp.uint32(seed), jnp.bool_(seeded),
-            jnp.int32(position), key, bias_ids, bias_vals,
+            last_logits, np.float32(temperature), np.int32(top_k),
+            np.float32(top_p), np.uint32(seed), np.bool_(seeded),
+            np.int32(position), draw_words(key), bias_ids, bias_vals,
         )
 
     # -- draft-model support ---------------------------------------------
@@ -872,8 +918,6 @@ class ModelRunner:
 
     def ingest_step(self, state: DecodeState, tokens, counts) -> DecodeState:
         """tokens [B, P] int32 (pad arbitrary), counts [B] int32."""
-        import numpy as np
-
         P = np.asarray(tokens).shape[1]
         fn = self._ingests.get(P)
         if fn is None:
