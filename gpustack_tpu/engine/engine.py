@@ -479,65 +479,37 @@ class _KVStager:
             return fut
 
 
-def _refuse_what_was_asked(model_has: str, asked) -> None:
-    """The first of ``asked`` (``(was it asked for, why it cannot be)``)
-    that was asked for, refused by name."""
+def _refuse_what_moves_a_slot(
+    cfg, speculative, host_kv_cache_mb, kv_spill_mb, kv_role, prefill_chunk
+) -> None:
+    """A model that keeps something a slot beside the rows it keeps a
+    position (``cfg.beside_rows``: a recurrent state, a ring of window
+    rows) cannot have a slot cut, stored, moved, gone on from or rolled
+    back by its positions: the tokens served after would come from a
+    slot that is not the sequence's. The first such mechanism that was
+    asked for is refused here, at engine start, by name (a mesh of
+    several devices: ``ModelRunner``)."""
+    beside = cfg.beside_rows
+    if beside is None:
+        return
+    asked = [
+        (speculative, f"speculative={speculative!r}: a verify step cannot "
+         f"roll back {beside.lost} past a rejected draft"),
+        (host_kv_cache_mb > 0, "host_kv_cache_mb: the prefix cache "
+         "(engine/kv_host_cache.py) keeps blocks of rows a token span: "
+         f"{beside.span}"),
+        (kv_spill_mb > 0, "kv_spill_mb: the spill tier (engine/kv_spill.py) "
+         f"stores blocks of rows a token span: {beside.span}"),
+        (kv_role, f"kv_role={kv_role!r}: a KV handoff (engine/"
+         f"kv_transfer.py) moves blocks of rows a token span: {beside.span}"),
+        (prefill_chunk > 0, "prefill_chunk: a chunk goes on from cached "
+         f"rows (prefill_with_prefix): {beside.span}"),
+    ]
     for on, why in asked:
         if on:
-            raise ValueError(f"{model_has} and cannot be served with {why}")
-
-
-def _refuse_for_a_state(
-    cfg, speculative, host_kv_cache_mb, kv_spill_mb, kv_role, prefill_chunk
-) -> None:
-    """A model with state-space layers keeps a recurrent state a slot
-    beside its rows (``KVCache.ssm``), which is no span of positions:
-    whatever cuts, stores, moves or rolls back a slot by positions would
-    serve tokens from a state that is not the sequence's. Each such
-    mechanism is refused here, at engine start, by name."""
-    asked = [
-        (speculative, f"speculative={speculative!r}: a verify step cannot "
-         "roll a recurrent state back past a rejected draft"),
-        (host_kv_cache_mb > 0, "host_kv_cache_mb: the prefix cache "
-         "(engine/kv_host_cache.py) keeps blocks of rows a token span; a "
-         "prefix's recurrent state is not among them"),
-        (kv_spill_mb > 0, "kv_spill_mb: the spill tier (engine/kv_spill.py) "
-         "stores blocks of rows and no recurrent state"),
-        (kv_role, f"kv_role={kv_role!r}: a KV handoff (engine/"
-         "kv_transfer.py) moves blocks of rows and no recurrent state"),
-        (prefill_chunk > 0, "prefill_chunk: a chunk goes on from cached "
-         "rows (prefill_with_prefix), which carry no recurrent state"),
-    ]
-    _refuse_what_was_asked(f"{cfg.name} has state-space layers", asked)
-
-
-def _refuse_for_a_ring(
-    cfg, speculative, host_kv_cache_mb, kv_spill_mb, kv_role, prefill_chunk
-) -> None:
-    """A stack that keeps its sliding layers' rows at window size
-    (``KVCache.wk``: a ring, row = position mod window) holds of a slot
-    only the last ``window`` positions of those layers: whatever cuts,
-    stores, moves or rolls back a slot by positions would need rows the
-    ring has overwritten. Each such mechanism is refused here, at engine
-    start, by name (a mesh of several devices: ``ModelRunner``)."""
-    asked = [
-        (speculative, f"speculative={speculative!r}: a verify step cannot "
-         "roll back rows a ring has overwritten with a rejected draft's"),
-        (host_kv_cache_mb > 0, "host_kv_cache_mb: the prefix cache "
-         "(engine/kv_host_cache.py) keeps blocks of rows a token span; a "
-         "span's sliding rows are gone once the window has passed it"),
-        (kv_spill_mb > 0, "kv_spill_mb: the spill tier (engine/kv_spill.py) "
-         "stores blocks of rows a token span, which a ring does not keep"),
-        (kv_role, f"kv_role={kv_role!r}: a KV handoff (engine/"
-         "kv_transfer.py) moves blocks of rows a token span, which a ring "
-         "does not keep"),
-        (prefill_chunk > 0, "prefill_chunk: a chunk goes on from cached "
-         "rows (prefill_with_prefix), and a ring holds only the last "
-         "window of them"),
-    ]
-    _refuse_what_was_asked(
-        f"{cfg.name} keeps its sliding layers' rows at window size", asked
-    )
+            raise ValueError(
+                f"{cfg.name} {beside.keeps} and cannot be served with {why}"
+            )
 
 
 class LLMEngine:
@@ -569,16 +541,10 @@ class LLMEngine:
         kv_spill_dir: str = "",      # spill directory ("" = derived tmp)
     ):
         self.cfg = cfg
-        if cfg.layer_kinds is not None:
-            _refuse_for_a_state(
-                cfg, speculative, host_kv_cache_mb, kv_spill_mb, kv_role,
-                prefill_chunk,
-            )
-        if cfg.window_rows:
-            _refuse_for_a_ring(
-                cfg, speculative, host_kv_cache_mb, kv_spill_mb, kv_role,
-                prefill_chunk,
-            )
+        _refuse_what_moves_a_slot(
+            cfg, speculative, host_kv_cache_mb, kv_spill_mb, kv_role,
+            prefill_chunk,
+        )
         self.tokenizer = tokenizer or load_tokenizer(model_dir)
         self.runner = ModelRunner(
             cfg, params, plan=plan, mesh=mesh,
@@ -588,27 +554,20 @@ class LLMEngine:
         self.max_seq_len = max_seq_len
         self._state: DecodeState = self.runner.new_state()
         cache = self._state.cache
-        self._kv_cache_bytes = int(cache.k.nbytes + cache.v.nbytes)
         self._kv_cache_bytes_per_token = cfg.kv_cache_bytes_per_token(
             8 * cache.k.dtype.itemsize
         ) // max(1, cfg.num_kv_layers)
-        # the second kind of per-slot memory (a hybrid's recurrent
-        # state: KVCache.ssm / .conv), 0 for any other model
-        self._state_bytes = (
-            int(cache.ssm.nbytes + cache.conv.nbytes)
-            if cache.ssm is not None else 0
-        )
-        self._state_dtype = (
-            str(cache.ssm.dtype) if cache.ssm is not None else None
-        )
-        # the third: the window store of a stack that keeps its sliding
-        # layers' rows at window size (KVCache.wk / .wv), 0 for any
-        # other model; kv_bytes is then the full layers' rows alone
-        self._window_bytes = (
-            int(cache.wk.nbytes + cache.wv.nbytes)
-            if cache.wk is not None else 0
-        )
-        self._window_rows = cache.wk.shape[2] if cache.wk is not None else 0
+        # the three kinds of per-slot memory, as the cache counts them:
+        # rows a position; a recurrent state (0 and no dtype for a model
+        # without one); the window store of a stack that keeps its
+        # sliding layers' rows at window size (0 bytes and 0 rows a ring
+        # for any other; kv_bytes is then the full layers' rows alone)
+        memory = cache.memory()
+        self._kv_cache_bytes = memory["kv_bytes"]
+        self._state_bytes = memory["state_bytes"]
+        self._state_dtype = memory["state_dtype"]
+        self._window_bytes = memory["window_bytes"]
+        self._window_rows = memory["window_rows"]
         self._slots: Dict[int, _SlotInfo] = {}
         self._free = list(range(max_slots))
         self._waiting: "queue.Queue[GenRequest]" = queue.Queue()
@@ -1767,7 +1726,7 @@ class LLMEngine:
         else:
             self._step_mode = self._step_mode or "prefill"
             self._note_prefill(len(ids), bucket)
-            # a hybrid's prefill hands its recurrent state back too
+            # with what the slot keeps beside its rows, if anything
             last_logits, k, v, *mixer = self.runner.prefill(padded, len(ids))
         if kv_cache is not None:
             self._submit_kv_copy(ids, k, v, len(ids))

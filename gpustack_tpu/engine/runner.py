@@ -158,30 +158,18 @@ class ModelRunner:
         # seq-sharded over sp for the whole generation; prefill runs ring
         # attention, decode/verify the pmax/psum merge (ops/ring_attention).
         self.sp_mode = self.plan.sp > 1
-        # a hybrid (cfg.layer_kinds) keeps a recurrent state a slot
-        # beside its rows (KVCache.ssm / .conv)
-        self.hybrid = cfg.layer_kinds is not None
-        if self.hybrid and self.mesh.size > 1:
+        # a slot of rows a position and nothing else shards; what a
+        # model keeps beside them (``cfg.beside_rows``: KVCache holds it)
+        # does not yet
+        beside = cfg.beside_rows
+        if beside and self.mesh.size > 1:
             raise ValueError(
-                f"{cfg.name}: a model with state-space layers is served "
-                "on one device: its recurrent state and its kernels are "
-                "not sharded yet (tp/ep/dp), and 'ring' attention (sp>1) "
-                "shards a cache over positions, which a recurrent state "
-                f"has none of; got plan {self.plan}"
+                f"{cfg.name} {beside.keeps} and is served on one device: "
+                f"what a slot keeps beside its rows ({beside.lost}) and "
+                "the kernels that read it are not sharded yet (tp/ep/dp), "
+                "and 'ring' attention (sp>1) shards a cache over "
+                f"positions, which only rows have; got plan {self.plan}"
             )
-        # a stack that keeps its sliding layers' rows at window size has a
-        # second store a slot beside k and v (KVCache.wk / .wv)
-        self.windowed = cfg.window_rows
-        if self.windowed and self.mesh.size > 1:
-            raise ValueError(
-                f"{cfg.name}: a model with a window store is served on "
-                "one device: the ring of a sliding layer and the band in "
-                "the kernels are not sharded yet (tp/ep/dp/sp); got plan "
-                f"{self.plan}"
-            )
-        # what a prefill hands on beside k and v, and insert takes as
-        # ``mixer``: a hybrid's recurrent state, a window store's rows
-        self.keeps_beside_rows = self.hybrid or self.windowed
         if self.sp_mode:
             if cfg.is_mla:
                 raise ValueError(
@@ -277,7 +265,7 @@ class ModelRunner:
         # in a decode step ``kernel`` (ops/ssm.py, the stacked state in
         # place) or ``xla``; None without such layers
         self.ssm_scan = self.ssm_update = None
-        if self.hybrid:
+        if cfg.layers_of("M"):
             from gpustack_tpu.models.hybrid import ssm_update_impl
 
             platform = self.mesh.devices.flat[0].platform
@@ -317,16 +305,8 @@ class ModelRunner:
         return jax.device_put(
             state,
             DecodeState(
-                cache=KVCache(
-                    self._cache_sharding, self._cache_sharding,
-                    **(
-                        dict(ssm=self._replicated, conv=self._replicated)
-                        if self.hybrid else {}
-                    ),
-                    **(
-                        dict(wk=self._replicated, wv=self._replicated)
-                        if self.windowed else {}
-                    ),
+                cache=state.cache.shardings(
+                    self._cache_sharding, self._replicated
                 ),
                 last_tokens=self._slot_sharding,
                 positions=self._slot_sharding,
@@ -431,11 +411,12 @@ class ModelRunner:
         self, params, tokens, true_len, *, attn_impl="xla", routing=False
     ):
         """tokens [1, Tb]; returns (last_logits [V], k, v [L, Tb, heads,
-        width]); for a hybrid then ``(ssm, conv)``, the slot's recurrent
-        state after ``true_len`` tokens (not after the bucket: the
-        padding moves no state); under a share of the experts the count
-        of the router's pairs on held experts; with ``routing`` last of
-        all what ``forward(routing_out=True)`` adds."""
+        width]); for a model that keeps something a slot beside its rows
+        then that, after ``true_len`` tokens (``KVCache.slot_share``;
+        not after the bucket: the padding moves no state and writes no
+        ring row); under a share of the experts the count of the
+        router's pairs on held experts; with ``routing`` last of all
+        what ``forward(routing_out=True)`` adds."""
         Tb = tokens.shape[1]
         cache = KVCache.create(self.cfg, 1, Tb)
         positions = jnp.arange(Tb, dtype=jnp.int32)[None, :]
@@ -449,16 +430,13 @@ class ModelRunner:
             # the vocabulary head run on that row alone
             logits_at=(true_len - 1)[None],
             **(
-                {"true_len": true_len[None]} if self.keeps_beside_rows
+                {"true_len": true_len[None]} if self.cfg.beside_rows
                 else {}
             ),
         )
         last = logits[0, 0]
-        mixer = ()
-        if self.hybrid:
-            mixer = ((cache.ssm[:, 0], cache.conv[:, 0]),)
-        elif self.windowed:
-            mixer = ((cache.wk[:, 0], cache.wv[:, 0]),)
+        beside = cache.slot_share()
+        mixer = () if beside is None else (beside,)
         return (last, cache.k[:, 0], cache.v[:, 0], *mixer, *extras)
 
     def prefill(self, token_ids, true_len: int, routing: bool = False):
@@ -469,10 +447,10 @@ class ModelRunner:
         chosen experts and router logits (``forward``), returned last.
         For a comparison with a reference; the engine never asks.
 
-        A hybrid returns one more after ``k, v``: ``(ssm, conv)``, the
-        recurrent state the prompt ends in, for :meth:`insert`'s
-        ``mixer``; a stack with a window store likewise ``(wk, wv)``,
-        its sliding layers' rows."""
+        A model that keeps something a slot beside its rows
+        (``cfg.beside_rows``) returns one more after ``k, v``: what the
+        prompt leaves of it, for :meth:`insert`'s ``mixer`` and opaque
+        to whoever carries it there (``KVCache.slot_share``)."""
         Tb = len(token_ids)
         assert Tb in self.prefill_buckets, (Tb, self.prefill_buckets)
         fns = self._prefills_routing if routing else self._prefills
@@ -488,7 +466,7 @@ class ModelRunner:
             fns[Tb] = fn
         tokens = np.asarray(token_ids, np.int32)[None, :]
         last, k, v, *extras = fn(self.params, tokens, np.int32(true_len))
-        mixer = (extras.pop(0),) if self.keeps_beside_rows else ()
+        mixer = (extras.pop(0),) if self.cfg.beside_rows else ()
         if self.cfg.experts_held:
             self._note_pairs(extras[0], Tb)
         return (last, k, v, *mixer, *((extras[-1],) if routing else ()))
@@ -573,17 +551,10 @@ class ModelRunner:
         suffix_ids, suffix_true_len: int, total_bucket: int,
     ):
         """suffix_ids must be pre-padded to a prefill bucket."""
-        if self.windowed:
+        if self.cfg.beside_rows:
             raise ValueError(
                 f"{self.cfg.name}: a prefill cannot go on from cached rows "
-                "(prefix reuse, chunked prefill): a span's sliding rows "
-                "are gone once the window has passed it"
-            )
-        if self.hybrid:
-            raise ValueError(
-                f"{self.cfg.name}: a prefill cannot go on from cached rows "
-                "(prefix reuse, chunked prefill): the rows carry no "
-                "recurrent state to go on from"
+                f"(prefix reuse, chunked prefill): {self.cfg.beside_rows.span}"
             )
         Pb = prefix_k.shape[1]
         Tsb = len(suffix_ids)
@@ -672,31 +643,8 @@ class ModelRunner:
         temperature, top_k, top_p, seed, seeded, bias_ids, bias_vals,
         mixer=None,
     ):
-        Tb = k.shape[1]
-        cache = state.cache
-        new_k = cache.k.at[:, slot, :Tb].set(k)
-        new_v = cache.v.at[:, slot, :Tb].set(v)
-        held = {}
-        if self.hybrid:
-            # the slot's whole recurrent state is the prompt's, nothing
-            # of its last tenant's stays (zeros without one)
-            ssm, conv = mixer if mixer is not None else (0.0, 0.0)
-            held = dict(
-                ssm=cache.ssm.at[:, slot].set(ssm),
-                conv=cache.conv.at[:, slot].set(conv),
-            )
-        if self.windowed:
-            # the prefill's ring rows lie where the slot's ring wants
-            # them (row = position mod W; a bucket under the window is
-            # its own first rows); what the slot's last tenant left
-            # above them is overwritten before a length reaches it
-            wk, wv = mixer
-            held = dict(
-                wk=cache.wk.at[:, slot, :wk.shape[1]].set(wk),
-                wv=cache.wv.at[:, slot, :wv.shape[1]].set(wv),
-            )
         return DecodeState(
-            cache=KVCache(k=new_k, v=new_v, **held),
+            cache=state.cache.with_slot(slot, k, v, mixer),
             # ``first_token`` is the [1] that the first-token program
             # returned: indexed here, not by a program of its own
             last_tokens=state.last_tokens.at[slot].set(first_token[0]),
@@ -714,12 +662,12 @@ class ModelRunner:
         seed: int = 0, seeded: bool = False, logit_bias=None,
         mixer=None,
     ) -> DecodeState:
-        """Place a prefill's rows, and for a hybrid its recurrent state
-        or for a stack with a window store its sliding layers' rows
-        (``mixer``: what :meth:`prefill` returned after ``k, v``), in
-        ``slot`` and make the slot live. ``first_token``: an int, or
-        the ``[1]`` array of tokens :meth:`sample_first` returned,
-        still on the device."""
+        """Place a prefill's rows, and what it left beside them
+        (``mixer``: what :meth:`prefill` returned after ``k, v``, for a
+        model that keeps such a thing; ``KVCache.with_slot`` knows what
+        it is), in ``slot`` and make the slot live. ``first_token``: an
+        int, or the ``[1]`` array of tokens :meth:`sample_first`
+        returned, still on the device."""
         Tb = k.shape[1]
         fn = self._inserts.get(Tb)
         if fn is None:
@@ -733,8 +681,7 @@ class ModelRunner:
             first_token, np.float32(temperature),
             np.int32(top_k), np.float32(top_p),
             np.uint32(seed), np.bool_(seeded),
-            bias_ids, bias_vals,
-            *((mixer,) if self.keeps_beside_rows else ()),
+            bias_ids, bias_vals, mixer,
         )
 
     def deactivate(self, state: DecodeState, slot: int) -> DecodeState:
@@ -898,8 +845,8 @@ class ModelRunner:
             params, self.cfg, fed, positions, state.cache,
             attn_impl="ring" if self.sp_mode else "xla",
             mesh=self.mesh,
-            # a hybrid's recurrent state takes the tokens that count
-            **({"true_len": counts} if self.hybrid else {}),
+            # a recurrent state takes the tokens that count
+            **({"true_len": counts} if self.cfg.layers_of("M") else {}),
         )
         has_any = counts > 0
         last_idx = jnp.maximum(counts - 1, 0)
@@ -935,21 +882,18 @@ class ModelRunner:
         speculative proposal run to rewind the draft's sequence state
         (cache entries above the restored positions are masked out).
         COPIES: the decode steps in between donate the state, which would
-        invalidate aliased buffers. A hybrid's recurrent state cannot be
-        masked out: every slot's is copied too and put back whole."""
-        snap = jnp.array(state.positions), jnp.array(state.last_tokens)
-        if self.hybrid:
-            snap += (jnp.array(state.cache.ssm), jnp.array(state.cache.conv))
-        return snap
+        invalidate aliased buffers. What of a slot cannot be masked out
+        is copied too and put back whole (``KVCache.unmaskable``)."""
+        return (
+            jnp.array(state.positions), jnp.array(state.last_tokens),
+            *state.cache.unmaskable(),
+        )
 
     def restore_sequence(self, state: DecodeState, snap) -> DecodeState:
-        positions, last_tokens, *mixer = snap
-        if mixer:
-            state = dataclasses.replace(state, cache=dataclasses.replace(
-                state.cache, ssm=mixer[0], conv=mixer[1]
-            ))
+        positions, last_tokens, *unmaskable = snap
         return dataclasses.replace(
-            state, positions=positions, last_tokens=last_tokens
+            state, positions=positions, last_tokens=last_tokens,
+            cache=state.cache.with_unmaskable(unmaskable),
         )
 
     # -- speculative decoding (greedy n-gram verify) ----------------------
@@ -970,15 +914,10 @@ class ModelRunner:
         ``position + P < max_seq_len`` (the engine falls back to plain
         decode near capacity) — the block KV write is contiguous.
         """
-        if self.hybrid:
+        if self.cfg.beside_rows:
             raise ValueError(
-                f"{self.cfg.name}: a verify step cannot roll a recurrent "
-                "state back past a rejected draft"
-            )
-        if self.windowed:
-            raise ValueError(
-                f"{self.cfg.name}: a verify step cannot roll back rows a "
-                "ring has overwritten"
+                f"{self.cfg.name}: a verify step cannot roll "
+                f"{self.cfg.beside_rows.lost} back past a rejected draft"
             )
         B, P = proposals.shape
         tokens = jnp.concatenate(

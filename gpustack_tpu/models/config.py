@@ -12,7 +12,18 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
+
+
+class BesideRows(NamedTuple):
+    """What a model keeps a slot beside the rows it keeps a position
+    (:attr:`ModelConfig.beside_rows`), in the phrases a refusal needs:
+    the engine and the runner refuse by these whatever cuts, stores,
+    moves or rolls back a slot by its positions, and name no kind."""
+
+    keeps: str   # "<model> <keeps>"
+    lost: str    # what a span of positions does not bring with it
+    span: str    # a sentence: why a span of cached rows is not enough
 
 
 @dataclasses.dataclass(frozen=True)
@@ -249,6 +260,31 @@ class ModelConfig:
         )
 
     @property
+    def beside_rows(self) -> Optional[BesideRows]:
+        """None for a model whose slot is rows a position and nothing
+        else (``kv_row_shapes``): any span of a slot's positions can
+        then be cut out, stored, moved, gone on from or rolled back.
+        Else what the slot keeps beside them (``KVCache`` holds it:
+        a state-space layer's recurrent state, a sliding layer's ring of
+        window rows). Such a model is served on one device, and the
+        prefix cache, the spill tier, a KV handoff, a verify step and a
+        chunked prefill are refused for it (``engine/engine.py
+        _refuse_what_moves_a_slot``, ``engine/runner.py``)."""
+        if self.layers_of("M"):
+            return BesideRows(
+                "has state-space layers", "a recurrent state",
+                "the rows carry no recurrent state to go on from",
+            )
+        if self.window_rows:
+            return BesideRows(
+                "keeps its sliding layers' rows at window size",
+                "rows a ring has overwritten",
+                "a span's sliding rows are gone once the window has "
+                "passed it",
+            )
+        return None
+
+    @property
     def attention_type(self) -> str:
         if self.num_kv_heads == 1:
             return "MQA"
@@ -401,6 +437,14 @@ class ModelConfig:
             + (self.conv_kernel - 1) * self.mamba_conv_dim * bits // 8
         )
         return self.layers_of("M") * per_layer
+
+    def beside_bytes_per_slot(self, max_len: int, bits: int = 16) -> int:
+        """Bytes a slot of ``max_len`` positions keeps beside the rows of
+        ``kv_cache_bytes_per_token``, whatever its length: 0 for a model
+        without :attr:`beside_rows`."""
+        return self.state_bytes_per_slot(bits) + self.window_bytes_per_slot(
+            max_len, bits
+        )
 
 
 # The families ``config_from_hf`` reads, by a substring of the file's

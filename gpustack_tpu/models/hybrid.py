@@ -23,8 +23,8 @@ the three rows to a tile of sixteen).
 
 from __future__ import annotations
 
+import dataclasses
 import math
-from functools import partial
 from typing import Any, Dict, Optional
 
 import jax
@@ -213,10 +213,6 @@ def forward_hybrid(
             "attn_impl='ring': a cache sharded over its positions cannot "
             "carry a recurrent state; serve this model with sp=1"
         )
-    use_flash = (
-        attn_impl in ("flash", "flash_interpret")
-        and cache is not None and T > 1 and cache.max_len >= T
-    )
     walk = None
     if cache is not None and decode_attn_impl != "xla":
         from gpustack_tpu.ops.decode_attention import gqa_walk
@@ -384,45 +380,13 @@ def forward_hybrid(
         if carried is None:
             attn = tf._attend(q, k, v, mask, scale)
         else:
-            start = positions[:, 0]
-            carried = type(carried)(
-                k=tf._write_rows(carried.k, k, i, start),
-                v=tf._write_rows(carried.v, v, i, start),
-                ssm=carried.ssm, conv=carried.conv,
+            attn, new_k, new_v = tf.attend_over_cache(
+                q, k, v, carried.k, carried.v, i, positions[:, 0],
+                positions=positions, mask=mask, scale=scale,
+                decode_attn_impl=decode_attn_impl, walk=walk,
+                attn_impl=attn_impl, mesh=mesh,
             )
-            if decode_attn_impl != "xla":
-                from gpustack_tpu.ops.decode_attention import (
-                    gqa_decode_attention,
-                )
-
-                attn = gqa_decode_attention(
-                    q.reshape(B, cfg.num_heads, cfg.head_dim),
-                    carried.k, carried.v, i, walk, scale,
-                    interpret=decode_attn_impl == "kernel_interpret",
-                )[:, None]
-            else:
-                all_k, all_v = (
-                    lax.dynamic_index_in_dim(buf, i, 0, keepdims=False)
-                    for buf in (carried.k, carried.v)
-                )
-                if use_flash:
-                    from gpustack_tpu.ops.flash_attention import (
-                        flash_attention_prefill,
-                        sharded_flash_attention_prefill,
-                    )
-
-                    flash = (
-                        flash_attention_prefill if mesh is None
-                        else partial(sharded_flash_attention_prefill, mesh)
-                    )
-                    attn = flash(
-                        q.reshape(B, T, cfg.num_heads, cfg.head_dim),
-                        all_k, all_v, scale,
-                        interpret=attn_impl == "flash_interpret",
-                        q_offset=positions[0, 0],
-                    )
-                else:
-                    attn = tf._attend(q, all_k, all_v, mask, scale)
+            carried = dataclasses.replace(carried, k=new_k, v=new_v)
         return tf._mm("btq,qd->btd", attn.reshape(B, T, -1), lp["wo"]), carried
 
     # One function a kind of layer, traced once: the layers are visited in

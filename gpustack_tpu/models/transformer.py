@@ -131,31 +131,24 @@ class KVCache:
 
     k: jax.Array
     v: jax.Array
-    # a hybrid's second kind of per-slot memory (models/hybrid.py), None
-    # for every other model: each state-space layer's recurrent state
-    # ``[L_M, B, H, P, N]`` (float32) and the last ``conv_kernel - 1``
-    # rows of its convolution's input, side by side, ``[L_M, B, (K-1) *
-    # C]``. Not rows a position: a slot has one of each whatever its
-    # length, a prefill ends in one and a decode step moves it on, so
-    # nothing that cuts, stores or reuses a span of positions (a prefix
-    # block, a spilled or transferred run, a rolled-back draft) can
-    # carry it, and the engine refuses those for such a model. ``k`` and
-    # ``v`` then have the attention layers only (``cfg.num_kv_layers``).
+    # What a slot keeps beside its rows, None for a model without it;
+    # ``k, v`` then hold the layers of ``cfg.num_kv_layers`` only. No
+    # span of positions carries either: ``ModelConfig.beside_rows`` says
+    # what follows, the methods below are what a holder of a cache calls
+    # (docs/KV_CACHE.md, "What a slot keeps").
+    # A state (models/hybrid.py): each state-space layer's recurrent
+    # state ``[L_M, B, H, P, N]`` (float32) and the last ``conv_kernel -
+    # 1`` rows of its convolution's input, side by side, ``[L_M, B, (K-1)
+    # * C]``: one a slot whatever its length, a prefill ends in one and a
+    # decode step moves it on.
     ssm: Optional[jax.Array] = None
     conv: Optional[jax.Array] = None
-    # the window store of a stack that keeps its sliding layers' rows at
-    # window size (``cfg.window_rows``), None for every other model:
-    # ``wk, wv [L_sliding, B, W, heads, width]``, ``W =
-    # min(sliding_window, S_max)`` rows a slot, a ring: position ``p``
-    # lies in row ``p mod W``, so once a slot is longer than the window
-    # a row holds the newest position of its residue and the ring the
-    # last ``W`` positions, which is all a sliding layer attends. Keys
-    # are stored after their rotation, so the order of a ring's rows
-    # means nothing to the softmax. ``k, v`` then hold the full layers
-    # only (``cfg.num_kv_layers``). A span of positions cut out of a
-    # slot (a prefix block, a spilled or transferred run, a rolled-back
-    # draft, a chunk to go on from) has lost the sliding rows the ring
-    # has overwritten: the engine refuses those for such a model.
+    # A ring (``cfg.window_rows``): the sliding layers' rows, ``wk, wv
+    # [L_sliding, B, W, heads, width]``, ``W = min(sliding_window,
+    # S_max)``: position ``p`` lies in row ``p mod W``, so a slot longer
+    # than the window holds the last ``W`` positions, all a sliding layer
+    # attends. Keys are stored rotated, so the rows' order means nothing
+    # to the softmax.
     wk: Optional[jax.Array] = None
     wv: Optional[jax.Array] = None
 
@@ -201,6 +194,78 @@ class KVCache:
             k=jnp.zeros(lead + k_row, dtype), v=jnp.zeros(lead + v_row, dtype),
             **state,
         )
+
+    def slot_share(self):
+        """Of a one-slot cache (a prefill's), what slot 0 keeps beside
+        ``k, v``, for :meth:`with_slot`'s ``beside``; None for rows alone."""
+        if self.ssm is not None:
+            return self.ssm[:, 0], self.conv[:, 0]
+        if self.wk is not None:
+            return self.wk[:, 0], self.wv[:, 0]
+        return None
+
+    def with_slot(self, slot, k, v, beside=None) -> "KVCache":
+        """A prefill's rows ``k, v [L, Tb, heads, width]`` in the first
+        ``Tb`` positions of ``slot``, and its :meth:`slot_share`."""
+        Tb = k.shape[1]
+        new = dict(
+            k=self.k.at[:, slot, :Tb].set(k), v=self.v.at[:, slot, :Tb].set(v)
+        )
+        if self.ssm is not None:
+            # the slot's whole state is the prompt's, nothing of its last
+            # tenant's stays (zeros without one)
+            ssm, conv = beside if beside is not None else (0.0, 0.0)
+            new.update(
+                ssm=self.ssm.at[:, slot].set(ssm),
+                conv=self.conv.at[:, slot].set(conv),
+            )
+        if self.wk is not None:
+            # the prefill's ring rows lie where the slot's ring wants
+            # them (row = position mod W; a bucket under the window is
+            # its own first rows); what the last tenant left above them
+            # is overwritten before a length reaches it
+            wk, wv = beside
+            new.update(
+                wk=self.wk.at[:, slot, :wk.shape[1]].set(wk),
+                wv=self.wv.at[:, slot, :wv.shape[1]].set(wv),
+            )
+        return KVCache(**new)
+
+    def unmaskable(self) -> Tuple[jax.Array, ...]:
+        """Copies of what a rollback by position cannot mask out, for
+        :meth:`with_unmaskable` to put back whole: a state. Not a ring:
+        a model with one is refused speculation at engine start, nobody
+        snapshots it, and ``wk, wv`` are copied for nobody."""
+        if self.ssm is None:
+            return ()
+        return jnp.array(self.ssm), jnp.array(self.conv)
+
+    def with_unmaskable(self, saved) -> "KVCache":
+        if not saved:
+            return self
+        return dataclasses.replace(self, ssm=saved[0], conv=saved[1])
+
+    def shardings(self, rows, beside) -> "KVCache":
+        """What to ``jax.device_put`` this cache with: ``rows`` for ``k,
+        v``, ``beside`` for whatever else it keeps."""
+        return dataclasses.replace(
+            jax.tree.map(lambda _: beside, self), k=rows, v=rows
+        )
+
+    def memory(self) -> Dict[str, Any]:
+        """Bytes by kind, a state's dtype and the rows of a slot's ring
+        (None, 0 without one): ``/healthz`` ``cache`` and the exporter."""
+
+        def nbytes(*bufs):
+            return sum(int(b.nbytes) for b in bufs if b is not None)
+
+        return {
+            "kv_bytes": nbytes(self.k, self.v),
+            "state_bytes": nbytes(self.ssm, self.conv),
+            "state_dtype": None if self.ssm is None else str(self.ssm.dtype),
+            "window_bytes": nbytes(self.wk, self.wv),
+            "window_rows": 0 if self.wk is None else self.wk.shape[2],
+        }
 
 
 _ROW_WRITE = lax.ScatterDimensionNumbers(
@@ -705,6 +770,104 @@ def _attend(
     out = jnp.einsum("bhgts,bshd->bthgd", weights, v)
     b, t = out.shape[0], out.shape[1]
     return out.reshape(b, t, -1)
+
+
+def _flash_prefill(mesh, attn_impl):
+    """The flash prefill kernel, to call with ``(q [B, T, H, hd], k, v,
+    scale, q_offset=, [window=])``: on a mesh of several devices a shard
+    of heads a device. Handed back and not called here, and on one
+    device without the wrapper that shards: a Python frame between a
+    program's ``jit`` and the trace of a kernel's body costs that trace
+    some 50 ms (0.3 s of a start a prefill program: PERF.md, PR 52)."""
+    from gpustack_tpu.ops.flash_attention import (
+        flash_attention_prefill,
+        sharded_flash_attention_prefill,
+    )
+
+    flash = (
+        flash_attention_prefill if mesh is None or mesh.size == 1
+        else partial(sharded_flash_attention_prefill, mesh)
+    )
+    return partial(flash, interpret=attn_impl == "flash_interpret")
+
+
+def attend_over_cache(
+    q, k, v,             # the step's [B, T, heads, hd], normed and rotated
+    buf_k, buf_v,        # [L, B, S, Hkv, hd]: the layer's store in the cache
+    index, start,        # the layer's place in it; [B] where a row's keys go
+    *, positions, mask, scale, decode_attn_impl, walk=None,
+    attn_impl="xla", mesh=None, softcap=0.0, sinks=None, name=None,
+):
+    """One GQA layer over its store in a cache, from where the families
+    agree: the step's rows are written at ``start``, then the step
+    attends: ``(attn [B, T, H * hd], buf_k, buf_v)``. By the decode
+    kernel over the store where it lies as far as ``walk`` says
+    (``decode_attn_impl`` not ``"xla"``; ``name``: the call's in a
+    trace), else over the layer's rows by ``attn_impl``: ``"ring"``
+    (``sp``: a cache sharded over its positions), the flash kernel for
+    several rows a slot over a cache that holds them, or ``_attend``
+    under ``mask [B, T, S]``. Projection, biases, norms and rotation are
+    the caller's.
+
+    ``q`` has its heads flat or grouped by kv head, as its caller's
+    family left them: each path reshapes to what it takes, a no-op where
+    the caller had it so. The decode kernel's ``[B, H, hd]`` has two
+    spellings, one value and two lowered texts, and each caller's
+    serving program is held to the text it had
+    (``tests/ops/lowered_programs.py``; ROADMAP C13)."""
+    B, T, Hkv, hd = k.shape
+    S = buf_k.shape[2]
+    ring = attn_impl == "ring"
+    write = partial(
+        _write_rows, layer=index, start=start, by_position=ring and T > 1
+    )
+    buf_k, buf_v = write(buf_k, k), write(buf_v, v)
+    if decode_attn_impl != "xla":
+        # a decode step on one chip: the kernel reads the layer's rows
+        # where they lie and no slab is taken out of the carry
+        from gpustack_tpu.ops.decode_attention import gqa_decode_attention
+
+        attn = gqa_decode_attention(
+            q[:, 0] if q.ndim == 4 else q.reshape(B, -1, hd),
+            buf_k, buf_v, index, walk, scale,
+            interpret=decode_attn_impl == "kernel_interpret",
+            **({"name": name} if name else {}),
+        )[:, None]
+        return attn, buf_k, buf_v
+    all_k, all_v = (
+        lax.dynamic_index_in_dim(buf, index, 0, keepdims=False)
+        for buf in (buf_k, buf_v)
+    )
+    grouped = q.reshape(B, T, Hkv, -1, hd)
+    if ring:
+        from gpustack_tpu.ops.ring_attention import (
+            sharded_prefill_attention,
+            sp_cache_attention,
+        )
+
+        if T > 1 and S == T:
+            # from position 0: the step's rows are the whole cache
+            attn = sharded_prefill_attention(
+                mesh, grouped, k, v, positions, scale
+            )
+        else:
+            # decode / verify: exact over the sp-sharded resident cache
+            attn = sp_cache_attention(
+                mesh, grouped, all_k, all_v, positions, scale
+            )
+    elif attn_impl in ("flash", "flash_interpret") and T > 1 and S >= T:
+        # from zero or from a chunk's or prefix's offset, against the
+        # freshly written cache: pad keys are masked via seq_k, rows
+        # above the last query's position are causally invisible
+        attn = _flash_prefill(mesh, attn_impl)(
+            q.reshape(B, T, -1, hd), all_k, all_v, scale,
+            q_offset=positions[0, 0],
+        )
+    else:
+        attn = _attend(
+            grouped, all_k, all_v, mask, scale, softcap, sinks=sinks
+        )
+    return attn, buf_k, buf_v
 
 
 def _kept_groups(sel: jax.Array, cfg: ModelConfig) -> jax.Array:
@@ -1373,6 +1536,7 @@ def forward(
         decode_attn_impl = decode_attention_impl(
             cfg, T, cache.max_len, platform, mesh
         )
+    walk = walk_w = None
     if cache is not None and decode_attn_impl != "xla":
         lengths = positions[:, 0] + 1
         if live is not None:
@@ -1598,23 +1762,9 @@ def forward(
             )
             q = jnp.concatenate([q_nope, q_pe], axis=-1)
             if use_flash:
-                from gpustack_tpu.ops.flash_attention import (
-                    flash_attention_prefill,
-                    sharded_flash_attention_prefill,
+                attn = _flash_prefill(mesh, attn_impl)(
+                    q, k, v, scale, q_offset=positions[0, 0]
                 )
-
-                flash_kw = dict(
-                    interpret=attn_impl == "flash_interpret",
-                    q_offset=positions[0, 0],
-                )
-                if mesh is not None:
-                    attn = sharded_flash_attention_prefill(
-                        mesh, q, k, v, scale, **flash_kw
-                    )
-                else:
-                    attn = flash_attention_prefill(
-                        q, k, v, scale, **flash_kw
-                    )
             else:
                 attn = _attend(q[:, :, :, None, :], k, v, mask_l, scale)
             return attn, carried
@@ -1700,34 +1850,19 @@ def forward(
         )
         rows = buf_k.shape[2]
         if T == 1:
-            start = positions[:, 0] % rows if sliding else positions[:, 0]
-            buf_k = _write_rows(buf_k, k, store, start)
-            buf_v = _write_rows(buf_v, v, store, start)
-            if decode_attn_impl != "xla":
-                from gpustack_tpu.ops.decode_attention import (
-                    gqa_decode_attention,
-                )
-
-                attn = gqa_decode_attention(
-                    q[:, 0], buf_k, buf_v, store,
-                    walk_w if sliding else walk, scale,
-                    interpret=decode_attn_impl == "kernel_interpret",
-                    **(
-                        {"name": "gqa_window_decode_attention"}
-                        if sliding else {}
-                    ),
-                )[:, None]
-            else:
-                all_k, all_v = (
-                    lax.dynamic_index_in_dim(buf, store, 0, keepdims=False)
-                    for buf in (buf_k, buf_v)
-                )
-                # a ring's live rows are its first min(length, W)
-                live_rows = mask_full if not sliding else (
-                    jnp.arange(rows, dtype=jnp.int32)[None, None, :]
-                    < jnp.minimum(positions + 1, rows)[:, :, None]
-                )
-                attn = _attend(grouped, all_k, all_v, live_rows, scale)
+            # a ring's live rows are its first min(length, W)
+            live_rows = mask_full if not sliding else (
+                jnp.arange(rows, dtype=jnp.int32)[None, None, :]
+                < jnp.minimum(positions + 1, rows)[:, :, None]
+            )
+            attn, buf_k, buf_v = attend_over_cache(
+                q, k, v, buf_k, buf_v, store,
+                positions[:, 0] % rows if sliding else positions[:, 0],
+                positions=positions, mask=live_rows, scale=scale,
+                decode_attn_impl=decode_attn_impl,
+                walk=walk_w if sliding else walk,
+                name="gqa_window_decode_attention" if sliding else None,
+            )
         else:
             if sliding:
                 # ring row r takes position r + W * ((n - 1 - r) // W),
@@ -1754,15 +1889,9 @@ def forward(
                 for buf, new in zip((buf_k, buf_v), kept)
             )
             if use_flash:
-                from gpustack_tpu.ops.flash_attention import (
-                    flash_attention_prefill,
-                )
-
                 band = cfg.sliding_window if sliding else 0
-                attn = flash_attention_prefill(
-                    q, k, v, scale,
-                    interpret=attn_impl == "flash_interpret",
-                    q_offset=positions[0, 0],
+                attn = _flash_prefill(None, attn_impl)(
+                    q, k, v, scale, q_offset=positions[0, 0],
                     window=band if band < T else 0,
                 )
             else:
@@ -1826,87 +1955,14 @@ def forward(
                     sinks=sinks_l,
                 )
             else:
-                # Write this step's rows into the carried cache, in place,
-                # and attend over this layer of it.
-                write = partial(
-                    _write_rows, layer=layer, start=positions[:, 0],
-                    by_position=use_ring and T > 1,
+                attn, new_k, new_v = attend_over_cache(
+                    q, k, v, carried.k, carried.v, layer, positions[:, 0],
+                    positions=positions, mask=mask_l, scale=scale,
+                    decode_attn_impl=decode_attn_impl, walk=walk,
+                    attn_impl=attn_impl, mesh=mesh,
+                    softcap=cfg.attn_logit_softcap, sinks=sinks_l,
                 )
-                carried = KVCache(
-                    k=write(carried.k, k), v=write(carried.v, v)
-                )
-                if decode_attn_impl != "xla":
-                    # a decode step on one chip: the kernel reads the
-                    # layer's rows where they lie and no slab is taken
-                    # out of the carry
-                    from gpustack_tpu.ops.decode_attention import (
-                        gqa_decode_attention,
-                    )
-
-                    attn = gqa_decode_attention(
-                        q.reshape(B, cfg.num_heads, cfg.head_dim),
-                        carried.k, carried.v, layer, walk, scale,
-                        interpret=decode_attn_impl == "kernel_interpret",
-                    )[:, None]
-                else:
-                    new_k, new_v = (
-                        lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
-                        for buf in (carried.k, carried.v)
-                    )
-                    if use_ring:
-                        from gpustack_tpu.ops.ring_attention import (
-                            sharded_prefill_attention,
-                            sp_cache_attention,
-                        )
-
-                        if T > 1 and cache.max_len == T:
-                            # prefill-from-zero: ring attention over the
-                            # sp-sharded step K/V (== the whole written cache)
-                            attn = sharded_prefill_attention(
-                                mesh, q, k, v, positions, scale
-                            )
-                        else:
-                            # decode / verify: exact attention over the
-                            # sp-sharded resident cache
-                            attn = sp_cache_attention(
-                                mesh, q, new_k, new_v, positions, scale
-                            )
-                    elif use_flash:
-                        # prefill (from zero or from a chunk/prefix offset):
-                        # q rows sit at positions offset..offset+T-1 against the
-                        # freshly written cache; the kernel's q_offset shifts the
-                        # causal diagonal (all batch rows share one offset — the
-                        # engine's prefill paths are B=1; pad keys masked via
-                        # seq_k, pad/garbage cache rows above the last query
-                        # position are causally invisible)
-                        from gpustack_tpu.ops.flash_attention import (
-                            flash_attention_prefill,
-                            sharded_flash_attention_prefill,
-                        )
-
-                        flash_args = (
-                            q.reshape(B, T, cfg.num_heads, cfg.head_dim),
-                            new_k,
-                            new_v,
-                            scale,
-                        )
-                        flash_kw = dict(
-                            interpret=attn_impl == "flash_interpret",
-                            q_offset=positions[0, 0],
-                        )
-                        if mesh is not None:
-                            # under tp the kernel runs per shard of heads
-                            attn = sharded_flash_attention_prefill(
-                                mesh, *flash_args, **flash_kw
-                            )
-                        else:
-                            attn = flash_attention_prefill(*flash_args, **flash_kw)
-                    else:
-                        attn = _attend(
-                            q, new_k, new_v, mask_l, scale,
-                            cfg.attn_logit_softcap,
-                            sinks=sinks_l,
-                        )
+                carried = dataclasses.replace(carried, k=new_k, v=new_v)
 
         attn_out = _mm("btq,qd->btd", attn, lp["wo"])
         if cfg.o_bias:

@@ -260,17 +260,13 @@ def evaluate_model(model: Model, role: str = "") -> ModelEvaluation:
         * model.max_seq_len
         * kv_slots
     )
-    # a model with state-space layers keeps a recurrent state a slot
-    # beside the rows of its attention layers, whatever the context
-    state_bytes = getattr(cfg, "state_bytes_per_slot", None)
-    if state_bytes is not None:
-        kv_bytes += state_bytes(kv_bits) * kv_slots
-    # a stack that keeps its sliding layers' rows at window size: those
-    # layers are not among kv_cache_bytes_per_token's, and a slot holds
-    # min(window, context) rows of each whatever its length
-    window_bytes = getattr(cfg, "window_bytes_per_slot", None)
-    if window_bytes is not None:
-        kv_bytes += window_bytes(model.max_seq_len, kv_bits) * kv_slots
+    # what a slot keeps beside the rows of kv_cache_bytes_per_token's
+    # layers, whatever its length (a recurrent state; min(window,
+    # context) rows of every sliding layer whose rows are kept at window
+    # size); the audio configurations have no such method
+    beside_bytes = getattr(cfg, "beside_bytes_per_slot", None)
+    if beside_bytes is not None:
+        kv_bytes += beside_bytes(model.max_seq_len, kv_bits) * kv_slots
     # activation + runtime overhead: prefill attention scratch dominates;
     # scale with seq len, floor at 256 MiB (audio configs use d_model)
     hidden = getattr(cfg, "hidden_size", 0) or cfg.d_model
@@ -370,12 +366,10 @@ def chips_for_claim(
 
     start = explicit_chips or 1
     chips = max(1, start)
-    if getattr(cfg, "layer_kinds", None) is not None or getattr(
-        cfg, "window_rows", False
-    ):
-        # a model with state-space layers, or with a window store, is
-        # served on one device (engine/runner.py): more chips than one
-        # hold nothing of it
+    if getattr(cfg, "beside_rows", None):
+        # a model that keeps something a slot beside its rows is served
+        # on one device (engine/runner.py): more chips than one hold
+        # nothing of it
         max_chips = min(max_chips, 1)
     while chips <= max_chips:
         if (

@@ -1,4 +1,4 @@
-"""The decode and prefill programs of the benchmark's other
+"""The decode and prefill programs of the benchmark's
 configurations, lowered for a described v5e chip, as text: what
 ``test_chip_compile.py::test_the_other_models_programs_lower_to_the_text_
 they_had`` holds to ``lowered_programs.json``.
@@ -42,6 +42,8 @@ PROGRAMS = (
     ("ax-k1-int8-ep16-l12", "ax-k1-int8-ep16-l12", 0, 16, 8192, 4096),
     ("nemotron-3-nano-30b-a3b-int8-ep8",
      "nemotron-3-nano-30b-a3b-int8-ep8", 0, 32, 4096, 1024),
+    ("command-a-plus-int8-ep8-l8", "command-a-plus-int8-ep8-l8",
+     0, 16, 8192, 4096),
 )
 
 
@@ -103,8 +105,10 @@ def lowered(one_chip) -> dict:
                 ) if experts else None,
                 count_held_pairs=bool(cfg.experts_held),
                 logits_at=(true_len - 1)[None],
-                **({"true_len": true_len[None], "ssm_impl": "scan"}
-                   if hybrid else {}),
+                # as ``ModelRunner._prefill_impl`` tells any model that
+                # keeps something a slot beside its rows
+                **({"true_len": true_len[None]} if cfg.beside_rows else {}),
+                **({"ssm_impl": "scan"} if hybrid else {}),
             )
 
         def ints(*shape):

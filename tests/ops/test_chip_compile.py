@@ -312,8 +312,7 @@ def test_a_prefill_computes_the_head_for_the_row_it_keeps(
     # described chip (so ``forward``'s choosers see a TPU)
     runner = types.SimpleNamespace(
         cfg=cfg, mesh=make_mesh(MeshPlan(), [topo.devices[0]]),
-        sp_mode=False, hybrid=False, windowed=False,
-        keeps_beside_rows=False, max_seq_len=MAX_LEN,
+        sp_mode=False, max_seq_len=MAX_LEN,
     )
     params = _shapes_on(
         one_chip, lambda: quantize_params(init_params(cfg, jax.random.key(0)))
@@ -1127,9 +1126,11 @@ def _lowered_names():
 def test_the_other_models_programs_lower_to_the_text_they_had(
     lowered_hashes, program
 ):
-    """The decode and prefill programs of the benchmark's four other
+    """The decode and prefill programs of the benchmark's five
     configurations, lowered for the chip at their cells' shapes, are to
-    the letter what they were before the window store, the band and the
-    parallel block went into ``forward`` (``lowered_programs.py`` says
-    what is hashed and how to take the hashes again on purpose)."""
+    the letter what they were when their hashes were taken: the first
+    four before the window store, the band and the parallel block went
+    into ``forward``, Command A+'s before the three copies of a GQA
+    layer over a cache became one (``lowered_programs.py`` says what is
+    hashed and how to take the hashes again on purpose)."""
     assert lowered_hashes[program] == _lowered_names()[program]
