@@ -931,10 +931,12 @@ class LLMEngine:
                 # full layers' rows
                 "window_bytes": self._window_bytes,
             },
-            # how a state-space layer runs its scan over a prompt and
-            # moves its state in a decode step (runner.ssm_update:
-            # "kernel" the stacked state in place, live slots only);
-            # None for a model without such layers
+            # how a layer that keeps a recurrent state (cfg.state_mixer:
+            # "ssm" Mamba-2, "delta" gated delta rule) runs its scan
+            # over a prompt and moves its state in a decode step
+            # (runner.ssm_update: "kernel" the stacked state in place,
+            # live slots only); None for a model without such layers
+            "state_mixer": self.cfg.state_mixer,
             "ssm_scan": self.runner.ssm_scan,
             "ssm_update": self.runner.ssm_update,
             # under a share of the experts: the prefill programs' router
@@ -1252,7 +1254,8 @@ class LLMEngine:
             moe_read=self._step_moe_read,
             moe_held=self._step_moe_held,
             ssm=(
-                (self._step_state_slots, self._step_ssm_tokens)
+                (self._step_state_slots, self._step_ssm_tokens,
+                 self.cfg.state_mixer)
                 if self._state_bytes else None
             ),
             attn_rows=(

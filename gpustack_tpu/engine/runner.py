@@ -260,20 +260,24 @@ class ModelRunner:
         )
         if self.decode_moe_dispatch:
             logger.info("decode experts: %s", self.decode_moe_dispatch)
-        # how a state-space layer moves its state, the form strings of
+        # how a layer that keeps a recurrent state (state-space or
+        # delta-rule: cfg.state_mixer) moves it, the form strings of
         # /healthz and the flight record: the chunked scan in a prefill,
-        # in a decode step ``kernel`` (ops/ssm.py, the stacked state in
-        # place) or ``xla``; None without such layers
+        # in a decode step ``kernel`` (ops/ssm.py, ops/delta_rule.py:
+        # the stacked state in place) or ``xla``; None without such
+        # layers
         self.ssm_scan = self.ssm_update = None
-        if cfg.layers_of("M"):
+        if cfg.state_mixer:
             from gpustack_tpu.models.hybrid import ssm_update_impl
 
             platform = self.mesh.devices.flat[0].platform
             self.ssm_scan = "chunked_einsum"
             self.ssm_update = ssm_update_impl(1, platform, self.mesh)
             logger.info(
-                "state-space layers: scan %s, update %s",
-                self.ssm_scan, self.ssm_update,
+                "%s layers: scan %s, update %s",
+                {"ssm": "state-space", "delta": "delta-rule"}[
+                    cfg.state_mixer
+                ], self.ssm_scan, self.ssm_update,
             )
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
         self._decode_routing = None
@@ -846,7 +850,7 @@ class ModelRunner:
             attn_impl="ring" if self.sp_mode else "xla",
             mesh=self.mesh,
             # a recurrent state takes the tokens that count
-            **({"true_len": counts} if self.cfg.layers_of("M") else {}),
+            **({"true_len": counts} if self.cfg.state_mixer else {}),
         )
         has_any = counts > 0
         last_idx = jnp.maximum(counts - 1, 0)

@@ -142,6 +142,12 @@ def build_lm_params(
     back-to-back (models/transformer.py)."""
     if cfg.layer_kinds is not None:
         return build_hybrid_params(cfg, tensors, quantization)
+    if cfg.layer_types is not None:
+        raise ValueError(
+            f"{cfg.name}: no checkpoint of a stack with layer_types is "
+            "read yet (the family's tensor names are not settled here); "
+            "a directory without weights is served with seeded ones"
+        )
     if cfg.parallel_block:
         raise ValueError(
             f"{cfg.name}: no checkpoint of a cohere2_moe stack is read "

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 import os
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
@@ -31,9 +32,15 @@ class ModelConfig:
     """Architecture description of a decoder-only LM of one of the
     families :data:`FAMILIES` lists: Llama / Qwen / Mistral / Gemma
     dense stacks, Mixtral / Qwen-MoE / GPT-OSS / DeepSeek (MLA) expert
-    stacks, each layer attention + MLP; and the Nemotron-H hybrid
+    stacks, each layer attention + MLP; the Nemotron-H hybrid
     (``layer_kinds``), each layer ONE mixer: a Mamba-2 state-space
-    mixer, a mixture of two-matrix experts, or GQA.
+    mixer, a mixture of two-matrix experts, or GQA; and the Olmo hybrid
+    (``layer_types``), each layer a mixer by kind (a gated-delta-rule
+    linear-attention mixer or full attention) and then an MLP.
+
+    Two kinds of layer keep a state a slot beside the rows a position
+    (a Mamba-2 mixer's, a delta-rule mixer's); :attr:`state_shapes` is
+    the one place that says what shape either is.
 
     Attention type is derived, not stored: MHA when num_kv_heads ==
     num_heads, GQA when 1 < num_kv_heads < num_heads, MQA when
@@ -169,6 +176,28 @@ class ModelConfig:
     # False: a sigmoid router without the selection's correction bias
     router_correction_bias: bool = True
     logit_scale: float = 1.0
+    # ---- Olmo-hybrid knobs ----
+    # The kind of each layer's mixer, as the hub file names it:
+    # "linear_attention" a gated-delta-rule mixer (ops/delta_rule.py: a
+    # matrix state a head a slot, the ``linear_*`` keys as the file has
+    # them), "full_attention" causal GQA. Every layer has an MLP after
+    # its mixer. None: every other family. num_layers == len of it.
+    layer_types: Optional[Tuple[str, ...]] = None
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    # beta = 2 sigmoid(.) and not sigmoid(.): along a key the state's
+    # transition has an eigenvalue in (-1, 1) (arXiv:2411.12537)
+    linear_allow_neg_eigval: bool = False
+    # q and k normalised over the whole projection before the heads are
+    # split (one gain of q_dim / kv_dim), not a head at a time (qk_norm)
+    qk_norm_whole: bool = False
+    # the kinds of ``layer_types`` whose two norms stand on each
+    # sublayer's output inside the residual (x + norm(f(x))), not on its
+    # input (x + f(norm(x)))
+    norm_after: Tuple[str, ...] = ()
     dtype: str = "bfloat16"
 
     # ---- derived ----
@@ -208,9 +237,16 @@ class ModelConfig:
         """Layers that keep a row for every position of a slot in the
         cache's ``k, v``: every layer, the hybrid's attention layers, or
         under ``window_rows`` the full-attention layers."""
+        if self.layer_types is not None:
+            return self.layer_types.count("full_attention")
         if self.layer_kinds is None:
             return self.num_layers - self.num_window_layers
         return self.layers_of("*")
+
+    @property
+    def num_linear_layers(self) -> int:
+        """Layers whose mixer is a gated delta rule (``layer_types``)."""
+        return (self.layer_types or ()).count("linear_attention")
 
     @property
     def window_period(self) -> Tuple[bool, ...]:
@@ -218,11 +254,13 @@ class ModelConfig:
         (three sliding layers and a full one); the whole stack where it
         repeats nothing. ``forward`` scans over periods and writes a
         period's layers out in the scan's body."""
-        flags = self.layer_sliding or ()
-        for n in range(1, len(flags) + 1):
-            if len(flags) % n == 0 and flags == flags[:n] * (len(flags) // n):
-                return flags[:n]
-        return ()
+        return _period(self.layer_sliding or ())
+
+    @property
+    def mixer_period(self) -> Tuple[str, ...]:
+        """:attr:`window_period` for ``layer_types`` (three linear layers
+        and a full one)."""
+        return _period(self.layer_types or ())
 
     @property
     def num_window_layers(self) -> int:
@@ -246,6 +284,57 @@ class ModelConfig:
         return self.mamba_inner + 2 * self.mamba_n_groups * self.ssm_state_size
 
     @property
+    def linear_conv_dim(self) -> int:
+        """Width of a delta-rule mixer's q, k and v side by side, what
+        its three causal convolutions run over."""
+        return (
+            2 * self.linear_num_key_heads * self.linear_key_head_dim
+            + self.linear_num_value_heads * self.linear_value_head_dim
+        )
+
+    @property
+    def state_shapes(
+        self,
+    ) -> Optional[Tuple[int, Tuple[int, ...], Tuple[int, ...]]]:
+        """``(layers, state, conv)``: how many layers keep a state a slot
+        whatever its length, the shape of one layer's recurrent state
+        (float32) and of the rows its convolution keeps (the ``kernel -
+        1`` last ones side by side on the lanes: as ``[kernel - 1, C]``
+        the TPU pads three rows to a tile of sixteen). None for a model
+        without such layers. The one place that says so:
+        ``KVCache.create`` and the byte counts follow it.
+
+        A Mamba-2 mixer keeps ``[H, P, N]`` a head's ``[P, N]`` at a
+        time; a delta-rule mixer ``[Dk, H * Dv]``, the key width on the
+        sublanes and every head's values side by side on the lanes, so
+        that widths that are no whole lane tiles (96, 192) store nothing
+        padded (``ops/delta_rule.py``)."""
+        if self.layers_of("M"):
+            return (
+                self.layers_of("M"),
+                (self.mamba_num_heads, self.mamba_head_dim,
+                 self.ssm_state_size),
+                ((self.conv_kernel - 1) * self.mamba_conv_dim,),
+            )
+        if self.num_linear_layers:
+            return (
+                self.num_linear_layers,
+                (self.linear_key_head_dim,
+                 self.linear_num_value_heads * self.linear_value_head_dim),
+                ((self.linear_conv_kernel_dim - 1) * self.linear_conv_dim,),
+            )
+        return None
+
+    @property
+    def state_mixer(self) -> Optional[str]:
+        """The kind of mixer whose state :attr:`state_shapes` describes,
+        as the flight records and ``/metrics`` label it: ``"ssm"``
+        (Mamba-2), ``"delta"`` (gated delta rule), None without one."""
+        if self.layers_of("M"):
+            return "ssm"
+        return "delta" if self.num_linear_layers else None
+
+    @property
     def kv_row_shapes(self) -> Tuple[Tuple[int, int], Tuple[int, int]]:
         """``((heads, width) of k, (heads, width) of v)``: what one
         position of one layer holds in the cache. The one place that
@@ -255,9 +344,26 @@ class ModelConfig:
         if self.is_mla:
             return (1, self.kv_lora_rank), (1, self.qk_rope_head_dim)
         return (
-            (self.num_kv_heads, self.head_dim),
-            (self.num_kv_heads, self.head_dim),
+            (self.kv_heads_stored, self.head_dim),
+            (self.kv_heads_stored, self.head_dim),
         )
+
+    @property
+    def kv_heads_stored(self) -> int:
+        """The kv heads a position's rows hold in a GQA cache:
+        ``num_kv_heads``, or, where those are more than one bf16 sublane
+        tile (16) and no whole number of them, the next whole number (30
+        -> 32), the heads behind the real ones zeros that no query head
+        reads. The TPU stores ``[S, Hkv, hd]`` with the heads on a tile's
+        sublanes; at 30 it stores the array with the positions there
+        instead (transposed), which the decode kernel cannot read in
+        place: compiled for a described v5e, the decode program copied
+        both caches whole into the other order and back every step (PR
+        53; ``models/hybrid.py pad_expert_width``'s lesson again).
+        ``transformer.block`` pads q, k and v on their way to the cache
+        and drops the heads that are none on the way out."""
+        h = self.num_kv_heads
+        return h if h <= 16 or h % 16 == 0 else -(-h // 16) * 16
 
     @property
     def beside_rows(self) -> Optional[BesideRows]:
@@ -265,14 +371,17 @@ class ModelConfig:
         else (``kv_row_shapes``): any span of a slot's positions can
         then be cut out, stored, moved, gone on from or rolled back.
         Else what the slot keeps beside them (``KVCache`` holds it:
-        a state-space layer's recurrent state, a sliding layer's ring of
-        window rows). Such a model is served on one device, and the
-        prefix cache, the spill tier, a KV handoff, a verify step and a
-        chunked prefill are refused for it (``engine/engine.py
-        _refuse_what_moves_a_slot``, ``engine/runner.py``)."""
-        if self.layers_of("M"):
+        a state-space or linear-attention layer's recurrent state, a
+        sliding layer's ring of window rows). Such a model is served on
+        one device, and the prefix cache, the spill tier, a KV handoff,
+        a verify step and a chunked prefill are refused for it
+        (``engine/engine.py _refuse_what_moves_a_slot``,
+        ``engine/runner.py``)."""
+        if self.state_mixer:
             return BesideRows(
-                "has state-space layers", "a recurrent state",
+                {"ssm": "has state-space layers",
+                 "delta": "has linear-attention layers"}[self.state_mixer],
+                "a recurrent state",
                 "the rows carry no recurrent state to go on from",
             )
         if self.window_rows:
@@ -322,6 +431,22 @@ class ModelConfig:
             if self.layers_of("M"):
                 assert self.mamba_num_heads % self.mamba_n_groups == 0
                 assert self.mamba_inner and self.ssm_state_size
+        if self.kv_heads_stored != self.num_kv_heads:
+            # only ``transformer.block``'s GQA branch pads its heads
+            assert self.layer_kinds is None and not self.window_rows
+            assert not self.attn_sinks
+        if self.layer_types is not None:
+            assert len(self.layer_types) == self.num_layers
+            assert set(self.layer_types) <= {
+                "linear_attention", "full_attention"
+            }, self.layer_types
+            assert self.layer_kinds is None and self.layer_sliding is None
+            assert not (self.is_moe or self.is_mla)
+            if self.num_linear_layers:
+                assert (
+                    self.linear_num_key_heads == self.linear_num_value_heads
+                ), "a delta-rule mixer is served with a key head a value head"
+                assert self.linear_key_head_dim and self.linear_value_head_dim
         return self
 
     # ---- memory accounting (used by scheduler + engine sizing) ----
@@ -355,6 +480,28 @@ class ModelConfig:
                 + self.layers_of("M") * (mamba + d)
                 + self.layers_of("E") * (experts + d)
                 + self.layers_of("*") * (attn + d)
+            )
+        if self.layer_types is not None:
+            keys = self.linear_num_key_heads * self.linear_key_head_dim
+            values = self.linear_num_value_heads * self.linear_value_head_dim
+            heads = self.linear_num_value_heads
+            linear = (
+                2 * d * keys + 2 * d * values    # wq, wk; wv and the gate
+                + values * d                     # wo
+                + 2 * d * heads + 2 * heads      # wa, wb; A_log, dt_bias
+                + self.linear_conv_kernel_dim * self.linear_conv_dim
+                + self.linear_value_head_dim     # the output norm's gain
+            )
+            full = (
+                d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+                + self.q_dim + self.kv_dim       # the whole-width q, k norms
+            )
+            mlp = 3 * d * self.intermediate_size
+            return (
+                embed + lm_head + d
+                + self.num_linear_layers * linear
+                + self.num_kv_layers * full
+                + self.num_layers * (mlp + 2 * d)
             )
         if self.is_mla:
             qk_dim = self.qk_nope_head_dim + self.qk_rope_head_dim
@@ -428,15 +575,16 @@ class ModelConfig:
         return self.num_window_layers * rows * per_layer * bits // 8
 
     def state_bytes_per_slot(self, bits: int = 16) -> int:
-        """Bytes a slot keeps whatever its length: a state-space layer's
-        recurrent state (float32, as the family's serving notes ask) and
-        the last ``conv_kernel - 1`` rows of ``xBC`` (``bits`` wide). 0
-        for a model without such layers."""
-        per_layer = (
-            self.mamba_inner * self.ssm_state_size * 4
-            + (self.conv_kernel - 1) * self.mamba_conv_dim * bits // 8
+        """Bytes a slot keeps whatever its length (:attr:`state_shapes`):
+        each such layer's recurrent state (float32, as the families'
+        serving notes ask) and the rows its convolution keeps (``bits``
+        wide). 0 for a model without such layers."""
+        if self.state_shapes is None:
+            return 0
+        layers, state, conv = self.state_shapes
+        return layers * (
+            math.prod(state) * 4 + math.prod(conv) * bits // 8
         )
-        return self.layers_of("M") * per_layer
 
     def beside_bytes_per_slot(self, max_len: int, bits: int = 16) -> int:
         """Bytes a slot of ``max_len`` positions keeps beside the rows of
@@ -445,6 +593,15 @@ class ModelConfig:
         return self.state_bytes_per_slot(bits) + self.window_bytes_per_slot(
             max_len, bits
         )
+
+
+def _period(kinds: tuple) -> tuple:
+    """The shortest run of ``kinds`` that the whole repeats; ``kinds``
+    itself where it repeats nothing, ``()`` of nothing."""
+    for n in range(1, len(kinds) + 1):
+        if len(kinds) % n == 0 and kinds == kinds[:n] * (len(kinds) // n):
+            return kinds[:n]
+    return ()
 
 
 # The families ``config_from_hf`` reads, by a substring of the file's
@@ -456,7 +613,7 @@ class ModelConfig:
 # tests' small files are.
 FAMILIES: Tuple[str, ...] = (
     "Llama", "Mistral", "Mixtral", "Qwen2", "Qwen3", "Gemma", "GptOss",
-    "Deepseek", "NemotronH", "Cohere2Moe",
+    "Deepseek", "NemotronH", "Cohere2Moe", "OlmoHybrid",
     # multimodal wrappers whose text stack is one of the above
     "Llava", "VLForConditionalGeneration",
 )
@@ -625,6 +782,65 @@ def _cohere2_moe_config(cfg: Dict[str, Any], name: str) -> ModelConfig:
     ).validate()
 
 
+def _olmo_hybrid_config(cfg: Dict[str, Any], name: str) -> ModelConfig:
+    """Olmo-Hybrid (``model_type: olmo_hybrid``): every layer a mixer and
+    then a gated MLP; the mixer by ``layer_types``, a gated-delta-rule
+    linear-attention mixer (the ``linear_*`` keys; three short causal
+    convolutions, ``beta`` doubled under ``linear_allow_neg_eigval``) or
+    full causal attention with q and k normalised over the whole
+    projection. The hub file has no key for two things, and what is
+    assumed of each is a field here, so that a correction is one line
+    (``perfbench/configs/olmo-hybrid-7b-int8/deployment.json``,
+    ``assumed``): a full-attention layer norms each sublayer's output
+    inside the residual, as the family's earlier models do, and a
+    linear-attention layer each sublayer's input (``norm_after``);
+    attention takes no rotary embedding where ``rope_parameters.
+    rope_theta`` is null (``rope``)."""
+    layer_types = tuple(cfg.get("layer_types") or ())
+    if set(layer_types) - {"linear_attention", "full_attention"}:
+        raise ValueError(
+            f"layer_types {sorted(set(layer_types))}: only linear_attention "
+            "and full_attention layers are served"
+        )
+    if len(layer_types) != cfg["num_hidden_layers"]:
+        raise ValueError(
+            f"layer_types has {len(layer_types)} layers, "
+            f"num_hidden_layers says {cfg['num_hidden_layers']}"
+        )
+    for key, want in (("hidden_act", "silu"), ("attention_bias", False)):
+        if cfg.get(key, want) != want:
+            raise ValueError(
+                f"{key} {cfg[key]!r}: an olmo_hybrid stack is served with "
+                f"{want!r} only"
+            )
+    hidden, heads = cfg["hidden_size"], cfg["num_attention_heads"]
+    theta = (cfg.get("rope_parameters") or {}).get("rope_theta")
+    return ModelConfig(
+        name=name,
+        vocab_size=cfg["vocab_size"],
+        hidden_size=hidden,
+        intermediate_size=cfg["intermediate_size"],
+        num_layers=cfg["num_hidden_layers"],
+        num_heads=heads,
+        num_kv_heads=cfg.get("num_key_value_heads", heads),
+        head_dim=cfg.get("head_dim") or hidden // heads,
+        rope=theta is not None,
+        rope_theta=float(theta or 10000.0),
+        rms_norm_eps=cfg.get("rms_norm_eps", 1e-6),
+        tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+        max_position_embeddings=cfg.get("max_position_embeddings", 8192),
+        layer_types=layer_types,
+        linear_num_key_heads=int(cfg["linear_num_key_heads"]),
+        linear_num_value_heads=int(cfg["linear_num_value_heads"]),
+        linear_key_head_dim=int(cfg["linear_key_head_dim"]),
+        linear_value_head_dim=int(cfg["linear_value_head_dim"]),
+        linear_conv_kernel_dim=int(cfg.get("linear_conv_kernel_dim") or 4),
+        linear_allow_neg_eigval=bool(cfg.get("linear_allow_neg_eigval")),
+        qk_norm_whole=True,
+        norm_after=("full_attention",),
+    ).validate()
+
+
 def config_from_hf(cfg: Dict[str, Any], name: str = "custom") -> ModelConfig:
     """Build a ModelConfig from an HF ``config.json`` dict of one of
     :data:`FAMILIES` (the reference's selectors introspect the same
@@ -642,6 +858,8 @@ def config_from_hf(cfg: Dict[str, Any], name: str = "custom") -> ModelConfig:
         return _nemotron_h_config(cfg, name)
     if "Cohere2Moe" in arch or cfg.get("model_type") == "cohere2_moe":
         return _cohere2_moe_config(cfg, name)
+    if "OlmoHybrid" in arch or cfg.get("model_type") == "olmo_hybrid":
+        return _olmo_hybrid_config(cfg, name)
     hidden = cfg["hidden_size"]
     heads = cfg["num_attention_heads"]
     head_dim = cfg.get("head_dim") or hidden // heads

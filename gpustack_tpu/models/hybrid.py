@@ -122,11 +122,12 @@ def pad_expert_width(w: jax.Array, axis: int) -> jax.Array:
 
 
 def ssm_update_impl(rows: int, platform: str, mesh) -> str:
-    """How a step of ``rows`` tokens a slot moves the recurrent state:
-    ``"kernel"`` (``ops/ssm.py ssm_state_update``, the stacked state in
-    place, live slots only) for a decode step on one TPU chip; the
-    chunked scan for several rows a slot; ``"xla"`` for one row anywhere
-    else."""
+    """How a step of ``rows`` tokens a slot moves the recurrent state,
+    of either kind (``ModelConfig.state_mixer``): ``"kernel"`` (``ops/
+    ssm.py ssm_state_update``, ``ops/delta_rule.py delta_state_update``:
+    the stacked state in place, live slots only) for a decode step on
+    one TPU chip; the chunked form for several rows a slot; ``"xla"``
+    for one row anywhere else."""
     one_chip = platform == "tpu" and (mesh is None or mesh.size == 1)
     if rows > 1:
         return "scan"

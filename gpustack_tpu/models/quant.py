@@ -60,18 +60,24 @@ _CONTRACT_AXES: Dict[str, tuple] = {
     # the hybrid's state-space mixer: in_proj [d, z|xBC|dt], out_proj
     # (its convolution, A_log, D, dt_bias and norms stay as they are)
     "w_in": (0,), "w_out": (0,),
+    # a delta-rule mixer's output gate [d, H * Dv], beside its wq, wk,
+    # wv, wo above (its convolution, A_log and dt_bias stay float32, its
+    # norm and the two one-a-head projections wa, wb bf16)
+    "wg": (0,),
 }
 # The stacks of layers in a param tree: one for most models, a dense
-# prefix beside it for DeepSeek, three for the hybrid (models/hybrid.py)
+# prefix beside it for DeepSeek, three for the hybrid (models/hybrid.py),
+# one a kind of mixer beside it for a stack with ``layer_types``
 LAYER_STACKS = (
     "layers", "dense_layers", "ssm_layers", "moe_layers", "attn_layers",
+    "delta_layers",
 )
 # Layer-stacked leaves carry a leading [L] axis not present at use time.
 _STACKED = {
     "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
     "we_gate", "we_up", "we_down",
     "wq_a", "wq_b", "wkv_a", "wk_b", "wv_b",
-    "ws_gate", "ws_up", "ws_down", "w_in", "w_out",
+    "ws_gate", "ws_up", "ws_down", "w_in", "w_out", "wg",
 }
 
 

@@ -136,11 +136,13 @@ class KVCache:
     # span of positions carries either: ``ModelConfig.beside_rows`` says
     # what follows, the methods below are what a holder of a cache calls
     # (docs/KV_CACHE.md, "What a slot keeps").
-    # A state (models/hybrid.py): each state-space layer's recurrent
-    # state ``[L_M, B, H, P, N]`` (float32) and the last ``conv_kernel -
-    # 1`` rows of its convolution's input, side by side, ``[L_M, B, (K-1)
-    # * C]``: one a slot whatever its length, a prefill ends in one and a
-    # decode step moves it on.
+    # A state, of one of two kinds and of the shapes the configuration
+    # says (``ModelConfig.state_shapes``): each state-space layer's
+    # recurrent state ``[L_M, B, H, P, N]`` (models/hybrid.py) or each
+    # delta-rule layer's ``[L_lin, B, Dk, H * Dv]`` (models/delta.py),
+    # float32, and the last ``kernel - 1`` rows of its convolutions'
+    # input, side by side, ``[L, B, (K-1) * C]``: one a slot whatever its
+    # length, a prefill ends in one and a decode step moves it on.
     ssm: Optional[jax.Array] = None
     conv: Optional[jax.Array] = None
     # A ring (``cfg.window_rows``): the sliding layers' rows, ``wk, wv
@@ -169,17 +171,11 @@ class KVCache:
         k_row, v_row = cfg.kv_row_shapes
         lead = (cfg.num_kv_layers, batch, max_len)
         state = {}
-        if cfg.layers_of("M"):
-            Lm = cfg.layers_of("M")
+        if cfg.state_shapes:
+            layers, ssm, conv = cfg.state_shapes
             state = dict(
-                ssm=jnp.zeros(
-                    (Lm, batch, cfg.mamba_num_heads, cfg.mamba_head_dim,
-                     cfg.ssm_state_size), jnp.float32,
-                ),
-                conv=jnp.zeros(
-                    (Lm, batch, (cfg.conv_kernel - 1) * cfg.mamba_conv_dim),
-                    dtype,
-                ),
+                ssm=jnp.zeros((layers, batch) + ssm, jnp.float32),
+                conv=jnp.zeros((layers, batch) + conv, dtype),
             )
         if cfg.window_rows:
             ring = (
@@ -401,6 +397,40 @@ def init_params(
         scale = scale if scale is not None else 1.0 / math.sqrt(shape[-2])
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
 
+    if cfg.layer_types is not None:
+        # a mixer by kind and an MLP in every layer: what every layer
+        # has (its two norms, the MLP) in one stack of L, each kind of
+        # mixer's leaves in a stack of its own (models/delta.py)
+        from gpustack_tpu.models.delta import init_delta_layers
+
+        La = cfg.num_kv_layers
+        params = {
+            "embed": w(next(keys), cfg.vocab_size, d, scale=0.02),
+            "final_norm": jnp.ones((d,), dtype),
+            "layers": {
+                "attn_norm": jnp.ones((L, d), dtype),
+                "mlp_norm": jnp.ones((L, d), dtype),
+                "w_gate": w(next(keys), L, d, f),
+                "w_up": w(next(keys), L, d, f),
+                "w_down": w(next(keys), L, f, d),
+            },
+        }
+        if La:
+            params["attn_layers"] = {
+                "wq": w(next(keys), La, d, cfg.q_dim),
+                "wk": w(next(keys), La, d, cfg.kv_dim),
+                "wv": w(next(keys), La, d, cfg.kv_dim),
+                "wo": w(next(keys), La, cfg.q_dim, d),
+                "q_norm": jnp.ones((La, cfg.q_dim), dtype),
+                "k_norm": jnp.ones((La, cfg.kv_dim), dtype),
+            }
+        if cfg.num_linear_layers:
+            params["delta_layers"] = init_delta_layers(
+                cfg, next(keys), dtype
+            )
+        if not cfg.tie_word_embeddings:
+            params["lm_head"] = w(next(keys), d, cfg.vocab_size)
+        return params
     if cfg.is_mla:
         qk = cfg.head_dim
         layers: Dict[str, jax.Array] = {
@@ -1386,16 +1416,25 @@ def decode_attention_impl(
     else:
         itemsize = 2 if cfg.dtype == "bfloat16" else 4
         block = gqa_block_positions(
-            max_len, cfg.num_kv_heads, cfg.head_dim, itemsize
+            max_len, cfg.kv_heads_stored, cfg.head_dim, itemsize
         )
         if cfg.window_rows and gqa_block_positions(
-            min(cfg.sliding_window, max_len), cfg.num_kv_heads,
+            min(cfg.sliding_window, max_len), cfg.kv_heads_stored,
             cfg.head_dim, itemsize,
         ) is None:
             # the ring of a sliding layer is walked by the same kernel
             block = None
     one_chip = platform == "tpu" and (mesh is None or mesh.size == 1)
     return "kernel" if one_chip and rows == 1 and block is not None else "xla"
+
+
+def heads_of_zeros_behind(n: int, *arrays: jax.Array):
+    """Each of ``[B, T, heads, ...]`` with ``n`` heads of zeros behind its
+    own (``ModelConfig.kv_heads_stored``)."""
+    return tuple(
+        jnp.pad(a, ((0, 0), (0, 0), (0, n)) + ((0, 0),) * (a.ndim - 3))
+        for a in arrays
+    )
 
 
 def forward(
@@ -1535,6 +1574,21 @@ def forward(
     if cache is not None and decode_attn_impl is None:
         decode_attn_impl = decode_attention_impl(
             cfg, T, cache.max_len, platform, mesh
+        )
+    # Under ``layer_types``: the delta-rule layers' mixer, with what it
+    # needs beside a layer's leaves bound to it (models/delta.py). One
+    # name, and made there: on the chip's host every name and every line
+    # that ``forward`` and ``block`` hold before they reach a kernel
+    # costs each operation of the kernel's traced body (PERF.md section
+    # 6, PR 53: 60 dead lines in either slowed a flash prefill program's
+    # trace by a third).
+    delta_layer = None
+    if cfg.layer_types is not None:
+        from gpustack_tpu.models.delta import bound_delta_mixer
+
+        delta_layer = bound_delta_mixer(
+            cfg, (B, T), cache, true_len, live, ssm_impl, platform, mesh,
+            ring=attn_impl == "ring",
         )
     walk = walk_w = None
     if cache is not None and decode_attn_impl != "xla":
@@ -1808,7 +1862,13 @@ def forward(
             attn = jnp.einsum("bthr,rhv->bthv", u, wv.reshape(rank, H, vd))
         return attn.reshape(B, T, H * vd), carried
 
-    period = cfg.window_period if cfg.window_rows else ()
+    period = cfg.window_period if cfg.window_rows else cfg.mixer_period
+
+    def among_its_kind(layer, kind):
+        """Where a layer's rows, its state or its mixer's leaves lie in
+        the store or the stack of its kind. ``kind`` (static) is ``(the
+        kind, how many of it come before the layer in its period)``."""
+        return (layer // len(period)) * period.count(kind[0]) + kind[1]
 
     def window_attention(h, lp, carried, layer, kind):
         """One GQA layer of a stack that keeps its sliding layers' rows
@@ -1827,7 +1887,7 @@ def forward(
         sliding layer's last ``min(true_len, W)`` rows in the ring: a
         row takes the newest real position of its residue, the padding
         of a bucket writes nothing."""
-        sliding, at = kind
+        sliding = kind[0]
         q, k, v = qkv_projections(h, lp, decode=cache is not None and T == 1)
         q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)
         k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
@@ -1843,8 +1903,7 @@ def forward(
         mask_l = mask_slide if sliding else mask_full
         if cache is None:
             return _attend(grouped, k, v, mask_l, scale), carried
-        n_kind = sum(s == sliding for s in period)
-        store = (layer // len(period)) * n_kind + at
+        store = among_its_kind(layer, kind)
         buf_k, buf_v = (
             (carried.wk, carried.wv) if sliding else (carried.k, carried.v)
         )
@@ -1908,7 +1967,14 @@ def forward(
         x_in, carried, layer, *counts = carry
         lp, slide_flag = scanned
         lp = {**lp, **stacked}
-        if kind is not None:
+        # under ``layer_types``: the layer's mixer, its place among its
+        # kind (where its rows or its state lie in their store), and
+        # whether its norms stand before its sublayers or after them
+        mixer = kind[0] if delta_layer is not None else None
+        store = among_its_kind(layer, kind) if mixer else layer
+        norm_first = mixer not in cfg.norm_after
+        windowed = kind is not None and mixer is None
+        if windowed:
             mask_l = sin_b = cos_b = None   # window_attention's own
         elif hetero:
             mask_l = jnp.where(slide_flag, mask_slide, mask_full)
@@ -1916,10 +1982,12 @@ def forward(
             cos_b = jnp.where(slide_flag, cos_loc, cos)
         else:
             mask_l, sin_b, cos_b = mask, sin, cos
-        h = model_norm(x_in, lp["attn_norm"], cfg)
+        h = model_norm(x_in, lp["attn_norm"], cfg) if norm_first else x_in
         if cfg.is_mla:
             attn, carried = mla_attention(h, lp, carried, layer, mask_l)
-        elif kind is not None:
+        elif mixer == "linear_attention":
+            attn_out, carried = delta_layer(h, lp, carried, store)
+        elif windowed:
             attn, carried = window_attention(h, lp, carried, layer, kind)
         else:
             q, k, v = qkv_projections(
@@ -1927,6 +1995,10 @@ def forward(
             )
             if cfg.qkv_bias:
                 q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+            if cfg.qk_norm_whole:
+                # over the whole projection, before the heads are split
+                q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+                k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
             q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)
             k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
             v = v.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
@@ -1940,10 +2012,18 @@ def forward(
                     k, lp["k_norm"], cfg.rms_norm_eps,
                     cfg.norm_delta_gain,
                 )
-            q = apply_rope(q, sin_b, cos_b).reshape(
+            if cfg.rope:
+                q = apply_rope(q, sin_b, cos_b)
+            q = q.reshape(
                 B, T, cfg.num_kv_heads, cfg.group_size, cfg.head_dim
             )
-            k = apply_rope(k, sin_b, cos_b)
+            if cfg.rope:
+                k = apply_rope(k, sin_b, cos_b)
+            unstored = cfg.kv_heads_stored - cfg.num_kv_heads
+            if unstored:
+                # so that the rows lie in the cache in whole tiles; what
+                # the heads that are none attend to is dropped below
+                q, k, v = heads_of_zeros_behind(unstored, q, k, v)
 
             sinks_l = (
                 lp["sinks"].reshape(cfg.num_kv_heads, cfg.group_size)
@@ -1956,15 +2036,18 @@ def forward(
                 )
             else:
                 attn, new_k, new_v = attend_over_cache(
-                    q, k, v, carried.k, carried.v, layer, positions[:, 0],
+                    q, k, v, carried.k, carried.v, store, positions[:, 0],
                     positions=positions, mask=mask_l, scale=scale,
                     decode_attn_impl=decode_attn_impl, walk=walk,
                     attn_impl=attn_impl, mesh=mesh,
                     softcap=cfg.attn_logit_softcap, sinks=sinks_l,
                 )
                 carried = dataclasses.replace(carried, k=new_k, v=new_v)
+            if unstored:
+                attn = attn[..., :cfg.q_dim]
 
-        attn_out = _mm("btq,qd->btd", attn, lp["wo"])
+        if mixer != "linear_attention":     # whose output is projected
+            attn_out = _mm("btq,qd->btd", attn, lp["wo"])
         if cfg.o_bias:
             attn_out = attn_out + lp["bo"]
         if cfg.post_norms:
@@ -1972,13 +2055,18 @@ def forward(
                 attn_out, lp["post_attn_norm"], cfg.rms_norm_eps,
                 cfg.norm_delta_gain,
             )
+        if not norm_first:
+            attn_out = model_norm(attn_out, lp["attn_norm"], cfg)
         if cfg.parallel_block:
             # attention and MLP both read the one norm's output, and
             # both are added to the stream
             x_mid, h2 = x_in, h
         else:
             x_mid = x_in + attn_out
-            h2 = model_norm(x_mid, lp["mlp_norm"], cfg)
+            h2 = (
+                model_norm(x_mid, lp["mlp_norm"], cfg) if norm_first
+                else x_mid
+            )
         routing = None
         if moe_layer:
             mlp = _moe_mlp(
@@ -2017,6 +2105,8 @@ def forward(
                 mlp, lp["post_mlp_norm"], cfg.rms_norm_eps,
                 cfg.norm_delta_gain,
             )
+        if not norm_first:
+            mlp = model_norm(mlp, lp["mlp_norm"], cfg)
         x_out = x_mid + attn_out + mlp if cfg.parallel_block else x_mid + mlp
         return (x_out, carried, layer + 1, *counts), routing
 
@@ -2049,8 +2139,19 @@ def forward(
         # the layers copies the stacked cache whole through the
         # conditional every step: models/hybrid.py.)
         per = len(period)
-        # (sliding, how many of its kind come before it in the period)
+        # (sliding, how many of its kind come before it in the period);
+        # under ``layer_types`` the mixer's name where ``sliding`` is
         kinds = [(s, period[:j].count(s)) for j, s in enumerate(period)]
+        by_mixer = {
+            "linear_attention": params.get("delta_layers"),
+            "full_attention": params.get("attn_layers"),
+        }
+
+        def leaves_at(stack, at):
+            return jax.tree.map(
+                lambda a: lax.dynamic_index_in_dim(a, at, 0, keepdims=False),
+                stack,
+            )
 
         def one_period(carry, _):
             routings = []
@@ -2061,12 +2162,12 @@ def forward(
                 # are copied out before the body reads them (1.3 GB of
                 # temporaries at this model's widths, compiled for a
                 # described v5e)
-                lp = jax.tree.map(
-                    lambda a: lax.dynamic_index_in_dim(
-                        a, carry[2], 0, keepdims=False
-                    ),
-                    layers,
-                )
+                lp = leaves_at(layers, carry[2])
+                if delta_layer is not None:
+                    # and its mixer's, at its place among its kind
+                    lp.update(leaves_at(
+                        by_mixer[kind[0]], among_its_kind(carry[2], kind)
+                    ))
                 carry, routing = block(
                     carry, (lp, None), moe_layer=cfg.is_moe, kind=kind,
                 )

@@ -79,11 +79,12 @@ Record vocabulary (per step):
   ``decode_moe_dispatch: touched`` (``/healthz``) it is the share of the
   experts' weights the live rows' routing touched; under ``dense`` 100.
   Absent otherwise.
-- ``state_slots``, ``ssm_tokens`` — for a model with state-space layers
-  (a recurrent state a slot beside its rows): the slots whose state the
-  step's decode step moved on, one token each, and the prompt tokens
-  the step sent through the chunked scan (a prefill). Absent for any
-  other model.
+- ``state_slots``, ``ssm_tokens``, ``state_mixer`` — for a model that
+  keeps a recurrent state a slot beside its rows: the slots whose state
+  the step's decode step moved on, one token each, the prompt tokens
+  the step sent through the chunked scan (a prefill), and the kind of
+  mixer that keeps the state (``"ssm"`` Mamba-2 layers, ``"delta"``
+  gated-delta-rule layers). Absent for any other model.
 
 - ``window_rows``, ``full_rows`` — for a stack that keeps its sliding
   layers' rows at window size (``/healthz`` ``cache.window_bytes``):
@@ -311,6 +312,7 @@ GUARDED_BY = {
     "_moe_decode_read_total": "_mu",
     "_moe_decode_held_total": "_mu",
     "_ssm_tokens_total": "_mu",
+    "_state_mixer": "_mu",
     "_attn_rows_total": "_mu",
     "_spec_proposed_total": "_mu",
     "_spec_accepted_total": "_mu",
@@ -381,9 +383,11 @@ class FlightRecorder:
         # experts): the held experts a step read, and held x layers
         self._moe_decode_read_total = 0
         self._moe_decode_held_total = 0
-        # tokens through the state-space layers (a hybrid), by the
-        # program that took them; None until such a step is recorded
+        # tokens through the layers that keep a recurrent state, by the
+        # program that took them, and the kind of mixer that keeps it;
+        # None until such a step is recorded
         self._ssm_tokens_total: Optional[Dict[str, int]] = None
+        self._state_mixer: Optional[str] = None
         # cached rows attended by kind of layer (a stack with a window
         # store); None until such a step is recorded
         self._attn_rows_total: Optional[Dict[str, int]] = None
@@ -437,7 +441,8 @@ class FlightRecorder:
         kv_allocated: int = 0,     # 0: the step dispatched no decode step
         moe_read: int = 0,
         moe_held: int = 0,         # 0: the step fetched no experts' count
-        ssm: Optional[Sequence[int]] = None,   # (state_slots, ssm_tokens)
+        # (state_slots, ssm_tokens, the mixer's kind)
+        ssm: Optional[Sequence[Any]] = None,
         attn_rows: Optional[Sequence[int]] = None,  # (window_rows, full_rows)
     ) -> Optional[Sequence[List[Any]]]:
         """Returns the step's ``programs`` (None in a steady step)."""
@@ -513,6 +518,7 @@ class FlightRecorder:
                     }
                 totals["decode"] += ssm[0]
                 totals["prefill"] += ssm[1]
+                self._state_mixer = ssm[2]
             if attn_rows is not None:
                 totals = self._attn_rows_total
                 if totals is None:
@@ -605,7 +611,8 @@ class FlightRecorder:
         if programs:
             entry["programs"] = programs
         if ssm is not None:
-            entry["state_slots"], entry["ssm_tokens"] = ssm
+            (entry["state_slots"], entry["ssm_tokens"],
+             entry["state_mixer"]) = ssm
         if attn_rows is not None:
             entry["window_rows"], entry["full_rows"] = attn_rows
         return entry
@@ -682,6 +689,7 @@ class FlightRecorder:
             moe_read = self._moe_decode_read_total
             moe_held = self._moe_decode_held_total
             ssm_tokens = dict(self._ssm_tokens_total or {})
+            state_mixer = self._state_mixer
             attn_rows = dict(self._attn_rows_total or {})
             proposed = self._spec_proposed_total
             accepted = self._spec_accepted_total
@@ -771,10 +779,11 @@ class FlightRecorder:
                 f'gpustack_engine_moe_decode_experts_total{{kind="held"}} '
                 f"{moe_held}",
             ]
-        if ssm_tokens:   # a model with state-space layers
+        if ssm_tokens:   # a model that keeps a recurrent state a slot
             lines.append(decl("gpustack_engine_ssm_tokens_total"))
             lines += [
-                f'gpustack_engine_ssm_tokens_total{{kind="{kind}"}} {n}'
+                f'gpustack_engine_ssm_tokens_total{{kind="{kind}",'
+                f'mixer="{state_mixer}"}} {n}'
                 for kind, n in sorted(ssm_tokens.items())
             ]
         if attn_rows:   # a stack with a window store

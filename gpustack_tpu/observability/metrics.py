@@ -102,10 +102,12 @@ METRIC_FAMILIES = {
     # kind of layer (label layer=sliding|full), over slots, positions
     # and layers; absent for any other model
     "gpustack_engine_attn_rows_total": "counter",
-    # a model with state-space layers: tokens through them, by the
-    # program that took them (label kind=prefill|decode: the chunked
-    # scan over a prompt, the one-step update of a live slot); absent
-    # for any other model
+    # a model that keeps a recurrent state a slot: tokens through the
+    # layers that keep it, by the program that took them (label
+    # kind=prefill|decode: the chunked scan over a prompt, the one-step
+    # update of a live slot) and the kind of mixer (label
+    # mixer=ssm|delta: Mamba-2, gated delta rule); absent for any other
+    # model
     "gpustack_engine_ssm_tokens_total": "counter",
     "gpustack_engine_occupancy_ratio": "gauge",
     "gpustack_engine_queue_oldest_wait_seconds": "gauge",

@@ -32,6 +32,12 @@ nothing: that is how a caller keeps padding out of it.
 
 ``tests/ops/test_ssm.py`` holds all three to the recurrence taken one
 position at a time.
+
+One of two kinds of state a slot can keep in ``KVCache.ssm``
+(``ModelConfig.state_shapes`` is the one place that says either's
+shape): this rule *adds* an outer product to a decayed state;
+``ops/delta_rule.py``'s *corrects* the state through itself, in the same
+two forms and with the same way of keeping dead slots and padding out.
 """
 
 from __future__ import annotations

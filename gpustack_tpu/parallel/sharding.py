@@ -136,8 +136,11 @@ class SpecLayout:
             specs["dense_layers"] = {
                 k: rules[k] for k in params["dense_layers"]
             }
-        for stack in ("ssm_layers", "moe_layers", "attn_layers"):
-            # the hybrid's three stacks (models/hybrid.py), served on one
+        for stack in (
+            "ssm_layers", "moe_layers", "attn_layers", "delta_layers"
+        ):
+            # the hybrid's three stacks (models/hybrid.py) and a stack
+            # with ``layer_types``' two kinds of mixer, served on one
             # device: every leaf whole on it
             if stack in params:
                 specs[stack] = {k: P() for k in params[stack]}

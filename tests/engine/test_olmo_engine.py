@@ -1,9 +1,10 @@
-"""A model with state-space layers through the engine: a recurrent state
-a slot beside its rows (``KVCache.ssm`` / ``.conv``). Prefill hands it
-back, insert places it, a slot that changes hands starts from the new
-prompt's and leaves its neighbours alone, snapshot and restore carry it,
-``/healthz`` and the exporters report both kinds, and whatever would
-move or reuse a slot without its state is refused at the start."""
+"""A model with gated-delta-rule layers through the engine: a matrix
+state a head a slot beside its rows, in the cache's fields a Mamba-2
+state lies in (``KVCache.ssm`` / ``.conv``, the shapes
+``ModelConfig.state_shapes``'). Prefill hands it back, insert places it,
+a slot that changes hands starts from the new prompt's, ``/healthz`` and
+the exporters count it under the mixer's kind, and whatever would move
+or reuse a slot without its state is refused at the start, by name."""
 
 import dataclasses
 import re
@@ -18,28 +19,27 @@ from gpustack_tpu.engine.runner import ModelRunner
 from gpustack_tpu.models.config import config_from_hf
 from gpustack_tpu.models.transformer import init_params
 from gpustack_tpu.parallel.mesh import MeshPlan
-from perfbench.reference import nemotron_h as ref
+from perfbench.reference import olmo_hybrid as ref
 
 HF = {
-    "architectures": ["NemotronHForCausalLM"], "model_type": "nemotron_h",
-    "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2,
-    "head_dim": 16, "vocab_size": 264,
-    "hybrid_override_pattern": "MEM*EME", "num_hidden_layers": 7,
-    "mamba_num_heads": 4, "mamba_head_dim": 8, "n_groups": 2,
-    "ssm_state_size": 16, "conv_kernel": 4, "chunk_size": 8,
-    "n_routed_experts": 8, "num_experts_per_tok": 2,
-    "moe_intermediate_size": 32, "moe_shared_expert_intermediate_size": 48,
-    "n_shared_experts": 1, "routed_scaling_factor": 2.5,
-    "norm_topk_prob": True, "n_group": 1, "topk_group": 1,
-    "layer_norm_epsilon": 1e-5, "rope_theta": 10000,
-    "mlp_hidden_act": "relu2", "tie_word_embeddings": False,
+    "architectures": ["OlmoHybridForCausalLM"], "model_type": "olmo_hybrid",
+    "vocab_size": 264, "hidden_size": 64, "intermediate_size": 128,
+    "num_hidden_layers": 8, "num_attention_heads": 4,
+    "num_key_value_heads": 4, "hidden_act": "silu",
+    "max_position_embeddings": 256, "attention_bias": False,
+    "rms_norm_eps": 1e-6, "tie_word_embeddings": False,
+    "layer_types": (["linear_attention"] * 3 + ["full_attention"]) * 2,
+    "linear_num_key_heads": 4, "linear_num_value_heads": 4,
+    "linear_key_head_dim": 6, "linear_value_head_dim": 12,
+    "linear_conv_kernel_dim": 4, "linear_allow_neg_eigval": True,
+    "rope_parameters": {"rope_theta": None},
 }
 
 
 @pytest.fixture(scope="module")
 def model():
     cfg = dataclasses.replace(
-        config_from_hf(HF, "tiny-nemotron-h"), dtype="float32"
+        config_from_hf(HF, "tiny-olmo-hybrid"), dtype="float32"
     )
     return cfg, init_params(cfg, jax.random.key(0), jnp.float32)
 
@@ -48,22 +48,25 @@ def prompt(n, start=5):
     return [(start + 7 * i) % 250 + 5 for i in range(n)]
 
 
-def test_the_engine_serves_the_reference_s_tokens_and_reports_both_kinds(model):
+def test_the_engine_serves_the_reference_s_tokens_and_counts_the_state(model):
     """Four requests over three slots (one slot changes hands), greedy:
     every token is the argmax of the reference's full forward over the
     prompt and what was generated so far."""
     cfg, params = model
     eng = LLMEngine(cfg, params, max_slots=3, max_seq_len=64)
     health = eng.health()
-    state = 3 * 3 * (4 * 8 * 16 * 4 + 3 * 96 * 4)    # float32 conv rows here
+    # 6 linear layers: [Dk, H * Dv] float32 and 3 rows of q | k | v
+    # (float32 conv rows here), nothing padded
+    state = 3 * 6 * (6 * 48 * 4 + 3 * 96 * 4)
     assert health["cache"] == {
-        "kv_bytes": 2 * 1 * 3 * 64 * 2 * 16 * 4, "state_bytes": state,
+        "kv_bytes": 2 * 2 * 3 * 64 * 4 * 16 * 4, "state_bytes": state,
         "state_dtype": "float32", "window_bytes": 0,
     }
+    assert health["state_mixer"] == "delta"
     assert (health["ssm_scan"], health["ssm_update"]) == (
         "chunked_einsum", "xla"
     )
-    assert health["kv_cache_bytes_per_token"] == 2 * 2 * 16 * 4
+    assert health["kv_cache_bytes_per_token"] == 2 * 4 * 16 * 4   # a layer
     reqs = [
         GenRequest(prompt_ids=prompt(n, n), max_tokens=6, temperature=0.0)
         for n in (7, 13, 20, 9)
@@ -83,54 +86,33 @@ def test_the_engine_serves_the_reference_s_tokens_and_reports_both_kinds(model):
     records = eng.flight.snapshot()
     assert sum(e["ssm_tokens"] for e in records) == 7 + 13 + 20 + 9
     assert max(e["state_slots"] for e in records) >= 1
+    assert {e["state_mixer"] for e in records} == {"delta"}
     text = "\n".join(eng.flight.metrics_lines())
     assert (
-        'gpustack_engine_ssm_tokens_total{kind="prefill",mixer="ssm"} 49'
+        'gpustack_engine_ssm_tokens_total{kind="prefill",mixer="delta"} 49'
         in text
     )
-    assert {e["state_mixer"] for e in records} == {"ssm"}
     decoded = re.search(
-        r'gpustack_engine_ssm_tokens_total\{kind="decode",mixer="ssm"\} (\d+)',
-        text,
+        r'gpustack_engine_ssm_tokens_total\{kind="decode",mixer="delta"\} '
+        r"(\d+)", text,
     )
     assert decoded and int(decoded.group(1)) >= 4 * 5
-
-
-def test_a_model_without_a_state_reports_none_of_it():
-    from gpustack_tpu.models.config import get_config
-
-    cfg = dataclasses.replace(get_config("tiny"), dtype="float32")
-    eng = LLMEngine(
-        cfg, init_params(cfg, jax.random.key(0), jnp.float32),
-        max_slots=2, max_seq_len=32,
-    )
-    health = eng.health()
-    assert health["cache"]["state_bytes"] == 0
-    assert health["cache"]["state_dtype"] is None
-    assert health["cache"]["kv_bytes"] == health["kv_cache_bytes"]
-    assert health["ssm_scan"] is None and health["ssm_update"] is None
-    eng.step()
-    assert "gpustack_engine_ssm_tokens_total" not in "\n".join(
-        eng.flight.metrics_lines()
-    )
 
 
 def test_a_slot_that_changes_hands_starts_clean_and_leaves_its_neighbours(model):
     cfg, params = model
     runner = ModelRunner(cfg, params, max_slots=3, max_seq_len=64)
     state = runner.new_state()
-    first = {}
     for slot, n in ((0, 9), (1, 17), (2, 5)):
         ids = prompt(n, slot)
         _, k, v, mixer = runner.prefill(ids + [0] * (32 - n), n)
-        first[slot] = mixer
+        assert mixer[0].shape == (6, 6, 48) and mixer[1].shape == (6, 288)
         state = runner.insert(
             state, k, v, slot, n, 7, 0.0, 0, 1.0, mixer=mixer
         )
     for _ in range(3):
         state, _ = runner.decode_step(state, jax.random.key(0))
     before = jnp.array(state.cache.ssm), jnp.array(state.cache.conv)
-    # slot 1 ends and is given to another prompt
     state = runner.deactivate(state, 1)
     ids = prompt(11, 40)
     _, k, v, mixer = runner.prefill(ids + [0] * (32 - 11), 11)
@@ -144,8 +126,6 @@ def test_a_slot_that_changes_hands_starts_clean_and_leaves_its_neighbours(model)
         np.testing.assert_array_equal(
             state.cache.conv[:, other], before[1][:, other]
         )
-    # nothing of the last tenant's is left: the new tenant decodes as if
-    # it had the slot from the start
     fresh = runner.insert(
         runner.new_state(), k, v, 1, 11, 7, 0.0, 0, 1.0, mixer=mixer
     )
@@ -160,37 +140,9 @@ def test_a_slot_that_changes_hands_starts_clean_and_leaves_its_neighbours(model)
     assert not np.asarray(blank.cache.conv[:, 2]).any()
 
 
-def test_snapshot_and_restore_carry_the_state(model):
-    """What a draft runner does around a proposal run: the steps in
-    between move every live slot's state, and restoring puts back the
-    snapshot's, bit for bit, with the positions and last tokens."""
-    cfg, params = model
-    runner = ModelRunner(cfg, params, max_slots=2, max_seq_len=64)
-    ids = prompt(12)
-    _, k, v, mixer = runner.prefill(ids + [0] * 20, 12)
-    state = runner.insert(
-        runner.new_state(), k, v, 0, 12, 9, 0.0, 0, 1.0, mixer=mixer
-    )
-    snap = runner.snapshot_sequence(state)
-    assert len(snap) == 4
-    state, first = runner.decode_step(state, jax.random.key(0))
-    for _ in range(2):
-        state, _ = runner.decode_step(state, jax.random.key(0))
-    assert float(jnp.abs(state.cache.ssm[:, 0] - snap[2][:, 0]).max()) > 1e-4
-    state = runner.restore_sequence(state, snap)
-    np.testing.assert_array_equal(state.cache.ssm, snap[2])
-    np.testing.assert_array_equal(state.cache.conv, snap[3])
-    assert int(state.positions[0]) == 12
-    _, again = runner.decode_step(state, jax.random.key(0))
-    np.testing.assert_array_equal(np.asarray(first[0]), np.asarray(again[0]))
-    np.testing.assert_allclose(
-        np.asarray(first[3])[0], np.asarray(again[3])[0], rtol=1e-5, atol=1e-5
-    )
-
-
 def test_an_ingest_takes_the_tokens_that_count_into_the_state(model):
-    """A draft's catch-up block is padded: the state moves over each
-    row's ``counts`` tokens and no further."""
+    """A padded block over a cache: the chunked form from a carried
+    state, over each row's ``counts`` tokens and no further."""
     cfg, params = model
     runner = ModelRunner(cfg, params, max_slots=2, max_seq_len=64)
     ids = prompt(10)
@@ -204,11 +156,10 @@ def test_an_ingest_takes_the_tokens_that_count_into_the_state(model):
     block = [[40, 41, 42, 0], [0, 0, 0, 0]]
     ingested = runner.ingest_step(seeded(), block, [3, 0])
     stepped = seeded()
-    for tok in (31, 40, 41):       # the verify feeding pattern: last first
+    for tok in (31, 40, 41):
         stepped = dataclasses.replace(
             stepped, last_tokens=stepped.last_tokens.at[0].set(tok)
         )
-        # greedy decode would sample its own token; feed ours
         stepped, _ = runner.decode_step(stepped, jax.random.key(0))
     np.testing.assert_allclose(
         ingested.cache.ssm[:, 0], stepped.cache.ssm[:, 0], rtol=2e-4, atol=2e-5
@@ -224,14 +175,13 @@ def test_an_ingest_takes_the_tokens_that_count_into_the_state(model):
     [
         ({"speculative": "ngram"}, "verify step"),
         ({"host_kv_cache_mb": 8}, "prefix cache"),
-        ({"host_kv_cache_mb": 8, "kv_spill_mb": 8}, "prefix cache"),
         ({"kv_spill_mb": 8}, "spill tier"),
         ({"kv_role": "prefill"}, "KV handoff"),
         ({"kv_role": "decode"}, "KV handoff"),
         ({"prefill_chunk": 16}, "chunk"),
     ],
-    ids=["speculative", "prefix_cache", "prefix_cache_and_spill", "spill",
-         "transfer_prefill", "transfer_decode", "chunked_prefill"],
+    ids=["speculative", "prefix_cache", "spill", "transfer_prefill",
+         "transfer_decode", "chunked_prefill"],
 )
 def test_what_would_move_a_slot_without_its_state_is_refused_at_the_start(
     model, asked, names
@@ -239,21 +189,13 @@ def test_what_would_move_a_slot_without_its_state_is_refused_at_the_start(
     cfg, params = model
     with pytest.raises(ValueError, match=names) as e:
         LLMEngine(cfg, params, max_slots=2, max_seq_len=32, **asked)
-    assert "state-space layers" in str(e.value) and cfg.name in str(e.value)
-
-
-def test_a_draft_model_for_a_hybrid_target_is_refused_too(model):
-    cfg, params = model
-    with pytest.raises(ValueError, match="speculative='draft'"):
-        LLMEngine(
-            cfg, params, max_slots=2, max_seq_len=32, speculative="draft",
-            draft_cfg=cfg, draft_params=params,
-        )
+    assert "linear-attention layers" in str(e.value)
+    assert cfg.name in str(e.value)
 
 
 @pytest.mark.parametrize(
-    "plan", [MeshPlan(sp=2), MeshPlan(tp=2), MeshPlan(ep=2), MeshPlan(dp=2)],
-    ids=["ring", "tp", "ep", "dp"],
+    "plan", [MeshPlan(sp=2), MeshPlan(tp=2), MeshPlan(dp=2)],
+    ids=["ring", "tp", "dp"],
 )
 def test_a_mesh_of_several_devices_is_refused_by_name(model, plan):
     cfg, params = model

@@ -116,7 +116,7 @@ def test_the_published_file_counts_the_published_parameters():
     assert held.state_bytes_per_slot(16) == 23 * (64 * 64 * 128 * 4 + 3 * 6144 * 2)
 
 
-@pytest.mark.parametrize("arch", ["CommandAForCausalLM", "OlmoHybridForCausalLM"])
+@pytest.mark.parametrize("arch", ["CommandAForCausalLM", "Zaya1ForCausalLM"])
 def test_an_architecture_of_no_family_is_refused_by_name(arch):
     hf = {**HF, "architectures": [arch], "model_type": "other"}
     with pytest.raises(ValueError, match=arch):

@@ -418,7 +418,7 @@ class TestOverhead:
                 phases_s=(1e-5, 1e-6, 0.0, 2e-4, 8e-4),
                 admitted=[("t", 0.01)], first_tokens=[("t", 0.3)],
                 kv_live=900, kv_allocated=4096, moe_read=40, moe_held=128,
-                ssm=(4, 0),
+                ssm=(4, 0, "ssm"),
             )
             records.append(fr._record_s - before)
             steps.append(t1 - t0)

@@ -44,6 +44,7 @@ PROGRAMS = (
      "nemotron-3-nano-30b-a3b-int8-ep8", 0, 32, 4096, 1024),
     ("command-a-plus-int8-ep8-l8", "command-a-plus-int8-ep8-l8",
      0, 16, 8192, 4096),
+    ("olmo-hybrid-7b-int8", "olmo-hybrid-7b-int8", 0, 12, 2560, 1024),
 )
 
 
@@ -77,7 +78,7 @@ def lowered(one_chip) -> dict:
         )
         if layers:
             cfg = dataclasses.replace(cfg, num_layers=layers)
-        hybrid = cfg.layer_kinds is not None
+        hybrid = cfg.state_mixer is not None   # a state a slot
         params = shapes(
             lambda: quantize_params(init_params(cfg, jax.random.key(0)))
         )

@@ -1101,6 +1101,138 @@ def test_a_window_stack_s_prefill_runs_the_band_and_no_score_tensor(one_chip):
     assert mem.temp_size_in_bytes < 2.5e9
 
 
+OLMO_DIR = "perfbench/configs/olmo-hybrid-7b-int8"
+
+
+def _olmo(periods: int = 2):
+    """The benchmark's Olmo-Hybrid-7B at its published widths, ``periods``
+    periods of three linear-attention layers and a full one."""
+    import os
+
+    from gpustack_tpu.models.config import load_hf_config
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)
+    )))
+    cfg = load_hf_config(os.path.join(root, OLMO_DIR))
+    return dataclasses.replace(
+        cfg, num_layers=4 * periods, layer_types=cfg.layer_types[:4 * periods],
+    )
+
+
+def test_a_delta_stack_s_decode_step_moves_state_and_rows_in_place(one_chip):
+    """The decode program of the benchmark's Olmo-Hybrid as the runner
+    traces it on one TPU chip, 12 slots of 2,560: each linear-attention
+    layer's ``delta_state_update`` reads and writes the stacked state
+    ``[L, B, 96, 5760]`` where it lies (nothing padded: 45 whole lane
+    tiles; donated and aliased, no copy, slice or update of it), the full
+    layer's GQA kernel walks rows of **32 stored heads** in place (at 30
+    the TPU stores the rows with positions on the sublanes, and the
+    program copied both caches whole into the other order and back every
+    step: ``ModelConfig.kv_heads_stored``), every layer's matrices read
+    where they lie in their stacks."""
+    from gpustack_tpu.models import init_params
+    from gpustack_tpu.models.hybrid import ssm_update_impl
+    from gpustack_tpu.models.quant import quantize_params
+    from gpustack_tpu.models.transformer import (
+        KVCache,
+        decode_attention_impl,
+        forward,
+    )
+
+    cfg = _olmo()
+    slots, S = 12, 2560
+    assert (cfg.num_kv_heads, cfg.kv_heads_stored) == (30, 32)
+    assert decode_attention_impl(cfg, 1, S, "tpu", None) == "kernel"
+    assert ssm_update_impl(1, "tpu", None) == "kernel"
+    params = _shapes_on(
+        one_chip, lambda: quantize_params(init_params(cfg, jax.random.key(0)))
+    )
+    cache = _shapes_on(one_chip, lambda: KVCache.create(cfg, slots, S))
+    assert cache.ssm.shape == (6, slots, 96, 5760)
+    assert cache.ssm.shape[-1] % 128 == 0 and cache.ssm.shape[-2] % 8 == 0
+    assert cache.k.shape == cache.v.shape == (2, slots, S, 32, 128)
+    tokens = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)
+
+    def step(params, tokens, positions, cache, live):
+        return forward(
+            params, cfg, tokens, positions, cache, live=live,
+            decode_attn_impl="kernel", ssm_impl="kernel",
+        )
+
+    compiled = jax.jit(step, donate_argnums=(3,)).lower(
+        params, tokens, tokens, cache, live
+    ).compile()
+    text = compiled.as_text()
+    state = f"f32[6,{slots},96,5760]"
+    rows = f"bf16[2,{slots},{S},32,128]"
+    # one period in the scan's body: three updates and a full layer
+    assert len(re.findall(
+        rf"%delta_state_update[\w.\-]* = \({re.escape(state)}", text
+    )) == 3
+    assert len(re.findall(
+        rf"%gqa_decode_attention[\w.\-]* = bf16\[{slots},32,128\]"
+        r".* custom-call\(", text,
+    )) == 1
+    assert not re.findall(
+        rf"= {re.escape(state)}[^ ]* (?:copy|dynamic-update-slice|"
+        r"dynamic-slice|transpose)\(", text,
+    )
+    # the step's own rows are written into the donated caches; neither
+    # is copied or stored in another order (any order of its axes)
+    assert not re.findall(r"= bf16\[2,12,[\d,]+\][^ ]* (?:copy|transpose)\(", text)
+    assert rows in text
+    # the mixers' and the MLP's matrices go in as they are stored
+    assert not re.findall(
+        r"= s8\[(?:\d+,)?(?:3840,(?:2880|5760|3840|11008)|"
+        r"(?:5760|11008),3840)\][^ ]* (?:copy|transpose)\(", text,
+    )
+    mem = compiled.memory_analysis()
+    held = (cache.ssm.size * 4 + 2 * cache.k.size * 2)
+    assert mem.alias_size_in_bytes >= held
+    # no copy of the state (0.16 GB here) or of a cache (0.5 GB)
+    assert mem.temp_size_in_bytes < 16 * 2**20
+
+
+def test_a_delta_stack_s_prefill_keeps_its_temporaries_small(one_chip):
+    """A 1,024 prefill of one period at the published widths: the three
+    linear layers through the chunked rule as einsums in float32 (16
+    chunks of 64, the solve for all heads and chunks together), the full
+    layer through the flash kernel at 32 stored heads; temporaries that
+    leave the resident model room (0.30 GB at full depth beside 12.1)."""
+    from gpustack_tpu.models import init_params
+    from gpustack_tpu.models.quant import quantize_params
+    from gpustack_tpu.models.transformer import KVCache, forward
+
+    cfg = _olmo(1)
+    T = 1024
+    params = _shapes_on(
+        one_chip, lambda: quantize_params(init_params(cfg, jax.random.key(0)))
+    )
+
+    def prefill(params, tokens, true_len):
+        cache = KVCache.create(cfg, 1, T)
+        positions = jnp.arange(T, dtype=jnp.int32)[None]
+        return forward(
+            params, cfg, tokens, positions, cache, attn_impl="flash",
+            logits_at=(true_len - 1)[None], true_len=true_len[None],
+            ssm_impl="scan",
+        )
+
+    compiled = jax.jit(prefill).lower(
+        params,
+        jax.ShapeDtypeStruct((1, T), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(
+        r"%flash_attention_prefill[\w.\-]* = .* custom-call\(", text
+    )) == 1
+    _no_score_tensor(text, T, T)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2**30
+
+
 @pytest.fixture(scope="module")
 def lowered_hashes(one_chip):
     import os
@@ -1126,11 +1258,13 @@ def _lowered_names():
 def test_the_other_models_programs_lower_to_the_text_they_had(
     lowered_hashes, program
 ):
-    """The decode and prefill programs of the benchmark's five
+    """The decode and prefill programs of the benchmark's six
     configurations, lowered for the chip at their cells' shapes, are to
     the letter what they were when their hashes were taken: the first
     four before the window store, the band and the parallel block went
     into ``forward``, Command A+'s before the three copies of a GQA
-    layer over a cache became one (``lowered_programs.py`` says what is
-    hashed and how to take the hashes again on purpose)."""
+    layer over a cache became one, those five unchanged when the mixer
+    by kind and the stored kv heads went in (PR 53), Olmo-Hybrid's with
+    that PR (``lowered_programs.py`` says what is hashed and how to
+    take the hashes again on purpose)."""
     assert lowered_hashes[program] == _lowered_names()[program]

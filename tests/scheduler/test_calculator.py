@@ -114,7 +114,7 @@ def test_resolve_errors():
         )
 
 
-def _nemotron(**spec):
+def _benchmark_s(directory, **spec):
     import os
 
     root = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -122,9 +122,13 @@ def _nemotron(**spec):
     )))
     return Model(
         name="m", quantization="int8", local_path=os.path.join(
-            root, "perfbench", "configs", "nemotron-3-nano-30b-a3b-int8-ep8"
+            root, "perfbench", "configs", directory
         ), **spec,
     )
+
+
+def _nemotron(**spec):
+    return _benchmark_s("nemotron-3-nano-30b-a3b-int8-ep8", **spec)
 
 
 def test_a_hybrid_claims_its_slots_states_beside_the_rows_of_six_layers():
@@ -153,3 +157,27 @@ def test_a_hybrid_that_does_not_fit_one_chip_is_not_spread_over_more():
     ev = evaluate_model(_nemotron(max_seq_len=4096, max_slots=160))
     assert ev.total_bytes > 16 * _GIB
     assert chips_for_claim(ev, hbm_per_chip=16 * _GIB, max_chips=8) is None
+
+
+def test_a_delta_rule_stack_claims_its_matrix_states_beside_eight_layers_rows():
+    """Olmo-Hybrid-7B whole, as the benchmark deploys it: 7.43 B
+    parameters at a byte, and a slot a matrix state of 24 layers (54.7
+    MB whatever the context, nothing padded) and rows of the 8 attention
+    layers only, 32 stored heads for the 30 (128 KB a position)."""
+    ev = evaluate_model(
+        _benchmark_s("olmo-hybrid-7b-int8", max_seq_len=2560, max_slots=12)
+    )
+    assert ev.config.layer_types is not None
+    assert 7.4e9 < ev.weight_bytes < 7.6e9
+    state = 24 * (96 * 5760 * 4 + 3 * 11520 * 2)
+    rows = 8 * 2 * 32 * 128 * 2 * 2560
+    assert ev.kv_cache_bytes == 12 * (state + rows)
+    assert round(state / 1e6, 1) == 54.7 and round(rows / 1e6, 1) == 335.5
+    claim = chips_for_claim(ev, hbm_per_chip=16 * _GIB, max_chips=8)
+    assert claim is not None and claim.chips == 1
+    # served on one device: what does not fit one chip is not spread
+    more = evaluate_model(
+        _benchmark_s("olmo-hybrid-7b-int8", max_seq_len=2560, max_slots=24)
+    )
+    assert more.total_bytes > 16 * _GIB
+    assert chips_for_claim(more, hbm_per_chip=16 * _GIB, max_chips=8) is None
