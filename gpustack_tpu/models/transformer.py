@@ -288,6 +288,7 @@ def _write_rows(
     layer: jax.Array,    # int32 scalar
     start: jax.Array,    # [B] int32, each row's first position
     by_position: bool = False,
+    decode_attn_impl: str = "xla",
 ) -> jax.Array:
     """``buf`` with ``rows[b]`` at ``[layer, b, start[b]:start[b]+T]``
     (the bounds contract is ``KVCache``'s).
@@ -309,16 +310,30 @@ def _write_rows(
     step (compiled for a described v5e: 4 GB of temporaries at 16 slots
     of 8,192). Where its width is no whole number of lane tiles (the
     rope keys, 64) the TPU stores it with the **positions on the
-    lanes**, which no scatter writes in place: the pass over the layer's
-    positions does, whatever the layout (17 MB a layer at 16 slots of
-    8,192; the program still copies that array whole once in and once
-    out a step, 0.2 GB and 0.37 ms each: ROADMAP M1).
+    lanes**, which no scatter writes in place. A decode step whose
+    attention is the kernel's (``decode_attn_impl`` not ``"xla"``: one
+    chip, one row a slot) writes it through the aliased call that lies
+    beside that kernel, a lane tile a slot
+    (``ops/mla_attention.py mla_write_rope_keys``). The pass over the
+    layer's positions writes it whatever the layout, and is what a mesh
+    (a Mosaic call is not partitioned), any other platform, ``T > 1``
+    over a cache (a prefill, a verify step, a continuation, a chunk) and
+    ``by_position`` take: 17 MB read and written a layer at 16 slots of
+    8,192, and the array copied whole once in and once out of the scan
+    (0.2 GB and 0.6 ms each in a decode step: PERF.md section 6, PR 54).
     """
     T = rows.shape[1]
     one_head = buf.ndim == 4
     # a row's trailing axes, for what is indexed by [B, S_max] or [B, T]
     each = (slice(None), slice(None)) + (None,) * (rows.ndim - 2)
     if by_position or (one_head and buf.shape[3] % 128):
+        if T == 1 and not by_position and decode_attn_impl != "xla":
+            from gpustack_tpu.ops.mla_attention import mla_write_rope_keys
+
+            return mla_write_rope_keys(
+                buf, rows[:, 0], layer, start,
+                interpret=decode_attn_impl == "kernel_interpret",
+            )
         at = jnp.arange(buf.shape[2], dtype=jnp.int32)[None, :] - jnp.clip(
             start, 0, buf.shape[2] - T
         )[:, None]                                    # [B, S_max] into rows
@@ -1800,7 +1815,10 @@ def forward(
         )                                               # [B, T, 1, rope]
         if carried is not None:
             # the cache rides the scan without its one head (below)
-            write = partial(_write_rows, layer=layer, start=positions[:, 0])
+            write = partial(
+                _write_rows, layer=layer, start=positions[:, 0],
+                decode_attn_impl=decode_attn_impl,
+            )
             carried = KVCache(
                 k=write(carried.k, c_kv), v=write(carried.v, k_pe[:, :, 0])
             )

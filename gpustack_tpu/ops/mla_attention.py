@@ -1,4 +1,5 @@
-"""Pallas TPU kernel for a decode step's attention over the latent cache.
+"""Pallas TPU kernels for a decode step over the latent cache: its
+attention, and the write of its rope keys.
 
 Latent attention (MLA, DeepSeek-V2/V3 family) caches, for each position
 of a layer, the shared latent ``c_kv`` (``kv_lora_rank`` wide) and the
@@ -33,8 +34,19 @@ here is free and the score product
 over them a plain matmul (``tests/ops/test_chip_compile.py`` holds the
 decode program to having no copy of either cache).
 
-``tests/ops/test_mla_attention.py`` holds it to the XLA formulation in
-interpret mode; ``tests/ops/test_chip_compile.py`` compiles it for a
+That layout is also why the step's rope keys are written here
+(:func:`mla_write_rope_keys`) and not by ``transformer._write_rows``'
+scatter: one position of a slot is one **lane** of 64 sublanes, which
+no XLA form writes in place. An aliased call on the same view does: a
+grid point a slot fetches the tile of 128 positions that holds the
+slot's position, puts the key on its lane and writes the tile back,
+16 KB each way, where the pass it replaces read and wrote the layer and
+had the array copied whole in and out of the scan (1.8 of A.X-K1's
+9.3 ms step: PERF.md section 6, PR 54). The latent keeps the scatter.
+
+``tests/ops/test_mla_attention.py`` holds the attention to the XLA
+formulation and the write to ``_write_rows``' pass, bit for bit, in
+interpret mode; ``tests/ops/test_chip_compile.py`` compiles both for a
 described v5e at A.X-K1's widths.
 """
 
@@ -184,3 +196,64 @@ def mla_decode_attention(
         jnp.reshape(layer, (1,)).astype(jnp.int32),
         q_lat, q_pe, c_cache, jnp.transpose(r_cache, (0, 1, 3, 2)),
     )
+
+
+def _write_kernel(layer_ref, start_ref, k_ref, r_ref, o_ref):
+    """Grid point = slot: the step's key onto its lane of the tile."""
+    del layer_ref
+    lane = start_ref[pl.program_id(0)] % r_ref.shape[1]
+    at = lax.broadcasted_iota(jnp.int32, r_ref.shape, 1)
+    o_ref[...] = jnp.where(at == lane, k_ref[...], r_ref[...])
+
+
+def mla_write_rope_keys(
+    r_cache: jax.Array,   # [L, B, S, rope]: KVCache.v, without its one head
+    k_pe: jax.Array,      # [B, rope]: the step's rope key a slot, rotated
+    layer: jax.Array,     # int32 scalar: which of the L
+    start: jax.Array,     # int32 [B]: each slot's position
+    *,
+    interpret: bool = False,
+) -> jax.Array:
+    """``r_cache`` with ``k_pe[b]`` at ``[layer, b, start[b]]``, a start
+    clamped into ``[0, S - 1]`` (``transformer._write_rows``' contract at
+    one row a slot; every slot's row is written, held or not).
+
+    In place, on the array as the TPU stores it, positions on the lanes
+    (the view :func:`mla_decode_attention` reads): of each slot the one
+    tile of 128 positions that holds its row is fetched, the key put on
+    its lane, and the tile written back into the aliased cache, 16 KB
+    each way a slot. No XLA form writes a column of such an array in
+    place: a scatter relays the whole array out and back, the pass over
+    the layer's positions reads and writes the layer."""
+    L, B, S, rope = r_cache.shape
+    tile = _LANES if S % _LANES == 0 else S
+    start = jnp.clip(start, 0, S - 1)
+
+    def r_tile(b, layer, start):
+        return (layer[0], b, 0, start[b] // tile)
+
+    view = pl.pallas_call(
+        _write_kernel,
+        out_shape=jax.ShapeDtypeStruct((L, B, rope, S), r_cache.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((None, rope, 1), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec((None, None, rope, tile), r_tile),
+            ],
+            out_specs=pl.BlockSpec((None, None, rope, tile), r_tile),
+        ),
+        # operand 3 (after the two prefetched scalars and the keys)
+        input_output_aliases={3: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+        ),
+        name="mla_write_rope_keys",
+        interpret=interpret,
+    )(
+        jnp.reshape(layer, (1,)).astype(jnp.int32), start,
+        k_pe[:, :, None],
+        jnp.transpose(r_cache, (0, 1, 3, 2)),
+    )
+    return jnp.transpose(view, (0, 1, 3, 2))

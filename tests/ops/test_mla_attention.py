@@ -6,7 +6,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from gpustack_tpu.ops.mla_attention import block_positions, mla_decode_attention
+from gpustack_tpu.ops.mla_attention import (
+    block_positions,
+    mla_decode_attention,
+    mla_write_rope_keys,
+)
 
 
 def xla(q_lat, q_pe, c, r, layer, lengths, scale):
@@ -79,3 +83,129 @@ def test_the_block_divides_the_cache_or_there_is_none():
     assert block_positions(64) == 64          # one block, the tests' caches
     assert block_positions(1000) is None      # forward takes the XLA form
     assert block_positions(100) is None
+
+
+# ---- a decode step's rope keys, written in place (mla_write_rope_keys) ----
+
+ROPE_S = 256     # two lane tiles a slot
+
+
+def _rope_cache(L, B, S, dtype=jnp.bfloat16):
+    keys = jax.random.split(jax.random.key(L * S + B), 2)
+    return (
+        jax.random.normal(keys[0], (L, B, S, 64), jnp.float32).astype(dtype),
+        jax.random.normal(keys[1], (B, 1, 64), jnp.float32).astype(dtype),
+    )
+
+
+@pytest.mark.parametrize("layer", [0, 2], ids=["first-layer", "last-layer"])
+@pytest.mark.parametrize(
+    "starts",
+    [
+        [0, 0, 0, 0],                     # every slot at the same position
+        [127, 128, 1, 254],               # a tile's last lane, the next's first
+        [255, 255, 0, 128],               # S_max - 1
+        [256, 300, 9000, 2 ** 31 - 1],    # past S_max: clamped to the last
+        [-1, -128, -(2 ** 31), 5],        # negative: clamped to the first
+        [3, 77, 130, 201],                # all at different ones
+    ],
+    ids=["same", "tile-edge", "last", "past-the-end", "negative", "different"],
+)
+def test_the_rope_key_write_is_write_rows_pass_bit_for_bit(starts, layer):
+    """The aliased call against ``_write_rows``' pass over the layer, on
+    the bits: the step's keys where the pass puts them, and every other
+    layer and position of the cache as it was."""
+    from gpustack_tpu.models.transformer import _write_rows
+
+    cache, rows = _rope_cache(3, len(starts), ROPE_S)
+    start = jnp.asarray(starts, jnp.int32)
+    want = _write_rows(cache, rows, jnp.int32(layer), start)
+    got = mla_write_rope_keys(
+        cache, rows[:, 0], jnp.int32(layer), start, interpret=True
+    )
+    bits = lambda a: np.asarray(a).view(np.uint16)
+    np.testing.assert_array_equal(bits(got), bits(want))
+    # and the oracle did write: one row a slot differs from the old cache
+    at = np.clip(starts, 0, ROPE_S - 1)
+    changed = (bits(want) != bits(cache)).any(axis=-1)
+    assert changed.sum() == len(starts)
+    assert changed[layer, np.arange(len(starts)), at].all()
+
+
+@pytest.mark.parametrize(
+    "S,dtype", [(64, jnp.float32), (1536, jnp.bfloat16)],
+    ids=["one-short-tile-f32", "twelve-tiles"],
+)
+def test_the_rope_key_write_takes_the_tests_caches_and_float32(S, dtype):
+    from gpustack_tpu.models.transformer import _write_rows
+
+    cache, rows = _rope_cache(2, 3, S, dtype)
+    start = jnp.asarray([S - 1, 0, S // 2 + 1], jnp.int32)
+    # through _write_rows, as forward calls it
+    write = lambda impl: _write_rows(
+        cache, rows, jnp.int32(1), start, decode_attn_impl=impl
+    )
+    np.testing.assert_array_equal(
+        np.asarray(write("kernel_interpret"), np.float32),
+        np.asarray(write("xla"), np.float32),
+    )
+
+
+MLA_HF = {
+    "architectures": ["DeepseekV3ForCausalLM"], "model_type": "axk1",
+    "vocab_size": 264, "hidden_size": 64, "intermediate_size": 160,
+    "num_hidden_layers": 2, "num_attention_heads": 4,
+    "num_key_value_heads": 4, "q_lora_rank": 48, "kv_lora_rank": 128,
+    "qk_nope_head_dim": 16, "qk_rope_head_dim": 64, "v_head_dim": 16,
+    "rms_norm_eps": 1e-6, "rope_theta": 10000,
+    "max_position_embeddings": 512, "first_k_dense_replace": 1,
+    "n_routed_experts": 8, "n_shared_experts": 1,
+    "num_experts_per_tok": 2, "moe_intermediate_size": 32,
+    "n_group": 2, "topk_group": 1, "norm_topk_prob": True,
+    "scoring_func": "sigmoid", "topk_method": "none",
+    "routed_scaling_factor": 2.5, "tie_word_embeddings": False,
+}
+
+
+def test_a_decode_step_of_a_two_layer_model_writes_the_xla_step_s_cache():
+    """One decode step of a two-layer A.X-K1 through ``forward``, the
+    kernels' step (attention and the rope keys' write, interpret mode)
+    against the XLA step: the first layer's rows and every position the
+    step does not write on the bits, the second layer's rows (behind the
+    first's attention, whose two forms round apart) and the logits to
+    float32's rounding."""
+    import dataclasses
+
+    from gpustack_tpu.models.config import config_from_hf
+    from gpustack_tpu.models.transformer import KVCache, forward, init_params
+
+    cfg = dataclasses.replace(
+        config_from_hf(MLA_HF, "tiny-axk1"), dtype="float32"
+    )
+    params = init_params(cfg, jax.random.key(0), dtype=jnp.float32)
+    B, S = 3, 256
+    keys = jax.random.split(jax.random.key(4), 3)
+    empty = KVCache.create(cfg, B, S)
+    cache = KVCache(
+        k=jax.random.normal(keys[0], empty.k.shape, empty.k.dtype),
+        v=jax.random.normal(keys[1], empty.v.shape, empty.v.dtype),
+    )
+    toks = jax.random.randint(keys[2], (B, 1), 0, cfg.vocab_size)
+    pos = jnp.asarray([[127], [128], [255]], jnp.int32)
+    (want, want_cache), (got, got_cache) = (
+        forward(params, cfg, toks, pos, cache, decode_attn_impl=impl)
+        for impl in ("xla", "kernel_interpret")
+    )
+    written = np.zeros((2, B, S), bool)
+    written[:, np.arange(B), np.asarray(pos[:, 0])] = True
+    for name in ("k", "v"):
+        new, ref, old = (
+            np.asarray(getattr(c, name)) for c in (got_cache, want_cache, cache)
+        )
+        np.testing.assert_array_equal(new[0], ref[0])
+        np.testing.assert_array_equal(new[~written], old[~written])
+        assert (new[written] != old[written]).any(axis=(-1, -2)).all()
+        np.testing.assert_allclose(new, ref, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), atol=2e-4, rtol=2e-4
+    )
