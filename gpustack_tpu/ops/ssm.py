@@ -30,6 +30,24 @@ nothing: that is how a caller keeps padding out of it.
   through a matmul's rounding. :func:`ssm_step_xla` is the same step as
   XLA operations, for any other platform and for the tests.
 
+  Which unit does what in the kernel's body. The state lies ``[P, N]``
+  a head with the contracted width ``N`` on the lanes, so a register of
+  state (8 rows of ``P``) needs its rows' ``dt x`` on every lane, and
+  its rows' sums over the lanes for ``y``: two crossings of the lanes a
+  register, and on a v5e the three cross-lane units' time for them,
+  not the 2 MB a slot moves, was what a call took (PERF.md section 6,
+  PR 55). So: the *vector unit* does the arithmetic (two products and a
+  sum a register for the state, one more product for the readout), with
+  the decay one scalar a head from SMEM; the *matrix unit* does no
+  arithmetic, only the spreading of ``dt x`` over the lanes (a head's
+  three bfloat16 parts against ones: three exact products and their
+  exact sum, :func:`_update_kernel`); the *cross-lane units* are left
+  the readout's one reduction a register, which is what they are then
+  busy with for four fifths of the body. ``ops/delta_rule.py``'s
+  kernel has the same outside and needs none of this: its state lies
+  with the contracted axis on the **sublanes**, where a reduction is
+  additions of whole registers.
+
 ``tests/ops/test_ssm.py`` holds all three to the recurrence taken one
 position at a time.
 
@@ -139,34 +157,67 @@ def ssm_step_xla(
     )
 
 
+# how many heads' ``dt x`` one tile of 128 lanes holds as three parts
+_HEADS_A_TILE = 128 // 3
+
+
+def _bf16_parts(v: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``v`` (float32) as three float32 arrays, each exactly a bfloat16
+    (the top 8 bits of what the ones before it left) and summing to ``v``
+    exactly, in any order."""
+    def top(a):
+        bits = lax.bitcast_convert_type(a, jnp.uint32)
+        return lax.bitcast_convert_type(
+            bits & jnp.uint32(0xFFFF0000), jnp.float32
+        )
+
+    first = top(v)
+    rest = v - first
+    second = top(rest)
+    return first, second, rest - second
+
+
 def _update_kernel(
-    live_ref, name_ref, layer_ref, xdt_ref, da_ref, b_ref, c_ref, h_ref,
+    live_ref, name_ref, layer_ref, da_ref, xdt_ref, b_ref, c_ref, h_ref,
     h_out_ref, y_ref, *, heads: int, per_group: int,
 ):
     """Grid point = one slot: its ``heads`` states ``[P, N]``, ``N`` on
-    the lanes. ``xdt`` and ``da`` come ``[P, heads]``, a head's values a
-    column, which broadcasts over the lanes as it is; ``B`` and ``C``
-    ``[G, N]``, a group's a row, which broadcasts over the sublanes."""
+    the lanes, a head at a time. ``B`` and ``C`` come ``[G, N]``, a
+    group's a row, which broadcasts over the sublanes; the decay is one
+    scalar a head, read from SMEM. ``dt x`` is one number a row of the
+    state and has to lie on every lane of that row: ``xdt_ref`` holds it
+    ``[P, tiles * 128]``, a tile the three bfloat16 parts
+    (:func:`_bf16_parts`) of up to ``_HEADS_A_TILE`` heads, and the
+    matrix unit spreads it: a head's three lanes, the others masked,
+    against a matrix of ones is each row's ``dt x`` on every lane,
+    exactly. The cross-lane units are left the readout's reduction, one
+    a register."""
     del name_ref, layer_ref
     b = pl.program_id(0)
 
     @pl.when(live_ref[b] > 0)
     def _slot():
-        lane = lax.broadcasted_iota(jnp.int32, y_ref.shape, 1)
-        y = jnp.zeros(y_ref.shape, jnp.float32)
+        lane = lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+        ones = jnp.ones((128, h_ref.shape[-1]), jnp.bfloat16)
         for j in range(heads):
             g = j // per_group
+            tile, at = divmod(j, _HEADS_A_TILE)
+            w = min(_HEADS_A_TILE, heads - tile * _HEADS_A_TILE)
+            mine = (lane == at) | (lane == w + at) | (lane == 2 * w + at)
+            xdt = jnp.dot(
+                jnp.where(
+                    mine, xdt_ref[:, tile * 128:(tile + 1) * 128], 0.0
+                ).astype(jnp.bfloat16),
+                ones, preferred_element_type=jnp.float32,
+            )
             new = (
-                da_ref[:, j:j + 1] * h_ref[j].astype(jnp.float32)
-                + xdt_ref[:, j:j + 1] * b_ref[g:g + 1, :]
+                da_ref[b, j] * h_ref[j].astype(jnp.float32)
+                + xdt * b_ref[g:g + 1, :]
             )
             h_out_ref[j] = new.astype(h_out_ref.dtype)
-            y = jnp.where(
-                lane == j,
-                jnp.sum(new * c_ref[g:g + 1, :], axis=1, keepdims=True),
-                y,
+            y_ref[:, j:j + 1] = jnp.sum(
+                new * c_ref[g:g + 1, :], axis=1, keepdims=True
             )
-        y_ref[...] = y
 
     @pl.when(live_ref[b] == 0)
     def _nobody():
@@ -193,11 +244,18 @@ def ssm_state_update(
     G = Bm.shape[1]
     f32 = jnp.float32
     dt = dt.astype(f32)
-    # a head's values a column: [B, P, H]
-    xdt = jnp.swapaxes(dt[..., None] * x.astype(f32), 1, 2)
-    da = jnp.broadcast_to(
-        jnp.exp(dt * A.astype(f32))[:, None, :], (B, P, H)
-    )
+    # [B, P, tiles * 128]: a row of the state a sublane; a tile its
+    # heads' first parts, then their second, then their third
+    parts = _bf16_parts(jnp.swapaxes(dt[..., None] * x.astype(f32), 1, 2))
+    xdt = jnp.concatenate([
+        jnp.pad(
+            jnp.concatenate(
+                [p[..., first:first + _HEADS_A_TILE] for p in parts], axis=-1
+            ),
+            ((0, 0), (0, 0), (0, 128 - 3 * min(_HEADS_A_TILE, H - first))),
+        )
+        for first in range(0, H, _HEADS_A_TILE)
+    ], axis=-1)
     # a slot nobody holds names the nearest live slot before it (before
     # the first live one, that one), whose block is resident already
     slots = jnp.arange(B, dtype=jnp.int32)
@@ -207,7 +265,7 @@ def ssm_state_update(
     def small(b, *_):
         return (b, 0, 0)
 
-    def block(b, live_ref, name_ref, layer_ref):
+    def block(b, live_ref, name_ref, layer_ref, da_ref):
         return (layer_ref[0], name_ref[b], 0, 0, 0)
 
     state_spec = pl.BlockSpec((None, None, H, P, N), block)
@@ -218,18 +276,17 @@ def ssm_state_update(
             jax.ShapeDtypeStruct((B, P, H), f32),
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(B,),
             in_specs=[
-                pl.BlockSpec((None, P, H), small),
-                pl.BlockSpec((None, P, H), small),
+                pl.BlockSpec((None, P, xdt.shape[-1]), small),
                 pl.BlockSpec((None, G, N), small),
                 pl.BlockSpec((None, G, N), small),
                 state_spec,
             ],
             out_specs=[state_spec, pl.BlockSpec((None, P, H), small)],
         ),
-        # operand 7 (after the three prefetched) is the state: result 0
+        # operand 7 (after the four prefetched) is the state: result 0
         input_output_aliases={7: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -240,6 +297,7 @@ def ssm_state_update(
     )(
         live.astype(jnp.int32), name,
         jnp.reshape(layer, (1,)).astype(jnp.int32),
-        xdt, da, Bm.astype(f32), Cm.astype(f32), state,
+        jnp.exp(dt * A.astype(f32)),
+        xdt, Bm.astype(f32), Cm.astype(f32), state,
     )
     return jnp.swapaxes(y, 1, 2), state

@@ -912,6 +912,18 @@ def test_the_hybrid_decode_step_moves_its_state_in_place(one_chip):
         rf"= {re.escape(state)}[^ ]* (?:copy|dynamic-update-slice|"
         r"dynamic-slice|transpose)\(", text,
     )
+    # the decay goes in as one scalar a head (SMEM), not spread over a
+    # head's rows outside the kernel and picked back out of a lane
+    # inside it: no operand of the call is built by a broadcast
+    for operands in re.findall(
+        r"%ssm_state_update[\w.\-]* = .* custom-call\(([^)]*)\)", text
+    ):
+        for name in re.findall(r"%[\w.\-]+", operands):
+            made = re.search(
+                rf"^\s*{re.escape(name)} = (\S+) ([\w\-]+)\(", text, re.M
+            )
+            assert made and made.group(2) != "broadcast", (name, made)
+            assert not made.group(1).startswith(f"f32[{slots},64,64]"), made
     assert re.search(
         rf"%gqa_decode_attention[\w.\-]* = bf16\[{slots},32,128\]"
         r".* custom-call\(", text,
@@ -938,6 +950,9 @@ def test_the_hybrid_decode_step_moves_its_state_in_place(one_chip):
     assert mem.alias_size_in_bytes >= state_bytes
     # no copy of the state (0.2 GB here, 1.57 GB at full depth)
     assert mem.temp_size_in_bytes < 0.25 * state_bytes
+    # and no more than the program held before the kernel's body changed
+    # (PR 54's tree, this compile: 3,354,624)
+    assert mem.temp_size_in_bytes <= 3_354_624
 
 
 def test_the_touched_kernel_takes_two_matrix_experts_of_1920(one_chip):
