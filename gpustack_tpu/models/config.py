@@ -34,9 +34,11 @@ class ModelConfig:
     dense stacks, Mixtral / Qwen-MoE / GPT-OSS / DeepSeek (MLA) expert
     stacks, each layer attention + MLP; the Nemotron-H hybrid
     (``layer_kinds``), each layer ONE mixer: a Mamba-2 state-space
-    mixer, a mixture of two-matrix experts, or GQA; and the Olmo hybrid
-    (``layer_types``), each layer a mixer by kind (a gated-delta-rule
-    linear-attention mixer or full attention) and then an MLP.
+    mixer, a mixture of two-matrix experts, or GQA; and the hybrids with
+    ``layer_types`` (Olmo-Hybrid, Granite 4.0-H), each layer a mixer by
+    kind (a gated-delta-rule linear-attention mixer, a Mamba-2 mixer or
+    full attention) and then an MLP. The Mamba-2 mixer is one function
+    for both (``models/hybrid.py mamba_mixer``).
 
     Two kinds of layer keep a state a slot beside the rows a position
     (a Mamba-2 mixer's, a delta-rule mixer's); :attr:`state_shapes` is
@@ -69,7 +71,9 @@ class ModelConfig:
     # ---- Gemma-family knobs (Gemma2/Gemma3 text) ----
     hidden_act: str = "silu"        # "gelu_tanh" for gemma
     norm_delta_gain: bool = False   # RMSNorm gain stored as (1 + w)
-    embed_scale: bool = False       # scale embeddings by sqrt(hidden)
+    # the embeddings times this number: sqrt(hidden) for gemma, a
+    # Granite file's ``embedding_multiplier``
+    embed_multiplier: float = 1.0
     post_norms: bool = False        # sandwich post-attn/post-mlp norms
     query_pre_attn_scalar: float = 0.0  # 0 = scale by 1/sqrt(head_dim)
     attn_logit_softcap: float = 0.0     # 0 = no softcapping
@@ -176,12 +180,19 @@ class ModelConfig:
     # False: a sigmoid router without the selection's correction bias
     router_correction_bias: bool = True
     logit_scale: float = 1.0
-    # ---- Olmo-hybrid knobs ----
-    # The kind of each layer's mixer, as the hub file names it:
-    # "linear_attention" a gated-delta-rule mixer (ops/delta_rule.py: a
-    # matrix state a head a slot, the ``linear_*`` keys as the file has
-    # them), "full_attention" causal GQA. Every layer has an MLP after
-    # its mixer. None: every other family. num_layers == len of it.
+    # ---- Granite knobs ----
+    # each sublayer's output times this number before it is added to
+    # the stream. The family's other three multipliers are
+    # ``embed_multiplier``, ``logit_scale`` (1 / logits_scaling) and
+    # ``query_pre_attn_scalar`` (attention_multiplier ** -2).
+    residual_multiplier: float = 1.0
+    # ---- knobs of a stack with a mixer by kind (Olmo-Hybrid, Granite) ----
+    # The kind of each layer's mixer: "linear_attention" a
+    # gated-delta-rule mixer (ops/delta_rule.py: a matrix state a head a
+    # slot, the ``linear_*`` keys as the hub file has them), "mamba" a
+    # Mamba-2 mixer (the ``mamba_*`` fields above), "full_attention"
+    # causal GQA. Every layer has an MLP after its mixer. None: every
+    # other family. num_layers == len of it.
     layer_types: Optional[Tuple[str, ...]] = None
     linear_num_key_heads: int = 0
     linear_num_value_heads: int = 0
@@ -242,6 +253,13 @@ class ModelConfig:
         if self.layer_kinds is None:
             return self.num_layers - self.num_window_layers
         return self.layers_of("*")
+
+    @property
+    def num_mamba_layers(self) -> int:
+        """Layers whose mixer is a Mamba-2 state-space mixer, of either
+        way to say so (``layer_kinds``' ``"M"``, ``layer_types``'
+        ``"mamba"``)."""
+        return self.layers_of("M") + (self.layer_types or ()).count("mamba")
 
     @property
     def num_linear_layers(self) -> int:
@@ -309,9 +327,9 @@ class ModelConfig:
         sublanes and every head's values side by side on the lanes, so
         that widths that are no whole lane tiles (96, 192) store nothing
         padded (``ops/delta_rule.py``)."""
-        if self.layers_of("M"):
+        if self.num_mamba_layers:
             return (
-                self.layers_of("M"),
+                self.num_mamba_layers,
                 (self.mamba_num_heads, self.mamba_head_dim,
                  self.ssm_state_size),
                 ((self.conv_kernel - 1) * self.mamba_conv_dim,),
@@ -330,7 +348,7 @@ class ModelConfig:
         """The kind of mixer whose state :attr:`state_shapes` describes,
         as the flight records and ``/metrics`` label it: ``"ssm"``
         (Mamba-2), ``"delta"`` (gated delta rule), None without one."""
-        if self.layers_of("M"):
+        if self.num_mamba_layers:
             return "ssm"
         return "delta" if self.num_linear_layers else None
 
@@ -343,10 +361,33 @@ class ModelConfig:
         ``k`` and the shared rope key in ``v``, one head each."""
         if self.is_mla:
             return (1, self.kv_lora_rank), (1, self.qk_rope_head_dim)
-        return (
-            (self.kv_heads_stored, self.head_dim),
-            (self.kv_heads_stored, self.head_dim),
-        )
+        side = self.kv_heads_a_row
+        row = (self.kv_heads_stored // side, side * self.head_dim)
+        return row, row
+
+    @property
+    def kv_heads_a_row(self) -> int:
+        """How many kv heads lie side by side on the lanes of one stored
+        row of a GQA cache: 1, or ``128 // head_dim`` for heads narrower
+        than a lane tile (64 -> 2: ``[S, 8, 64]`` is stored ``[S, 4,
+        128]``, the same bytes in the same order, nothing padded). The
+        TPU tiles the last dimension in 128 lanes, so ``[.., 8, 64]``
+        bf16 is half-empty tiles or a transposed array, and the decode
+        kernel's merged view of positions and heads (``ops/
+        decode_attention.py``) is not the stored one; with two heads a
+        row it is, and a query head attends with zeros in the other
+        head's lanes and keeps its own half of the result
+        (``transformer.attend_over_cache``). Only where the heads fill
+        whole rows, and only for a model that is served on one device
+        whatever is asked (:attr:`beside_rows`): a mesh divides a cache
+        by its kv heads, and a row of two is not divided."""
+        hd = self.head_dim
+        if (
+            hd >= 128 or 128 % hd or self.kv_dim % 128
+            or self.state_mixer is None
+        ):
+            return 1
+        return 128 // hd
 
     @property
     def kv_heads_stored(self) -> int:
@@ -438,10 +479,13 @@ class ModelConfig:
         if self.layer_types is not None:
             assert len(self.layer_types) == self.num_layers
             assert set(self.layer_types) <= {
-                "linear_attention", "full_attention"
+                "linear_attention", "mamba", "full_attention"
             }, self.layer_types
             assert self.layer_kinds is None and self.layer_sliding is None
             assert not (self.is_moe or self.is_mla)
+            if "mamba" in self.layer_types:
+                assert self.mamba_num_heads % self.mamba_n_groups == 0
+                assert self.mamba_inner and self.ssm_state_size
             if self.num_linear_layers:
                 assert (
                     self.linear_num_key_heads == self.linear_num_value_heads
@@ -496,10 +540,20 @@ class ModelConfig:
                 d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
                 + self.q_dim + self.kv_dim       # the whole-width q, k norms
             )
+            if not self.qk_norm_whole:
+                full -= self.q_dim + self.kv_dim
+            inner, conv = self.mamba_inner, self.mamba_conv_dim
+            mamba = (
+                d * (inner + conv + self.mamba_num_heads)  # z | xBC | dt
+                + conv * self.conv_kernel + conv    # conv1d and its bias
+                + 3 * self.mamba_num_heads          # A_log, D, dt_bias
+                + inner + inner * d                 # the gated norm, out_proj
+            )
             mlp = 3 * d * self.intermediate_size
             return (
                 embed + lm_head + d
                 + self.num_linear_layers * linear
+                + self.num_mamba_layers * mamba
                 + self.num_kv_layers * full
                 + self.num_layers * (mlp + 2 * d)
             )
@@ -613,7 +667,7 @@ def _period(kinds: tuple) -> tuple:
 # tests' small files are.
 FAMILIES: Tuple[str, ...] = (
     "Llama", "Mistral", "Mixtral", "Qwen2", "Qwen3", "Gemma", "GptOss",
-    "Deepseek", "NemotronH", "Cohere2Moe", "OlmoHybrid",
+    "Deepseek", "NemotronH", "Cohere2Moe", "OlmoHybrid", "GraniteMoeHybrid",
     # multimodal wrappers whose text stack is one of the above
     "Llava", "VLForConditionalGeneration",
 )
@@ -841,6 +895,84 @@ def _olmo_hybrid_config(cfg: Dict[str, Any], name: str) -> ModelConfig:
     ).validate()
 
 
+def _granite_hybrid_config(cfg: Dict[str, Any], name: str) -> ModelConfig:
+    """Granite 4.0-H (``model_type: granitemoehybrid``): every layer a
+    mixer by ``layer_types`` (``mamba`` a Mamba-2 mixer, the
+    ``mamba_*`` keys; ``attention`` causal GQA without positional
+    embedding) and then a gated SiLU MLP (``shared_intermediate_size``
+    wide), each sublayer's output times ``residual_multiplier``; the
+    embeddings times ``embedding_multiplier``, the attention scores
+    times ``attention_multiplier`` (not ``1 / sqrt(head_dim)``), the
+    logits of the tied head divided by ``logits_scaling``. The family's
+    larger files route experts beside the shared MLP
+    (``num_local_experts > 0``): not read, refused by name."""
+    if int(cfg.get("num_local_experts") or 0):
+        raise ValueError(
+            f"num_local_experts {cfg['num_local_experts']}: a "
+            "granitemoehybrid stack is served with its shared MLP alone "
+            "(num_local_experts 0); routed experts beside it are not read"
+        )
+    for key, want in (
+        ("position_embedding_type", "nope"), ("hidden_act", "silu"),
+        ("attention_bias", False), ("mamba_proj_bias", False),
+        ("mamba_conv_bias", True), ("normalization_function", "rmsnorm"),
+    ):
+        if cfg.get(key, want) != want:
+            raise ValueError(
+                f"{key} {cfg[key]!r}: a granitemoehybrid stack is served "
+                f"with {want!r} only"
+            )
+    kinds = {"mamba": "mamba", "attention": "full_attention"}
+    layer_types = tuple(cfg.get("layer_types") or ())
+    if set(layer_types) - set(kinds):
+        raise ValueError(
+            f"layer_types {sorted(set(layer_types))}: only mamba and "
+            "attention layers are served"
+        )
+    if len(layer_types) != cfg["num_hidden_layers"]:
+        raise ValueError(
+            f"layer_types has {len(layer_types)} layers, "
+            f"num_hidden_layers says {cfg['num_hidden_layers']}"
+        )
+    hidden, heads = cfg["hidden_size"], cfg["num_attention_heads"]
+    m_heads, m_dim = int(cfg["mamba_n_heads"]), int(cfg["mamba_d_head"])
+    if m_heads * m_dim != int(cfg.get("mamba_expand", 2)) * hidden:
+        raise ValueError(
+            f"mamba_n_heads {m_heads} x mamba_d_head {m_dim} is not "
+            f"mamba_expand x hidden_size"
+        )
+    return ModelConfig(
+        name=name,
+        vocab_size=cfg["vocab_size"],
+        hidden_size=hidden,
+        intermediate_size=int(
+            cfg.get("shared_intermediate_size") or cfg["intermediate_size"]
+        ),
+        num_layers=cfg["num_hidden_layers"],
+        num_heads=heads,
+        num_kv_heads=cfg.get("num_key_value_heads", heads),
+        head_dim=cfg.get("head_dim") or hidden // heads,
+        rope=False,
+        rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+        tie_word_embeddings=cfg.get("tie_word_embeddings", True),
+        max_position_embeddings=cfg.get("max_position_embeddings", 8192),
+        layer_types=tuple(kinds[t] for t in layer_types),
+        mamba_num_heads=m_heads,
+        mamba_head_dim=m_dim,
+        ssm_state_size=int(cfg["mamba_d_state"]),
+        mamba_n_groups=int(cfg.get("mamba_n_groups") or 1),
+        conv_kernel=int(cfg.get("mamba_d_conv") or 4),
+        ssm_chunk_size=int(cfg.get("mamba_chunk_size") or 256),
+        embed_multiplier=float(cfg.get("embedding_multiplier", 1.0)),
+        residual_multiplier=float(cfg.get("residual_multiplier", 1.0)),
+        logit_scale=1.0 / float(cfg.get("logits_scaling", 1.0)),
+        query_pre_attn_scalar=(
+            float(cfg["attention_multiplier"]) ** -2
+            if cfg.get("attention_multiplier") else 0.0
+        ),
+    ).validate()
+
+
 def config_from_hf(cfg: Dict[str, Any], name: str = "custom") -> ModelConfig:
     """Build a ModelConfig from an HF ``config.json`` dict of one of
     :data:`FAMILIES` (the reference's selectors introspect the same
@@ -860,6 +992,11 @@ def config_from_hf(cfg: Dict[str, Any], name: str = "custom") -> ModelConfig:
         return _cohere2_moe_config(cfg, name)
     if "OlmoHybrid" in arch or cfg.get("model_type") == "olmo_hybrid":
         return _olmo_hybrid_config(cfg, name)
+    if (
+        "GraniteMoeHybrid" in arch
+        or cfg.get("model_type") == "granitemoehybrid"
+    ):
+        return _granite_hybrid_config(cfg, name)
     hidden = cfg["hidden_size"]
     heads = cfg["num_attention_heads"]
     head_dim = cfg.get("head_dim") or hidden // heads
@@ -966,7 +1103,7 @@ def config_from_hf(cfg: Dict[str, Any], name: str = "custom") -> ModelConfig:
             else "silu"
         ),
         norm_delta_gain=gemma,
-        embed_scale=gemma,
+        embed_multiplier=math.sqrt(hidden) if gemma else 1.0,
         post_norms=gemma2plus,
         query_pre_attn_scalar=(
             float(cfg.get("query_pre_attn_scalar") or 0) if gemma2plus else 0.0
@@ -1122,7 +1259,7 @@ PRESETS: Dict[str, ModelConfig] = {
         tie_word_embeddings=True,
         hidden_act="gelu_tanh",
         norm_delta_gain=True,
-        embed_scale=True,
+        embed_multiplier=math.sqrt(3584),
         post_norms=True,
         query_pre_attn_scalar=256.0,
         attn_logit_softcap=50.0,

@@ -1,9 +1,10 @@
 """The gated-delta-rule mixer of a linear-attention layer
 (``cfg.layer_types``, the Olmo hybrid): one function over a layer's
 input, its leaves, the carried cache and the layer's index among its
-kind, beside ``models/hybrid.py``'s ``mamba``. ``transformer.forward``
-calls it from ``block`` for a ``"linear_attention"`` layer; the layer's
-norms, its residual adds and its MLP are ``block``'s.
+kind, beside ``models/hybrid.py mamba_mixer``. ``transformer.forward``
+calls it from ``block`` for a ``"linear_attention"`` layer, bound by
+``models/hybrid.py bound_state_mixers``; the layer's norms, its residual
+adds and its MLP are ``block``'s.
 
 ``q~ = h Wq``, ``k~ = h Wk`` (``H * Dk`` wide), ``v~ = h Wv`` (``H * Dv``
 wide), side by side through one causal depthwise convolution of ``K``
@@ -24,7 +25,6 @@ last ``K - 1`` rows of ``[q~ | k~ | v~]`` before the convolution, ``conv
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any, Dict
 
@@ -77,38 +77,6 @@ def init_delta_layers(cfg: ModelConfig, key: jax.Array, dtype) -> Dict[str, Any]
         "o_norm": jnp.ones((L, cfg.linear_value_head_dim), dtype),
         "wo": w(L, values_w, d),
     }
-
-
-def bound_delta_mixer(
-    cfg: ModelConfig, rows, cache, true_len, live, impl, platform, mesh,
-    *, ring: bool,
-):
-    """:func:`delta_mixer` for one call of ``forward`` over ``rows = (B,
-    T)``, as ``f(h, layer's leaves, carried, index among its kind)``:
-    which positions count for a state (``true_len``; all of them without
-    one), which slots the one-step kernel moves (``live``; all without
-    one), and how a step moves the state (``impl``, or what
-    ``models/hybrid.py ssm_update_impl`` chooses). A cache on a mesh of
-    several devices, or sharded over its positions, is refused: a
-    recurrent state is not sharded."""
-    from gpustack_tpu.models.hybrid import ssm_update_impl
-
-    B, T = rows
-    if cache is not None and (ring or (mesh is not None and mesh.size > 1)):
-        raise ValueError(
-            f"{cfg.name}: a recurrent state is not sharded; serve it on one "
-            "device (a cache sharded over its positions cannot carry one)"
-        )
-    if impl is None:
-        impl = ssm_update_impl(T if cache is not None else 2, platform, mesh)
-    return functools.partial(
-        delta_mixer, cfg=cfg, impl=impl,
-        real=(
-            jnp.ones((B, T), bool) if true_len is None
-            else jnp.arange(T, dtype=jnp.int32)[None, :] < true_len[:, None]
-        ),
-        alive=live if live is not None else jnp.ones((B,), bool),
-    )
 
 
 def delta_mixer(

@@ -1,5 +1,16 @@
-"""The Nemotron-H hybrid: one mixer a layer, three kinds of layer.
+"""The Mamba-2 mixer, and the Nemotron-H hybrid that is made of it.
 
+:func:`mamba_mixer` is the one Mamba-2 state-space mixer, for both ways
+a stack can hold it: Nemotron-H's one mixer a layer
+(:func:`forward_hybrid` below, ``cfg.layer_kinds``) and a stack whose
+every layer is a mixer by kind and then an MLP (``cfg.layer_types``'
+``"mamba"``, Granite 4.0-H: ``transformer.forward``'s ``block`` calls
+it where it calls ``models/delta.py``'s mixer for a delta-rule layer,
+and the layer's norms, residual adds and MLP are ``block``'s).
+:func:`bound_state_mixers` binds either kind for one call of
+``forward``.
+
+The Nemotron-H hybrid: one mixer a layer, three kinds of layer.
 ``cfg.layer_kinds`` names each layer's mixer: ``"M"`` a Mamba-2
 state-space mixer, ``"E"`` a mixture of two-matrix ``relu2`` experts
 with a shared expert, ``"*"`` grouped-query attention without rotary
@@ -18,12 +29,13 @@ of ``xBC`` before its causal convolution, ``conv [L_M, B, (K-1) * C]``
 the three rows to a tile of sixteen).
 
 ``transformer.forward`` hands a model with ``layer_kinds`` to
-:func:`forward_hybrid`; no other model's program passes through here.
+:func:`forward_hybrid`; no other model's layer loop passes through here.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional
 
@@ -51,31 +63,11 @@ def init_hybrid_layers(cfg: ModelConfig, key: jax.Array, dtype) -> Dict[str, Any
         ).astype(dtype)
 
     Lm, Le, La = (cfg.layers_of(k) for k in "ME*")
-    H, inner, conv = cfg.mamba_num_heads, cfg.mamba_inner, cfg.mamba_conv_dim
-    f32 = jnp.float32
     out: Dict[str, Any] = {}
     if Lm:
         out["ssm_layers"] = {
             "norm": jnp.ones((Lm, d), dtype),
-            # [z | xBC | dt]
-            "w_in": w(Lm, d, inner + conv + H),
-            "conv_w": w(Lm, cfg.conv_kernel, conv, scale=0.5).astype(f32),
-            "conv_b": jnp.zeros((Lm, conv), f32),
-            # dt around softplus^-1 of 0.001..0.1, A in -(1..16), as the
-            # family initialises them: a state that neither dies in a
-            # step nor never forgets
-            "dt_bias": jnp.log(jnp.expm1(jnp.exp(
-                jax.random.uniform(
-                    next(keys), (Lm, H), f32,
-                    math.log(1e-3), math.log(1e-1),
-                )
-            ))),
-            "A_log": jnp.log(
-                jax.random.uniform(next(keys), (Lm, H), f32, 1.0, 16.0)
-            ),
-            "D": jnp.ones((Lm, H), f32),
-            "gate_norm": jnp.ones((Lm, inner), dtype),
-            "w_out": w(Lm, inner, d),
+            **init_mamba_layers(cfg, Lm, w, keys, dtype),
         }
     if Le:
         fm, E, Eh = (
@@ -85,7 +77,7 @@ def init_hybrid_layers(cfg: ModelConfig, key: jax.Array, dtype) -> Dict[str, Any
         out["moe_layers"] = {
             "norm": jnp.ones((Le, d), dtype),
             "router": w(Le, d, E),
-            "router_bias": jnp.zeros((Le, E), f32),
+            "router_bias": jnp.zeros((Le, E), jnp.float32),
             "we_up": pad_expert_width(w(Le, Eh, d, fm), -1),
             "we_down": pad_expert_width(w(Le, Eh, fm, d), -2),
         }
@@ -101,6 +93,36 @@ def init_hybrid_layers(cfg: ModelConfig, key: jax.Array, dtype) -> Dict[str, Any
             "wo": w(La, cfg.q_dim, d),
         }
     return out
+
+
+def init_mamba_layers(cfg: ModelConfig, Lm: int, w, keys, dtype):
+    """``Lm`` Mamba-2 mixers' leaves, random, each drawn whole at its
+    depth by ``w(*shape, scale=)`` and from ``keys`` (an iterator; five
+    are taken): what either kind of stack holds a mixer (a stack of one
+    mixer a layer adds the layer's norm)."""
+    d, f32 = cfg.hidden_size, jnp.float32
+    H, inner, conv = cfg.mamba_num_heads, cfg.mamba_inner, cfg.mamba_conv_dim
+    return {
+        # [z | xBC | dt]
+        "w_in": w(Lm, d, inner + conv + H),
+        "conv_w": w(Lm, cfg.conv_kernel, conv, scale=0.5).astype(f32),
+        "conv_b": jnp.zeros((Lm, conv), f32),
+        # dt around softplus^-1 of 0.001..0.1, A in -(1..16), as the
+        # family initialises them: a state that neither dies in a
+        # step nor never forgets
+        "dt_bias": jnp.log(jnp.expm1(jnp.exp(
+            jax.random.uniform(
+                next(keys), (Lm, H), f32,
+                math.log(1e-3), math.log(1e-1),
+            )
+        ))),
+        "A_log": jnp.log(
+            jax.random.uniform(next(keys), (Lm, H), f32, 1.0, 16.0)
+        ),
+        "D": jnp.ones((Lm, H), f32),
+        "gate_norm": jnp.ones((Lm, inner), dtype),
+        "w_out": w(Lm, inner, d),
+    }
 
 
 def pad_expert_width(w: jax.Array, axis: int) -> jax.Array:
@@ -147,6 +169,153 @@ def grouped_rms_norm(y, gate, w, eps: float, groups: int):
     return hg.reshape(*lead, width).astype(y.dtype) * w
 
 
+def mamba_mixer(
+    h: jax.Array,       # [B, T, D], behind whatever norm stands before it
+    lp,                 # the layer's leaves
+    carried,            # the cache (None: from zeros, nothing kept)
+    i: jax.Array,       # int32: the layer's index among the Mamba-2 layers
+    *,
+    cfg: ModelConfig,
+    impl: str,          # "scan" | "xla" | "kernel" | "kernel_interpret"
+    real: jax.Array,    # bool [B, T]: which positions count
+    alive: jax.Array,   # bool [B]: the slots somebody holds
+):
+    """One Mamba-2 mixer: ``(out [B, T, D], carried)``, under the scope
+    ``ssm_mixer`` wherever it is called from. ``[z | xBC | dt] = h
+    W_in``; ``xBC`` through a causal depthwise convolution of ``K`` taps
+    with bias, then ``silu``; ``xBC -> x [H, P], B [G, N], C [G, N]``;
+    ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)``; the rule
+    itself is ``ops/ssm.py``'s; ``y + D x`` through the gated norm over
+    ``G`` groups (:func:`grouped_rms_norm`) and ``W_out``. A padded
+    position (``real`` False) has ``dt = 0``: it moves no state, and the
+    kept conv rows end at the last real position."""
+    from gpustack_tpu.models import transformer as tf
+    from gpustack_tpu.ops.ssm import (
+        ssm_chunk_scan,
+        ssm_state_update,
+        ssm_step_xla,
+    )
+
+    B, T, _ = h.shape
+    H, P = cfg.mamba_num_heads, cfg.mamba_head_dim
+    G, N = cfg.mamba_n_groups, cfg.ssm_state_size
+    inner, conv_dim, K = cfg.mamba_inner, cfg.mamba_conv_dim, cfg.conv_kernel
+    dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
+    f32 = jnp.float32
+    eps = cfg.rms_norm_eps
+    with jax.named_scope("ssm_mixer"):
+        zxd = tf._mm("btd,df->btf", h, lp["w_in"])
+        z = zxd[..., :inner]
+        xbc = zxd[..., inner:inner + conv_dim]
+        dt = jax.nn.softplus(
+            zxd[..., inner + conv_dim:].astype(f32) + lp["dt_bias"]
+        )
+        dt = jnp.where(real[..., None], dt, 0.0)
+        A = -jnp.exp(lp["A_log"].astype(f32))
+        # the K - 1 rows before the step's first, oldest first
+        if carried is not None:
+            before = lax.dynamic_index_in_dim(
+                carried.conv, i, 0, keepdims=False
+            ).reshape(B, K - 1, conv_dim)
+        else:
+            before = jnp.zeros((B, K - 1, conv_dim), xbc.dtype)
+        window = jnp.concatenate([before.astype(xbc.dtype), xbc], axis=1)
+        conv = lp["conv_b"].astype(f32) + sum(
+            window[:, j:j + T].astype(f32) * lp["conv_w"][j].astype(f32)
+            for j in range(K)
+        )
+        xbc_a = jax.nn.silu(conv).astype(dtype)
+        xs = xbc_a[..., :inner].reshape(B, T, H, P)
+        Bm = xbc_a[..., inner:inner + G * N].reshape(B, T, G, N)
+        Cm = xbc_a[..., inner + G * N:].reshape(B, T, G, N)
+        if carried is not None:
+            # the last K - 1 rows that count: rows n .. n + K - 2 of
+            # the window, n the row's real length
+            n = jnp.sum(real, axis=1, dtype=jnp.int32)
+            rows = n[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None]
+            kept = jnp.take_along_axis(
+                window, rows[..., None], axis=1
+            ).reshape(B, (K - 1) * conv_dim)
+            new_conv = lax.dynamic_update_index_in_dim(
+                carried.conv, kept.astype(carried.conv.dtype), i, 0
+            )
+        if carried is None:
+            y, _ = ssm_chunk_scan(
+                xs, dt, A, Bm, Cm, jnp.zeros((B, H, P, N), f32),
+                cfg.ssm_chunk_size,
+            )
+        elif impl == "scan":
+            h0 = lax.dynamic_index_in_dim(
+                carried.ssm, i, 0, keepdims=False
+            )
+            y, last = ssm_chunk_scan(
+                xs, dt, A, Bm, Cm, h0, cfg.ssm_chunk_size
+            )
+            new_ssm = lax.dynamic_update_index_in_dim(
+                carried.ssm, last.astype(carried.ssm.dtype), i, 0
+            )
+        elif impl == "xla":
+            y, new_ssm = ssm_step_xla(
+                carried.ssm, i, xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0]
+            )
+            y = y[:, None]
+        else:
+            y, new_ssm = ssm_state_update(
+                carried.ssm, i, xs[:, 0], dt[:, 0], A, Bm[:, 0],
+                Cm[:, 0], alive,
+                interpret=impl == "kernel_interpret",
+            )
+            y = y[:, None]
+        if carried is not None:
+            carried = dataclasses.replace(
+                carried, ssm=new_ssm, conv=new_conv
+            )
+        y = y + lp["D"].astype(f32)[:, None] * xs.astype(f32)
+        y = grouped_rms_norm(
+            y.astype(dtype).reshape(B, T, inner), z, lp["gate_norm"],
+            eps, G,
+        )
+        return tf._mm("btf,fd->btd", y, lp["w_out"]), carried
+
+
+def bound_state_mixers(
+    cfg: ModelConfig, rows, cache, true_len, live, impl, platform, mesh,
+    *, ring: bool,
+):
+    """The mixers that keep a recurrent state, by their kind in
+    ``cfg.layer_types`` (``"mamba"``: :func:`mamba_mixer`;
+    ``"linear_attention"``: ``models/delta.py delta_mixer``), each bound
+    for one call of ``forward`` over ``rows = (B, T)`` as ``f(h, layer's
+    leaves, carried, index among its kind)``: which positions count for
+    a state (``true_len``; all of them without one), which slots the
+    one-step kernel moves (``live``; all without one), and how a step
+    moves the state (``impl``, or what :func:`ssm_update_impl` chooses).
+    A cache on a mesh of several devices, or sharded over its positions,
+    is refused: a recurrent state is not sharded."""
+    from gpustack_tpu.models.delta import delta_mixer
+
+    B, T = rows
+    if cache is not None and (ring or (mesh is not None and mesh.size > 1)):
+        raise ValueError(
+            f"{cfg.name}: a recurrent state is not sharded; serve it on one "
+            "device (a cache sharded over its positions cannot carry one)"
+        )
+    if impl is None:
+        impl = ssm_update_impl(T if cache is not None else 2, platform, mesh)
+    bound = dict(
+        cfg=cfg, impl=impl,
+        real=(
+            jnp.ones((B, T), bool) if true_len is None
+            else jnp.arange(T, dtype=jnp.int32)[None, :] < true_len[:, None]
+        ),
+        alive=live if live is not None else jnp.ones((B,), bool),
+    )
+    return {
+        "mamba": functools.partial(mamba_mixer, **bound),
+        "linear_attention": functools.partial(delta_mixer, **bound),
+    }
+
+
 def forward_hybrid(
     params,
     cfg: ModelConfig,
@@ -185,12 +354,6 @@ def forward_hybrid(
     a cache every layer starts from zeros and keeps nothing.
     """
     from gpustack_tpu.models import transformer as tf
-    from gpustack_tpu.ops.ssm import (
-        ssm_chunk_scan,
-        ssm_state_update,
-        ssm_step_xla,
-    )
-
     B, T = tokens.shape
     platform = (
         mesh.devices.flat[0].platform if mesh is not None
@@ -242,10 +405,6 @@ def forward_hybrid(
         else jnp.arange(T, dtype=jnp.int32)[None, :] < true_len[:, None]
     )
 
-    H, P = cfg.mamba_num_heads, cfg.mamba_head_dim
-    G, N = cfg.mamba_n_groups, cfg.ssm_state_size
-    inner, conv_dim, K = cfg.mamba_inner, cfg.mamba_conv_dim, cfg.conv_kernel
-
     # the experts' stacked matrices go to the kernels whole, with the
     # layer's index; the scales of the touched kernel as its blocks
     # take them (``transformer.forward``)
@@ -271,81 +430,9 @@ def forward_hybrid(
             for k, w in stack.items() if k not in stacked
         }
 
-    def mamba(h, lp, carried, i):
-        """One Mamba-2 mixer: ``(out [B, T, D], carried)``."""
-        with jax.named_scope("ssm_mixer"):
-            zxd = tf._mm("btd,df->btf", h, lp["w_in"])
-            z = zxd[..., :inner]
-            xbc = zxd[..., inner:inner + conv_dim]
-            dt = jax.nn.softplus(
-                zxd[..., inner + conv_dim:].astype(f32) + lp["dt_bias"]
-            )
-            dt = jnp.where(real[..., None], dt, 0.0)
-            A = -jnp.exp(lp["A_log"].astype(f32))
-            # the K - 1 rows before the step's first, oldest first
-            if carried is not None:
-                before = lax.dynamic_index_in_dim(
-                    carried.conv, i, 0, keepdims=False
-                ).reshape(B, K - 1, conv_dim)
-            else:
-                before = jnp.zeros((B, K - 1, conv_dim), xbc.dtype)
-            window = jnp.concatenate([before.astype(xbc.dtype), xbc], axis=1)
-            conv = lp["conv_b"].astype(f32) + sum(
-                window[:, j:j + T].astype(f32) * lp["conv_w"][j].astype(f32)
-                for j in range(K)
-            )
-            xbc_a = jax.nn.silu(conv).astype(dtype)
-            xs = xbc_a[..., :inner].reshape(B, T, H, P)
-            Bm = xbc_a[..., inner:inner + G * N].reshape(B, T, G, N)
-            Cm = xbc_a[..., inner + G * N:].reshape(B, T, G, N)
-            if carried is not None:
-                # the last K - 1 rows that count: rows n .. n + K - 2 of
-                # the window, n the row's real length
-                n = jnp.sum(real, axis=1, dtype=jnp.int32)
-                rows = n[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None]
-                kept = jnp.take_along_axis(
-                    window, rows[..., None], axis=1
-                ).reshape(B, (K - 1) * conv_dim)
-                new_conv = lax.dynamic_update_index_in_dim(
-                    carried.conv, kept.astype(carried.conv.dtype), i, 0
-                )
-            if carried is None:
-                y, _ = ssm_chunk_scan(
-                    xs, dt, A, Bm, Cm, jnp.zeros((B, H, P, N), f32),
-                    cfg.ssm_chunk_size,
-                )
-            elif ssm_impl == "scan":
-                h0 = lax.dynamic_index_in_dim(
-                    carried.ssm, i, 0, keepdims=False
-                )
-                y, last = ssm_chunk_scan(
-                    xs, dt, A, Bm, Cm, h0, cfg.ssm_chunk_size
-                )
-                new_ssm = lax.dynamic_update_index_in_dim(
-                    carried.ssm, last.astype(carried.ssm.dtype), i, 0
-                )
-            elif ssm_impl == "xla":
-                y, new_ssm = ssm_step_xla(
-                    carried.ssm, i, xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0]
-                )
-                y = y[:, None]
-            else:
-                y, new_ssm = ssm_state_update(
-                    carried.ssm, i, xs[:, 0], dt[:, 0], A, Bm[:, 0],
-                    Cm[:, 0], alive,
-                    interpret=ssm_impl == "kernel_interpret",
-                )
-                y = y[:, None]
-            if carried is not None:
-                carried = type(carried)(
-                    k=carried.k, v=carried.v, ssm=new_ssm, conv=new_conv
-                )
-            y = y + lp["D"].astype(f32)[:, None] * xs.astype(f32)
-            y = grouped_rms_norm(
-                y.astype(dtype).reshape(B, T, inner), z, lp["gate_norm"],
-                eps, G,
-            )
-            return tf._mm("btf,fd->btd", y, lp["w_out"]), carried
+    mamba = functools.partial(
+        mamba_mixer, cfg=cfg, impl=ssm_impl, real=real, alive=alive
+    )
 
     def experts(h, lp, i):
         """One expert layer: ``(out, held pairs, experts read,

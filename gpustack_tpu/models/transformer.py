@@ -28,6 +28,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from gpustack_tpu.models.config import ModelConfig
@@ -415,8 +416,10 @@ def init_params(
     if cfg.layer_types is not None:
         # a mixer by kind and an MLP in every layer: what every layer
         # has (its two norms, the MLP) in one stack of L, each kind of
-        # mixer's leaves in a stack of its own (models/delta.py)
+        # mixer's leaves in a stack of its own (models/delta.py,
+        # models/hybrid.py)
         from gpustack_tpu.models.delta import init_delta_layers
+        from gpustack_tpu.models.hybrid import init_mamba_layers
 
         La = cfg.num_kv_layers
         params = {
@@ -436,12 +439,21 @@ def init_params(
                 "wk": w(next(keys), La, d, cfg.kv_dim),
                 "wv": w(next(keys), La, d, cfg.kv_dim),
                 "wo": w(next(keys), La, cfg.q_dim, d),
-                "q_norm": jnp.ones((La, cfg.q_dim), dtype),
-                "k_norm": jnp.ones((La, cfg.kv_dim), dtype),
             }
+            if cfg.qk_norm_whole:
+                params["attn_layers"].update(
+                    q_norm=jnp.ones((La, cfg.q_dim), dtype),
+                    k_norm=jnp.ones((La, cfg.kv_dim), dtype),
+                )
         if cfg.num_linear_layers:
             params["delta_layers"] = init_delta_layers(
                 cfg, next(keys), dtype
+            )
+        if cfg.num_mamba_layers:
+            params["ssm_layers"] = init_mamba_layers(
+                cfg, cfg.num_mamba_layers,
+                lambda *shape, scale=None: w(next(keys), *shape, scale=scale),
+                keys, dtype,
             )
         if not cfg.tie_word_embeddings:
             params["lm_head"] = w(next(keys), d, cfg.vocab_size)
@@ -866,21 +878,34 @@ def attend_over_cache(
     write = partial(
         _write_rows, layer=index, start=start, by_position=ring and T > 1
     )
+    # heads narrower than a lane tile lie ``side`` to a stored row
+    # (``ModelConfig.kv_heads_a_row``): the same bytes in the same order
+    side = buf_k.shape[-1] // hd
+    if side > 1:
+        k, v = (a.reshape(B, T, Hkv // side, side * hd) for a in (k, v))
     buf_k, buf_v = write(buf_k, k), write(buf_v, v)
     if decode_attn_impl != "xla":
         # a decode step on one chip: the kernel reads the layer's rows
         # where they lie and no slab is taken out of the carry
         from gpustack_tpu.ops.decode_attention import gqa_decode_attention
 
+        q = q[:, 0] if q.ndim == 4 else q.reshape(B, -1, hd)
+        if side > 1:
+            q, own = queries_for_rows_of(side, q, Hkv)
         attn = gqa_decode_attention(
-            q[:, 0] if q.ndim == 4 else q.reshape(B, -1, hd),
-            buf_k, buf_v, index, walk, scale,
+            q, buf_k, buf_v, index, walk, scale,
             interpret=decode_attn_impl == "kernel_interpret",
             **({"name": name} if name else {}),
         )[:, None]
+        if side > 1:
+            # of a stored row's values a head keeps its own head's
+            attn = jnp.sum(
+                jnp.where(own, attn.reshape(B, 1, -1, side, hd), 0), axis=3
+            ).reshape(B, 1, -1)
         return attn, buf_k, buf_v
     all_k, all_v = (
         lax.dynamic_index_in_dim(buf, index, 0, keepdims=False)
+        .reshape(B, S, Hkv, hd)
         for buf in (buf_k, buf_v)
     )
     grouped = q.reshape(B, T, Hkv, -1, hd)
@@ -913,6 +938,20 @@ def attend_over_cache(
             grouped, all_k, all_v, mask, scale, softcap, sinks=sinks
         )
     return attn, buf_k, buf_v
+
+
+def queries_for_rows_of(side: int, q: jax.Array, kv_heads: int):
+    """``q [B, Hq, hd]`` as the decode kernel takes it over a cache whose
+    stored row holds ``side`` kv heads on its lanes: ``([B, Hq, side *
+    hd], own)``, a query head's numbers on its own kv head's lanes and
+    zeros on the others', so that its product with a stored row is its
+    product with its own head's key; ``own`` (bool ``[Hq, side, 1]``)
+    says which lanes those are, for the result's values."""
+    Hq = q.shape[1]
+    at = (np.arange(Hq) // (Hq // kv_heads)) % side     # [Hq]
+    own = jnp.asarray(at[:, None, None] == np.arange(side)[None, :, None])
+    wide = jnp.where(own, q[:, :, None, :], jnp.zeros((), q.dtype))
+    return wide.reshape(q.shape[0], Hq, -1), own
 
 
 def _kept_groups(sel: jax.Array, cfg: ModelConfig) -> jax.Array:
@@ -1416,8 +1455,11 @@ def decode_attention_impl(
     continuation (several rows a slot), a mesh of more than one device
     (the kernels are not wrapped in a ``shard_map``), any other
     platform, and for a GQA cache a model whose scores only the einsum
-    computes (:func:`needs_xla_attention`) or whose heads are no whole
-    number of lane tiles wide.
+    computes (:func:`needs_xla_attention`) or whose stored rows are no
+    whole number of lane tiles wide (``ModelConfig.kv_row_shapes``: a
+    head of 128 or a multiple, or narrower heads stored side by side on
+    a row, ``kv_heads_a_row``; a head of 64 stored alone, 96, 80 take
+    the XLA form).
     """
     from gpustack_tpu.ops.decode_attention import (
         block_positions,
@@ -1430,12 +1472,10 @@ def decode_attention_impl(
         block = None
     else:
         itemsize = 2 if cfg.dtype == "bfloat16" else 4
-        block = gqa_block_positions(
-            max_len, cfg.kv_heads_stored, cfg.head_dim, itemsize
-        )
+        heads, width = cfg.kv_row_shapes[0]     # of a row as it is stored
+        block = gqa_block_positions(max_len, heads, width, itemsize)
         if cfg.window_rows and gqa_block_positions(
-            min(cfg.sliding_window, max_len), cfg.kv_heads_stored,
-            cfg.head_dim, itemsize,
+            min(cfg.sliding_window, max_len), heads, width, itemsize,
         ) is None:
             # the ring of a sliding layer is walked by the same kernel
             block = None
@@ -1476,15 +1516,20 @@ def forward(
 
     A model of one mixer a layer (``cfg.layer_kinds``, the Nemotron-H
     hybrid) runs in ``models/hybrid.py forward_hybrid``, which takes
-    these arguments and says what ``true_len`` and ``ssm_impl`` are;
-    no other model takes notice of ``ssm_impl``, and of ``true_len``
-    only a stack that keeps its sliding layers' rows at window size
-    (``cfg.window_rows``; ``window_attention`` below): int32 ``[B]``,
-    how many of a prefill's ``T`` positions are real, so that the ring
-    it leaves holds the last real rows and nothing of the padding (None:
-    every position counts). Such a stack takes a cache in two forms
-    only, a prefill from position 0 into a cache of the step's length
-    and one row a slot, and neither a mesh nor ``"ring"``.
+    these arguments and says what ``true_len`` and ``ssm_impl`` are. A
+    stack with a mixer by kind and an MLP in every layer
+    (``cfg.layer_types``: Olmo-Hybrid, Granite 4.0-H) runs here, in
+    ``block``, and hands both to the mixers that keep a state
+    (``models/hybrid.py bound_state_mixers``: the same Mamba-2 mixer
+    ``forward_hybrid`` calls, or the delta rule's). Of the others only a
+    stack that keeps its sliding layers' rows at window size
+    (``cfg.window_rows``; ``window_attention`` below) takes notice of
+    ``true_len``: int32 ``[B]``, how many of a prefill's ``T`` positions
+    are real, so that the ring it leaves holds the last real rows and
+    nothing of the padding (None: every position counts). Such a stack
+    takes a cache in two forms only, a prefill from position 0 into a
+    cache of the step's length and one row a slot, and neither a mesh
+    nor ``"ring"``.
 
     Without ``cache``: plain causal forward (training / scoring path).
     With ``cache``: the cache rides the scan over the layers as its carry;
@@ -1590,18 +1635,18 @@ def forward(
         decode_attn_impl = decode_attention_impl(
             cfg, T, cache.max_len, platform, mesh
         )
-    # Under ``layer_types``: the delta-rule layers' mixer, with what it
-    # needs beside a layer's leaves bound to it (models/delta.py). One
-    # name, and made there: on the chip's host every name and every line
-    # that ``forward`` and ``block`` hold before they reach a kernel
-    # costs each operation of the kernel's traced body (PERF.md section
-    # 6, PR 53: 60 dead lines in either slowed a flash prefill program's
-    # trace by a third).
-    delta_layer = None
+    # Under ``layer_types``: the mixers that keep a state, by kind
+    # (delta rule, Mamba-2), with what each needs beside a layer's
+    # leaves bound to it (models/hybrid.py). One name, and made there:
+    # on the chip's host every name and every line that ``forward`` and
+    # ``block`` hold before they reach a kernel costs each operation of
+    # the kernel's traced body (PERF.md section 6, PR 53: 60 dead lines
+    # in either slowed a flash prefill program's trace by a third).
+    state_layers = None
     if cfg.layer_types is not None:
-        from gpustack_tpu.models.delta import bound_delta_mixer
+        from gpustack_tpu.models.hybrid import bound_state_mixers
 
-        delta_layer = bound_delta_mixer(
+        state_layers = bound_state_mixers(
             cfg, (B, T), cache, true_len, live, ssm_impl, platform, mesh,
             ring=attn_impl == "ring",
         )
@@ -1633,10 +1678,10 @@ def forward(
         # vocab row (models/vlm.py build_mm_prompt)
         ov, ov_mask = embeds_override
         x = jnp.where(ov_mask[..., None], ov.astype(dtype), x)
-    if cfg.embed_scale:
-        # gemma: embeddings scaled by sqrt(d); HF casts the normalizer
-        # to the compute dtype before multiplying
-        x = x * jnp.asarray(math.sqrt(cfg.hidden_size)).astype(dtype)
+    if cfg.embed_multiplier != 1.0:
+        # gemma's sqrt(d), Granite's embedding_multiplier; HF casts the
+        # number to the compute dtype before multiplying
+        x = x * jnp.asarray(cfg.embed_multiplier).astype(dtype)
     main_inv, main_att_factor = rope_params(cfg)
     sin, cos = rope_sin_cos(positions, main_inv)
     if main_att_factor != 1.0:
@@ -1988,8 +2033,10 @@ def forward(
         # under ``layer_types``: the layer's mixer, its place among its
         # kind (where its rows or its state lie in their store), and
         # whether its norms stand before its sublayers or after them
-        mixer = kind[0] if delta_layer is not None else None
+        mixer = kind[0] if state_layers is not None else None
         store = among_its_kind(layer, kind) if mixer else layer
+        # None: the layer's mixer is attention
+        state_layer = state_layers.get(mixer) if mixer else None
         norm_first = mixer not in cfg.norm_after
         windowed = kind is not None and mixer is None
         if windowed:
@@ -2003,8 +2050,8 @@ def forward(
         h = model_norm(x_in, lp["attn_norm"], cfg) if norm_first else x_in
         if cfg.is_mla:
             attn, carried = mla_attention(h, lp, carried, layer, mask_l)
-        elif mixer == "linear_attention":
-            attn_out, carried = delta_layer(h, lp, carried, store)
+        elif state_layer is not None:
+            attn_out, carried = state_layer(h, lp, carried, store)
         elif windowed:
             attn, carried = window_attention(h, lp, carried, layer, kind)
         else:
@@ -2064,7 +2111,7 @@ def forward(
             if unstored:
                 attn = attn[..., :cfg.q_dim]
 
-        if mixer != "linear_attention":     # whose output is projected
+        if state_layer is None:     # a state's mixer projects its own
             attn_out = _mm("btq,qd->btd", attn, lp["wo"])
         if cfg.o_bias:
             attn_out = attn_out + lp["bo"]
@@ -2075,6 +2122,10 @@ def forward(
             )
         if not norm_first:
             attn_out = model_norm(attn_out, lp["attn_norm"], cfg)
+        if cfg.residual_multiplier != 1.0:
+            attn_out = attn_out * jnp.asarray(
+                cfg.residual_multiplier, attn_out.dtype
+            )
         if cfg.parallel_block:
             # attention and MLP both read the one norm's output, and
             # both are added to the stream
@@ -2115,9 +2166,10 @@ def forward(
                     routing = extras.pop()
                 counts = [c + n for c, n in zip(counts, extras)]
         else:
-            g = _mm("btd,df->btf", h2, lp["w_gate"])
-            u = _mm("btd,df->btf", h2, lp["w_up"])
-            mlp = _mm("btf,fd->btd", act(g) * u, lp["w_down"])
+            with jax.named_scope("gated_mlp"):
+                g = _mm("btd,df->btf", h2, lp["w_gate"])
+                u = _mm("btd,df->btf", h2, lp["w_up"])
+                mlp = _mm("btf,fd->btd", act(g) * u, lp["w_down"])
         if cfg.post_norms:
             mlp = rms_norm(
                 mlp, lp["post_mlp_norm"], cfg.rms_norm_eps,
@@ -2125,6 +2177,8 @@ def forward(
             )
         if not norm_first:
             mlp = model_norm(mlp, lp["mlp_norm"], cfg)
+        if cfg.residual_multiplier != 1.0:
+            mlp = mlp * jnp.asarray(cfg.residual_multiplier, mlp.dtype)
         x_out = x_mid + attn_out + mlp if cfg.parallel_block else x_mid + mlp
         return (x_out, carried, layer + 1, *counts), routing
 
@@ -2162,6 +2216,7 @@ def forward(
         kinds = [(s, period[:j].count(s)) for j, s in enumerate(period)]
         by_mixer = {
             "linear_attention": params.get("delta_layers"),
+            "mamba": params.get("ssm_layers"),
             "full_attention": params.get("attn_layers"),
         }
 
@@ -2181,7 +2236,7 @@ def forward(
                 # temporaries at this model's widths, compiled for a
                 # described v5e)
                 lp = leaves_at(layers, carry[2])
-                if delta_layer is not None:
+                if state_layers is not None:
                     # and its mixer's, at its place among its kind
                     lp.update(leaves_at(
                         by_mixer[kind[0]], among_its_kind(carry[2], kind)

@@ -5,6 +5,7 @@ and final logit softcapping (gemma2), alternating sliding/full layers,
 and dual rope thetas + qk-norm (gemma3)."""
 
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -135,7 +136,8 @@ def test_gemma1_logits_match_transformers(gemma1_checkpoint):
     loading it llama-style produces wrong logits (round-2 advisor)."""
     model, model_dir = gemma1_checkpoint
     cfg, params = _load_ours(model_dir)
-    assert cfg.norm_delta_gain and cfg.embed_scale
+    assert cfg.norm_delta_gain
+    assert cfg.embed_multiplier == math.sqrt(cfg.hidden_size)
     assert not cfg.post_norms
     assert cfg.attn_logit_softcap == 0.0
     assert cfg.hidden_act == "gelu_tanh"
@@ -146,7 +148,8 @@ def test_gemma1_logits_match_transformers(gemma1_checkpoint):
 def test_gemma2_logits_match_transformers(gemma2_checkpoint):
     model, model_dir = gemma2_checkpoint
     cfg, params = _load_ours(model_dir)
-    assert cfg.post_norms and cfg.norm_delta_gain and cfg.embed_scale
+    assert cfg.post_norms and cfg.norm_delta_gain
+    assert cfg.embed_multiplier == math.sqrt(cfg.hidden_size)
     assert cfg.attn_logit_softcap == 50.0
     assert cfg.final_logit_softcap == 30.0
     assert cfg.layer_sliding == (True, False, True, False)
@@ -209,7 +212,7 @@ def test_gemma_param_count_matches_init():
         head_dim=8,
         hidden_act="gelu_tanh",
         norm_delta_gain=True,
-        embed_scale=True,
+        embed_multiplier=math.sqrt(32),
         post_norms=True,
         qk_norm=True,
         tie_word_embeddings=True,

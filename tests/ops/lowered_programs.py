@@ -45,6 +45,8 @@ PROGRAMS = (
     ("command-a-plus-int8-ep8-l8", "command-a-plus-int8-ep8-l8",
      0, 16, 8192, 4096),
     ("olmo-hybrid-7b-int8", "olmo-hybrid-7b-int8", 0, 12, 2560, 1024),
+    ("granite-4.0-h-micro-int8", "granite-4.0-h-micro-int8",
+     0, 64, 2048, 1024),
 )
 
 

@@ -1268,6 +1268,143 @@ def test_a_delta_stack_s_prefill_keeps_its_temporaries_small(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2**30
 
 
+GRANITE_DIR = "perfbench/configs/granite-4.0-h-micro-int8"
+
+
+def _granite(periods: int = 1):
+    """The benchmark's Granite 4.0-H Micro at its published widths,
+    ``periods`` periods of ten layers (``MMMMM*MMMM``)."""
+    import os
+
+    from gpustack_tpu.models.config import load_hf_config
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)
+    )))
+    cfg = load_hf_config(os.path.join(root, GRANITE_DIR))
+    return dataclasses.replace(
+        cfg, num_layers=10 * periods,
+        layer_types=cfg.layer_types[:10 * periods],
+    )
+
+
+def test_a_mamba_stack_s_decode_step_moves_state_and_rows_in_place(one_chip):
+    """The decode program of the benchmark's Granite 4.0-H Micro as the
+    runner traces it on one TPU chip, 64 slots of 2,048: each Mamba-2
+    layer's ``ssm_state_update`` (one group for all 64 heads) reads and
+    writes the stacked state ``[L, 64, 64, 64, 128]`` where it lies, the
+    conv rows ``[L, 64, 13056]`` are updated a layer at a time, and the
+    attention layer's GQA kernel walks rows of **4 stored rows of 128
+    lanes** in place, two heads of 64 to a row
+    (``ModelConfig.kv_heads_a_row``): no copy, transpose or slice of the
+    state, the conv rows or the rows; every layer's matrices read where
+    they lie in their stacks."""
+    from gpustack_tpu.models import init_params
+    from gpustack_tpu.models.hybrid import ssm_update_impl
+    from gpustack_tpu.models.quant import quantize_params
+    from gpustack_tpu.models.transformer import (
+        KVCache,
+        decode_attention_impl,
+        forward,
+    )
+
+    cfg = _granite(2)
+    slots, S = 64, 2048
+    assert (cfg.head_dim, cfg.kv_heads_a_row) == (64, 2)
+    assert decode_attention_impl(cfg, 1, S, "tpu", None) == "kernel"
+    assert ssm_update_impl(1, "tpu", None) == "kernel"
+    params = _shapes_on(
+        one_chip, lambda: quantize_params(init_params(cfg, jax.random.key(0)))
+    )
+    cache = _shapes_on(one_chip, lambda: KVCache.create(cfg, slots, S))
+    assert cache.ssm.shape == (18, slots, 64, 64, 128)
+    assert cache.conv.shape == (18, slots, 13056)
+    assert cache.k.shape == cache.v.shape == (2, slots, S, 4, 128)
+    tokens = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)
+
+    def step(params, tokens, positions, cache, live):
+        return forward(
+            params, cfg, tokens, positions, cache, live=live,
+            decode_attn_impl="kernel", ssm_impl="kernel",
+        )
+
+    compiled = jax.jit(step, donate_argnums=(3,)).lower(
+        params, tokens, tokens, cache, live
+    ).compile()
+    text = compiled.as_text()
+    state = f"f32[18,{slots},64,64,128]"
+    # one period in the scan's body: nine updates and an attention layer
+    assert len(re.findall(
+        rf"%ssm_state_update[\w.\-]* = \({re.escape(state)}", text
+    )) == 9
+    assert len(re.findall(
+        rf"%gqa_decode_attention[\w.\-]* = bf16\[{slots},32,128\]"
+        r".* custom-call\(", text,
+    )) == 1
+    assert not re.findall(
+        rf"= {re.escape(state)}[^ ]* (?:copy|dynamic-update-slice|"
+        r"dynamic-slice|transpose)\(", text,
+    )
+    # the conv rows and the rows are written a layer's or a step's part
+    # at a time into the donated arrays; neither is copied or stored in
+    # another order
+    assert not re.findall(
+        rf"= bf16\[(?:18,{slots},13056|2,{slots},[\d,]+)\][^ ]* "
+        r"(?:copy|transpose)\(", text,
+    )
+    assert f"bf16[2,{slots},{S},4,128]" in text
+    # the mixers' and the MLP's matrices go in as they are stored
+    assert not re.findall(
+        r"= s8\[(?:\d+,)?(?:2048,(?:8512|8192|2048|512)|"
+        r"(?:4096|8192),2048)\][^ ]* (?:copy|transpose)\(", text,
+    )
+    mem = compiled.memory_analysis()
+    held = cache.ssm.size * 4 + cache.conv.size * 2 + 2 * cache.k.size * 2
+    assert mem.alias_size_in_bytes >= held
+    # no copy of the state (2.4 GB here), the conv rows (30 MB) or a
+    # cache (0.27 GB)
+    assert mem.temp_size_in_bytes < 16 * 2**20
+
+
+def test_a_mamba_stack_s_prefill_keeps_its_temporaries_small(one_chip):
+    """A 1,024 prefill of one period at the published widths: nine
+    chunked scans as einsums in float32 (4 chunks of 256, one group),
+    the attention layer through the flash kernel at heads of 64;
+    temporaries that leave the resident model room (0.04 GB at full
+    depth beside 9.4)."""
+    from gpustack_tpu.models import init_params
+    from gpustack_tpu.models.quant import quantize_params
+    from gpustack_tpu.models.transformer import KVCache, forward
+
+    cfg = _granite()
+    T = 1024
+    params = _shapes_on(
+        one_chip, lambda: quantize_params(init_params(cfg, jax.random.key(0)))
+    )
+
+    def prefill(params, tokens, true_len):
+        cache = KVCache.create(cfg, 1, T)
+        positions = jnp.arange(T, dtype=jnp.int32)[None]
+        return forward(
+            params, cfg, tokens, positions, cache, attn_impl="flash",
+            logits_at=(true_len - 1)[None], true_len=true_len[None],
+            ssm_impl="scan",
+        )
+
+    compiled = jax.jit(prefill).lower(
+        params,
+        jax.ShapeDtypeStruct((1, T), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(
+        r"%flash_attention_prefill[\w.\-]* = .* custom-call\(", text
+    )) == 1
+    _no_score_tensor(text, T, T)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2**30
+
+
 @pytest.fixture(scope="module")
 def lowered_hashes(one_chip):
     import os
