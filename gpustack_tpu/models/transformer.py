@@ -313,28 +313,37 @@ def _write_rows(
     rope keys, 64) the TPU stores it with the **positions on the
     lanes**, which no scatter writes in place. A decode step whose
     attention is the kernel's (``decode_attn_impl`` not ``"xla"``: one
-    chip, one row a slot) writes it through the aliased call that lies
-    beside that kernel, a lane tile a slot
-    (``ops/mla_attention.py mla_write_rope_keys``). The pass over the
-    layer's positions writes it whatever the layout, and is what a mesh
-    (a Mosaic call is not partitioned), any other platform, ``T > 1``
-    over a cache (a prefill, a verify step, a continuation, a chunk) and
-    ``by_position`` take: 17 MB read and written a layer at 16 slots of
+    chip, one row a slot) writes either through the aliased call that
+    lies beside that kernel, one stored tile a slot: the rope keys a
+    lane tile (``ops/mla_attention.py mla_write_rope_keys``), the latent
+    a tile of 16 rows (``mla_write_latent_rows``), which the scatter
+    below writes in place too but as a loop of one update a slot (0.86
+    of A.X-K1's 7.43 ms step: PERF.md section 6, PR 57). Everything
+    else keeps what it had: a mesh (a Mosaic call is not partitioned),
+    any other platform, ``T > 1`` over a cache (a prefill, a verify
+    step, a continuation, a chunk) and ``by_position``. For the rope
+    keys that is the pass over the layer's positions, which writes
+    whatever the layout: 17 MB read and written a layer at 16 slots of
     8,192, and the array copied whole once in and once out of the scan
     (0.2 GB and 0.6 ms each in a decode step: PERF.md section 6, PR 54).
     """
     T = rows.shape[1]
     one_head = buf.ndim == 4
+    on_lanes = one_head and buf.shape[3] % 128 != 0
+    if one_head and T == 1 and not by_position and decode_attn_impl != "xla":
+        from gpustack_tpu.ops import mla_attention
+
+        write = (
+            mla_attention.mla_write_rope_keys if on_lanes
+            else mla_attention.mla_write_latent_rows
+        )
+        return write(
+            buf, rows[:, 0], layer, start,
+            interpret=decode_attn_impl == "kernel_interpret",
+        )
     # a row's trailing axes, for what is indexed by [B, S_max] or [B, T]
     each = (slice(None), slice(None)) + (None,) * (rows.ndim - 2)
-    if by_position or (one_head and buf.shape[3] % 128):
-        if T == 1 and not by_position and decode_attn_impl != "xla":
-            from gpustack_tpu.ops.mla_attention import mla_write_rope_keys
-
-            return mla_write_rope_keys(
-                buf, rows[:, 0], layer, start,
-                interpret=decode_attn_impl == "kernel_interpret",
-            )
+    if by_position or on_lanes:
         at = jnp.arange(buf.shape[2], dtype=jnp.int32)[None, :] - jnp.clip(
             start, 0, buf.shape[2] - T
         )[:, None]                                    # [B, S_max] into rows

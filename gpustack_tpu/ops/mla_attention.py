@@ -1,5 +1,5 @@
 """Pallas TPU kernels for a decode step over the latent cache: its
-attention, and the write of its rope keys.
+attention, and the write of its latent rows and of its rope keys.
 
 Latent attention (MLA, DeepSeek-V2/V3 family) caches, for each position
 of a layer, the shared latent ``c_kv`` (``kv_lora_rank`` wide) and the
@@ -42,12 +42,21 @@ grid point a slot fetches the tile of 128 positions that holds the
 slot's position, puts the key on its lane and writes the tile back,
 16 KB each way, where the pass it replaces read and wrote the layer and
 had the array copied whole in and out of the scan (1.8 of A.X-K1's
-9.3 ms step: PERF.md section 6, PR 54). The latent keeps the scatter.
+9.3 ms step: PERF.md section 6, PR 54).
+
+The latent's rows lie as a scatter wants them, and a scatter writes them
+in place; but the TPU runs a scatter of ``B`` windows as a loop of one
+small update a slot, four operations each, one after another (0.86 of
+A.X-K1's 7.43 ms step at 12 layers of 16 slots: PERF.md section 6,
+PR 57). :func:`mla_write_latent_rows` is the same aliased call turned
+round: a grid point a slot fetches the tile of 16 positions that holds
+the slot's position, puts the row on its sublane and writes the tile
+back, the slots' tiles in flight behind one another.
 
 ``tests/ops/test_mla_attention.py`` holds the attention to the XLA
-formulation and the write to ``_write_rows``' pass, bit for bit, in
-interpret mode; ``tests/ops/test_chip_compile.py`` compiles both for a
-described v5e at A.X-K1's widths.
+formulation and both writes to ``_write_rows``' scatter and pass, bit
+for bit, in interpret mode; ``tests/ops/test_chip_compile.py`` compiles
+all three for a described v5e at A.X-K1's widths.
 """
 
 from __future__ import annotations
@@ -69,6 +78,10 @@ from gpustack_tpu.ops.decode_attention import (
     cached_block,
     slot_walk,
 )
+
+# positions to a stored tile whose rows are positions: a bf16 tile's 16
+# sublanes (two float32 tiles of 8)
+_SUBLANES = 16
 
 
 def _kernel(
@@ -198,12 +211,56 @@ def mla_decode_attention(
     )
 
 
-def _write_kernel(layer_ref, start_ref, k_ref, r_ref, o_ref):
-    """Grid point = slot: the step's key onto its lane of the tile."""
+def _write_kernel(layer_ref, start_ref, new_ref, old_ref, o_ref, *, axis):
+    """Grid point = slot: the step's row onto its place along ``axis``
+    of the tile, a sublane (0) or a lane (1)."""
     del layer_ref
-    lane = start_ref[pl.program_id(0)] % r_ref.shape[1]
-    at = lax.broadcasted_iota(jnp.int32, r_ref.shape, 1)
-    o_ref[...] = jnp.where(at == lane, k_ref[...], r_ref[...])
+    place = start_ref[pl.program_id(0)] % old_ref.shape[axis]
+    at = lax.broadcasted_iota(jnp.int32, old_ref.shape, axis)
+    o_ref[...] = jnp.where(at == place, new_ref[...], old_ref[...])
+
+
+def _write_a_tile_a_slot(stored, new, layer, start, *, axis, name, interpret):
+    """``stored [L, B, ., .]`` (a cache as the TPU stores it, its
+    positions on ``2 + axis``) with ``new[b]`` at position ``start[b]``,
+    clamped into ``[0, S - 1]``, of ``[layer, b]``, in place: a grid
+    point a slot fetches the one tile that holds the position (16
+    sublanes or 128 lanes of positions by the whole of the other axis;
+    the whole slot where the tile does not divide it), puts the row in
+    and writes the tile back into the aliased array."""
+    S = stored.shape[2 + axis]
+    start = jnp.clip(start, 0, S - 1)
+    tile = (_SUBLANES, _LANES)[axis]
+    tile = tile if S % tile == 0 else S
+    block = list(stored.shape[2:])
+    block[axis] = tile
+
+    def stored_tile(b, layer, start):
+        at = [0, 0]
+        at[axis] = start[b] // tile
+        return (layer[0], b, *at)
+
+    spec = pl.BlockSpec((None, None, *block), stored_tile)
+    return pl.pallas_call(
+        functools.partial(_write_kernel, axis=axis),
+        out_shape=jax.ShapeDtypeStruct(stored.shape, stored.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(stored.shape[1],),
+            in_specs=[
+                pl.BlockSpec((None, *new.shape[1:]), lambda b, *_: (b, 0, 0)),
+                spec,
+            ],
+            out_specs=spec,
+        ),
+        # operand 3 (after the two prefetched scalars and the rows)
+        input_output_aliases={3: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+        ),
+        name=name,
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), start, new, stored)
 
 
 def mla_write_rope_keys(
@@ -225,35 +282,31 @@ def mla_write_rope_keys(
     each way a slot. No XLA form writes a column of such an array in
     place: a scatter relays the whole array out and back, the pass over
     the layer's positions reads and writes the layer."""
-    L, B, S, rope = r_cache.shape
-    tile = _LANES if S % _LANES == 0 else S
-    start = jnp.clip(start, 0, S - 1)
-
-    def r_tile(b, layer, start):
-        return (layer[0], b, 0, start[b] // tile)
-
-    view = pl.pallas_call(
-        _write_kernel,
-        out_shape=jax.ShapeDtypeStruct((L, B, rope, S), r_cache.dtype),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B,),
-            in_specs=[
-                pl.BlockSpec((None, rope, 1), lambda b, *_: (b, 0, 0)),
-                pl.BlockSpec((None, None, rope, tile), r_tile),
-            ],
-            out_specs=pl.BlockSpec((None, None, rope, tile), r_tile),
-        ),
-        # operand 3 (after the two prefetched scalars and the keys)
-        input_output_aliases={3: 0},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-        ),
-        name="mla_write_rope_keys",
-        interpret=interpret,
-    )(
-        jnp.reshape(layer, (1,)).astype(jnp.int32), start,
-        k_pe[:, :, None],
-        jnp.transpose(r_cache, (0, 1, 3, 2)),
+    view = _write_a_tile_a_slot(
+        jnp.transpose(r_cache, (0, 1, 3, 2)), k_pe[:, :, None], layer, start,
+        axis=1, name="mla_write_rope_keys", interpret=interpret,
     )
     return jnp.transpose(view, (0, 1, 3, 2))
+
+
+def mla_write_latent_rows(
+    c_cache: jax.Array,   # [L, B, S, rank]: KVCache.k, without its one head
+    c_kv: jax.Array,      # [B, rank]: the step's latent a slot, normed
+    layer: jax.Array,     # int32 scalar: which of the L
+    start: jax.Array,     # int32 [B]: each slot's position
+    *,
+    interpret: bool = False,
+) -> jax.Array:
+    """``c_cache`` with ``c_kv[b]`` at ``[layer, b, start[b]]``, a start
+    clamped into ``[0, S - 1]``: :func:`mla_write_rope_keys`' contract,
+    on the array whose rows the TPU stores as rows.
+
+    In place: of each slot the one tile of 16 positions that holds its
+    row is fetched (16 KB at a rank of 512 in bf16), the row put on its
+    sublane, and the tile written back into the aliased cache. The
+    scatter this stands for writes in place too, but as a loop of one
+    update a slot whose four small operations wait on one another."""
+    return _write_a_tile_a_slot(
+        c_cache, c_kv[:, None, :], layer, start,
+        axis=0, name="mla_write_latent_rows", interpret=interpret,
+    )

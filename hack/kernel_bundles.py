@@ -8,6 +8,7 @@ count.
 
     python3 hack/kernel_bundles.py ssm            # ops/ssm.py, Nemotron's cell
     python3 hack/kernel_bundles.py delta      # ops/delta_rule.py, Olmo-Hybrid's
+    python3 hack/kernel_bundles.py latent     # ops/mla_attention.py's row write
     python3 hack/kernel_bundles.py ssm --keep <an empty directory>
 
 The dump aborts the process once the kernel's files are written (a
@@ -60,11 +61,26 @@ def compile_delta(on):
     ).compile()
 
 
+def compile_latent(on):
+    import jax
+    import jax.numpy as jnp
+
+    from gpustack_tpu.ops.mla_attention import mla_write_latent_rows
+
+    L, B, S, rank = 12, 16, 8192, 512
+    bf16 = jnp.bfloat16
+    jax.jit(mla_write_latent_rows, donate_argnums=0).lower(
+        on((L, B, S, rank), bf16), on((B, rank), bf16), on((), jnp.int32),
+        on((B,), jnp.int32),
+    ).compile()
+
+
 # the script's name for a kernel -> (its pallas_call's name, what lowers
 # and compiles it at a cell's shapes given ``on(shape, dtype)``)
 KERNELS = {
     "ssm": ("ssm_state_update", compile_ssm),
     "delta": ("delta_state_update", compile_delta),
+    "latent": ("mla_write_latent_rows", compile_latent),
 }
 
 

@@ -638,12 +638,16 @@ def test_the_latent_decode_step_moves_no_cache(one_chip, layers):
     cell's 12 layers (a scan over eleven alike) and at 2 (both written
     out): the absorbed attention is the kernel the benchmark's reader
     finds by name, and no operation copies, transposes or slices out the
-    latent cache, the 7/8 of it that is 512 wide. The rope keys (64
-    wide, stored with the positions on the lanes) are written by a call
-    of their own, in place, a tile a slot: nothing copies, relays out or
-    passes over their array or a layer of it (until PR 54 a pass over
-    the layer, and the array copied whole once in and once out a step:
-    202.6 MB of temporaries)."""
+    latent cache, the 7/8 of it that is 512 wide. Either array is
+    written by an aliased call of its own, in place, one stored tile a
+    slot. The latent's rows: no loop over the slots of one
+    ``dynamic-update-slice`` each (until PR 57 the scatter's, two such
+    ``while``s in the text, the first layer's and the scan's, 0.77 of a
+    7.44 ms step). The rope keys (64 wide, stored with the positions on
+    the lanes): nothing copies, relays out or passes over their array or
+    a layer of it (until PR 54 a pass over the layer, and the array
+    copied whole once in and once out a step: 202.6 MB of
+    temporaries)."""
     from gpustack_tpu.models.transformer import KVCache, forward
 
     cfg = _axk1(layers)
@@ -675,6 +679,24 @@ def test_the_latent_decode_step_moves_no_cache(one_chip, layers):
         and re.search(r" (copy|transpose|dynamic-slice)\(", line)
     ]
     assert moved == []
+    # its rows: written by the call into the operand it aliases, once
+    # where the first (dense) layer is written out and once in the scan
+    # over the rest (one call a layer) ...
+    calls = re.findall(
+        rf"%mla_write_latent_rows[\w.\-]* = bf16\[{layers},16,8192,512\]"
+        r".* custom-call\(.*output_to_operand_aliasing={{}: \(3, {}\)}",
+        text,
+    )
+    assert len(calls) == 2, calls
+    # ... and by nothing else: no update of a window of the array, which
+    # is what the scatter's loop over the slots is made of, and no loop
+    # but the scan over the layers
+    assert not re.findall(
+        rf"= bf16\[{layers},16,8192,512\][^ ]* "
+        r"(dynamic-update-slice|scatter)\(",
+        text,
+    )
+    assert len(re.findall(r" while\(", text)) == (1 if layers > 2 else 0)
     # the rope keys: written by the call, as the TPU stores them ...
     assert re.search(
         rf"%mla_write_rope_keys[\w.\-]* = bf16\[{layers},16,64,8192\]"

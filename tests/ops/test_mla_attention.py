@@ -9,6 +9,7 @@ import pytest
 from gpustack_tpu.ops.mla_attention import (
     block_positions,
     mla_decode_attention,
+    mla_write_latent_rows,
     mla_write_rope_keys,
 )
 
@@ -98,6 +99,21 @@ def _rope_cache(L, B, S, dtype=jnp.bfloat16):
     )
 
 
+def _the_call_writes_what_write_rows_does(call, cache, rows, starts, layer):
+    from gpustack_tpu.models.transformer import _write_rows
+
+    start = jnp.asarray(starts, jnp.int32)
+    want = _write_rows(cache, rows, jnp.int32(layer), start)
+    got = call(cache, rows[:, 0], jnp.int32(layer), start, interpret=True)
+    bits = lambda a: np.asarray(a).view(np.uint16)
+    np.testing.assert_array_equal(bits(got), bits(want))
+    # and the oracle did write: one row a slot differs from the old cache
+    at = np.clip(starts, 0, cache.shape[2] - 1)
+    changed = (bits(want) != bits(cache)).any(axis=-1)
+    assert changed.sum() == len(starts)
+    assert changed[layer, np.arange(len(starts)), at].all()
+
+
 @pytest.mark.parametrize("layer", [0, 2], ids=["first-layer", "last-layer"])
 @pytest.mark.parametrize(
     "starts",
@@ -115,21 +131,10 @@ def test_the_rope_key_write_is_write_rows_pass_bit_for_bit(starts, layer):
     """The aliased call against ``_write_rows``' pass over the layer, on
     the bits: the step's keys where the pass puts them, and every other
     layer and position of the cache as it was."""
-    from gpustack_tpu.models.transformer import _write_rows
-
     cache, rows = _rope_cache(3, len(starts), ROPE_S)
-    start = jnp.asarray(starts, jnp.int32)
-    want = _write_rows(cache, rows, jnp.int32(layer), start)
-    got = mla_write_rope_keys(
-        cache, rows[:, 0], jnp.int32(layer), start, interpret=True
+    _the_call_writes_what_write_rows_does(
+        mla_write_rope_keys, cache, rows, starts, layer
     )
-    bits = lambda a: np.asarray(a).view(np.uint16)
-    np.testing.assert_array_equal(bits(got), bits(want))
-    # and the oracle did write: one row a slot differs from the old cache
-    at = np.clip(starts, 0, ROPE_S - 1)
-    changed = (bits(want) != bits(cache)).any(axis=-1)
-    assert changed.sum() == len(starts)
-    assert changed[layer, np.arange(len(starts)), at].all()
 
 
 @pytest.mark.parametrize(
@@ -151,6 +156,111 @@ def test_the_rope_key_write_takes_the_tests_caches_and_float32(S, dtype):
     )
 
 
+# ---- a decode step's latent rows, in place (mla_write_latent_rows) ----
+
+LATENT_S = 64    # four tiles of 16 rows a slot
+
+
+def _latent_cache(L, B, S, dtype=jnp.bfloat16, rank=256):
+    keys = jax.random.split(jax.random.key(L * S + B + rank), 2)
+    return (
+        jax.random.normal(keys[0], (L, B, S, rank), jnp.float32).astype(dtype),
+        jax.random.normal(keys[1], (B, 1, rank), jnp.float32).astype(dtype),
+    )
+
+
+@pytest.mark.parametrize(
+    "layer", [0, 1, 2], ids=["first-layer", "middle-layer", "last-layer"]
+)
+@pytest.mark.parametrize(
+    "starts",
+    [
+        [0, 0, 0, 0],                     # every slot at the same position
+        [15, 16, 1, 47],                  # a tile's last row, the next's first
+        [63, 63, 0, 32],                  # S_max - 1
+        [64, 300, 9000, 2 ** 31 - 1],     # past S_max: clamped to the last
+        [-1, -16, -(2 ** 31), 5],         # negative: clamped to the first
+        [3, 17, 30, 61],                  # all at different ones
+    ],
+    ids=["same", "tile-edge", "last", "past-the-end", "negative", "different"],
+)
+def test_the_latent_row_write_is_write_rows_scatter_bit_for_bit(starts, layer):
+    """The aliased call against ``_write_rows``' scatter, on the bits: the
+    step's rows where the scatter puts them, and every other layer and
+    position of the cache as it was."""
+    cache, rows = _latent_cache(3, len(starts), LATENT_S)
+    _the_call_writes_what_write_rows_does(
+        mla_write_latent_rows, cache, rows, starts, layer
+    )
+
+
+@pytest.mark.parametrize(
+    "S,dtype,rank",
+    [
+        (64, jnp.float32, 128), (256, jnp.float32, 128),
+        (1536, jnp.bfloat16, 512), (40, jnp.bfloat16, 128),
+    ],
+    ids=["four-tiles-f32", "sixteen-tiles-f32", "96-tiles-of-512",
+         "no-tile-divides-40"],
+)
+def test_the_latent_row_write_takes_the_tests_caches_and_float32(
+    S, dtype, rank
+):
+    from gpustack_tpu.models.transformer import _write_rows
+
+    cache, rows = _latent_cache(2, 3, S, dtype, rank)
+    start = jnp.asarray([S - 1, 0, S // 2 + 1], jnp.int32)
+    # through _write_rows, as forward calls it
+    write = lambda impl: _write_rows(
+        cache, rows, jnp.int32(1), start, decode_attn_impl=impl
+    )
+    np.testing.assert_array_equal(
+        np.asarray(write("kernel_interpret"), np.float32),
+        np.asarray(write("xla"), np.float32),
+    )
+
+
+def _count_calls(monkeypatch, took):
+    """Every call of either aliased write appends its name to ``took``."""
+    from gpustack_tpu.ops import mla_attention
+
+    for name in ("mla_write_latent_rows", "mla_write_rope_keys"):
+        real = getattr(mla_attention, name)
+        monkeypatch.setattr(
+            mla_attention, name,
+            lambda *a, _name=name, _real=real, **kw:
+                took.append(_name) or _real(*a, **kw),
+        )
+
+
+def test_only_a_decode_step_s_one_row_a_slot_takes_the_calls(monkeypatch):
+    """``_write_rows`` gives a latent cache's arrays to the aliased calls
+    at one row a slot under the kernel's attention and at nothing else:
+    ``T > 1``, the XLA step and ``by_position`` keep the scatter and the
+    pass, as does a cache with its heads."""
+    from gpustack_tpu.models.transformer import _write_rows
+
+    took = []
+    _count_calls(monkeypatch, took)
+    latent, row = _latent_cache(2, 3, LATENT_S)
+    keys, key = _rope_cache(2, 3, ROPE_S)
+    start = jnp.asarray([5, 0, 33], jnp.int32)
+    write = lambda buf, rows, **kw: _write_rows(
+        buf, rows, jnp.int32(1), start, **kw
+    )
+    write(latent, row, decode_attn_impl="kernel_interpret")
+    write(keys, key, decode_attn_impl="kernel_interpret")
+    assert took == ["mla_write_latent_rows", "mla_write_rope_keys"]
+    two = lambda rows: jnp.concatenate([rows, rows], axis=1)
+    write(latent, row, decode_attn_impl="xla")
+    write(keys, key)
+    write(latent, two(row), decode_attn_impl="kernel_interpret")
+    write(keys, two(key), decode_attn_impl="kernel_interpret")
+    write(latent, row, by_position=True, decode_attn_impl="kernel_interpret")
+    write(latent[:, :, :, None], row[:, :, None])     # [L, B, S, 1, width]
+    assert len(took) == 2
+
+
 MLA_HF = {
     "architectures": ["DeepseekV3ForCausalLM"], "model_type": "axk1",
     "vocab_size": 264, "hidden_size": 64, "intermediate_size": 160,
@@ -167,11 +277,14 @@ MLA_HF = {
 }
 
 
-def test_a_decode_step_of_a_two_layer_model_writes_the_xla_step_s_cache():
+def test_a_decode_step_of_a_two_layer_model_writes_the_xla_step_s_cache(
+    monkeypatch,
+):
     """One decode step of a two-layer A.X-K1 through ``forward``, the
-    kernels' step (attention and the rope keys' write, interpret mode)
-    against the XLA step: the first layer's rows and every position the
-    step does not write on the bits, the second layer's rows (behind the
+    kernels' step (attention and both arrays' writes, the latent's rows
+    and the rope keys, interpret mode) against the XLA step: of either
+    array the first layer's rows and every position the step does not
+    write on the bits, the second layer's rows (behind the
     first's attention, whose two forms round apart) and the logits to
     float32's rounding."""
     import dataclasses
@@ -192,9 +305,19 @@ def test_a_decode_step_of_a_two_layer_model_writes_the_xla_step_s_cache():
     )
     toks = jax.random.randint(keys[2], (B, 1), 0, cfg.vocab_size)
     pos = jnp.asarray([[127], [128], [255]], jnp.int32)
-    (want, want_cache), (got, got_cache) = (
-        forward(params, cfg, toks, pos, cache, decode_attn_impl=impl)
-        for impl in ("xla", "kernel_interpret")
+    took = []
+    _count_calls(monkeypatch, took)
+    want, want_cache = forward(
+        params, cfg, toks, pos, cache, decode_attn_impl="xla"
+    )
+    assert took == []
+    got, got_cache = forward(
+        params, cfg, toks, pos, cache, decode_attn_impl="kernel_interpret"
+    )
+    # the latent's array (128 wide here, whole lane tiles as A.X-K1's
+    # 512) and the rope keys', each through its call in both layers
+    assert sorted(took) == (
+        ["mla_write_latent_rows"] * 2 + ["mla_write_rope_keys"] * 2
     )
     written = np.zeros((2, B, S), bool)
     written[:, np.arange(B), np.asarray(pos[:, 0])] = True
