@@ -50,6 +50,7 @@ import numpy as np
 from gpustack_tpu.engine.runner import DecodeState, ModelRunner
 from gpustack_tpu.engine.tokenizer import load_tokenizer
 from gpustack_tpu.models.config import ModelConfig
+from gpustack_tpu.observability import capture as _capture
 from gpustack_tpu.observability import flight as _flight
 from gpustack_tpu.observability import startup as _startup
 
@@ -101,6 +102,8 @@ GUARDED_BY = {
     "_overlap_s": "_overlap_mu",
     "_profile": "_profile_mu",
     "_capturing": "_profile_mu",
+    "_captures": "_profile_mu",
+    "_last_capture": "_profile_mu",
     "_KVStager._inflight": "_mu",
     "_slots": _SCHEDULER_METHODS,
     "_free": _SCHEDULER_METHODS,
@@ -663,6 +666,12 @@ class LLMEngine:
         self._profile_mu = threading.Lock()
         self._profile: Optional[Dict[str, Any]] = None
         self._capturing = False
+        # captures begun, and (which capture, what /healthz keeps of its
+        # trace's summary): the newest traced capture's
+        self._captures = 0
+        self._last_capture: Tuple[int, Optional[Dict[str, Any]]] = (0, None)
+        # the step_num of the sched.step span the scheduler is in
+        self._span_step = 0
         self.ttft_hist = LatencyHistogram(TTFT_BUCKETS_S)
         self.tpot_hist = LatencyHistogram(TPOT_BUCKETS_S)
         self.e2e_hist = LatencyHistogram(E2E_BUCKETS_S)
@@ -975,6 +984,10 @@ class LLMEngine:
             "compile_seconds_total": round(
                 self.flight.compile_seconds_total, 3
             ),
+            # the newest traced capture's idle time by host span
+            # (capture_profile; observability/capture.py digest): None
+            # until one has been summarised
+            "last_capture": self._last_capture_digest(),
             # the replica's multi-chip layout as one inspectable object
             # (parallel/sharding.SpecLayout)
             "layout": self.runner.layout.describe(),
@@ -1116,16 +1129,20 @@ class LLMEngine:
 
     def step(self) -> bool:
         """One scheduling iteration. Returns False when fully idle."""
-        # unlocked probe: None is the steady state
-        if self._profile is None:  # analysis: ignore[guarded-by]
+        # unlocked probe: False is the steady state
+        if not self._capturing:  # analysis: ignore[guarded-by]
             self._phases.annotate = None
             return self._step()
-        # a capture is open: the step and its phases also go into the
+        # a capture is open, from before the profiler starts until after
+        # it has stopped (the steps that run while it does either are in
+        # the trace too): the step and its phases also go into the
         # profiler's trace as host spans, on the clock of the device's
-        # operations
+        # operations. Outside a profiler session an annotation does
+        # nothing.
         self._phases.annotate = jax.profiler.TraceAnnotation
+        self._span_step = self._step_count
         with jax.profiler.StepTraceAnnotation(
-            "sched.step", step_num=self._step_count
+            "sched.step", step_num=self._span_step
         ):
             return self._step()
 
@@ -1133,6 +1150,7 @@ class LLMEngine:
         phases = self._phases
         phases.reset()
         t0 = time.perf_counter()
+        cpu0 = time.thread_time()
         self._step_mode = ""
         self._step_real = self._step_padded = 0
         self._step_out = self._step_prompt = 0
@@ -1162,10 +1180,10 @@ class LLMEngine:
             progressed = self._advance_chunk()
         if self._slots:
             self._decode_once()
-            self._flight_record(t0)
+            self._flight_record(t0, cpu0)
             return True
         if admitted or progressed or self._chunk_jobs:
-            self._flight_record(t0)
+            self._flight_record(t0, cpu0)
             return True
         # Nothing active: drain any lagging fetches so finished requests
         # complete deterministically.
@@ -1175,7 +1193,7 @@ class LLMEngine:
             # tokens delivered by the drain would otherwise vanish when
             # the next step resets the accumulators — record them so
             # flight tokens_out/spec_accepted match tokens_generated
-            self._flight_record(t0)
+            self._flight_record(t0, cpu0)
         return not self._waiting.empty()
 
     def _note_prefill(self, tokens: int, bucket: int) -> None:
@@ -1207,9 +1225,14 @@ class LLMEngine:
         self._step_window_rows += sliding * cfg.num_window_layers
         self._step_full_rows += full * cfg.num_kv_layers
 
-    def _flight_record(self, t0: float) -> None:
-        """Seal this step's flight record (and advance an in-flight
-        profiler capture). Scheduler-thread only."""
+    def _flight_record(self, t0: float, cpu0: float) -> None:
+        """Seal the flight record of the step that began at ``t0`` on
+        the wall clock and ``cpu0`` on this thread's own (and advance an
+        in-flight profiler capture). Scheduler-thread only."""
+        # this thread's own CPU time in the step, read inside the wall
+        # interval: what is left of dur_s beside it and the wait phase is
+        # time the thread wanted to run and did not
+        cpu_s = time.thread_time() - cpu0
         dur_s = time.perf_counter() - t0
         oldest = 0.0
         try:
@@ -1228,6 +1251,7 @@ class LLMEngine:
         phases_s = self._phases.seconds
         programs = self.flight.record(
             dur_s=dur_s,
+            cpu_s=cpu_s,
             host_overlap_s=max(0.0, overlap_delta),
             phases_s=phases_s,
             admitted=self._step_admitted,
@@ -1265,8 +1289,8 @@ class LLMEngine:
         )
         if dur_s > _SLOW_STEP_S:
             logger.warning(
-                "slow scheduler step: %.0f ms, mode %s, %s%s",
-                dur_s * 1e3, mode, ", ".join(
+                "slow scheduler step: %.0f ms (cpu %.0f ms), mode %s, %s%s",
+                dur_s * 1e3, cpu_s * 1e3, mode, ", ".join(
                     f"{name} {sec * 1e3:.0f} ms"
                     for name, sec in zip(_flight.PHASES, phases_s)
                 ),
@@ -1297,6 +1321,13 @@ class LLMEngine:
         trace does not stand in its way. Steps that run while the trace
         is being stopped are in the trace too, past the ones asked for.
 
+        A capture with a trace is then summarised (``idle``:
+        ``observability/capture.py``, what the host was doing while the
+        chip stood idle), by a child process and on the calling thread,
+        with the capture already released; ``/healthz`` keeps the newest
+        one's digest (``last_capture``). A child that fails leaves
+        ``{"error": ...}`` in both and the capture's answer whole.
+
         Blocks up to ``timeout_s`` for the steps to elapse; an idle
         engine returns whatever was captured by the deadline. One
         capture at a time — a concurrent request gets a ValueError
@@ -1305,6 +1336,8 @@ class LLMEngine:
             "remaining": max(1, min(int(steps), 10_000)),
             "requested": max(1, min(int(steps), 10_000)),
             "records": [],
+            # the step_num of a sched.step span -> its step's record
+            "record_of": {},
             "done": threading.Event(),
         }
         with self._profile_mu:
@@ -1313,6 +1346,8 @@ class LLMEngine:
                     "a profile capture is already in progress"
                 )
             self._capturing = True
+            self._captures += 1
+            seq = self._captures
         profiler, error = "flight-only", ""
         try:
             if out_dir:
@@ -1328,6 +1363,7 @@ class LLMEngine:
                 # the idle-timeout path: the countdown never reached zero
                 self._profile = None
                 records = list(cap["records"])
+                record_of = dict(cap["record_of"])
             if profiler == "jax":
                 try:
                     jax.profiler.stop_trace()
@@ -1337,7 +1373,7 @@ class LLMEngine:
         finally:
             with self._profile_mu:
                 self._capturing = False
-        return {
+        result = {
             "requested": cap["requested"],
             "steps_captured": len(records),
             "profiler": profiler,
@@ -1349,6 +1385,20 @@ class LLMEngine:
                 overhead_ratio=self.flight.overhead_ratio(),
             ) if records else {},
         }
+        if profiler == "jax":
+            idle = _capture.summarize_in_child(out_dir)
+            for gap in idle.get("gaps") or ():
+                # which of ``records`` is the step the gap fell in
+                gap["record"] = record_of.get(gap["step_num"])
+            result["idle"] = idle
+            with self._profile_mu:
+                if seq > self._last_capture[0]:
+                    self._last_capture = (seq, _capture.digest(idle))
+        return result
+
+    def _last_capture_digest(self) -> Optional[Dict[str, Any]]:
+        with self._profile_mu:
+            return self._last_capture[1]
 
     def _profile_step(self) -> None:
         """Advance the armed capture by one recorded step (scheduler
@@ -1361,6 +1411,11 @@ class LLMEngine:
             snap = self.flight.snapshot(limit=1)
             if snap:
                 cap["records"].append(snap[-1])
+                # (a step that began before the capture did has no span)
+                if self._phases.annotate is not None:
+                    cap["record_of"].setdefault(
+                        self._span_step, len(cap["records"]) - 1
+                    )
             cap["remaining"] -= 1
             if cap["remaining"] <= 0:
                 self._profile = None

@@ -41,6 +41,17 @@ Record vocabulary (per step):
   the scheduler's thread blocked on the device. ``dur_ms - wait_ms`` is
   the host's own work in the step (``host_ms_p50`` per mode in the
   aggregate).
+- ``cpu_ms`` — the CPU time of the scheduler's own thread in the step
+  (``time.thread_time()`` at its start and at its end). ``dur_ms -
+  wait_ms - cpu_ms`` is the time the thread wanted to run and did not:
+  the interpreter held by another thread, or a block inside a runtime
+  call that no ``wait`` phase covers. The thread's clock is as fine as
+  the host's kernel keeps it: where it moves in ticks of 10 ms (the
+  chip's host), a step of 6 ms reads 0.0 or 10.0, a step of two seconds
+  is told to a tick, and over many steps the mean is right where a
+  single step's number is not (``cpu_ms_mean`` per mode in the
+  aggregate). Absent from a record whose writer gave none (the stub
+  engine's).
 - ``admitted``/``first_tokens`` — per request, in the step that caused
   them: ``[trace_id, wait_ms]`` for each request taken from the queue
   (now - submitted), ``[trace_id, ms]`` for each request whose first
@@ -221,6 +232,7 @@ def aggregate_records(
         return out
     by_mode: Dict[str, List[float]] = {}
     host_by_mode: Dict[str, List[float]] = {}
+    cpu_by_mode: Dict[str, List[float]] = {}
     occ: List[float] = []
     waits: List[float] = []
     real = padded = tokens_out = proposed = accepted = 0
@@ -231,6 +243,8 @@ def aggregate_records(
         host_by_mode.setdefault(e["mode"], []).append(
             e["dur_ms"] - e.get("wait_ms", 0.0)
         )
+        if "cpu_ms" in e:
+            cpu_by_mode.setdefault(e["mode"], []).append(e["cpu_ms"])
         occ.append(e["slots_used"] / max(1, slots_total))
         waits.append(e["oldest_wait_ms"])
         real += e["tokens_real"]
@@ -256,6 +270,12 @@ def aggregate_records(
             "host_ms_p50": round(
                 _pctl(sorted(host_by_mode[mode]), 0.5), 3
             ),
+            # the thread's own CPU time a step: the mean, which a
+            # clock that moves in ticks longer than a step still gets
+            # right (records with ``cpu_ms``)
+            **({"cpu_ms_mean": round(
+                sum(cpu_by_mode[mode]) / len(cpu_by_mode[mode]), 3
+            )} if mode in cpu_by_mode else {}),
         }
         for mode, durs in sorted(by_mode.items())
     }
@@ -432,6 +452,7 @@ class FlightRecorder:
         kv_blocks: int = 0,
         kv_reused_total: int = 0,
         host_overlap_s: float = 0.0,
+        cpu_s: Optional[float] = None,
         phases_s: Sequence[float] = (0.0,) * len(PHASES),
         admitted: Sequence = (),
         first_tokens: Sequence = (),
@@ -463,7 +484,7 @@ class FlightRecorder:
             kv_reused_total, host_overlap_s, phases_s, admitted,
             first_tokens, traced, compiled, moe_dispatch, attn,
             kv_live, kv_allocated, moe_read, moe_held, programs, ssm,
-            attn_rows,
+            attn_rows, cpu_s,
         )
         with self._mu:
             if self._unfolded >= self._fold_at:
@@ -489,7 +510,7 @@ class FlightRecorder:
              spec_proposed, spec_accepted, kv_blocks, _kv_reused,
              host_overlap_s, _phases, _admitted, _first, _traced,
              _compiled, moe_dispatch, _attn, kv_live, kv_allocated,
-             moe_read, moe_held, _programs, ssm, attn_rows) = row
+             moe_read, moe_held, _programs, ssm, attn_rows, _cpu) = row
             h = self._hist.get(mode)
             if h is None:
                 h = self._hist[mode] = [
@@ -570,7 +591,7 @@ class FlightRecorder:
          spec_proposed, spec_accepted, kv_blocks, kv_reused_total,
          host_overlap_s, phases_s, admitted, first_tokens, traced,
          compiled, moe_dispatch, attn, kv_live, kv_allocated,
-         moe_read, moe_held, programs, ssm, attn_rows) = row
+         moe_read, moe_held, programs, ssm, attn_rows, cpu_s) = row
         entry = {
             "ts": ts,
             "dur_ms": round(dur_s * 1e3, 4),
@@ -600,6 +621,8 @@ class FlightRecorder:
             "traced": traced,
             "compiled": compiled,
         }
+        if cpu_s is not None:
+            entry["cpu_ms"] = round(cpu_s * 1e3, 4)
         if moe_dispatch:
             entry["moe_dispatch"] = dict(moe_dispatch)
         if attn:

@@ -16,6 +16,7 @@ import time
 import aiohttp
 from aiohttp import web
 
+from gpustack_tpu.observability.capture import CHILD_TIMEOUT_S
 from gpustack_tpu.routes.crud import json_error
 from gpustack_tpu.scheduler.calculator import (
     EvaluationError,
@@ -1279,7 +1280,7 @@ def add_extra_routes(app: web.Application) -> None:
             # on the capture-in-progress guard)
             resp = await worker_fetch(
                 request.app, worker, "POST", path,
-                timeout=timeout_s + 90,
+                timeout=timeout_s + 90 + CHILD_TIMEOUT_S,
             )
         except (
             aiohttp.ClientError, OSError, asyncio.TimeoutError,

@@ -18,6 +18,8 @@ from typing import Dict, List, Optional, Tuple
 import aiohttp
 from aiohttp import web
 
+from gpustack_tpu.observability.capture import CHILD_TIMEOUT_S
+
 logger = logging.getLogger(__name__)
 
 TAIL_DEFAULT = 200
@@ -445,7 +447,11 @@ class WorkerServer:
         try:
             async with self._proxy_session.post(
                 url,
-                timeout=aiohttp.ClientTimeout(total=timeout_s + 60),
+                # the steps, the profiler's stop, and the summary of the
+                # trace (a child with a time limit of its own)
+                timeout=aiohttp.ClientTimeout(
+                    total=timeout_s + 60 + CHILD_TIMEOUT_S
+                ),
             ) as upstream:
                 try:
                     payload = await upstream.json()
