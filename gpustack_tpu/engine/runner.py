@@ -108,6 +108,23 @@ def prefill_attention(
     return "xla"
 
 
+def flash_tile(cfg: ModelConfig, bucket: int, tp: int = 1):
+    """The tile the flash kernel's shapes choose for a prefill of
+    ``bucket`` tokens from scratch, and the points of one call's grid on
+    one device of ``tp``: what says in the log that a rule of
+    ``ops/flash_attention.py choose_tiles`` engaged. From the
+    configuration alone, outside any traced function."""
+    from gpustack_tpu.ops import flash_attention as fa
+
+    rows = -(-bucket // fa.SUB_K) * fa.SUB_K
+    tiles = fa.choose_tiles(
+        rows, rows, cfg.num_heads // cfg.num_kv_heads,
+        max(cfg.head_dim, cfg.v_head_dim), jnp.dtype(cfg.dtype).itemsize,
+    )
+    stored = cfg.num_heads if cfg.is_mla else cfg.kv_heads_stored
+    return tiles, fa.grid_points(tiles, rows, rows, max(1, stored // tp))
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class DecodeState:
@@ -344,7 +361,10 @@ class ModelRunner:
             # which kernel serves which prompt widths
             self._logged_attn_buckets.add(bucket)
             logger.info(
-                "prefill bucket %d: attention impl %s", bucket, impl
+                "prefill bucket %d: attention impl %s%s", bucket, impl,
+                ", %s, %d grid points a call" % flash_tile(
+                    self.cfg, bucket, int(self.mesh.shape.get("tp", 1))
+                ) if impl == "flash" else "",
             )
         return impl
 

@@ -9,6 +9,33 @@ has tens of points a layer, not thousands (at 2,048 tokens, 32 / 8
 heads: 64-128 against the 8,192 of the one-head, 128 x 128 grid this
 replaced; a grid point costs about 0.3 us whatever it does).
 
+That holds for a group of 4, 8 or 16. **A group of one** has a key/value
+head a query head (a latent model's decompressed prefill, A.X-K1: 64
+heads, keys 192 and values 128 wide; Olmo-Hybrid's 32 stored heads), and
+with blocks of 512 rows, the most any group was offered until PR 59, its
+point held one head's 512 rows where another's holds 2,048: at 8,192
+tokens 16,384 points a call, 7,680 of them above the diagonal, matmuls of
+512 rows, and a K/V block fetched for 512 rows: 20.70 ms a call, 33.7 %
+of its roofline as counted (40.5 % of what is issued: a key of 192 is
+two passes of the 128-deep matrix unit) where the groups' calls read
+41.5-44.2 %. So a group of one is offered blocks of 1,024 query rows, one
+matmul of them, and of up to 2,048 keys (:func:`candidate_tiles`), and
+the call asks for the VMEM they take. Measured on a v5e at ``[1, 64,
+8192, 192 / 128]`` (``hack/flash_bench.py --sweep``; PERF.md section 5,
+PR 59; ``block_q`` x ``block_k``, points, ms): 512 x 512 16,384 20.70;
+1,024 x 1,024 4,096 16.06; **1,024 x 2,048 2,048 15.78** (44.2 %
+counted, 53.1 % issued); at 4,096 tokens 5.69 -> 4.45. Halving the
+points alone is worth 0.14 us a point there (512 x 512 / 1,024 / 2,048:
+20.70 / 19.53 / 18.94), the rest is the rows of a matmul and the K/V
+traffic. At 1,024 tokens (Olmo-Hybrid: 32 points or 128) every tile reads
+0.214-0.225 ms. **Not offered: a ``block_q`` of 2,048** in two matmuls
+of 1,024. It reads 15.29 ms alone and a first token of A.X-K1's cell 5
+ms sooner (607.5 against 612.7 ms), and its body holds every sweep twice,
+once a q sub-block: the two prefill programs' trace and lowering took
+3.1 s more of a start (``start.lower_s`` 26.60 against 23.46 s on one
+machine; 23.52 with blocks of 1,024), which the groups of 4 and 8 have
+paid since PR 32 (``sub_q`` 256 in 512).
+
 Inside a point the fetched block is walked in 128-row sub-blocks, in
 ascending order, and the q rows in sub-blocks of ``sub_q``: the running
 max / sum / accumulator (VMEM scratch that persists across the k sweep)
@@ -65,7 +92,8 @@ fp32 score tensor: 128 GiB at 32k for an 8B model; reference
 long-context profile:
 gpustack/assets/profiles_config/profiles_config.yaml:29-38).
 
-The block sizes follow the shapes (:func:`choose_tiles`): no knob.
+The block sizes follow the shapes (:func:`choose_tiles`: the group's
+size, the padded lengths, the head widths, the item size): no knob.
 
 Engine wiring: ``models/transformer.forward(attn_impl="flash")`` uses this
 for prefill steps; the engine enables it per prefill bucket
@@ -97,9 +125,17 @@ _NEG = -1e30
 # what a grid point may hold of the 16 MiB of VMEM a kernel is given by
 # default on a v5e (blocks double-buffered, scratch, float32 temporaries)
 _VMEM_BUDGET = 10 * 1024 * 1024
+# and what a point may hold where the call asks for its VMEM
+# (``vmem_limit_bytes``, by the same arithmetic): a v5e has 128 MiB
+_VMEM_ASKED = 24 * 1024 * 1024
 # rows of one matmul inside a grid point (all G heads of ``sub_q`` rows)
 # and sub-blocks of an interior stretch in one basic block: the chip's
-# sweep (PERF.md, PR 32) has 512 rows 18 % and no unrolling 16 % slower
+# sweep (PERF.md, PR 32) has 512 rows 18 % and no unrolling 16 % slower.
+# At a group of one (PR 59's sweep, 8,192 tokens) a ``sub_q`` of 1,024 in
+# a block of 1,024 reads 16.06 ms against 18.40 for two of 512 (7.4
+# bundles a row against 8.9, ``hack/kernel_bundles.py flash``) and, in a
+# block of 2,048, two of 1,024 15.29 against 15.71 for one of 2,048; no
+# unrolling 18.10 against 16.06
 _MATMUL_ROWS = 1024
 _UNROLL = 4
 
@@ -114,11 +150,13 @@ class Tiles(NamedTuple):
 def tiles_of(block_q: int, block_k: int, G: int) -> Tiles:
     """The tile of a pair of block sizes: the one place a tile is made.
     The block sizes are the choice; the inner shape follows from them, the
-    group's size and the two constants above. ``sub_q`` is the largest of
-    512 / 256 / 128 that holds at most ``_MATMUL_ROWS`` rows over the
-    ``G`` heads and divides ``block_q``: the kernel walks ``block_q //
-    sub_q`` sub-blocks, so one that does not divide would leave a
-    q-block's last rows unwritten (any ``G``: 3, 5, 6, 7 too)."""
+    group's size and the two constants above. ``sub_q`` is the largest
+    power of two from 128 up that holds at most ``_MATMUL_ROWS`` rows over
+    the ``G`` heads and divides ``block_q`` (1,024 at a group of one; 2,048
+    only where ``hack/flash_bench.py`` sweeps the constant): the kernel
+    walks ``block_q // sub_q`` sub-blocks, so one that does not divide
+    would leave a q-block's last rows unwritten (any ``G``: 3, 5, 6, 7
+    too)."""
     if block_q % SUB_K or block_k % SUB_K:
         raise ValueError(
             f"block sizes ({block_q}, {block_k}) must be multiples of "
@@ -126,7 +164,8 @@ def tiles_of(block_q: int, block_k: int, G: int) -> Tiles:
         )
     most = max(SUB_K, _MATMUL_ROWS // G)
     sub_q = next(
-        s for s in (512, 256, 128) if s <= most and block_q % s == 0
+        s for s in (2048, 1024, 512, 256, 128)
+        if s <= most and block_q % s == 0
     )
     return Tiles(block_q, sub_q, block_k, min(_UNROLL, block_k // SUB_K))
 
@@ -135,6 +174,8 @@ def _vmem_bytes(tiles: Tiles, G: int, d: int, itemsize: int) -> int:
     """What a grid point holds, roughly: the chip's compiler has the last
     word (tests/ops/test_chip_compile.py at the cells' shapes). ``d`` is
     the wider of the two head widths where keys and values differ."""
+    if d > _LANES:
+        d = -(-d // _LANES) * _LANES    # a row of 192 lies in 256 lanes
     rows, sub_rows = G * tiles.block_q, G * tiles.sub_q
     blocks = 2 * (2 * rows * d + 2 * tiles.block_k * d) * itemsize
     scratch = rows * (2 * _LANES + d) * 4
@@ -143,13 +184,28 @@ def _vmem_bytes(tiles: Tiles, G: int, d: int, itemsize: int) -> int:
     return blocks + scratch + temps
 
 
+def _one_head_a_point(G: int) -> bool:
+    """Whether a group's 512 rows, the most any group had until PR 59, are
+    less than one matmul's: a group of one. Its grid point holds one head
+    where another's holds 4, 8 or 16, so it is offered longer blocks, and
+    the VMEM they take is asked for (module docstring)."""
+    return G * 512 < _MATMUL_ROWS
+
+
 def candidate_tiles(T_pad: int, S_pad: int, G: int) -> list[Tiles]:
     """The tiles that divide the padded lengths, largest first; the last
-    is always 128 x 128."""
+    is always 128 x 128. Blocks of at most 512 rows, of queries and of
+    keys, but for a group of one: one matmul's rows of queries (a second
+    q sub-block would be written out in the body: the module docstring
+    has what that costs a start) and up to 2,048 of keys."""
+    q_sizes = k_sizes = (512, 256, 128)
+    if _one_head_a_point(G):
+        q_sizes = (_MATMUL_ROWS // G,) + q_sizes
+        k_sizes = (2048, 1024) + k_sizes
     return [
         tiles_of(block_q, block_k, G)
-        for block_q in (512, 256, 128) if T_pad % block_q == 0
-        for block_k in (512, 256, 128) if S_pad % block_k == 0
+        for block_q in q_sizes if T_pad % block_q == 0
+        for block_k in k_sizes if S_pad % block_k == 0
     ]
 
 
@@ -159,13 +215,21 @@ def choose_tiles(
     """The largest tiles of a short list that divide the padded lengths
     and fit VMEM; 128 x 128 where nothing larger does."""
     candidates = candidate_tiles(T_pad, S_pad, G)
+    budget = _VMEM_ASKED if _one_head_a_point(G) else _VMEM_BUDGET
     return next(
         (
             tiles for tiles in candidates
-            if _vmem_bytes(tiles, G, d, itemsize) <= _VMEM_BUDGET
+            if _vmem_bytes(tiles, G, d, itemsize) <= budget
         ),
         candidates[-1],
     )
+
+
+def grid_points(tiles: Tiles, T_pad: int, S_pad: int, groups: int) -> int:
+    """The points of one call's grid over ``groups`` key/value heads
+    (batch rows times heads): what the runner's log and
+    ``hack/flash_bench.py`` say of a call beside its tile."""
+    return groups * (T_pad // tiles.block_q) * (S_pad // tiles.block_k)
 
 
 def _across(x, d: int):
@@ -329,6 +393,10 @@ def flash_call(
     if T_pad % block_q or S_pad % block_k or block_q % sub_q:
         raise ValueError(f"{tiles} does not divide {T_pad} x {S_pad}")
     n_qs, n_kb = block_q // sub_q, S_pad // block_k
+    # a tile past the budget asks for its VMEM by the same arithmetic,
+    # with the room the budget leaves in the default 16 MiB
+    need = _vmem_bytes(tiles, G, max(d, dv), qt.dtype.itemsize)
+    vmem = {"vmem_limit_bytes": need * 8 // 5} if need > _VMEM_BUDGET else {}
 
     def q_block(b, h, qb, kb, off_ref):
         return (b, h, qb, 0)     # heads h*G .. h*G+G-1: one GQA group
@@ -372,6 +440,7 @@ def flash_call(
             dimension_semantics=(
                 "parallel", "parallel", "parallel", "arbitrary"
             ),
+            **vmem,
         ),
         # a call with a band under a name of its own in the compiled
         # program and the profiler's trace; without one the call is

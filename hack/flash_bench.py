@@ -7,11 +7,15 @@ the shapes the benchmark's cells run and at a continuation.
 
 Each line: ms a call (the ``pallas_call`` alone on head-major operands,
 ``reps`` calls chained inside one program so the host is out of it), the
-call's share of ``perfbench/roofline.py``'s floor, and the count of output
-elements that differ from the old kernel's (expected 0). ``--sweep`` times
-every pair of block sizes and every inner shape (rows of a matmul,
-sub-blocks unrolled: two constants of the kernel's module, which the sweep
-sets while it traces), not only what the shapes choose. One JSON line a
+call's share of its floor (``perfbench/roofline.py``'s, or
+``perfbench/roofline_mla.py``'s where keys and values differ in width) as
+counted and as issued (a key of 192 is two passes of the 128-deep matrix
+unit: 256), the grid's points, and the count of output elements that
+differ from the old kernel's (expected 0). ``--sweep`` times every pair of
+block sizes and every inner shape (rows of a matmul, sub-blocks unrolled:
+two constants of the kernel's module, which the sweep sets while it
+traces), not only what the shapes choose; at a group of one the rows of a
+matmul are swept at every pair of the larger blocks too. One JSON line a
 measurement on stdout and in ``chiprun_out/flash_bench.jsonl``.
 Nothing a cell runs imports this file.
 """
@@ -34,22 +38,31 @@ import jax.numpy as jnp  # noqa: E402
 from jax import lax  # noqa: E402
 
 from gpustack_tpu.ops import flash_attention as fa  # noqa: E402
-from perfbench import roofline  # noqa: E402
+from perfbench import roofline, roofline_mla  # noqa: E402
 
-# (name, T, S, offset, q heads, kv heads, head width)
+# (name, T, S, offset, q heads, kv heads, key width, value width)
 SHAPES = [
-    ("8b-2048", 2048, 2048, 0, 32, 8, 128),
-    ("8b-1024", 1024, 1024, 0, 32, 8, 128),
-    ("moe-2048", 2048, 2048, 0, 32, 4, 128),
-    ("moe-1024", 1024, 1024, 0, 32, 4, 128),
-    ("8b-chunk", 512, 2048, 1536, 32, 8, 128),
-    ("moe-chunk", 512, 2048, 1536, 32, 4, 128),
-    ("g7-2048", 2048, 2048, 0, 28, 4, 128),     # Qwen2.5-7B: seven a group
+    ("8b-2048", 2048, 2048, 0, 32, 8, 128, 128),
+    ("8b-1024", 1024, 1024, 0, 32, 8, 128, 128),
+    ("moe-2048", 2048, 2048, 0, 32, 4, 128, 128),
+    ("moe-1024", 1024, 1024, 0, 32, 4, 128, 128),
+    ("8b-chunk", 512, 2048, 1536, 32, 8, 128, 128),
+    ("moe-chunk", 512, 2048, 1536, 32, 4, 128, 128),
+    ("g7-2048", 2048, 2048, 0, 28, 4, 128, 128),    # Qwen2.5-7B: seven a group
+    # a group of one: A.X-K1's decompressed latent (keys 192, values 128)
+    # at the long-document cell's two buckets, Olmo-Hybrid's stored heads
+    ("axk1-8192", 8192, 8192, 0, 64, 64, 192, 128),
+    ("axk1-4096", 4096, 4096, 0, 64, 64, 192, 128),
+    ("olmo-1024", 1024, 1024, 0, 32, 32, 128, 128),
 ]
-SWEEP_Q = (128, 256, 512)
-SWEEP_K = (128, 256, 512, 1024)
+SWEEP_Q = (128, 256, 512, 1024)
+SWEEP_K = (128, 256, 512, 1024, 2048)
 SWEEP_ROWS = (512, 1024, 2048)
 SWEEP_UNROLL = (1, 2, 4)
+# where one head's rows are the whole of a matmul, its rows are swept at
+# every pair of these too, not only at the chosen blocks
+SWEEP_ONE_Q = (512, 1024, 2048)
+SWEEP_ONE_K = (512, 1024, 2048)
 
 
 def old_flash_call():
@@ -61,16 +74,30 @@ def old_flash_call():
     return mod.old_flash_call
 
 
-def floor_seconds(T, S, off, Hq, Hkv, d, peaks) -> float:
+def floor_seconds(T, S, off, Hq, Hkv, d, dv, peaks) -> float:
     """``flash_prefill_call``'s floor; for a continuation the rows of the
     triangle below the offset are taken off its operations, and q and o
-    count T rows against k's and v's S."""
+    count T rows against k's and v's S. Keys and values of different
+    widths (from scratch, a key/value head a query head):
+    ``roofline_mla.mla_prefill_call``'s."""
+    if d != dv:
+        call = roofline_mla.mla_prefill_call(T, Hq, d, dv)
+        return roofline.least_seconds(
+            call["flops"], call["bytes"], peaks
+        )["seconds"]
     whole = roofline.flash_prefill_call(off + T, Hq, Hkv, d)
     below = roofline.flash_prefill_call(off, Hq, Hkv, d)
     bytes_ = 2.0 * d * (2 * T * Hq + 2 * S * Hkv)
     return roofline.least_seconds(
         whole["flops"] - below["flops"], bytes_, peaks
     )["seconds"]
+
+
+def issued_over_counted(d: int, dv: int) -> float:
+    """The matrix unit is 128 deep: a contraction over 192 is two passes,
+    so QK^T issues 256 where the floor counts 192 (PV contracts over the
+    128 keys of a sub-block whatever ``dv``)."""
+    return (-(-d // 128) * 128 + dv) / (d + dv)
 
 
 def timed(call, q, k, v, off, reps: int):
@@ -121,29 +148,46 @@ def main() -> int:
         with out_path.open("a") as f:
             f.write(line + "\n")
 
-    for name, T, S, off, Hq, Hkv, d in SHAPES:
+    for name, T, S, off, Hq, Hkv, d, dv in SHAPES:
         if want and name not in want:
             continue
         G = Hq // Hkv
         ks = jax.random.split(jax.random.key(T + S + Hkv), 3)
         q = jax.random.normal(ks[0], (1, Hq, T, d), jnp.float32)
         k = jax.random.normal(ks[1], (1, Hkv, S, d), jnp.float32)
-        v = jax.random.normal(ks[2], (1, Hkv, S, d), jnp.float32)
+        v = jax.random.normal(ks[2], (1, Hkv, S, dv), jnp.float32)
         q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
         off_arr = jnp.full((1,), off, jnp.int32)
-        floor = floor_seconds(T, S, off, Hq, Hkv, d, peaks)
+        floor = floor_seconds(T, S, off, Hq, Hkv, d, dv, peaks)
+        issued = issued_over_counted(d, dv)
         kw = dict(scale=d ** -0.5, seq_k=S, interpret=args.rehearse)
+        # a chained call takes its own output as its next q: narrower than
+        # a key where the widths differ, so it is laid over q's first
+        # columns (one more fused pass over q a call, 1 % of its time)
+        widen = (
+            lambda o, q0: q0.at[..., :dv].set(o[..., :dv])
+        ) if d != dv else (lambda o, q0: o)
 
         def report(which, tiles, call, old_out=None):
-            rec = {"shape": name, "kernel": which, "tiles": tiles}
+            # the old grid has a point a query head, the new a point a group
+            heads = Hq if which == "old" else Hkv
+            rec = {"shape": name, "kernel": which, "tiles": tiles,
+                   "grid_points": fa.grid_points(
+                       fa.Tiles(*tiles), T, S, heads
+                   )}
             try:
-                sec, out = timed(call, q, k, v, off_arr, args.reps)
+                sec, out = timed(
+                    lambda x, k, v, o: widen(call(x, k, v, o), x),
+                    q, k, v, off_arr, args.reps,
+                )
+                out = out[..., :dv]
             except Exception as e:   # a tile the chip's VMEM refuses
                 rec["refused"] = str(e).splitlines()[0][:160]
                 say(rec)
                 return None
             rec["ms"] = sec * 1e3
             rec["roofline_pct"] = 100.0 * floor / sec
+            rec["issued_pct"] = 100.0 * floor * issued / sec
             if old_out is not None:
                 rec["differing"] = int(jnp.sum(
                     out.astype(jnp.float32) != old_out.astype(jnp.float32)
@@ -151,11 +195,15 @@ def main() -> int:
             say(rec)
             return out
 
+        # the old kernel has one width: it takes the values padded with
+        # zero columns to the keys' (a column of a product stands alone)
         old_out = report(
             "old", [128, 128, 128, 1],
-            lambda q, k, v, o: old_call(q, k, v, o, **kw),
+            lambda q, k, v, o: old_call(
+                q, k, jnp.pad(v, ((0, 0),) * 3 + ((0, d - dv),)), o, **kw
+            ),
         )
-        chosen = fa.choose_tiles(T, S, G, d, q.dtype.itemsize)
+        chosen = fa.choose_tiles(T, S, G, max(d, dv), q.dtype.itemsize)
         inner = (fa._MATMUL_ROWS, fa._UNROLL)
         # (block_q, block_k, rows of a matmul, sub-blocks unrolled)
         todo = [(chosen.block_q, chosen.block_k, *inner)]
@@ -169,6 +217,11 @@ def main() -> int:
             ] + [
                 (chosen.block_q, chosen.block_k, rows, unroll)
                 for rows in SWEEP_ROWS for unroll in SWEEP_UNROLL
+            ] + [
+                (bq, bk, rows, fa._UNROLL)
+                for bq in SWEEP_ONE_Q if G == 1 and T % bq == 0
+                for bk in SWEEP_ONE_K if S % bk == 0
+                for rows in SWEEP_ROWS if rows <= bq
             ]
             todo = list(dict.fromkeys(todo))
         for bq, bk, rows, unroll in todo:

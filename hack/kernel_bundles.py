@@ -9,7 +9,16 @@ count.
     python3 hack/kernel_bundles.py ssm            # ops/ssm.py, Nemotron's cell
     python3 hack/kernel_bundles.py delta      # ops/delta_rule.py, Olmo-Hybrid's
     python3 hack/kernel_bundles.py latent     # ops/mla_attention.py's row write
+    python3 hack/kernel_bundles.py flash      # ops/flash_attention.py, A.X-K1's
+    python3 hack/kernel_bundles.py flash --tile 1024,1024,512
     python3 hack/kernel_bundles.py ssm --keep <an empty directory>
+
+``flash`` is the prefill kernel at the long-document cell's call (64
+heads of one, 8,192 rows, keys 192 and values 128) with the tile its
+shapes choose, or with ``--tile block_q,block_k,rows`` (``rows``: of one
+matmul, the module's ``_MATMUL_ROWS``). Its body holds every sweep of a
+grid point, masked and clear, once a q sub-block: the counts are of the
+body, not of the path a point takes through it.
 
 The dump aborts the process once the kernel's files are written (a
 report template the wheel lacks), so the compile is a child process and
@@ -75,16 +84,43 @@ def compile_latent(on):
     ).compile()
 
 
+def compile_flash(on, tile=None):
+    import contextlib
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+
+    from gpustack_tpu.ops import flash_attention as fa
+
+    T, H, d, dv = 8192, 64, 192, 128
+    bf16 = jnp.bfloat16
+    other = contextlib.nullcontext()
+    if tile:
+        block_q, block_k, rows = tile
+        with mock.patch.object(fa, "_MATMUL_ROWS", rows):
+            tiles = fa.tiles_of(block_q, block_k, 1)
+        other = mock.patch.object(fa, "choose_tiles", lambda *shapes: tiles)
+    with other:
+        jax.jit(
+            lambda q, k, v: fa.flash_attention_prefill(q, k, v, d ** -0.5)
+        ).lower(
+            on((1, T, H, d), bf16), on((1, T, H, d), bf16),
+            on((1, T, H, dv), bf16),
+        ).compile()
+
+
 # the script's name for a kernel -> (its pallas_call's name, what lowers
 # and compiles it at a cell's shapes given ``on(shape, dtype)``)
 KERNELS = {
     "ssm": ("ssm_state_update", compile_ssm),
     "delta": ("delta_state_update", compile_delta),
     "latent": ("mla_write_latent_rows", compile_latent),
+    "flash": ("flash_attention_prefill", compile_flash),
 }
 
 
-def child(kernel: str) -> int:
+def child(kernel: str, tile=None) -> int:
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     sys.path.insert(0, ROOT)
     import jax
@@ -100,7 +136,8 @@ def child(kernel: str) -> int:
     def on(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    KERNELS[kernel][1](on)
+    compile_at = KERNELS[kernel][1]
+    compile_at(on, tile) if tile else compile_at(on)
     return 0
 
 
@@ -153,10 +190,16 @@ def main() -> int:
     ap.add_argument(
         "--keep", help="the dump's directory, empty (else a temporary one)"
     )
+    ap.add_argument(
+        "--tile", help="flash only: block_q,block_k,rows of a matmul",
+        type=lambda s: tuple(int(n) for n in s.split(",")),
+    )
     ap.add_argument("--child", action="store_true", help=argparse.SUPPRESS)
     args = ap.parse_args()
+    if args.tile and args.kernel != "flash":
+        ap.error("--tile goes with flash")
     if args.child:
-        return child(args.kernel)
+        return child(args.kernel, args.tile)
     call = KERNELS[args.kernel][0]
     with tempfile.TemporaryDirectory() as tmp:
         dump = args.keep or tmp
@@ -169,7 +212,10 @@ def main() -> int:
         )
         done = subprocess.run(
             [sys.executable, os.path.abspath(__file__), args.kernel,
-             "--child"],
+             "--child"] + (
+                ["--tile", ",".join(map(str, args.tile))] if args.tile
+                else []
+            ),
             env=env, capture_output=True, text=True,
         )
         # the child aborts after the dump (see the docstring): what

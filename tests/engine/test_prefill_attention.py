@@ -4,12 +4,22 @@
 
 import dataclasses
 import logging
+import os
 import types
 
 import pytest
 
-from gpustack_tpu.engine.runner import ModelRunner, prefill_attention
-from gpustack_tpu.models.config import get_config
+from gpustack_tpu.engine.runner import (
+    ModelRunner,
+    flash_tile,
+    prefill_attention,
+)
+from gpustack_tpu.models.config import get_config, load_hf_config
+from gpustack_tpu.ops.flash_attention import Tiles, choose_tiles, grid_points
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)
+)))
 
 _PLAIN = get_config("tiny")
 MODELS = {
@@ -51,11 +61,15 @@ def test_a_position_sharded_cache_is_read_by_the_ring_alone(platform, bucket):
 
 def test_the_log_names_the_kernel_that_runs_for_a_windowed_model(caplog):
     """``attn_impl_for`` on a TPU mesh: flash for the plain model, XLA
-    for the windowed one, and the once-per-bucket line says so."""
+    for the windowed one, and the once-per-bucket line says so; for the
+    kernel it also names the tile its shapes choose and the points of a
+    call's grid (``flash_tile``: what says that a rule of
+    ``choose_tiles`` engaged)."""
     tpu_mesh = types.SimpleNamespace(
         devices=types.SimpleNamespace(
             flat=[types.SimpleNamespace(platform="tpu")]
-        )
+        ),
+        shape={"tp": 1},
     )
 
     def runner(cfg):
@@ -70,7 +84,36 @@ def test_the_log_names_the_kernel_that_runs_for_a_windowed_model(caplog):
         assert ModelRunner.attn_impl_for(windowed, 2048) == "xla"
         assert ModelRunner.attn_impl_for(windowed, 2048) == "xla"
     lines = [r.getMessage() for r in caplog.records]
+    tiles, points = flash_tile(_PLAIN, 2048)
+    group = _PLAIN.num_heads // _PLAIN.num_kv_heads
+    assert tiles == choose_tiles(2048, 2048, group, _PLAIN.head_dim, 2)
+    assert points == grid_points(tiles, 2048, 2048, _PLAIN.num_kv_heads)
     assert lines == [
-        "prefill bucket 2048: attention impl flash",
+        f"prefill bucket 2048: attention impl flash, {tiles}, "
+        f"{points} grid points a call",
         "prefill bucket 2048: attention impl xla",
     ]
+
+
+@pytest.mark.parametrize("directory,bucket,tp,tiles,points", [
+    # a latent's decompressed heads are a group of one each: 1,024 query
+    # rows against 2,048 keys, an eighth of the 16,384 points 512 x 512 gave
+    ("ax-k1-int8-ep16-l12", 8192, 1, Tiles(1024, 1024, 2048, 4), 2048),
+    ("ax-k1-int8-ep16-l12", 4096, 1, Tiles(1024, 1024, 2048, 4), 512),
+    ("ax-k1-int8-ep16-l12", 8192, 4, Tiles(1024, 1024, 2048, 4), 512),
+    # 30 heads of one stored as 32
+    ("olmo-hybrid-7b-int8", 1024, 1, Tiles(1024, 1024, 1024, 4), 32),
+    # groups of 4, 8 and 16: as before PR 59
+    ("qwen3-8b-int8", 2048, 1, Tiles(512, 256, 512, 4), 128),
+    ("qwen3-30b-a3b-int8-l12", 2048, 1, Tiles(256, 128, 512, 4), 128),
+    ("granite-4.0-h-micro-int8", 1024, 1, Tiles(512, 256, 512, 4), 32),
+])
+def test_the_tile_the_log_names_is_the_one_a_cell_s_call_takes(
+    directory, bucket, tp, tiles, points
+):
+    """``flash_tile`` from a benchmark configuration's own file: the tile
+    and the grid of the call its prefill program makes."""
+    cfg = load_hf_config(
+        os.path.join(ROOT, "perfbench", "configs", directory)
+    )
+    assert flash_tile(cfg, bucket, tp) == (tiles, points)

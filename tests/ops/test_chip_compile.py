@@ -610,14 +610,27 @@ def _axk1_shapes(one_chip, cfg):
     )
 
 
-def test_flash_prefill_takes_keys_of_192_and_values_of_128(one_chip):
-    """The MLA prefill's call: 64 heads, keys 192 and values 128 wide,
-    nothing padded; the result is as wide as a value, which is where the
-    benchmark's reader takes the value width from
-    (perfbench/layer_metrics/kernel.mla_prefill_roofline.py)."""
-    from gpustack_tpu.ops.flash_attention import flash_attention_prefill
+@pytest.mark.parametrize("T", [8192, 4096])
+def test_flash_prefill_takes_keys_of_192_and_values_of_128(one_chip, T):
+    """The MLA prefill's call at the long-document cell's two buckets: 64
+    heads, keys 192 and values 128 wide, nothing padded; the result is as
+    wide as a value, which is where the benchmark's reader takes the
+    value width from
+    (perfbench/layer_metrics/kernel.mla_prefill_roofline.py). A group of
+    one takes blocks of 1,024 query rows, one matmul of them, against
+    2,048 keys (PR 59: 2,048 grid points at 8,192 where blocks of 512
+    gave 16,384) and asks for the VMEM they need; the chip's compiler,
+    which has the last word on VMEM, takes the tile."""
+    from gpustack_tpu.ops.flash_attention import (
+        Tiles,
+        choose_tiles,
+        flash_attention_prefill,
+        grid_points,
+    )
 
-    T = 8192
+    tiles = choose_tiles(T, T, 1, 192, 2)
+    assert tiles == Tiles(1024, 1024, 2048, 4)
+    assert grid_points(tiles, T, T, 64) == 64 * (T // 1024) * (T // 2048)
     q = jax.ShapeDtypeStruct((1, T, 64, 192), jnp.bfloat16, sharding=one_chip)
     v = jax.ShapeDtypeStruct((1, T, 64, 128), jnp.bfloat16, sharding=one_chip)
     compiled = jax.jit(
@@ -1459,6 +1472,9 @@ def test_the_other_models_programs_lower_to_the_text_they_had(
     into ``forward``, Command A+'s before the three copies of a GQA
     layer over a cache became one, those five unchanged when the mixer
     by kind and the stored kv heads went in (PR 53), Olmo-Hybrid's with
-    that PR (``lowered_programs.py`` says what is hashed and how to
-    take the hashes again on purpose)."""
+    that PR, A.X-K1's prefill again with PR 59 (a group of one's flash
+    call asks for its VMEM, which is in the call's text; the tile itself
+    is in the kernel's body, which is not hashed, so Olmo-Hybrid's new
+    tile moved nothing) (``lowered_programs.py`` says what is hashed and
+    how to take the hashes again on purpose)."""
     assert lowered_hashes[program] == _lowered_names()[program]

@@ -138,7 +138,10 @@ def _attend_with(call, q, k, v, off):
 # (T, S, offset, Hq, Hkv, d, dtype): from scratch, continuations whose
 # offset is a multiple of no block, ragged T and S, G of 1, 4 and 8 and
 # the groups that are no power of two (3, 5, 6 and Qwen2.5-7B's 7), head
-# widths of 64, 128 and 192 (MLA's qk width), both input precisions
+# widths of 64, 128 and 192 (MLA's qk width), both input precisions; a
+# group of one long enough for the blocks of 1,024 queries and of 1,024
+# and 2,048 keys it alone is offered (PR 59), from scratch and at an odd
+# offset with T and S ragged
 _EQUAL_CASES = [
     (512, 512, 0, 4, 1, 64, jnp.float32),       # from scratch, G 4
     (1024, 1024, 0, 2, 2, 64, jnp.bfloat16),    # G 1: MHA
@@ -153,6 +156,8 @@ _EQUAL_CASES = [
     (300, 700, 393, 6, 1, 64, jnp.float32),     # G 6, ragged, odd offset
     (512, 512, 0, 7, 1, 128, jnp.bfloat16),     # G 7: Qwen2.5-7B's 28 / 4
     (256, 1024, 700, 14, 2, 64, jnp.bfloat16),  # G 7, two groups, mid-cache
+    (2048, 2048, 0, 2, 2, 64, jnp.float32),     # G 1, every larger block
+    (1000, 4000, 2987, 1, 1, 64, jnp.float32),  # G 1, mid-cache, ragged
 ]
 
 
@@ -211,6 +216,11 @@ def test_every_tiling_equals_the_old_kernel_element_for_element(
     (2048, 2048, 7, 128, 2, Tiles(256, 128, 512, 4)),  # Qwen2.5-7B, 2048
     (1024, 1024, 3, 128, 2, Tiles(512, 256, 512, 4)),  # 24 / 8 heads
     (1024, 1024, 5, 128, 2, Tiles(512, 128, 512, 4)),  # 40 / 8 heads
+    # a group of one (PR 59's sweep on the chip): A.X-K1's decompressed
+    # latent at the long-document cell's two buckets, Olmo-Hybrid's heads
+    (8192, 8192, 1, 192, 2, Tiles(1024, 1024, 2048, 4)),
+    (4096, 4096, 1, 192, 2, Tiles(1024, 1024, 2048, 4)),
+    (1024, 1024, 1, 128, 2, Tiles(1024, 1024, 1024, 4)),
 ])
 def test_tiles_follow_the_shapes(T_pad, S_pad, G, d, itemsize, want):
     got = choose_tiles(T_pad, S_pad, G, d, itemsize)
@@ -224,15 +234,16 @@ def test_the_rows_of_a_matmul_divide_the_block_for_any_group(G):
     """The kernel walks ``block_q // sub_q`` sub-blocks of a q-block: a
     ``sub_q`` that did not divide (146 rows at G 7, 341 at G 3) left the
     block's last rows unwritten. Whatever the shapes choose divides."""
-    for block_q in (128, 256, 384, 512, 1024):
-        for block_k in (128, 512, 1024):
+    for block_q in (128, 256, 384, 512, 1024, 2048):
+        for block_k in (128, 512, 1024, 2048):
             tiles = tiles_of(block_q, block_k, G)
             assert (tiles.block_q, tiles.block_k) == (block_q, block_k)
             assert block_q % tiles.sub_q == 0 and tiles.sub_q % SUB_K == 0
             assert 1 <= tiles.unroll <= block_k // SUB_K
     for T_pad, S_pad, d, itemsize in (
         (2048, 2048, 128, 2), (1024, 1024, 64, 2), (1024, 1024, 256, 2),
-        (512, 2048, 192, 4), (384, 640, 128, 2),
+        (512, 2048, 192, 4), (384, 640, 128, 2), (8192, 8192, 192, 2),
+        (4096, 4096, 128, 2),
     ):
         tiles = choose_tiles(T_pad, S_pad, G, d, itemsize)
         assert T_pad % tiles.block_q == 0 and S_pad % tiles.block_k == 0
