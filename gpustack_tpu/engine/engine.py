@@ -940,8 +940,28 @@ class LLMEngine:
                 # full layers' rows
                 "window_bytes": self._window_bytes,
             },
+            # the recurrent state's share of what the slots hold (a
+            # slot's state over its state, rows and window rows), per
+            # cent; None for a model without such a state
+            "state_share_pct": (
+                round(100.0 * self._state_bytes / (
+                    self._state_bytes + self._kv_cache_bytes
+                    + self._window_bytes
+                ), 2) if self._state_bytes else None
+            ),
+            # one chip's share of the routed experts (cfg.experts_held):
+            # how many this replica holds, of how many the router scores,
+            # from which id on; None where every expert is held
+            "experts_held": (
+                {
+                    "held": self.cfg.num_held_experts,
+                    "of": self.cfg.num_experts,
+                    "first": self.cfg.first_held_expert,
+                } if self.cfg.experts_held else None
+            ),
             # how a layer that keeps a recurrent state (cfg.state_mixer:
-            # "ssm" Mamba-2, "delta" gated delta rule) runs its scan
+            # "ssm" Mamba-2, "delta" gated delta rule, "kda" the same
+            # rule with a decay a key channel) runs its scan
             # over a prompt and moves its state in a decode step
             # (runner.ssm_update: "kernel" the stacked state in place,
             # live slots only); None for a model without such layers

@@ -292,7 +292,8 @@ class ModelRunner:
             self.ssm_update = ssm_update_impl(1, platform, self.mesh)
             logger.info(
                 "%s layers: scan %s, update %s",
-                {"ssm": "state-space", "delta": "delta-rule"}[
+                {"ssm": "state-space", "delta": "delta-rule",
+                 "kda": "delta-rule (a decay a channel)"}[
                     cfg.state_mixer
                 ], self.ssm_scan, self.ssm_update,
             )

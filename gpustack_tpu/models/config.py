@@ -35,10 +35,11 @@ class ModelConfig:
     stacks, each layer attention + MLP; the Nemotron-H hybrid
     (``layer_kinds``), each layer ONE mixer: a Mamba-2 state-space
     mixer, a mixture of two-matrix experts, or GQA; and the hybrids with
-    ``layer_types`` (Olmo-Hybrid, Granite 4.0-H), each layer a mixer by
-    kind (a gated-delta-rule linear-attention mixer, a Mamba-2 mixer or
-    full attention) and then an MLP. The Mamba-2 mixer is one function
-    for both (``models/hybrid.py mamba_mixer``).
+    ``layer_types`` (Olmo-Hybrid, Granite 4.0-H, Solar-Open2), each
+    layer a mixer by kind (a gated-delta-rule linear-attention mixer,
+    its decay one number a head or one a key channel; a Mamba-2 mixer;
+    or full attention) and then an MLP or routed experts. The Mamba-2
+    mixer is one function for both (``models/hybrid.py mamba_mixer``).
 
     Two kinds of layer keep a state a slot beside the rows a position
     (a Mamba-2 mixer's, a delta-rule mixer's); :attr:`state_shapes` is
@@ -191,8 +192,9 @@ class ModelConfig:
     # gated-delta-rule mixer (ops/delta_rule.py: a matrix state a head a
     # slot, the ``linear_*`` keys as the hub file has them), "mamba" a
     # Mamba-2 mixer (the ``mamba_*`` fields above), "full_attention"
-    # causal GQA. Every layer has an MLP after its mixer. None: every
-    # other family. num_layers == len of it.
+    # causal GQA. Every layer has an MLP after its mixer, or routed
+    # experts where ``num_experts`` says so. None: every other family.
+    # num_layers == len of it.
     layer_types: Optional[Tuple[str, ...]] = None
     linear_num_key_heads: int = 0
     linear_num_value_heads: int = 0
@@ -202,6 +204,21 @@ class ModelConfig:
     # beta = 2 sigmoid(.) and not sigmoid(.): along a key the state's
     # transition has an eigenvalue in (-1, 1) (arXiv:2411.12537)
     linear_allow_neg_eigval: bool = False
+    # What KDA (Kimi Linear, arXiv:2510.26692: Solar-Open2's linear
+    # layers) changes of the delta-rule mixer, each a field:
+    # the decay one number a head AND key channel (``g [B, T, H, Dk]``,
+    # ``dt_bias [H * Dk]``), not one a head;
+    linear_decay_a_channel: bool = False
+    # the decay's and the output gate's projections through a
+    # bottleneck of this rank (``Wf_a [D, r] Wf_b [r, H * Dk]``, ``Wg_a
+    # [D, r] Wg_b [r, H * Dv]``); 0: one full matrix each (``Wa [D,
+    # H]``, ``Wg [D, H * Dv]``);
+    linear_low_rank: int = 0
+    # the output gate under "sigmoid", not "silu"
+    linear_gate_act: str = "silu"
+    # full attention's output times ``sigmoid(h W_gate)``, elementwise
+    # over its ``q_dim`` channels, before ``Wo`` (arXiv:2505.06708)
+    attn_output_gate: bool = False
     # q and k normalised over the whole projection before the heads are
     # split (one gain of q_dim / kv_dim), not a head at a time (qk_norm)
     qk_norm_whole: bool = False
@@ -347,10 +364,14 @@ class ModelConfig:
     def state_mixer(self) -> Optional[str]:
         """The kind of mixer whose state :attr:`state_shapes` describes,
         as the flight records and ``/metrics`` label it: ``"ssm"``
-        (Mamba-2), ``"delta"`` (gated delta rule), None without one."""
+        (Mamba-2), ``"delta"`` (gated delta rule, a decay a head),
+        ``"kda"`` (the same rule, a decay a key channel), None without
+        one."""
         if self.num_mamba_layers:
             return "ssm"
-        return "delta" if self.num_linear_layers else None
+        if self.num_linear_layers:
+            return "kda" if self.linear_decay_a_channel else "delta"
+        return None
 
     @property
     def kv_row_shapes(self) -> Tuple[Tuple[int, int], Tuple[int, int]]:
@@ -421,7 +442,8 @@ class ModelConfig:
         if self.state_mixer:
             return BesideRows(
                 {"ssm": "has state-space layers",
-                 "delta": "has linear-attention layers"}[self.state_mixer],
+                 "delta": "has linear-attention layers",
+                 "kda": "has linear-attention layers"}[self.state_mixer],
                 "a recurrent state",
                 "the rows carry no recurrent state to go on from",
             )
@@ -482,7 +504,12 @@ class ModelConfig:
                 "linear_attention", "mamba", "full_attention"
             }, self.layer_types
             assert self.layer_kinds is None and self.layer_sliding is None
-            assert not (self.is_moe or self.is_mla)
+            assert not self.is_mla, "latent attention beside a mixer by kind"
+            # experts stand in every layer or in none (no dense prefix),
+            # gated three-matrix ones without biases
+            assert not (self.is_moe and (
+                self.first_k_dense or self.moe_bias or self.moe_act != "silu"
+            ))
             if "mamba" in self.layer_types:
                 assert self.mamba_num_heads % self.mamba_n_groups == 0
                 assert self.mamba_inner and self.ssm_state_size
@@ -491,9 +518,27 @@ class ModelConfig:
                     self.linear_num_key_heads == self.linear_num_value_heads
                 ), "a delta-rule mixer is served with a key head a value head"
                 assert self.linear_key_head_dim and self.linear_value_head_dim
+                assert self.linear_gate_act in ("silu", "sigmoid")
+        else:
+            assert not self.attn_output_gate, (
+                "an output gate on attention is read in a stack with "
+                "layer_types only"
+            )
         return self
 
     # ---- memory accounting (used by scheduler + engine sizing) ----
+    def _gated_experts_params(self) -> int:
+        """One layer's router, held three-matrix experts and shared
+        expert."""
+        d = self.hidden_size
+        n = d * self.num_experts + 3 * d * (
+            self.num_held_experts * self.moe_intermediate_size
+            + self.shared_expert_intermediate_size
+        )
+        if self.moe_scoring == "sigmoid" and self.router_correction_bias:
+            n += self.num_experts     # e_score_correction_bias
+        return n
+
     def param_count(self) -> int:
         """Exact parameter count of what this replica holds: embedding,
         head, every layer's matrices, norms, biases and router, with the
@@ -529,12 +574,20 @@ class ModelConfig:
             keys = self.linear_num_key_heads * self.linear_key_head_dim
             values = self.linear_num_value_heads * self.linear_value_head_dim
             heads = self.linear_num_value_heads
+            rank = self.linear_low_rank
+            decays = keys if self.linear_decay_a_channel else heads
             linear = (
-                2 * d * keys + 2 * d * values    # wq, wk; wv and the gate
+                2 * d * keys + d * values        # wq, wk; wv
                 + values * d                     # wo
-                + 2 * d * heads + 2 * heads      # wa, wb; A_log, dt_bias
+                + d * heads + heads + decays     # wb; A_log, dt_bias
                 + self.linear_conv_kernel_dim * self.linear_conv_dim
                 + self.linear_value_head_dim     # the output norm's gain
+            )
+            # the decay's and the gate's projections: one matrix each, or
+            # each through the bottleneck
+            linear += (
+                2 * d * rank + rank * (decays + values) if rank
+                else d * decays + d * values
             )
             full = (
                 d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
@@ -542,6 +595,8 @@ class ModelConfig:
             )
             if not self.qk_norm_whole:
                 full -= self.q_dim + self.kv_dim
+            if self.attn_output_gate:
+                full += d * self.q_dim
             inner, conv = self.mamba_inner, self.mamba_conv_dim
             mamba = (
                 d * (inner + conv + self.mamba_num_heads)  # z | xBC | dt
@@ -549,7 +604,10 @@ class ModelConfig:
                 + 3 * self.mamba_num_heads          # A_log, D, dt_bias
                 + inner + inner * d                 # the gated norm, out_proj
             )
-            mlp = 3 * d * self.intermediate_size
+            mlp = (
+                self._gated_experts_params() if self.is_moe
+                else 3 * d * self.intermediate_size
+            )
             return (
                 embed + lm_head + d
                 + self.num_linear_layers * linear
@@ -581,13 +639,7 @@ class ModelConfig:
             if self.qk_norm:
                 attn += 2 * self.head_dim
         if self.is_moe:
-            mlp = d * self.num_experts + self.num_held_experts * (
-                3 * d * self.moe_intermediate_size
-            )
-            if self.shared_expert_intermediate_size:
-                mlp += 3 * d * self.shared_expert_intermediate_size
-            if self.moe_scoring == "sigmoid" and self.router_correction_bias:
-                mlp += self.num_experts     # e_score_correction_bias
+            mlp = self._gated_experts_params()
         else:
             mlp = 3 * d * self.intermediate_size
         norms = (4 if self.post_norms else 1 if self.parallel_block else 2) * d
@@ -668,6 +720,7 @@ def _period(kinds: tuple) -> tuple:
 FAMILIES: Tuple[str, ...] = (
     "Llama", "Mistral", "Mixtral", "Qwen2", "Qwen3", "Gemma", "GptOss",
     "Deepseek", "NemotronH", "Cohere2Moe", "OlmoHybrid", "GraniteMoeHybrid",
+    "SolarOpen2",
     # multimodal wrappers whose text stack is one of the above
     "Llava", "VLForConditionalGeneration",
 )
@@ -973,6 +1026,96 @@ def _granite_hybrid_config(cfg: Dict[str, Any], name: str) -> ModelConfig:
     ).validate()
 
 
+def _solar_open2_config(cfg: Dict[str, Any], name: str) -> ModelConfig:
+    """Solar-Open2 (``model_type: solar_open2``): every layer a mixer and
+    then routed experts under one shared expert (the DeepSeek-V3
+    router's keys: sigmoid scores, a correction bias, the chosen scores
+    normalised). The layers ``gqa_layers`` lists are causal GQA without
+    positional embedding (``use_rope: false``) whose output passes a
+    sigmoid gate (``use_gqa_gate``); every other layer is a KDA
+    linear-attention mixer (``linear_attn_config``; Kimi Linear,
+    arXiv:2510.26692): the gated delta rule with **one decay a head and
+    key channel**, the decay's and the gate's projections through a
+    bottleneck (``kda_use_full_proj: false``), the gate a sigmoid.
+
+    What the file has no key for is assumed, each a field of
+    :class:`ModelConfig` or a line here, and listed with its other
+    reading in ``perfbench/configs/solar-open2-250b-int8-ep8-l12/
+    deployment.json``: the bottleneck's rank is the linear heads'
+    ``head_dim``; the shared expert is ``n_shared_experts *
+    moe_intermediate_size`` wide (``intermediate_size`` belongs to
+    leading dense layers, of which a served file has none); no norm on
+    q and k.
+
+    One chip's share of the experts is ``n_routed_experts`` (how many
+    are held here) beside ``experts_held: {"of", "first"}``, as the
+    Nemotron-H files state it and for its reason."""
+    def refuse(key, want, group=cfg):
+        if group.get(key, want) != want:
+            raise ValueError(
+                f"{key} {group[key]!r}: a solar_open2 stack is served with "
+                f"{want!r} only"
+            )
+
+    refuse("kda_use_full_proj", False)
+    refuse("first_k_dense_replace", 0)
+    refuse("hidden_act", "silu")
+    refuse("attention_bias", False)
+    refuse("use_rope", False)
+    linear = cfg.get("linear_attn_config") or {}
+    heads_l = int(linear["num_heads"])
+    refuse("num_kv_heads", None, linear)
+    n = cfg["num_hidden_layers"]
+    full = sorted(int(i) for i in cfg.get("gqa_layers") or ())
+    if full and not 0 <= full[0] <= full[-1] < n:
+        raise ValueError(
+            f"gqa_layers {full}: num_hidden_layers says {n} layers"
+        )
+    hidden, heads = cfg["hidden_size"], cfg["num_attention_heads"]
+    share = cfg.get("experts_held") or {}
+    held = int(cfg["n_routed_experts"])
+    shared = int(cfg.get("n_shared_experts") or 0)
+    width = int(cfg["moe_intermediate_size"])
+    return ModelConfig(
+        name=name,
+        vocab_size=cfg["vocab_size"],
+        hidden_size=hidden,
+        intermediate_size=cfg.get("intermediate_size", 0),
+        num_layers=n,
+        num_heads=heads,
+        num_kv_heads=cfg.get("num_key_value_heads", heads),
+        head_dim=cfg.get("head_dim") or hidden // heads,
+        rope=False,
+        rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+        tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+        max_position_embeddings=cfg.get("max_position_embeddings", 8192),
+        num_experts=int(share["of"]) if share else held,
+        experts_held=held if share else 0,
+        first_held_expert=int(share.get("first", 0)),
+        num_experts_per_tok=cfg["num_experts_per_tok"],
+        moe_intermediate_size=width,
+        norm_topk_prob=cfg.get("norm_topk_prob", True),
+        n_shared_experts=shared,
+        shared_expert_intermediate_size=shared * width,
+        routed_scaling_factor=float(cfg.get("routed_scaling_factor") or 1.0),
+        moe_scoring="sigmoid",
+        layer_types=tuple(
+            "full_attention" if i in full else "linear_attention"
+            for i in range(n)
+        ),
+        linear_num_key_heads=heads_l,
+        linear_num_value_heads=heads_l,
+        linear_key_head_dim=int(linear["head_dim"]),
+        linear_value_head_dim=int(linear["head_dim"]),
+        linear_conv_kernel_dim=int(linear.get("short_conv_kernel_size") or 4),
+        linear_allow_neg_eigval=bool(cfg.get("kda_allow_neg_eigval")),
+        linear_decay_a_channel=True,
+        linear_low_rank=int(linear["head_dim"]),
+        linear_gate_act="sigmoid",
+        attn_output_gate=bool(cfg.get("use_gqa_gate")),
+    ).validate()
+
+
 def config_from_hf(cfg: Dict[str, Any], name: str = "custom") -> ModelConfig:
     """Build a ModelConfig from an HF ``config.json`` dict of one of
     :data:`FAMILIES` (the reference's selectors introspect the same
@@ -997,6 +1140,8 @@ def config_from_hf(cfg: Dict[str, Any], name: str = "custom") -> ModelConfig:
         or cfg.get("model_type") == "granitemoehybrid"
     ):
         return _granite_hybrid_config(cfg, name)
+    if "SolarOpen2" in arch or cfg.get("model_type") == "solar_open2":
+        return _solar_open2_config(cfg, name)
     hidden = cfg["hidden_size"]
     heads = cfg["num_attention_heads"]
     head_dim = cfg.get("head_dim") or hidden // heads

@@ -1,5 +1,6 @@
 """The gated-delta-rule mixer of a linear-attention layer
-(``cfg.layer_types``, the Olmo hybrid): one function over a layer's
+(``cfg.layer_types``: Olmo-Hybrid's, and KDA, Solar-Open2's, the same
+function with three fields of the configuration set): one function over a layer's
 input, its leaves, the carried cache and the layer's index among its
 kind, beside ``models/hybrid.py mamba_mixer``. ``transformer.forward``
 calls it from ``block`` for a ``"linear_attention"`` layer, bound by
@@ -14,6 +15,15 @@ sqrt(Dk)``, ``k = l2norm(k')``, ``v = v'``; ``beta = sigmoid(h Wb)``
 softplus(h Wa + dt_bias)``, one each a head, float32. The rule itself is
 ``ops/delta_rule.py``'s. Then ``y = rms_norm(o; w_o) * silu(h Wg)`` a
 head (the norm first, then the gate) and ``out = y Wo``.
+
+KDA (Kimi Linear, arXiv:2510.26692) differs in three things, each a
+field of ``ModelConfig``: the decay is one number a head **and key
+channel** (``cfg.linear_decay_a_channel``: ``g [B, T, H, Dk]``,
+``dt_bias [H * Dk]``, ``A_log`` still one a head; every form of
+``ops/delta_rule.py`` takes either shape of ``g``); the decay's and the
+gate's projections go through a bottleneck (``cfg.linear_low_rank``:
+``(h Wf_a) Wf_b`` and ``(h Wg_a) Wg_b`` where the other has ``h Wa`` and
+``h Wg``); and the gate stands under a sigmoid (``cfg.linear_gate_act``).
 
 What a slot keeps (``transformer.KVCache``, the shapes
 ``ModelConfig.state_shapes``'): each such layer's state ``ssm [L_lin, B,
@@ -42,11 +52,14 @@ def init_delta_layers(cfg: ModelConfig, key: jax.Array, dtype) -> Dict[str, Any]
     at its own depth. ``A_log`` the log of a uniform draw in (0, 16] and
     ``dt_bias`` the inverse softplus of 0.001..0.1, as the rule's public
     code initialises them: a state that neither dies in a step nor never
-    forgets."""
+    forgets. ``dt_bias`` is one a decay: a head's, or under
+    ``cfg.linear_decay_a_channel`` a head's key channel's."""
     L, d = cfg.num_linear_layers, cfg.hidden_size
     H = cfg.linear_num_value_heads
     keys_w = cfg.linear_num_key_heads * cfg.linear_key_head_dim
     values_w = H * cfg.linear_value_head_dim
+    decays = keys_w if cfg.linear_decay_a_channel else H
+    rank = cfg.linear_low_rank
     keys = iter(jax.random.split(key, 12))
     f32 = jnp.float32
 
@@ -56,19 +69,29 @@ def init_delta_layers(cfg: ModelConfig, key: jax.Array, dtype) -> Dict[str, Any]
             jax.random.normal(next(keys), shape, f32) * scale
         ).astype(dtype)
 
+    def gates():
+        """The gate's and the decay's projections, each one matrix or
+        through the bottleneck (its four matrices bf16 under int8:
+        ``models/quant.py`` lists none of them)."""
+        if not rank:
+            return {"wg": w(L, d, values_w), "wa": w(L, d, decays)}
+        return {
+            "wg_a": w(L, d, rank), "wg_b": w(L, rank, values_w),
+            "wf_a": w(L, d, rank), "wf_b": w(L, rank, decays),
+        }
+
     return {
         "wq": w(L, d, keys_w),
         "wk": w(L, d, keys_w),
         "wv": w(L, d, values_w),
-        "wg": w(L, d, values_w),
-        "wa": w(L, d, H),
+        **gates(),
         "wb": w(L, d, H),
         "conv_w": w(
             L, cfg.linear_conv_kernel_dim, cfg.linear_conv_dim, scale=0.5
         ).astype(f32),
         "dt_bias": jnp.log(jnp.expm1(jnp.exp(
             jax.random.uniform(
-                next(keys), (L, H), f32, math.log(1e-3), math.log(1e-1)
+                next(keys), (L, decays), f32, math.log(1e-3), math.log(1e-1)
             )
         ))),
         "A_log": jnp.log(
@@ -123,11 +146,26 @@ def delta_mixer(
         beta = jax.nn.sigmoid(_mm("btd,dh->bth", h, lp["wb"]).astype(f32))
         if cfg.linear_allow_neg_eigval:
             beta = 2.0 * beta
-        g = -jnp.exp(lp["A_log"].astype(f32)) * jax.nn.softplus(
-            _mm("btd,dh->bth", h, lp["wa"]).astype(f32)
-            + lp["dt_bias"].astype(f32)
+        def projected(full, low):
+            """``h W``, or ``(h W_a) W_b`` through the bottleneck."""
+            if not cfg.linear_low_rank:
+                return _mm("btd,dn->btn", h, lp[full])
+            return _mm(
+                "btr,rn->btn", _mm("btd,dr->btr", h, lp[low + "_a"]),
+                lp[low + "_b"],
+            )
+
+        rate = -jnp.exp(lp["A_log"].astype(f32))         # one a head
+        dt = jax.nn.softplus(
+            projected("wa", "wf").astype(f32) + lp["dt_bias"].astype(f32)
         )
-        g = jnp.where(real[..., None], g, 0.0)
+        if cfg.linear_decay_a_channel:
+            # one a head and key channel: [B, T, H, Dk]
+            g = rate[:, None] * dt.reshape(B, T, H, Dk)
+            g = jnp.where(real[..., None, None], g, 0.0)
+        else:
+            g = rate * dt
+            g = jnp.where(real[..., None], g, 0.0)
         beta = jnp.where(real[..., None], beta, 0.0)
         # the K - 1 rows before the step's first, oldest first
         if carried is not None:
@@ -189,6 +227,11 @@ def delta_mixer(
         o = o * lax.rsqrt(
             jnp.mean(o * o, axis=-1, keepdims=True) + cfg.rms_norm_eps
         ) * lp["o_norm"].astype(f32)
-        gate = _mm("btd,dv->btv", h, lp["wg"]).reshape(B, T, H, Dv)
-        y = (o * jax.nn.silu(gate.astype(f32))).astype(h.dtype)
+        gate = projected("wg", "wg")
+        gate_act = (
+            jax.nn.sigmoid if cfg.linear_gate_act == "sigmoid" else jax.nn.silu
+        )
+        y = (
+            o * gate_act(gate.reshape(B, T, H, Dv).astype(f32))
+        ).astype(h.dtype)
         return _mm("btv,vd->btd", y.reshape(B, T, H * Dv), lp["wo"]), carried

@@ -437,9 +437,16 @@ def init_params(
             "layers": {
                 "attn_norm": jnp.ones((L, d), dtype),
                 "mlp_norm": jnp.ones((L, d), dtype),
-                "w_gate": w(next(keys), L, d, f),
-                "w_up": w(next(keys), L, d, f),
-                "w_down": w(next(keys), L, f, d),
+                **(
+                    _init_experts(
+                        cfg, L, lambda *a, **k: w(next(keys), *a, **k), dtype
+                    )
+                    if cfg.is_moe else {
+                        "w_gate": w(next(keys), L, d, f),
+                        "w_up": w(next(keys), L, d, f),
+                        "w_down": w(next(keys), L, f, d),
+                    }
+                ),
             },
         }
         if La:
@@ -449,6 +456,8 @@ def init_params(
                 "wv": w(next(keys), La, d, cfg.kv_dim),
                 "wo": w(next(keys), La, cfg.q_dim, d),
             }
+            if cfg.attn_output_gate:
+                params["attn_layers"]["wg"] = w(next(keys), La, d, cfg.q_dim)
             if cfg.qk_norm_whole:
                 params["attn_layers"].update(
                     q_norm=jnp.ones((La, cfg.q_dim), dtype),
@@ -530,30 +539,9 @@ def init_params(
         layers["post_attn_norm"] = init((L, d), dtype)
         layers["post_mlp_norm"] = init((L, d), dtype)
     if cfg.is_moe:
-        fm, E = cfg.moe_intermediate_size, cfg.num_experts
-        # the router scores every expert; weights exist for those held
-        Eh = cfg.num_held_experts
-        layers["router"] = w(next(keys), L, d, E)
-        layers["we_gate"] = w(next(keys), L, Eh, d, fm)
-        layers["we_up"] = w(next(keys), L, Eh, d, fm)
-        layers["we_down"] = w(next(keys), L, Eh, fm, d, scale=1.0 / math.sqrt(fm))
-        if cfg.shared_expert_intermediate_size:
-            fs = cfg.shared_expert_intermediate_size
-            layers["ws_gate"] = w(next(keys), L, d, fs)
-            layers["ws_up"] = w(next(keys), L, d, fs)
-            layers["ws_down"] = w(next(keys), L, fs, d)
-            if cfg.shared_expert_gated:
-                layers["shared_gate"] = w(next(keys), L, d, 1)
-        if (
-            cfg.moe_scoring in ("sigmoid", "softmax_topk")
-            and cfg.router_correction_bias
-        ):
-            # DeepSeek-V3 correction bias / GPT-OSS affine router
-            layers["router_bias"] = jnp.zeros((L, E), jnp.float32)
-        if cfg.moe_bias:
-            layers["we_gate_b"] = jnp.zeros((L, Eh, fm), dtype)
-            layers["we_up_b"] = jnp.zeros((L, Eh, fm), dtype)
-            layers["we_down_b"] = jnp.zeros((L, Eh, d), dtype)
+        layers.update(_init_experts(
+            cfg, L, lambda *a, **k: w(next(keys), *a, **k), dtype
+        ))
     else:
         layers["w_gate"] = w(next(keys), L, d, f)
         layers["w_up"] = w(next(keys), L, d, f)
@@ -569,6 +557,39 @@ def init_params(
     if not cfg.tie_word_embeddings:
         params["lm_head"] = w(next(keys), d, cfg.vocab_size)
     return params
+
+
+def _init_experts(cfg: ModelConfig, L: int, w, dtype) -> Dict[str, jax.Array]:
+    """``L`` layers' router, routed experts and shared expert, random
+    (``w(*shape, scale=)`` draws one leaf), beside whatever mixer the
+    layers have."""
+    d, fm, E = cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts
+    # the router scores every expert; weights exist for those held
+    Eh = cfg.num_held_experts
+    layers = {
+        "router": w(L, d, E),
+        "we_gate": w(L, Eh, d, fm),
+        "we_up": w(L, Eh, d, fm),
+        "we_down": w(L, Eh, fm, d, scale=1.0 / math.sqrt(fm)),
+    }
+    if cfg.shared_expert_intermediate_size:
+        fs = cfg.shared_expert_intermediate_size
+        layers["ws_gate"] = w(L, d, fs)
+        layers["ws_up"] = w(L, d, fs)
+        layers["ws_down"] = w(L, fs, d)
+        if cfg.shared_expert_gated:
+            layers["shared_gate"] = w(L, d, 1)
+    if (
+        cfg.moe_scoring in ("sigmoid", "softmax_topk")
+        and cfg.router_correction_bias
+    ):
+        # DeepSeek-V3 correction bias / GPT-OSS affine router
+        layers["router_bias"] = jnp.zeros((L, E), jnp.float32)
+    if cfg.moe_bias:
+        layers["we_gate_b"] = jnp.zeros((L, Eh, fm), dtype)
+        layers["we_up_b"] = jnp.zeros((L, Eh, fm), dtype)
+        layers["we_down_b"] = jnp.zeros((L, Eh, d), dtype)
+    return layers
 
 
 # ---------------------------------------------------------------------------
@@ -2119,6 +2140,15 @@ def forward(
                 carried = dataclasses.replace(carried, k=new_k, v=new_v)
             if unstored:
                 attn = attn[..., :cfg.q_dim]
+            if cfg.attn_output_gate:
+                # elementwise over the q_dim channels, from the layer's
+                # input, before Wo (arXiv:2505.06708)
+                with jax.named_scope("attn_output_gate"):
+                    gate = _mm("btd,dq->btq", h, lp["wg"])
+                    attn = (
+                        attn.astype(jnp.float32)
+                        * jax.nn.sigmoid(gate.astype(jnp.float32))
+                    ).astype(attn.dtype)
 
         if state_layer is None:     # a state's mixer projects its own
             attn_out = _mm("btq,qd->btd", attn, lp["wo"])

@@ -95,7 +95,8 @@ Record vocabulary (per step):
   the step's decode step moved on, one token each, the prompt tokens
   the step sent through the chunked scan (a prefill), and the kind of
   mixer that keeps the state (``"ssm"`` Mamba-2 layers, ``"delta"``
-  gated-delta-rule layers). Absent for any other model.
+  gated-delta-rule layers with a decay a head, ``"kda"`` those with a
+  decay a key channel). Absent for any other model.
 
 - ``window_rows``, ``full_rows`` — for a stack that keeps its sliding
   layers' rows at window size (``/healthz`` ``cache.window_bytes``):

@@ -106,8 +106,9 @@ METRIC_FAMILIES = {
     # layers that keep it, by the program that took them (label
     # kind=prefill|decode: the chunked scan over a prompt, the one-step
     # update of a live slot) and the kind of mixer (label
-    # mixer=ssm|delta: Mamba-2, gated delta rule); absent for any other
-    # model
+    # mixer=ssm|delta|kda: Mamba-2, gated delta rule with a decay a
+    # head, the same rule with a decay a key channel); absent for any
+    # other model
     "gpustack_engine_ssm_tokens_total": "counter",
     "gpustack_engine_occupancy_ratio": "gauge",
     "gpustack_engine_queue_oldest_wait_seconds": "gauge",

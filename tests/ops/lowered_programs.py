@@ -47,6 +47,8 @@ PROGRAMS = (
     ("olmo-hybrid-7b-int8", "olmo-hybrid-7b-int8", 0, 12, 2560, 1024),
     ("granite-4.0-h-micro-int8", "granite-4.0-h-micro-int8",
      0, 64, 2048, 1024),
+    ("solar-open2-250b-int8-ep8-l12", "solar-open2-250b-int8-ep8-l12",
+     0, 32, 2560, 1024),
 )
 
 

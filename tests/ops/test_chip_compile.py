@@ -1303,6 +1303,157 @@ def test_a_delta_stack_s_prefill_keeps_its_temporaries_small(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2**30
 
 
+SOLAR_DIR = "perfbench/configs/solar-open2-250b-int8-ep8-l12"
+
+
+def _solar(periods: int = 1):
+    """The benchmark's Solar-Open2 share at its published widths,
+    ``periods`` periods of a gated attention layer and three KDA layers."""
+    import os
+
+    from gpustack_tpu.models.config import load_hf_config
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)
+    )))
+    cfg = load_hf_config(os.path.join(root, SOLAR_DIR))
+    return dataclasses.replace(
+        cfg, num_layers=4 * periods, layer_types=cfg.layer_types[:4 * periods],
+    )
+
+
+def test_a_kda_stack_s_decode_step_moves_state_rows_and_experts_in_place(
+    one_chip,
+):
+    """The decode program of the benchmark's Solar-Open2 share as the
+    runner traces it on one TPU chip, 32 slots of 2,560, one period: each
+    KDA layer's ``kda_state_update`` (the decay a key channel, a column a
+    head beside ``q`` and ``k``: nothing of the state's size is made for
+    it) reads and writes the stacked state ``[L, B, 128, 8192]`` where it
+    lies, the attention layer's GQA kernel walks its rows in place, and
+    every layer's touched-experts kernel reads the 40 held experts of the
+    stacked weights by the layer's index inside the scan over periods."""
+    from gpustack_tpu.models import init_params
+    from gpustack_tpu.models.hybrid import ssm_update_impl
+    from gpustack_tpu.models.quant import quantize_params
+    from gpustack_tpu.models.transformer import (
+        KVCache,
+        decode_attention_impl,
+        forward,
+        moe_dispatch,
+    )
+
+    cfg = _solar()
+    slots, S = 32, 2560
+    assert decode_attention_impl(cfg, 1, S, "tpu", None) == "kernel"
+    assert ssm_update_impl(1, "tpu", None) == "kernel"
+    assert moe_dispatch(slots, cfg, "tpu", None, decode=True) == "touched"
+    params = _shapes_on(
+        one_chip, lambda: quantize_params(init_params(cfg, jax.random.key(0)))
+    )
+    cache = _shapes_on(one_chip, lambda: KVCache.create(cfg, slots, S))
+    assert cache.ssm.shape == (3, slots, 128, 8192)
+    assert cache.k.shape == cache.v.shape == (1, slots, S, 8, 128)
+    tokens = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)
+
+    def step(params, tokens, positions, cache, live):
+        return forward(
+            params, cfg, tokens, positions, cache, live=live,
+            decode_attn_impl="kernel", ssm_impl="kernel",
+            moe_dispatch_impl="touched", count_experts_read=True,
+        )
+
+    compiled = jax.jit(step, donate_argnums=(3,)).lower(
+        params, tokens, tokens, cache, live
+    ).compile()
+    text = compiled.as_text()
+    state = f"f32[3,{slots},128,8192]"
+    assert len(re.findall(
+        rf"%kda_state_update[\w.\-]* = \({re.escape(state)}", text
+    )) == 3
+    assert not re.findall(r"%delta_state_update[\w.\-]* = ", text)
+    assert len(re.findall(
+        rf"%gqa_decode_attention[\w.\-]* = bf16\[{slots},64,128\]"
+        r".* custom-call\(", text,
+    )) == 1
+    assert len(re.findall(
+        r"%moe_touched_experts[\w.\-]* = .* custom-call\(", text
+    )) == 4
+    # no decay of the state's size: nothing f32 [.., 128, 8192] but the
+    # state itself, and that neither copied, sliced nor updated
+    assert not re.findall(
+        rf"= {re.escape(state)}[^ ]* (?:copy|dynamic-update-slice|"
+        r"dynamic-slice|transpose)\(", text,
+    )
+    assert not re.findall(rf"= f32\[{slots},128,8192\]", text)
+    assert not re.findall(
+        r"= bf16\[1,32,[\d,]+\][^ ]* (?:copy|transpose)\(", text
+    )
+    # the experts', the mixers' and the gate's matrices as they are stored
+    assert not re.findall(
+        r"= s8\[(?:\d+,)*(?:4096,(?:1280|8192|1024)|(?:1280|8192),4096)\]"
+        r"[^ ]* (?:copy|transpose)\(", text,
+    )
+    mem = compiled.memory_analysis()
+    held = cache.ssm.size * 4 + (cache.conv.size + 2 * cache.k.size) * 2
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 32 * 2**20
+
+
+def test_a_kda_stack_s_prefill_keeps_its_temporaries_small(one_chip):
+    """A 1,024 prefill of one period at the published widths: the three
+    KDA layers through the chunked rule with a decay a key channel as
+    einsums in float32 (16 chunks of 64 in sub-blocks of 16; the diagonal
+    sub-blocks' ``[16, 16, 128]`` differences are reduced where they are
+    made, never stored for all 64 heads), the attention layer through the
+    flash kernel, every layer's experts through the grouped kernel;
+    temporaries that leave the resident model room (0.51 GB at full depth
+    beside 11.8)."""
+    from gpustack_tpu.models import init_params
+    from gpustack_tpu.models.quant import quantize_params
+    from gpustack_tpu.models.transformer import KVCache, forward, moe_dispatch
+
+    cfg = _solar()
+    T = 1024
+    assert moe_dispatch(T, cfg, "tpu", None) == "grouped"
+    params = _shapes_on(
+        one_chip, lambda: quantize_params(init_params(cfg, jax.random.key(0)))
+    )
+
+    def prefill(params, tokens, true_len):
+        cache = KVCache.create(cfg, 1, T)
+        positions = jnp.arange(T, dtype=jnp.int32)[None]
+        return forward(
+            params, cfg, tokens, positions, cache, attn_impl="flash",
+            logits_at=(true_len - 1)[None], true_len=true_len[None],
+            ssm_impl="scan", moe_dispatch_impl="grouped",
+            count_held_pairs=True,
+        )
+
+    compiled = jax.jit(prefill).lower(
+        params,
+        jax.ShapeDtypeStruct((1, T), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(
+        r"%flash_attention_prefill[\w.\-]* = .* custom-call\(", text
+    )) == 1
+    # the chunked form runs under the scope of a decay a channel
+    assert re.search(r'op_name="[^"]*/kda_chunk_scan/', text)
+    assert not re.search(r'op_name="[^"]*/delta_chunk_scan/', text)
+    _no_score_tensor(text, T, T)
+    # the explicit differences of every head and sub-block at once would
+    # be f32[1,16,64,4,16,16,128], 0.54 GB: inside a fusion that sums
+    # over the channels, never a fusion's result
+    assert re.findall(r"= f32\[1,16,64,4,16,16,128\]", text)
+    assert not re.findall(
+        r"%(?:[\w\-]*fusion|copy)[\w.\-]* = f32\[1,16,64,4,16,16,128\]", text
+    )
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.75 * 2**30
+
+
 GRANITE_DIR = "perfbench/configs/granite-4.0-h-micro-int8"
 
 
@@ -1475,6 +1626,7 @@ def test_the_other_models_programs_lower_to_the_text_they_had(
     that PR, A.X-K1's prefill again with PR 59 (a group of one's flash
     call asks for its VMEM, which is in the call's text; the tile itself
     is in the kernel's body, which is not hashed, so Olmo-Hybrid's new
-    tile moved nothing) (``lowered_programs.py`` says what is hashed and
-    how to take the hashes again on purpose)."""
+    tile moved nothing), Solar-Open2's taken with PR 60, which left the
+    other fourteen as they were (``lowered_programs.py`` says what is
+    hashed and how to take the hashes again on purpose)."""
     assert lowered_hashes[program] == _lowered_names()[program]

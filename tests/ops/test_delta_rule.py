@@ -245,3 +245,188 @@ def test_the_chunked_form_then_steps_is_one_sequence():
     np.testing.assert_allclose(
         state_heads(state[0], H), want_S, rtol=2e-4, atol=2e-4
     )
+
+
+# ---- a decay a key channel (KDA): ``g [B, T, H, Dk]`` ----
+
+def recurrence_a_channel(q, k, v, g, beta, h0):
+    """:func:`recurrence` with ``S_t = diag(a_t) S_{t-1} + ...``, a
+    decay a key channel, in NumPy float64."""
+    q, k, v, g, beta, S = (
+        np.asarray(a, np.float64) for a in (q, k, v, g, beta, h0)
+    )
+    out = []
+    for t in range(q.shape[1]):
+        S = np.exp(g[:, t])[..., None] * S
+        u = beta[:, t][..., None] * (
+            v[:, t] - np.einsum("bhkv,bhk->bhv", S, k[:, t])
+        )
+        S = S + k[:, t][..., :, None] * u[..., None, :]
+        out.append(np.einsum("bhkv,bhk->bhv", S, q[:, t]))
+    return np.stack(out, 1), S
+
+
+def draw_a_channel(T, decay="mild", seed=0, heads=H, dk=DK, dv=DV):
+    """:func:`draw` with a log decay a key channel: ``"mild"`` -0.001 ..
+    -1.6 as the mixer draws them, ``"strong"`` -2 a position on every
+    channel (over a chunk of 64 the running sum passes -88, where the
+    factored form's ``exp(-G)`` is no float32), ``"mixed"`` a strong
+    channel beside one that never forgets."""
+    q, k, v, _, beta, h0 = draw(T, seed, heads, dk, dv)
+    key = jax.random.key(seed + 100)
+    shape = (B, T, heads, dk)
+    if decay == "mild":
+        g = -jnp.exp(jax.random.uniform(key, shape, minval=-7, maxval=0.5))
+    elif decay == "strong":
+        g = jnp.full(shape, -2.0)
+    else:
+        g = jnp.where(jnp.arange(dk) % 2 == 0, -2.0, -1e-4) * jnp.ones(shape)
+    return q, k, v, g, beta, h0
+
+
+def test_the_yardstick_takes_a_decay_a_channel():
+    args = draw_a_channel(37)
+    o, last = delta_recurrence(*args)
+    want_o, want_last = recurrence_a_channel(*args)
+    np.testing.assert_allclose(o, want_o, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(last, want_last, rtol=2e-5, atol=2e-5)
+    # and a decay a channel whose channels are alike is a decay a head
+    q, k, v, g, beta, h0 = draw(37)
+    wide = jnp.broadcast_to(g[..., None], g.shape + (DK,))
+    np.testing.assert_allclose(
+        delta_recurrence(q, k, v, wide, beta, h0)[0],
+        delta_recurrence(q, k, v, g, beta, h0)[0], rtol=1e-6, atol=1e-6,
+    )
+
+
+@pytest.mark.parametrize("decay", ["mild", "strong", "mixed"])
+@pytest.mark.parametrize("initial", [False, True], ids=["zeros", "carried"])
+@pytest.mark.parametrize(
+    "T,chunk",
+    [(64, 64), (128, 64), (150, 64), (37, 16), (96, 32)],
+    ids=["one_chunk", "two_chunks", "a_ragged_tail", "one_sub_block",
+         "two_sub_blocks"],
+)
+def test_the_chunked_form_takes_a_decay_a_channel(T, chunk, initial, decay):
+    """Nothing non-finite and the recurrence's numbers, the overflow
+    case included: -2 a position over a whole chunk."""
+    q, k, v, g, beta, h0 = draw_a_channel(T, decay)
+    if not initial:
+        h0 = jnp.zeros_like(h0)
+    o, last = jax.jit(delta_chunk_scan, static_argnames="chunk")(
+        q, k, v, g, beta, h0, chunk=chunk
+    )
+    assert bool(jnp.all(jnp.isfinite(o))) and bool(jnp.all(jnp.isfinite(last)))
+    want_o, want_last = recurrence_a_channel(q, k, v, g, beta, h0)
+    np.testing.assert_allclose(o, want_o, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(last, want_last, rtol=1e-4, atol=1e-4)
+
+
+def test_the_factored_form_would_overflow_where_the_sub_blocks_do_not():
+    """What the sub-block rule is for: at -2 a position the running sum
+    over a chunk of 64 reaches -128, and ``exp(128)`` is no float32."""
+    g = draw_a_channel(64, "strong")[3]
+    cum = jnp.cumsum(g, axis=1)
+    assert not bool(jnp.all(jnp.isfinite(jnp.exp(-cum))))
+
+
+@pytest.mark.parametrize("decay", ["mild", "strong"])
+def test_a_padded_tail_moves_no_state_with_a_decay_a_channel(decay):
+    q, k, v, g, beta, h0 = draw_a_channel(80, decay)
+    real = jnp.arange(80) < 53
+    g = jnp.where(real[None, :, None, None], g, 0.0)
+    beta = jnp.where(real[None, :, None], beta, 0.0)
+    _, last = delta_chunk_scan(q, k, v, g, beta, h0)
+    _, want = recurrence_a_channel(
+        q[:, :53], k[:, :53], v[:, :53], g[:, :53], beta[:, :53], h0
+    )
+    np.testing.assert_allclose(last, want, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("decay", ["mild", "strong"])
+@pytest.mark.parametrize(
+    "heads,dk,dv", [(H, DK, DV), (4, 16, 128), (2, 8, 192)],
+    ids=["no_tile_s_widths", "a_head_a_lane_tile", "two_heads_three_tiles"],
+)
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_one_step_takes_a_decay_a_channel(form, heads, dk, dv, decay):
+    """Both one-step forms against the recurrence; the kernel (interpret
+    mode) multiplies each sublane row of each head's lanes by its own
+    number, moves the live slots only and leaves the other layers."""
+    q, k, v, g, beta, h0 = draw_a_channel(1, decay, 3, heads, dk, dv)
+    L, layer = 3, 1
+    stored = jnp.full((L, B, dk, heads * dv), 5.0).at[layer].set(
+        state_layout(h0)
+    )
+    step = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+    if form == "xla":
+        o, new = delta_step_xla(stored, jnp.int32(layer), *step)
+        live = np.ones(B, bool)
+    else:
+        live = np.array([True, False])
+        o, new = delta_state_update(
+            stored, jnp.int32(layer), *step, jnp.asarray(live),
+            interpret=True,
+        )
+    want_o, want_last = recurrence_a_channel(q, k, v, g, beta, h0)
+    got = state_heads(new[layer], heads)
+    for b in range(B):
+        if live[b]:
+            np.testing.assert_allclose(
+                o[b], want_o[b, 0], rtol=2e-5, atol=2e-5
+            )
+            np.testing.assert_allclose(
+                got[b], want_last[b], rtol=2e-5, atol=2e-5
+            )
+        else:
+            np.testing.assert_array_equal(o[b], 0.0)
+            np.testing.assert_array_equal(got[b], h0[b])
+    np.testing.assert_array_equal(new[0], 5.0)
+    np.testing.assert_array_equal(new[2], 5.0)
+
+
+def test_the_call_is_named_by_the_decay_s_shape():
+    """``delta_state_update`` for a decay a head, ``kda_state_update``
+    for one a key channel: a device trace tells the two apart, and the
+    reader of each finds its own."""
+    q, k, v, g, beta, h0 = draw(1)
+    stored = state_layout(h0)[None]
+    live = jnp.ones((B,), bool)
+
+    # (interpret mode lowers to no custom call: the names are the
+    # scopes the call is traced under)
+    a_head = jax.make_jaxpr(lambda s: delta_state_update(
+        s, jnp.int32(0), q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+        live, interpret=True,
+    ))(stored)
+    a_channel = jax.make_jaxpr(lambda s: delta_state_update(
+        s, jnp.int32(0), q[:, 0], k[:, 0], v[:, 0],
+        jnp.broadcast_to(g[:, 0, :, None], (B, H, DK)), beta[:, 0],
+        live, interpret=True,
+    ))(stored)
+    assert "delta_state_update" in str(a_head)
+    assert "kda_state_update" not in str(a_head)
+    assert "kda_state_update" in str(a_channel)
+    assert "delta_state_update" not in str(a_channel)
+
+
+def test_the_chunked_form_then_steps_is_one_sequence_with_a_decay_a_channel():
+    q, k, v, g, beta, h0 = draw_a_channel(70)
+    want_o, want_last = recurrence_a_channel(q, k, v, g, beta, h0)
+    o, last = delta_chunk_scan(
+        q[:, :66], k[:, :66], v[:, :66], g[:, :66], beta[:, :66], h0
+    )
+    stored = state_layout(last)[None]
+    outs = [o]
+    for t in range(66, 70):
+        o_t, stored = delta_state_update(
+            stored, jnp.int32(0), q[:, t], k[:, t], v[:, t], g[:, t],
+            beta[:, t], jnp.ones((B,), bool), interpret=True,
+        )
+        outs.append(o_t[:, None])
+    np.testing.assert_allclose(
+        jnp.concatenate(outs, 1), want_o, rtol=1e-4, atol=1e-4
+    )
+    np.testing.assert_allclose(
+        state_heads(stored[0], H), want_last, rtol=1e-4, atol=1e-4
+    )
