@@ -112,7 +112,9 @@ def flash_tile(cfg: ModelConfig, bucket: int, tp: int = 1):
     """The tile the flash kernel's shapes choose for a prefill of
     ``bucket`` tokens from scratch, and the points of one call's grid on
     one device of ``tp``: what says in the log that a rule of
-    ``ops/flash_attention.py choose_tiles`` engaged. From the
+    ``ops/flash_attention.py choose_tiles`` engaged (a latent's own
+    prefill call, :func:`latent_prefill_call`, takes the same rule's
+    tile at its group of one and a key of ``head_dim``). From the
     configuration alone, outside any traced function."""
     from gpustack_tpu.ops import flash_attention as fa
 
@@ -123,6 +125,21 @@ def flash_tile(cfg: ModelConfig, bucket: int, tp: int = 1):
     )
     stored = cfg.num_heads if cfg.is_mla else cfg.kv_heads_stored
     return tiles, fa.grid_points(tiles, rows, rows, max(1, stored // tp))
+
+
+def latent_prefill_call(cfg: ModelConfig, mesh) -> str:
+    """The name of the latent's own prefill call where a flash prefill of
+    this model on this mesh makes it (``transformer._mla_over_own_rows``:
+    one device, widths that are whole lane tiles), else ``""``: what the
+    log says before the tile, and a trace's ``%mla_prefill_attention``."""
+    if not cfg.is_mla or mesh.size != 1:
+        return ""
+    from gpustack_tpu.ops.mla_attention import mla_prefill_takes
+
+    return "mla_prefill_attention" if mla_prefill_takes(
+        cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+        cfg.v_head_dim,
+    ) else ""
 
 
 @jax.tree_util.register_dataclass
@@ -361,10 +378,13 @@ class ModelRunner:
             # once per bucket, at compile time: the engine's log says
             # which kernel serves which prompt widths
             self._logged_attn_buckets.add(bucket)
+            call = latent_prefill_call(self.cfg, self.mesh)
             logger.info(
                 "prefill bucket %d: attention impl %s%s", bucket, impl,
-                ", %s, %d grid points a call" % flash_tile(
-                    self.cfg, bucket, int(self.mesh.shape.get("tp", 1))
+                ", %s%s, %d grid points a call" % (
+                    call and call + " ", *flash_tile(
+                        self.cfg, bucket, int(self.mesh.shape.get("tp", 1))
+                    )
                 ) if impl == "flash" else "",
             )
         return impl

@@ -878,6 +878,59 @@ def _flash_prefill(mesh, attn_impl):
     return partial(flash, interpret=attn_impl == "flash_interpret")
 
 
+def _mla_over_own_rows(
+    q,                   # [B, T, H, nope + rope], the rope part not rotated
+    c_kv, k_pe,          # [B, T, rank], normed; [B, T, 1, rope], rotated
+    wk_b, wv_b,          # [rank, H * nope], [rank, H * vd]
+    sin, cos,            # [B, T, rope / 2]: the positions' rotation
+    mask, scale, mesh, attn_impl, q_offset,
+) -> jax.Array:
+    """A latent's decompressed attention over the step's own rows, every
+    key there is: ``[B, T, H * vd]``. ``k_nope`` and ``v`` are made per
+    head from ``c_kv`` inside the program, compute-bound.
+
+    On one device the flash form is a call of the latent's own
+    (``ops/mla_attention.py mla_prefill_attention``): it reads the query,
+    ``k_nope`` and ``v`` as the projections make them, a head a block of
+    their columns, rotates the query's rope part itself and takes the one
+    rope key for all heads, and writes its result the same way, so no key
+    of ``nope + rope`` a head is built and nothing is relaid round it
+    (4.2 ms a layer of A.X-K1's 8,192 prefill until PR 62: PERF.md).
+    Widths that are no whole lane tiles, a mesh of several devices and the
+    XLA form take the keys built out to ``[B, T, H, nope + rope]``."""
+    B, T, H, _ = q.shape
+    rope_d = k_pe.shape[3]
+    k_nope = _mm("btr,rq->btq", c_kv, wk_b)
+    v = _mm("btr,rq->btq", c_kv, wv_b)
+    nope, vd = k_nope.shape[2] // H, v.shape[2] // H
+    if attn_impl != "xla" and (mesh is None or mesh.size == 1):
+        from gpustack_tpu.ops.mla_attention import (
+            mla_prefill_attention,
+            mla_prefill_takes,
+        )
+
+        if mla_prefill_takes(H, nope, rope_d, vd):
+            return mla_prefill_attention(
+                q.reshape(B, T, -1), k_nope, k_pe[:, :, 0], v, sin, cos,
+                scale, interpret=attn_impl == "flash_interpret",
+            )
+    k = jnp.concatenate(
+        [
+            k_nope.reshape(B, T, H, nope),
+            jnp.broadcast_to(k_pe, (B, T, H, rope_d)),
+        ],
+        axis=-1,
+    )
+    q = jnp.concatenate(
+        [q[..., :nope], apply_rope_interleaved(q[..., nope:], sin, cos)],
+        axis=-1,
+    )
+    v = v.reshape(B, T, H, vd)
+    if attn_impl == "xla":
+        return _attend(q[:, :, :, None, :], k, v, mask, scale)
+    return _flash_prefill(mesh, attn_impl)(q, k, v, scale, q_offset=q_offset)
+
+
 def attend_over_cache(
     q, k, v,             # the step's [B, T, heads, hd], normed and rotated
     buf_k, buf_v,        # [L, B, S, Hkv, hd]: the layer's store in the cache
@@ -1855,9 +1908,8 @@ def forward(
         - **decompressed**, where the step's own rows are every key
           there is (no cache, or a prefill from position 0 into a cache
           of the step's length): ``k_nope`` and ``v`` are made per head
-          from ``c_kv`` inside the program, compute-bound, and the flash
-          kernel takes the 192-wide keys and the 128-wide values as
-          they are;
+          from ``c_kv`` inside the program, compute-bound, and attended
+          over as the projections make them (``_mla_over_own_rows``);
         - **absorbed**, over cached rows (decode, verify, a
           continuation): ``W_uk`` goes into the query (``q' = q_nope
           W_uk^T``, 128 -> 512 a head) and ``W_uv`` into the output, and
@@ -1866,7 +1918,7 @@ def forward(
           64 heads and never decompressed.
         """
         H = cfg.num_heads
-        nope, rope_d = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        nope = cfg.qk_nope_head_dim
         rank, vd = cfg.kv_lora_rank, cfg.v_head_dim
         if cfg.q_lora_rank:
             q_c = rms_norm(
@@ -1898,23 +1950,11 @@ def forward(
                 k=write(carried.k, c_kv), v=write(carried.v, k_pe[:, :, 0])
             )
         if cache is None or (T > 1 and cache.max_len == T):
-            k_nope = _mm("btr,rq->btq", c_kv, lp["wk_b"])
-            v = _mm("btr,rq->btq", c_kv, lp["wv_b"]).reshape(B, T, H, vd)
-            k = jnp.concatenate(
-                [
-                    k_nope.reshape(B, T, H, nope),
-                    jnp.broadcast_to(k_pe, (B, T, H, rope_d)),
-                ],
-                axis=-1,
-            )
-            q = jnp.concatenate([q_nope, q_pe], axis=-1)
-            if use_flash:
-                attn = _flash_prefill(mesh, attn_impl)(
-                    q, k, v, scale, q_offset=positions[0, 0]
-                )
-            else:
-                attn = _attend(q[:, :, :, None, :], k, v, mask_l, scale)
-            return attn, carried
+            return _mla_over_own_rows(
+                q, c_kv, k_pe, lp["wk_b"], lp["wv_b"], mla_sin, mla_cos,
+                mask_l, scale, mesh, attn_impl if use_flash else "xla",
+                positions[0, 0],
+            ), carried
 
         # absorbed, over this layer of the cache. An int8 weight's
         # scales are per output channel of kv_b_proj, (head, nope) or

@@ -15,7 +15,11 @@ differ from the old kernel's (expected 0). ``--sweep`` times every pair of
 block sizes and every inner shape (rows of a matmul, sub-blocks unrolled:
 two constants of the kernel's module, which the sweep sets while it
 traces), not only what the shapes choose; at a group of one the rows of a
-matmul are swept at every pair of the larger blocks too. One JSON line a
+matmul are swept at every pair of the larger blocks too. At a latent's
+shapes (keys wider than values) two more lines, ``latent`` and
+``flash_with_relayouts`` (:func:`latent_lines`): the latent's own prefill
+call on operands as the projections make them, beside the flash call as a
+layer ran it until PR 62, from the same operands. One JSON line a
 measurement on stdout and in ``chiprun_out/flash_bench.jsonl``.
 Nothing a cell runs imports this file.
 """
@@ -54,6 +58,8 @@ SHAPES = [
     ("axk1-8192", 8192, 8192, 0, 64, 64, 192, 128),
     ("axk1-4096", 4096, 4096, 0, 64, 64, 192, 128),
     ("olmo-1024", 1024, 1024, 0, 32, 32, 128, 128),
+    # a latent's widths at a size a rehearsal on the CPU gets through
+    ("axk1-256", 256, 256, 0, 4, 4, 192, 128),
 ]
 SWEEP_Q = (128, 256, 512, 1024)
 SWEEP_K = (128, 256, 512, 1024, 2048)
@@ -115,6 +121,72 @@ def timed(call, q, k, v, off, reps: int):
         jax.block_until_ready(chain(q, k, v, off))
         best = min(best, (time.perf_counter() - t0) / reps)
     return best, out
+
+
+def latent_lines(name, T, H, d, dv, floor, reps, rehearse, say):
+    """``ops/mla_attention.py mla_prefill_attention`` on token-major
+    operands (the query ``[1, T, H * d]`` not rotated, ``k_nope`` and
+    ``v`` ``[1, T, H * dv]``, one rope key ``[1, T, d - dv]``) beside what
+    it stands for: the query rotated outside, the keys built out to ``d``
+    a head, and ``flash_attention_prefill`` with its transposes in and
+    out. Each a program of its own, ``reps`` of them in flight behind one
+    another (a call is 15 ms, a dispatch a tenth of one); the shares are
+    of the attention's floor, which the second line's relayouts are no
+    part of."""
+    from gpustack_tpu.models import transformer as tf
+    from gpustack_tpu.ops.mla_attention import mla_prefill_attention
+
+    rope = d - dv
+    ks = jax.random.split(jax.random.key(T), 4)
+    draw = lambda k, *shape: jax.random.normal(
+        k, shape, jnp.float32
+    ).astype(jnp.bfloat16)
+    q, k_nope = draw(ks[0], 1, T, H * d), draw(ks[1], 1, T, H * dv)
+    k_pe, v = draw(ks[2], 1, T, rope), draw(ks[3], 1, T, H * dv)
+    sin, cos = tf.rope_sin_cos(
+        jnp.arange(T, dtype=jnp.int32)[None], tf._inv_freq(10000.0, rope)
+    )
+    scale = d ** -0.5
+
+    def latent(q, k_nope, k_pe, v):
+        return mla_prefill_attention(
+            q, k_nope, k_pe, v, sin, cos, scale, interpret=rehearse
+        )
+
+    def flash_with_relayouts(q, k_nope, k_pe, v):
+        q = q.reshape(1, T, H, d)
+        q = jnp.concatenate([
+            q[..., :dv], tf.apply_rope_interleaved(q[..., dv:], sin, cos)
+        ], axis=-1)
+        k = jnp.concatenate([
+            k_nope.reshape(1, T, H, dv),
+            jnp.broadcast_to(k_pe[:, :, None], (1, T, H, rope)),
+        ], axis=-1)
+        return fa.flash_attention_prefill(
+            q, k, v.reshape(1, T, H, dv), scale, interpret=rehearse
+        )
+
+    outs = {}
+    for which, call in (
+        ("latent", latent), ("flash_with_relayouts", flash_with_relayouts)
+    ):
+        run = jax.jit(call)
+        outs[which] = jax.block_until_ready(run(q, k_nope, k_pe, v))
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            for _ in range(reps):
+                out = run(q, k_nope, k_pe, v)
+            jax.block_until_ready(out)
+            best = min(best, (time.perf_counter() - t0) / reps)
+        rec = {"shape": name, "kernel": which, "ms": best * 1e3,
+               "roofline_pct": 100.0 * floor / best}
+        if which != "latent":
+            rec["largest_difference"] = float(jnp.max(jnp.abs(
+                outs[which].astype(jnp.float32)
+                - outs["latent"].astype(jnp.float32)
+            )))
+        say(rec)
 
 
 def main() -> int:
@@ -236,6 +308,10 @@ def main() -> int:
             report(
                 "chosen" if tiles == chosen else "new", list(tiles), call,
                 old_out,
+            )
+        if d != dv and Hq == Hkv and not off:
+            latent_lines(
+                name, T, Hq, d, dv, floor, args.reps, args.rehearse, say
             )
     print(json.dumps({"ok": True, "device": dev.device_kind}))
     return 0

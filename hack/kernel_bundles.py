@@ -10,6 +10,7 @@ count.
     python3 hack/kernel_bundles.py delta      # ops/delta_rule.py, Olmo-Hybrid's
     python3 hack/kernel_bundles.py latent     # ops/mla_attention.py's row write
     python3 hack/kernel_bundles.py flash      # ops/flash_attention.py, A.X-K1's
+    python3 hack/kernel_bundles.py prefill    # ops/mla_attention.py's prefill
     python3 hack/kernel_bundles.py flash --tile 1024,1024,512
     python3 hack/kernel_bundles.py ssm --keep <an empty directory>
 
@@ -18,7 +19,9 @@ heads of one, 8,192 rows, keys 192 and values 128) with the tile its
 shapes choose, or with ``--tile block_q,block_k,rows`` (``rows``: of one
 matmul, the module's ``_MATMUL_ROWS``). Its body holds every sweep of a
 grid point, masked and clear, once a q sub-block: the counts are of the
-body, not of the path a point takes through it.
+body, not of the path a point takes through it. ``prefill`` is the
+latent's own call that took that call's place in the cell (PR 62), at the
+same rows and widths, token-major operands: to be read beside ``flash``.
 
 The dump aborts the process once the kernel's files are written (a
 report template the wheel lacks), so the compile is a child process and
@@ -110,6 +113,23 @@ def compile_flash(on, tile=None):
         ).compile()
 
 
+def compile_prefill(on):
+    import jax
+    import jax.numpy as jnp
+
+    from gpustack_tpu.ops.mla_attention import mla_prefill_attention
+
+    T, H, nope, rope, vd = 8192, 64, 128, 64, 128
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    jax.jit(
+        lambda *operands: mla_prefill_attention(*operands, 192 ** -0.5)
+    ).lower(
+        on((1, T, H * (nope + rope)), bf16), on((1, T, H * nope), bf16),
+        on((1, T, rope), bf16), on((1, T, H * vd), bf16),
+        on((1, T, rope // 2), f32), on((1, T, rope // 2), f32),
+    ).compile()
+
+
 # the script's name for a kernel -> (its pallas_call's name, what lowers
 # and compiles it at a cell's shapes given ``on(shape, dtype)``)
 KERNELS = {
@@ -117,6 +137,7 @@ KERNELS = {
     "delta": ("delta_state_update", compile_delta),
     "latent": ("mla_write_latent_rows", compile_latent),
     "flash": ("flash_attention_prefill", compile_flash),
+    "prefill": ("mla_prefill_attention", compile_prefill),
 }
 
 

@@ -59,18 +59,22 @@ def test_a_position_sharded_cache_is_read_by_the_ring_alone(platform, bucket):
     assert prefill_attention(platform, bucket, True, _PLAIN) == "ring"
 
 
+def _tpu_mesh(tp: int):
+    return types.SimpleNamespace(
+        devices=types.SimpleNamespace(
+            flat=[types.SimpleNamespace(platform="tpu")]
+        ),
+        shape={"tp": tp}, size=tp,
+    )
+
+
 def test_the_log_names_the_kernel_that_runs_for_a_windowed_model(caplog):
     """``attn_impl_for`` on a TPU mesh: flash for the plain model, XLA
     for the windowed one, and the once-per-bucket line says so; for the
     kernel it also names the tile its shapes choose and the points of a
     call's grid (``flash_tile``: what says that a rule of
     ``choose_tiles`` engaged)."""
-    tpu_mesh = types.SimpleNamespace(
-        devices=types.SimpleNamespace(
-            flat=[types.SimpleNamespace(platform="tpu")]
-        ),
-        shape={"tp": 1},
-    )
+    tpu_mesh = _tpu_mesh(1)
 
     def runner(cfg):
         return types.SimpleNamespace(
@@ -117,3 +121,30 @@ def test_the_tile_the_log_names_is_the_one_a_cell_s_call_takes(
         os.path.join(ROOT, "perfbench", "configs", directory)
     )
     assert flash_tile(cfg, bucket, tp) == (tiles, points)
+
+
+@pytest.mark.parametrize("tp,call,points", [
+    (1, "mla_prefill_attention ", 2048),    # the latent's own call
+    (4, "", 512),       # a shard of heads a device: the flash call's
+])
+def test_the_log_names_the_latent_s_own_call_where_it_runs(
+    caplog, tp, call, points
+):
+    """A.X-K1's 8,192 bucket: on one device the line names the call a
+    trace then shows, ``%mla_prefill_attention``, before the tile (the
+    flash rule's at a group of one and a key of 192, which the call
+    takes by import); on a mesh the flash call runs and the line is as it
+    was."""
+    cfg = load_hf_config(
+        os.path.join(ROOT, "perfbench", "configs", "ax-k1-int8-ep16-l12")
+    )
+    runner = types.SimpleNamespace(
+        mesh=_tpu_mesh(tp), sp_mode=False, cfg=cfg,
+        _logged_attn_buckets=set(),
+    )
+    with caplog.at_level(logging.INFO, logger="gpustack_tpu.engine.runner"):
+        assert ModelRunner.attn_impl_for(runner, 8192) == "flash"
+    assert [r.getMessage() for r in caplog.records] == [
+        f"prefill bucket 8192: attention impl flash, {call}"
+        f"{Tiles(1024, 1024, 2048, 4)}, {points} grid points a call"
+    ]

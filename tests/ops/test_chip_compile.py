@@ -738,10 +738,15 @@ def test_the_latent_decode_step_moves_no_cache(one_chip, layers):
 def test_the_latent_prefill_s_temporaries_leave_the_resident_model_room(one_chip):
     """The cell's 8,192 prefill program, grouped dispatch under the share
     of 12 experts (two layers: the scan's body is what takes the
-    temporaries, whatever the depth): it compiles (the flash call at 192 /
-    128, the grouped kernel in rounds) and its temporaries, 1.9 GB, leave
-    the 10.05 GB that stay resident at 12 layers (tree and cache) room on
-    a 16 GB chip."""
+    temporaries, whatever the depth): it compiles (the latent's own
+    prefill call, the grouped kernel in rounds) and its temporaries, 1.75
+    GB, leave the 10.05 GB that stay resident at 12 layers (tree and
+    cache) room on a 16 GB chip. Round the call nothing a head wide is
+    made or laid out again (PR 62; until then 6.0 ms a layer of the 8,192
+    program): no key built out to 64 heads of 192, no transpose, copy,
+    pad or slice of a query, a key, a value or the result; the call reads
+    the three products as they come, ``[1, 8192, 64 * 192]`` and twice
+    ``[1, 8192, 64 * 128]``, and ``wo`` reads its result as it lies."""
     from gpustack_tpu.models.transformer import KVCache, forward
 
     bucket = 8192
@@ -761,8 +766,73 @@ def test_the_latent_prefill_s_temporaries_leave_the_resident_model_room(one_chip
         shapes, jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=one_chip)
     ).compile()
     text = compiled.as_text()
-    assert "moe_grouped_matmul" in text and "flash_attention_prefill" in text
+    assert "moe_grouped_matmul" in text
+    assert "flash_attention_prefill" not in text
+    calls = re.findall(
+        r"%mla_prefill_attention[\w.\-]* = bf16\[1,8192,8192\][^ ]* "
+        r"custom-call\(([^)]*)\)", text,
+    )
+    assert len(calls) == 2, calls       # a layer, both written out
+    by_head = [
+        line.strip()[:120] for line in text.splitlines()
+        if re.search(r"= \(?bf16\[1,(8192,64|64,8192),\d+\]", line)
+    ]
+    assert by_head == []
+    for operands in calls:
+        # the query, k_nope and v: each the output of its product's own
+        # fusion (the scale of an int8 weight rides it), nothing between
+        made_by = [
+            re.search(
+                rf"{re.escape(name)} = bf16\[1,8192,\d+\][^ ]* (\w[\w\-]*)\(",
+                text,
+            ).group(1)
+            for name in re.findall(r"%[\w.\-]+", operands)
+            if re.search(
+                rf"{re.escape(name)} = bf16\[1,8192,(12288|8192)\]", text
+            )
+        ]
+        assert made_by == ["fusion"] * 3, (operands, made_by)
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
+@pytest.mark.parametrize("T", [8192, 4096])
+def test_the_latent_s_prefill_call_compiles_for_v5e(one_chip, T):
+    """``mla_prefill_attention`` at the long-document cell's two buckets,
+    A.X-K1's 64 heads of 128 + 64 / 128, operands token-major as the
+    projections make them: the tile is the flash rule's at a group of one
+    and a key of 192 (1,024 query rows against 2,048 keys), the VMEM the
+    call asks for is granted, the result is ``[1, T, 64 * 128]`` and
+    nothing but the rotation's two small tables is made beside it."""
+    from gpustack_tpu.ops.flash_attention import Tiles, choose_tiles
+    from gpustack_tpu.ops.mla_attention import (
+        mla_prefill_attention,
+        mla_prefill_takes,
+    )
+
+    H, nope, rope, vd = 64, 128, 64, 128
+    assert mla_prefill_takes(H, nope, rope, vd)
+    assert choose_tiles(T, T, 1, nope + rope, 2) == Tiles(1024, 1024, 2048, 4)
+
+    def on(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    compiled = jax.jit(
+        lambda *operands: mla_prefill_attention(*operands, 0.1)
+    ).lower(
+        on(bf16, 1, T, H * (nope + rope)), on(bf16, 1, T, H * nope),
+        on(bf16, 1, T, rope), on(bf16, 1, T, H * vd),
+        on(f32, 1, T, rope // 2), on(f32, 1, T, rope // 2),
+    ).compile()
+    text = compiled.as_text()
+    assert re.search(
+        rf"%mla_prefill_attention[\w.\-]* = bf16\[1,{T},{H * vd}\]"
+        r".* custom-call\(",
+        text,
+    )
+    wide = re.findall(rf"= bf16\[1,{T},\d\d\d\d+\][^ ]* ([\w\-]+)\(", text)
+    assert sorted(wide) == ["custom-call"] + ["parameter"] * 3, wide
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e6
 
 
 @pytest.mark.parametrize(

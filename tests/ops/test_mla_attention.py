@@ -1,5 +1,7 @@
-"""The absorbed decode attention kernel (ops/mla_attention.py) in
-interpret mode against the XLA formulation ``forward`` keeps beside it."""
+"""The latent's kernels (ops/mla_attention.py) in interpret mode: the
+absorbed decode attention against the XLA formulation ``forward`` keeps
+beside it, the two writes against ``_write_rows``, and the decompressed
+prefill's call against ``_attend`` over the keys built out."""
 
 import jax
 import jax.numpy as jnp
@@ -9,6 +11,8 @@ import pytest
 from gpustack_tpu.ops.mla_attention import (
     block_positions,
     mla_decode_attention,
+    mla_prefill_attention,
+    mla_prefill_takes,
     mla_write_latent_rows,
     mla_write_rope_keys,
 )
@@ -332,3 +336,176 @@ def test_a_decode_step_of_a_two_layer_model_writes_the_xla_step_s_cache(
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), atol=2e-4, rtol=2e-4
     )
+
+
+# ---- the decompressed prefill's call ----
+
+# (heads, nope, rope, value): A.X-K1's widths (two heads' queries are three
+# lane tiles) and another latent's (four heads' are five; a value of two)
+AXK1_WIDTHS, OTHER_WIDTHS = (4, 128, 64, 128), (4, 128, 32, 256)
+
+
+def _prefill_tiles(T: int):
+    """Every (block_q, block_k) the rule offers a group of one at ``T``
+    rows padded to 128s."""
+    from gpustack_tpu.ops.flash_attention import candidate_tiles
+
+    rows = -(-T // 128) * 128
+    return [(t.block_q, t.block_k) for t in candidate_tiles(rows, rows, 1)]
+
+
+def _prefill_operands(widths, B, T, dtype):
+    from gpustack_tpu.models.transformer import _inv_freq, rope_sin_cos
+
+    H, nope, rope, vd = widths
+    keys = jax.random.split(jax.random.key(T + rope), 4)
+    draw = lambda key, *shape: jax.random.normal(
+        key, shape, jnp.float32
+    ).astype(dtype)
+    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    return (
+        draw(keys[0], B, T, H, nope + rope), draw(keys[1], B, T, H * nope),
+        draw(keys[2], B, T, rope), draw(keys[3], B, T, H * vd),
+        *rope_sin_cos(positions, _inv_freq(10000.0, rope)),
+    )
+
+
+def _attend_over_built_out_keys(widths, q, k_nope, k_pe, v, sin, cos, scale):
+    """The parent's form: the query's rope part rotated outside, the keys
+    built out to ``nope + rope`` a head, ``_attend`` under a causal mask,
+    all in float32."""
+    from gpustack_tpu.models.transformer import (
+        _attend,
+        apply_rope_interleaved,
+    )
+
+    H, nope, rope, vd = widths
+    B, T = q.shape[:2]
+    q = jnp.concatenate(
+        [q[..., :nope], apply_rope_interleaved(q[..., nope:], sin, cos)], -1
+    ).astype(jnp.float32)
+    k = jnp.concatenate([
+        k_nope.reshape(B, T, H, nope),
+        jnp.broadcast_to(k_pe[:, :, None], (B, T, H, rope)),
+    ], -1).astype(jnp.float32)
+    mask = jnp.broadcast_to(jnp.tril(jnp.ones((T, T), bool)), (B, T, T))
+    return _attend(
+        q[:, :, :, None, :], k,
+        v.reshape(B, T, H, vd).astype(jnp.float32), mask, scale,
+    )
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=str)
+@pytest.mark.parametrize(
+    "widths,T,blocks",
+    [
+        (widths, T, blocks)
+        for widths in (AXK1_WIDTHS, OTHER_WIDTHS)
+        for T in (256, 200)         # a multiple of 128, and not
+        for blocks in _prefill_tiles(T)
+    ] + [
+        # the tile the cell's buckets take, 1,024 rows against 2,048 keys,
+        # and the one whose k-block is the shorter (two heads: three lane
+        # tiles of queries, both places of a head in them)
+        ((2, 128, 64, 128), 2048, None),
+        ((2, 128, 64, 128), 2048, (1024, 1024)),
+    ],
+    ids=lambda x: "x".join(map(str, x)) if isinstance(x, tuple) else str(x),
+)
+def test_the_prefill_call_is_attend_over_the_keys_built_out(
+    widths, T, blocks, dtype
+):
+    """``mla_prefill_attention`` on operands as the projections make
+    them against ``_attend`` over keys of ``nope + rope`` a head: every
+    tile the rule offers, rows that are no multiple of 128 (padded and
+    masked), two rows of a batch, the query's rotation done inside."""
+    operands = _prefill_operands(widths, 2 if T < 2048 else 1, T, dtype)
+    assert mla_prefill_takes(*widths)
+    got = mla_prefill_attention(
+        operands[0].reshape(*operands[0].shape[:2], -1), *operands[1:],
+        0.07, interpret=True, _blocks=blocks,
+    )
+    want = _attend_over_built_out_keys(widths, *operands, 0.07)
+    assert got.shape == want.shape and got.dtype == dtype
+    # bfloat16: the result's own rounding, and the rotation's (one
+    # rounding inside the call, one an operation outside on the CPU)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want), atol=tol, rtol=tol
+    )
+
+
+@pytest.mark.parametrize("widths", [
+    (64, 128, 64, 192),     # a value that is no whole lane tile
+    (64, 96, 64, 128),      # nor the no-position part
+    (64, 128, 48, 128),     # rope parts that fill no lane tile
+    (3, 128, 64, 128),      # an odd head left over
+])
+def test_widths_that_are_no_whole_lane_tiles_are_not_taken(widths):
+    H, nope, rope, vd = widths
+    assert not mla_prefill_takes(*widths)
+    with pytest.raises(ValueError, match="lane tiles"):
+        mla_prefill_attention(
+            jnp.zeros((1, 128, H * (nope + rope))),
+            jnp.zeros((1, 128, H * nope)), jnp.zeros((1, 128, rope)),
+            jnp.zeros((1, 128, H * vd)), jnp.zeros((1, 128, rope // 2)),
+            jnp.zeros((1, 128, rope // 2)), 0.1, interpret=True,
+        )
+
+
+@pytest.mark.parametrize("widths,takes", [
+    ((128, 64, 128), True),     # A.X-K1's: the call of the latent's own
+    ((16, 64, 16), False),      # the flash call over the keys built out
+])
+def test_a_two_layer_model_s_flash_prefill_is_its_xla_prefill(
+    monkeypatch, widths, takes
+):
+    """``forward(..., attn_impl="flash_interpret")`` of a two-layer
+    A.X-K1 from position 0 into a cache of its own length against
+    ``attn_impl="xla"``, to the tolerance
+    ``tests/models/test_transformer.py::test_prefill_flash_matches_xla``
+    holds a GQA model to; at widths that are whole lane tiles through
+    :func:`mla_prefill_attention`, once a layer, at others not."""
+    import dataclasses
+
+    from gpustack_tpu.models.config import config_from_hf
+    from gpustack_tpu.models.transformer import KVCache, forward, init_params
+    from gpustack_tpu.ops import mla_attention
+
+    nope, rope, vd = widths
+    cfg = dataclasses.replace(config_from_hf({
+        **MLA_HF, "qk_nope_head_dim": nope, "qk_rope_head_dim": rope,
+        "v_head_dim": vd,
+    }, "tiny-axk1"), dtype="float32")
+    params = init_params(cfg, jax.random.key(0), dtype=jnp.float32)
+    B, T = 1, 160   # no multiple of 128: the padding is masked
+    toks = jax.random.randint(jax.random.key(1), (B, T), 0, cfg.vocab_size)
+    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    took = []
+    real = mla_attention.mla_prefill_attention
+    monkeypatch.setattr(
+        mla_attention, "mla_prefill_attention",
+        lambda *a, **kw: took.append(kw) or real(*a, **kw),
+    )
+    want, want_cache = forward(
+        params, cfg, toks, positions, KVCache.create(cfg, B, T)
+    )
+    assert took == []
+    got, got_cache = forward(
+        params, cfg, toks, positions, KVCache.create(cfg, B, T),
+        attn_impl="flash_interpret",
+    )
+    assert took == ([{"interpret": True}] * 2 if takes else [])
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=0.1, atol=0.12
+    )
+    # in float32 the two forms are far closer than that tolerance
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3
+    )
+    # the first layer's rows precede its attention
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(got_cache, name)[0]),
+            np.asarray(getattr(want_cache, name)[0]),
+        )
