@@ -12,15 +12,25 @@ biggest serving wins to):
 1. admit: while a slot is free and requests wait → prefill (bucketed) +
    insert. The first sampled token is fed on-device (a device scalar
    into ``insert``), so admission dispatches N+1's prefill while N's
-   sample is still in flight.
+   sample is still in flight. (A model generated by diffusion over
+   blocks, ``cfg.diffusion_block``: the prefill is of the prompt's whole
+   blocks and yields no token; the prompt's tail goes into the slot as
+   the decided positions of its first block.)
 2. decode: one ``decode_step`` advances all active slots; sampled tokens
    are fetched ``pipeline_depth`` steps behind dispatch. When a lagged
    fetch reveals a slot finished, the speculatively dispatched steps
    for it are rolled back host-side (dropped + counted) and the slot is
-   re-tenanted cleanly.
+   re-tenanted cleanly. A step's yield is one token a slot, or 1 to
+   ``spec_tokens`` under speculation, or, for a diffusion model, whose
+   step is a pass over each slot's block (``runner.denoise_step``), **0
+   to ``L`` tokens a slot**: a token goes to its request once every
+   position before it is decided, so a pass hands on the newly decided
+   prefix of the block, each token a chunk of its own, in order.
 3. retire: EOS / max_tokens / capacity → free slot; detokenization and
    SSE stream writes ride a dedicated worker thread so tokenizer calls
-   and client queues never stall dispatch.
+   and client queues never stall dispatch. A diffusion model's stream
+   ends at exactly ``max_tokens`` or at the first EOS in order, whatever
+   the rest of the block holds.
 
 ``pipeline_depth=0`` is the serial reference mode (fetch + inline
 detok every step) — greedy outputs are bit-identical across modes; the
@@ -75,7 +85,7 @@ DISPATCH_SYNC_FREE = (
     "_deliver", "_emit_text", "_push", "_finish", "_flight_record",
     "_submit_kv_copy", "_store_finished_sequence", "_build_proposals",
     "_entry_ready", "_drain_ready", "_advance_one_shot",
-    "_flush_detok",
+    "_flush_detok", "_start_block_request", "_at_capacity", "_offload",
 )
 
 # guarded-by contract (analysis/rules/guarded_by.py): lock-guarded
@@ -95,7 +105,8 @@ _SCHEDULER_METHODS = (
     "_resolve_staged_prefix", "_plan_chunk_job", "_new_slot_info",
     "_emit_text", "_push", "_note_spec_dispatch", "_spec_safe",
     "_entry_ready", "_submit_kv_copy", "_draw", "_insert",
-    "_flush_freed",
+    "_flush_freed", "_start_block_request", "_process_passes",
+    "_at_capacity", "_offload",
 )
 
 GUARDED_BY = {
@@ -331,6 +342,15 @@ class _SlotInfo:
     # JSON mode). False: buffer_ids/text/emitted are owned by the detok
     # worker after handoff; the scheduler only appends token ids.
     sync_detok: bool = True
+    # generation by diffusion over blocks: how many of the current
+    # block's positions have been handed on (the prompt's tail counts:
+    # it is decided from the start), and for each position decided and
+    # not yet handed on, the numbers of the pass that decided it
+    # (logprob, [(id, logprob)])
+    block_sent: int = 0
+    block_lps: Dict[int, Tuple[float, list]] = dataclasses.field(
+        default_factory=dict
+    )
 
 
 class _DetokWorker:
@@ -508,11 +528,48 @@ def _refuse_what_moves_a_slot(
         (prefill_chunk > 0, "prefill_chunk: a chunk goes on from cached "
          f"rows (prefill_with_prefix): {beside.span}"),
     ]
+    _refuse_first_asked(f"{cfg.name} {beside.keeps}", asked)
+
+
+def _refuse_first_asked(model_is: str, asked) -> None:
+    """Raise for the first mechanism of ``asked`` (``(asked for, why
+    not)`` pairs) that a deployment asked for."""
     for on, why in asked:
         if on:
-            raise ValueError(
-                f"{cfg.name} {beside.keeps} and cannot be served with {why}"
-            )
+            raise ValueError(f"{model_is} and cannot be served with {why}")
+
+
+def _refuse_what_diffusion_does_not_serve(
+    cfg, speculative, host_kv_cache_mb, kv_spill_mb, kv_role, prefill_chunk
+) -> None:
+    """A model generated by diffusion over blocks (``cfg.diffusion_block``)
+    is served by the published order of work alone: a prefill of the
+    prompt's whole blocks from position 0, then passes. The first
+    mechanism asked for that would cut, store, move, go on from or
+    guess at a slot's rows by another order is refused here, at engine
+    start, by name (a mesh of several devices: ``ModelRunner``)."""
+    L = cfg.diffusion_block
+    if not L:
+        return
+    asked = [
+        (speculative, f"speculative={speculative!r}: a verify step checks "
+         "a draft against a causal model's next token, and a block pass "
+         "has none"),
+        (host_kv_cache_mb > 0, "host_kv_cache_mb: the prefix cache "
+         "(engine/kv_host_cache.py) goes on from cached rows, and its "
+         f"blocks are not cut at this model's blocks of {L}"),
+        (kv_spill_mb > 0, "kv_spill_mb: the spill tier (engine/kv_spill.py) "
+         "stores the prefix cache's blocks, which this model does not keep"),
+        (kv_role, f"kv_role={kv_role!r}: a KV handoff (engine/"
+         "kv_transfer.py) moves the prefix cache's blocks, which this "
+         "model does not keep"),
+        (prefill_chunk > 0, "prefill_chunk: a chunk goes on from cached "
+         "rows (prefill_with_prefix), which the block mask's prefill "
+         "does not: it runs from position 0"),
+    ]
+    _refuse_first_asked(
+        f"{cfg.name} is generated by diffusion over blocks of {L}", asked
+    )
 
 
 class LLMEngine:
@@ -545,6 +602,10 @@ class LLMEngine:
     ):
         self.cfg = cfg
         _refuse_what_moves_a_slot(
+            cfg, speculative, host_kv_cache_mb, kv_spill_mb, kv_role,
+            prefill_chunk,
+        )
+        _refuse_what_diffusion_does_not_serve(
             cfg, speculative, host_kv_cache_mb, kv_spill_mb, kv_role,
             prefill_chunk,
         )
@@ -660,6 +721,10 @@ class LLMEngine:
         self._step_full_rows = 0
         self._step_spec_proposed = 0
         self._step_spec_accepted = 0
+        # generation by diffusion over blocks: of the passes the step
+        # fetched, the live slots' denoise passes, their commit passes
+        # (as many blocks done) and the positions they decided
+        self._step_passes = [0, 0, 0]
         # on-demand profiler capture (capture_profile): the capturing
         # thread starts and stops the jax.profiler trace; the scheduler
         # only counts the armed capture's steps down
@@ -831,6 +896,18 @@ class LLMEngine:
                 f"prompt of {len(req.prompt_ids)} tokens >= max_seq_len "
                 f"{self.max_seq_len}"
             )
+        if self.cfg.diffusion_block:
+            if req.embeds_override is not None:
+                raise ValueError(
+                    "image inputs are unavailable for a model generated "
+                    "by diffusion over blocks"
+                )
+            if self._at_capacity(len(req.prompt_ids)):
+                raise ValueError(
+                    f"prompt of {len(req.prompt_ids)} tokens: the block "
+                    f"of {self.cfg.diffusion_block} its first token lies "
+                    f"in passes max_seq_len {self.max_seq_len}"
+                )
         # enqueue + notify under one lock so a submit can never slip
         # between the scheduler's emptiness check and its cv wait (the
         # classic lost wakeup)
@@ -984,6 +1061,19 @@ class LLMEngine:
             # the live rows chose, "dense" every held one; None for a
             # model without experts
             "decode_moe_dispatch": self.runner.decode_moe_dispatch,
+            # generation by diffusion over blocks: the block's length,
+            # the passes a block of undecided positions takes at least,
+            # the rule that says which a pass decides, and the totals
+            # (slot-passes by kind, blocks done, positions decided);
+            # None for any other model
+            "diffusion": (
+                {
+                    "block": self.cfg.diffusion_block,
+                    "steps": self.cfg.denoising_steps,
+                    "rule": self.cfg.remasking_strategy,
+                    **self.flight.diffusion_totals(),
+                } if self.cfg.diffusion_block else None
+            ),
             "flight_overhead_ratio": round(
                 self.flight.overhead_ratio(), 6
             ),
@@ -1181,6 +1271,7 @@ class LLMEngine:
         self._step_window_rows = self._step_full_rows = 0
         self._step_moe_read = self._step_moe_held = 0
         self._step_spec_proposed = self._step_spec_accepted = 0
+        self._step_passes = [0, 0, 0]
         self._step_admitted = []
         self._step_first = []
         # Eager-ready drain BEFORE admission: fetch whatever the device
@@ -1209,7 +1300,10 @@ class LLMEngine:
         # complete deterministically.
         with phases.drain:
             self._drain_pending()
-        if self._step_out or self._step_spec_accepted or self._step_first:
+        if (
+            self._step_out or self._step_spec_accepted or self._step_first
+            or any(self._step_passes)
+        ):
             # tokens delivered by the drain would otherwise vanish when
             # the next step resets the accumulators — record them so
             # flight tokens_out/spec_accepted match tokens_generated
@@ -1305,6 +1399,10 @@ class LLMEngine:
             attn_rows=(
                 (self._step_window_rows, self._step_full_rows)
                 if self._window_bytes else None
+            ),
+            diffusion=(
+                tuple(self._step_passes) if self.cfg.diffusion_block
+                else None
             ),
         )
         if dur_s > _SLOW_STEP_S:
@@ -1711,7 +1809,45 @@ class LLMEngine:
             req.stream.put(None)
         req.done.set()
 
+    def _start_block_request(self, slot: int, req: GenRequest) -> None:
+        """Admission for a model generated by diffusion over blocks: the
+        prompt's whole blocks are prefilled under the block mask and
+        their rows kept; its tail goes into the slot as the decided
+        positions of the first block (``runner.insert_block``). No token
+        comes of a prefill: the first is the first block's passes'."""
+        ids = req.prompt_ids
+        L = self.cfg.diffusion_block
+        whole = len(ids) // L * L
+        bucket = self.runner.bucket_for(max(1, whole))
+        self._step_mode = self._step_mode or "prefill"
+        self._note_prefill(whole, bucket)
+        _, k, v = self.runner.prefill(
+            list(ids[:whole]) + [0] * (bucket - whole), whole
+        )
+        self._freed.discard(slot)
+        self._state = self.runner.insert_block(
+            self._state, k, v, slot, ids, req.temperature, req.top_k,
+            req.top_p, 0 if req.seed is None else int(req.seed) & 0xFFFFFFFF,
+            req.seed is not None, req.logit_bias,
+        )
+        info = self._new_slot_info(req)
+        info.block_sent = len(ids) - whole
+        self._slots[slot] = info
+
+    def _at_capacity(self, used: int) -> bool:
+        """Whether a sequence of ``used`` tokens can take no more: the
+        cache's last row but one for a causal model (a decode step feeds
+        the token before the one it yields); for a diffusion model the
+        block the next token lies in must fit whole."""
+        L = self.cfg.diffusion_block
+        if L:
+            return (used // L + 1) * L > self.max_seq_len
+        return used >= self.max_seq_len - 1
+
     def _start_request(self, slot: int, req: GenRequest) -> None:
+        if self.cfg.diffusion_block:
+            self._start_block_request(slot, req)
+            return
         ids = req.prompt_ids
         bucket = self.runner.bucket_for(max(1, len(ids)))
         padded = list(ids) + [0] * (bucket - len(ids))
@@ -2123,15 +2259,25 @@ class LLMEngine:
                 mask[list(self._freed)] = True
                 self._freed.clear()
                 freed = {"freed": mask}
-            self._state, out = self.runner.decode_step(
-                self._state, self._draw(), **freed
-            )
-            self._pending.append((("decode", out), owners))
+            # the model's own step over the cache: a token a slot, or a
+            # pass over each slot's block of ``rows`` positions
+            rows = self.runner.step_rows
+            if rows > 1:
+                self._state, out = self.runner.denoise_step(
+                    self._state, self._draw(), **freed
+                )
+                self._pending.append((("denoise", out), owners))
+                self._step_mode = self._step_mode or "denoise"
+            else:
+                self._state, out = self.runner.decode_step(
+                    self._state, self._draw(), **freed
+                )
+                self._pending.append((("decode", out), owners))
+                self._step_mode = self._step_mode or "decode"
             # decode runs every slot whether or not it is active: the
             # idle-slot share is the decode side of padding waste
-            self._step_mode = self._step_mode or "decode"
-            self._step_real += len(owners)
-            self._step_padded += self.max_slots
+            self._step_real += len(owners) * rows
+            self._step_padded += self.max_slots * rows
             # what the step's live slots attend of what the cache
             # allocates, from the scheduler's own counts (flight
             # ``kv_live_pct``)
@@ -2275,6 +2421,9 @@ class LLMEngine:
             self._deliver(slot, info, [first])
             self._flush_detok()
             return
+        if kind == "denoise":
+            self._process_passes(payload, owners)
+            return
         # the sync point (lagged): the one place a decode step's result
         # makes the scheduler's thread wait for the device
         with self._phases.wait:
@@ -2324,6 +2473,59 @@ class LLMEngine:
         # one detok queue entry
         self._flush_detok()
 
+    def _process_passes(self, payload, owners: Dict[int, str]) -> None:
+        """A block pass's result (``runner.denoise_step``), the third
+        kind of answer: each live slot's newly decided positions, of
+        which the ones with every position before them decided go to the
+        request, in order, each token on its own. A pass dispatched for
+        a slot whose request has ended since is dropped, as a decode
+        step's token is."""
+        with self._phases.wait:
+            (tokens, decided, committed, tok_lp, top_ids, top_lps,
+             experts_read) = [np.asarray(x) for x in payload]
+            self._step_moe_read += int(experts_read)
+            self._step_moe_held += self._moe_held_a_step
+        L = tokens.shape[1]
+        passes = self._step_passes
+        for slot, owner_id in owners.items():
+            info = self._slots.get(slot)
+            if info is None or info.request.request_id != owner_id:
+                n = int(decided[slot].sum()) if not committed[slot] else 0
+                if n > 0:
+                    self.flight.note_rollback(n)
+                continue
+            if committed[slot]:
+                # the block's rows are kept; the next one begins
+                passes[1] += 1
+                info.block_sent = 0
+                info.block_lps.clear()
+                continue
+            passes[0] += 1
+            known = info.block_lps
+            want_lps = info.request.logprobs
+            for i in range(info.block_sent, L):
+                if decided[slot, i] and i not in known:
+                    passes[2] += 1
+                    known[i] = (
+                        float(tok_lp[slot, i]),
+                        [
+                            (int(t), float(lp)) for t, lp in
+                            zip(top_ids[slot, i], top_lps[slot, i])
+                        ],
+                    ) if want_lps else None
+            first = info.block_sent
+            upto = first
+            while upto < L and decided[slot, upto]:
+                upto += 1
+            if upto > first:
+                info.block_sent = upto
+                self._deliver(
+                    slot, info, [int(t) for t in tokens[slot, first:upto]],
+                    [known[i] for i in range(first, upto)]
+                    if want_lps else None,
+                )
+        self._flush_detok()
+
     def _deliver(
         self, slot: int, info: _SlotInfo, toks: List[int], lps=None
     ) -> None:
@@ -2370,20 +2572,27 @@ class LLMEngine:
                         return
                 else:
                     offload.append(tok)
-            at_cap = (
+            at_cap = self._at_capacity(
                 len(req.prompt_ids) + len(req.output_ids)
-                >= self.max_seq_len - 1
             )
             if is_eos or at_cap or len(req.output_ids) >= req.max_tokens:
                 dropped = len(toks) - j - 1
                 if dropped:
                     self.flight.note_rollback(dropped)
-                if offload:
-                    self._detok_batch.append((info, offload))
+                self._offload(info, offload)
                 self._finish(slot, info, "stop" if is_eos else "length")
                 return
-        if offload:
-            self._detok_batch.append((info, offload))
+        self._offload(info, offload)
+
+    def _offload(self, info: _SlotInfo, toks: List[int]) -> None:
+        """``toks`` to the detok worker's next batch. The worker decodes
+        an entry's tokens as one piece; where a step may yield several
+        tokens a slot (a block pass) each goes as an entry of its own,
+        so that every token is its own content chunk."""
+        if self.cfg.diffusion_block:
+            self._detok_batch.extend((info, [t]) for t in toks)
+        elif toks:
+            self._detok_batch.append((info, toks))
 
     def _emit_text(self, info: _SlotInfo, final: bool) -> bool:
         """Advance incremental detokenization; stream newly-safe text.

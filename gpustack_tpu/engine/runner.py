@@ -38,7 +38,10 @@ from gpustack_tpu.engine.sampling import (
     MAX_BIAS,
     SamplingState,
     candidates_form,
+    decide,
+    pass_quota,
     sample,
+    sample_block,
 )
 from gpustack_tpu.models.config import ModelConfig
 from gpustack_tpu.models.quant import QuantW, quant_pspecs
@@ -152,15 +155,32 @@ class DecodeState:
     positions: jax.Array     # i32 [B] — next write position (== seq len)
     active: jax.Array        # bool [B]
     sampling: SamplingState
+    # Generation by diffusion over blocks (``cfg.diffusion_block``: L):
+    # ``positions`` is then the start of the block a slot is generating,
+    # a whole number of blocks, and beside it the slot keeps the block:
+    # its L token ids (the mask token's where undecided), which are
+    # decided, and how many denoise passes it has had. None for any
+    # other model (``last_tokens`` is then what a step feeds).
+    block_tokens: Optional[jax.Array] = None    # i32 [B, L]
+    block_decided: Optional[jax.Array] = None   # bool [B, L]
+    block_pass: Optional[jax.Array] = None      # i32 [B]
 
     @staticmethod
     def create(cfg: ModelConfig, batch: int, max_len: int) -> "DecodeState":
+        L = cfg.diffusion_block
         return DecodeState(
             cache=KVCache.create(cfg, batch, max_len),
             last_tokens=jnp.zeros((batch,), jnp.int32),
             positions=jnp.zeros((batch,), jnp.int32),
             active=jnp.zeros((batch,), jnp.bool_),
             sampling=SamplingState.create(batch),
+            **({
+                "block_tokens": jnp.full(
+                    (batch, L), cfg.mask_token_id, jnp.int32
+                ),
+                "block_decided": jnp.zeros((batch, L), jnp.bool_),
+                "block_pass": jnp.zeros((batch,), jnp.int32),
+            } if L else {}),
         )
 
 
@@ -195,6 +215,14 @@ class ModelRunner:
         # a slot of rows a position and nothing else shards; what a
         # model keeps beside them (``cfg.beside_rows``: KVCache holds it)
         # does not yet
+        if cfg.diffusion_block and self.mesh.size > 1:
+            raise ValueError(
+                f"{cfg.name} is generated by diffusion over blocks of "
+                f"{cfg.diffusion_block} and is served on one device: the "
+                "block pass, its decode kernel over the block's rows and "
+                "the touched experts' kernel are not sharded (tp/ep/dp/sp); "
+                f"got plan {self.plan}"
+            )
         beside = cfg.beside_rows
         if beside and self.mesh.size > 1:
             raise ValueError(
@@ -281,16 +309,18 @@ class ModelRunner:
         # how a decode step attends over the cache, as ``forward`` will
         # choose when the decode program is traced: ``kernel`` reads the
         # live slots' rows where they lie, ``xla`` every slot's slab
+        # (a diffusion model's step is a pass over a block's rows)
+        self.step_rows = cfg.diffusion_block or 1
         self.decode_attention = decode_attention_impl(
-            cfg, 1, max_seq_len, self.mesh.devices.flat[0].platform,
-            self.mesh,
+            cfg, self.step_rows, max_seq_len,
+            self.mesh.devices.flat[0].platform, self.mesh,
         )
         logger.info("decode attention: %s", self.decode_attention)
         # how a decode step enumerates its experts' products, chosen the
         # same way: ``touched`` reads the experts the live rows chose,
         # ``dense`` every held one; None without experts
         self.decode_moe_dispatch = self.moe_dispatch_for(
-            max_slots, decode=True
+            max_slots * self.step_rows, decode=True
         )
         if self.decode_moe_dispatch:
             logger.info("decode experts: %s", self.decode_moe_dispatch)
@@ -316,6 +346,9 @@ class ModelRunner:
             )
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
         self._decode_routing = None
+        self._denoise = jax.jit(self._denoise_impl, donate_argnums=(1,))
+        self._denoise_probe = None
+        self._insert_block = None
         self._none_freed = np.zeros((max_slots,), np.bool_)
         self._prefills: Dict[int, Any] = {}
         self._prefills_routing: Dict[int, Any] = {}
@@ -353,6 +386,11 @@ class ModelRunner:
                 sampling=SamplingState(
                     *([self._slot_sharding] * 7),
                 ),
+                **({
+                    "block_tokens": self._slot_sharding,
+                    "block_decided": self._slot_sharding,
+                    "block_pass": self._slot_sharding,
+                } if self.cfg.diffusion_block else {}),
             ),
         )
 
@@ -824,6 +862,148 @@ class ModelRunner:
         return fn(
             self.params, state, draw_words(key),
             self._none_freed if freed is None else freed,
+        )
+
+    # -- generation by diffusion over blocks ------------------------------
+
+    def _denoise_impl(self, params, state, key, freed, probe=False):
+        """One pass over every slot's block (``cfg.diffusion_block`` rows
+        a slot, at ``positions .. positions + L - 1``, over the cached
+        rows below and the block's own, written first).
+
+        A slot with an undecided position makes a **denoise pass**: a
+        candidate and its confidence for every undecided position
+        (``sampling.sample_block``), of which the rule decides some
+        (``sampling.decide``); the rows it wrote are provisional. A slot
+        with none undecided makes its **commit pass** in the same call:
+        the same rows over the block's final tokens, which are the rows
+        kept; its block then starts anew ``L`` further on, all mask, or
+        the slot goes off where that block would pass ``max_seq_len``.
+        So a pass yields 0 to ``L`` tokens a slot, and a block of ``L``
+        undecided positions under a rule that decides one a pass takes
+        ``L + 1``.
+
+        Returns ``(state', (tokens i32[B, L], decided bool[B, L],
+        committed bool[B], logprob f32[B, L], top_ids i32[B, L, TOPLP],
+        top_logprobs f32[B, L, TOPLP], experts read i32))``: the block
+        and which of it is decided after the pass (a committed slot's:
+        the block it committed), and for every position the candidate's
+        numbers of this pass, which are a token's own if this pass
+        decided it."""
+        cfg = self.cfg
+        L = cfg.diffusion_block
+        active = state.active & ~freed
+        decided = state.block_decided
+        tokens = jnp.where(decided, state.block_tokens, cfg.mask_token_id)
+        positions = (
+            state.positions[:, None] + jnp.arange(L, dtype=jnp.int32)[None]
+        )
+        logits, cache, n_read, *routing = forward(
+            params, cfg, tokens, positions, state.cache,
+            attn_impl="xla", mesh=self.mesh, live=active,
+            count_experts_read=True, routing_out=probe,
+        )
+        cand, tok_lp, top_ids, top_lps, conf = sample_block(
+            logits, state.sampling, jax.random.wrap_key_data(key),
+            positions, cfg.mask_token_id,
+        )
+        committed = jnp.all(decided, axis=1)
+        chosen = decide(
+            cfg.remasking_strategy, ~decided, conf,
+            pass_quota(state.block_pass, L, cfg.denoising_steps),
+            cfg.confidence_threshold,
+        )
+        tokens = jnp.where(chosen, cand, tokens)
+        decided = decided | chosen
+        # a committed block's rows stay; the next block, all mask, begins
+        # behind it, unless it would not fit the cache
+        full = state.positions + 2 * L > self.max_seq_len
+        advance = committed & active & ~full
+        return (
+            dataclasses.replace(
+                state,
+                cache=cache,
+                positions=jnp.where(
+                    advance, state.positions + L, state.positions
+                ),
+                active=active & ~(committed & full),
+                block_tokens=jnp.where(
+                    advance[:, None], cfg.mask_token_id, tokens
+                ),
+                block_decided=jnp.where(advance[:, None], False, decided),
+                block_pass=jnp.where(committed, 0, state.block_pass + 1),
+            ),
+            (tokens, decided, committed, tok_lp, top_ids, top_lps, n_read,
+             *((logits, *routing) if probe else ())),
+        )
+
+    def denoise_step(
+        self, state: DecodeState, key, freed=None, probe: bool = False
+    ):
+        """One block pass for all slots (:meth:`_denoise_impl`), the step
+        of a model generated by diffusion over blocks. ``key`` and
+        ``freed`` as :meth:`decode_step`'s. ``probe``: the same program
+        with two more outputs, the pass's logits ``[B, L, V]`` and its
+        routing (``forward``'s ``routing_out``), for a comparison with a
+        reference; the engine never asks."""
+        fn = self._denoise
+        if probe:
+            if self._denoise_probe is None:
+                impl = partial(self._denoise_impl, probe=True)
+                impl.__name__ = "_denoise_probe"
+                self._denoise_probe = jax.jit(impl, donate_argnums=(1,))
+            fn = self._denoise_probe
+        return fn(
+            self.params, state, draw_words(key),
+            self._none_freed if freed is None else freed,
+        )
+
+    def _insert_block_impl(
+        self, state, k, v, slot, start, tail, n_tail,
+        temperature, top_k, top_p, seed, seeded, bias_ids, bias_vals,
+    ):
+        cfg = self.cfg
+        held = jnp.arange(cfg.diffusion_block) < n_tail
+        return dataclasses.replace(
+            state,
+            cache=state.cache.with_slot(slot, k, v),
+            positions=state.positions.at[slot].set(start),
+            active=state.active.at[slot].set(True),
+            sampling=state.sampling.set_slot(
+                slot, temperature, top_k, top_p, seed, seeded,
+                bias_ids, bias_vals,
+            ),
+            block_tokens=state.block_tokens.at[slot].set(
+                jnp.where(held, tail, cfg.mask_token_id)
+            ),
+            block_decided=state.block_decided.at[slot].set(held),
+            block_pass=state.block_pass.at[slot].set(0),
+        )
+
+    def insert_block(
+        self, state: DecodeState, k, v, slot: int, prompt_ids,
+        temperature: float, top_k: int, top_p: float,
+        seed: int = 0, seeded: bool = False, logit_bias=None,
+    ) -> DecodeState:
+        """Place the rows of a prompt's whole blocks (``k, v``: what
+        :meth:`prefill` of its first ``len // L * L`` tokens returned) in
+        ``slot``, the prompt's tail as the decided positions of the first
+        block, and make the slot live. A prefill yields no token: the
+        first comes of the first block's passes."""
+        L = self.cfg.diffusion_block
+        n_tail = len(prompt_ids) % L
+        tail = np.zeros((L,), np.int32)
+        tail[:n_tail] = prompt_ids[len(prompt_ids) - n_tail:]
+        if self._insert_block is None:
+            self._insert_block = jax.jit(
+                self._insert_block_impl, donate_argnums=(0,)
+            )
+        bias_ids, bias_vals = bias_arrays(logit_bias)
+        return self._insert_block(
+            state, k, v, np.int32(slot), np.int32(len(prompt_ids) - n_tail),
+            tail, np.int32(n_tail), np.float32(temperature),
+            np.int32(top_k), np.float32(top_p), np.uint32(seed),
+            np.bool_(seeded), bias_ids, bias_vals,
         )
 
     def _sample_first_impl(

@@ -192,12 +192,17 @@ def sample(
     state: SamplingState,
     key: jax.Array,
     positions: jax.Array | None = None,  # i32 [B]; required for seeded rows
+    confidence: bool = False,
 ):
     """Sample one token per row honoring per-row temperature/top-k/top-p
     and per-row seeds.
 
     Returns ``(tokens i32[B], token_logprob f32[B], top_ids i32[B, TOPLP],
-    top_logprobs f32[B, TOPLP])``.
+    top_logprobs f32[B, TOPLP])``, and with ``confidence`` (static) a
+    fifth: the probability of the row's token under the distribution it
+    was drawn from (``f32[B]``: the candidates after temperature, top-k
+    and top-p; a greedy row's under temperature 1), what a diffusion
+    pass decides by (:func:`decide`).
     """
     B, V = logits.shape
     # logit_bias before ranking: scatter-add the sparse per-row biases
@@ -251,4 +256,76 @@ def sample(
     m = min(TOPLP, n)
     top_ids = top_idx[:, :m]
     top_logprobs = top_logits[:, :m] - lse[:, None]
-    return tokens, token_logprob, top_ids, top_logprobs
+    if not confidence:
+        return tokens, token_logprob, top_ids, top_logprobs
+    # (a greedy row's ``masked`` keeps its first candidate alone: the
+    # near-zero temperature puts all of top-p's mass on it)
+    drawn_from = jnp.where(
+        state.temperature[:, None] > 0, masked, top_logits
+    )
+    conf = jnp.take_along_axis(
+        jax.nn.softmax(drawn_from, axis=-1), choice[:, None], axis=1
+    )[:, 0]
+    return tokens, token_logprob, top_ids, top_logprobs, conf
+
+
+def sample_block(
+    logits: jax.Array,       # [B, L, V] f32: a diffusion block's rows
+    state: SamplingState,    # [B]: a slot's parameters serve its L rows
+    key: jax.Array,
+    positions: jax.Array,    # i32 [B, L]: the rows' absolute positions
+    mask_id: int,
+):
+    """:func:`sample` for every row of every slot's block: ``(candidates
+    i32[B, L], logprob f32[B, L], top_ids i32[B, L, TOPLP], top_logprobs
+    f32[B, L, TOPLP], confidence f32[B, L])``. The logits at position
+    ``i`` are for the token at ``i``. A seeded slot's draw is a function
+    of its seed and the row's absolute position, so an answer depends on
+    no neighbour and a position undecided in one pass draws the same
+    noise over the next pass's logits. The mask token is never a
+    candidate, whatever a ``logit_bias`` says of it."""
+    B, L, V = logits.shape
+    rows = jax.tree.map(lambda a: jnp.repeat(a, L, axis=0), state)
+    flat = logits.reshape(B * L, V).at[:, mask_id].set(-jnp.inf)
+    outs = sample(flat, rows, key, positions.reshape(-1), confidence=True)
+    return tuple(o.reshape(B, L, *o.shape[1:]) for o in outs)
+
+
+def pass_quota(pass_index: jax.Array, block: int, steps: int) -> jax.Array:
+    """How many positions the ``pass_index``-th denoise pass of a block
+    decides at least (the family's ``get_num_transfer_tokens``): ``block
+    // steps``, the remainder spread over the first passes."""
+    return block // steps + (pass_index < block % steps).astype(jnp.int32)
+
+
+def decide(
+    rule: str,               # one of models.config.DIFFUSION_RULES (static)
+    undecided: jax.Array,    # bool [B, L]
+    conf: jax.Array,         # f32 [B, L]: sample_block's confidence
+    quota: jax.Array,        # i32 [B]: pass_quota
+    threshold: float = 0.0,
+) -> jax.Array:
+    """Which of a block's undecided positions a denoise pass decides
+    (bool ``[B, L]``, never a decided one), by the published rules:
+    ``sequential`` the first ``quota`` undecided ones;
+    ``low_confidence_static`` the ``quota`` of largest confidence (the
+    lower index first among equals); ``low_confidence_dynamic`` every one
+    whose confidence is over ``threshold`` where those are at least
+    ``quota``, else as ``low_confidence_static``. A pass over a block
+    with fewer undecided positions than ``quota`` decides them all."""
+    quota = quota[:, None]
+    if rule == "sequential":
+        return undecided & (jnp.cumsum(undecided, axis=1) <= quota)
+    c = jnp.where(undecided, conf, -jnp.inf)
+    at = jnp.arange(c.shape[1])
+    ahead = (c[:, None, :] > c[:, :, None]) | (
+        (c[:, None, :] == c[:, :, None]) & (at[None, :] < at[:, None])
+    )                                       # [B, i, j]: j ranks before i
+    largest = undecided & (jnp.sum(ahead, axis=2) < quota)
+    if rule == "low_confidence_static":
+        return largest
+    if rule != "low_confidence_dynamic":
+        raise ValueError(f"remasking_strategy {rule!r}")
+    high = undecided & (conf > threshold)
+    enough = jnp.sum(high, axis=1, keepdims=True) >= quota
+    return jnp.where(enough, high, largest)

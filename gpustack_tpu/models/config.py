@@ -226,6 +226,21 @@ class ModelConfig:
     # sublayer's output inside the residual (x + norm(f(x))), not on its
     # input (x + f(norm(x)))
     norm_after: Tuple[str, ...] = ()
+    # Generation by diffusion over blocks (SDAR): with ``L`` this length,
+    # a query at position q sees the keys below ``(q // L + 1) * L``
+    # (causal over blocks, both ways inside one), the undecided positions
+    # of the block being generated hold ``mask_token_id`` and a step is a
+    # pass over the block's ``L`` rows that decides 0 to ``L`` of them
+    # (``engine/runner.py _denoise_impl``). 0: every other family, a
+    # causal mask and a token a step.
+    diffusion_block: int = 0
+    # how many of a block's positions a pass decides at least: ``L //
+    # steps``, the remainder spread over the first passes
+    denoising_steps: int = 0
+    # which ones: ``DIFFUSION_RULES`` (``engine/sampling.py decide``)
+    remasking_strategy: str = ""
+    confidence_threshold: float = 0.0
+    mask_token_id: int = -1
     dtype: str = "bfloat16"
 
     # ---- derived ----
@@ -524,6 +539,23 @@ class ModelConfig:
                 "an output gate on attention is read in a stack with "
                 "layer_types only"
             )
+        if self.diffusion_block:
+            L = self.diffusion_block
+            # the blocked kernels' tiles are of 128 rows: a block may
+            # not straddle one
+            assert L > 1 and 128 % L == 0, f"diffusion_block {L}"
+            assert 1 <= self.denoising_steps <= L, self.denoising_steps
+            assert self.remasking_strategy in DIFFUSION_RULES, (
+                self.remasking_strategy
+            )
+            assert 0 <= self.mask_token_id < self.vocab_size
+            # causal GQA layers with positions, and nothing a slot keeps
+            # beside its rows: the block's mask is made in ``forward``
+            assert not (
+                self.is_mla or self.sliding_window or self.attn_sinks
+                or self.attn_logit_softcap or self.layer_kinds
+                or self.layer_types
+            ), "generation by diffusion is read for a causal GQA stack"
         return self
 
     # ---- memory accounting (used by scheduler + engine sizing) ----
@@ -710,6 +742,15 @@ def _period(kinds: tuple) -> tuple:
     return ()
 
 
+# Which of a block's undecided positions a pass decides (the family's
+# published ``block_diffusion_generate``): the first ones, the ones of
+# largest confidence, or every one over a threshold and at least as many
+# as a pass must.
+DIFFUSION_RULES: Tuple[str, ...] = (
+    "sequential", "low_confidence_static", "low_confidence_dynamic",
+)
+
+
 # The families ``config_from_hf`` reads, by a substring of the file's
 # first ``architectures`` entry. A file that names an architecture of
 # none of them is refused by name: served as a Llama-class stack (what
@@ -720,7 +761,7 @@ def _period(kinds: tuple) -> tuple:
 FAMILIES: Tuple[str, ...] = (
     "Llama", "Mistral", "Mixtral", "Qwen2", "Qwen3", "Gemma", "GptOss",
     "Deepseek", "NemotronH", "Cohere2Moe", "OlmoHybrid", "GraniteMoeHybrid",
-    "SolarOpen2",
+    "SolarOpen2", "SDARMoe",
     # multimodal wrappers whose text stack is one of the above
     "Llava", "VLForConditionalGeneration",
 )
@@ -1116,11 +1157,59 @@ def _solar_open2_config(cfg: Dict[str, Any], name: str) -> ModelConfig:
     ).validate()
 
 
-def config_from_hf(cfg: Dict[str, Any], name: str = "custom") -> ModelConfig:
+def _sdar_moe_config(
+    cfg: Dict[str, Any], name: str, generation: Optional[Dict[str, Any]]
+) -> ModelConfig:
+    """SDAR-MoE (``model_type: sdar_moe``): Qwen3-MoE's layers to the
+    number, generated by diffusion over blocks. The file is read as a
+    Qwen3-MoE file; what differs is no key of it but which keys a query
+    sees and what a step is (:attr:`ModelConfig.diffusion_block`), and
+    those numbers are a deployment's generation defaults: ``generation``,
+    the ``generation_config.json`` beside the file where there is one
+    (``block_length``, ``denoising_steps``, ``remasking_strategy``,
+    ``confidence_threshold``, ``mask_token_id``), else the released Chat
+    checkpoints' (blocks of 4 in 4 steps, ``low_confidence_dynamic`` at
+    0.9, the family tokenizer's ``<|MASK|>``).
+
+    Refused by key, since no layer here does it: a sliding window, dense
+    layers among the experts' (``mlp_only_layers``, ``decoder_sparse_step``
+    other than 1)."""
+    for key, want in (
+        ("use_sliding_window", False), ("mlp_only_layers", []),
+        ("decoder_sparse_step", 1),
+    ):
+        if (cfg.get(key) or want) != want:
+            raise ValueError(
+                f"{key} {cfg[key]!r}: an sdar_moe stack is served with "
+                f"{want!r} only"
+            )
+    gen = generation or {}
+    base = config_from_hf(
+        {**cfg, "architectures": ["Qwen3MoeForCausalLM"],
+         "model_type": "qwen3_moe", "sliding_window": None}, name,
+    )
+    return dataclasses.replace(
+        base,
+        diffusion_block=int(gen.get("block_length", 4)),
+        denoising_steps=int(gen.get("denoising_steps", 4)),
+        remasking_strategy=str(
+            gen.get("remasking_strategy", "low_confidence_dynamic")
+        ),
+        confidence_threshold=float(gen.get("confidence_threshold", 0.9)),
+        mask_token_id=int(gen.get("mask_token_id", 151669)),
+    ).validate()
+
+
+def config_from_hf(
+    cfg: Dict[str, Any], name: str = "custom",
+    generation: Optional[Dict[str, Any]] = None,
+) -> ModelConfig:
     """Build a ModelConfig from an HF ``config.json`` dict of one of
     :data:`FAMILIES` (the reference's selectors introspect the same
     keys, base_candidate_selector.py:56-165). An architecture of no
-    family listed is refused by name."""
+    family listed is refused by name. ``generation``: the directory's
+    ``generation_config.json``, for the one family whose reader takes
+    its numbers from it (:func:`_sdar_moe_config`)."""
     archs = cfg.get("architectures") or [""]
     arch = archs[0] if archs else ""
     if arch and not any(f in arch for f in FAMILIES):
@@ -1142,6 +1231,8 @@ def config_from_hf(cfg: Dict[str, Any], name: str = "custom") -> ModelConfig:
         return _granite_hybrid_config(cfg, name)
     if "SolarOpen2" in arch or cfg.get("model_type") == "solar_open2":
         return _solar_open2_config(cfg, name)
+    if "SDARMoe" in arch or cfg.get("model_type") == "sdar_moe":
+        return _sdar_moe_config(cfg, name, generation)
     hidden = cfg["hidden_size"]
     heads = cfg["num_attention_heads"]
     head_dim = cfg.get("head_dim") or hidden // heads
@@ -1311,7 +1402,17 @@ def load_hf_config(path: str, name: str = "") -> ModelConfig:
     """Read ``config.json`` from a local HF model directory."""
     with open(os.path.join(path, "config.json")) as f:
         cfg = json.load(f)
-    return config_from_hf(cfg, name=name or os.path.basename(path.rstrip("/")))
+    generation = None
+    beside = os.path.join(path, "generation_config.json")
+    if cfg.get("model_type") == "sdar_moe" and os.path.exists(beside):
+        # the hub's place for a model's generation defaults; only this
+        # family's reader takes numbers from it
+        with open(beside) as f:
+            generation = json.load(f)
+    return config_from_hf(
+        cfg, name=name or os.path.basename(path.rstrip("/")),
+        generation=generation,
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -937,13 +937,17 @@ def attend_over_cache(
     index, start,        # the layer's place in it; [B] where a row's keys go
     *, positions, mask, scale, decode_attn_impl, walk=None,
     attn_impl="xla", mesh=None, softcap=0.0, sinks=None, name=None,
+    block=0,
 ):
     """One GQA layer over its store in a cache, from where the families
     agree: the step's rows are written at ``start``, then the step
     attends: ``(attn [B, T, H * hd], buf_k, buf_v)``. By the decode
     kernel over the store where it lies as far as ``walk`` says
     (``decode_attn_impl`` not ``"xla"``; ``name``: the call's in a
-    trace), else over the layer's rows by ``attn_impl``: ``"ring"``
+    trace; the ``T`` rows of a diffusion block, ``block``, see one key
+    set, every row below ``start + T``, and go in as ``T x G`` query
+    rows of their kv head: :func:`block_rows_as_heads`), else over the
+    layer's rows by ``attn_impl``: ``"ring"``
     (``sp``: a cache sharded over its positions), the flash kernel for
     several rows a slot over a cache that holds them, or ``_attend``
     under ``mask [B, T, S]``. Projection, biases, norms and rotation are
@@ -972,7 +976,10 @@ def attend_over_cache(
         # where they lie and no slab is taken out of the carry
         from gpustack_tpu.ops.decode_attention import gqa_decode_attention
 
-        q = q[:, 0] if q.ndim == 4 else q.reshape(B, -1, hd)
+        if block:
+            q = block_rows_as_heads(q)
+        else:
+            q = q[:, 0] if q.ndim == 4 else q.reshape(B, -1, hd)
         if side > 1:
             q, own = queries_for_rows_of(side, q, Hkv)
         attn = gqa_decode_attention(
@@ -980,6 +987,8 @@ def attend_over_cache(
             interpret=decode_attn_impl == "kernel_interpret",
             **({"name": name} if name else {}),
         )[:, None]
+        if block:
+            attn = block_rows_as_heads(attn[:, 0], back=(T, Hkv))
         if side > 1:
             # of a stored row's values a head keeps its own head's
             attn = jnp.sum(
@@ -1014,13 +1023,26 @@ def attend_over_cache(
         # above the last query's position are causally invisible
         attn = _flash_prefill(mesh, attn_impl)(
             q.reshape(B, T, -1, hd), all_k, all_v, scale,
-            q_offset=positions[0, 0],
+            q_offset=positions[0, 0], **({"block": block} if block else {}),
         )
     else:
         attn = _attend(
             grouped, all_k, all_v, mask, scale, softcap, sinks=sinks
         )
     return attn, buf_k, buf_v
+
+
+def block_rows_as_heads(x: jax.Array, back=None) -> jax.Array:
+    """A diffusion block's query rows as the decode kernel takes them:
+    ``[B, T, Hkv, G, hd]`` to ``[B, Hkv * T * G, hd]``, a kv head's ``T x
+    G`` rows together (the kernel's query head ``h`` is of kv head ``h //
+    (Hq / Hkv)``); with ``back = (T, Hkv)`` the kernel's ``[B, Hq * hd]``
+    to the layer's ``[B, T, Hkv * G * hd]``."""
+    B = x.shape[0]
+    if back is None:
+        return x.transpose(0, 2, 1, 3, 4).reshape(B, -1, x.shape[-1])
+    T, Hkv = back
+    return x.reshape(B, Hkv, T, -1).transpose(0, 2, 1, 3).reshape(B, T, -1)
 
 
 def queries_for_rows_of(side: int, q: jax.Array, kv_heads: int):
@@ -1302,11 +1324,12 @@ def _experts_touched(
     x, top_idx, top_w, we_gate, we_up, we_down, cfg, live, interpret,
     layer=None,
 ):
-    """A decode step's rows (``T == 1``) against the held experts its
-    live rows chose and no others (``ops/grouped_matmul.py
-    touched_experts``): ``(out [B, 1, D], experts read int32)``.
+    """A step's rows over the cache (one a slot, or a diffusion block's
+    ``T`` a slot: ``B * T`` rows) against the held experts its live rows
+    chose and no others (``ops/grouped_matmul.py touched_experts``):
+    ``(out [B, T, D], experts read int32)``.
 
-    The ``B`` rows are one tile, so nothing is laid out: every touched
+    The rows are one tile, so nothing is laid out: every touched
     expert meets all of them, and ``combine [B, E_held]`` (float32, as
     the grouped prefill weighs) is zero where a row did not choose the
     expert, where the expert is not held, and all along a row nobody
@@ -1317,8 +1340,17 @@ def _experts_touched(
     from gpustack_tpu.ops.grouped_matmul import touched_experts
 
     B, T, D = x.shape
-    if T != 1:
-        raise ValueError(f"dispatch 'touched' is a decode step's: T={T}")
+    if not steps_over_cache(cfg, T):
+        raise ValueError(
+            f"dispatch 'touched' is a step's over the cache: T={T}"
+        )
+    if T > 1:
+        # a block's rows as so many slots' (a reshape of nothing at T=1,
+        # and no operation of a decode program's text)
+        x, top_idx, top_w = (
+            a.reshape(B * T, 1, -1) for a in (x, top_idx, top_w)
+        )
+        live = None if live is None else jnp.repeat(live, T)
     Eh = cfg.num_held_experts
     held = jnp.arange(Eh, dtype=jnp.int32)
     chosen = (
@@ -1334,7 +1366,7 @@ def _experts_touched(
     n_touched = jnp.sum(touched, dtype=jnp.int32)
     # the touched ids first, ascending; the rest name the last expert
     ids = lax.sort(jnp.where(touched, held, Eh - 1))
-    ids = ids[: min(Eh, B * cfg.num_experts_per_tok)]
+    ids = ids[: min(Eh, B * T * cfg.num_experts_per_tok)]
     # the plain form (cfg.moe_act "relu2") has no gate: None all along
     weights, scales = zip(*(
         (w.q, w.s) if isinstance(w, QuantW) else (w, None)
@@ -1345,7 +1377,8 @@ def _experts_touched(
         scales if scales[1] is not None else None, layer,
         interpret=interpret,
     )
-    return out.astype(x.dtype)[:, None], n_touched
+    out = out.astype(x.dtype)
+    return (out[:, None] if T == 1 else out.reshape(B, T, D)), n_touched
 
 
 def _moe_mlp(
@@ -1495,14 +1528,20 @@ def moe_dispatch(
 ) -> str:
     """How a program of ``rows`` tokens enumerates its experts' products
     (``_moe_mlp``): the one place that decides, from what ``forward`` can
-    observe. ``decode``: the rows are one a slot over a cache.
+    observe. ``decode``: the rows are a step's over a cache
+    (:func:`steps_over_cache`: one a slot, or a diffusion block's ``L``
+    a slot).
 
-    On one TPU chip: ``"touched"`` for a decode step of a model the
-    kernel takes (no expert biases; silu, or the plain relu2): it reads the
-    experts the live rows chose, at most what the dense form reads, so
-    no row count chooses between them; ``"grouped"`` where the rows fill
-    the groups. ``"dense"`` otherwise: a verify, ingest, chunked or
-    prefix step's few rows a slot; a mesh of more than one device (the
+    On one TPU chip: ``"touched"`` for a decode step, and for a
+    diffusion model's block pass, of a model the kernel takes (no expert
+    biases; silu, or the plain relu2): it reads the experts the live
+    rows chose, at most what the dense form reads, so no row count
+    chooses between them (at 32 slots a block pass is 128 rows, 8 pairs
+    an expert on the average: under ``GROUPED_MIN_FILL``, and the dense
+    form would compute 128 rows x 128 experts a layer); ``"grouped"``
+    where the rows fill the groups. ``"dense"`` otherwise: a verify,
+    ingest, chunked or prefix step's few rows a slot; a mesh of more
+    than one device (the
     dense einsum is what GSPMD partitions over ``ep`` / ``tp`` /
     ``fsdp``, and the kernels are not wrapped in a ``shard_map``); any
     other platform (the compiled kernels exist only for the TPU, as the
@@ -1534,7 +1573,10 @@ def decode_attention_impl(
     for a decode step on one TPU chip whose cache divides into the
     kernel's blocks: one token a slot, each block of cached positions
     read where it lies, once for all heads and only as far as the
-    slot's length. ``"xla"`` otherwise: a verify step, an ingest or a
+    slot's length; and for a diffusion model's block pass
+    (``cfg.diffusion_block`` rows a slot: they see one key set, every
+    row below the block's end, and go in as so many more query rows of
+    their kv head). ``"xla"`` otherwise: a verify step, an ingest or a
     continuation (several rows a slot), a mesh of more than one device
     (the kernels are not wrapped in a ``shard_map``), any other
     platform, and for a GQA cache a model whose scores only the einsum
@@ -1563,7 +1605,16 @@ def decode_attention_impl(
             # the ring of a sliding layer is walked by the same kernel
             block = None
     one_chip = platform == "tpu" and (mesh is None or mesh.size == 1)
-    return "kernel" if one_chip and rows == 1 and block is not None else "xla"
+    a_step = steps_over_cache(cfg, rows)
+    return "kernel" if one_chip and a_step and block is not None else "xla"
+
+
+def steps_over_cache(cfg: ModelConfig, rows: int) -> bool:
+    """Whether ``rows`` tokens a slot over a cache are the model's own
+    step, the one its decode kernels serve: one row, or a diffusion
+    block's (``cfg.diffusion_block``; 0, and never a row count, for any
+    other model)."""
+    return rows == 1 or 1 < rows == cfg.diffusion_block
 
 
 def heads_of_zeros_behind(n: int, *arrays: jax.Array):
@@ -1712,7 +1763,8 @@ def forward(
     )
     if cfg.is_moe and moe_dispatch_impl is None:
         moe_dispatch_impl = moe_dispatch(
-            B * T, cfg, platform, mesh, decode=cache is not None and T == 1
+            B * T, cfg, platform, mesh,
+            decode=cache is not None and steps_over_cache(cfg, T),
         )
     if cache is not None and decode_attn_impl is None:
         decode_attn_impl = decode_attention_impl(
@@ -1735,7 +1787,7 @@ def forward(
         )
     walk = walk_w = None
     if cache is not None and decode_attn_impl != "xla":
-        lengths = positions[:, 0] + 1
+        lengths = positions[:, 0] + T    # a block's rows see its end
         if live is not None:
             lengths = jnp.where(live, lengths, 0)
         # the kernel's walk over the slots, once a step: inside the scan
@@ -1851,13 +1903,24 @@ def forward(
         )
 
     # mask[b, t, s] — query t attends key s
+    over_cache = attend_over_cache
+    if cfg.diffusion_block:
+        # causal over blocks, both ways inside one: a query sees as far
+        # as the last position of its block (the one place the mask is
+        # made; the kernels are told the block's length)
+        sees = positions - positions % cfg.diffusion_block + (
+            cfg.diffusion_block - 1
+        )
+        over_cache = partial(attend_over_cache, block=cfg.diffusion_block)
+    else:
+        sees = positions
     if cache is None:
-        causal = positions[:, :, None] >= positions[:, None, :]
+        causal = sees[:, :, None] >= positions[:, None, :]
         delta = positions[:, :, None] - positions[:, None, :]
     else:
         S = cache.max_len
         cache_pos = jnp.arange(S, dtype=jnp.int32)
-        causal = cache_pos[None, None, :] <= positions[:, :, None]
+        causal = cache_pos[None, None, :] <= sees[:, :, None]
         delta = positions[:, :, None] - cache_pos[None, None, :]
     if hetero:
         # gemma-style alternating layers: both masks exist, each layer
@@ -2170,7 +2233,7 @@ def forward(
                     sinks=sinks_l,
                 )
             else:
-                attn, new_k, new_v = attend_over_cache(
+                attn, new_k, new_v = over_cache(
                     q, k, v, carried.k, carried.v, store, positions[:, 0],
                     positions=positions, mask=mask_l, scale=scale,
                     decode_attn_impl=decode_attn_impl, walk=walk,

@@ -105,6 +105,18 @@ Record vocabulary (per step):
   ``min(length, window)`` and ``length`` a slot a layer; a prefill the
   band's and the triangle's). Absent for any other model.
 
+- ``passes_denoise``, ``passes_commit``, ``tokens_decided``,
+  ``blocks_done`` — for a model generated by diffusion over blocks
+  (``/healthz`` ``diffusion``), whose step (``mode: denoise``) is one
+  program over every slot's block of ``L`` rows: of the passes whose
+  results the step fetched, the live slots' that denoised (decided 0 to
+  ``L`` positions each, ``tokens_decided`` in all), the ones that
+  committed a block's final rows, and the blocks so completed (one a
+  commit pass). ``tokens_real`` is then ``L`` rows a live slot a pass
+  and ``tokens_out`` the tokens handed on to requests, 0 to ``L`` a
+  slot: a decided position waits for every position before it. Absent
+  for any other model.
+
 Cumulative (not per-record): ``idle_wait_s_total`` — seconds the
 scheduler parked on its wakeup condition instead of busy-polling (the
 old 2 ms sleep loop, measured as saved spin); ``rollback_tokens_total``
@@ -335,6 +347,7 @@ GUARDED_BY = {
     "_ssm_tokens_total": "_mu",
     "_state_mixer": "_mu",
     "_attn_rows_total": "_mu",
+    "_diffusion_total": "_mu",
     "_spec_proposed_total": "_mu",
     "_spec_accepted_total": "_mu",
     "_last_slots_used": "_mu",
@@ -412,6 +425,10 @@ class FlightRecorder:
         # cached rows attended by kind of layer (a stack with a window
         # store); None until such a step is recorded
         self._attn_rows_total: Optional[Dict[str, int]] = None
+        # generation by diffusion over blocks: the live slots' passes by
+        # kind, the blocks they completed and the positions they
+        # decided; None until such a step is recorded
+        self._diffusion_total: Optional[Dict[str, int]] = None
         self._spec_proposed_total = 0
         self._spec_accepted_total = 0
         self._last_slots_used = 0
@@ -466,6 +483,8 @@ class FlightRecorder:
         # (state_slots, ssm_tokens, the mixer's kind)
         ssm: Optional[Sequence[Any]] = None,
         attn_rows: Optional[Sequence[int]] = None,  # (window_rows, full_rows)
+        # (slot-passes that denoised, that committed, positions decided)
+        diffusion: Optional[Sequence[int]] = None,
     ) -> Optional[Sequence[List[Any]]]:
         """Returns the step's ``programs`` (None in a steady step)."""
         t0 = time.perf_counter()
@@ -485,7 +504,7 @@ class FlightRecorder:
             kv_reused_total, host_overlap_s, phases_s, admitted,
             first_tokens, traced, compiled, moe_dispatch, attn,
             kv_live, kv_allocated, moe_read, moe_held, programs, ssm,
-            attn_rows, cpu_s,
+            attn_rows, cpu_s, diffusion,
         )
         with self._mu:
             if self._unfolded >= self._fold_at:
@@ -511,7 +530,8 @@ class FlightRecorder:
              spec_proposed, spec_accepted, kv_blocks, _kv_reused,
              host_overlap_s, _phases, _admitted, _first, _traced,
              _compiled, moe_dispatch, _attn, kv_live, kv_allocated,
-             moe_read, moe_held, _programs, ssm, attn_rows, _cpu) = row
+             moe_read, moe_held, _programs, ssm, attn_rows, _cpu,
+             diffusion) = row
             h = self._hist.get(mode)
             if h is None:
                 h = self._hist[mode] = [
@@ -549,6 +569,16 @@ class FlightRecorder:
                     }
                 totals["sliding"] += attn_rows[0]
                 totals["full"] += attn_rows[1]
+            if diffusion is not None:
+                totals = self._diffusion_total
+                if totals is None:
+                    totals = self._diffusion_total = {
+                        "passes_denoise": 0, "passes_commit": 0,
+                        "tokens_decided": 0,
+                    }
+                totals["passes_denoise"] += diffusion[0]
+                totals["passes_commit"] += diffusion[1]
+                totals["tokens_decided"] += diffusion[2]
             self._spec_proposed_total += spec_proposed
             self._spec_accepted_total += spec_accepted
             self._host_overlap_s_total += host_overlap_s
@@ -592,7 +622,8 @@ class FlightRecorder:
          spec_proposed, spec_accepted, kv_blocks, kv_reused_total,
          host_overlap_s, phases_s, admitted, first_tokens, traced,
          compiled, moe_dispatch, attn, kv_live, kv_allocated,
-         moe_read, moe_held, programs, ssm, attn_rows, cpu_s) = row
+         moe_read, moe_held, programs, ssm, attn_rows, cpu_s,
+         diffusion) = row
         entry = {
             "ts": ts,
             "dur_ms": round(dur_s * 1e3, 4),
@@ -639,6 +670,11 @@ class FlightRecorder:
              entry["state_mixer"]) = ssm
         if attn_rows is not None:
             entry["window_rows"], entry["full_rows"] = attn_rows
+        if diffusion is not None:
+            (entry["passes_denoise"], entry["passes_commit"],
+             entry["tokens_decided"]) = diffusion
+            # a commit pass completes its block
+            entry["blocks_done"] = diffusion[1]
         return entry
 
     # ---- read side -----------------------------------------------------
@@ -659,6 +695,18 @@ class FlightRecorder:
                 return 0.0
             self._fold_locked()
             return self._host_overlap_s_total / self._step_s
+
+    def diffusion_totals(self) -> Dict[str, int]:
+        """The live slots' block passes so far, by kind, the blocks they
+        completed (a commit pass each) and the positions they decided;
+        zeros until a pass's result has been fetched."""
+        with self._mu:
+            self._fold_locked()
+            totals = dict(self._diffusion_total or {
+                "passes_denoise": 0, "passes_commit": 0, "tokens_decided": 0,
+            })
+        totals["blocks_done"] = totals["passes_commit"]
+        return totals
 
     def snapshot(self, limit: int = 200) -> List[Dict[str, Any]]:
         """Newest-last copy of the most recent ``limit`` records."""
@@ -715,6 +763,7 @@ class FlightRecorder:
             ssm_tokens = dict(self._ssm_tokens_total or {})
             state_mixer = self._state_mixer
             attn_rows = dict(self._attn_rows_total or {})
+            diffusion = dict(self._diffusion_total or {})
             proposed = self._spec_proposed_total
             accepted = self._spec_accepted_total
             hist = {
@@ -816,6 +865,20 @@ class FlightRecorder:
                 f'gpustack_engine_attn_rows_total{{layer="{kind}"}} {n}'
                 for kind, n in sorted(attn_rows.items())
             ]
+        if diffusion:   # a model generated by diffusion over blocks
+            lines += [
+                decl("gpustack_engine_diffusion_passes_total"),
+                f'gpustack_engine_diffusion_passes_total{{kind="denoise"}} '
+                f"{diffusion['passes_denoise']}",
+                f'gpustack_engine_diffusion_passes_total{{kind="commit"}} '
+                f"{diffusion['passes_commit']}",
+                decl("gpustack_engine_diffusion_blocks_total"),
+                f"gpustack_engine_diffusion_blocks_total "
+                f"{diffusion['passes_commit']}",
+                decl("gpustack_engine_diffusion_tokens_decided_total"),
+                f"gpustack_engine_diffusion_tokens_decided_total "
+                f"{diffusion['tokens_decided']}",
+            ]
         if moe_prompt:   # a model with experts, once it has prefilled
             lines.append(decl("gpustack_engine_moe_prompt_tokens_total"))
             lines += [
@@ -842,7 +905,7 @@ for _name in (
     "prompt_tokens_total", "moe_prompt_tokens_total",
     "decode_kv_live_total", "decode_kv_allocated_total",
     "moe_decode_read_total", "moe_decode_held_total", "ssm_tokens_total",
-    "attn_rows_total",
+    "attn_rows_total", "diffusion_total",
     "spec_proposed_total", "spec_accepted_total", "host_overlap_s_total",
 ):
     setattr(FlightRecorder, _name, _folded(_name))

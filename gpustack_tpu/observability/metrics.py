@@ -102,6 +102,9 @@ METRIC_FAMILIES = {
     # kind of layer (label layer=sliding|full), over slots, positions
     # and layers; absent for any other model
     "gpustack_engine_attn_rows_total": "counter",
+    "gpustack_engine_diffusion_passes_total": "counter",
+    "gpustack_engine_diffusion_blocks_total": "counter",
+    "gpustack_engine_diffusion_tokens_decided_total": "counter",
     # a model that keeps a recurrent state a slot: tokens through the
     # layers that keep it, by the program that took them (label
     # kind=prefill|decode: the chunked scan over a prompt, the one-step

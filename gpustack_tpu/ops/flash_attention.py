@@ -85,6 +85,17 @@ edge. A stack that mixes window and full layers calls the kernel with
 and without one (``models/transformer.py window_attention``); without
 one the program is the kernel's as it was.
 
+With a **block** (``block``, static; 0: none; generation by diffusion
+over blocks of that many positions) query ``i`` sees the keys up to the
+last position of its own block, ``j <= i - i % block + block - 1``:
+causal over blocks, both ways inside one. ``block`` divides the 128 rows
+of a sub-block and the offset is a whole number of blocks, so a
+sub-block's last row is its block's last and sees what it saw: the three
+bounds above stand as they are (a sub-block counted as straddling the
+diagonal still does, by up to ``block - 1`` keys more), and the mask's
+compare is the one line that differs. Without one the program is the
+kernel's as it was.
+
 The [T, S] score matrix never exists in HBM and VMEM use is
 O(G * block_q x 128) regardless of sequence length, so a 32k prefill
 fits as easily as a 1k one (the XLA path materializes a [B, H, T, S]
@@ -240,6 +251,7 @@ def _across(x, d: int):
 def _flash_kernel(
     off_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     *, scale: float, seq_k: int, tiles: Tiles, window: int = 0,
+    block: int = 0,
 ):
     """Grid point = one (batch, kv-head, q-block, k-block) tile: the
     ``block_q`` rows of the group's ``G`` query heads against ``block_k``
@@ -288,6 +300,8 @@ def _flash_kernel(
             k_idx = (first_sub_k + j) * SUB_K + lax.broadcasted_iota(
                 jnp.int32, s.shape, 1
             )
+            if block:    # as far as the last position of the row's block
+                q_idx = q_idx - lax.rem(q_idx, block) + (block - 1)
             seen = (k_idx <= q_idx) & (k_idx < seq_k)
             if window:
                 seen = seen & (q_idx - k_idx < window)
@@ -372,6 +386,7 @@ def flash_call(
     off: jax.Array,     # int32[1]: the position of q row 0
     *, scale: float, seq_k: int, interpret: bool = False,
     _blocks: tuple[int, int] | None = None, window: int = 0,
+    block: int = 0,
 ) -> jax.Array:
     """The ``pallas_call`` alone, on head-major operands whose rows are
     already padded to multiples of 128: what :func:`flash_attention_prefill`
@@ -383,6 +398,11 @@ def flash_call(
     B, Hq, T_pad, d = qt.shape
     Hkv, S_pad, dv = kt.shape[1], kt.shape[2], vt.shape[3]
     G = Hq // Hkv
+    if block and (window or SUB_K % block):
+        raise ValueError(
+            f"a block of {block} must divide the {SUB_K} rows of a "
+            "sub-block, and comes without a window"
+        )
     if _blocks is None:
         tiles = choose_tiles(
             T_pad, S_pad, G, max(d, dv), qt.dtype.itemsize
@@ -419,6 +439,7 @@ def flash_call(
         functools.partial(
             _flash_kernel, scale=scale, seq_k=seq_k, tiles=tiles,
             **({"window": window} if window else {}),
+            **({"block": block} if block else {}),
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hq, T_pad, dv), qt.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -451,7 +472,7 @@ def flash_call(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scale", "interpret", "window")
+    jax.jit, static_argnames=("scale", "interpret", "window", "block")
 )
 def flash_attention_prefill(
     q: jax.Array,       # [B, T, Hq, d]
@@ -461,6 +482,7 @@ def flash_attention_prefill(
     interpret: bool = False,
     q_offset=0,
     window: int = 0,
+    block: int = 0,
 ) -> jax.Array:
     """Causal GQA prefill attention (q positions q_offset..q_offset+T-1
     against k positions 0..S-1, with keys at index >= S masked via
@@ -469,7 +491,9 @@ def flash_attention_prefill(
     [B, T, Hq*dv]: the values may be narrower than the keys (latent
     attention decompresses to keys of 192 and values of 128), nothing is
     padded to make them alike. ``window`` (static; 0: none) keeps a
-    query to the keys ``i - window < j <= i``. T and S are padded to
+    query to the keys ``i - window < j <= i``; ``block`` (static; 0:
+    none) lets it see to the end of its block of that many positions
+    (``q_offset`` then a whole number of blocks). T and S are padded to
     multiples of 128 internally; any sequence length fits (VMEM use is O(block)); the
     shapes choose the tiles (:func:`choose_tiles`)."""
     B, T, Hq, d = q.shape
@@ -493,6 +517,7 @@ def flash_attention_prefill(
         qt, kt, vt, jnp.asarray(q_offset, jnp.int32).reshape(1),
         scale=scale, seq_k=S, interpret=interpret,
         **({"window": window} if window else {}),
+        **({"block": block} if block else {}),
     )
     out = jnp.transpose(out[:, :, :T, :], (0, 2, 1, 3))  # [B, T, Hq, dv]
     return out.reshape(B, T, Hq * v.shape[3])

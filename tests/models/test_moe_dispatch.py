@@ -254,6 +254,10 @@ def _mesh(**axes):
 
 
 QWEN3_MOE = dataclasses.replace(BASE, num_experts=128, num_experts_per_tok=8)
+DIFFUSION = {
+    "diffusion_block": 4, "denoising_steps": 4,
+    "remasking_strategy": "sequential", "mask_token_id": 7,
+}
 
 
 @pytest.mark.parametrize(
@@ -290,6 +294,13 @@ QWEN3_MOE = dataclasses.replace(BASE, num_experts=128, num_experts_per_tok=8)
         ({"moe_act": "gptoss"}, 32, "tpu", None, True, "dense"),
         ({"moe_act": "gptoss", "moe_bias": True}, 2048, "tpu", None, False,
          "grouped"),
+        # a diffusion model's block pass, 32 slots of 4 rows over the
+        # cache: 8 pairs an expert, touched and not dense (PR 63)
+        (DIFFUSION, 4 * 32, "tpu", _mesh(), True, "touched"),
+        (DIFFUSION, 4 * 32, "tpu", None, True, "touched"),
+        (DIFFUSION, 4 * 32, "tpu", _mesh(tp=2), True, "dense"),
+        (DIFFUSION, 4 * 32, "cpu", None, True, "dense"),
+        (DIFFUSION, 1024, "tpu", None, False, "grouped"),   # its prefill
     ],
 )
 def test_the_chooser_is_a_function_of_rows_platform_and_mesh(
@@ -299,6 +310,23 @@ def test_the_chooser_is_a_function_of_rows_platform_and_mesh(
     assert moe_dispatch(rows, cfg, platform, mesh, decode=decode) == want
     if not decode:      # the argument's default
         assert moe_dispatch(rows, cfg, platform, mesh) == want
+
+
+@pytest.mark.parametrize("change,rows,want", [
+    ({}, 1, True), ({}, 4, False), ({}, 0, False),
+    (DIFFUSION, 4, True), (DIFFUSION, 1, True), (DIFFUSION, 8, False),
+    (DIFFUSION, 2, False),
+])
+def test_a_step_over_the_cache_is_one_row_or_a_diffusion_block_s(
+    change, rows, want
+):
+    """What ``forward`` tells the chooser of ``T`` rows a slot over a
+    cache: a verify step's 4 rows are no step of a causal model's, a
+    block pass's 4 are the diffusion model's own."""
+    from gpustack_tpu.models.transformer import steps_over_cache
+
+    cfg = dataclasses.replace(QWEN3_MOE, **change)
+    assert steps_over_cache(cfg, rows) is want
 
 
 def test_the_crossover_is_rows_an_expert_not_a_row_count():

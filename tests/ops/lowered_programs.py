@@ -49,6 +49,9 @@ PROGRAMS = (
      0, 64, 2048, 1024),
     ("solar-open2-250b-int8-ep8-l12", "solar-open2-250b-int8-ep8-l12",
      0, 32, 2560, 1024),
+    # ``.decode`` is its step over the cache: a block pass, 4 rows a slot
+    ("sdar-30b-a3b-chat-int8-l12", "sdar-30b-a3b-chat-int8-l12",
+     0, 32, 2560, 1024),
 )
 
 
@@ -87,14 +90,16 @@ def lowered(one_chip) -> dict:
             lambda: quantize_params(init_params(cfg, jax.random.key(0)))
         )
         experts = cfg.is_moe
-        attends = decode_attention_impl(cfg, 1, context, "tpu", None)
+        # rows a slot of a step over the cache: a diffusion block's, or 1
+        rows = cfg.diffusion_block or 1
+        attends = decode_attention_impl(cfg, rows, context, "tpu", None)
 
         def decode(params, tokens, positions, cache, live):
             return forward(
                 params, cfg, tokens, positions, cache, live=live,
                 decode_attn_impl=attends,
                 moe_dispatch_impl=moe_dispatch(
-                    slots, cfg, "tpu", None, decode=True
+                    slots * rows, cfg, "tpu", None, decode=True
                 ) if experts else None,
                 count_experts_read=experts,
                 **({"ssm_impl": "kernel"} if hybrid else {}),
@@ -120,7 +125,7 @@ def lowered(one_chip) -> dict:
             return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
         out[name + ".decode"] = jax.jit(decode, donate_argnums=(3,)).lower(
-            params, ints(slots, 1), ints(slots, 1),
+            params, ints(slots, rows), ints(slots, rows),
             shapes(lambda: KVCache.create(cfg, slots, context)),
             jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
         ).as_text()

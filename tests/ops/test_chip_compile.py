@@ -1661,6 +1661,79 @@ def test_a_mamba_stack_s_prefill_keeps_its_temporaries_small(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2**30
 
 
+def test_a_diffusion_block_pass_reads_cache_and_experts_where_they_lie(
+    one_chip,
+):
+    """The block pass of the model generated by diffusion over blocks, as
+    ``forward`` is traced for it on one TPU chip at the cell's shapes (32
+    slots of 2,560, 4 rows a slot; two layers: the scan's body is what is
+    looked at): the choosers say ``kernel`` and ``touched`` for its 128
+    rows over the cache, both kernels are in the program once a scan (the
+    decode kernel with the block's rows folded into its group: 4 kv heads
+    x 4 rows x 8 query heads = 128 query rows), no layer's slab of rows
+    and no expert matrix is copied, sliced or transposed, and the dense
+    products' ``[128, 128, 768]`` exists nowhere."""
+    import os
+
+    from gpustack_tpu.models import init_params
+    from gpustack_tpu.models.config import load_hf_config
+    from gpustack_tpu.models.quant import quantize_params
+    from gpustack_tpu.models.transformer import (
+        KVCache,
+        decode_attention_impl,
+        forward,
+        moe_dispatch,
+    )
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)
+    )))
+    cfg = dataclasses.replace(load_hf_config(os.path.join(
+        root, "perfbench", "configs", "sdar-30b-a3b-chat-int8-l12"
+    )), num_layers=2)
+    slots, S, L = 32, 2560, cfg.diffusion_block
+    assert L == 4
+    assert decode_attention_impl(cfg, L, S, "tpu", None) == "kernel"
+    assert decode_attention_impl(cfg, 2, S, "tpu", None) == "xla"
+    assert moe_dispatch(slots * L, cfg, "tpu", None, decode=True) == "touched"
+    params = _shapes_on(
+        one_chip, lambda: quantize_params(init_params(cfg, jax.random.key(0)))
+    )
+    cache = _shapes_on(one_chip, lambda: KVCache.create(cfg, slots, S))
+    ids = jax.ShapeDtypeStruct((slots, L), jnp.int32, sharding=one_chip)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)
+
+    def block_pass(params, cache, tokens, positions, live):
+        return forward(
+            params, cfg, tokens, positions, cache, live=live,
+            decode_attn_impl="kernel", moe_dispatch_impl="touched",
+            count_experts_read=True,
+        )
+
+    compiled = jax.jit(block_pass, donate_argnums=(1,)).lower(
+        params, cache, ids, ids, live
+    ).compile()
+    text = compiled.as_text()
+    D, E, F = cfg.hidden_size, cfg.num_experts, cfg.moe_intermediate_size
+    rows = slots * L
+    assert len(re.findall(
+        rf"%moe_touched_experts[\w.\-]* = f32\[{rows},{D}\].* custom-call\(",
+        text,
+    )) == 1
+    assert len(re.findall(
+        rf"%gqa_decode_attention[\w.\-]* = bf16\[{slots},{rows},128\].* "
+        r"custom-call\(", text,
+    )) == 1
+    assert not re.findall(rf"\[{rows},(?:1,)?{E},{F}\]", text)
+    assert not re.findall(rf"= s8\[{E},(?:{D},{F}|{F},{D})\]", text)
+    # no slab of a layer's rows beside the cache, which is updated in place
+    assert not re.findall(rf"= bf16\[{slots},{S},4,128\]", text)
+    assert not re.findall(
+        rf"= bf16\[2,{slots},{S},4,128\][^ ]* (?:copy|transpose)\(", text
+    )
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
+
+
 @pytest.fixture(scope="module")
 def lowered_hashes(one_chip):
     import os
@@ -1697,6 +1770,10 @@ def test_the_other_models_programs_lower_to_the_text_they_had(
     call asks for its VMEM, which is in the call's text; the tile itself
     is in the kernel's body, which is not hashed, so Olmo-Hybrid's new
     tile moved nothing), Solar-Open2's taken with PR 60, which left the
-    other fourteen as they were (``lowered_programs.py`` says what is
-    hashed and how to take the hashes again on purpose)."""
+    other fourteen as they were, and the diffusion model's block pass
+    and 1,024 prefill with PR 63, which left those sixteen as they were
+    though the block's mask, the kernels' ``block`` and the touched
+    experts at several rows a slot went into ``forward``
+    (``lowered_programs.py`` says what is hashed and how to take the
+    hashes again on purpose)."""
     assert lowered_hashes[program] == _lowered_names()[program]
