@@ -26,7 +26,9 @@ state-space layer a recurrent state ``ssm [L_M, B, H, P, N]`` (float32,
 as the family's serving notes ask) and the last ``conv_kernel - 1`` rows
 of ``xBC`` before its causal convolution, ``conv [L_M, B, (K-1) * C]``
 (the rows side by side on the lanes: as ``[.., K-1, C]`` the TPU pads
-the three rows to a tile of sixteen).
+the three rows to a tile of sixteen). A step's mixers read their rows
+out of ``conv`` as the step received it and the step writes the stack
+once, after its last layer.
 
 ``transformer.forward`` hands a model with ``layer_kinds`` to
 :func:`forward_hybrid`; no other model's layer loop passes through here.
@@ -180,15 +182,25 @@ def mamba_mixer(
     real: jax.Array,    # bool [B, T]: which positions count
     alive: jax.Array,   # bool [B]: the slots somebody holds
 ):
-    """One Mamba-2 mixer: ``(out [B, T, D], carried)``, under the scope
-    ``ssm_mixer`` wherever it is called from. ``[z | xBC | dt] = h
+    """One Mamba-2 mixer: ``(out [B, T, D], carried, kept)``, under the
+    scope ``ssm_mixer`` wherever it is called from. ``[z | xBC | dt] = h
     W_in``; ``xBC`` through a causal depthwise convolution of ``K`` taps
     with bias, then ``silu``; ``xBC -> x [H, P], B [G, N], C [G, N]``;
     ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)``; the rule
     itself is ``ops/ssm.py``'s; ``y + D x`` through the gated norm over
     ``G`` groups (:func:`grouped_rms_norm`) and ``W_out``. A padded
     position (``real`` False) has ``dt = 0``: it moves no state, and the
-    kept conv rows end at the last real position."""
+    kept conv rows end at the last real position.
+
+    The state is moved in the stack (``carried.ssm``; the kernel is
+    aliased). The layer's rows before its convolution are read from
+    ``carried.conv``, which is handed on as it was received: the rows
+    the layer leaves, ``kept [B, (K - 1) * conv_dim]`` (None without a
+    cache), are the caller's to place, since the two callers differ in
+    kind (:func:`bound_state_mixers` for a scan over the layers, whose
+    carry XLA updates in place; :func:`forward_hybrid`, which writes the
+    stack once a step: an update a layer of an unrolled program carried
+    all of it through the chip's second memory every layer)."""
     from gpustack_tpu.models import transformer as tf
     from gpustack_tpu.ops.ssm import (
         ssm_chunk_scan,
@@ -228,6 +240,7 @@ def mamba_mixer(
         xs = xbc_a[..., :inner].reshape(B, T, H, P)
         Bm = xbc_a[..., inner:inner + G * N].reshape(B, T, G, N)
         Cm = xbc_a[..., inner + G * N:].reshape(B, T, G, N)
+        kept = None
         if carried is not None:
             # the last K - 1 rows that count: rows n .. n + K - 2 of
             # the window, n the row's real length
@@ -235,10 +248,7 @@ def mamba_mixer(
             rows = n[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None]
             kept = jnp.take_along_axis(
                 window, rows[..., None], axis=1
-            ).reshape(B, (K - 1) * conv_dim)
-            new_conv = lax.dynamic_update_index_in_dim(
-                carried.conv, kept.astype(carried.conv.dtype), i, 0
-            )
+            ).reshape(B, (K - 1) * conv_dim).astype(carried.conv.dtype)
         if carried is None:
             y, _ = ssm_chunk_scan(
                 xs, dt, A, Bm, Cm, jnp.zeros((B, H, P, N), f32),
@@ -267,15 +277,26 @@ def mamba_mixer(
             )
             y = y[:, None]
         if carried is not None:
-            carried = dataclasses.replace(
-                carried, ssm=new_ssm, conv=new_conv
-            )
+            carried = dataclasses.replace(carried, ssm=new_ssm)
         y = y + lp["D"].astype(f32)[:, None] * xs.astype(f32)
         y = grouped_rms_norm(
             y.astype(dtype).reshape(B, T, inner), z, lp["gate_norm"],
             eps, G,
         )
-        return tf._mm("btf,fd->btd", y, lp["w_out"]), carried
+        return tf._mm("btf,fd->btd", y, lp["w_out"]), carried, kept
+
+
+def rows_read_a_layer(conv, x):
+    """``conv`` as it is, behind a barrier that ties the stacked rows to
+    a layer's input ``x`` (which goes on as it was: a barrier in its own
+    way changes what XLA fuses round it), so that each layer of an
+    unrolled program fetches its own rows when it runs (1.2 MB of the
+    cell's 27). Left alone, XLA takes the 23 reads of one array for
+    siblings and cuts every layer's rows out in one fusion before the
+    first layer: the whole stack fetched into the chip's second memory,
+    split into 23 arrays, most of them written out and fetched again
+    (compiled for a described v5e, PR 64)."""
+    return lax.optimization_barrier((conv, x))[0]
 
 
 def bound_state_mixers(
@@ -310,8 +331,21 @@ def bound_state_mixers(
         ),
         alive=live if live is not None else jnp.ones((B,), bool),
     )
+
+    def mamba(h, lp, carried, i):
+        # a scan over the layers: its carry is updated in place
+        out, carried, kept = mamba_mixer(h, lp, carried, i, **bound)
+        if carried is not None:
+            carried = dataclasses.replace(
+                carried,
+                conv=lax.dynamic_update_index_in_dim(
+                    carried.conv, kept, i, 0
+                ),
+            )
+        return out, carried
+
     return {
-        "mamba": functools.partial(mamba_mixer, **bound),
+        "mamba": mamba,
         "linear_attention": functools.partial(delta_mixer, **bound),
     }
 
@@ -488,8 +522,10 @@ def forward_hybrid(
     # against 0.09 GB, a ``copy`` of ``f32[23, 32, 64, 64, 128]``).
     def m_layer(x, carried, stack, i):
         lp = at(stack, i)
-        out, carried = mamba(tf.rms_norm(x, lp["norm"], eps), lp, carried, i)
-        return x + out, carried
+        out, carried, kept = mamba(
+            tf.rms_norm(x, lp["norm"], eps), lp, carried, i
+        )
+        return x + out, carried, kept
 
     def e_layer(x, stack, i):
         lp = at(stack, i)
@@ -506,12 +542,20 @@ def forward_hybrid(
     m_layer, e_layer, a_layer = map(jax.jit, (m_layer, e_layer, a_layer))
     held = read = jnp.int32(0)
     routings = []
+    # every mixer reads its rows from ``cache.conv`` as the step received
+    # it, and the layers' new rows go into it in one write after the last
+    conv_rows = []
     index = {"M": 0, "E": 0, "*": 0}
     for kind in cfg.layer_kinds:
         i = jnp.int32(index[kind])
         index[kind] += 1
         if kind == "M":
-            x, cache = m_layer(x, cache, params["ssm_layers"], i)
+            if cache is not None:
+                cache = dataclasses.replace(
+                    cache, conv=rows_read_a_layer(cache.conv, x)
+                )
+            x, cache, kept = m_layer(x, cache, params["ssm_layers"], i)
+            conv_rows.append(kept)
         elif kind == "*":
             x, cache = a_layer(x, cache, params["attn_layers"], i)
         else:
@@ -520,6 +564,8 @@ def forward_hybrid(
             if routing_out:
                 routings.append(routing)
 
+    if cache is not None and conv_rows:
+        cache = dataclasses.replace(cache, conv=jnp.stack(conv_rows))
     extras = []
     if count_held_pairs:
         extras.append(held)

@@ -408,8 +408,10 @@ def test_the_attention_layer_s_finished_products_change_no_number(
 ):
     """The hybrid's GQA layers take ``wq``, ``wk`` and ``wv`` through the
     helper every other model's layer does (``transformer.
-    qkv_projections``), with its barrier in a decode step: logits, rows
-    and both states are bit for bit the program's without it."""
+    qkv_projections``), with its barrier in a decode step, and every
+    mixer reads its conv rows behind a barrier of its own
+    (``hybrid.rows_read_a_layer``): logits, rows and both states are
+    bit for bit the program's without any of them."""
     cfg, params = model(int8=int8)
     toks = tokens(2).reshape(2, 1)
     pos = jnp.asarray([[7], [3]], jnp.int32)
@@ -427,6 +429,181 @@ def test_the_attention_layer_s_finished_products_change_no_number(
 
         return step
 
-    got, want = with_and_without_the_barrier(program, cache)
+    got, want = with_and_without_the_barrier(
+        program, cache, barriers=1 + cfg.layers_of("M")
+    )
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+# ---- the conv rows: read as the step received them, written once (PR 64) ----
+
+MIXERS = {**HF, "hybrid_override_pattern": "MMM", "num_hidden_layers": 3}
+
+
+def layer_at_a_time(params, cfg, toks, pos, cache, *, live, true_len, impl):
+    """A stack of mixers over a cache as every hybrid program ran it
+    until PR 64, written out: each mixer is handed the cache the one
+    before it left, reads its rows out of that and writes its new rows
+    into the stack before the next one runs."""
+    from gpustack_tpu.models import transformer as tf
+    from gpustack_tpu.models.hybrid import mamba_mixer
+
+    B, T = toks.shape
+    real = (
+        jnp.ones((B, T), bool) if true_len is None
+        else jnp.arange(T, dtype=jnp.int32)[None, :] < true_len[:, None]
+    )
+    alive = live if live is not None else jnp.ones((B,), bool)
+    x = tf._embed_lookup(params["embed"], toks, jnp.float32)
+    for i in range(cfg.layers_of("M")):
+        lp = jax.tree.map(lambda a: a[i], params["ssm_layers"])
+        out, cache, kept = mamba_mixer(
+            tf.rms_norm(x, lp["norm"], cfg.rms_norm_eps), lp, cache,
+            jnp.int32(i), cfg=cfg, impl=impl, real=real, alive=alive,
+        )
+        cache = dataclasses.replace(
+            cache, conv=jax.lax.dynamic_update_index_in_dim(
+                cache.conv, kept, i, 0
+            ),
+        )
+        x = x + out
+    return tf.head(x, params, cfg, None, False), cache
+
+
+def same_bits(got, want):
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["float32", "int8"])
+@pytest.mark.parametrize("update", ["xla", "kernel_interpret"])
+def test_eight_steps_leave_the_rows_a_layer_at_a_time_would(update, int8):
+    """Eight decode steps over four slots, two of them dead and their
+    rows not zero: ``KVCache.conv``, ``KVCache.ssm`` and every logit are
+    bit for bit those of the loop that updates the stack a layer at a
+    time, dead slots and all."""
+    cfg, params = model(MIXERS, int8=int8)
+    shapes = jax.eval_shape(lambda: KVCache.create(cfg, 4, 16))
+    cache = jax.tree.map(
+        lambda s: 0.1 * jax.random.normal(
+            jax.random.key(s.size % 89), s.shape
+        ).astype(s.dtype),
+        shapes,
+    )
+    live = jnp.array([True, False, True, False])
+    step = jax.jit(lambda t, p, c: forward(
+        params, cfg, t, p, c, live=live, ssm_impl=update,
+        decode_attn_impl="xla",
+    ))
+    plain = jax.jit(lambda t, p, c: layer_at_a_time(
+        params, cfg, t, p, c, live=live, true_len=None, impl=update,
+    ))
+    got = want = cache
+    for t in range(8):
+        toks = jax.random.randint(jax.random.key(t), (4, 1), 0, 264)
+        pos = jnp.full((4, 1), t, jnp.int32)
+        logits, got = step(toks, pos, got)
+        ref_logits, want = plain(toks, pos, want)
+        same_bits(
+            (logits, got.conv, got.ssm), (ref_logits, want.conv, want.ssm)
+        )
+    # the rows moved, the dead slots' too (a mixer keeps every slot's)
+    assert float(jnp.abs(got.conv - cache.conv).min(axis=-1).max()) > 0
+
+
+def test_a_padded_prefill_its_insert_and_a_step_leave_those_rows_too():
+    """13 tokens in a bucket of 16 (``true_len``), the slot's state and
+    rows put into slot 1 of 3, then a step with slot 2 dead: at each of
+    the three the cache and the logits are the layer-at-a-time loop's,
+    bit for bit."""
+    cfg, params = model(MIXERS)
+    n, bucket = 13, 16
+    padded = jnp.concatenate(
+        [tokens()[:, :n], jnp.zeros((1, bucket - n), jnp.int32)], axis=1
+    )
+    pos = jnp.arange(bucket, dtype=jnp.int32)[None]
+    true_len = jnp.array([n])
+    # each side one program: XLA rounds a fused program's float32 and
+    # an eager one's a last bit apart
+    logits, one = jax.jit(lambda c: forward(
+        params, cfg, padded, pos, c, true_len=true_len
+    ))(KVCache.create(cfg, 1, bucket))
+    ref_logits, ref_one = jax.jit(lambda c: layer_at_a_time(
+        params, cfg, padded, pos, c, live=None, true_len=true_len,
+        impl="scan",
+    ))(KVCache.create(cfg, 1, bucket))
+    same_bits(
+        (logits, one.conv, one.ssm), (ref_logits, ref_one.conv, ref_one.ssm)
+    )
+    # padding is not kept: the rows end at the 13th position
+    _, whole = forward(
+        params, cfg, padded, pos, KVCache.create(cfg, 1, bucket)
+    )
+    assert float(jnp.abs(whole.conv - one.conv).max()) > 1e-3
+
+    def insert(got):
+        cache = KVCache.create(cfg, 3, 32)
+        return dataclasses.replace(
+            cache, ssm=cache.ssm.at[:, 1].set(got.ssm[:, 0]),
+            conv=cache.conv.at[:, 1].set(got.conv[:, 0]),
+        )
+
+    live = jnp.array([True, True, False])
+    tok = jnp.array([[5], [int(tokens()[0, n])], [0]], jnp.int32)
+    at = jnp.array([[0], [n], [0]], jnp.int32)
+    for update in ("xla", "kernel_interpret"):
+        logits, got = jax.jit(lambda c: forward(
+            params, cfg, tok, at, c, live=live, ssm_impl=update,
+            decode_attn_impl="xla",
+        ))(insert(one))
+        ref_logits, want = jax.jit(lambda c: layer_at_a_time(
+            params, cfg, tok, at, c, live=live, true_len=None, impl=update,
+        ))(insert(ref_one))
+        same_bits(
+            (logits, got.conv, got.ssm), (ref_logits, want.conv, want.ssm)
+        )
+
+
+@pytest.mark.parametrize("update", ["xla", "kernel_interpret"])
+def test_the_whole_pattern_s_step_reads_the_rows_as_it_received_them(
+    update, monkeypatch
+):
+    """The same through every kind of layer (``MEM*EME``): a step whose
+    mixers update the stack they are handed one after another, and read
+    their rows out of what the ones before them left, gives the bits of
+    the step whose mixers all read the stack as the step received it."""
+    from gpustack_tpu.models import hybrid
+
+    cfg, params = model()
+    shapes = jax.eval_shape(lambda: KVCache.create(cfg, 3, 16))
+    cache = jax.tree.map(
+        lambda s: 0.1 * jax.random.normal(
+            jax.random.key(s.size % 83), s.shape
+        ).astype(s.dtype),
+        shapes,
+    )
+    live = jnp.array([True, False, True])
+    toks = jnp.array([[3], [0], [9]], jnp.int32)
+    pos = jnp.array([[7], [0], [2]], jnp.int32)
+
+    def run():
+        return forward(
+            params, cfg, toks, pos, cache, live=live, ssm_impl=update,
+            decode_attn_impl="xla",
+        )
+
+    got = run()
+    mixer = hybrid.mamba_mixer
+
+    def a_layer_at_a_time(h, lp, carried, i, **bound):
+        out, carried, kept = mixer(h, lp, carried, i, **bound)
+        return out, dataclasses.replace(
+            carried, conv=jax.lax.dynamic_update_index_in_dim(
+                carried.conv, kept, i, 0
+            ),
+        ), kept
+
+    monkeypatch.setattr(hybrid, "mamba_mixer", a_layer_at_a_time)
+    monkeypatch.setattr(hybrid, "rows_read_a_layer", lambda conv, x: conv)
+    same_bits(got, run())

@@ -963,37 +963,20 @@ def _nemotron(pattern: str):
     return config_from_hf(hf, "nemotron-3-nano")
 
 
-def test_the_hybrid_decode_step_moves_its_state_in_place(one_chip):
+@pytest.fixture(scope="module")
+def hybrid_decode(one_chip):
     """The decode program of the benchmark's hybrid as the runner traces
-    it on one TPU chip, 32 slots of 4,096: every state-space layer's
-    ``ssm_state_update`` reads and writes the stacked state where it
-    lies (donated and aliased; PR 29 found XLA copying a donated stacked
-    carry whole twice a step), the GQA kernel takes 2 kv heads, the
-    touched-experts kernel the two-matrix form, and the experts' stored
-    width keeps their matrices out of the temporaries (at 1,856 columns
-    the TPU stores ``we_up`` transposed and the program copied all of
-    it before every call: 1.8 GB of temporaries at full depth)."""
+    it on one TPU chip, ``MEM*EME`` deep at 32 slots of 4,096, compiled:
+    ``(text, memory analysis, the parameters' shapes)``."""
     from gpustack_tpu.models import init_params
-    from gpustack_tpu.models.hybrid import ssm_update_impl
     from gpustack_tpu.models.quant import quantize_params
-    from gpustack_tpu.models.transformer import (
-        KVCache,
-        decode_attention_impl,
-        forward,
-        moe_dispatch,
-    )
+    from gpustack_tpu.models.transformer import KVCache, forward
 
     cfg = _nemotron("MEM*EME")
     slots, S = 32, 4096
-    assert moe_dispatch(slots, cfg, "tpu", None, decode=True) == "touched"
-    assert decode_attention_impl(cfg, 1, S, "tpu", None) == "kernel"
-    assert ssm_update_impl(1, "tpu", None) == "kernel"
-    assert ssm_update_impl(512, "tpu", None) == "scan"
-    assert ssm_update_impl(1, "cpu", None) == "xla"
     params = _shapes_on(
         one_chip, lambda: quantize_params(init_params(cfg, jax.random.key(0)))
     )
-    assert params["moe_layers"]["we_up"].q.shape == (3, 16, 2688, 1920)
     cache = _shapes_on(one_chip, lambda: KVCache.create(cfg, slots, S))
     ids = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
     live = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)
@@ -1008,7 +991,34 @@ def test_the_hybrid_decode_step_moves_its_state_in_place(one_chip):
     compiled = jax.jit(step, donate_argnums=(1,)).lower(
         params, cache, ids, ids, live
     ).compile()
-    text = compiled.as_text()
+    return compiled.as_text(), compiled.memory_analysis(), params
+
+
+def test_the_hybrid_decode_step_moves_its_state_in_place(hybrid_decode):
+    """The decode program of the benchmark's hybrid as the runner traces
+    it on one TPU chip, 32 slots of 4,096: every state-space layer's
+    ``ssm_state_update`` reads and writes the stacked state where it
+    lies (donated and aliased; PR 29 found XLA copying a donated stacked
+    carry whole twice a step), the GQA kernel takes 2 kv heads, the
+    touched-experts kernel the two-matrix form, and the experts' stored
+    width keeps their matrices out of the temporaries (at 1,856 columns
+    the TPU stores ``we_up`` transposed and the program copied all of
+    it before every call: 1.8 GB of temporaries at full depth)."""
+    from gpustack_tpu.models.hybrid import ssm_update_impl
+    from gpustack_tpu.models.transformer import (
+        decode_attention_impl,
+        moe_dispatch,
+    )
+
+    cfg = _nemotron("MEM*EME")
+    slots, S = 32, 4096
+    assert moe_dispatch(slots, cfg, "tpu", None, decode=True) == "touched"
+    assert decode_attention_impl(cfg, 1, S, "tpu", None) == "kernel"
+    assert ssm_update_impl(1, "tpu", None) == "kernel"
+    assert ssm_update_impl(512, "tpu", None) == "scan"
+    assert ssm_update_impl(1, "cpu", None) == "xla"
+    text, mem, params = hybrid_decode
+    assert params["moe_layers"]["we_up"].q.shape == (3, 16, 2688, 1920)
     state = f"f32[3,{slots},64,64,128]"
     assert len(re.findall(
         rf"%ssm_state_update[\w.\-]* = \({re.escape(state)}", text
@@ -1050,13 +1060,61 @@ def test_the_hybrid_decode_step_moves_its_state_in_place(one_chip):
         r"= s8\[(?:1,)?(?:2688,4096|4096,2688|2688,256|256,2688)\][^ ]* "
         r"copy\(", text,
     )
-    mem = compiled.memory_analysis()
     state_bytes = 3 * slots * 64 * 64 * 128 * 4
     assert mem.alias_size_in_bytes >= state_bytes
     # no copy of the state (0.2 GB here, 1.57 GB at full depth)
     assert mem.temp_size_in_bytes < 0.25 * state_bytes
     # and no more than the program held before the kernel's body changed
     # (PR 54's tree, this compile: 3,354,624)
+    assert mem.temp_size_in_bytes <= 3_354_624
+
+
+def test_the_hybrid_decode_step_writes_its_conv_rows_once(hybrid_decode):
+    """The same program's stacked conv rows (``KVCache.conv``,
+    ``bf16[3,32,18432]`` here, ``[23,..]`` and 27 MB in the cell): a
+    layer fetches its own rows, and only those, out of the stack as the
+    step received it, and the step makes the stack once, after its last
+    layer (``jnp.stack``). Until PR 64 every mixer updated the stack it
+    was handed: compiled, each update worked on a copy of the whole
+    array in the chip's second memory space, fetched in parts before
+    the layer that needed one of them and written back by a
+    ``copy-start`` / ``copy-done`` of the full shape (at this depth the
+    copy stays there between the layers and is written back once; at
+    the cell's 23 layers it went in and out every layer, 1.25 GB a
+    step, 0.86 ms of a 15 ms step on the chip). What the compiler makes
+    of ``jnp.stack`` at the cell's depth is 23 updates of one layer's
+    rows in a buffer there and one ``copy-start`` of it back, which the
+    chip's clock does not tell from no traffic at all (PERF.md section
+    6, PR 64): so the text is held to a fetch a layer and at most one
+    write of the whole."""
+    text, mem, _ = hybrid_decode
+    slots, layers = 32, 3
+    stack = rf"bf16\[{layers},{slots},18432\]"
+    # each layer's rows are read out of the stack the step was given, a
+    # layer's at a time (asynchronous slices of one layer each)
+    reads = re.findall(
+        r" ([\w\-]+)\(%cache_conv[\w.]*\), slice=\{\[(\d+):(\d+)\]", text
+    )
+    assert sorted(reads) == [
+        ("slice-start", str(l), str(l + 1)) for l in range(layers)
+    ], reads
+    # the whole stack goes nowhere but out, once at most
+    moved = re.findall(
+        rf"= \(?{stack}[^ ]*(?:, [^ ]+)*\)? "
+        r"(copy|copy-start|slice-start|dynamic-slice)\(", text,
+    )
+    assert moved in ([], ["copy-start"]), moved
+    # and it is made once: one operation of the entry computation has
+    # it for a result, beside the step's own argument
+    made = re.findall(
+        rf"^  (?:ROOT )?%[\w.\-]+ = {stack}[^ ]* ([\w\-]+)\(",
+        text[text.index("\nENTRY "):], re.M,
+    )
+    assert sorted(set(made) - {"parameter", "copy-done"}) == ["fusion"], made
+    assert made.count("fusion") == 1, made
+    # the result is the donated buffer, and nothing of the stack's size
+    # is held beside it
+    assert mem.alias_size_in_bytes >= layers * slots * 18432 * 2
     assert mem.temp_size_in_bytes <= 3_354_624
 
 
@@ -1773,7 +1831,13 @@ def test_the_other_models_programs_lower_to_the_text_they_had(
     other fourteen as they were, and the diffusion model's block pass
     and 1,024 prefill with PR 63, which left those sixteen as they were
     though the block's mask, the kernels' ``block`` and the touched
-    experts at several rows a slot went into ``forward``
+    experts at several rows a slot went into ``forward``; Nemotron's
+    two taken again with PR 64 (its mixers hand their conv rows back
+    and the step writes the stack once: ``models/hybrid.py``) and
+    Granite's two with it (``bound_state_mixers`` places the rows its
+    mixer hands back, so a row's update stands after the state's in the
+    text: the same operations in another order, and compiled the
+    parent's program), which left the other fourteen as they were
     (``lowered_programs.py`` says what is hashed and how to take the
     hashes again on purpose)."""
     assert lowered_hashes[program] == _lowered_names()[program]
