@@ -437,8 +437,8 @@ class ModelConfig:
         place: compiled for a described v5e, the decode program copied
         both caches whole into the other order and back every step (PR
         53; ``models/hybrid.py pad_expert_width``'s lesson again).
-        ``transformer.block`` pads q, k and v on their way to the cache
-        and drops the heads that are none on the way out."""
+        ``transformer.gqa_attention`` pads q, k and v on their way to the
+        cache and drops the heads that are none on the way out."""
         h = self.num_kv_heads
         return h if h <= 16 or h % 16 == 0 else -(-h // 16) * 16
 
@@ -510,7 +510,7 @@ class ModelConfig:
                 assert self.mamba_num_heads % self.mamba_n_groups == 0
                 assert self.mamba_inner and self.ssm_state_size
         if self.kv_heads_stored != self.num_kv_heads:
-            # only ``transformer.block``'s GQA branch pads its heads
+            # only ``transformer.gqa_attention`` pads its heads
             assert self.layer_kinds is None and not self.window_rows
             assert not self.attn_sinks
         if self.layer_types is not None:

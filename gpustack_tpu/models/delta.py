@@ -2,10 +2,9 @@
 (``cfg.layer_types``: Olmo-Hybrid's, and KDA, Solar-Open2's, the same
 function with three fields of the configuration set): one function over a layer's
 input, its leaves, the carried cache and the layer's index among its
-kind, beside ``models/hybrid.py mamba_mixer``. ``transformer.forward``
-calls it from ``block`` for a ``"linear_attention"`` layer, bound by
-``models/hybrid.py bound_state_mixers``; the layer's norms, its residual
-adds and its MLP are ``block``'s.
+kind, beside ``models/hybrid.py mamba_mixer``. ``transformer.scan_periods``
+calls it for a ``"linear_attention"`` layer; the layer's norms, its
+residual adds and its MLP are ``transformer.after_mixer``'s.
 
 ``q~ = h Wq``, ``k~ = h Wk`` (``H * Dk`` wide), ``v~ = h Wv`` (``H * Dv``
 wide), side by side through one causal depthwise convolution of ``K``
@@ -107,15 +106,12 @@ def delta_mixer(
     lp,                 # the layer's leaves
     carried,            # the cache (None: from zeros, nothing kept)
     i: jax.Array,       # int32: the layer's index among the linear layers
-    *,
-    cfg: ModelConfig,
-    impl: str,          # "scan" | "xla" | "kernel" | "kernel_interpret"
-    real: jax.Array,    # bool [B, T]: which positions count
-    alive: jax.Array,   # bool [B]: the slots somebody holds
+    step,               # transformer.Step: cfg, ssm_impl, real, alive
 ):
     """One gated-delta-rule mixer: ``(out [B, T, D], carried)``. A padded
-    position (``real`` False) has ``g = 0`` and ``beta = 0``: it moves no
-    state, and the kept conv rows end at the last real position."""
+    position (``step.real`` False) has ``g = 0`` and ``beta = 0``: it
+    moves no state, and the kept conv rows end at the last real position.
+    ``step.ssm_impl`` as ``models/hybrid.py mamba_mixer``'s."""
     from gpustack_tpu.models.transformer import _mm, finish_products
     from gpustack_tpu.ops.delta_rule import (
         delta_chunk_scan,
@@ -126,6 +122,7 @@ def delta_mixer(
     )
 
     B, T, _ = h.shape
+    cfg, impl, real = step.cfg, step.ssm_impl, step.real
     H, Dk, Dv = (
         cfg.linear_num_value_heads, cfg.linear_key_head_dim,
         cfg.linear_value_head_dim,
@@ -211,12 +208,12 @@ def delta_mixer(
                     state_layout(last).astype(carried.ssm.dtype), i, 0,
                 )
             else:
-                step = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+                row = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
                 if impl == "xla":
-                    o, new_ssm = delta_step_xla(carried.ssm, i, *step)
+                    o, new_ssm = delta_step_xla(carried.ssm, i, *row)
                 else:
                     o, new_ssm = delta_state_update(
-                        carried.ssm, i, *step, alive,
+                        carried.ssm, i, *row, step.alive,
                         interpret=impl == "kernel_interpret",
                     )
                 o = o[:, None]

@@ -4,11 +4,10 @@
 a stack can hold it: Nemotron-H's one mixer a layer
 (:func:`forward_hybrid` below, ``cfg.layer_kinds``) and a stack whose
 every layer is a mixer by kind and then an MLP (``cfg.layer_types``'
-``"mamba"``, Granite 4.0-H: ``transformer.forward``'s ``block`` calls
-it where it calls ``models/delta.py``'s mixer for a delta-rule layer,
-and the layer's norms, residual adds and MLP are ``block``'s).
-:func:`bound_state_mixers` binds either kind for one call of
-``forward``.
+``"mamba"``, Granite 4.0-H: :func:`mamba_layer`, which
+``transformer.scan_periods`` calls where it calls ``models/delta.py``'s
+mixer for a delta-rule layer; the layer's norms, residual adds and MLP
+are ``transformer.after_mixer``'s).
 
 The Nemotron-H hybrid: one mixer a layer, three kinds of layer.
 ``cfg.layer_kinds`` names each layer's mixer: ``"M"`` a Mamba-2
@@ -30,23 +29,22 @@ the three rows to a tile of sixteen). A step's mixers read their rows
 out of ``conv`` as the step received it and the step writes the stack
 once, after its last layer.
 
-``transformer.forward`` hands a model with ``layer_kinds`` to
-:func:`forward_hybrid`; no other model's layer loop passes through here.
+``transformer.forward`` hands a model with ``layer_kinds``, and the
+Step it made, to :func:`forward_hybrid`; no other model's layer loop
+passes through here.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
-from typing import Any, Dict, Optional
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from gpustack_tpu.models.config import ModelConfig
-from gpustack_tpu.models.quant import QuantW
 
 STACKS = {"M": "ssm_layers", "E": "moe_layers", "*": "attn_layers"}
 
@@ -176,11 +174,7 @@ def mamba_mixer(
     lp,                 # the layer's leaves
     carried,            # the cache (None: from zeros, nothing kept)
     i: jax.Array,       # int32: the layer's index among the Mamba-2 layers
-    *,
-    cfg: ModelConfig,
-    impl: str,          # "scan" | "xla" | "kernel" | "kernel_interpret"
-    real: jax.Array,    # bool [B, T]: which positions count
-    alive: jax.Array,   # bool [B]: the slots somebody holds
+    step,               # transformer.Step: cfg, ssm_impl, real, alive
 ):
     """One Mamba-2 mixer: ``(out [B, T, D], carried, kept)``, under the
     scope ``ssm_mixer`` wherever it is called from. ``[z | xBC | dt] = h
@@ -197,10 +191,12 @@ def mamba_mixer(
     ``carried.conv``, which is handed on as it was received: the rows
     the layer leaves, ``kept [B, (K - 1) * conv_dim]`` (None without a
     cache), are the caller's to place, since the two callers differ in
-    kind (:func:`bound_state_mixers` for a scan over the layers, whose
-    carry XLA updates in place; :func:`forward_hybrid`, which writes the
+    kind (:func:`mamba_layer` for a scan over the layers, whose carry
+    XLA updates in place; :func:`forward_hybrid`, which writes the
     stack once a step: an update a layer of an unrolled program carried
-    all of it through the chip's second memory every layer)."""
+    all of it through the chip's second memory every layer).
+    ``step.ssm_impl`` (``"scan" | "xla" | "kernel" |
+    "kernel_interpret"``) says how a step over a cache moves the state."""
     from gpustack_tpu.models import transformer as tf
     from gpustack_tpu.ops.ssm import (
         ssm_chunk_scan,
@@ -209,6 +205,7 @@ def mamba_mixer(
     )
 
     B, T, _ = h.shape
+    cfg, impl, real = step.cfg, step.ssm_impl, step.real
     H, P = cfg.mamba_num_heads, cfg.mamba_head_dim
     G, N = cfg.mamba_n_groups, cfg.ssm_state_size
     inner, conv_dim, K = cfg.mamba_inner, cfg.mamba_conv_dim, cfg.conv_kernel
@@ -272,7 +269,7 @@ def mamba_mixer(
         else:
             y, new_ssm = ssm_state_update(
                 carried.ssm, i, xs[:, 0], dt[:, 0], A, Bm[:, 0],
-                Cm[:, 0], alive,
+                Cm[:, 0], step.alive,
                 interpret=impl == "kernel_interpret",
             )
             y = y[:, None]
@@ -299,159 +296,88 @@ def rows_read_a_layer(conv, x):
     return lax.optimization_barrier((conv, x))[0]
 
 
-def bound_state_mixers(
-    cfg: ModelConfig, rows, cache, true_len, live, impl, platform, mesh,
-    *, ring: bool,
-):
-    """The mixers that keep a recurrent state, by their kind in
-    ``cfg.layer_types`` (``"mamba"``: :func:`mamba_mixer`;
-    ``"linear_attention"``: ``models/delta.py delta_mixer``), each bound
-    for one call of ``forward`` over ``rows = (B, T)`` as ``f(h, layer's
-    leaves, carried, index among its kind)``: which positions count for
-    a state (``true_len``; all of them without one), which slots the
-    one-step kernel moves (``live``; all without one), and how a step
-    moves the state (``impl``, or what :func:`ssm_update_impl` chooses).
-    A cache on a mesh of several devices, or sharded over its positions,
-    is refused: a recurrent state is not sharded."""
-    from gpustack_tpu.models.delta import delta_mixer
-
-    B, T = rows
-    if cache is not None and (ring or (mesh is not None and mesh.size > 1)):
-        raise ValueError(
-            f"{cfg.name}: a recurrent state is not sharded; serve it on one "
-            "device (a cache sharded over its positions cannot carry one)"
+def mamba_layer(h, lp, carried, i, step):
+    """:func:`mamba_mixer` as a layer of a scan over the layers
+    (``cfg.layer_types``' ``"mamba"``): ``(out, carried)``, the rows the
+    mixer leaves written into the scan's carry, which XLA updates in
+    place."""
+    out, carried, kept = mamba_mixer(h, lp, carried, i, step)
+    if carried is not None:
+        carried = dataclasses.replace(
+            carried,
+            conv=lax.dynamic_update_index_in_dim(carried.conv, kept, i, 0),
         )
-    if impl is None:
-        impl = ssm_update_impl(T if cache is not None else 2, platform, mesh)
-    bound = dict(
-        cfg=cfg, impl=impl,
-        real=(
-            jnp.ones((B, T), bool) if true_len is None
-            else jnp.arange(T, dtype=jnp.int32)[None, :] < true_len[:, None]
-        ),
-        alive=live if live is not None else jnp.ones((B,), bool),
+    return out, carried
+
+
+def experts_layer(h, lp, i, step):
+    """One layer of two-matrix ``relu2`` experts with a shared expert
+    (``"E"``): ``(out, held pairs, experts read, routing)``, the counts
+    0 and the routing None unless the Step asks."""
+    from gpustack_tpu.models.transformer import _moe_mlp
+
+    stacked = step.stacked
+    shared = (
+        (None, lp["ws_up"], lp["ws_down"], None) if "ws_up" in lp else None
     )
-
-    def mamba(h, lp, carried, i):
-        # a scan over the layers: its carry is updated in place
-        out, carried, kept = mamba_mixer(h, lp, carried, i, **bound)
-        if carried is not None:
-            carried = dataclasses.replace(
-                carried,
-                conv=lax.dynamic_update_index_in_dim(
-                    carried.conv, kept, i, 0
-                ),
-            )
-        return out, carried
-
-    return {
-        "mamba": mamba,
-        "linear_attention": functools.partial(delta_mixer, **bound),
-    }
+    out = _moe_mlp(
+        h, lp["router"], None,
+        stacked.get("we_up", lp.get("we_up")),
+        stacked.get("we_down", lp.get("we_down")), step.cfg,
+        router_bias=lp.get("router_bias"), shared=shared,
+        dispatch=step.moe_dispatch_impl,
+        layer=i if stacked else None,
+        count_held=step.count_held_pairs, routing_out=step.routing_out,
+        live=step.live, count_read=step.count_experts_read,
+    )
+    out, *extras = out if isinstance(out, tuple) else (out,)
+    routing = extras.pop() if step.routing_out else None
+    held = extras.pop(0) if step.count_held_pairs else jnp.int32(0)
+    read = extras.pop(0) if step.count_experts_read else jnp.int32(0)
+    return out, held, read, routing
 
 
-def forward_hybrid(
-    params,
-    cfg: ModelConfig,
-    tokens: jax.Array,
-    positions: jax.Array,
-    cache=None,
-    *,
-    return_hidden: bool = False,
-    attn_impl: str = "xla",
-    mesh=None,
-    moe_dispatch_impl: Optional[str] = None,
-    decode_attn_impl: Optional[str] = None,
-    ssm_impl: Optional[str] = None,
-    live: Optional[jax.Array] = None,
-    true_len: Optional[jax.Array] = None,
-    count_held_pairs: bool = False,
-    routing_out: bool = False,
-    count_experts_read: bool = False,
-    logits_at: Optional[jax.Array] = None,
-):
-    """``transformer.forward`` for a model with ``layer_kinds``; the same
-    arguments and results, and two more arguments.
+def attention_layer(h, lp, carried, i, step):
+    """One GQA layer without rotary embedding (``"*"``): ``(out,
+    carried)``. Its queries go grouped from the projection, in one
+    reshape where ``transformer.gqa_attention`` has two: the same values
+    and another lowered text (ROADMAP C17)."""
+    from gpustack_tpu.models import transformer as tf
 
-    ``true_len`` (int32 ``[B]``; None: every position counts): how many
-    of each row's ``T`` positions are real. A state-space layer's state
-    and its kept ``xBC`` rows end after them and not after the padding
-    of a bucket: padded positions get ``dt = 0``, which moves no state.
-    Attention needs nothing of the kind (a padded row is above every
-    real query).
+    cfg, B, T = step.cfg, step.B, step.T
+    q, k, v = tf.qkv_projections(h, lp, decode=carried is not None and T == 1)
+    q = q.reshape(B, T, cfg.num_kv_heads, cfg.group_size, cfg.head_dim)
+    k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+    v = v.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+    if carried is None:
+        attn = tf._attend(q, k, v, step.mask, step.scale)
+    else:
+        attn, new_k, new_v = tf.attend_over_cache(
+            q, k, v, carried.k, carried.v, i, step.positions[:, 0],
+            positions=step.positions, mask=step.mask, scale=step.scale,
+            decode_attn_impl=step.decode_attn_impl, walk=step.walk,
+            attn_impl=step.attn_impl, mesh=step.mesh,
+        )
+        carried = dataclasses.replace(carried, k=new_k, v=new_v)
+    return tf._mm("btq,qd->btd", attn.reshape(B, T, -1), lp["wo"]), carried
+
+
+def forward_hybrid(params, step, x: jax.Array, cache=None):
+    """``transformer.forward``'s driver for a model with ``layer_kinds``:
+    the layers in the pattern's order from the embedded tokens ``x``,
+    under the Step ``forward`` made: ``(x, cache, extras)``.
 
     With a cache a state-space layer starts from the cache's state of
     its slot (zeros in a fresh prefill cache) and leaves its new one
-    there: a decode step (``T == 1``) through ``ssm_impl`` (by
-    :func:`ssm_update_impl`: the kernel over the live slots, or XLA
-    operations), several rows a slot through the chunked scan. Without
-    a cache every layer starts from zeros and keeps nothing.
+    there; without one every layer starts from zeros and keeps nothing.
+    A state and its kept ``xBC`` rows end after a row's real positions
+    (``step.real``) and not after the padding of a bucket; attention
+    needs nothing of the kind (a padded row is above every real query).
     """
     from gpustack_tpu.models import transformer as tf
-    B, T = tokens.shape
-    platform = (
-        mesh.devices.flat[0].platform if mesh is not None
-        else jax.default_backend()
-    )
-    decode = cache is not None and T == 1
-    if moe_dispatch_impl is None:
-        moe_dispatch_impl = tf.moe_dispatch(
-            B * T, cfg, platform, mesh, decode=decode
-        )
-    if cache is not None and decode_attn_impl is None:
-        decode_attn_impl = tf.decode_attention_impl(
-            cfg, T, cache.max_len, platform, mesh
-        )
-    if ssm_impl is None:
-        ssm_impl = ssm_update_impl(
-            T if cache is not None else 2, platform, mesh
-        )
-    if attn_impl == "ring":
-        raise ValueError(
-            "attn_impl='ring': a cache sharded over its positions cannot "
-            "carry a recurrent state; serve this model with sp=1"
-        )
-    walk = None
-    if cache is not None and decode_attn_impl != "xla":
-        from gpustack_tpu.ops.decode_attention import gqa_walk
 
-        lengths = positions[:, 0] + 1
-        if live is not None:
-            lengths = jnp.where(live, lengths, 0)
-        walk = gqa_walk(lengths, cache.k)
-    alive = live if live is not None else jnp.ones((B,), bool)
-
-    dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
-    f32 = jnp.float32
-    eps = cfg.rms_norm_eps
-    x = tf._embed_lookup(params["embed"], tokens, dtype)
-    scale = 1.0 / math.sqrt(cfg.head_dim)
-    if cache is None:
-        mask = positions[:, :, None] >= positions[:, None, :]
-    else:
-        mask = (
-            jnp.arange(cache.max_len, dtype=jnp.int32)[None, None, :]
-            <= positions[:, :, None]
-        )
-    # which of a row's positions count (state-space layers)
-    real = (
-        jnp.ones((B, T), bool) if true_len is None
-        else jnp.arange(T, dtype=jnp.int32)[None, :] < true_len[:, None]
-    )
-
-    # the experts' stacked matrices go to the kernels whole, with the
-    # layer's index; the scales of the touched kernel as its blocks
-    # take them (``transformer.forward``)
+    cfg, stacked, eps = step.cfg, step.stacked, step.cfg.rms_norm_eps
     moe = params.get("moe_layers", {})
-    stacked = {}
-    if moe and moe_dispatch_impl != "dense":
-        stacked = {k: moe[k] for k in ("we_up", "we_down")}
-        if moe_dispatch_impl.startswith("touched"):
-            stacked = {
-                k: QuantW(q=w.q, s=w.s[:, :, None, :])
-                if isinstance(w, QuantW) else w
-                for k, w in stacked.items()
-            }
 
     def at(stack, i):
         """Layer ``i``'s leaves of a stack, without the matrices that go
@@ -464,53 +390,6 @@ def forward_hybrid(
             for k, w in stack.items() if k not in stacked
         }
 
-    mamba = functools.partial(
-        mamba_mixer, cfg=cfg, impl=ssm_impl, real=real, alive=alive
-    )
-
-    def experts(h, lp, i):
-        """One expert layer: ``(out, held pairs, experts read,
-        routing)``, the counts 0 and the routing None unless asked."""
-        shared = (
-            (None, lp["ws_up"], lp["ws_down"], None) if "ws_up" in lp
-            else None
-        )
-        out = tf._moe_mlp(
-            h, lp["router"], None,
-            stacked.get("we_up", lp.get("we_up")),
-            stacked.get("we_down", lp.get("we_down")), cfg,
-            router_bias=lp.get("router_bias"), shared=shared,
-            dispatch=moe_dispatch_impl,
-            layer=i if stacked else None,
-            count_held=count_held_pairs, routing_out=routing_out,
-            live=live, count_read=count_experts_read,
-        )
-        out, *extras = out if isinstance(out, tuple) else (out,)
-        routing = extras.pop() if routing_out else None
-        held = extras.pop(0) if count_held_pairs else jnp.int32(0)
-        read = extras.pop(0) if count_experts_read else jnp.int32(0)
-        return out, held, read, routing
-
-    def attention(h, lp, carried, i):
-        """One GQA layer, no rotary embedding: ``(out, carried)``."""
-        q, k, v = tf.qkv_projections(
-            h, lp, decode=carried is not None and T == 1
-        )
-        q = q.reshape(B, T, cfg.num_kv_heads, cfg.group_size, cfg.head_dim)
-        k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-        v = v.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-        if carried is None:
-            attn = tf._attend(q, k, v, mask, scale)
-        else:
-            attn, new_k, new_v = tf.attend_over_cache(
-                q, k, v, carried.k, carried.v, i, positions[:, 0],
-                positions=positions, mask=mask, scale=scale,
-                decode_attn_impl=decode_attn_impl, walk=walk,
-                attn_impl=attn_impl, mesh=mesh,
-            )
-            carried = dataclasses.replace(carried, k=new_k, v=new_v)
-        return tf._mm("btq,qd->btd", attn.reshape(B, T, -1), lp["wo"]), carried
-
     # One function a kind of layer, traced once: the layers are visited in
     # a Python loop (every layer's operations are in the program, which
     # XLA inlines), but each kind is a jitted function called with its
@@ -522,20 +401,22 @@ def forward_hybrid(
     # against 0.09 GB, a ``copy`` of ``f32[23, 32, 64, 64, 128]``).
     def m_layer(x, carried, stack, i):
         lp = at(stack, i)
-        out, carried, kept = mamba(
-            tf.rms_norm(x, lp["norm"], eps), lp, carried, i
+        out, carried, kept = mamba_mixer(
+            tf.rms_norm(x, lp["norm"], eps), lp, carried, i, step
         )
         return x + out, carried, kept
 
     def e_layer(x, stack, i):
         lp = at(stack, i)
-        out, *more = experts(tf.rms_norm(x, lp["norm"], eps), lp, i)
+        out, *more = experts_layer(
+            tf.rms_norm(x, lp["norm"], eps), lp, i, step
+        )
         return (x + out, *more)
 
     def a_layer(x, carried, stack, i):
         lp = at(stack, i)
-        out, carried = attention(
-            tf.rms_norm(x, lp["norm"], eps), lp, carried, i
+        out, carried = attention_layer(
+            tf.rms_norm(x, lp["norm"], eps), lp, carried, i, step
         )
         return x + out, carried
 
@@ -561,17 +442,16 @@ def forward_hybrid(
         else:
             x, n_held, n_read, routing = e_layer(x, moe, i)
             held, read = held + n_held, read + n_read
-            if routing_out:
+            if step.routing_out:
                 routings.append(routing)
 
     if cache is not None and conv_rows:
         cache = dataclasses.replace(cache, conv=jnp.stack(conv_rows))
     extras = []
-    if count_held_pairs:
+    if step.count_held_pairs:
         extras.append(held)
-    if count_experts_read:
+    if step.count_experts_read:
         extras.append(read)
-    if routing_out:
+    if step.routing_out:
         extras.append(tuple(jnp.stack(r) for r in zip(*routings)))
-    out = tf.head(x, params, cfg, logits_at, return_hidden)
-    return (out, cache, *extras)
+    return x, cache, extras

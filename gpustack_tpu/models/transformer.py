@@ -1626,137 +1626,87 @@ def heads_of_zeros_behind(n: int, *arrays: jax.Array):
     )
 
 
-def forward(
-    params: Params,
-    cfg: ModelConfig,
-    tokens: jax.Array,                # [B, T] int32
-    positions: jax.Array,             # [B, T] int32 absolute positions
-    cache: Optional[KVCache] = None,
-    return_hidden: bool = False,
-    attn_impl: str = "xla",
-    mesh=None,
-    embeds_override: Optional[Tuple[jax.Array, jax.Array]] = None,
-    moe_dispatch_impl: Optional[str] = None,
-    decode_attn_impl: Optional[str] = None,
-    live: Optional[jax.Array] = None,
-    count_held_pairs: bool = False,
-    routing_out: bool = False,
-    count_experts_read: bool = False,
-    true_len: Optional[jax.Array] = None,
-    ssm_impl: Optional[str] = None,
-    logits_at: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, Optional[KVCache]]:
-    """Run the model.
+@dataclasses.dataclass(frozen=True, eq=False)
+class Step:
+    """What one call of ``forward`` works out once and every layer
+    reads: :func:`make_step` makes it, the drivers hand it to the layer
+    functions, nothing else does either (docs/MODELS.md). A layer of a
+    stack with ``layer_sliding`` but no window store reads its own
+    ``mask``, ``sin`` and ``cos``, chosen by its flag
+    (:func:`_uniform_layer`)."""
 
-    A model of one mixer a layer (``cfg.layer_kinds``, the Nemotron-H
-    hybrid) runs in ``models/hybrid.py forward_hybrid``, which takes
-    these arguments and says what ``true_len`` and ``ssm_impl`` are. A
-    stack with a mixer by kind and an MLP in every layer
-    (``cfg.layer_types``: Olmo-Hybrid, Granite 4.0-H) runs here, in
-    ``block``, and hands both to the mixers that keep a state
-    (``models/hybrid.py bound_state_mixers``: the same Mamba-2 mixer
-    ``forward_hybrid`` calls, or the delta rule's). Of the others only a
-    stack that keeps its sliding layers' rows at window size
-    (``cfg.window_rows``; ``window_attention`` below) takes notice of
-    ``true_len``: int32 ``[B]``, how many of a prefill's ``T`` positions
-    are real, so that the ring it leaves holds the last real rows and
-    nothing of the padding (None: every position counts). Such a stack
-    takes a cache in two forms only, a prefill from position 0 into a
-    cache of the step's length and one row a slot, and neither a mesh
-    nor ``"ring"``.
+    cfg: ModelConfig
+    B: int
+    T: int
+    max_len: Optional[int]      # a slot's rows in the cache; None without one
+    positions: jax.Array        # [B, T] int32
+    mesh: Any
+    # how each thing is computed, decided once a program
+    attn_impl: str
+    use_flash: bool             # the flash kernel for this step's attention
+    decode_attn_impl: Optional[str]
+    moe_dispatch_impl: Optional[str]
+    ssm_impl: Optional[str]
+    scale: float
+    # mask[b, t, s], query t attends key s: one for every layer, or with
+    # ``layer_sliding`` one each for the full and the sliding layers
+    mask: Optional[jax.Array]
+    mask_full: Optional[jax.Array]
+    mask_slide: Optional[jax.Array]
+    # rotation tables [B, T, width / 2] float32 (None: no rope): the
+    # main one, the sliding layers' own (gemma3) and the latent's
+    sin: Optional[jax.Array]
+    cos: Optional[jax.Array]
+    sin_loc: Optional[jax.Array]
+    cos_loc: Optional[jax.Array]
+    mla_sin: Optional[jax.Array]
+    mla_cos: Optional[jax.Array]
+    # the decode kernel's walk over the slots, and over a ring's live rows
+    walk: Any
+    walk_w: Any
+    live: Optional[jax.Array]       # bool [B]: the slots somebody holds
+    true_len: Optional[jax.Array]   # int32 [B]: a prefill's real positions
+    # for the mixers that keep a state: which positions count, bool
+    # [B, T], and which slots the one-step kernel moves, bool [B]
+    real: Optional[jax.Array]
+    alive: Optional[jax.Array]
+    count_held_pairs: bool
+    count_experts_read: bool
+    routing_out: bool
+    # the routed experts' matrices of every layer, as the grouped and
+    # the touched kernels read them; empty under dense dispatch
+    stacked: Dict[str, Any]
+    kd: int                         # layers of DeepSeek's dense prefix
 
-    Without ``cache``: plain causal forward (training / scoring path).
-    With ``cache``: the cache rides the scan over the layers as its carry;
-    each layer writes the step's K/V rows into it at ``positions`` (only
-    those rows: ``KVCache``) and attends over its own layer of the whole
-    cache with an absolute-position causal mask. ``T > 1`` is a prefill
-    step, ``T == 1`` a decode step — same code path, different jit
-    specialization.
 
-    ``attn_impl`` selects the prefill attention kernel: ``"xla"`` (einsum
-    scores, fine for short prompts), ``"flash"`` (pallas blocked
-    online-softmax — no [T, S] score tensor; required for long-context
-    prefill; with the band of a window whose rows are kept at window
-    size), or ``"flash_interpret"`` (same kernel in interpret mode, for
-    hermetic CPU tests). Flash applies to the prefill-from-zero cache path
-    (T > 1, cache sized to the bucket); a step over cached rows attends
-    as :func:`decode_attention_impl` says (below), the cacheless paths
-    through XLA. A model the kernel refuses
-    (:func:`needs_xla_attention`) raises: the caller chooses
-    (``engine/runner.py prefill_attention``), this function never falls
-    back in silence.
+def _counted(B: int, T: int, true_len, live):
+    """``(real, alive)`` of :class:`Step`; all of them where nobody
+    says (``true_len``, ``live`` None)."""
+    real = (
+        jnp.ones((B, T), bool) if true_len is None
+        else jnp.arange(T, dtype=jnp.int32)[None, :] < true_len[:, None]
+    )
+    return real, live if live is not None else jnp.ones((B,), bool)
 
-    ``attn_impl="ring"`` (requires ``mesh`` with an ``sp`` axis) is the
-    sequence-parallel serving path: prefill attention runs as ring
-    attention over sp-sharded activations and the KV cache STAYS sharded
-    over sp for the whole generation — decode/verify steps attend over the
-    sharded cache with an exact pmax/psum online-softmax merge
-    (ops/ring_attention.py). This is context parallelism as a first-class
-    engine mode, not an arg passthrough (reference carries
-    --prefill-context-parallel-size to vLLM and implements nothing:
-    vllm_resource_fit_selector.py:118-148).
 
-    A mixture of experts enumerates its experts' products as
-    :func:`moe_dispatch` says for ``B * T`` rows, the platform of
-    ``mesh`` (of the process, without one), ``mesh``, and whether the
-    step is one row a slot over a cache; ``moe_dispatch_impl`` names one
-    instead: the tests (``"grouped_interpret"``, ``"touched_interpret"``,
-    as ``"flash_interpret"``) and a caller that differentiates
-    (``"dense"``: the kernels have no VJP). Under ``"touched"`` a slot
-    that ``live`` says nobody holds is routed nowhere: it touches no
-    expert and passes the shared expert only.
-
-    A latent-attention model (MLA) keeps the latent in the cache and
-    attends in one of two forms (``mla_attention`` below): decompressed
-    where the step's rows are all the keys (``attn_impl`` then chooses
-    the kernel as for any model), absorbed over cached rows. A cache
-    sharded over its positions (``"ring"``) cannot yet carry a latent
-    and is refused.
-
-    Over cached rows a step attends as :func:`decode_attention_impl`
-    says, or ``decode_attn_impl`` (``"kernel_interpret"`` for the
-    tests): a decode step's kernel reads the cache where it lies and
-    each slot only as far as its length, ``positions[:, 0] + 1``, or 0
-    where ``live`` (bool ``[B]``; None: every slot) says nobody holds
-    the slot. Such a slot's logits mean nothing (zeros attended under
-    the kernel, its last tenant's rows under ``"xla"``, which takes no
-    notice of ``live``); its row of the cache is still written.
-
-    Returns ``(logits [B, T, vocab] fp32, updated cache or None)``; with
-    ``logits_at`` (int32 ``[B]``, an index along ``T``) the logits are
-    ``[B, 1, vocab]``, those of the one row a sequence it names, and the
-    final norm and the vocabulary head run on that row alone
-    (:func:`head`; a prefill keeps one row of its bucket). And
-    with ``count_held_pairs`` (a model served as one share of its
-    experts, ``cfg.experts_held``) a third: how many of the router's
-    ``B * T * k`` pairs a layer, over the layers with experts, fell on
-    experts held here (int32 scalar; the engine's
-    ``gpustack_engine_moe_pairs_total``). With ``routing_out`` a last
-    one more: ``(chosen int32 [L_moe, B, T, k], router logits float32
-    [L_moe, B, T, E])`` of the layers with experts, for a comparison
-    with a reference that must follow the program's choices
-    (``perfbench/reference_check.py``); no served program asks for it.
-    With ``count_experts_read`` one more, after the pairs and before the
-    routing: the held experts whose weights the step read, summed over
-    the layers with experts (int32 scalar; ``held * layers`` but under
-    ``"touched"``: the engine's
-    ``gpustack_engine_moe_decode_experts_total``).
-    """
-    if cfg.layer_kinds is not None:
-        from gpustack_tpu.models.hybrid import forward_hybrid
-
-        if embeds_override is not None:
-            raise ValueError("a hybrid model takes no embedding override")
-        return forward_hybrid(
-            params, cfg, tokens, positions, cache,
-            return_hidden=return_hidden, attn_impl=attn_impl, mesh=mesh,
-            moe_dispatch_impl=moe_dispatch_impl,
-            decode_attn_impl=decode_attn_impl, ssm_impl=ssm_impl, live=live,
-            true_len=true_len, count_held_pairs=count_held_pairs,
-            routing_out=routing_out, count_experts_read=count_experts_read,
-            logits_at=logits_at,
-        )
+def make_step(
+    params: Params, cfg: ModelConfig, tokens, positions, cache=None,
+    attn_impl: str = "xla", mesh=None, embeds_override=None,
+    moe_dispatch_impl=None, decode_attn_impl=None, live=None,
+    count_held_pairs=False, routing_out=False, count_experts_read=False,
+    true_len=None, ssm_impl=None,
+) -> Tuple[Step, jax.Array]:
+    """``forward``'s arguments to ``(the Step, x [B, T, d] the embedded
+    tokens)``: the choices (:func:`moe_dispatch`,
+    :func:`decode_attention_impl`, ``models/hybrid.py ssm_update_impl``,
+    each called once and only where the caller named none), the
+    refusals, then the arrays in the order the lowered programs have
+    them: walk, embedding, rotation tables, masks, the stacked experts'
+    relayout."""
     B, T = tokens.shape
+    over = cache is not None
+    sharded = mesh is not None and mesh.size > 1
+    ring = attn_impl == "ring"
     platform = (
         mesh.devices.flat[0].platform if mesh is not None
         else jax.default_backend()
@@ -1764,29 +1714,64 @@ def forward(
     if cfg.is_moe and moe_dispatch_impl is None:
         moe_dispatch_impl = moe_dispatch(
             B * T, cfg, platform, mesh,
-            decode=cache is not None and steps_over_cache(cfg, T),
+            decode=over and steps_over_cache(cfg, T),
         )
-    if cache is not None and decode_attn_impl is None:
+    if over and decode_attn_impl is None:
         decode_attn_impl = decode_attention_impl(
             cfg, T, cache.max_len, platform, mesh
         )
-    # Under ``layer_types``: the mixers that keep a state, by kind
-    # (delta rule, Mamba-2), with what each needs beside a layer's
-    # leaves bound to it (models/hybrid.py). One name, and made there:
-    # on the chip's host every name and every line that ``forward`` and
-    # ``block`` hold before they reach a kernel costs each operation of
-    # the kernel's traced body (PERF.md section 6, PR 53: 60 dead lines
-    # in either slowed a flash prefill program's trace by a third).
-    state_layers = None
-    if cfg.layer_types is not None:
-        from gpustack_tpu.models.hybrid import bound_state_mixers
+    if cfg.state_mixer and ssm_impl is None:
+        from gpustack_tpu.models.hybrid import ssm_update_impl
 
-        state_layers = bound_state_mixers(
-            cfg, (B, T), cache, true_len, live, ssm_impl, platform, mesh,
-            ring=attn_impl == "ring",
+        ssm_impl = ssm_update_impl(T if over else 2, platform, mesh)
+    use_flash = (
+        attn_impl in ("flash", "flash_interpret") and over and T > 1
+        and cache.max_len >= T
+    )
+    use_ring = ring and over
+    if cfg.layer_kinds is not None and embeds_override is not None:
+        raise ValueError("a hybrid model takes no embedding override")
+    if cfg.state_mixer and over and (ring or sharded):
+        raise ValueError(
+            f"{cfg.name}: a recurrent state is not sharded; serve it on one "
+            "device (a cache sharded over its positions cannot carry one)"
         )
+    if (use_flash or use_ring) and needs_xla_attention(cfg):
+        raise ValueError(
+            f"attn_impl={attn_impl!r} needs no attention softcapping, no "
+            "attention sinks and no sliding window but one whose rows "
+            "are kept at window size"
+        )
+    if cfg.window_rows and over:
+        if use_ring or sharded:
+            raise ValueError(
+                f"{cfg.name}: a window store is not sharded; serve it on "
+                "one device"
+            )
+        if T > 1 and cache.max_len != T:
+            raise ValueError(
+                f"{cfg.name}: {T} rows a slot over a cache of "
+                f"{cache.max_len}: a stack that keeps its sliding layers' "
+                "rows at window size takes a prefill from position 0 into "
+                "a cache of its own length, or one row a slot (a chunk, a "
+                "prefix or a draft would need rows the ring has dropped)"
+            )
+    if use_ring and mesh is None:
+        raise ValueError("attn_impl='ring' needs a mesh")
+    if use_ring and cfg.is_mla:
+        raise ValueError(
+            "attn_impl='ring': a cache sharded over its positions cannot "
+            "carry a latent (MLA) yet; serve this model with sp=1"
+        )
+
+    # ``real`` and ``alive`` stand where each family's programs had
+    # them: first under ``layer_types``, last under ``layer_kinds``
+    # (one place when Nemotron's hashes are next taken: ROADMAP C17)
+    real = alive = None
+    if cfg.layer_types is not None:
+        real, alive = _counted(B, T, true_len, live)
     walk = walk_w = None
-    if cache is not None and decode_attn_impl != "xla":
+    if over and decode_attn_impl != "xla":
         lengths = positions[:, 0] + T    # a block's rows see its end
         if live is not None:
             lengths = jnp.where(live, lengths, 0)
@@ -1817,140 +1802,89 @@ def forward(
         # gemma's sqrt(d), Granite's embedding_multiplier; HF casts the
         # number to the compute dtype before multiplying
         x = x * jnp.asarray(cfg.embed_multiplier).astype(dtype)
-    main_inv, main_att_factor = rope_params(cfg)
-    sin, cos = rope_sin_cos(positions, main_inv)
-    if main_att_factor != 1.0:
-        # yarn on the standard attention path (Qwen/Llama long-context
-        # configs): HF's attention_scaling rides cos/sin
-        sin = sin * main_att_factor
-        cos = cos * main_att_factor
+    sin = cos = sin_loc = cos_loc = mla_sin = mla_cos = None
+    scale = 1.0 / math.sqrt(cfg.query_pre_attn_scalar or cfg.head_dim)
     if cfg.is_mla:
         # decoupled rope: only the qk_rope part rotates, with its own
         # frequency table (interleaved-pair convention); DeepSeek ships
         # YaRN scaling whose attention factor rides the sin/cos tables
         rs = cfg.rope_scaling or {}
-        if (rs.get("rope_type") or rs.get("type")) == "yarn":
-            mla_inv, att_factor = yarn_inv_freq(
-                cfg.rope_theta, cfg.qk_rope_head_dim, rs
-            )
-        else:
-            mla_inv = _inv_freq(cfg.rope_theta, cfg.qk_rope_head_dim)
-            att_factor = 1.0
+        yarn = rs if (rs.get("rope_type") or rs.get("type")) == "yarn" else {}
+        mla_inv, att_factor = (
+            yarn_inv_freq(cfg.rope_theta, cfg.qk_rope_head_dim, yarn)
+            if yarn else
+            (_inv_freq(cfg.rope_theta, cfg.qk_rope_head_dim), 1.0)
+        )
         mla_sin, mla_cos = rope_sin_cos(positions, mla_inv)
         if att_factor != 1.0:
-            mla_sin = mla_sin * att_factor
-            mla_cos = mla_cos * att_factor
-    if cfg.rope_local_theta:
-        # gemma3: sliding layers rotate with a separate, unscaled theta
-        sin_loc, cos_loc = rope_sin_cos(
-            positions, _inv_freq(cfg.rope_local_theta, cfg.head_dim)
-        )
-    else:
+            mla_sin, mla_cos = mla_sin * att_factor, mla_cos * att_factor
+        if yarn.get("mscale_all_dim"):
+            # DeepSeek YaRN applies a SECOND magnitude correction beyond
+            # the sin/cos attention_factor: HF/vLLM multiply the softmax
+            # scale by yarn_get_mscale(factor, mscale_all_dim)^2
+            # (modeling_deepseek_v2 DeepseekV2Attention.__init__). For
+            # the shipped V2/V3 configs mscale == mscale_all_dim, so the
+            # sin/cos factor is 1.0 and THIS term carries the whole
+            # correction (~1.59x for V2-Lite's factor=40,
+            # mscale_all_dim=0.707).
+            m = yarn_get_mscale(
+                float(yarn["factor"]), float(yarn["mscale_all_dim"])
+            )
+            scale = scale * m * m
+    elif cfg.rope:
+        main_inv, main_att_factor = rope_params(cfg)
+        sin, cos = rope_sin_cos(positions, main_inv)
+        if main_att_factor != 1.0:
+            # yarn on the standard attention path (Qwen/Llama
+            # long-context configs): HF's attention_scaling rides cos/sin
+            sin, cos = sin * main_att_factor, cos * main_att_factor
         sin_loc, cos_loc = sin, cos
-    scale = 1.0 / math.sqrt(cfg.query_pre_attn_scalar or cfg.head_dim)
-    if cfg.is_mla:
-        # DeepSeek YaRN applies a SECOND magnitude correction beyond the
-        # sin/cos attention_factor: HF/vLLM multiply the softmax scale by
-        # yarn_get_mscale(factor, mscale_all_dim)^2 (modeling_deepseek_v2
-        # DeepseekV2Attention.__init__; vLLM deepseek_v2.py). For the
-        # shipped V2/V3 configs mscale == mscale_all_dim, so the sin/cos
-        # factor is 1.0 and THIS term carries the whole correction
-        # (~1.59x for V2-Lite's factor=40, mscale_all_dim=0.707).
-        rs_ = cfg.rope_scaling or {}
-        if (
-            (rs_.get("rope_type") or rs_.get("type")) == "yarn"
-            and rs_.get("mscale_all_dim")
-        ):
-            m_ = yarn_get_mscale(
-                float(rs_["factor"]), float(rs_["mscale_all_dim"])
+        if cfg.rope_local_theta:
+            # gemma3: sliding layers rotate with a separate, unscaled theta
+            sin_loc, cos_loc = rope_sin_cos(
+                positions, _inv_freq(cfg.rope_local_theta, cfg.head_dim)
             )
-            scale = scale * m_ * m_
-    hetero = cfg.layer_sliding is not None
 
-    use_flash = (
-        attn_impl in ("flash", "flash_interpret")
-        and cache is not None
-        and T > 1
-        and cache.max_len >= T
-    )
-    use_ring = attn_impl == "ring" and cache is not None
-    if (use_flash or use_ring) and needs_xla_attention(cfg):
-        raise ValueError(
-            f"attn_impl={attn_impl!r} needs no attention softcapping, no "
-            "attention sinks and no sliding window but one whose rows "
-            "are kept at window size"
-        )
-    if cfg.window_rows and cache is not None:
-        if use_ring or (mesh is not None and mesh.size > 1):
-            raise ValueError(
-                f"{cfg.name}: a window store is not sharded; serve it on "
-                "one device"
-            )
-        if T > 1 and cache.max_len != T:
-            raise ValueError(
-                f"{cfg.name}: {T} rows a slot over a cache of "
-                f"{cache.max_len}: a stack that keeps its sliding layers' "
-                "rows at window size takes a prefill from position 0 into "
-                "a cache of its own length, or one row a slot (a chunk, a "
-                "prefix or a draft would need rows the ring has dropped)"
-            )
-    if use_ring and mesh is None:
-        raise ValueError("attn_impl='ring' needs a mesh")
-    if use_ring and cfg.is_mla:
-        raise ValueError(
-            "attn_impl='ring': a cache sharded over its positions cannot "
-            "carry a latent (MLA) yet; serve this model with sp=1"
-        )
-
-    # mask[b, t, s] — query t attends key s
-    over_cache = attend_over_cache
+    # causal; over blocks under ``cfg.diffusion_block``, both ways inside
+    # one: a query sees as far as the last position of its block (the
+    # one place the mask is made; the kernels are told the block's length)
+    sees = positions
     if cfg.diffusion_block:
-        # causal over blocks, both ways inside one: a query sees as far
-        # as the last position of its block (the one place the mask is
-        # made; the kernels are told the block's length)
         sees = positions - positions % cfg.diffusion_block + (
             cfg.diffusion_block - 1
         )
-        over_cache = partial(attend_over_cache, block=cfg.diffusion_block)
-    else:
-        sees = positions
-    if cache is None:
+    if not over:
         causal = sees[:, :, None] >= positions[:, None, :]
         delta = positions[:, :, None] - positions[:, None, :]
     else:
-        S = cache.max_len
-        cache_pos = jnp.arange(S, dtype=jnp.int32)
+        cache_pos = jnp.arange(cache.max_len, dtype=jnp.int32)
         causal = cache_pos[None, None, :] <= sees[:, :, None]
         delta = positions[:, :, None] - cache_pos[None, None, :]
-    if hetero:
+    mask = mask_full = mask_slide = None
+    if cfg.layer_sliding is not None:
         # gemma-style alternating layers: both masks exist, each layer
-        # picks one inside the scan by its slide flag
+        # takes one by its slide flag
         mask_full = causal
         mask_slide = causal & (delta < cfg.sliding_window)
-        mask = None
     elif cfg.sliding_window:
         mask = causal & (delta < cfg.sliding_window)
     else:
         mask = causal
-    slide_flags = (
-        jnp.asarray(cfg.layer_sliding, jnp.bool_)
-        if hetero
-        else jnp.zeros((cfg.num_layers,), jnp.bool_)
-    )
-    act = (
-        jax.nn.silu
-        if cfg.hidden_act == "silu"
-        else lambda z: jax.nn.gelu(z, approximate=True)
-    )
+    if cfg.layer_kinds is not None:
+        real, alive = _counted(B, T, true_len, live)
 
     # Grouped experts read their layer's blocks out of the stacked
     # weights by the layer's index: as the scan's slices they would be
     # copied whole before every kernel call (``grouped_matmul``).
-    layers = params["layers"]
     stacked = {}
     if cfg.is_moe and moe_dispatch_impl != "dense":
-        stacked = {k: layers[k] for k in ("we_gate", "we_up", "we_down")}
-        layers = {k: v for k, v in layers.items() if k not in stacked}
+        layers = params.get(
+            "layers" if cfg.layer_kinds is None else "moe_layers", {}
+        )
+        stacked = {
+            k: layers[k] for k in ("we_gate", "we_up", "we_down")
+            if k in layers
+        }
         if moe_dispatch_impl.startswith("touched"):
             # the scales as the kernel's blocks take them, [L, E, 1, N]:
             # a relayout, made here once a step and not once a layer
@@ -1959,457 +1893,619 @@ def forward(
                 if isinstance(w, QuantW) else w
                 for k, w in stacked.items()
             }
-
-    def mla_attention(h, lp, carried, layer, mask_l):
-        """One layer of latent attention (DeepSeek-V2/V3 family) over the
-        latent cache: ``(attn [B, T, H * v_head_dim], carried)``.
-
-        The step's latent rows (``c_kv`` after its norm, the shared rope
-        key after its rotation) are written to the cache; nothing wider
-        is ever stored. Then one of two forms of the same attention:
-
-        - **decompressed**, where the step's own rows are every key
-          there is (no cache, or a prefill from position 0 into a cache
-          of the step's length): ``k_nope`` and ``v`` are made per head
-          from ``c_kv`` inside the program, compute-bound, and attended
-          over as the projections make them (``_mla_over_own_rows``);
-        - **absorbed**, over cached rows (decode, verify, a
-          continuation): ``W_uk`` goes into the query (``q' = q_nope
-          W_uk^T``, 128 -> 512 a head) and ``W_uv`` into the output, and
-          all heads attend over the latent as one shared key/value head
-          of width 576 / 512, so a cached position is read once for all
-          64 heads and never decompressed.
-        """
-        H = cfg.num_heads
-        nope = cfg.qk_nope_head_dim
-        rank, vd = cfg.kv_lora_rank, cfg.v_head_dim
-        if cfg.q_lora_rank:
-            q_c = rms_norm(
-                _mm("btd,dr->btr", h, lp["wq_a"]),
-                lp["q_a_norm"], cfg.rms_norm_eps, False,
-            )
-            q = _mm("btr,rq->btq", q_c, lp["wq_b"])
-        else:
-            q = _mm("btd,dq->btq", h, lp["wq"])
-        # wq_b meets the same fold as a GQA layer's wq
-        (q,) = finish_products(carried is not None and T == 1, q)
-        q = q.reshape(B, T, H, cfg.head_dim)
-        q_nope = q[..., :nope]
-        q_pe = apply_rope_interleaved(q[..., nope:], mla_sin, mla_cos)
-        kv_a = _mm("btd,dr->btr", h, lp["wkv_a"])
-        c_kv = rms_norm(
-            kv_a[..., :rank], lp["kv_a_norm"], cfg.rms_norm_eps, False
-        )
-        k_pe = apply_rope_interleaved(
-            kv_a[..., rank:][:, :, None, :], mla_sin, mla_cos
-        )                                               # [B, T, 1, rope]
-        if carried is not None:
-            # the cache rides the scan without its one head (below)
-            write = partial(
-                _write_rows, layer=layer, start=positions[:, 0],
-                decode_attn_impl=decode_attn_impl,
-            )
-            carried = KVCache(
-                k=write(carried.k, c_kv), v=write(carried.v, k_pe[:, :, 0])
-            )
-        if cache is None or (T > 1 and cache.max_len == T):
-            return _mla_over_own_rows(
-                q, c_kv, k_pe, lp["wk_b"], lp["wv_b"], mla_sin, mla_cos,
-                mask_l, scale, mesh, attn_impl if use_flash else "xla",
-                positions[0, 0],
-            ), carried
-
-        # absorbed, over this layer of the cache. An int8 weight's
-        # scales are per output channel of kv_b_proj, (head, nope) or
-        # (head, v): absorbing W_uk contracts over nope, so its scales
-        # go onto the query first; W_uv's multiply the output.
-        wk, wv = lp["wk_b"], lp["wv_b"]
-        if isinstance(wk, QuantW):
-            q_nope = q_nope * wk.s.reshape(H, nope).astype(q_nope.dtype)
-            wk = wk.q.astype(q_nope.dtype)
-        q_lat = jnp.einsum(
-            "bthn,rhn->bthr", q_nope, wk.reshape(rank, H, nope)
-        )
-        if decode_attn_impl == "xla":
-            c_all, r_all = (
-                lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
-                for buf in (carried.k, carried.v)
-            )                                   # [B, S, rank], [B, S, rope]
-            scores = (
-                jnp.einsum("bthr,bsr->bhts", q_lat, c_all)
-                + jnp.einsum("bthe,bse->bhts", q_pe, r_all)
-            ).astype(jnp.float32) * scale
-            scores = jnp.where(mask_l[:, None, :, :], scores, -1e30)
-            weights = jax.nn.softmax(scores, axis=-1).astype(q_lat.dtype)
-            u = jnp.einsum("bhts,bsr->bthr", weights, c_all)
-        else:
-            from gpustack_tpu.ops.mla_attention import mla_decode_attention
-
-            u = mla_decode_attention(
-                q_lat[:, 0], q_pe[:, 0], carried.k, carried.v, layer,
-                walk, scale,
-                interpret=decode_attn_impl == "kernel_interpret",
-            )[:, None]
-        if isinstance(wv, QuantW):
-            attn = jnp.einsum(
-                "bthr,rhv->bthv", u, wv.q.astype(u.dtype).reshape(rank, H, vd)
-            ) * wv.s.reshape(H, vd).astype(u.dtype)
-        else:
-            attn = jnp.einsum("bthr,rhv->bthv", u, wv.reshape(rank, H, vd))
-        return attn.reshape(B, T, H * vd), carried
-
-    period = cfg.window_period if cfg.window_rows else cfg.mixer_period
-
-    def among_its_kind(layer, kind):
-        """Where a layer's rows, its state or its mixer's leaves lie in
-        the store or the stack of its kind. ``kind`` (static) is ``(the
-        kind, how many of it come before the layer in its period)``."""
-        return (layer // len(period)) * period.count(kind[0]) + kind[1]
-
-    def window_attention(h, lp, carried, layer, kind):
-        """One GQA layer of a stack that keeps its sliding layers' rows
-        at window size (``cfg.window_rows``): ``(attn [B, T, H * hd],
-        carried)``. ``kind`` (static) is ``(sliding, at)``: whether the
-        layer is a sliding one, and how many of its kind come before it
-        in its period, which with the period's number says where its
-        rows lie in its store.
-
-        A sliding layer sees keys ``0 <= i - j < sliding_window``; its
-        step's rows go to the ring at ``position mod W`` and a decode
-        step attends the ring's live rows, ``min(length, W)`` of them. A
-        full layer is a causal layer over ``k, v``. A prefill is from
-        position 0 and its own rows are every key, so it attends over
-        them as they come (a band in the flash kernel) and leaves each
-        sliding layer's last ``min(true_len, W)`` rows in the ring: a
-        row takes the newest real position of its residue, the padding
-        of a bucket writes nothing."""
-        sliding = kind[0]
-        q, k, v = qkv_projections(h, lp, decode=cache is not None and T == 1)
-        q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)
-        k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-        v = v.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-        if sliding or not cfg.nope_full_layers:
-            rotate = (
-                apply_rope_interleaved if cfg.rope_interleaved else apply_rope
-            )
-            q, k = rotate(q, sin, cos), rotate(k, sin, cos)
-        grouped = q.reshape(
-            B, T, cfg.num_kv_heads, cfg.group_size, cfg.head_dim
-        )
-        mask_l = mask_slide if sliding else mask_full
-        if cache is None:
-            return _attend(grouped, k, v, mask_l, scale), carried
-        store = among_its_kind(layer, kind)
-        buf_k, buf_v = (
-            (carried.wk, carried.wv) if sliding else (carried.k, carried.v)
-        )
-        rows = buf_k.shape[2]
-        if T == 1:
-            # a ring's live rows are its first min(length, W)
-            live_rows = mask_full if not sliding else (
-                jnp.arange(rows, dtype=jnp.int32)[None, None, :]
-                < jnp.minimum(positions + 1, rows)[:, :, None]
-            )
-            attn, buf_k, buf_v = attend_over_cache(
-                q, k, v, buf_k, buf_v, store,
-                positions[:, 0] % rows if sliding else positions[:, 0],
-                positions=positions, mask=live_rows, scale=scale,
-                decode_attn_impl=decode_attn_impl,
-                walk=walk_w if sliding else walk,
-                name="gqa_window_decode_attention" if sliding else None,
-            )
-        else:
-            if sliding:
-                # ring row r takes position r + W * ((n - 1 - r) // W),
-                # the newest real one of its residue, where r < n
-                n = (
-                    true_len if true_len is not None
-                    else jnp.full((B,), T, jnp.int32)
-                )[:, None]
-                r = jnp.arange(rows, dtype=jnp.int32)[None, :]
-                src = jnp.clip(r + rows * ((n - 1 - r) // rows), 0, T - 1)
-                kept = [
-                    jnp.where(
-                        (r < n)[:, :, None, None],
-                        jnp.take_along_axis(
-                            new, src[:, :, None, None], axis=1
-                        ),
-                        jnp.zeros((), new.dtype),
-                    ) for new in (k, v)
-                ]
-            else:
-                kept = [k, v]
-            buf_k, buf_v = (
-                lax.dynamic_update_index_in_dim(buf, new, store, 0)
-                for buf, new in zip((buf_k, buf_v), kept)
-            )
-            if use_flash:
-                band = cfg.sliding_window if sliding else 0
-                attn = _flash_prefill(None, attn_impl)(
-                    q, k, v, scale, q_offset=positions[0, 0],
-                    window=band if band < T else 0,
-                )
-            else:
-                attn = _attend(grouped, k, v, mask_l, scale)
-        carried = dataclasses.replace(
-            carried, **(
-                dict(wk=buf_k, wv=buf_v) if sliding
-                else dict(k=buf_k, v=buf_v)
-            )
-        )
-        return attn, carried
-
-    def block(carry, scanned, moe_layer: bool, kind=None):
-        x_in, carried, layer, *counts = carry
-        lp, slide_flag = scanned
-        lp = {**lp, **stacked}
-        # under ``layer_types``: the layer's mixer, its place among its
-        # kind (where its rows or its state lie in their store), and
-        # whether its norms stand before its sublayers or after them
-        mixer = kind[0] if state_layers is not None else None
-        store = among_its_kind(layer, kind) if mixer else layer
-        # None: the layer's mixer is attention
-        state_layer = state_layers.get(mixer) if mixer else None
-        norm_first = mixer not in cfg.norm_after
-        windowed = kind is not None and mixer is None
-        if windowed:
-            mask_l = sin_b = cos_b = None   # window_attention's own
-        elif hetero:
-            mask_l = jnp.where(slide_flag, mask_slide, mask_full)
-            sin_b = jnp.where(slide_flag, sin_loc, sin)
-            cos_b = jnp.where(slide_flag, cos_loc, cos)
-        else:
-            mask_l, sin_b, cos_b = mask, sin, cos
-        h = model_norm(x_in, lp["attn_norm"], cfg) if norm_first else x_in
-        if cfg.is_mla:
-            attn, carried = mla_attention(h, lp, carried, layer, mask_l)
-        elif state_layer is not None:
-            attn_out, carried = state_layer(h, lp, carried, store)
-        elif windowed:
-            attn, carried = window_attention(h, lp, carried, layer, kind)
-        else:
-            q, k, v = qkv_projections(
-                h, lp, decode=cache is not None and T == 1
-            )
-            if cfg.qkv_bias:
-                q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-            if cfg.qk_norm_whole:
-                # over the whole projection, before the heads are split
-                q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
-                k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
-            q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)
-            k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-            v = v.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-            if cfg.qk_norm:
-                # Qwen3/Gemma3: per-head RMSNorm on q/k BEFORE RoPE
-                q = rms_norm(
-                    q, lp["q_norm"], cfg.rms_norm_eps,
-                    cfg.norm_delta_gain,
-                )
-                k = rms_norm(
-                    k, lp["k_norm"], cfg.rms_norm_eps,
-                    cfg.norm_delta_gain,
-                )
-            if cfg.rope:
-                q = apply_rope(q, sin_b, cos_b)
-            q = q.reshape(
-                B, T, cfg.num_kv_heads, cfg.group_size, cfg.head_dim
-            )
-            if cfg.rope:
-                k = apply_rope(k, sin_b, cos_b)
-            unstored = cfg.kv_heads_stored - cfg.num_kv_heads
-            if unstored:
-                # so that the rows lie in the cache in whole tiles; what
-                # the heads that are none attend to is dropped below
-                q, k, v = heads_of_zeros_behind(unstored, q, k, v)
-
-            sinks_l = (
-                lp["sinks"].reshape(cfg.num_kv_heads, cfg.group_size)
-                if cfg.attn_sinks else None
-            )
-            if cache is None:
-                attn = _attend(
-                    q, k, v, mask_l, scale, cfg.attn_logit_softcap,
-                    sinks=sinks_l,
-                )
-            else:
-                attn, new_k, new_v = over_cache(
-                    q, k, v, carried.k, carried.v, store, positions[:, 0],
-                    positions=positions, mask=mask_l, scale=scale,
-                    decode_attn_impl=decode_attn_impl, walk=walk,
-                    attn_impl=attn_impl, mesh=mesh,
-                    softcap=cfg.attn_logit_softcap, sinks=sinks_l,
-                )
-                carried = dataclasses.replace(carried, k=new_k, v=new_v)
-            if unstored:
-                attn = attn[..., :cfg.q_dim]
-            if cfg.attn_output_gate:
-                # elementwise over the q_dim channels, from the layer's
-                # input, before Wo (arXiv:2505.06708)
-                with jax.named_scope("attn_output_gate"):
-                    gate = _mm("btd,dq->btq", h, lp["wg"])
-                    attn = (
-                        attn.astype(jnp.float32)
-                        * jax.nn.sigmoid(gate.astype(jnp.float32))
-                    ).astype(attn.dtype)
-
-        if state_layer is None:     # a state's mixer projects its own
-            attn_out = _mm("btq,qd->btd", attn, lp["wo"])
-        if cfg.o_bias:
-            attn_out = attn_out + lp["bo"]
-        if cfg.post_norms:
-            attn_out = rms_norm(
-                attn_out, lp["post_attn_norm"], cfg.rms_norm_eps,
-                cfg.norm_delta_gain,
-            )
-        if not norm_first:
-            attn_out = model_norm(attn_out, lp["attn_norm"], cfg)
-        if cfg.residual_multiplier != 1.0:
-            attn_out = attn_out * jnp.asarray(
-                cfg.residual_multiplier, attn_out.dtype
-            )
-        if cfg.parallel_block:
-            # attention and MLP both read the one norm's output, and
-            # both are added to the stream
-            x_mid, h2 = x_in, h
-        else:
-            x_mid = x_in + attn_out
-            h2 = (
-                model_norm(x_mid, lp["mlp_norm"], cfg) if norm_first
-                else x_mid
-            )
-        routing = None
-        if moe_layer:
-            mlp = _moe_mlp(
-                h2, lp["router"], lp["we_gate"], lp["we_up"], lp["we_down"],
-                cfg,
-                router_bias=lp.get("router_bias"),
-                shared=(
-                    (
-                        lp["ws_gate"], lp["ws_up"], lp["ws_down"],
-                        lp.get("shared_gate"),
-                    )
-                    if "ws_gate" in lp else None
-                ),
-                biases=(
-                    (lp["we_gate_b"], lp["we_up_b"], lp["we_down_b"])
-                    if cfg.moe_bias else None
-                ),
-                dispatch=moe_dispatch_impl,
-                layer=layer - kd if stacked else None,
-                count_held=count_held_pairs,
-                routing_out=routing_out,
-                live=live,
-                count_read=count_experts_read,
-            )
-            if counts or routing_out:
-                mlp, *extras = mlp
-                if routing_out:
-                    routing = extras.pop()
-                counts = [c + n for c, n in zip(counts, extras)]
-        else:
-            with jax.named_scope("gated_mlp"):
-                g = _mm("btd,df->btf", h2, lp["w_gate"])
-                u = _mm("btd,df->btf", h2, lp["w_up"])
-                mlp = _mm("btf,fd->btd", act(g) * u, lp["w_down"])
-        if cfg.post_norms:
-            mlp = rms_norm(
-                mlp, lp["post_mlp_norm"], cfg.rms_norm_eps,
-                cfg.norm_delta_gain,
-            )
-        if not norm_first:
-            mlp = model_norm(mlp, lp["mlp_norm"], cfg)
-        if cfg.residual_multiplier != 1.0:
-            mlp = mlp * jnp.asarray(cfg.residual_multiplier, mlp.dtype)
-        x_out = x_mid + attn_out + mlp if cfg.parallel_block else x_mid + mlp
-        return (x_out, carried, layer + 1, *counts), routing
-
-    # DeepSeek ships heterogeneous stacks: the first first_k_dense
-    # layers use a dense MLP, the rest MoE — structurally different
-    # params can't share one lax.scan, so the stacks run back-to-back,
-    # the second going on from the first's carry: the hidden state, the
-    # one cache (None without one) and the layer index.
     kd = (
         len(next(iter(params["dense_layers"].values())))
         if "dense_layers" in params else 0
     )
+    return Step(
+        cfg=cfg, B=B, T=T, max_len=cache.max_len if over else None,
+        positions=positions, mesh=mesh, attn_impl=attn_impl,
+        use_flash=use_flash,
+        decode_attn_impl=decode_attn_impl,
+        moe_dispatch_impl=moe_dispatch_impl, ssm_impl=ssm_impl, scale=scale,
+        mask=mask, mask_full=mask_full, mask_slide=mask_slide,
+        sin=sin, cos=cos, sin_loc=sin_loc, cos_loc=cos_loc,
+        mla_sin=mla_sin, mla_cos=mla_cos, walk=walk, walk_w=walk_w,
+        live=live, true_len=true_len, real=real, alive=alive,
+        count_held_pairs=count_held_pairs,
+        count_experts_read=count_experts_read, routing_out=routing_out,
+        stacked=stacked, kd=kd,
+    ), x
+
+
+# ---------------------------------------------------------------------------
+# A layer: one function a kind of mixer, and what follows any of them
+# ---------------------------------------------------------------------------
+#
+# A mixer is ``f(h, lp, carried, at, step) -> (out [B, T, d], carried)``:
+# the layer's input behind whatever norm stands before it, the layer's
+# leaves, the cache as the layers before left it (None without one),
+# where the layer's rows or state lie in their store, the Step. Beside
+# the three here: ``models/delta.py delta_mixer``, ``models/hybrid.py
+# mamba_layer`` and, for its one mixer a layer, ``mamba_mixer``,
+# ``experts_layer`` and ``attention_layer``.
+
+
+def gqa_attention(h, lp, carried, store, step: Step):
+    """One layer of grouped-query attention, with whatever of biases,
+    q/k norms, rotation, sinks, an output gate and heads of zeros behind
+    the stored ones the configuration names; ``store``: the layer's
+    index in ``carried.k, .v``."""
+    cfg, B, T = step.cfg, step.B, step.T
+    q, k, v = qkv_projections(h, lp, decode=carried is not None and T == 1)
+    if cfg.qkv_bias:
+        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+    if cfg.qk_norm_whole:
+        # over the whole projection, before the heads are split
+        q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
+    q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)
+    k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+    v = v.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+    if cfg.qk_norm:
+        # Qwen3/Gemma3: per-head RMSNorm on q/k BEFORE RoPE
+        q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps, cfg.norm_delta_gain)
+        k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps, cfg.norm_delta_gain)
+    if cfg.rope:
+        q = apply_rope(q, step.sin, step.cos)
+    q = q.reshape(B, T, cfg.num_kv_heads, cfg.group_size, cfg.head_dim)
+    if cfg.rope:
+        k = apply_rope(k, step.sin, step.cos)
+    unstored = cfg.kv_heads_stored - cfg.num_kv_heads
+    if unstored:
+        # so that the rows lie in the cache in whole tiles; what the
+        # heads that are none attend to is dropped below
+        q, k, v = heads_of_zeros_behind(unstored, q, k, v)
+    sinks_l = (
+        lp["sinks"].reshape(cfg.num_kv_heads, cfg.group_size)
+        if cfg.attn_sinks else None
+    )
+    if carried is None:
+        attn = _attend(
+            q, k, v, step.mask, step.scale, cfg.attn_logit_softcap,
+            sinks=sinks_l,
+        )
+    else:
+        attn, new_k, new_v = attend_over_cache(
+            q, k, v, carried.k, carried.v, store, step.positions[:, 0],
+            positions=step.positions, mask=step.mask, scale=step.scale,
+            decode_attn_impl=step.decode_attn_impl, walk=step.walk,
+            attn_impl=step.attn_impl, mesh=step.mesh,
+            softcap=cfg.attn_logit_softcap, sinks=sinks_l,
+            block=cfg.diffusion_block,
+        )
+        carried = dataclasses.replace(carried, k=new_k, v=new_v)
+    if unstored:
+        attn = attn[..., :cfg.q_dim]
+    if cfg.attn_output_gate:
+        # elementwise over the q_dim channels, from the layer's input,
+        # before Wo (arXiv:2505.06708)
+        with jax.named_scope("attn_output_gate"):
+            gate = _mm("btd,dq->btq", h, lp["wg"])
+            attn = (
+                attn.astype(jnp.float32)
+                * jax.nn.sigmoid(gate.astype(jnp.float32))
+            ).astype(attn.dtype)
+    return _mm("btq,qd->btd", attn, lp["wo"]), carried
+
+
+def mla_attention(h, lp, carried, layer, step: Step):
+    """One layer of latent attention (DeepSeek-V2/V3 family) over the
+    latent cache, ``layer`` its index there.
+
+    The step's latent rows (``c_kv`` after its norm, the shared rope
+    key after its rotation) are written to the cache; nothing wider
+    is ever stored. Then one of two forms of the same attention:
+
+    - **decompressed**, where the step's own rows are every key
+      there is (no cache, or a prefill from position 0 into a cache
+      of the step's length): ``k_nope`` and ``v`` are made per head
+      from ``c_kv`` inside the program, compute-bound, and attended
+      over as the projections make them (``_mla_over_own_rows``);
+    - **absorbed**, over cached rows (decode, verify, a
+      continuation): ``W_uk`` goes into the query (``q' = q_nope
+      W_uk^T``, 128 -> 512 a head) and ``W_uv`` into the output, and
+      all heads attend over the latent as one shared key/value head
+      of width 576 / 512, so a cached position is read once for all
+      64 heads and never decompressed.
+    """
+    cfg, B, T = step.cfg, step.B, step.T
+    H = cfg.num_heads
+    nope = cfg.qk_nope_head_dim
+    rank, vd = cfg.kv_lora_rank, cfg.v_head_dim
+    if cfg.q_lora_rank:
+        q_c = rms_norm(
+            _mm("btd,dr->btr", h, lp["wq_a"]),
+            lp["q_a_norm"], cfg.rms_norm_eps, False,
+        )
+        q = _mm("btr,rq->btq", q_c, lp["wq_b"])
+    else:
+        q = _mm("btd,dq->btq", h, lp["wq"])
+    # wq_b meets the same fold as a GQA layer's wq
+    (q,) = finish_products(carried is not None and T == 1, q)
+    q = q.reshape(B, T, H, cfg.head_dim)
+    q_nope = q[..., :nope]
+    q_pe = apply_rope_interleaved(q[..., nope:], step.mla_sin, step.mla_cos)
+    kv_a = _mm("btd,dr->btr", h, lp["wkv_a"])
+    c_kv = rms_norm(
+        kv_a[..., :rank], lp["kv_a_norm"], cfg.rms_norm_eps, False
+    )
+    k_pe = apply_rope_interleaved(
+        kv_a[..., rank:][:, :, None, :], step.mla_sin, step.mla_cos
+    )                                               # [B, T, 1, rope]
+    if carried is not None:
+        # the cache rides the scan without its one head (scan_layers)
+        write = partial(
+            _write_rows, layer=layer, start=step.positions[:, 0],
+            decode_attn_impl=step.decode_attn_impl,
+        )
+        carried = KVCache(
+            k=write(carried.k, c_kv), v=write(carried.v, k_pe[:, :, 0])
+        )
+    if carried is None or (T > 1 and step.max_len == T):
+        attn = _mla_over_own_rows(
+            q, c_kv, k_pe, lp["wk_b"], lp["wv_b"], step.mla_sin,
+            step.mla_cos, step.mask, step.scale, step.mesh,
+            step.attn_impl if step.use_flash else "xla",
+            step.positions[0, 0],
+        )
+        return _mm("btq,qd->btd", attn, lp["wo"]), carried
+
+    # absorbed, over this layer of the cache. An int8 weight's
+    # scales are per output channel of kv_b_proj, (head, nope) or
+    # (head, v): absorbing W_uk contracts over nope, so its scales
+    # go onto the query first; W_uv's multiply the output.
+    wk, wv = lp["wk_b"], lp["wv_b"]
+    if isinstance(wk, QuantW):
+        q_nope = q_nope * wk.s.reshape(H, nope).astype(q_nope.dtype)
+        wk = wk.q.astype(q_nope.dtype)
+    q_lat = jnp.einsum("bthn,rhn->bthr", q_nope, wk.reshape(rank, H, nope))
+    if step.decode_attn_impl == "xla":
+        c_all, r_all = (
+            lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
+            for buf in (carried.k, carried.v)
+        )                                   # [B, S, rank], [B, S, rope]
+        scores = (
+            jnp.einsum("bthr,bsr->bhts", q_lat, c_all)
+            + jnp.einsum("bthe,bse->bhts", q_pe, r_all)
+        ).astype(jnp.float32) * step.scale
+        scores = jnp.where(step.mask[:, None, :, :], scores, -1e30)
+        weights = jax.nn.softmax(scores, axis=-1).astype(q_lat.dtype)
+        u = jnp.einsum("bhts,bsr->bthr", weights, c_all)
+    else:
+        from gpustack_tpu.ops.mla_attention import mla_decode_attention
+
+        u = mla_decode_attention(
+            q_lat[:, 0], q_pe[:, 0], carried.k, carried.v, layer,
+            step.walk, step.scale,
+            interpret=step.decode_attn_impl == "kernel_interpret",
+        )[:, None]
+    if isinstance(wv, QuantW):
+        attn = jnp.einsum(
+            "bthr,rhv->bthv", u, wv.q.astype(u.dtype).reshape(rank, H, vd)
+        ) * wv.s.reshape(H, vd).astype(u.dtype)
+    else:
+        attn = jnp.einsum("bthr,rhv->bthv", u, wv.reshape(rank, H, vd))
+    return _mm("btq,qd->btd", attn.reshape(B, T, H * vd), lp["wo"]), carried
+
+
+def among_its_kind(layer, kind, period):
+    """Where a layer's rows, its state or its mixer's leaves lie in
+    the store or the stack of its kind. ``kind`` (static) is ``(the
+    kind, how many of it come before the layer in its period)``."""
+    return (layer // len(period)) * period.count(kind[0]) + kind[1]
+
+
+def leaves_at(stack, at):
+    """Layer ``at``'s leaves of a stack, read where they lie."""
+    return jax.tree.map(
+        lambda a: lax.dynamic_index_in_dim(a, at, 0, keepdims=False), stack
+    )
+
+
+def window_attention(h, lp, carried, at, step: Step):
+    """One GQA layer of a stack that keeps its sliding layers' rows
+    at window size (``cfg.window_rows``). ``at`` is ``(the layer's
+    index in the stack, kind)``, ``kind`` (static) ``(sliding, how many
+    of its kind come before it in its period)``: with the period's
+    number that says where its rows lie in its store.
+
+    A sliding layer sees keys ``0 <= i - j < sliding_window``; its
+    step's rows go to the ring at ``position mod W`` and a decode
+    step attends the ring's live rows, ``min(length, W)`` of them. A
+    full layer is a causal layer over ``k, v``. A prefill is from
+    position 0 and its own rows are every key, so it attends over
+    them as they come (a band in the flash kernel) and leaves each
+    sliding layer's last ``min(true_len, W)`` rows in the ring: a
+    row takes the newest real position of its residue, the padding
+    of a bucket writes nothing."""
+    cfg, B, T, positions = step.cfg, step.B, step.T, step.positions
+    layer, kind = at
+    sliding = kind[0]
+    q, k, v = qkv_projections(h, lp, decode=carried is not None and T == 1)
+    q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)
+    k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+    v = v.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+    if sliding or not cfg.nope_full_layers:
+        rotate = (
+            apply_rope_interleaved if cfg.rope_interleaved else apply_rope
+        )
+        q, k = rotate(q, step.sin, step.cos), rotate(k, step.sin, step.cos)
+    grouped = q.reshape(B, T, cfg.num_kv_heads, cfg.group_size, cfg.head_dim)
+    mask_l = step.mask_slide if sliding else step.mask_full
+    if carried is None:
+        attn = _attend(grouped, k, v, mask_l, step.scale)
+        return _mm("btq,qd->btd", attn, lp["wo"]), carried
+    store = among_its_kind(layer, kind, cfg.window_period)
+    buf_k, buf_v = (
+        (carried.wk, carried.wv) if sliding else (carried.k, carried.v)
+    )
+    rows = buf_k.shape[2]
+    if T == 1:
+        # a ring's live rows are its first min(length, W)
+        live_rows = step.mask_full if not sliding else (
+            jnp.arange(rows, dtype=jnp.int32)[None, None, :]
+            < jnp.minimum(positions + 1, rows)[:, :, None]
+        )
+        attn, buf_k, buf_v = attend_over_cache(
+            q, k, v, buf_k, buf_v, store,
+            positions[:, 0] % rows if sliding else positions[:, 0],
+            positions=positions, mask=live_rows, scale=step.scale,
+            decode_attn_impl=step.decode_attn_impl,
+            walk=step.walk_w if sliding else step.walk,
+            name="gqa_window_decode_attention" if sliding else None,
+        )
+    else:
+        if sliding:
+            # ring row r takes position r + W * ((n - 1 - r) // W),
+            # the newest real one of its residue, where r < n
+            n = (
+                step.true_len if step.true_len is not None
+                else jnp.full((B,), T, jnp.int32)
+            )[:, None]
+            r = jnp.arange(rows, dtype=jnp.int32)[None, :]
+            src = jnp.clip(r + rows * ((n - 1 - r) // rows), 0, T - 1)
+            kept = [
+                jnp.where(
+                    (r < n)[:, :, None, None],
+                    jnp.take_along_axis(new, src[:, :, None, None], axis=1),
+                    jnp.zeros((), new.dtype),
+                ) for new in (k, v)
+            ]
+        else:
+            kept = [k, v]
+        buf_k, buf_v = (
+            lax.dynamic_update_index_in_dim(buf, new, store, 0)
+            for buf, new in zip((buf_k, buf_v), kept)
+        )
+        if step.use_flash:
+            band = cfg.sliding_window if sliding else 0
+            attn = _flash_prefill(None, step.attn_impl)(
+                q, k, v, step.scale, q_offset=positions[0, 0],
+                window=band if band < T else 0,
+            )
+        else:
+            attn = _attend(grouped, k, v, mask_l, step.scale)
+    carried = dataclasses.replace(
+        carried, **(
+            dict(wk=buf_k, wv=buf_v) if sliding else dict(k=buf_k, v=buf_v)
+        )
+    )
+    return _mm("btq,qd->btd", attn, lp["wo"]), carried
+
+
+def after_mixer(carry, h, attn_out, carried, lp, step: Step, moe_layer: bool,
+                norm_first: bool = True):
+    """The rest of a layer, whatever its mixer: the mixer's output
+    ``attn_out [B, T, d]`` through its bias, post norm and multiplier
+    onto the stream, then the dense MLP or the routed experts
+    (``moe_layer``) with the counters the Step asks for: a scan's
+    ``(carry, routing)`` from the ``carry`` the layer was handed.
+    ``norm_first`` False: the layer's two norms stand on its sublayers'
+    outputs, not on their inputs (``cfg.norm_after``)."""
+    cfg = step.cfg
+    x_in, _, layer, *counts = carry
+    if cfg.o_bias:
+        attn_out = attn_out + lp["bo"]
+    if cfg.post_norms:
+        attn_out = rms_norm(
+            attn_out, lp["post_attn_norm"], cfg.rms_norm_eps,
+            cfg.norm_delta_gain,
+        )
+    if not norm_first:
+        attn_out = model_norm(attn_out, lp["attn_norm"], cfg)
+    if cfg.residual_multiplier != 1.0:
+        attn_out = attn_out * jnp.asarray(
+            cfg.residual_multiplier, attn_out.dtype
+        )
+    if cfg.parallel_block:
+        # attention and MLP both read the one norm's output, and both
+        # are added to the stream
+        x_mid, h2 = x_in, h
+    else:
+        x_mid = x_in + attn_out
+        h2 = model_norm(x_mid, lp["mlp_norm"], cfg) if norm_first else x_mid
+    routing = None
+    if moe_layer:
+        mlp = _moe_mlp(
+            h2, lp["router"], lp["we_gate"], lp["we_up"], lp["we_down"], cfg,
+            router_bias=lp.get("router_bias"),
+            shared=(
+                (
+                    lp["ws_gate"], lp["ws_up"], lp["ws_down"],
+                    lp.get("shared_gate"),
+                )
+                if "ws_gate" in lp else None
+            ),
+            biases=(
+                (lp["we_gate_b"], lp["we_up_b"], lp["we_down_b"])
+                if cfg.moe_bias else None
+            ),
+            dispatch=step.moe_dispatch_impl,
+            layer=layer - step.kd if step.stacked else None,
+            count_held=step.count_held_pairs,
+            routing_out=step.routing_out,
+            live=step.live,
+            count_read=step.count_experts_read,
+        )
+        if counts or step.routing_out:
+            mlp, *extras = mlp
+            if step.routing_out:
+                routing = extras.pop()
+            counts = [c + n for c, n in zip(counts, extras)]
+    else:
+        act = (
+            jax.nn.silu if cfg.hidden_act == "silu"
+            else lambda z: jax.nn.gelu(z, approximate=True)
+        )
+        with jax.named_scope("gated_mlp"):
+            g = _mm("btd,df->btf", h2, lp["w_gate"])
+            u = _mm("btd,df->btf", h2, lp["w_up"])
+            mlp = _mm("btf,fd->btd", act(g) * u, lp["w_down"])
+    if cfg.post_norms:
+        mlp = rms_norm(
+            mlp, lp["post_mlp_norm"], cfg.rms_norm_eps, cfg.norm_delta_gain
+        )
+    if not norm_first:
+        mlp = model_norm(mlp, lp["mlp_norm"], cfg)
+    if cfg.residual_multiplier != 1.0:
+        mlp = mlp * jnp.asarray(cfg.residual_multiplier, mlp.dtype)
+    x_out = x_mid + attn_out + mlp if cfg.parallel_block else x_mid + mlp
+    return (x_out, carried, layer + 1, *counts), routing
+
+
+# ---------------------------------------------------------------------------
+# The drivers: a stack of layers as one program
+# ---------------------------------------------------------------------------
+
+
+def _first_carry(x, cache, step: Step):
+    """A scan's carry before the first layer: the stream, the cache
+    (None without one), the layer's index, and the counters asked for
+    in ``_moe_mlp``'s order: held pairs, experts read."""
+    return (x, cache, jnp.int32(0)) + (jnp.int32(0),) * (
+        step.count_held_pairs + step.count_experts_read
+    )
+
+
+def _uniform_layer(carry, scanned, *, step: Step, mixer, moe_layer: bool):
+    """:func:`scan_layers`' body: one layer of ``mixer``."""
+    lp, sliding = scanned
+    if step.mask_full is not None:
+        # gemma-style alternating layers: the layer's own mask and
+        # rotation, by its slide flag
+        step = dataclasses.replace(
+            step,
+            mask=jnp.where(sliding, step.mask_slide, step.mask_full),
+            sin=jnp.where(sliding, step.sin_loc, step.sin),
+            cos=jnp.where(sliding, step.cos_loc, step.cos),
+        )
+    lp = {**lp, **step.stacked}
+    h = model_norm(carry[0], lp["attn_norm"], step.cfg)
+    attn_out, carried = mixer(h, lp, carry[1], carry[2], step)
+    return after_mixer(carry, h, attn_out, carried, lp, step, moe_layer)
+
+
+def scan_layers(params: Params, step: Step, x, cache):
+    """A uniform stack, a ``lax.scan`` over the stacked layers:
+    ``(x, cache, extras)``. DeepSeek ships heterogeneous stacks: the
+    first ``first_k_dense`` layers use a dense MLP, the rest MoE;
+    structurally different params can't share one scan, so the two run
+    back to back, the second going on from the first's carry."""
+    cfg, kd = step.cfg, step.kd
+    body = partial(
+        _uniform_layer, step=step,
+        mixer=mla_attention if cfg.is_mla else gqa_attention,
+    )
+    slide_flags = (
+        jnp.asarray(cfg.layer_sliding, jnp.bool_)
+        if cfg.layer_sliding is not None
+        else jnp.zeros((cfg.num_layers,), jnp.bool_)
+    )
+    layers = {
+        k: v for k, v in params["layers"].items() if k not in step.stacked
+    }
     if cfg.is_mla and cache is not None:
         # an MLA cache has one head: inside the scan it is the same
         # arrays without it, as the TPU stores them (``_write_rows``)
         cache = KVCache(k=cache.k[:, :, :, 0, :], v=cache.v[:, :, :, 0, :])
-    # the counters asked for, in _moe_mlp's order: held pairs, experts read
-    carry = (x, cache, jnp.int32(0)) + (jnp.int32(0),) * (
-        count_held_pairs + count_experts_read
-    )
+    carry = _first_carry(x, cache, step)
     if kd:
         carry, _ = lax.scan(
-            partial(block, moe_layer=False),
+            partial(body, moe_layer=False),
             carry, (params["dense_layers"], slide_flags[:kd]),
         )
-    if period:
-        # Window and full layers in one stack: a scan over the periods,
-        # a period's layers written out in its body, each reading its
-        # own store. (A ``lax.switch`` on the kind inside a scan over
-        # the layers copies the stacked cache whole through the
-        # conditional every step: models/hybrid.py.)
-        per = len(period)
-        # (sliding, how many of its kind come before it in the period);
-        # under ``layer_types`` the mixer's name where ``sliding`` is
-        kinds = [(s, period[:j].count(s)) for j, s in enumerate(period)]
-        by_mixer = {
-            "linear_attention": params.get("delta_layers"),
-            "mamba": params.get("ssm_layers"),
-            "full_attention": params.get("attn_layers"),
-        }
-
-        def leaves_at(stack, at):
-            return jax.tree.map(
-                lambda a: lax.dynamic_index_in_dim(a, at, 0, keepdims=False),
-                stack,
-            )
-
-        def one_period(carry, _):
-            routings = []
-            for kind in kinds:
-                # the layer's leaves read where they lie in the stacks,
-                # by its index: as a scan's slices of [periods, layers a
-                # period, ...] a period's four matrices of every kind
-                # are copied out before the body reads them (1.3 GB of
-                # temporaries at this model's widths, compiled for a
-                # described v5e)
-                lp = leaves_at(layers, carry[2])
-                if state_layers is not None:
-                    # and its mixer's, at its place among its kind
-                    lp.update(leaves_at(
-                        by_mixer[kind[0]], among_its_kind(carry[2], kind)
-                    ))
-                carry, routing = block(
-                    carry, (lp, None), moe_layer=cfg.is_moe, kind=kind,
-                )
-                routings.append(routing)
-            if not routing_out:
-                return carry, None
-            return carry, tuple(jnp.stack(r) for r in zip(*routings))
-
-        (x, new_cache, _, *extras), routing = lax.scan(
-            one_period, carry, None, length=cfg.num_layers // per,
+    (x, cache, _, *extras), routing = lax.scan(
+        partial(body, moe_layer=cfg.is_moe), carry, (layers, slide_flags[kd:])
+    )
+    if step.routing_out:
+        extras.append(routing)
+    if cfg.is_mla and cache is not None:
+        cache = KVCache(
+            k=cache.k[:, :, :, None, :], v=cache.v[:, :, :, None, :]
         )
-        if routing_out:
-            # [periods, layers a period, ...] -> [L, ...]
-            routing = tuple(
-                r.reshape(-1, *r.shape[2:]) for r in routing
-            )
+    return x, cache, extras
+
+
+def _one_period(carry, _, *, params: Params, layers, step: Step, kinds):
+    """:func:`scan_periods`' body: a period's layers written out, each
+    reading its own store. ``kinds``: a layer's ``(kind, its mixer's
+    function, the stack of its mixer's leaves or None)``."""
+    cfg = step.cfg
+    routings = []
+    for kind, mixer, stack in kinds:
+        # the layer's leaves read where they lie in the stacks, by its
+        # index: as a scan's slices of [periods, layers a period, ...] a
+        # period's four matrices of every kind are copied out before the
+        # body reads them (1.3 GB of temporaries at Command A+'s widths,
+        # compiled for a described v5e)
+        lp = leaves_at(layers, carry[2])
+        at = (carry[2], kind)
+        if stack is not None:
+            # and its mixer's, at its place among its kind, where its
+            # rows or its state lie in their store too (worked out twice,
+            # as the programs' text has it: ROADMAP C17)
+            lp.update(leaves_at(
+                params[stack],
+                among_its_kind(carry[2], kind, cfg.mixer_period),
+            ))
+            at = among_its_kind(carry[2], kind, cfg.mixer_period)
+        lp = {**lp, **step.stacked}
+        norm_first = kind[0] not in cfg.norm_after
+        h = carry[0]
+        if norm_first:
+            h = model_norm(h, lp["attn_norm"], cfg)
+        attn_out, carried = mixer(h, lp, carry[1], at, step)
+        carry, routing = after_mixer(
+            carry, h, attn_out, carried, lp, step, cfg.is_moe, norm_first
+        )
+        routings.append(routing)
+    if not step.routing_out:
+        return carry, None
+    return carry, tuple(jnp.stack(r) for r in zip(*routings))
+
+
+def scan_periods(params: Params, step: Step, x, cache, period):
+    """Several kinds of layer in a pattern that repeats (``period``:
+    ``cfg.window_period``, window and full layers over two stores; or
+    ``cfg.mixer_period``, a mixer by kind under ``layer_types``): a scan
+    over the periods, a period's layers written out in its body:
+    ``(x, cache, extras)``. (A ``lax.switch`` on the kind inside a scan
+    over the layers copies the stacked cache whole through the
+    conditional every step: models/hybrid.py.)"""
+    from gpustack_tpu.models.delta import delta_mixer
+    from gpustack_tpu.models.hybrid import mamba_layer
+
+    cfg = step.cfg
+    # a kind of ``layer_types``: its mixer and the stack of its leaves;
+    # a window stack's (``layer_sliding``) are all in ``layers``
+    by_kind = {
+        "linear_attention": (delta_mixer, "delta_layers"),
+        "mamba": (mamba_layer, "ssm_layers"),
+        "full_attention": (gqa_attention, "attn_layers"),
+    }
+    # (the kind, how many of it come before the layer in the period)
+    kinds = [
+        ((s, period[:j].count(s)), *by_kind.get(s, (window_attention, None)))
+        for j, s in enumerate(period)
+    ]
+    layers = {
+        k: v for k, v in params["layers"].items() if k not in step.stacked
+    }
+    (x, cache, _, *extras), routing = lax.scan(
+        partial(
+            _one_period, params=params, layers=layers, step=step, kinds=kinds
+        ),
+        _first_carry(x, cache, step), None,
+        length=cfg.num_layers // len(period),
+    )
+    if step.routing_out:
+        # [periods, layers a period, ...] -> [L, ...]
+        extras.append(tuple(r.reshape(-1, *r.shape[2:]) for r in routing))
+    return x, cache, extras
+
+
+def forward(
+    params: Params,
+    cfg: ModelConfig,
+    tokens: jax.Array,                # [B, T] int32
+    positions: jax.Array,             # [B, T] int32 absolute positions
+    cache: Optional[KVCache] = None,
+    return_hidden: bool = False,
+    attn_impl: str = "xla",
+    mesh=None,
+    embeds_override: Optional[Tuple[jax.Array, jax.Array]] = None,
+    moe_dispatch_impl: Optional[str] = None,
+    decode_attn_impl: Optional[str] = None,
+    live: Optional[jax.Array] = None,
+    count_held_pairs: bool = False,
+    routing_out: bool = False,
+    count_experts_read: bool = False,
+    true_len: Optional[jax.Array] = None,
+    ssm_impl: Optional[str] = None,
+    logits_at: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Optional[KVCache]]:
+    """Run the model: ``(logits [B, T, vocab] float32, the updated cache
+    or None)``. How the pieces fit: docs/MODELS.md.
+
+    Without ``cache``: plain causal forward (training / scoring path).
+    With ``cache``: each layer writes the step's rows into it at
+    ``positions`` (only those rows: ``KVCache``) and attends over its
+    own layer of it. ``T > 1`` is a prefill step, ``T == 1`` a decode
+    step: same code path, different jit specialization.
+
+    ``attn_impl``: the prefill attention, ``"xla"`` (einsum scores),
+    ``"flash"`` (the blocked kernel, for a prefill into a cache that
+    holds its rows; ``"flash_interpret"`` for hermetic CPU tests) or
+    ``"ring"`` (needs ``mesh`` with an ``sp`` axis: the cache stays
+    sharded over its positions, ops/ring_attention.py). A model the
+    kernel refuses (:func:`needs_xla_attention`) raises: the caller
+    chooses (``engine/runner.py prefill_attention``), this function
+    never falls back in silence.
+
+    ``moe_dispatch_impl``, ``decode_attn_impl``, ``ssm_impl``: None, and
+    :func:`moe_dispatch`, :func:`decode_attention_impl` and
+    ``models/hybrid.py ssm_update_impl`` choose from what can be
+    observed; a name instead for the tests (``"grouped_interpret"``,
+    ``"touched_interpret"``, ``"kernel_interpret"``) and for a caller
+    that differentiates (``"dense"``: the kernels have no VJP).
+
+    ``live`` (bool ``[B]``; None: every slot): the slots somebody holds.
+    A decode step's kernels read a dead slot as far as length 0, route
+    it to no expert and move no state of it; its logits mean nothing,
+    its row of the cache is still written. ``true_len`` (int32 ``[B]``;
+    None: every position counts): how many of a prefill's ``T``
+    positions are real, for what a slot keeps beside its rows (a
+    recurrent state, a window's ring), which must end at the last real
+    position and not in a bucket's padding.
+
+    ``logits_at`` (int32 ``[B]``, an index along ``T``): logits ``[B, 1,
+    vocab]`` of the one row a sequence it names (:func:`head`);
+    ``return_hidden``: the normed hidden states in their place.
+
+    After the cache, in this order, each only if asked for:
+    ``count_held_pairs`` (a share of the experts, ``cfg.experts_held``):
+    how many of the router's pairs fell on experts held here, summed
+    over the layers (int32; ``gpustack_engine_moe_pairs_total``);
+    ``count_experts_read``: the held experts whose weights the step
+    read (int32; ``held * layers`` but under ``"touched"``:
+    ``gpustack_engine_moe_decode_experts_total``); ``routing_out``:
+    ``(chosen int32 [L_moe, B, T, k], router logits float32 [L_moe, B,
+    T, E])`` for a reference that must follow the program's choices
+    (``perfbench/reference_check.py``; no served program asks).
+    """
+    step, x = make_step(
+        params, cfg, tokens, positions, cache, attn_impl, mesh,
+        embeds_override, moe_dispatch_impl, decode_attn_impl, live,
+        count_held_pairs, routing_out, count_experts_read, true_len, ssm_impl,
+    )
+    period = cfg.window_period if cfg.window_rows else cfg.mixer_period
+    if cfg.layer_kinds is not None:
+        from gpustack_tpu.models.hybrid import forward_hybrid
+
+        x, cache, extras = forward_hybrid(params, step, x, cache)
+    elif period:
+        x, cache, extras = scan_periods(params, step, x, cache, period)
     else:
-        (x, new_cache, _, *extras), routing = lax.scan(
-            partial(block, moe_layer=cfg.is_moe),
-            carry, (layers, slide_flags[kd:]),
-        )
-    if routing_out:
-        extras = [*extras, routing]
-
-    if cfg.is_mla and new_cache is not None:
-        new_cache = KVCache(
-            k=new_cache.k[:, :, :, None, :], v=new_cache.v[:, :, :, None, :]
-        )
-    out = head(x, params, cfg, logits_at, return_hidden)
-    return (out, new_cache, *extras)
+        x, cache, extras = scan_layers(params, step, x, cache)
+    return (head(x, params, cfg, logits_at, return_hidden), cache, *extras)

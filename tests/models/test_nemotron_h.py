@@ -7,7 +7,6 @@ file."""
 import dataclasses
 import json
 import os
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +24,9 @@ from gpustack_tpu.models.transformer import (
     KVCache,
     _moe_mlp,
     forward,
+    head,
     init_params,
+    make_step,
 )
 from perfbench.reference import nemotron_h as ref
 
@@ -146,10 +147,14 @@ def test_the_full_forward_is_the_reference_s(jitted):
     cfg, params = model()
     toks = tokens()
     pos = jnp.arange(T, dtype=jnp.int32)[None]
-    run = partial(forward_hybrid, cfg=cfg)
+    def run(params, tokens, positions):
+        step, x = make_step(params, cfg, tokens, positions)
+        x, _, _ = forward_hybrid(params, step, x)
+        return head(x, params, cfg)
+
     if jitted:
         run = jax.jit(run)
-    logits, _ = run(params, tokens=toks, positions=pos)
+    logits = run(params, tokens=toks, positions=pos)
     want, _ = ref.forward(params, HF, toks[0].tolist(), list(range(T)))
     np.testing.assert_allclose(logits[0], want, rtol=2e-4, atol=2e-4)
 
@@ -449,18 +454,15 @@ def layer_at_a_time(params, cfg, toks, pos, cache, *, live, true_len, impl):
     from gpustack_tpu.models import transformer as tf
     from gpustack_tpu.models.hybrid import mamba_mixer
 
-    B, T = toks.shape
-    real = (
-        jnp.ones((B, T), bool) if true_len is None
-        else jnp.arange(T, dtype=jnp.int32)[None, :] < true_len[:, None]
+    step, x = make_step(
+        params, cfg, toks, pos, cache, live=live, true_len=true_len,
+        ssm_impl=impl,
     )
-    alive = live if live is not None else jnp.ones((B,), bool)
-    x = tf._embed_lookup(params["embed"], toks, jnp.float32)
     for i in range(cfg.layers_of("M")):
         lp = jax.tree.map(lambda a: a[i], params["ssm_layers"])
         out, cache, kept = mamba_mixer(
             tf.rms_norm(x, lp["norm"], cfg.rms_norm_eps), lp, cache,
-            jnp.int32(i), cfg=cfg, impl=impl, real=real, alive=alive,
+            jnp.int32(i), step,
         )
         cache = dataclasses.replace(
             cache, conv=jax.lax.dynamic_update_index_in_dim(
@@ -596,8 +598,8 @@ def test_the_whole_pattern_s_step_reads_the_rows_as_it_received_them(
     got = run()
     mixer = hybrid.mamba_mixer
 
-    def a_layer_at_a_time(h, lp, carried, i, **bound):
-        out, carried, kept = mixer(h, lp, carried, i, **bound)
+    def a_layer_at_a_time(h, lp, carried, i, step):
+        out, carried, kept = mixer(h, lp, carried, i, step)
         return out, dataclasses.replace(
             carried, conv=jax.lax.dynamic_update_index_in_dim(
                 carried.conv, kept, i, 0

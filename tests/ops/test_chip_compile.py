@@ -1834,7 +1834,7 @@ def test_the_other_models_programs_lower_to_the_text_they_had(
     experts at several rows a slot went into ``forward``; Nemotron's
     two taken again with PR 64 (its mixers hand their conv rows back
     and the step writes the stack once: ``models/hybrid.py``) and
-    Granite's two with it (``bound_state_mixers`` places the rows its
+    Granite's two with it (``hybrid.mamba_layer`` places the rows its
     mixer hands back, so a row's update stands after the state's in the
     text: the same operations in another order, and compiled the
     parent's program), which left the other fourteen as they were
