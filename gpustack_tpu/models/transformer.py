@@ -326,6 +326,21 @@ def _write_rows(
     whatever the layout: 17 MB read and written a layer at 16 slots of
     8,192, and the array copied whole once in and once out of the scan
     (0.2 GB and 0.6 ms each in a decode step: PERF.md section 6, PR 54).
+
+    A GQA cache's rows come here from ``attend_over_cache`` in every
+    case but one, a **whole tile**: a diffusion block's ``T`` rows a slot
+    under the decode kernel, where ``T`` rows of the stored heads are
+    whole stored tiles, go over their tile by the aliased call beside
+    that kernel's view (``ops/cache_write.py gqa_write_block_rows``,
+    keys and values in one call, nothing fetched) and not through the
+    loop of one update a slot below (2.5 of SDAR's 11.45 ms pass: PERF.md
+    section 6, PR 66). A **part tile**, one row a slot of any GQA model's
+    decode step, is a quarter of such a tile and wants the latent's
+    read-modify-write; it keeps the scatter until the benchmark's count
+    of experts read is mended (ROADMAP A5(b), B5). **Everything else**
+    keeps the scatter as the latent does: a prefill, a verify step, a
+    chunk, ``by_position``, a mesh, any other platform, a block that is
+    no whole tile.
     """
     T = rows.shape[1]
     one_head = buf.ndim == 4
@@ -946,7 +961,11 @@ def attend_over_cache(
     (``decode_attn_impl`` not ``"xla"``; ``name``: the call's in a
     trace; the ``T`` rows of a diffusion block, ``block``, see one key
     set, every row below ``start + T``, and go in as ``T x G`` query
-    rows of their kv head: :func:`block_rows_as_heads`), else over the
+    rows of their kv head: :func:`block_rows_as_heads`; such a block
+    that is whole stored tiles of the view the kernel reads is written
+    over them by ``ops/cache_write.py``'s call, which floors a start
+    that is no multiple of ``T``, a dead slot's, to its block; every
+    other step's rows by :func:`_write_rows`, which says why), else over the
     layer's rows by ``attn_impl``: ``"ring"``
     (``sp``: a cache sharded over its positions), the flash kernel for
     several rows a slot over a cache that holds them, or ``_attend``
@@ -970,7 +989,20 @@ def attend_over_cache(
     side = buf_k.shape[-1] // hd
     if side > 1:
         k, v = (a.reshape(B, T, Hkv // side, side * hd) for a in (k, v))
-    buf_k, buf_v = write(buf_k, k), write(buf_v, v)
+    whole_tiles = False
+    if block and T == block and decode_attn_impl != "xla":
+        from gpustack_tpu.ops import cache_write
+
+        whole_tiles = cache_write.a_block_is_whole_tiles(buf_k, T)
+    if whole_tiles:
+        # a diffusion block's rows are a stored tile of the view the
+        # kernel below reads: written over it, keys and values in a call
+        buf_k, buf_v = cache_write.gqa_write_block_rows(
+            buf_k, buf_v, k, v, index, start,
+            interpret=decode_attn_impl == "kernel_interpret",
+        )
+    else:
+        buf_k, buf_v = write(buf_k, k), write(buf_v, v)
     if decode_attn_impl != "xla":
         # a decode step on one chip: the kernel reads the layer's rows
         # where they lie and no slab is taken out of the carry

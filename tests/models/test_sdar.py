@@ -26,6 +26,7 @@ from gpustack_tpu.models.config import (
     load_hf_config,
 )
 from gpustack_tpu.models.transformer import KVCache, forward
+from gpustack_tpu.ops import cache_write
 from gpustack_tpu.testing import reference_sdar as ref
 from gpustack_tpu.testing.sdar_small import GENERATION, HF, MASK_ID, small
 
@@ -166,17 +167,29 @@ def interpreted():
     return stack
 
 
-@pytest.fixture(scope="module", params=["xla", "kernels"])
-def passes(request, model):
+_PASSES = {}
+
+
+def passes_in(model, kernels: bool):
     """Four prompts (``P mod L`` 0, 1, 2, 3; two of them in the bucket
     the flash kernel serves) prefilled, inserted and run for nine passes
     with the program's logits as an output: ``{P mod L: the largest
     difference of any pass's logits from the reference's full forward
     over [decided tokens ..., the block with its mask ids]}``, the forms
-    chosen and the blocks each slot completed."""
+    chosen, the blocks each slot completed, how often the block's row
+    write was traced as the tile call, and each pass's logits, blocks and
+    commits and the cache they left, for a comparison of the two forms.
+    Once a process a form."""
+    if kernels in _PASSES:
+        return _PASSES[kernels]
     cfg, params = model
-    kernels = request.param == "kernels"
-    with interpreted() if kernels else contextlib.nullcontext():
+    with contextlib.ExitStack() as stack:
+        if kernels:
+            stack.enter_context(interpreted())
+        tile_write = stack.enter_context(mock.patch.object(
+            cache_write, "gqa_write_block_rows",
+            wraps=cache_write.gqa_write_block_rows,
+        ))
         r = ModelRunner(
             cfg, params, max_slots=4, max_seq_len=256,
             prefill_buckets=(32, 128, 256),
@@ -192,11 +205,13 @@ def passes(request, model):
         worst = {len(p) % L: 0.0 for p in prompts}
         blocks = [0] * 4
         key = np.zeros((2,), np.uint32)
+        trail = []
         for _ in range(9):
             tokens = np.asarray(state.block_tokens)
             decided = np.asarray(state.block_decided)
             state, out = r.denoise_step(state, key, probe=True)
             committed, logits = np.asarray(out[2]), np.asarray(out[7])
+            trail.append((tokens, decided, committed, logits))
             for slot, p in enumerate(prompts):
                 ids = ref.pass_ids(
                     seqs[slot], tokens[slot], decided[slot], MASK_ID, len(p)
@@ -213,11 +228,19 @@ def passes(request, model):
                     seqs[slot] += [int(t) for t in tokens[slot]]
                     blocks[slot] += 1
         positions = np.asarray(state.positions).tolist()
-    return {
+        cache = (np.asarray(state.cache.k), np.asarray(state.cache.v))
+    _PASSES[kernels] = {
         "worst": worst, "blocks": blocks, "positions": positions,
         "forms": (r.decode_attention, r.decode_moe_dispatch),
-        "kernels": kernels,
+        "kernels": kernels, "tile_writes": tile_write.call_count,
+        "trail": trail, "cache": cache,
     }
+    return _PASSES[kernels]
+
+
+@pytest.fixture(scope="module", params=["xla", "kernels"])
+def passes(request, model):
+    return passes_in(model, request.param == "kernels")
 
 
 @pytest.mark.parametrize("tail", [0, 1, 2, 3])
@@ -239,6 +262,30 @@ def test_the_passes_ran_in_the_forms_the_choosers_named(passes):
     # commit, then 5 a block
     assert passes["blocks"] == [1, 2, 2, 2]
     assert passes["positions"] == [104, 48, 108, 48]
+    # a block of 4 rows of 2 heads is a float32 tile: the pass's row
+    # write is the tile call, traced once (the layers' scan), and a
+    # prefill's, or anything's in the XLA form, never
+    assert passes["tile_writes"] == (1 if passes["kernels"] else 0)
+
+
+def test_the_tile_call_leaves_what_the_scatter_leaves_over_two_blocks(model):
+    """The same nine passes with the block's rows written by the tile
+    call (the kernels' form) and by the scatter (the XLA form): three
+    slots finish a block, commit it and denoise the next over the rows
+    the commit kept. The same blocks and commits pass for pass, logits
+    and the cache left behind to the rounding of the two attention
+    forms."""
+    scatter, call = passes_in(model, False), passes_in(model, True)
+    assert (scatter["tile_writes"], call["tile_writes"]) == (0, 1)
+    assert scatter["blocks"] == call["blocks"] == [1, 2, 2, 2]
+    for a, b in zip(scatter["trail"], call["trail"]):
+        for x, y in zip(a[:3], b[:3]):          # tokens, decided, committed
+            assert np.array_equal(x, y)
+        np.testing.assert_allclose(a[3], b[3], atol=2e-4)
+    for x, y in zip(scatter["cache"], call["cache"]):
+        np.testing.assert_allclose(x, y, atol=2e-5)
+    # layer 0's rows come of the embedding alone: bit for bit
+    assert np.array_equal(scatter["cache"][0][0], call["cache"][0][0])
 
 
 # ---- the rules ---------------------------------------------------------------

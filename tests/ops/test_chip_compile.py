@@ -1729,8 +1729,10 @@ def test_a_diffusion_block_pass_reads_cache_and_experts_where_they_lie(
     rows over the cache, both kernels are in the program once a scan (the
     decode kernel with the block's rows folded into its group: 4 kv heads
     x 4 rows x 8 query heads = 128 query rows), no layer's slab of rows
-    and no expert matrix is copied, sliced or transposed, and the dense
-    products' ``[128, 128, 768]`` exists nowhere."""
+    and no expert matrix is copied, sliced or transposed, the dense
+    products' ``[128, 128, 768]`` exists nowhere, and the block's rows
+    reach the cache by ``ops/cache_write.py``'s call, not the scatter's
+    loop."""
     import os
 
     from gpustack_tpu.models import init_params
@@ -1789,6 +1791,24 @@ def test_a_diffusion_block_pass_reads_cache_and_experts_where_they_lie(
     assert not re.findall(
         rf"= bf16\[2,{slots},{S},4,128\][^ ]* (?:copy|transpose)\(", text
     )
+    # the block's rows go over their stored tile in one aliased call a
+    # layer, keys and values, on the view the decode kernel reads; the
+    # scatter's loop of one update a slot (a ``while`` that carries the
+    # cache, for K and again for V) is in the program no more: the one
+    # ``while`` left is the layers' scan
+    written = re.findall(
+        rf"%gqa_write_block_rows[\w.\-]* = \(bf16\[2,{slots},{S * 4},128\]"
+        rf"[^ ]*, bf16\[2,{slots},{S * 4},128\][^ ]*\) custom-call\(.*", text
+    )
+    assert len(written) == 1
+    assert "output_to_operand_aliasing={{0}: (4, {}), {1}: (5, {})}" in (
+        written[0]
+    )
+    assert not re.findall(
+        rf"= bf16\[2,{slots},{S * 4},128\][^ ]* (?:copy|transpose)\(", text
+    )
+    assert len(re.findall(r" while\(", text)) == 1
+    assert not re.findall(r' while\(.*op_name="[^"]*/scatter"', text)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
 
 
